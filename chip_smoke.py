@@ -1,0 +1,766 @@
+#!/usr/bin/env python3
+"""Smoke of the serving path on one TPU v5e (or, with ``--chips 4``, of the
+multi-chip path on four): the quickest proof that the system still starts on
+the chip.
+
+    python chip_smoke.py             # one chip; the run the driver makes
+    python chip_smoke.py --chips 4   # the mesh phase and nothing else
+    python chip_smoke.py --rehearse  # same phases on the CPU at tiny widths
+
+The last line of stdout is one JSON object,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``, with
+the device as JAX reports it; everything else goes on earlier lines.  Any
+failed phase, any non-200, any device that is not a TPU (``--rehearse``
+excepted, whose last line says ``cpu`` and so cannot pass for a chip run)
+exits non-zero without that line.
+
+One process per chip: libtpu gives the chip to one process at a time, so this
+process never imports JAX — it starts the real entry points as children, one
+after the other, and talks HTTP to them.
+
+One-chip phases, at the published widths (weights random, from the builders'
+fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
+
+- *serve*    ``tpuserve serve`` with resnet50 (224 px, buckets 1 and 8) and
+             GPT-2 small three times — slot scheduler, paged KV, int8
+             weights; ``:predict`` / ``:generate`` requests, ``/healthz``,
+             ``/metrics``, SIGINT.
+- *restart*  the same config again: the boot must hit the compile cache.
+- *kernels*  ``int8_matmul`` and ``flash_attention`` with ``interpret=False``
+             at real shapes against ``jax.numpy`` references, on the chip.
+- *sd15*     Stable Diffusion 1.5 at 512x512, two steps, one ``:submit``
+             polled to ``done`` (flash attention's only serving caller).
+"""
+
+from __future__ import annotations
+
+import argparse
+import base64
+import io
+import json
+import os
+import signal
+import socket
+import subprocess
+import sys
+import time
+import urllib.error
+import urllib.request
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent
+PKG = ROOT / "pytorch_zappa_serverless_tpu"
+OUT = ROOT / "smoke_out"
+SEED = 20260926
+GEN_TOKENS = 16  # tokens asked of every :generate stream
+# --rehearse widths: d_model is one 128-lane tile, the int8 kernel's floor.
+TINY_GPT2 = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 256,
+             "vocab_size": 512, "max_positions": 128}
+
+
+# Where the cache lives when JAX_COMPILATION_CACHE_DIR does not place it.
+IN_CHECKOUT_CACHE = ROOT / ".cache" / "xla"
+
+
+def entries(directory: Path) -> int:
+    return len(list(directory.iterdir())) if directory.is_dir() else 0
+
+
+class SmokeFailure(Exception):
+    """A phase did not do what it must; the message says which and why."""
+
+
+def say(msg: str) -> None:
+    print(f"[smoke] {msg}", flush=True)
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+# -- children ----------------------------------------------------------------
+
+_CHILDREN: list[subprocess.Popen] = []
+
+
+def child_env(rehearse: bool, devices: int = 1) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(ROOT) + (os.pathsep + env["PYTHONPATH"]
+                                     if env.get("PYTHONPATH") else "")
+    if rehearse:
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                            + f" --xla_force_host_platform_device_count={devices}")
+    return env
+
+
+def run_child(code: str, rehearse: bool, log_name: str, *, devices: int = 1,
+              timeout: float = 900.0) -> dict:
+    """Run ``code`` in a fresh interpreter that owns the chip while it lives;
+    its last stdout line is its JSON result, its stderr goes to a log."""
+    log_path = OUT / log_name
+    with open(log_path, "wb") as log:
+        proc = subprocess.Popen([sys.executable, "-c", code], cwd=str(ROOT),
+                                env=child_env(rehearse, devices),
+                                stdout=subprocess.PIPE, stderr=log)
+        _CHILDREN.append(proc)
+        try:
+            out, _ = proc.communicate(timeout=timeout)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.communicate()
+            raise SmokeFailure(f"{log_name}: child still running after "
+                               f"{timeout:.0f}s") from None
+    lines = out.decode(errors="replace").strip().splitlines()
+    if proc.returncode != 0 or not lines:
+        tail = log_path.read_text(errors="replace").strip()[-1500:]
+        raise SmokeFailure(f"{log_name}: child exited {proc.returncode}\n"
+                           f"{tail}")
+    for line in lines[:-1]:
+        if not line.startswith("{"):  # the engine's JSON log records
+            say(f"  {line}")
+    return json.loads(lines[-1])
+
+
+_PROBE = """\
+import json, jax
+from pytorch_zappa_serverless_tpu.engine.cache import resolve_compile_cache_dir
+from pytorch_zappa_serverless_tpu.utils.device import device_info
+print(json.dumps({"device": device_info(), "jax": jax.__version__,
+                  "cache_dir": resolve_compile_cache_dir()}))
+"""
+
+
+def probe_device(rehearse: bool, chips: int) -> dict:
+    """What JAX finds, from a child that exits before any other starts."""
+    info = run_child(_PROBE, rehearse, "probe.log", devices=chips,
+                     timeout=300.0)
+    dev = info["device"]
+    say(f"device: {dev}  jax {info['jax']}  compile cache: "
+        f"{info['cache_dir']}")
+    want = "cpu" if rehearse else "tpu"
+    check(dev["platform"] == want,
+          f"JAX found platform {dev['platform']!r}, this run needs {want!r}"
+          + ("" if rehearse else " (no accelerator: nothing to smoke; "
+             "--rehearse runs the phases on the CPU)"))
+    check(dev["count"] >= chips,
+          f"--chips {chips} needs {chips} devices, JAX found {dev['count']}")
+    return info
+
+
+# -- inputs, generated from the seed ------------------------------------------
+
+def make_inputs(rehearse: bool) -> dict:
+    import numpy as np
+    from PIL import Image
+
+    rng = np.random.default_rng(SEED)
+    (OUT / "inputs").mkdir(parents=True, exist_ok=True)
+    jpegs = []
+    for i in range(8):
+        arr = rng.integers(0, 256, (320, 400, 3), np.uint8)
+        buf = io.BytesIO()
+        Image.fromarray(arr).save(buf, format="JPEG")
+        (OUT / "inputs" / f"img{i}.jpg").write_bytes(buf.getvalue())
+        jpegs.append(buf.getvalue())
+    vocab = 512 if rehearse else 50257
+    prompts = [[int(t) for t in rng.integers(1, vocab - 2, n)]
+               for n in (12, 9, 14)]
+    (OUT / "inputs" / "prompts.json").write_text(json.dumps(prompts))
+    return {"jpegs": jpegs, "prompts": prompts}
+
+
+def write_configs(rehearse: bool) -> tuple[Path, Path]:
+    """The YAML profiles ``tpuserve serve`` boots: full published widths, or
+    tiny ones under ``--rehearse``."""
+    import yaml
+
+    if rehearse:
+        arch = TINY_GPT2
+        seq, max_new = 16, GEN_TOKENS
+        resnet = {"name": "resnet50", "batch_buckets": [1, 8],
+                  "extra": {"image_size": 64, "resize_to": 72}}
+        sd_extra = {"variant": "tiny", "height": 64, "width": 64}
+    else:
+        arch, seq, max_new = None, 64, 32
+        resnet = {"name": "resnet50", "batch_buckets": [1, 8]}
+        sd_extra = {"height": 512, "width": 512,
+                    "params_dtype": "bfloat16"}
+
+    def gpt2(name, batch, params_dtype, **kw):
+        extra = {"max_new_tokens": max_new, "params_dtype": params_dtype,
+                 "gen_slots": 8, "segment_tokens": 8}
+        if arch:  # tiny kernels sit under the default quantization floor
+            extra.update(arch=arch, quantize_min_size=1024)
+        return {"name": name, "builder": "gpt2", "batch_buckets": [batch],
+                "seq_buckets": [seq], "extra": extra, **kw}
+
+    serve = {"warmup_at_boot": True, "models": [
+        resnet,
+        gpt2("gpt2", 1, "bfloat16"),
+        gpt2("gpt2_paged", 1, "bfloat16", kv_cache="paged"),
+        gpt2("gpt2_int8", 8, "int8"),
+    ]}
+    sd15 = {"warmup_at_boot": True, "models": [
+        {"name": "sd15", "batch_buckets": [1],
+         "extra": {"num_steps": 2, **sd_extra}}]}
+    paths = []
+    for name, cfg in (("serve.yaml", serve), ("sd15.yaml", sd15)):
+        path = OUT / name
+        path.write_text(yaml.safe_dump(cfg, sort_keys=False))
+        paths.append(path)
+    return paths[0], paths[1]
+
+
+# -- HTTP against a live server ------------------------------------------------
+
+def http(url: str, *, data: bytes | None = None, headers: dict | None = None,
+         timeout: float = 300.0):
+    req = urllib.request.Request(url, data=data, headers=headers or {},
+                                 method="POST" if data is not None else "GET")
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as resp:
+            return resp.status, resp.read()
+    except urllib.error.HTTPError as e:
+        return e.code, e.read()
+
+
+def http_json(url: str, payload=None, **kw):
+    data = None if payload is None else json.dumps(payload).encode()
+    headers = {"Content-Type": "application/json"} if data else {}
+    status, body = http(url, data=data, headers=headers, **kw)
+    try:
+        return status, json.loads(body)
+    except ValueError:
+        return status, {"raw": body[:300].decode(errors="replace")}
+
+
+class Server:
+    """One ``tpuserve serve`` child: started through the CLI, stopped with
+    SIGINT, its log kept under ``smoke_out/``."""
+
+    def __init__(self, config: Path, rehearse: bool, log_name: str):
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+        self.url = f"http://127.0.0.1:{port}"
+        self.log_path = OUT / log_name
+        cmd = [sys.executable, "-m", "pytorch_zappa_serverless_tpu.cli",
+               "serve", "--config", str(config), "--port", str(port)]
+        if rehearse:
+            cmd += ["--platform", "cpu"]
+        self._log = open(self.log_path, "wb")
+        self.t_start = time.monotonic()
+        self.proc = subprocess.Popen(cmd, cwd=str(ROOT), stdout=self._log,
+                                     stderr=subprocess.STDOUT,
+                                     env=child_env(rehearse))
+        _CHILDREN.append(self.proc)
+        self.boot_s = None
+
+    def wait_healthy(self, timeout: float) -> dict:
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            if self.proc.poll() is not None:
+                raise SmokeFailure(
+                    f"server exited {self.proc.returncode} while booting\n"
+                    + self.log_tail())
+            try:
+                status, body = http_json(self.url + "/healthz", timeout=10.0)
+            except (urllib.error.URLError, OSError):
+                time.sleep(0.5)
+                continue
+            if status == 200:
+                self.boot_s = time.monotonic() - self.t_start
+                return body
+            time.sleep(0.5)
+        raise SmokeFailure(f"server not healthy after {timeout:.0f}s\n"
+                           + self.log_tail())
+
+    def log_tail(self, n: int = 1500) -> str:
+        self._log.flush()
+        return self.log_path.read_text(errors="replace")[-n:]
+
+    def log_events(self, msg: str) -> list[dict]:
+        """The server's JSON log records with this ``msg``."""
+        self._log.flush()
+        out = []
+        for line in self.log_path.read_text(errors="replace").splitlines():
+            if line.startswith("{") and f'"msg": "{msg}"' in line:
+                out.append(json.loads(line))
+        return out
+
+    def stop(self) -> None:
+        """SIGINT, as an operator's ctrl-c; anything but a clean exit fails."""
+        self.proc.send_signal(signal.SIGINT)
+        try:
+            rc = self.proc.wait(timeout=90)
+        except subprocess.TimeoutExpired:
+            self.proc.kill()
+            self.proc.wait()
+            raise SmokeFailure("server still running 90s after SIGINT\n"
+                               + self.log_tail()) from None
+        finally:
+            self._log.close()
+        check(rc == 0, f"server exited {rc} on SIGINT\n"
+              + self.log_path.read_text(errors="replace")[-1500:])
+
+
+def predict_image(srv: Server, jpeg: bytes) -> dict:
+    status, body = http(srv.url + "/v1/models/resnet50:predict", data=jpeg,
+                        headers={"Content-Type": "image/jpeg"})
+    check(status == 200, f"resnet50:predict -> {status}: {body[:300]!r}")
+    return check_topk(json.loads(body)["predictions"])
+
+
+def check_topk(pred: dict) -> dict:
+    import math
+
+    top = pred["top_k"]
+    probs = [e["prob"] for e in top]
+    check(len(top) == 5 and all(math.isfinite(p) and 0.0 <= p <= 1.0
+                                for p in probs)
+          and probs == sorted(probs, reverse=True) and sum(probs) <= 1.001,
+          f"resnet50 top_k is not 5 sorted finite probabilities: {top}")
+    return pred
+
+
+def generate(srv: Server, model: str, ids: list[int]) -> list[int]:
+    """One greedy SSE stream; returns the tokens, checked against the
+    stream's own ``done`` event."""
+    req = urllib.request.Request(
+        f"{srv.url}/v1/models/{model}:generate",
+        data=json.dumps({"input_ids": ids,
+                         "max_new_tokens": GEN_TOKENS}).encode(),
+        headers={"Content-Type": "application/json"}, method="POST")
+    streamed, final = [], None
+    try:
+        with urllib.request.urlopen(req, timeout=600.0) as resp:
+            check(resp.headers.get_content_type() == "text/event-stream",
+                  f"{model}:generate answered "
+                  f"{resp.headers.get_content_type()}, not an SSE stream")
+            for raw in resp:
+                line = raw.decode().strip()
+                if not line.startswith("data: "):
+                    continue
+                ev = json.loads(line[len("data: "):])
+                if "token" in ev:
+                    streamed.append(int(ev["token"]))
+                elif ev.get("done"):
+                    final = ev
+                elif "error" in ev:
+                    raise SmokeFailure(f"{model}:generate stream error: {ev}")
+    except urllib.error.HTTPError as e:
+        raise SmokeFailure(f"{model}:generate -> {e.code}: "
+                           f"{e.read()[:300]!r}") from None
+    check(final is not None, f"{model}:generate stream ended without done")
+    check(streamed == final["tokens"],
+          f"{model}: streamed tokens differ from the done event's")
+    check(len(streamed) >= GEN_TOKENS,
+          f"{model}: {len(streamed)} tokens streamed, asked {GEN_TOKENS}")
+    return streamed
+
+
+def read_metrics(srv: Server) -> dict:
+    status, metrics = http_json(srv.url + "/metrics")
+    check(status == 200, f"/metrics -> {status}")
+    return metrics
+
+
+def check_health(health: dict, device: dict) -> None:
+    check(health.get("device_ok") is True, f"/healthz device_ok: {health}")
+    check(health.get("device") == device,
+          f"/healthz device block {health.get('device')} is not what the "
+          f"probe saw {device}")
+    for name, m in health["models"].items():
+        check(m["buckets_compiled"] == m["buckets_total"],
+              f"{name}: {m} — not every bucket was warmed at boot")
+
+
+def phase_serve(config: Path, inputs: dict, probe: dict,
+                rehearse: bool) -> dict:
+    srv = Server(config, rehearse, "serve-boot1.log")
+    health = srv.wait_healthy(900.0)
+    check_health(health, probe["device"])
+    boot = read_metrics(srv)["cold_start"]
+    say(f"serve: boot {srv.boot_s:.1f}s wall, engine cold start "
+        f"{boot['seconds']}s of which compile {boot['compile_seconds_total']}s"
+        f" ({len(boot['compile_entries'])} executables)")
+
+    # resnet50: JPEGs singly, then the same eight as one instances body.
+    jpegs = inputs["jpegs"]
+    singles = [predict_image(srv, j) for j in jpegs[:3]]
+    status, body = http_json(
+        srv.url + "/v1/models/resnet50:predict",
+        {"instances": [{"b64": base64.b64encode(j).decode()} for j in jpegs]})
+    check(status == 200 and len(body.get("predictions", [])) == 8,
+          f"resnet50 8-instance predict -> {status}: {str(body)[:300]}")
+    for pred in body["predictions"]:
+        check_topk(pred)
+    for one, many in zip(singles, body["predictions"]):
+        gap = max(abs(a["prob"] - b["prob"])
+                  for a, b in zip(one["top_k"], many["top_k"]))
+        check(gap <= 2e-2, f"resnet50: a JPEG alone and in the batch of 8 "
+              f"differ by {gap} in top-k probability")
+    say("serve: resnet50 3 single + 1x8-instance predicts ok "
+        f"(batch_size {body['timing']['batch_size']})")
+
+    # GPT-2 streams on both schedulers: greedy, so a prompt sent alone twice
+    # gives the same tokens.
+    tokens = {}
+    for model in ("gpt2", "gpt2_paged"):
+        first = generate(srv, model, inputs["prompts"][0])
+        again = generate(srv, model, inputs["prompts"][0])
+        check(first == again, f"{model}: the same prompt gave different "
+              f"greedy tokens twice:\n  {first}\n  {again}")
+        other = generate(srv, model, inputs["prompts"][1])
+        tokens[model] = [first, other]
+        say(f"serve: {model} 3 streams of {len(first)} tokens, "
+            f"repeat identical; first: {first}")
+    agree = tokens["gpt2"] == tokens["gpt2_paged"]
+    n_same = sum(a == b for s, p in zip(tokens["gpt2"], tokens["gpt2_paged"])
+                 for a, b in zip(s, p))
+    say(f"serve: slot and paged schedulers agree: {agree} "
+        f"({n_same}/{2 * GEN_TOKENS} token positions equal)")
+
+    status, body = http_json(srv.url + "/v1/models/gpt2_int8:predict",
+                             {"input_ids": inputs["prompts"][2]})
+    check(status == 200, f"gpt2_int8:predict -> {status}: {str(body)[:300]}")
+    int8_tokens = body["predictions"]["tokens"]
+    check(len(int8_tokens) >= GEN_TOKENS
+          and all(isinstance(t, int) and t >= 0 for t in int8_tokens),
+          f"gpt2_int8 tokens: {int8_tokens}")
+    say(f"serve: gpt2_int8 predict {len(int8_tokens)} tokens: "
+        f"{int8_tokens[:8]}...")
+
+    status, health = http_json(srv.url + "/healthz")
+    check(status == 200, f"/healthz -> {status} after the requests")
+    check_health(health, probe["device"])
+    metrics = read_metrics(srv)
+    errors = {m: s["errors"] for m, s in metrics["models"].items()
+              if s["errors"]}
+    check(not errors, f"/metrics reports request errors: {errors}")
+    after = metrics["cold_start"]
+    check(len(after["compile_entries"]) == len(boot["compile_entries"]),
+          "a bucket compiled after warm-up: "
+          f"{after['compile_entries'][len(boot['compile_entries']):]}")
+    say(f"serve: /healthz ok, /metrics no errors over "
+        f"{sum(s['requests'] for s in metrics['models'].values())} requests, "
+        "no bucket compiled after warm-up")
+    ready = srv.log_events("engine ready")
+    check(ready and ready[0].get("device") == probe["device"]
+          and ready[0].get("compile_cache_dir") == probe["cache_dir"],
+          f"boot log does not name the device and cache dir: {ready}")
+    srv.stop()
+    say("serve: SIGINT -> clean exit")
+    return {"boot_s": srv.boot_s, "compile_s": boot["compile_seconds_total"],
+            "tokens": tokens, "int8_tokens": int8_tokens,
+            "resnet": singles[0]}
+
+
+def phase_restart(config: Path, inputs: dict, probe: dict, first: dict,
+                  cache_was_empty: bool, rehearse: bool) -> None:
+    cache_dir = Path(probe["cache_dir"])
+    check(entries(cache_dir),
+          f"compile cache {cache_dir} is empty after the first boot")
+    check(cache_dir == IN_CHECKOUT_CACHE
+          or entries(IN_CHECKOUT_CACHE) == probe["in_checkout_entries"],
+          f"the cache was placed at {cache_dir}, yet {IN_CHECKOUT_CACHE} "
+          "grew: something set a directory of its own")
+    srv = Server(config, rehearse, "serve-boot2.log")
+    health = srv.wait_healthy(900.0)
+    check_health(health, probe["device"])
+    boot = read_metrics(srv)["cold_start"]
+    say(f"restart: boot {srv.boot_s:.1f}s wall (first {first['boot_s']:.1f}s),"
+        f" compile {boot['compile_seconds_total']}s "
+        f"(first {first['compile_s']}s), cache {cache_dir}")
+    if cache_was_empty:
+        check(boot["compile_seconds_total"] < first["compile_s"],
+              f"second boot compiled for {boot['compile_seconds_total']}s, "
+              f"the first for {first['compile_s']}s: the cache did not hit")
+    else:
+        say("restart: the cache held entries before the first boot, so the "
+            "first boot was not cold: compile seconds not compared")
+    pred = predict_image(srv, inputs["jpegs"][0])
+    check(pred["top_k"][0]["index"] == first["resnet"]["top_k"][0]["index"]
+          or abs(pred["top_k"][0]["prob"]
+                 - first["resnet"]["top_k"][0]["prob"]) <= 2e-2,
+          "resnet50 answers differently after the restart")
+    for model in ("gpt2", "gpt2_paged"):
+        check(generate(srv, model, inputs["prompts"][0])
+              == first["tokens"][model][0],
+              f"{model}: tokens differ after the restart")
+    status, body = http_json(srv.url + "/v1/models/gpt2_int8:predict",
+                             {"input_ids": inputs["prompts"][2]})
+    check(status == 200
+          and body["predictions"]["tokens"] == first["int8_tokens"],
+          f"gpt2_int8 differs after the restart: {status} {str(body)[:200]}")
+    srv.stop()
+    say("restart: one request per model ok, same answers, clean exit")
+
+
+def phase_sd15(config: Path, probe: dict, rehearse: bool) -> None:
+    srv = Server(config, rehearse, "serve-sd15.log")
+    health = srv.wait_healthy(1000.0)
+    check_health(health, probe["device"])
+    say(f"sd15: boot {srv.boot_s:.1f}s wall")
+    status, body = http_json(srv.url + "/v1/models/sd15:submit",
+                             {"prompt": "a photo of a tpu", "seed": SEED})
+    check(status == 202, f"sd15:submit -> {status}: {str(body)[:300]}")
+    job_id = body["job"]["id"]
+    deadline = time.monotonic() + 600.0
+    job = body["job"]
+    while time.monotonic() < deadline:
+        status, body = http_json(f"{srv.url}/v1/jobs/{job_id}")
+        check(status == 200, f"job poll -> {status}")
+        job = body["job"]
+        if job["status"] in ("done", "failed"):
+            break
+        time.sleep(1.0)
+    check(job["status"] == "done", f"sd15 job ended {job['status']}: "
+          f"{str(job)[:300]}")
+    from PIL import Image
+
+    img = Image.open(io.BytesIO(base64.b64decode(job["result"]["image_b64"])))
+    img.load()
+    side = 64 if rehearse else 512
+    check(img.format == "PNG" and img.size == (side, side),
+          f"sd15 image is {img.format} {img.size}, wanted PNG {side}x{side}")
+    lo, hi = img.convert("L").getextrema()
+    check(hi > lo, "sd15 image is one flat colour")
+    say(f"sd15: job done, PNG {img.size[0]}x{img.size[1]} decodes")
+    srv.stop()
+    say("sd15: SIGINT -> clean exit")
+
+
+# -- children that own the chip themselves ---------------------------------------
+
+def _kernels_child(rehearse: bool) -> None:
+    """The Pallas kernels on the serving path, compiled for the device
+    (``interpret=False``; the interpreter under ``--rehearse``), against
+    ``jax.numpy`` references at the tolerances of tests/test_int8_matmul.py
+    and tests/test_flash_attention.py (its bf16 case)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.ops import hostops
+    from pytorch_zappa_serverless_tpu.ops.flash_attention import (
+        flash_attention)
+    from pytorch_zappa_serverless_tpu.ops.int8_matmul import (
+        int8_matmul, quantize_per_channel)
+
+    interpret = rehearse
+    rng = np.random.default_rng(SEED)
+    mm = ([(8, 128, 256)] if rehearse else
+          [(8, 768, 2304), (8, 768, 50257), (8, 3072, 768), (128, 768, 3072)])
+    for m, k, n in mm:
+        x = jnp.asarray(rng.standard_normal((m, k)) * 0.5, jnp.bfloat16)
+        w_q, scale = quantize_per_channel(
+            (rng.standard_normal((k, n)) * 0.02).astype(np.float32), axis=0)
+        got = int8_matmul(x, jnp.asarray(w_q), jnp.asarray(scale),
+                          interpret=interpret)
+        w = (jnp.asarray(w_q, jnp.float32)
+             * jnp.asarray(scale)[None, :]).astype(jnp.bfloat16)
+        want = jnp.dot(x, w, preferred_element_type=jnp.float32)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=2e-2, atol=2e-2)
+        print(f"int8_matmul [{m},{k}]x[{k},{n}] matches its reference")
+
+    fa = ([(1, 256, 256, 2, 64, False), (1, 128, 128, 2, 64, True)]
+          if rehearse else
+          [(2, 4096, 4096, 8, 64, False), (8, 4096, 4096, 8, 64, False),
+           (2, 1024, 1024, 8, 80, False), (2, 256, 256, 8, 160, False),
+           (2, 4096, 77, 8, 64, False), (8, 128, 128, 12, 64, True)])
+
+    @jax.jit
+    def reference(q, k, v, causal_bias):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                       precision="highest") * q.shape[-1] ** -0.5
+        p = jax.nn.softmax(s + causal_bias, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
+
+    for b, tq, tk, h, d, causal in fa:
+        q, k, v = (jnp.asarray(rng.standard_normal((b, t, h, d)),
+                               jnp.bfloat16) for t in (tq, tk, tk))
+        got = flash_attention(q, k, v, causal=causal, interpret=interpret)
+        bias = (jnp.where(jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :],
+                          0.0, -1e9) if causal else jnp.zeros((tq, tk)))
+        want = reference(q, k, v, bias)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=3e-2, atol=3e-2)
+        print(f"flash_attention q[{b},{tq},{h},{d}] kv {tk} causal={causal} "
+              "matches its reference")
+    print("preprocess path: "
+          + ("native (hostops.cpp built with g++)" if hostops.native_available()
+             else "PIL (no native library: no compiler here)"))
+    print(json.dumps({"int8_matmul": len(mm), "flash_attention": len(fa)}))
+
+
+def _multichip_child(rehearse: bool) -> None:
+    """``mesh: {data: 2, model: 2}`` through ``build_engine`` against the
+    same models on one device: where the shards sit, that the step holds a
+    collective, and that both compute the same function."""
+    import jax
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
+    from pytorch_zappa_serverless_tpu.engine.loader import build_engine
+
+    if rehearse:
+        resnet = ModelConfig(name="resnet50", batch_buckets=(8,),
+                             extra={"image_size": 64, "resize_to": 72})
+        gpt2 = ModelConfig(
+            name="gpt2", batch_buckets=(4,), seq_buckets=(16,),
+            extra={"max_new_tokens": 8, "params_dtype": "bfloat16",
+                   "arch": TINY_GPT2})
+        vocab, size = 512, 64
+    else:
+        resnet = ModelConfig(name="resnet50", batch_buckets=(8,))
+        gpt2 = ModelConfig(name="gpt2", batch_buckets=(4,), seq_buckets=(64,),
+                           extra={"max_new_tokens": 32,
+                                  "params_dtype": "bfloat16"})
+        vocab, size = 50257, 224
+
+    def engine(mesh):
+        return build_engine(ServeConfig(
+            mesh=mesh, warmup_at_boot=True, models=[resnet, gpt2]))
+
+    sharded, single = engine({"data": 2, "model": 2}), engine({})
+    try:
+        # Where the parameters sit.
+        for name in ("resnet50", "gpt2"):
+            leaves = jax.tree.leaves(sharded.model(name).servable.params)
+            devices = set().union(*(leaf.sharding.device_set
+                                    for leaf in leaves))
+            assert len(devices) == 4, (name, devices)
+            alone = set().union(*(leaf.sharding.device_set for leaf in
+                                  jax.tree.leaves(
+                                      single.model(name).servable.params)))
+            assert len(alone) == 1, (name, alone)
+        q = sharded.model("gpt2").servable.params["layer0"]["q"]["kernel"]
+        shard_shapes = {s.data.shape for s in q.addressable_shards}
+        assert shard_shapes == {(q.shape[0], q.shape[1] // 2)}, shard_shapes
+        assert len({s.device for s in q.addressable_shards}) == 4
+        print(f"params on 4 distinct devices; gpt2 layer0/q/kernel "
+              f"{tuple(q.shape)} split over model into {shard_shapes}")
+
+        # The compiled GPT-2 step holds a collective.
+        cm = sharded.model("gpt2")
+        spec = cm.servable.input_spec(cm.buckets[0])
+        dummy = cm._place({k: np.zeros(s.shape, s.dtype)
+                           for k, s in spec.items()})
+        text = cm._jit.lower(cm.servable.params, dummy).compile().as_text()
+        found = sorted(op for op in ("all-reduce", "all-gather",
+                                     "reduce-scatter", "collective-permute",
+                                     "all-to-all") if op in text)
+        assert found, "no collective in the sharded GPT-2 step"
+        print(f"sharded gpt2 step holds collectives: {found}")
+
+        # Same function: resnet50 probabilities, GPT-2 prefill activations
+        # (every layer's K and V), then the generated tokens.
+        rng = np.random.default_rng(SEED)
+        images = [{"image": rng.integers(0, 256, (size, size, 3), np.uint8)}
+                  for _ in range(8)]
+        got = sharded.runner.run_sync(sharded.model("resnet50"), images)
+        want = single.runner.run_sync(single.model("resnet50"), images)
+        gap = max(abs(g["prob"] - w["prob"]) for a, b in zip(got, want)
+                  for g, w in zip(a["top_k"], b["top_k"]))
+        assert gap <= 2e-2, f"resnet50 top-k probabilities differ by {gap}"
+        print(f"resnet50 b8: sharded and unsharded top-k probabilities "
+              f"within {gap:.2e}")
+
+        prompts = [[int(t) for t in rng.integers(1, vocab - 2, n)]
+                   for n in (12, 9, 14, 5)]
+        payload = {
+            "input_ids": np.asarray([p + [0] * (16 - len(p))
+                                     for p in prompts], np.int32),
+            "length": np.asarray([len(p) for p in prompts], np.int32),
+            "temperature": np.zeros((4,), np.float32),
+            "seed": np.zeros((4,), np.int32),
+            "top_k": np.zeros((4,), np.int32),
+            "top_p": np.ones((4,), np.float32)}
+
+        def kv(eng):
+            cm = eng.model("gpt2")
+            prefill = jax.jit(cm.servable.meta["continuous"]["prefill"])
+            _, k, v = prefill(cm.servable.params, cm._place(payload))
+            return [np.asarray(a, np.float32)[:, i, :len(p)]
+                    for a in (k, v) for i, p in enumerate(prompts)]
+
+        for a, b in zip(kv(sharded), kv(single)):
+            np.testing.assert_allclose(a, b, rtol=3e-2,
+                                       atol=3e-2 * float(np.abs(b).max()))
+        print("gpt2 prefill: every layer's K and V agree within bf16 "
+              "tolerance")
+        samples = [{"input_ids": p} for p in prompts]
+        toks = [[r["tokens"] for r in eng.runner.run_sync(
+            eng.model("gpt2"),
+            [eng.model("gpt2").servable.preprocess(s) for s in samples])]
+            for eng in (sharded, single)]
+        same = sum(a == b for s, u in zip(*toks) for a, b in zip(s, u))
+        total = sum(len(u) for u in toks[1])
+        print(f"gpt2 greedy tokens: {same}/{total} positions equal between "
+              "sharded and unsharded (bf16 near-ties may diverge a row)")
+        assert all(s[0] == u[0] for s, u in zip(*toks)) or same >= total // 2, \
+            "sharded and unsharded GPT-2 disagree from the first token on"
+    finally:
+        sharded.shutdown()
+        single.shutdown()
+    print(json.dumps({"tokens_equal": same, "tokens_total": total}))
+
+
+# -- the run ----------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4: run the mesh phase (and only it) on four chips")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="run the same phases on the CPU at tiny widths")
+    args = ap.parse_args(argv)
+    if not PKG.is_dir():
+        print(f"[smoke] FAIL: {PKG.name}/ is not next to chip_smoke.py — "
+              "nothing to smoke", file=sys.stderr)
+        return 2
+    OUT.mkdir(exist_ok=True)
+    t0 = time.monotonic()
+    try:
+        probe = probe_device(args.rehearse, args.chips)
+        if args.chips == 4:
+            run_child(f"import chip_smoke; "
+                      f"chip_smoke._multichip_child({args.rehearse})",
+                      args.rehearse, "multichip.log", devices=4,
+                      timeout=1500.0)
+            say("multichip: sharded and unsharded engines agree")
+        else:
+            cache_was_empty = not entries(Path(probe["cache_dir"]))
+            probe["in_checkout_entries"] = entries(IN_CHECKOUT_CACHE)
+            inputs = make_inputs(args.rehearse)
+            serve_cfg, sd15_cfg = write_configs(args.rehearse)
+            first = phase_serve(serve_cfg, inputs, probe, args.rehearse)
+            phase_restart(serve_cfg, inputs, probe, first, cache_was_empty,
+                          args.rehearse)
+            run_child(f"import chip_smoke; "
+                      f"chip_smoke._kernels_child({args.rehearse})",
+                      args.rehearse, "kernels.log", timeout=600.0)
+            say("kernels: int8_matmul and flash_attention match their "
+                "references on the device")
+            phase_sd15(sd15_cfg, probe, args.rehearse)
+    except SmokeFailure as e:
+        print(f"[smoke] FAIL after {time.monotonic() - t0:.0f}s: {e}",
+              file=sys.stderr, flush=True)
+        return 1
+    finally:
+        for proc in _CHILDREN:
+            if proc.poll() is None:
+                proc.kill()
+                proc.wait()
+    say(f"all phases passed in {time.monotonic() - t0:.0f}s")
+    print(json.dumps({"ok": True, "device": probe["device"]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

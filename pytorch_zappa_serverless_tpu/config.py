@@ -244,8 +244,10 @@ class ServeConfig:
     host: str = "127.0.0.1"
     port: int = 8000
     # Persistent XLA compilation cache directory (cold-start accelerator;
-    # the TPU-native analogue of Lambda keep-warm, SURVEY §3.4).
-    compile_cache_dir: str = "~/.cache/tpuserve/xla"
+    # the TPU-native analogue of Lambda keep-warm, SURVEY §3.4).  "" → the
+    # fixed default inside the checkout; JAX_COMPILATION_CACHE_DIR, when
+    # set, wins over both (engine/cache.py resolve_compile_cache_dir).
+    compile_cache_dir: str = ""
     # Precompile all (model × bucket) executables at boot rather than lazily.
     warmup_at_boot: bool = True
     # Two-level priority dispatch (engine/runner.py): latency-class dispatches

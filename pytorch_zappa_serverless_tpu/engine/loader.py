@@ -14,6 +14,7 @@ from dataclasses import dataclass, field
 
 from .. import models as _zoo  # noqa: F401  (imports register the model builders)
 from ..config import ServeConfig
+from ..utils.device import device_info
 from ..utils.logging import get_logger, log_event
 from ..utils.registry import get_model_builder
 from .cache import CompileClock, setup_compile_cache
@@ -201,7 +202,7 @@ def build_engine(cfg: ServeConfig, *, warmup: bool | None = None) -> Engine:
                   process=jax.process_index(), processes=jax.process_count(),
                   global_devices=len(jax.devices()),
                   local_devices=len(jax.local_devices()))
-    setup_compile_cache(cfg.compile_cache_dir)
+    cache_dir = setup_compile_cache(cfg.compile_cache_dir)
     clock = CompileClock()
     runner = DeviceRunner()
     # QoS lane mode (docs/QOS.md): two-level priority unless the profile
@@ -237,7 +238,9 @@ def build_engine(cfg: ServeConfig, *, warmup: bool | None = None) -> Engine:
                   buckets=[list(b) for b in cm.buckets])
     cold = time.perf_counter() - t0
     log_event(log, "engine ready", cold_start_seconds=round(cold, 3),
-              compile_seconds=round(clock.total_seconds, 3), models=sorted(compiled))
+              compile_seconds=round(clock.total_seconds, 3),
+              models=sorted(compiled), device=device_info(),
+              compile_cache_dir=cache_dir)
     engine = Engine(models=compiled, runner=runner, clock=clock,
                     cold_start_seconds=cold, build_seconds=build_seconds,
                     mesh=mesh)

@@ -217,8 +217,7 @@ def make_bert_servable(name: str, cfg) -> Any:
                       if k.startswith("layer") else v)
                   for k, v in dict(params).items()}
     params = jax.device_put(params)  # ONE batched tree transfer: per-leaf jnp.asarray
-    # serializes a round-trip per buffer (measured 3.46 s vs 0.08 s for
-    # resnet50 over the relay; still one PCIe transaction per leaf on a VM).
+    # serializes a host round-trip per buffer.
 
     tokenizer = None
     tok_path = cfg.extra.get("tokenizer")

@@ -255,8 +255,7 @@ def make_sd15_servable(name: str, cfg_model, cfg: SD15Config | None = None):
     else:
         params = init_sd15_params(0, cfg)
     params = jax.device_put(params)  # ONE batched tree transfer: per-leaf jnp.asarray
-    # serializes a round-trip per buffer (measured 3.46 s vs 0.08 s for
-    # resnet50 over the relay; still one PCIe transaction per leaf on a VM).
+    # serializes a host round-trip per buffer.
     schedule = ddim_schedule(num_steps, cfg)
 
     def apply_fn(p, inputs):

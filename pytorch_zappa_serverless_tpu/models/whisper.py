@@ -579,8 +579,7 @@ def make_whisper_servable(name: str, cfg_model) -> Any:
         dec["lm_q"], dec["lm_scale"] = pad_weights(lm_q, lm_scale)
         params = cast_params_at_rest(params, jnp.bfloat16)
     params = jax.device_put(params)  # ONE batched tree transfer: per-leaf
-    # jnp.asarray serializes a round-trip per buffer (measured 3.46 s vs
-    # 0.08 s for resnet50 over the relay).
+    # jnp.asarray serializes a host round-trip per buffer.
 
     # sot, en, transcribe, notimestamps — the multilingual-vocab task prompt;
     # English-only and test vocabs fall back to a bare SOT.

@@ -237,7 +237,7 @@ def _attn_call(kern, n_vmem_inputs, x, cache_k, cache_v, operands,
     the kernel and the VMEM-operand count differ, so a fix to e.g. the
     scratch sizing or the wait idiom applies to both lanes."""
     vspec = pl.BlockSpec(memory_space=pltpu.VMEM)
-    aspec = pl.BlockSpec(memory_space=pltpu.ANY)
+    aspec = pl.BlockSpec(memory_space=pl.ANY)
     T, S, D = cache_k.shape
     return pl.pallas_call(
         kern,

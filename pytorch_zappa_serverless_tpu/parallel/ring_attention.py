@@ -42,16 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax moved shard_map from jax.experimental to the top level (and renamed
-# check_rep → check_vma) across the versions this repo meets; resolve once so
-# the wrapper below works on either.
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover — depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 _NEG_INF = -1e9
 
 
@@ -128,11 +118,11 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = "seq", kv_mask=None,
     local = functools.partial(ring_attention_local, axis_name=axis,
                               causal=causal, sm_scale=sm_scale)
     if kv_mask is None:
-        fn = _shard_map(lambda q, k, v: local(q, k, v), mesh=mesh,
-                        in_specs=(spec, spec, spec), out_specs=spec,
-                        **{_CHECK_KW: False})
+        fn = jax.shard_map(lambda q, k, v: local(q, k, v), mesh=mesh,
+                           in_specs=(spec, spec, spec), out_specs=spec,
+                           check_vma=False)
         return fn(q, k, v)
-    fn = _shard_map(local, mesh=mesh,
-                    in_specs=(spec, spec, spec, P(None, axis)),
-                    out_specs=spec, **{_CHECK_KW: False})
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec, P(None, axis)),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v, kv_mask)

@@ -273,8 +273,10 @@ class LifecycleManager:
         """Does the persistent compile cache plausibly cover this model set?
         (Any entries at all — the cache is keyed by HLO, so a populated dir
         means re-compiles are deserializes, not builds.)"""
+        from ..engine.cache import resolve_compile_cache_dir
+
         try:
-            d = Path(self.cfg.compile_cache_dir).expanduser()
+            d = Path(resolve_compile_cache_dir(self.cfg.compile_cache_dir))
             return d.is_dir() and any(d.iterdir())
         except OSError:
             return False

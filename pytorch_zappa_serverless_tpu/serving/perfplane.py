@@ -47,6 +47,7 @@ import sys
 import threading
 import time
 
+from ..utils.device import CHIP_PEAKS, device_info
 from .metrics import Histogram
 
 # The http→device gap decomposition (docs/OBSERVABILITY.md §9).  These are
@@ -75,19 +76,6 @@ LAG_BUCKETS_MS = (0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 # spans prefill (tens to hundreds of ms), itl is the per-tick cadence.
 TOKEN_LATENCY_BUCKETS_MS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
                             500.0, 1000.0, 2500.0, 5000.0)
-
-# Per-chip bf16 dense peak FLOP/s by jax device_kind (public spec sheets;
-# benchmark.py keeps the same table for the bench-time MFU columns).
-# Unknown kinds → no live MFU gauge rather than a guessed one.
-CHIP_PEAK_FLOPS = {
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v4": 275e12,
-    "TPU v5": 459e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-}
 
 
 def hist_quantile(snap: dict, q: float) -> float | None:
@@ -369,13 +357,10 @@ class PerfPlane:
 
     def _peak(self) -> float | None:
         if self.peak_flops is False:
-            try:
-                import jax
-
-                self.peak_flops = CHIP_PEAK_FLOPS.get(
-                    jax.devices()[0].device_kind)
-            except Exception:  # no backend (unit tests, tools)
-                self.peak_flops = None
+            # An unknown kind (the CPU under --platform cpu) has no peak:
+            # no live MFU gauge rather than a guessed one.
+            peaks = CHIP_PEAKS.get(device_info()["kind"])
+            self.peak_flops = peaks[0] if peaks else None
         return self.peak_flops
 
     # -- ingest attribution ---------------------------------------------------

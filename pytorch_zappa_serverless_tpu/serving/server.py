@@ -43,6 +43,7 @@ import numpy as np
 from aiohttp import web
 
 from ..config import ServeConfig
+from ..utils.device import device_info
 from ..utils.logging import current_trace_id, get_logger, log_event
 from ..engine.loader import Engine, build_engine
 from .adapters import AdapterCold, AdapterManager, UnknownAdapter
@@ -1604,6 +1605,9 @@ class Server:
         quarantined = sorted(self.resilience.quarantined)
         body = {
             "device_ok": alive,
+            # What JAX is serving from: a host where libtpu failed to
+            # initialise must not look like a healthy chip.
+            "device": device_info(),
             "generation_ok": not gen_fatal,
             # Draining flips health so the load balancer stops routing here
             # while in-flight work finishes (SIGTERM lifecycle, SURVEY §5).
@@ -1810,14 +1814,9 @@ class Server:
                     "overlap_ms": round(sum(overlap.values()) / 1e6, 3),
                     "envelope_ms": round(sum(envelope.values()) / 1e6, 3)}
 
-        try:
-            breakdown = await loop.run_in_executor(None, classify)
-        except Exception as e:
-            # An empty/foreign capture (CPU backend variants) still reports
-            # the capture location instead of 500ing the escalation path.
-            breakdown = {"ops": [], "device_compute_ms": None,
-                         "note": f"classification failed: "
-                                 f"{type(e).__name__}: {e}"}
+        # A capture with no device plane (the CPU backend) classifies to
+        # zero ops; the answer still carries the capture location.
+        breakdown = await loop.run_in_executor(None, classify)
         log_event(log, "profile captured", dir=str(out_dir), seconds=seconds,
                   ops=len(breakdown.get("ops", [])))
         return web.json_response({"dir": str(out_dir), "seconds": seconds,
@@ -2532,9 +2531,9 @@ class Server:
                 out["text"] = sched.detokenize(tokens)
             if gen.rounds_to_first_token is not None:
                 # Device round-trips before the first token (admission
-                # prefills + decode segments): lets a client separate queue/
-                # relay effects from device time in its TTFT (benchmark.py
-                # generate_path derives ttft_est_tpu_vm_ms from this).
+                # prefills + decode segments): lets a client separate queue
+                # effects from device time in its TTFT (benchmark.py
+                # generate_path reports the medians).
                 out["stats"] = {
                     "rounds_to_first_token": gen.rounds_to_first_token,
                     "segments_to_first_token": gen.segments_to_first_token,

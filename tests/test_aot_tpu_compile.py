@@ -1,0 +1,118 @@
+"""Chip-less TPU compiles of the Pallas kernels at real widths.
+
+Interpret mode on the CPU cannot see what Mosaic refuses: a slice not
+aligned to the tiling, more fast memory than a kernel may use, a shape the
+kernel cannot partition.  The TPU compiler is installed here and compiles
+for a chip that is *described* and not attached (the ``on-chip-measurement``
+guide, section 2), so each case below lowers one kernel with
+``interpret=False`` for one device of a ``v5e:2x2`` topology and asserts a
+``tpu_custom_call`` came out.  A compile that passes is not a chip run:
+``chip_smoke.py`` is where results are checked on the device.
+
+The file name sorts early on purpose, so a suite cut by its clock still
+reaches it.  The persistent compile cache is switched off around the cases:
+a TPU executable written here cannot be read back without a chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from pytorch_zappa_serverless_tpu.ops.flash_attention import flash_attention
+from pytorch_zappa_serverless_tpu.ops.fused_decode import (
+    fused_attn_step, fused_mlp_step)
+from pytorch_zappa_serverless_tpu.ops.int8_matmul import int8_matmul
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip, with the compile cache off."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no compiler in this environment
+        pytest.skip(f"v5e:2x2 topology cannot be described here: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` over ``(shape, dtype)`` arguments placed on the described
+    chip; returns the compiled program's text."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# GPT-2 small: decode qkv, the 50257-vocab lm head, fc2 (K=3072), and a
+# prefill-sized M on fc1.
+@pytest.mark.parametrize("m,k,n", [
+    (8, 768, 2304), (8, 768, 50257), (8, 3072, 768), (128, 768, 3072)],
+    ids=lambda v: str(v))
+def test_int8_matmul_compiles_for_v5e(one_chip, m, k, n):
+    text = _compile(
+        lambda x, w, s: int8_matmul(x, w, s, interpret=False), one_chip,
+        ((m, k), jnp.bfloat16), ((k, n), jnp.int8), ((n,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+# SD-1.5 UNet self-attention at 512x512 (4096 tokens, CFG batch 2 and the b4
+# job batch 8), its deeper levels (1024 x d80, 256 x d160), cross-attention
+# over the 77-token prompt, and a causal GPT-2-shaped block.
+@pytest.mark.parametrize("b,tq,tk,h,d,causal", [
+    (2, 4096, 4096, 8, 64, False),
+    (8, 4096, 4096, 8, 64, False),
+    (2, 1024, 1024, 8, 80, False),
+    (2, 256, 256, 8, 160, False),
+    (2, 4096, 77, 8, 64, False),
+    (8, 128, 128, 12, 64, True),
+], ids=lambda v: str(v))
+def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk, h, d, causal):
+    text = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        interpret=False), one_chip,
+        ((b, tq, h, d), jnp.bfloat16), ((b, tk, h, d), jnp.bfloat16),
+        ((b, tk, h, d), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+# The two bf16 fused-decode entry points at GPT-2 small's step shape
+# (S=8 slots, D=768, T=128 cache rows, F=3072).
+S, D, T, F, HEADS = 8, 768, 128, 3072, 12
+
+
+def test_fused_attn_step_compiles_for_v5e(one_chip):
+    text = _compile(
+        lambda x, lns, lnb, wqkv, bqkv, wout, bout, ck, cv, pos, mask:
+            fused_attn_step(x, lns, lnb, wqkv, bqkv, wout, bout, ck, cv, pos,
+                            mask, heads=HEADS, interpret=False),
+        one_chip,
+        ((S, D), jnp.bfloat16), ((D,), jnp.float32), ((D,), jnp.float32),
+        ((D, 3 * D), jnp.bfloat16), ((3 * D,), jnp.float32),
+        ((D, D), jnp.bfloat16), ((D,), jnp.float32),
+        ((T, S, D), jnp.bfloat16), ((T, S, D), jnp.bfloat16),
+        ((S,), jnp.int32), ((T, S, 1), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_mlp_step_compiles_for_v5e(one_chip):
+    text = _compile(
+        lambda x, lns, lnb, w1, b1, w2, b2:
+            fused_mlp_step(x, lns, lnb, w1, b1, w2, b2, interpret=False),
+        one_chip,
+        ((S, D), jnp.bfloat16), ((D,), jnp.float32), ((D,), jnp.float32),
+        ((D, F), jnp.bfloat16), ((F,), jnp.float32),
+        ((F, D), jnp.bfloat16), ((D,), jnp.float32))
+    assert "tpu_custom_call" in text

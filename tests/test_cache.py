@@ -11,8 +11,10 @@ import jax
 import pytest
 
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
+from pytorch_zappa_serverless_tpu.engine import cache as cache_mod
 from pytorch_zappa_serverless_tpu.engine.cache import (
-    CompileClock, setup_compile_cache)
+    DEFAULT_CACHE_DIR, CompileClock, resolve_compile_cache_dir,
+    setup_compile_cache)
 from pytorch_zappa_serverless_tpu.engine.loader import build_engine
 
 
@@ -36,6 +38,65 @@ def test_setup_compile_cache_reconfigures_to_new_dir(tmp_path):
     assert setup_compile_cache(b) == str(b)
     assert jax.config.jax_compilation_cache_dir == str(b)
     assert b.is_dir()
+
+
+# -- the resolver: one place decides where the cache lives --------------------
+
+REPO = cache_mod.Path(__file__).resolve().parents[1]
+
+
+def test_resolver_env_wins_and_no_directory_is_set_in_code(tmp_path,
+                                                           monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the cache lives there (jax reads the
+    variable itself) and the program makes no jax_compilation_cache_dir
+    update of its own — explicit config or not."""
+    placed = tmp_path / "placed-from-outside"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    monkeypatch.setattr(cache_mod, "_configured", None)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, value: (updates.append(name), real_update(name, value)))
+    assert resolve_compile_cache_dir(tmp_path / "from-config") == str(placed)
+    assert setup_compile_cache(tmp_path / "from-config") == str(placed)
+    assert placed.is_dir() and not (tmp_path / "from-config").exists()
+    assert "jax_compilation_cache_dir" not in updates
+    # The size/time floors are still lifted: every executable is cached.
+    assert "jax_persistent_cache_min_compile_time_secs" in updates
+
+
+def test_resolver_explicit_config_when_env_unset(tmp_path):
+    assert resolve_compile_cache_dir(tmp_path / "cfg") == str(tmp_path / "cfg")
+    assert resolve_compile_cache_dir("~/x").startswith(
+        str(cache_mod.Path.home()))
+
+
+@pytest.mark.parametrize("unset", [None, ""])
+def test_resolver_default_lands_inside_the_checkout(unset):
+    """No variable, no config: one fixed directory inside the checkout —
+    what ServeConfig's default ("") resolves to."""
+    got = cache_mod.Path(resolve_compile_cache_dir(unset))
+    assert got == DEFAULT_CACHE_DIR == REPO / ".cache" / "xla"
+    assert ServeConfig().compile_cache_dir == ""
+    # .gitignore lists it: the cache is built at run time, never committed.
+    assert ".cache/" in (REPO / ".gitignore").read_text().split()
+
+
+def test_resolver_same_path_on_two_calls(tmp_path, monkeypatch):
+    """The path is part of jax's cache key: nothing in it may come from a
+    pid, a clock or a tempdir, so two resolutions agree — in this process
+    and in a fresh one."""
+    import subprocess
+    import sys
+
+    assert resolve_compile_cache_dir() == resolve_compile_cache_dir()
+    code = ("from pytorch_zappa_serverless_tpu.engine.cache import "
+            "resolve_compile_cache_dir as r; print(r())")
+    fresh = subprocess.run([sys.executable, "-c", code], cwd=str(REPO),
+                           capture_output=True, text=True, timeout=120,
+                           check=True).stdout.strip()
+    assert fresh == resolve_compile_cache_dir()
 
 
 def test_compile_clock_per_model_totals():

@@ -2,6 +2,8 @@
 
 import json
 
+import pytest
+
 from pytorch_zappa_serverless_tpu.cli import main
 from pytorch_zappa_serverless_tpu.config import ServeConfig
 from pytorch_zappa_serverless_tpu.deploy.render import render_deploy
@@ -34,10 +36,24 @@ def test_warm_cli(tmp_path, capsys, monkeypatch):
         "models:\n"
         "  - {name: resnet18, batch_buckets: [1], dtype: float32,\n"
         "     extra: {image_size: 64}}\n" % tmp_path)
-    assert main(["warm", "--config", str(cfg)]) == 0
+    assert main(["warm", "--config", str(cfg), "--platform", "cpu"]) == 0
     # Engine JSON log lines share stdout; the summary is the last line.
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["executables"] == 1 and out["cold_start_seconds"] > 0
+
+
+@pytest.mark.parametrize("cmd", ["serve", "warm"])
+def test_serving_commands_refuse_a_non_tpu_backend(cmd, tmp_path):
+    """Without ``--platform cpu`` a host where JAX found no TPU must not
+    serve: exit non-zero at start, naming the flag — before any model is
+    built (the config names one that does not exist)."""
+    cfg = tmp_path / "cfg.yaml"
+    cfg.write_text("models:\n  - {name: no_such_model}\n")
+    with pytest.raises(SystemExit) as e:
+        main([cmd, "--config", str(cfg)])
+    assert e.value.code not in (0, None)
+    assert "--platform cpu" in str(e.value.code)
+    assert "'cpu' backend" in str(e.value.code)
 
 
 def test_render_deploy_emits_mounted_config(tmp_path):

@@ -6,8 +6,8 @@ Four layers:
   detection), the stack sampler's top-K bounding/eviction with injected
   frames, the ingest histogram registry, and the rolling gauge windows.
 - Sentinel: tools/benchdiff.py verdicts (pass / regress / improved /
-  missing) over tiny fixture JSONs, the --check self-test, and the repo's
-  real BENCH_r04→r05 pair against the checked-in tools/perf_budget.json.
+  missing) over tiny fixture JSONs, the --check self-test, and a
+  driver-round pair against the checked-in tools/perf_budget.json.
 - Integration: a real booted CPU server — GET /admin/perf carries loop
   lag, ingest stages for a served request, and the split ttft/itl
   histograms ride gen_snapshot + /metrics; the `tpuserve perf` table
@@ -259,15 +259,80 @@ def test_benchdiff_check_mode_self_tests(capsys):
     assert bd.self_check(lax)
 
 
-def test_benchdiff_passes_real_r04_r05_rounds():
-    """Acceptance criterion: the checked-in budget tolerates the observed
-    cross-round harness spread — r04→r05 is a healthy pair."""
+def _driver_round(p50_ms: float, rps: float) -> dict:
+    """A driver round envelope (``{"parsed": <compact bench line>}``)."""
+    return {"rc": 0, "parsed": {
+        "metric": "resnet50_b8_p50_latency", "value": p50_ms, "unit": "ms",
+        "vs_baseline": round(30.0 / p50_ms, 3),
+        "extra": {"req_s_chip": round(8000.0 / p50_ms, 1),
+                  "device_trace_ms": 0.773,
+                  "server_path": {"achieved_rps": rps,
+                                  "http_device_p50_ms": 120.0}}}}
+
+
+@pytest.mark.parametrize("new_p50,new_rps,flagged", [
+    (1.88, 52.3, []),                      # +59% / -10%: inside the budget
+    (2.60, 30.0, ["extra.req_s_chip", "extra.server_path.achieved_rps",
+                  "value", "vs_baseline"]),
+], ids=["inside-budget-passes", "outside-budget-flagged"])
+def test_benchdiff_round_pair_against_checked_in_budget(
+        tmp_path, new_p50, new_rps, flagged):
+    """The checked-in budget over two driver-round files: a pair inside it
+    passes, a pair outside it names the keys that broke it."""
     bd = _benchdiff()
-    budget = bd.load_budget()
-    rows = bd.diff(bd.load_round(REPO / "BENCH_r04.json"),
-                   bd.load_round(REPO / "BENCH_r05.json"), budget)
-    assert rows, "no comparable keys between real rounds"
-    assert bd.violations(rows) == [], bd.render(rows)
+    old, new = tmp_path / "old.json", tmp_path / "new.json"
+    old.write_text(json.dumps(_driver_round(1.18, 58.2)))
+    new.write_text(json.dumps(_driver_round(new_p50, new_rps)))
+    rows = bd.diff(bd.load_round(old), bd.load_round(new), bd.load_budget())
+    assert rows, "no comparable keys between the rounds"
+    assert sorted(r["key"] for r in bd.violations(rows)) == flagged, \
+        bd.render(rows)
+
+
+# -- bench: no fallback that hides the device ---------------------------------
+
+def _full(section_entry: dict) -> dict:
+    return {"metric": "resnet50_b8_p50_latency", "value": 1.2, "unit": "ms",
+            "vs_baseline": 25.0,
+            "extra": {"req_s_chip": 6666.7, "configs": {"gpt2": section_entry},
+                      "cold_start": None, "server_path": {"achieved_rps": 50.0},
+                      "generate_path": None, "mixed_path": None}}
+
+
+@pytest.mark.parametrize("entry,rc", [
+    ({"p50_ms": 11.0, "tokens_per_s": 15000.0}, 0),
+    ({"error": "RuntimeError: boom"}, 1),
+], ids=["healthy-exits-0", "failed-section-exits-1"])
+def test_bench_main_exit_code_follows_section_errors(monkeypatch, tmp_path,
+                                                     capsys, entry, rc):
+    """A section that errors still gets its line printed — and then the run
+    exits non-zero instead of passing for a result."""
+    import pytorch_zappa_serverless_tpu.benchmark as B
+
+    monkeypatch.setattr(B, "run_flagship_bench", lambda emit=None: _full(entry))
+    monkeypatch.setenv("BENCH_FULL_PATH", str(tmp_path / "full.json"))
+    assert B.main() == rc
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["value"] == 1.2 and "gpt2" in line["extra"]["configs"]
+    assert B.section_errors(_full(entry)) == (["gpt2"] if rc else [])
+
+
+def test_bench_refuses_a_non_tpu_backend():
+    """bench.py off-TPU ends at its device probe (a child: the parent stays
+    off JAX), before any section runs."""
+    import pytorch_zappa_serverless_tpu.benchmark as B
+
+    with pytest.raises(SystemExit) as e:
+        B._probe_device()
+    assert "needs a TPU" in str(e.value.code)
+
+
+def test_peaks_table_is_keyed_by_reported_kind_and_unknown_is_an_error():
+    from pytorch_zappa_serverless_tpu.utils.device import chip_peaks
+
+    assert chip_peaks("TPU v5 lite") == (197e12, 819e9)   # what a v5e reports
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("TPU v9 imaginary")
 
 
 # -- integration: a real booted server ---------------------------------------
@@ -452,7 +517,7 @@ def test_bench_serverpath_tiny_smoke(monkeypatch, tmp_path):
     from pytorch_zappa_serverless_tpu.benchmark import bench_serverpath
 
     monkeypatch.setenv("BENCH_SERVERPATH_TINY", "1")
-    monkeypatch.setenv("TPUSERVE_CACHE", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     out = bench_serverpath()
     assert out["tiny"] is True
     assert out["n_traces"] >= 1

@@ -59,6 +59,17 @@ async def test_root_and_health(client):
     assert body["models"]["resnet18"]["buckets_compiled"] == 2
 
 
+async def test_healthz_names_the_device(client):
+    """A host serving from the CPU must say so: the device block carries
+    what JAX reports, next to (not instead of) ``device_ok``."""
+    import jax
+
+    body = await (await client.get("/healthz")).json()
+    assert body["device"] == {"platform": "cpu",
+                              "kind": jax.devices()[0].device_kind,
+                              "count": len(jax.devices())}
+
+
 async def test_predict_image_bytes(client):
     r = await client.post("/v1/models/resnet18:predict", data=_jpeg(),
                           headers={"Content-Type": "image/jpeg"})

@@ -1,27 +1,26 @@
 #!/usr/bin/env python
 """Bench regression sentinel: compare two bench rounds against a budget.
 
-Six BENCH_r0x rounds sat on disk with no automated comparison — a perf
+Bench rounds used to sit on disk with no automated comparison — a perf
 regression shipped silently unless a human eyeballed two JSON blobs.  This
 tool makes the comparison a checked contract (docs/OBSERVABILITY.md §9):
 
-    python -m tools.benchdiff BENCH_r04.json BENCH_r05.json
+    python -m tools.benchdiff old_FULL.json new_FULL.json
     python -m tools.benchdiff old_FULL.json new_FULL.json --budget my.json
     python -m tools.benchdiff --check          # fixture self-test (CI)
 
 Inputs are any two of: a driver round (``{"parsed": {...}}``), a compact
-bench line (``{"metric", "value", "extra": ...}``), a ``BENCH_FULL.json``
-artifact, or any plain section dict — every numeric leaf is flattened to a
+bench line (``{"metric", "value", "extra": ...}``), a full bench artifact
+(``BENCH_FULL_PATH``), or any plain section dict — every numeric leaf is flattened to a
 dotted key (``extra.server_path.achieved_rps``) and compared key by key.
 
 The budget (``tools/perf_budget.json``, checked in) declares per-key
 regression thresholds and directions; keys not listed fall back to the
 defaults, with direction inferred from the name (``*_ms``/``*p99*`` lower
 is better; ``*_rps``/``*tokens_per_s``/``*mfu*`` higher is better).  The
-default thresholds are sized to the cross-round spread actually observed
-on the shared dev harness over r01–r05 (see the budget's note) — tight
-enough to catch a real 2x regression, loose enough that harness noise
-between healthy rounds passes.
+default thresholds date from the pre-round records (deleted in PR 21) and
+have not been re-sized from repeat runs on the chip as installed today
+(see the budget's note): wide enough that only a 2x regression fails.
 
 Verdicts per key: ``pass`` / ``regress`` / ``improved`` / ``missing``
 (key vanished from the new round) / ``new`` (key only in the new round).

@@ -193,7 +193,7 @@ def main():
 
     from pytorch_zappa_serverless_tpu.engine.cache import setup_compile_cache
 
-    setup_compile_cache("~/.cache/tpuserve/xla")
+    setup_compile_cache()
     builder = BUILDERS[args.target]
     if args.batch is not None:
         if not inspect.signature(builder).parameters:
