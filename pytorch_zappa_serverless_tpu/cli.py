@@ -205,11 +205,13 @@ def cmd_bench(args) -> int:
 
 
 def cmd_profile(args) -> int:
-    """Trigger a trace capture on a running server (POST /debug/trace)."""
+    """Capture a profile of live traffic on a running server (POST
+    /admin/profile): where the capture lies, the device's busiest
+    operations, its programs, and its idle time by host phase."""
     import urllib.request
 
     req = urllib.request.Request(
-        args.url.rstrip("/") + "/debug/trace",
+        args.url.rstrip("/") + "/admin/profile",
         data=json.dumps({"seconds": args.seconds}).encode(),
         headers={"Content-Type": "application/json"}, method="POST")
     with urllib.request.urlopen(req, timeout=args.seconds + 30) as resp:

@@ -270,7 +270,7 @@ class ServeConfig:
     # jax.profiler trace server port (SURVEY §5 tracing): connect
     # TensorBoard/XProf to this port for live profiling.  0 → disabled.
     profiler_port: int = 0
-    # Where POST /debug/trace captures land (perfetto/xplane format).
+    # Where POST /admin/profile captures land (perfetto/xplane format).
     trace_dir: str = "~/.cache/tpuserve/traces"
     # Supervisor (SURVEY §5 failure detection): probe the device every
     # interval; after fail_threshold consecutive failures rebuild the engine

@@ -398,7 +398,7 @@ class CompiledModel:
         spec = self.servable.input_spec(bucket)
         collate = self.servable.meta.get("collate") or default_collate
         # TraceAnnotations decompose the serving step into host phases for
-        # /debug/trace captures (collate → h2d → device+d2h → postprocess).
+        # /admin/profile captures (collate → h2d → device+d2h → postprocess).
         with jax.profiler.TraceAnnotation("collate"):
             batch = collate(samples, bucket, spec)
         if self.lockstep is not None:

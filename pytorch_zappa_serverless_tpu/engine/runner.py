@@ -243,7 +243,7 @@ class DeviceRunner:
         try:
             self.faults.on_dispatch(model.servable.name)
             t0 = time.perf_counter()
-            # Span shows the batcher→dispatch handoff in /debug/trace captures.
+            # Span shows the batcher→dispatch handoff in /admin/profile captures.
             with jax.profiler.TraceAnnotation(
                     f"dispatch:{model.servable.name}:b{len(samples)}"):
                 results, bucket = model.run_batch(samples, seq=seq)
@@ -280,7 +280,7 @@ class DeviceRunner:
             self._lane_of(model), self._run, model, samples, seq, span))
 
     async def run_fn(self, fn, *args, lane: str = LANE_LATENCY,
-                     model: str | None = None) -> Any:
+                     model: str | None = None, trip=None) -> Any:
         """Run an arbitrary device callable on the dispatch thread.
 
         The generation scheduler's prefill/segment kernels go through here so
@@ -293,6 +293,12 @@ class DeviceRunner:
         leans on this to land its kill mid-stream).  Failure rules stay on
         the batch/chunk paths — a mid-stream generation has no retry
         story, so chaos failures target ``_run``/``run_chunked``.
+
+        ``trip`` (a ``RoundTimeline.trip(kind)``, serving/tracing.py) is told
+        when the dispatch thread picks the call up, when the callable has
+        returned there, and when this coroutine resumes: the two hand-overs
+        of a scheduler round, ``round.lane_wait`` and ``round.wakeup``, per
+        program kind and on the profiler's timeline.
         """
         if self.faults.poison_exc is not None:
             raise self.faults.poison_exc
@@ -307,8 +313,22 @@ class DeviceRunner:
             def fn(*a, _run=run, _delay=delay_s):  # noqa: F811
                 time.sleep(_delay)
                 return _run(*a)
-        return await asyncio.wrap_future(
-            self._pool.submit_lane(lane, fn, *args))
+        if trip is None:
+            return await asyncio.wrap_future(
+                self._pool.submit_lane(lane, fn, *args))
+        call = fn
+
+        def fn(*a):  # noqa: F811
+            trip.picked_up()
+            try:
+                return call(*a)
+            finally:
+                trip.returned()
+        try:
+            return await asyncio.wrap_future(
+                self._pool.submit_lane(lane, fn, *args))
+        finally:
+            trip.resumed()
 
     async def run_chunked(self, model: CompiledModel, samples: Sequence[dict],
                           seq: int | None = None, span=None) -> list[Any]:

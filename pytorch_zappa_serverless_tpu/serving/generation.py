@@ -48,6 +48,7 @@ from .perfplane import TOKEN_LATENCY_BUCKETS_MS
 from .kvmigrate import (MigrationError, MigrationNeedsPages, MigrationStats,
                         PageIntegrityError, pack_page, unpack_page)
 from .prefixcache import PrefixCache
+from .tracing import RoundTimeline
 
 log = get_logger("serving.generation")
 
@@ -230,6 +231,17 @@ class GenRequest:
     max_new: int
     submitted: float = field(default_factory=time.perf_counter)
     admitted: float | None = None
+    # Where the time to the first token went (``timing_stats``), each stamp
+    # written once: by the scheduler task the first loop top that found the
+    # request pending (``seen_at``) and the loop top that popped a slot for
+    # it (``slotted_at``, in round ``slotted_round``); by the server the
+    # root span's start (``t_ingest0``) and the return of the first token
+    # event's socket write (``first_write_at``).
+    seen_at: float | None = None
+    slotted_at: float | None = None     # guarded-by: event-loop
+    slotted_round: int | None = None    # guarded-by: event-loop
+    t_ingest0: float | None = None
+    first_write_at: float | None = None
     # Device-round accounting (VERDICT r3 weak #5): how many device
     # dispatch+fetch round-trips elapsed between submit and the first token.
     rounds_at_submit: int = 0
@@ -273,6 +285,36 @@ class GenRequest:
     migrations: int = 0
     migrated: bool = False
 
+    def timing_stats(self) -> dict[str, float]:
+        """The request's time to its first token, tiled by its stamps (ms):
+        ingest, waiting for the running round to end, rounds with no free
+        slot, prefill, the segment that streams the first token, egress.  A
+        leg whose stamps are missing (a request that never ran its course,
+        ``stream: false``) is left out."""
+        legs = (("ingest_ms", self.t_ingest0, self.submitted),
+                ("round_wait_ms", self.submitted, self.seen_at),
+                ("slot_wait_ms", self.seen_at, self.slotted_at),
+                ("prefill_ms", self.slotted_at, self.admitted),
+                ("first_emit_ms", self.admitted, self.first_token_at),
+                ("egress_ms", self.first_token_at, self.first_write_at))
+        return {name: round((t1 - t0) * 1000.0, 3) for name, t0, t1 in legs
+                if t0 is not None and t1 is not None}
+
+    def note_slotted(self, t_top: float, round_no: int) -> None:
+        if self.slotted_at is None:
+            self.slotted_at, self.slotted_round = t_top, round_no
+
+    def trace_admission(self, **attrs) -> None:
+        """The repaired waterfall: ``queue`` is submit to the slot, and
+        ``prefill`` the slot to admission, in the round that gave the slot."""
+        if self.span is None or self.slotted_at is None:
+            return
+        self.span.child("queue", start=self.submitted).end(
+            end=self.slotted_at, slot=self.slot)
+        self.span.child("prefill", start=self.slotted_at,
+                        round=self.slotted_round, **attrs).end(
+            end=self.admitted)
+
     def finish(self, error: str | None = None):
         if not self.done.done():
             if error is None:
@@ -284,6 +326,15 @@ class GenRequest:
                 # "exception was never retrieved" log; awaiting still raises.
                 self.done.exception()
         self.events.put_nowait(None)
+
+
+def _note_seen(pending, t_top: float) -> None:
+    """Stamp ``seen_at`` on the requests that arrived since the last loop top
+    (they are at the right end of the queue)."""
+    for req in reversed(pending):
+        if req.seen_at is not None:
+            break
+        req.seen_at = t_top
 
 
 def _note_token_latency(req: GenRequest, ttft_hist: Histogram,
@@ -381,6 +432,8 @@ class GenerationScheduler:
         self.tokens_emitted = 0  # guarded-by: event-loop
         self.ttft_hist = Histogram(TOKEN_LATENCY_BUCKETS_MS)
         self.itl_hist = Histogram(TOKEN_LATENCY_BUCKETS_MS)
+        # Host phases of every round, on both threads (serving/tracing.py).
+        self.timeline = RoundTimeline(self.name)
 
     # -- device kernels (all called on the runner's dispatch thread) --------
     def _ensure_cache(self):
@@ -398,23 +451,28 @@ class GenerationScheduler:
 
     def _admit_sync(self, req: GenRequest, slot: int):
         """Prefill one request and splice it into the pool (dispatch thread)."""
-        bucket = self._bucket_for(self._admit_len_of(req.sample))
-        payload = self._collate_admit(req.sample, bucket)
-        if self.lockstep is not None:
-            self.lockstep.lead_gen_admit(self.name, slot, bucket, payload)
-        # AFTER the lead broadcasts: on a global mesh the pool allocation's
-        # device_put itself runs a collective (sharding assert_equal), so it
-        # must sit at the same protocol point on both sides — the follower
-        # allocates inside its admit handler, post-payload (deadlocked
-        # before this ordering: leader in the alloc allgather, follower in
-        # the header broadcast).
-        self._ensure_cache()
-        first, k_row, v_row = self._prefill(self.params, payload)
-        self.prefill_dispatches += 1
-        self._cache_k, self._cache_v = self._insert(
-            self._cache_k, self._cache_v, k_row, v_row, np.int32(slot))
-        self._set_slot(slot, int(first[0]), payload, 0)
-        self.device_rounds += 1
+        tl = self.timeline
+        with tl.phase("prefill.launch", programs=1, batch=1):
+            bucket = self._bucket_for(self._admit_len_of(req.sample))
+            payload = self._collate_admit(req.sample, bucket)
+            if self.lockstep is not None:
+                self.lockstep.lead_gen_admit(self.name, slot, bucket, payload)
+            # AFTER the lead broadcasts: on a global mesh the pool
+            # allocation's device_put itself runs a collective (sharding
+            # assert_equal), so it must sit at the same protocol point on
+            # both sides — the follower allocates inside its admit handler,
+            # post-payload (deadlocked before this ordering: leader in the
+            # alloc allgather, follower in the header broadcast).
+            self._ensure_cache()
+            first, k_row, v_row = self._prefill(self.params, payload)
+            self.prefill_dispatches += 1
+        with tl.phase("prefill.fetch"):
+            first_tok = int(np.asarray(first)[0])
+        with tl.phase("insert.launch", programs=1):
+            self._cache_k, self._cache_v = self._insert(
+                self._cache_k, self._cache_v, k_row, v_row, np.int32(slot))
+            self._set_slot(slot, first_tok, payload, 0)
+            self.device_rounds += 1
 
     def _set_slot(self, slot: int, first_tok: int, payload: dict, j: int):
         self._tok[slot] = first_tok
@@ -442,47 +500,55 @@ class GenerationScheduler:
         (VERDICT r3 #5).  Single-host only: the lockstep broadcast protocol
         keeps the proven per-admission form (serving/generation._loop).
         """
+        tl = self.timeline
         B = len(group)
-        Bp = 1 << (B - 1).bit_length()
-        payloads = [p for _, _, p in group]
-        batched = {
-            k: np.concatenate([p[k] for p in payloads]
-                              + [payloads[0][k]] * (Bp - B), axis=0)
-            for k in payloads[0]
-        }
-        self._ensure_cache()
-        first, k_rows, v_rows = self._prefill(self.params, batched)
-        self.prefill_dispatches += 1
-        first = np.asarray(first)
-        for j, (req, slot, payload) in enumerate(group):
-            self._cache_k, self._cache_v = self._insert_from(
-                self._cache_k, self._cache_v, k_rows, v_rows,
-                np.int32(j), np.int32(slot))
-            self._set_slot(slot, int(first[j]), batched, j)
-        self.device_rounds += 1
+        with tl.phase("prefill.launch", programs=1, batch=B, bucket=bucket):
+            Bp = 1 << (B - 1).bit_length()
+            payloads = [p for _, _, p in group]
+            batched = {
+                k: np.concatenate([p[k] for p in payloads]
+                                  + [payloads[0][k]] * (Bp - B), axis=0)
+                for k in payloads[0]
+            }
+            self._ensure_cache()
+            first, k_rows, v_rows = self._prefill(self.params, batched)
+            self.prefill_dispatches += 1
+        with tl.phase("prefill.fetch"):
+            first = np.asarray(first)  # blocks until the device is done
+        with tl.phase("insert.launch", programs=B):
+            for j, (req, slot, payload) in enumerate(group):
+                self._cache_k, self._cache_v = self._insert_from(
+                    self._cache_k, self._cache_v, k_rows, v_rows,
+                    np.int32(j), np.int32(slot))
+                self._set_slot(slot, int(first[j]), batched, j)
+            self.device_rounds += 1
 
     def _segment_sync(self):
         """One decode segment over the whole pool (dispatch thread)."""
-        if self.lockstep is not None:
-            self.lockstep.lead_gen_segment(
-                self.name, {"tok": self._tok, "pos": self._pos,
-                            "step": self._step, "fin": self._finished,
-                            "temp": self._temp, "seed": self._seed,
-                            "topk": self._topk, "topp": self._topp})
-        emits, self._cache_k, self._cache_v, tok, pos, step, fin = self._segment(
-            self.params, self._cache_k, self._cache_v,
-            self._tok, self._pos, self._step, self._finished,
-            self._temp, self._seed, self._topk, self._topp)
+        tl = self.timeline
+        with tl.phase("segment.launch", programs=1):
+            if self.lockstep is not None:
+                self.lockstep.lead_gen_segment(
+                    self.name, {"tok": self._tok, "pos": self._pos,
+                                "step": self._step, "fin": self._finished,
+                                "temp": self._temp, "seed": self._seed,
+                                "topk": self._topk, "topp": self._topp})
+            emits, self._cache_k, self._cache_v, tok, pos, step, fin = \
+                self._segment(
+                    self.params, self._cache_k, self._cache_v,
+                    self._tok, self._pos, self._step, self._finished,
+                    self._temp, self._seed, self._topk, self._topp)
         # Small fetches: [S, seg] emits + [S] carries; caches stay on device.
         # np.array (copy), not np.asarray: device fetches come back read-only
         # and the scheduler mutates these on retire/admit.
-        out = np.asarray(emits)
-        self._tok = np.array(tok)
-        self._pos = np.array(pos)
-        self._step = np.array(step)
-        self._finished = np.array(fin)
-        self.device_rounds += 1
-        self.segment_rounds += 1
+        with tl.phase("segment.fetch"):
+            out = np.asarray(emits)
+            self._tok = np.array(tok)
+            self._pos = np.array(pos)
+            self._step = np.array(step)
+            self._finished = np.array(fin)
+            self.device_rounds += 1
+            self.segment_rounds += 1
         return out
 
     # -- client API ---------------------------------------------------------
@@ -551,7 +617,9 @@ class GenerationScheduler:
                 "prefill_dispatches": self.prefill_dispatches,
                 "tokens_emitted": self.tokens_emitted,
                 "latency": {"ttft_ms": self.ttft_hist.snapshot(),
-                            "itl_ms": self.itl_hist.snapshot()}}
+                            "itl_ms": self.itl_hist.snapshot()},
+                "host_phases": self.timeline.snapshot(),
+                "lane_wait": self.timeline.lane_wait_snapshot()}
 
     def start(self):
         if self._task is None:
@@ -575,61 +643,68 @@ class GenerationScheduler:
 
     # -- the loop -----------------------------------------------------------
     async def _loop(self):
+        tl = self.timeline
         while True:
             if not self._pending and not self._active:
                 self._wake.clear()
-                await self._wake.wait()
+                with tl.phase("round.idle"):
+                    await self._wake.wait()
             self._process_cancellations()
+            t_top = time.perf_counter()
+            round_no = tl.begin_round(active=len(self._active))
             # Admit into free slots (prefill runs on the dispatch thread, so
             # it serializes with segments and other models' traffic).
             # Single-host, >1 admissible: same-bucket admissions coalesce
             # into ONE batched prefill dispatch (_admit_batch_sync); the
             # lockstep leader keeps the proven per-admission broadcast.
-            admits: list[tuple[GenRequest, int]] = []
-            while self._free and self._pending:
-                admits.append((self._pending.popleft(), self._free.pop()))
-            groups: dict[int, list] = {}
-            for req, slot in admits:
-                if self.lockstep is None:
-                    try:
-                        bucket = self._bucket_for(self._admit_len_of(req.sample))
-                        payload = self._collate_admit(req.sample, bucket)
-                    except Exception as e:  # bad sample fails only itself
-                        self._free.append(slot)
-                        req.finish(error=f"{type(e).__name__}: {e}")
-                        continue
-                    groups.setdefault(bucket, []).append((req, slot, payload))
-                else:
-                    groups.setdefault(-1 - slot, []).append((req, slot, None))
-            group_list = list(groups.items())
+            with tl.phase("round.admit_host"):
+                _note_seen(self._pending, t_top)
+                admits: list[tuple[GenRequest, int]] = []
+                while self._free and self._pending:
+                    req = self._pending.popleft()
+                    req.note_slotted(t_top, round_no)
+                    admits.append((req, self._free.pop()))
+                groups: dict[int, list] = {}
+                for req, slot in admits:
+                    if self.lockstep is None:
+                        try:
+                            bucket = self._bucket_for(
+                                self._admit_len_of(req.sample))
+                            payload = self._collate_admit(req.sample, bucket)
+                        except Exception as e:  # bad sample fails only itself
+                            self._free.append(slot)
+                            req.finish(error=f"{type(e).__name__}: {e}")
+                            continue
+                        groups.setdefault(bucket, []).append(
+                            (req, slot, payload))
+                    else:
+                        groups.setdefault(-1 - slot, []).append(
+                            (req, slot, None))
+                group_list = list(groups.items())
             for gi, (bucket, group) in enumerate(group_list):
-                # Prefill span on the head member (batch-mates linked, same
-                # convention as the batcher's device span).
-                psp = None
-                for req, _, _ in group:
-                    if req.span is not None:
-                        mates = [r.span.trace.trace_id for r, _, _ in group
-                                 if r is not req and r.span is not None][:8]
-                        psp = req.span.child(
-                            "prefill", batch=len(group),
-                            **({"bucket": bucket} if bucket >= 0 else {}),
-                            **({"batch_mates": mates} if mates else {}))
-                        break
                 try:
                     if bucket >= 0:  # single-host: batched (B=1 included)
                         await self.runner.run_fn(self._admit_batch_sync,
                                                  group, bucket,
-                                                 model=self.name)
+                                                 model=self.name,
+                                                 trip=tl.trip("prefill"))
                     else:  # lockstep leader: per-admission broadcast
                         req, slot, _ = group[0]
                         await self.runner.run_fn(self._admit_sync, req, slot,
-                                                 model=self.name)
-                    if psp is not None:
-                        psp.end()
+                                                 model=self.name,
+                                                 trip=tl.trip("prefill"))
                 except Exception as e:  # device fault: fail these requests
-                    if psp is not None:
-                        psp.end(status="error",
-                                error=f"{type(e).__name__}: {e}")
+                    err = f"{type(e).__name__}: {e}"
+                    for req, _, _ in group:
+                        # The failed prefill on the head member's waterfall
+                        # (batch-mates share it, as they shared the program).
+                        if req.span is not None and req.slotted_at is not None:
+                            req.span.child(
+                                "prefill", start=req.slotted_at,
+                                round=req.slotted_round,
+                                batch=len(group)).end(status="error",
+                                                      error=err)
+                            break
                     log.exception("admission failed for %s", self.name)
                     for req, slot, _ in group:
                         self._free.append(slot)
@@ -690,15 +765,21 @@ class GenerationScheduler:
                                        "hosts")
                         return
                     continue
-                for req, slot, _ in group:
-                    req.slot = slot
-                    req.admitted = time.perf_counter()
-                    self._active[slot] = req
-                    if req.span is not None:
-                        # Queue wait = submit → slot admission (the prefill
-                        # itself is the sibling span above).
-                        req.span.child("queue", start=req.submitted).end(
-                            end=req.admitted, slot=slot)
+                with tl.phase("round.admit_host"):
+                    now = time.perf_counter()
+                    traced = [r.span.trace.trace_id for r, _, _ in group
+                              if r.span is not None]
+                    for req, slot, _ in group:
+                        req.slot = slot
+                        req.admitted = now
+                        self._active[slot] = req
+                        if req.span is not None:
+                            mates = [t for t in traced
+                                     if t != req.span.trace.trace_id][:8]
+                            req.trace_admission(
+                                batch=len(group),
+                                **({"bucket": bucket} if bucket >= 0 else {}),
+                                **({"batch_mates": mates} if mates else {}))
                 # (The first token is computed at admission but streamed by
                 # the next segment — decode_segment emits the token decided
                 # before each step, so emitting here would double-count it.)
@@ -706,7 +787,8 @@ class GenerationScheduler:
                 continue
             try:
                 emits = await self.runner.run_fn(self._segment_sync,
-                                                 model=self.name)
+                                                 model=self.name,
+                                                 trip=tl.trip("segment"))
             except Exception as e:
                 # Device fault mid-segment (donated caches are gone): fail
                 # every in-flight request loudly and reset the pool.
@@ -725,7 +807,8 @@ class GenerationScheduler:
                     return
                 self._reset_pool()
                 continue
-            self._distribute(emits)
+            with tl.phase("round.distribute"):
+                self._distribute(emits)
 
     def _cache_deleted(self) -> bool:
         """True when a donating dispatch faulted after consuming the pool."""
@@ -1040,6 +1123,10 @@ class PagedGenerationScheduler:
         self.ttft_hist = Histogram(TOKEN_LATENCY_BUCKETS_MS)
         self.itl_hist = Histogram(TOKEN_LATENCY_BUCKETS_MS)
         self._exit_on_fatal = exit_on_fatal  # unused: single-host only
+        # Host phases of every round, the slot scheduler's names at the same
+        # boundaries (serving/tracing.py); swap, migration and command paths
+        # carry none and show as untiled time.
+        self.timeline = RoundTimeline(self.name)
 
     # -- sizing ---------------------------------------------------------------
     def _chunk_plan(self, n: int, start: int = 0) -> list[tuple[int, int]]:
@@ -1115,21 +1202,26 @@ class PagedGenerationScheduler:
         chunk below reads the copied page's cached positions — so the copy
         must land before the chunk in the same dispatch-thread turn."""
         toks, start, length, temp, seed, topk, topp, table, aidx = payload
-        self._ensure_cache()
-        for src, dst in cows:
-            self._cache_k, self._cache_v = self._copy_page(
-                self._cache_k, self._cache_v, np.int32(src), np.int32(dst))
-        first, self._cache_k, self._cache_v = self._prefill_chunk(
-            self.params, toks, start, length, self._cache_k, self._cache_v,
-            table, temp, seed, topk, topp, aidx)
-        if draft_params is not None:
-            _, self._dcache_k, self._dcache_v = self._draft_kernels[
-                "prefill_chunk"](draft_params, toks, start, length,
-                                 self._dcache_k, self._dcache_v, table,
-                                 temp, seed, topk, topp, aidx)
-        self.prefill_chunks += n_jobs
-        self.device_rounds += 1
-        return np.asarray(first)
+        tl = self.timeline
+        with tl.phase("prefill.launch", batch=n_jobs, bucket=toks.shape[1],
+                      programs=len(cows) + 1 + (draft_params is not None)):
+            self._ensure_cache()
+            for src, dst in cows:
+                self._cache_k, self._cache_v = self._copy_page(
+                    self._cache_k, self._cache_v, np.int32(src),
+                    np.int32(dst))
+            first, self._cache_k, self._cache_v = self._prefill_chunk(
+                self.params, toks, start, length, self._cache_k,
+                self._cache_v, table, temp, seed, topk, topp, aidx)
+            if draft_params is not None:
+                _, self._dcache_k, self._dcache_v = self._draft_kernels[
+                    "prefill_chunk"](draft_params, toks, start, length,
+                                     self._dcache_k, self._dcache_v, table,
+                                     temp, seed, topk, topp, aidx)
+            self.prefill_chunks += n_jobs
+            self.device_rounds += 1
+        with tl.phase("prefill.fetch"):
+            return np.asarray(first)
 
     def _snap_state(self) -> tuple:
         """Immutable per-dispatch snapshot of the host slot state.
@@ -1151,21 +1243,25 @@ class PagedGenerationScheduler:
 
     def _segment_sync(self, table: np.ndarray):
         """One plain decode segment over the pool (dispatch thread)."""
-        _, tok, pos, step, fin, temp, seed, topk, topp, aidx = \
-            self._snap_state()
-        emits, self._cache_k, self._cache_v, tok, pos, step, fin = \
-            self._segment(self.params, self._cache_k, self._cache_v, table,
-                          tok, pos, step, fin, temp, seed, topk, topp, aidx)
-        out = np.asarray(emits)
-        # The final step's fed token is the new chain token at pos-1 (EOS
-        # for finished rows — they never speculate).
-        self._prev = np.array(out[:, -1], np.int32)
-        self._tok = np.array(tok)
-        self._pos = np.array(pos)
-        self._step = np.array(step)
-        self._finished = np.array(fin)
-        self.device_rounds += 1
-        self.segment_rounds += 1
+        tl = self.timeline
+        with tl.phase("segment.launch", programs=1):
+            _, tok, pos, step, fin, temp, seed, topk, topp, aidx = \
+                self._snap_state()
+            emits, self._cache_k, self._cache_v, tok, pos, step, fin = \
+                self._segment(self.params, self._cache_k, self._cache_v,
+                              table, tok, pos, step, fin, temp, seed, topk,
+                              topp, aidx)
+        with tl.phase("segment.fetch"):
+            out = np.asarray(emits)
+            # The final step's fed token is the new chain token at pos-1
+            # (EOS for finished rows — they never speculate).
+            self._prev = np.array(out[:, -1], np.int32)
+            self._tok = np.array(tok)
+            self._pos = np.array(pos)
+            self._step = np.array(step)
+            self._finished = np.array(fin)
+            self.device_rounds += 1
+            self.segment_rounds += 1
         return out
 
     def _spec_tick_sync(self, draft_params, table: np.ndarray,
@@ -1173,29 +1269,38 @@ class PagedGenerationScheduler:
         """One speculative tick: draft proposes k, target verifies in one
         forward, rejection sampling picks the survivors (dispatch thread).
         Returns (n_accept [S], out_toks [S,k+1], proposals [S,k], spans)."""
+        tl = self.timeline
         t0 = time.perf_counter()
-        prev, tok, pos, step, fin, temp, seed, topk, topp, _ = \
-            self._snap_state()
-        props, d_logits, self._dcache_k, self._dcache_v = \
-            self._draft_kernels["propose"](
-                draft_params, self._dcache_k, self._dcache_v, table,
-                prev, tok, pos, step, fin, temp, seed, topk, topp)
-        props_np = np.array(props)
-        if corrupt:
-            # spec_mismatch chaos (faults.py): derail every proposal so the
-            # rejection path runs; verification corrects, output unchanged.
-            props_np = (props_np + 1) % max(self.eos_id, 2)
+        # Two launch/fetch pairs a tick (the proposals come to the host in
+        # between), so a speculative tick counts twice in segment.launch.
+        with tl.phase("segment.launch", programs=1, kind="propose"):
+            prev, tok, pos, step, fin, temp, seed, topk, topp, _ = \
+                self._snap_state()
+            props, d_logits, self._dcache_k, self._dcache_v = \
+                self._draft_kernels["propose"](
+                    draft_params, self._dcache_k, self._dcache_v, table,
+                    prev, tok, pos, step, fin, temp, seed, topk, topp)
+        with tl.phase("segment.fetch", kind="propose"):
+            props_np = np.array(props)
+            if corrupt:
+                # spec_mismatch chaos (faults.py): derail every proposal so
+                # the rejection path runs; verification corrects, output
+                # unchanged.
+                props_np = (props_np + 1) % max(self.eos_id, 2)
         t1 = time.perf_counter()
-        toks = np.concatenate([tok[:, None], props_np], axis=1)
-        t_logits, self._cache_k, self._cache_v = self._verify(
-            self.params, self._cache_k, self._cache_v, table, toks,
-            pos, fin)
-        n, out = self._spec_verify(t_logits, d_logits, props_np, temp,
-                                   seed, step, topk, topp)
+        with tl.phase("segment.launch", programs=2, kind="verify"):
+            toks = np.concatenate([tok[:, None], props_np], axis=1)
+            t_logits, self._cache_k, self._cache_v = self._verify(
+                self.params, self._cache_k, self._cache_v, table, toks,
+                pos, fin)
+            n, out = self._spec_verify(t_logits, d_logits, props_np, temp,
+                                       seed, step, topk, topp)
         t2 = time.perf_counter()
-        self.device_rounds += 1
-        self.segment_rounds += 1
-        return np.asarray(n), np.asarray(out), props_np, (t0, t1, t2)
+        with tl.phase("segment.fetch", kind="verify"):
+            n, out = np.asarray(n), np.asarray(out)
+            self.device_rounds += 1
+            self.segment_rounds += 1
+        return n, out, props_np, (t0, t1, t2)
 
     # -- client API -----------------------------------------------------------
     def submit(self, sample: dict, max_new: int | None = None,
@@ -1299,6 +1404,8 @@ class PagedGenerationScheduler:
                           "enabled": self.kv_migrate,
                           "swapped": len(self._swapped),
                           "detached": len(self._detached)},
+            "host_phases": self.timeline.snapshot(),
+            "lane_wait": self.timeline.lane_wait_snapshot(),
         }
         if self._prefix is not None:
             out["prefix"] = self._prefix.snapshot()
@@ -1352,11 +1459,14 @@ class PagedGenerationScheduler:
             if not (self._pending or self._prefilling or self._active
                     or self._cmds or self._swapped):
                 self._wake.clear()
-                await self._wake.wait()
+                with self.timeline.phase("round.idle"):
+                    await self._wake.wait()
             self._process_cancellations()
             await self._process_cmds()
             if self._prefix is not None and self.prefix_ttl_s > 0:
                 self._prefix.decay(self.prefix_ttl_s)
+            self.timeline.begin_round(active=len(self._active),
+                                      prefilling=len(self._prefilling))
             try:
                 await self._admit()
                 await self._prefill_tick()
@@ -1503,6 +1613,11 @@ class PagedGenerationScheduler:
         # Swapped-out streams re-admit FIRST: they were live before anything
         # still queued, and their pages restore without recompute.
         await self._try_swap_in()
+        with self.timeline.phase("round.admit_host"):
+            self._admit_pending(time.perf_counter())
+
+    def _admit_pending(self, t_top: float):
+        _note_seen(self._pending, t_top)
         while self._free and self._pending:
             req = self._pending[0]
             try:
@@ -1573,6 +1688,7 @@ class PagedGenerationScheduler:
                                cow_copies=len(cow_pairs))
             self._pending.popleft()
             slot = self._free.pop()
+            req.note_slotted(t_top, self.timeline.round)
             self._admit_counter += 1
             req.admit_seq = self._admit_counter
             req.slot = slot
@@ -1643,10 +1759,12 @@ class PagedGenerationScheduler:
             psp = head.span.child(
                 "prefill_chunk", batch=len(jobs), bucket=bucket,
                 chunk=jobs[0].next, chunks=len(jobs[0].chunks))
+        with self.timeline.phase("round.admit_host"):
+            payload = self._chunk_payload(jobs, bucket)
         try:
             first = await self.runner.run_fn(
-                self._prefill_chunk_sync, self._chunk_payload(jobs, bucket),
-                len(jobs), draft_params, cows, model=self.name)
+                self._prefill_chunk_sync, payload, len(jobs), draft_params,
+                cows, model=self.name, trip=self.timeline.trip("prefill"))
             if psp is not None:
                 psp.end()
         except Exception as e:
@@ -1664,6 +1782,11 @@ class PagedGenerationScheduler:
         finally:
             if draft_live:
                 self.draft.release()
+        with self.timeline.phase("round.admit_host"):
+            self._finish_chunk(jobs, first)
+
+    def _finish_chunk(self, jobs: list[_PrefillJob], first: np.ndarray):
+        """After a chunk dispatch: jobs whose last chunk it was go live."""
         for j in jobs:
             # The CoW copies landed with this dispatch: the pinned source
             # pages go back to being ordinary tree/stream pages.
@@ -1704,10 +1827,9 @@ class PagedGenerationScheduler:
                                   "unaffected)", self.name)
             req.admitted = time.perf_counter()
             self._active[job.slot] = req
-            if req.span is not None:
-                req.span.child("queue", start=req.submitted).end(
-                    end=req.admitted, slot=job.slot,
-                    **({"prefix_cached": job.cached} if job.cached else {}))
+            req.trace_admission(
+                chunks=len(job.chunks),
+                **({"prefix_cached": job.cached} if job.cached else {}))
 
     # -- decode ---------------------------------------------------------------
     def _pick_victim(self, protect: GenRequest) -> GenRequest | None:
@@ -1809,15 +1931,16 @@ class PagedGenerationScheduler:
             if draft_params is not None:
                 self.draft.release()
             return
-        table = self._table_np()
-        head = next((r for r in self._active.values()
-                     if r.span is not None), None)
+        with self.timeline.phase("round.admit_host"):
+            table = self._table_np()
+            head = next((r for r in self._active.values()
+                         if r.span is not None), None)
         emitted_total = 0
         if draft_params is not None:
             try:
                 n, out, props, ts = await self.runner.run_fn(
                     self._spec_tick_sync, draft_params, table, corrupt,
-                    model=self.name)
+                    model=self.name, trip=self.timeline.trip("segment"))
             finally:
                 self.draft.release()
             if head is not None:
@@ -1825,11 +1948,14 @@ class PagedGenerationScheduler:
                 head.span.child("spec_draft", start=t0,
                                 k=self.spec_k).end(end=t1)
                 head.span.child("spec_verify", start=t1).end(end=t2)
-            emitted_total = self._distribute_spec(n, out, props)
+            with self.timeline.phase("round.distribute"):
+                emitted_total = self._distribute_spec(n, out, props)
         else:
-            emits = await self.runner.run_fn(self._segment_sync, table,
-                                             model=self.name)
-            emitted_total = self._distribute(emits)
+            emits = await self.runner.run_fn(
+                self._segment_sync, table, model=self.name,
+                trip=self.timeline.trip("segment"))
+            with self.timeline.phase("round.distribute"):
+                emitted_total = self._distribute(emits)
         if emitted_total:
             dt = (time.perf_counter() - t_tick) / emitted_total
             self._s_per_token = (0.7 * self._s_per_token + 0.3 * dt

@@ -890,6 +890,19 @@ class MetricsHub:
                    "Rolling-window MFU per model (needs a flops_per_sample "
                    "hint; absent otherwise)",
                    [({"model": m}, r.get("mfu_pct")) for m, r in rows])
+            if engine is not None:
+                # Read at scrape time; the CPU keeps no such count, and the
+                # family is then absent.
+                from ..utils.device import device_memory
+
+                metric("tpuserve_device_memory_bytes", "gauge",
+                       "Device memory from memory_stats(): in use, peak "
+                       "since process start, and the limit",
+                       [({"device": str(row["id"]), "kind": kind}, row[key])
+                        for row in device_memory()
+                        for kind, key in (("in_use", "bytes_in_use"),
+                                          ("peak", "peak_bytes_in_use"),
+                                          ("limit", "bytes_limit"))])
         if self.autoscale is not None:
             # Predictive autoscaling plane (serving/autoscale.py;
             # docs/AUTOSCALE.md): the demand forecast, the learned

@@ -43,7 +43,7 @@ import numpy as np
 from aiohttp import web
 
 from ..config import ServeConfig
-from ..utils.device import device_info
+from ..utils.device import device_info, device_memory
 from ..utils.logging import current_trace_id, get_logger, log_event
 from ..engine.loader import Engine, build_engine
 from .adapters import AdapterCold, AdapterManager, UnknownAdapter
@@ -400,7 +400,6 @@ class Server:
             web.get("/admin/autoscale", self.handle_admin_autoscale),
             web.get("/admin/perf", self.handle_admin_perf),
             web.post("/admin/profile", self.handle_profile),
-            web.post("/debug/trace", self.handle_trace),
             web.get("/v1/models", self.handle_models),
             web.post("/v1/models/{name:[^:/]+}:predict", self.handle_predict),
             web.post("/v1/models/{name:[^:/]+}:generate", self.handle_generate),
@@ -1666,60 +1665,6 @@ class Server:
             "cold_start_seconds": round(self.engine.cold_start_seconds, 3),
         })
 
-    async def handle_trace(self, request):
-        """Capture a jax.profiler trace of live traffic for N seconds.
-
-        ``POST /debug/trace {"seconds": 2}`` → xplane/perfetto capture under
-        ``trace_dir``; the batcher→dispatch spans (TraceAnnotations in
-        engine/runner + engine/compiled) land on the host threads alongside
-        the device timeline.  Open with xprof/TensorBoard or perfetto.
-        """
-        import time as _time
-        import uuid
-
-        import jax.profiler
-
-        from pathlib import Path
-
-        try:
-            body = await request.json() if request.can_read_body else {}
-        except ValueError:
-            body = {}
-        if not isinstance(body, dict):
-            return _error(400, "body must be a JSON object")
-        try:
-            seconds = float(body.get("seconds", 2.0))
-        except (TypeError, ValueError):
-            return _error(400, "seconds must be a number")
-        if not (0.05 <= seconds <= 60.0):  # also rejects NaN
-            return _error(400, "seconds must be in [0.05, 60]")
-        if self._tracing:
-            return _error(409, "a trace capture is already running")
-        out_dir = (Path(self.cfg.trace_dir).expanduser()
-                   / f"{_time.strftime('%Y%m%d-%H%M%S')}-{uuid.uuid4().hex[:6]}")
-        out_dir.mkdir(parents=True, exist_ok=True)
-        self._tracing = True
-        loop = asyncio.get_running_loop()
-        try:
-            # start/stop serialize the capture buffer — keep them (and the
-            # file listing below) off the event loop so /healthz and predicts
-            # stay responsive during a long capture.  stop_trace sits in a
-            # finally so a client disconnect mid-sleep can't leave the
-            # profiler session open (which would 500 every later capture).
-            await loop.run_in_executor(None, jax.profiler.start_trace, str(out_dir))
-            try:
-                await asyncio.sleep(seconds)
-            finally:
-                await loop.run_in_executor(None, jax.profiler.stop_trace)
-        finally:
-            self._tracing = False
-        files = await loop.run_in_executor(None, lambda: sorted(
-            str(p.relative_to(out_dir)) for p in out_dir.rglob("*") if p.is_file()))
-        log_event(log, "trace captured", dir=str(out_dir), seconds=seconds,
-                  files=len(files))
-        return web.json_response({"dir": str(out_dir), "seconds": seconds,
-                                  "files": files})
-
     # -- admin: request tracing + on-demand profiling ------------------------
     async def handle_trace_list(self, request):
         """``GET /admin/trace`` — finished/live trace summaries, filtered.
@@ -1727,20 +1672,30 @@ class Server:
         Query params: ``model``, ``status`` (ok|error|open), ``min_ms``
         (minimum duration), ``limit`` (default 50).  Newest first; the
         flight recorder guarantees the slowest/errored traces per model
-        survive ring churn (docs/OBSERVABILITY.md).
+        survive ring churn (docs/OBSERVABILITY.md).  ``rounds=N`` adds the
+        last N scheduler rounds of every generation lane (of ``model``, if
+        given) with their host phases: the timeline a ``prefill`` span's
+        ``round`` attribute points into.
         """
         q = request.query
         try:
             min_ms = float(q.get("min_ms", 0.0))
             limit = int(q.get("limit", 50))
+            rounds = int(q.get("rounds", 0))
         except (TypeError, ValueError):
-            return _error(400, "min_ms must be a number, limit an integer")
-        return web.json_response({
-            "traces": self.tracer.list(model=q.get("model"),
-                                       status=q.get("status"),
-                                       min_ms=min_ms, limit=limit),
-            "pinned": self.tracer.pinned(),
-            **self.tracer.snapshot()})
+            return _error(400, "min_ms must be a number, limit and rounds "
+                               "integers")
+        out = {"traces": self.tracer.list(model=q.get("model"),
+                                          status=q.get("status"),
+                                          min_ms=min_ms, limit=limit),
+               "pinned": self.tracer.pinned(),
+               **self.tracer.snapshot()}
+        if rounds > 0:
+            out["rounds"] = {
+                n: sched.timeline.recent(rounds)
+                for n, sched in self.schedulers.items()
+                if q.get("model") in (None, n)}
+        return web.json_response(out)
 
     async def handle_trace_get(self, request):
         """``GET /admin/trace/{id}`` — the full span tree for one trace."""
@@ -1751,15 +1706,19 @@ class Server:
         return web.json_response({"trace": trace.tree()})
 
     async def handle_profile(self, request):
-        """``POST /admin/profile {"seconds": 2}`` — timed device capture +
-        op-time breakdown, in one call.
+        """``POST /admin/profile {"seconds": 2}`` — timed capture of live
+        traffic, reduced by the program itself, in one call.
 
         The escalation path from a trace: a span tree says *which stage* is
-        slow, this says *which device ops* — a ``jax.profiler`` capture of
-        live traffic classified through the same ``utils/xplane.py`` rules
-        the bench's ``device_trace_ms`` uses, so the numbers are comparable
-        and no redeploy/TensorBoard round-trip is needed.  ``top`` bounds
-        the op list (default 15).
+        slow; this says *which device ops* (``ops``, classified through the
+        ``utils/xplane.py`` rules the bench's ``device_trace_ms`` uses;
+        ``top`` bounds the list, default 15), *which program* runs them
+        (``programs``: device runs named by the ``tpuserve.*.launch``
+        annotation that launched them) and *what the host was doing while
+        the device sat idle* (``idle``: every gap between device operations
+        booked to the scheduler phase that covers it).  The capture stays
+        under ``dir`` for xprof/TensorBoard or perfetto.  One capture at a
+        time: a second request while one runs answers 409.
         """
         import time as _time
         import uuid as _uuid
@@ -1790,9 +1749,11 @@ class Server:
         self._tracing = True
         loop = asyncio.get_running_loop()
         try:
-            # Same serialization/cleanup contract as handle_trace: start/stop
-            # off the event loop, stop in a finally so an abandoned request
-            # can't wedge the profiler session.
+            # start/stop serialize the capture buffer: keep them (and the
+            # reduction below) off the event loop so /healthz and predicts
+            # stay responsive; stop sits in a finally so a client that went
+            # away mid-sleep can't leave the profiler session open (which
+            # would 500 every later capture).
             await loop.run_in_executor(None, jax.profiler.start_trace,
                                        str(out_dir))
             try:
@@ -1803,7 +1764,7 @@ class Server:
             self._tracing = False
 
         def classify():
-            from ..utils.xplane import op_time_breakdown
+            from ..utils.xplane import attribute_idle, op_time_breakdown
 
             compute, counts, overlap, envelope = op_time_breakdown(out_dir)
             ops = [{"op": fam, "ms": round(ns / 1e6, 3),
@@ -1812,10 +1773,12 @@ class Server:
             return {"ops": ops,
                     "device_compute_ms": round(sum(compute.values()) / 1e6, 3),
                     "overlap_ms": round(sum(overlap.values()) / 1e6, 3),
-                    "envelope_ms": round(sum(envelope.values()) / 1e6, 3)}
+                    "envelope_ms": round(sum(envelope.values()) / 1e6, 3),
+                    **attribute_idle(out_dir)}
 
         # A capture with no device plane (the CPU backend) classifies to
-        # zero ops; the answer still carries the capture location.
+        # zero ops, an empty ``idle`` and no ``programs``; the answer still
+        # carries the capture location.
         breakdown = await loop.run_in_executor(None, classify)
         log_event(log, "profile captured", dir=str(out_dir), seconds=seconds,
                   ops=len(breakdown.get("ops", [])))
@@ -2524,6 +2487,8 @@ class Server:
         # the disaggregated router) can migrate it mid-flight.
         stream_id = ctx.request_id if ctx is not None else new_request_id()
         self._register_stream(stream_id, name, sched, gen, imported=False)
+        if ctx is not None:
+            gen.t_ingest0 = ctx.span.t0
 
         def final_body(tokens: list[int]) -> dict:
             out: dict = {"done": True, "tokens": tokens}
@@ -2534,9 +2499,12 @@ class Server:
                 # prefills + decode segments): lets a client separate queue
                 # effects from device time in its TTFT (benchmark.py
                 # generate_path reports the medians).
+                # The *_ms keys tile the time to the first token by the
+                # server's own stamps (GenRequest.timing_stats).
                 out["stats"] = {
                     "rounds_to_first_token": gen.rounds_to_first_token,
                     "segments_to_first_token": gen.segments_to_first_token,
+                    **gen.timing_stats(),
                 }
             if gen.spec_proposed:
                 # Speculation evidence (docs/GENERATION.md): the draft rung
@@ -2628,6 +2596,8 @@ class Server:
                 if ev is None:
                     break
                 await send({"token": ev})
+                if gen.first_write_at is None:
+                    gen.first_write_at = time.perf_counter()
             if gen.done.done() and gen.done.exception() is not None:
                 if gen.migrated:
                     # The stream left this replica via a committed
@@ -3376,6 +3346,8 @@ class Server:
                 row["ttft_p50_ms"] = ttft
             if itl is not None:
                 row["itl_p50_ms"] = itl
+        # Read here, at scrape time, and never on the request path.
+        snap["device_memory"] = device_memory()
         return web.json_response(snap)
 
     # -- admin: chaos + drain ------------------------------------------------

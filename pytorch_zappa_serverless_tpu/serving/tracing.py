@@ -30,6 +30,11 @@ through logs (``utils/logging`` stamps it on every record via
 ``current_trace_id``), metrics (OpenMetrics exemplars on the queue/device
 histograms, serving/metrics.py) and ``GET /admin/trace/{id}``.
 ``tools/tracedump.py`` renders the tree as a text waterfall.
+
+:class:`RoundTimeline` is the scheduler-side twin: what a generation
+scheduler's two threads were doing in each round of its loop, as cumulative
+per-phase counters, a ring of the last rounds, and ``TraceAnnotation``s that
+put the same phases on the profiler's clock (docs/OBSERVABILITY.md sections 1, 4).
 """
 
 from __future__ import annotations
@@ -411,3 +416,154 @@ class Tracer:
                 "dropped_spans": dropped,
                 "pinned_slow": sum(pins["slow"].values()),
                 "pinned_errored": sum(pins["errored"].values())}
+
+
+# -- scheduler rounds -----------------------------------------------------------
+
+# The host phases of one generation-scheduler round (one iteration of the
+# scheduler's ``_loop``: zero or more admission groups, then one segment).
+# The ``round.*`` phases run on the event loop, the others on the dispatch
+# thread; ``round.lane_wait`` and ``round.wakeup`` are the two hand-overs
+# between them.  PERF.md, the benchmark's readers and ``attribute_idle``
+# (utils/xplane.py) share these names.
+PHASES = ("round.idle", "round.admit_host", "round.lane_wait",
+          "prefill.launch", "prefill.fetch", "insert.launch",
+          "segment.launch", "segment.fetch", "round.wakeup",
+          "round.distribute")
+
+
+class _Phase:
+    """One timed phase: stamps outermost, the annotation inside them."""
+
+    __slots__ = ("tl", "name", "attrs", "ann", "t0")
+
+    def __init__(self, tl: "RoundTimeline", name: str, attrs: dict):
+        self.tl, self.name, self.attrs = tl, name, attrs
+        # One thread opens and closes a phase; which one, the phase says.
+        self.t0 = 0      # guarded-by: dispatch-serialized
+        self.ann = None  # guarded-by: dispatch-serialized
+
+    def __enter__(self) -> "_Phase":
+        self.t0 = time.perf_counter_ns()
+        self.ann = self.tl.annotation(self.name, **self.attrs)
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.ann.__exit__(exc_type, exc, tb)
+        self.tl.book(self.name, self.t0, time.perf_counter_ns(), self.attrs)
+
+
+class _Trip:
+    """One awaited round-trip to the dispatch thread (``runner.run_fn``).
+
+    ``round.lane_wait`` runs from the enqueue on the event loop to the
+    pick-up on the dispatch thread, ``round.wakeup`` from the callable's
+    return there to the scheduler coroutine's resumption.  Each annotation is
+    entered on one thread and left on the other: the profiler records a
+    ``TraceMe`` when it is left, with the start it was entered at, so the
+    event lands whole on the timeline of the thread that ends it.
+    """
+
+    __slots__ = ("tl", "kind", "t_enq", "t_done", "ann")
+
+    def __init__(self, tl: "RoundTimeline", kind: str):
+        self.tl, self.kind = tl, kind
+        # Handed from thread to thread with the call itself.
+        self.t_done: int | None = None  # guarded-by: dispatch-serialized
+        self.t_enq = time.perf_counter_ns()
+        self.ann = tl.annotation("round.lane_wait", kind=kind)  # guarded-by: dispatch-serialized
+        self.ann.__enter__()
+
+    def picked_up(self) -> None:  # dispatch thread
+        self.ann.__exit__(None, None, None)
+        now = time.perf_counter_ns()
+        self.tl.book("round.lane_wait", self.t_enq, now, {"kind": self.kind})
+        split = self.tl.lane_wait_ns.setdefault(self.kind, [0, 0])
+        split[0] += now - self.t_enq
+        split[1] += 1
+
+    def returned(self) -> None:  # dispatch thread
+        self.t_done = time.perf_counter_ns()
+        self.ann = self.tl.annotation("round.wakeup", kind=self.kind)
+        self.ann.__enter__()
+
+    def resumed(self) -> None:  # event loop
+        if self.t_done is None:  # never picked up (cancelled, pool down)
+            return
+        self.ann.__exit__(None, None, None)
+        self.tl.book("round.wakeup", self.t_done, time.perf_counter_ns(),
+                     {"kind": self.kind})
+
+
+class RoundTimeline:
+    """What one scheduler's two threads did, round by round.
+
+    Three views of the same stamps: cumulative ``sum_ns``/``count`` per phase
+    (``gen_snapshot()["host_phases"]``, read as deltas), a ring of the last
+    ``ring`` rounds with every phase interval in ``perf_counter_ns`` (``GET
+    /admin/trace?rounds=N``), and a ``jax.profiler.TraceAnnotation`` named
+    ``tpuserve.<phase>`` around each phase, which costs well under a
+    microsecond without a profiler session and puts the phase on the
+    capture's own clock with one (``POST /admin/profile``).
+
+    No lock: every phase has exactly one writer thread, and the scheduler
+    task and the dispatch thread alternate through awaited round-trips.
+    """
+
+    def __init__(self, model: str, ring: int = 256):
+        from jax.profiler import TraceAnnotation
+
+        self.model = model
+        self._annotate = TraceAnnotation
+        self.round = 0  # guarded-by: dispatch-serialized
+        self.sum_ns = dict.fromkeys(PHASES, 0)  # guarded-by: dispatch-serialized
+        self.count = dict.fromkeys(PHASES, 0)   # guarded-by: dispatch-serialized
+        # round.lane_wait again, split by the program kind that waited.
+        self.lane_wait_ns: dict[str, list[int]] = {}  # guarded-by: dispatch-serialized
+        self._rounds: deque[dict] = deque(maxlen=max(int(ring), 1))  # guarded-by: dispatch-serialized
+        self._cur: dict | None = None  # guarded-by: dispatch-serialized
+
+    def annotation(self, phase: str, **attrs):
+        return self._annotate(f"tpuserve.{phase}", round=self.round,
+                              model=self.model, **attrs)
+
+    def begin_round(self, **attrs) -> int:
+        """Open the next round (scheduler task, loop top); ends the last."""
+        now = time.perf_counter_ns()
+        if self._cur is not None:
+            self._cur["t1_ns"] = now
+        self.round += 1
+        self._cur = {"round": self.round, "t0_ns": now, "t1_ns": None,
+                     **attrs, "phases": []}
+        self._rounds.append(self._cur)
+        return self.round
+
+    def phase(self, name: str, **attrs) -> _Phase:
+        return _Phase(self, name, attrs)
+
+    def trip(self, kind: str) -> _Trip:
+        return _Trip(self, kind)
+
+    def book(self, name: str, t0_ns: int, t1_ns: int, attrs: dict) -> None:
+        self.sum_ns[name] += t1_ns - t0_ns
+        self.count[name] += 1
+        if self._cur is not None:
+            self._cur["phases"].append((name, t0_ns, t1_ns, attrs))
+
+    def snapshot(self) -> dict:
+        """``{phase: {"sum_ms", "count"}}``, cumulative since the start."""
+        return {p: {"sum_ms": round(self.sum_ns[p] / 1e6, 3),
+                    "count": self.count[p]} for p in PHASES}
+
+    def lane_wait_snapshot(self) -> dict:
+        return {kind: {"sum_ms": round(ns / 1e6, 3), "count": n}
+                for kind, (ns, n) in list(self.lane_wait_ns.items())}
+
+    def recent(self, limit: int = 32) -> list[dict]:
+        """The newest ``limit`` rounds, oldest first."""
+        rounds = list(self._rounds)[-max(int(limit), 1):]
+        return [{**{k: v for k, v in r.items() if k != "phases"},
+                 "phases": [{"phase": name, "t0_ns": t0, "t1_ns": t1, **attrs}
+                            for name, t0, t1, attrs in list(r["phases"])]}
+                for r in rounds]

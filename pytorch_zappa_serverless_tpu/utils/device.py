@@ -45,6 +45,20 @@ def device_info() -> dict:
             "count": len(devices)}
 
 
+def device_memory() -> list[dict]:
+    """What every local device holds, from ``memory_stats()``: bytes in use,
+    their peak since the process began, and the limit.  A backend that keeps
+    no such count (the CPU) gives ``None`` for each."""
+    import jax
+
+    rows = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        rows.append({"id": d.id, **{key: stats.get(key) for key in (
+            "bytes_in_use", "peak_bytes_in_use", "bytes_limit")}})
+    return rows
+
+
 def require_tpu(platform: str | None, what: str) -> dict:
     """Start-up gate for entry points that serve or measure: returns
     :func:`device_info`, or raises ``SystemExit`` naming ``--platform cpu``

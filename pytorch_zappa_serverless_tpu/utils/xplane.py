@@ -21,16 +21,32 @@ Rules:
   430 ms wall before this).  Consequence: ``device_compute_ms`` is a
   lower bound for loop-heavy programs — the envelope-minus-body gap
   (per-iteration sequencing) is not attributed.
+
+:func:`attribute_idle` reads the same capture for ``POST /admin/profile``
+with the device rules of ``benchmark/trace_reduce.py``, so that the program's
+idle share and the benchmark's agree: busy is the union of the ``XLA Ops``
+intervals (envelopes included), a program run is an ``XLA Modules`` event,
+the window runs from the first device event to the last.  On top of them it
+reads the host plane's ``tpuserve.*`` annotations (serving/tracing.py
+``RoundTimeline``): they name each run and say what the host was doing in
+every gap between runs.
 """
 
 from __future__ import annotations
 
+import bisect
 import collections
+import heapq
 import re
 from pathlib import Path
 
 _ASYNC_NAME = re.compile(r"(copy|slice|async)[-_]?(start|done)")
 _ENVELOPE = {"while", "conditional", "call"}  # see module docstring rules
+
+
+def _family(op_name: str) -> str:
+    """``%fusion.123 = ...`` -> ``fusion``."""
+    return re.sub(r"[.\d]+$", "", op_name.split(" = ")[0].lstrip("%"))
 
 
 def op_time_breakdown(trace_dir):
@@ -56,8 +72,7 @@ def op_time_breakdown(trace_dir):
                     name = ev.name
                     if name.startswith("jit_") or " = " not in name:
                         continue
-                    fam = re.sub(r"[.\d]+$", "",
-                                 name.split(" = ")[0].lstrip("%"))
+                    fam = _family(name)
                     if line_is_async or _ASYNC_NAME.search(fam):
                         overlap[fam] += ev.duration_ns
                         continue
@@ -74,3 +89,231 @@ def device_compute_ms(trace_dir, iters: int) -> float | None:
     compute, _, _, _ = op_time_breakdown(trace_dir)
     total = sum(compute.values())
     return round(total / iters / 1e6, 3) if total else None
+
+
+# -- host and device together (POST /admin/profile) ----------------------------
+
+_ANNOTATION = "tpuserve."
+# Phases that run on the dispatch thread; the ``round.*`` ones run on the
+# event loop (``round.lane_wait`` ends on the dispatch thread, and no event
+# loop phase of the same scheduler covers its time).
+_DISPATCH = ("prefill.", "insert.", "segment.")
+IN_PROGRAM = "in_program"  # idle between the operations of one program run
+
+
+def _read_capture(trace_dir):
+    """-> (device planes as {line name: line}, host annotations as
+    ``(start_ns, end_ns, phase, programs)`` sorted by start)."""
+    from jax.profiler import ProfileData
+
+    device, host = [], []
+    for pb in sorted(Path(trace_dir).rglob("*.xplane.pb")):
+        for plane in ProfileData.from_file(str(pb)).planes:
+            lines = {line.name: line for line in plane.lines}
+            if "XLA Ops" in lines:
+                device.append(lines)
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    name = ev.name
+                    if not name.startswith(_ANNOTATION):
+                        continue
+                    programs = 1
+                    if name.endswith(".launch"):
+                        programs = int(dict(ev.stats).get("programs", 1))
+                    start = int(ev.start_ns)
+                    host.append((start, start + int(ev.duration_ns),
+                                 name[len(_ANNOTATION):], programs))
+    host.sort()
+    return device, host
+
+
+def _phase_steps(host) -> tuple[list[int], list[str | None]]:
+    """The one phase that holds each instant, as a step function: where
+    several annotations cover it, a dispatch-thread phase beats an event-loop
+    one, and among equals the one that started last (the innermost)."""
+    bounds = sorted({t for s, e, _, _ in host for t in (s, e)})
+    steps: list[str | None] = []
+    heap: list[tuple] = []
+    i = 0
+    for t in bounds:
+        while i < len(host) and host[i][0] <= t:
+            s, e, phase, _ = host[i]
+            heapq.heappush(heap, (not phase.startswith(_DISPATCH), -s, e,
+                                  phase))
+            i += 1
+        while heap and heap[0][2] <= t:
+            heapq.heappop(heap)
+        steps.append(heap[0][3] if heap else None)
+    return bounds, steps
+
+
+def _book(into: dict, bounds, steps, a: int, b: int) -> None:
+    """Add the interval [a, b) to ``into`` by the phase that holds it."""
+    i = bisect.bisect_right(bounds, a) - 1  # -1: before the first annotation
+    while a < b:
+        phase = steps[i] if i >= 0 else None
+        end = min(bounds[i + 1], b) if i + 1 < len(bounds) else b
+        into[phase] = into.get(phase, 0) + end - a
+        a = end
+        i += 1
+
+
+# How long before its launch began a device run may seem to have started: the
+# profiler sets the device plane's clock against the host's to within about a
+# millisecond (on the v5e the device plane read 0.3-0.9 ms early).
+_EARLY_NS = 2_000_000
+
+
+def join_runs(mods: list[tuple[int, int, str]], host) -> list[dict]:
+    """Name each device program run by the ``*.launch`` annotation that
+    launched it; ``mods`` is ``(start, end, module name)`` sorted by start.
+
+    One chip runs programs in the order they were dispatched, and a run
+    cannot begin before its launch did: each launch, in order, takes the next
+    ``programs`` runs that began after it (give or take the clocks).  A run
+    that began before the next launch with nobody to claim it (launched
+    before the capture began, or by code that carries no annotation) keeps
+    its module's name.  A fetched launch's run also records when the
+    ``<kind>.fetch`` that followed returned."""
+    runs = [{"start": s, "end": e, "module": m, "kind": m, "launch_start": None,
+             "fetch_end": None} for s, e, m in mods]
+    fetches: dict[str, list] = {}
+    for h in host:
+        if h[2].endswith(".fetch"):
+            fetches.setdefault(h[2].split(".")[0], []).append(h)
+    j = 0
+    for l_start, _, phase, programs in host:
+        if not phase.endswith(".launch"):
+            continue
+        kind = phase.split(".")[0]
+        while j < len(runs) and runs[j]["start"] < l_start - _EARLY_NS:
+            j += 1
+        rows = fetches.get(kind, [])
+        k = bisect.bisect_left(rows, (l_start,))
+        fetch_end = rows[k][1] if k < len(rows) else None
+        for r in runs[j:j + programs]:
+            r.update(kind=kind, launch_start=l_start, fetch_end=fetch_end)
+        j = min(j + programs, len(runs))
+    return runs
+
+
+def _clock_check(runs: list[dict]) -> tuple[int, dict]:
+    """How the two planes' clocks stand, from the join: the least the device
+    plane is early by (a run cannot begin before its launch did), and, with
+    that put right, how long after each segment's run its ``segment.fetch``
+    returned.  That has to be after, and shortly: it is what guards the join
+    and the attribution against the clocks drifting apart unnoticed."""
+    early = max((r["launch_start"] - r["start"] for r in runs
+                 if r["launch_start"] is not None), default=0)
+    early = max(early, 0)
+    lags = [(r["fetch_end"] - r["end"] - early) / 1e6 for r in runs
+            if r["kind"] == "segment" and r["fetch_end"] is not None]
+    return early, {
+        "segments": len(lags),
+        "ok": sum(1 for lag in lags if 0.0 < lag < 5.0),
+        "lag_ms": {"min": round(min(lags), 3), "max": round(max(lags), 3)}
+        if lags else None,
+        "device_early_ms": round(early / 1e6, 3)}
+
+
+def attribute_idle(trace_dir) -> dict:
+    """``{"idle": ..., "programs": ...}`` of a capture: every idle gap between
+    device runs booked to the host phase that covers it, and every device run
+    named from inside the program.  Times are means over the device planes.
+    A capture without a device plane gives both empty."""
+    device, host = _read_capture(trace_dir)
+    if not device:
+        return {"idle": {}, "programs": {}}
+    bounds, steps = _phase_steps(host)
+    n = len(device)
+    window = busy = between = 0
+    by_phase: dict = {}
+    gaps: dict[tuple[str, str], dict] = {}
+    programs: dict[str, dict] = {}
+    clock = None
+    families: dict[str, str] = {}  # a million events, a few hundred names
+    for lines in device:
+        ops = []
+        for ev in lines["XLA Ops"].events:
+            name = ev.name
+            fam = families.get(name)
+            if fam is None:
+                fam = families[name] = _family(name)
+            start = int(ev.start_ns)
+            ops.append((start, start + int(ev.duration_ns), fam))
+        ops.sort()
+        mods = sorted((int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
+                       ev.name.split("(")[0])
+                      for ev in lines["XLA Modules"].events) \
+            if "XLA Modules" in lines else []
+        if not ops:
+            continue
+        first = min(ops[0][0], mods[0][0] if mods else ops[0][0])
+        last = max(max(e for _, e, _ in ops),
+                   max((e for _, e, _ in mods), default=0))
+        window += last - first
+        end = -1
+        for s, e, _ in ops:  # the union of the operation intervals
+            if s > end:
+                busy += e - s
+                end = e
+            elif e > end:
+                busy += e - end
+                end = e
+        runs = join_runs(mods, host)
+        early, checked = _clock_check(runs)
+        clock = clock or checked
+        k = 0
+        prev = None
+        for r in runs:
+            p = programs.setdefault(r["kind"], {"runs": 0, "device_ns": 0,
+                                                "ops": {}})
+            p["runs"] += 1
+            p["device_ns"] += r["end"] - r["start"]
+            while k < len(ops) and ops[k][0] < r["end"]:
+                s, e, fam = ops[k]
+                if s >= r["start"] and fam not in _ENVELOPE:
+                    p["ops"][fam] = p["ops"].get(fam, 0) + e - s
+                k += 1
+            if prev is not None and r["start"] > prev[0]:
+                gap = gaps.setdefault((prev[1], r["kind"]),
+                                      {"count": 0, "ns": 0, "phases": {}})
+                gap["count"] += 1
+                gap["ns"] += r["start"] - prev[0]
+                between += r["start"] - prev[0]
+                _book(gap["phases"], bounds, steps, prev[0] + early,
+                      r["start"] + early)
+            if prev is None or r["end"] > prev[0]:
+                prev = (r["end"], r["kind"])
+    for gap in gaps.values():
+        for phase, ns in gap["phases"].items():
+            by_phase[phase] = by_phase.get(phase, 0) + ns
+    idle = window - busy
+    by_phase[IN_PROGRAM] = max(idle - between, 0)
+    unattributed = by_phase.pop(None, 0)
+
+    def ms(ns) -> float:
+        return round(ns / n / 1e6, 3)
+
+    def phases_ms(d: dict) -> dict:
+        return {(k or "unattributed"): ms(v)
+                for k, v in sorted(d.items(), key=lambda kv: -kv[1])}
+
+    longest = sorted(gaps.items(), key=lambda kv: -kv[1]["ns"])[:10]
+    return {
+        "idle": {
+            "window_ms": ms(window), "busy_ms": ms(busy), "idle_ms": ms(idle),
+            "by_phase": phases_ms(by_phase),
+            "unattributed_ms": ms(unattributed),
+            "gaps": [{"before": before, "after": after, "ms": ms(g["ns"]),
+                      "count": g["count"] // n,
+                      "phases": phases_ms(g["phases"])}
+                     for (before, after), g in longest],
+            "clock": clock,
+        },
+        "programs": {
+            kind: {"runs": p["runs"] // n, "device_ms": ms(p["device_ns"]),
+                   "ops": phases_ms(p["ops"])}
+            for kind, p in programs.items()},
+    }

@@ -455,3 +455,27 @@ def test_exposition_matches_checked_in_manifest():
     mutated = text.replace('tpuserve_requests_total{model="resnet18"}',
                            'tpuserve_requests_total{rogue="x"}', 1)
     assert any("label set" in p for p in mod.check(mutated, manifest))
+
+
+def test_device_memory_gauge_renders_where_the_backend_counts(monkeypatch):
+    """``tpuserve_device_memory_bytes{device,kind}``: read from
+    ``memory_stats()`` at scrape time, one series per device and kind, in the
+    manifest; a kind the backend does not report is left out."""
+    from pytorch_zappa_serverless_tpu.utils import device
+
+    monkeypatch.setattr(device, "device_memory", lambda: [
+        {"id": 0, "bytes_in_use": 5, "peak_bytes_in_use": 9,
+         "bytes_limit": 16},
+        {"id": 1, "bytes_in_use": 7, "peak_bytes_in_use": None,
+         "bytes_limit": 16}])
+    mod = _check_metrics_mod()
+    hub = _loaded_hub()
+    engine = SimpleNamespace(
+        runner=SimpleNamespace(stats={}, lane_stats=lambda: {}),
+        cold_start_seconds=1.0,
+        clock=SimpleNamespace(entries=[], total_seconds=0.0), models={})
+    text = hub.render_prometheus(engine)
+    assert 'tpuserve_device_memory_bytes{device="0",kind="peak"} 9' in text
+    assert 'tpuserve_device_memory_bytes{device="1",kind="in_use"} 7' in text
+    assert 'device="1",kind="peak"' not in text
+    assert mod.check(text, mod.load_manifest()) == []

@@ -399,6 +399,13 @@ async def test_admin_perf_over_a_real_server(served):
         assert stages[stage]["count"] >= 1, (stage, stages)
     # Stage order in the snapshot follows the pipeline.
     assert list(stages) == [s for s in INGEST_STAGES if s in stages]
+    # Device memory, read at scrape time: the CPU keeps no such count, so
+    # every device is there with nulls (and the gauge family is absent).
+    assert perf["device_memory"] and all(
+        set(row) == {"id", "bytes_in_use", "peak_bytes_in_use", "bytes_limit"}
+        and row["peak_bytes_in_use"] is None for row in perf["device_memory"])
+    text = await (await client.get("/metrics?format=prometheus")).text()
+    assert "tpuserve_device_memory_bytes" not in text
     # ?top bounds the stack table; junk 400s.
     r = await client.get("/admin/perf", params={"top": 1})
     assert len((await r.json())["stacks"]["stacks"]) <= 1

@@ -28,7 +28,7 @@ from PIL import Image
 
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.engine.loader import build_engine
-from pytorch_zappa_serverless_tpu.serving.server import create_app
+from pytorch_zappa_serverless_tpu.serving.server import Server, create_app
 from pytorch_zappa_serverless_tpu.serving.tracing import (
     Tracer, format_traceparent, parse_traceparent)
 
@@ -425,6 +425,163 @@ async def test_generation_trace_spans(aiohttp_client, tmp_path):
         assert payload["trace"]["status"] == "ok"
     finally:
         engine.shutdown()
+
+
+# -- the scheduler-round timeline (ISSUE 24) ---------------------------------
+
+# Wide and long enough that a round's work dwarfs the microseconds between
+# its phases, which a 98% floor would otherwise trip over on a busy CPU.
+_GEN_ARCH = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 512,
+             "vocab_size": 512, "max_positions": 128}
+_GEN_SLOTS = 2
+_DISPATCH_PHASES = ("prefill.", "insert.", "segment.", "round.lane_wait")
+
+
+def _gen_cfg(tmp_path, kv_cache):
+    return ServeConfig(
+        compile_cache_dir=str(tmp_path / "xla"),
+        models=[ModelConfig(
+            name="gpt2", batch_buckets=(1, 2), seq_buckets=(8,),
+            dtype="float32", coalesce_ms=1.0, kv_cache=kv_cache,
+            kv_block_size=4,
+            extra={"max_new_tokens": 96, "gen_slots": _GEN_SLOTS,
+                   "segment_tokens": 32, "arch": _GEN_ARCH})])
+
+
+async def _generate(client, n, max_new=96):
+    async def one(i):
+        r = await client.post(
+            "/v1/models/gpt2:generate",
+            json={"input_ids": [3 + i, 5, 7, 11, 13], "stream": True,
+                  "max_new_tokens": max_new})
+        assert r.status == 200, await r.text()
+        final = None
+        async for raw in r.content:
+            if raw.startswith(b"data: "):
+                ev = json.loads(raw[6:])
+                if ev.get("done"):
+                    final = ev
+        assert final is not None
+        return r.headers["X-Trace-Id"], final["stats"]
+    return await asyncio.gather(*(one(i) for i in range(n)))
+
+
+async def _gen_counters(client):
+    return (await (await client.get("/metrics")).json())["generation"]["gpt2"]
+
+
+@pytest.mark.parametrize("kv_cache,floor", [("slot", 0.98), ("paged", 0.95)])
+async def test_round_phases_tile_busy_rounds(aiohttp_client, tmp_path,
+                                             kv_cache, floor):
+    """(a) The ten phases tile a busy round and never overlap on one thread;
+    (b) their counts are the scheduler's own dispatch counters."""
+    cfg = _gen_cfg(tmp_path, kv_cache)
+    engine = build_engine(cfg)
+    try:
+        client = await aiohttp_client(create_app(cfg, engine=engine))
+        await _generate(client, 1, max_new=4)  # compiles, outside the count
+        before = await _gen_counters(client)
+        await _generate(client, 2 * _GEN_SLOTS)
+        after = await _gen_counters(client)
+        r = await client.get("/admin/trace?rounds=256&model=gpt2")
+        rounds = (await r.json())["rounds"]["gpt2"]
+    finally:
+        engine.shutdown()
+
+    def delta(phase):
+        return (after["host_phases"][phase]["count"]
+                - before["host_phases"][phase]["count"])
+
+    assert delta("segment.launch") == delta("segment.fetch") \
+        == after["segment_rounds"] - before["segment_rounds"] > 0
+    dispatches = "prefill_dispatches" if kv_cache == "slot" \
+        else "device_rounds"
+    prefills = delta("prefill.launch")
+    assert prefills == delta("prefill.fetch") > 0
+    if kv_cache == "slot":
+        assert prefills == after[dispatches] - before[dispatches]
+    else:  # every device round is a chunk or a segment here
+        assert prefills + delta("segment.launch") \
+            == after[dispatches] - before[dispatches]
+    assert delta("round.lane_wait") == delta("round.wakeup") \
+        == prefills + delta("segment.launch")
+    assert sum(v["count"] for v in after["lane_wait"].values()) \
+        == after["host_phases"]["round.lane_wait"]["count"]
+
+    wall = covered = 0
+    busy = 0
+    for this, nxt in zip(rounds, rounds[1:]):
+        phases = this["phases"]
+        names = [p["phase"] for p in phases]
+        if "round.idle" in names or "segment.launch" not in names:
+            continue  # the lane went quiet in this round
+        t0 = next(p["t0_ns"] for p in phases
+                  if p["phase"] == "round.admit_host")
+        t1 = next(p["t0_ns"] for p in nxt["phases"]
+                  if p["phase"] == "round.admit_host")
+        for on_dispatch in (True, False):
+            line = sorted((p["t0_ns"], p["t1_ns"]) for p in phases
+                          if p["phase"].startswith(_DISPATCH_PHASES)
+                          == on_dispatch)
+            for (_, end), (start, _) in zip(line, line[1:]):
+                assert start >= end, (this["round"], line)
+        assert all(t0 <= p["t0_ns"] and p["t1_ns"] <= t1 for p in phases)
+        wall += t1 - t0
+        covered += sum(p["t1_ns"] - p["t0_ns"] for p in phases)
+        busy += 1
+    assert busy >= 3, [r["round"] for r in rounds]
+    assert covered / wall >= floor, (covered / wall, busy)
+
+
+@pytest.mark.parametrize("kv_cache", ["slot", "paged"])
+async def test_request_stamps_tile_time_to_first_token(aiohttp_client,
+                                                       tmp_path, kv_cache):
+    """(c) round_wait + slot_wait + prefill + first_emit is the server's
+    whole time to the first token, and only the requests beyond the slot
+    count waited for a slot; (d) ``queue`` ends where ``prefill`` starts."""
+    cfg = _gen_cfg(tmp_path, kv_cache)
+    engine = build_engine(cfg)
+    try:
+        server = Server(cfg, engine=engine)
+        client = await aiohttp_client(server.app)
+        await _generate(client, 1, max_new=4)
+        sched = server.schedulers["gpt2"]
+        seen = []
+        submit = sched.submit
+
+        def spy(*a, **kw):
+            seen.append(submit(*a, **kw))
+            return seen[-1]
+
+        sched.submit = spy
+        out = await _generate(client, 2 * _GEN_SLOTS)
+        trees = [(await (await client.get(f"/admin/trace/{tid}")).json())
+                 ["trace"]["tree"] for tid, _ in out]
+    finally:
+        engine.shutdown()
+    assert len(seen) == 2 * _GEN_SLOTS
+    for req in seen:
+        st = req.timing_stats()
+        legs = (st["round_wait_ms"] + st["slot_wait_ms"] + st["prefill_ms"]
+                + st["first_emit_ms"])
+        assert abs(legs - (req.first_token_at - req.submitted) * 1e3) < 1.0
+        assert min(st.values()) >= 0.0, st
+        assert st["ingest_ms"] > 0 and st["egress_ms"] > 0
+    for _, stats in out:  # the done event carries the same six legs
+        assert {"ingest_ms", "round_wait_ms", "slot_wait_ms", "prefill_ms",
+                "first_emit_ms", "egress_ms"} <= set(stats), stats
+    # Submitted together, they are seen at one loop top: the first
+    # _GEN_SLOTS get a slot there, the others wait rounds for one.
+    waits = sorted(req.timing_stats()["slot_wait_ms"] for req in seen)
+    assert waits[:_GEN_SLOTS] == [0.0] * _GEN_SLOTS, waits
+    assert all(w > 0 for w in waits[_GEN_SLOTS:]), waits
+    for tree in trees:
+        kids = {c["name"]: c for c in tree["children"]}
+        queue, prefill = kids["queue"], kids["prefill"]
+        assert abs(queue["start_ms"] + queue["duration_ms"]
+                   - prefill["start_ms"]) < 0.01, (queue, prefill)
+        assert prefill["attrs"]["round"] >= 1
+        assert "decode" in kids
 
 
 async def test_admin_profile_capture(served):

@@ -140,7 +140,7 @@ def render(payload: dict, bar_width: int = BAR_WIDTH) -> str:
         attrs = node.get("attrs") or {}
         keys = [k for k in ("batch_size", "batch_mates", "attempt", "lane",
                             "tokens", "error", "shed", "variant", "adapter",
-                            "slot", "waited_ms", "cached_tokens",
+                            "slot", "round", "waited_ms", "cached_tokens",
                             "cow_copies", "prefix_cached", "chunk",
                             "degraded", "bytes", "instances") if k in attrs]
         if keys:
