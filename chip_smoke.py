@@ -26,8 +26,14 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
              weights; ``:predict`` / ``:generate`` requests, ``/healthz``,
              ``/metrics``, SIGINT.
 - *restart*  the same config again: the boot must hit the compile cache.
-- *kernels*  ``int8_matmul`` and ``flash_attention`` with ``interpret=False``
-             at real shapes against ``jax.numpy`` references, on the chip.
+- *kernels*  ``int8_matmul``, ``flash_attention`` and ``decode_attention``
+             with ``interpret=False`` at real shapes against ``jax.numpy``
+             references, on the chip.
+- *segment*  the decode segment program at GPT-2 XL's serving shape (8 slots
+             of 960 positions, 48 layers, compiled from shapes alone): its
+             optimised HLO must hold no ``copy``, ``slice`` or ``transpose``
+             whose result is as large as one layer of the slot pool.  On the
+             chip only; ``--rehearse`` prints what the CPU's compiler made.
 - *sd15*     Stable Diffusion 1.5 at 512x512, two steps, one ``:submit``
              polled to ``done`` (flash attention's only serving caller).
 """
@@ -545,6 +551,8 @@ def _kernels_child(rehearse: bool) -> None:
     import numpy as np
 
     from pytorch_zappa_serverless_tpu.ops import hostops
+    from pytorch_zappa_serverless_tpu.ops.decode_attention import (
+        decode_attention)
     from pytorch_zappa_serverless_tpu.ops.flash_attention import (
         flash_attention)
     from pytorch_zappa_serverless_tpu.ops.int8_matmul import (
@@ -592,10 +600,142 @@ def _kernels_child(rehearse: bool) -> None:
                                    np.asarray(want), rtol=3e-2, atol=3e-2)
         print(f"flash_attention q[{b},{tq},{h},{d}] kv {tk} causal={causal} "
               "matches its reference")
+    # Decode attention over a slot pool [L, S, T, D]: the benchmark's two
+    # serving shapes, slots at 0, mid-block, a block edge and the last row.
+    da = ([(2, 4, 32, 128, 2)] if rehearse else
+          [(2, 8, 960, 1600, 25), (2, 16, 960, 1280, 20)])
+    for layers, slots, total, d, heads in da:
+        q = jnp.asarray(rng.standard_normal((slots, d)), jnp.bfloat16)
+        ck, cv = (jnp.asarray(rng.standard_normal((layers, slots, total, d)),
+                              jnp.bfloat16) for _ in range(2))
+        wpos = jnp.asarray(([0, 5, total // 4 - 1, total // 4, total - 1]
+                            * 4)[:slots], jnp.int32)
+        dh = d // heads
+        got = decode_attention(q * dh ** -0.5, ck, cv, wpos, layer=1,
+                               heads=heads, interpret=interpret)
+        bias = jnp.where(jnp.arange(total)[None, :] <= wpos[:, None], 0.0,
+                         -1e9)[:, None, None, :]
+        want = reference(*(a.reshape(slots, -1, heads, dh)
+                           for a in (q[:, None], ck[1], cv[1])), bias)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want).reshape(slots, d),
+                                   rtol=3e-2, atol=3e-2)
+        print(f"decode_attention pool[{layers},{slots},{total},{d}] "
+              f"{heads} heads matches its reference")
     print("preprocess path: "
           + ("native (hostops.cpp built with g++)" if hostops.native_available()
              else "PIL (no native library: no compiler here)"))
-    print(json.dumps({"int8_matmul": len(mm), "flash_attention": len(fa)}))
+    print(json.dumps({"int8_matmul": len(mm), "flash_attention": len(fa),
+                      "decode_attention": len(da)}))
+
+
+_MOVES = ("copy", "slice", "dynamic-slice", "transpose")
+
+
+def pool_sized_moves(hlo_text: str, elements: int) -> list[tuple[int, str]]:
+    """The instructions of an optimised HLO module that materialise a copy,
+    a slice or a transposition of at least ``elements`` elements, largest
+    first, as ``(elements, "computation: instruction = shape op")``.
+
+    Counted: ``copy``, ``slice``, ``dynamic-slice`` and ``transpose`` (their
+    asynchronous ``-start`` / ``-done`` halves too) outside fused
+    computations, and fusions the compiler named after one of them
+    (``slice_bitcast_fusion``, ``copy_fusion``).  Not counted: the same
+    operations inside a fusion, where they are the consumer's addressing and
+    write nothing, and the in-place scatter that adds a step's row to the
+    pool (its result has the pool's shape and the pool's buffer).
+    """
+    import re
+
+    fused = set(re.findall(r"\bcalls=%?([\w.\-]+)", hlo_text))
+    head = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+    inst = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = "
+                      r"\(?([a-z]+[0-9]*)\[([0-9,]*)\]\S* ([\w\-]+)\(")
+    found, comp = [], ""
+    for line in hlo_text.splitlines():
+        m = head.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = inst.match(line)
+        if not m or comp in fused:
+            continue
+        name, dtype, dims, op = m.groups()
+        base = re.sub(r"-(start|done)$", "", op)
+        named = op == "fusion" and any(
+            part in _MOVES for part in re.split(r"[_.]", name))
+        if base not in _MOVES and not named:
+            continue
+        n = 1
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        if n >= elements:
+            found.append((n, f"{comp}: {name} = {dtype}[{dims}] {op}"))
+    return sorted(found, reverse=True)
+
+
+def _segment_child(rehearse: bool) -> None:
+    """Compile the decode segment at GPT-2 XL's serving shape, from shapes
+    alone (nothing is allocated), and look through its optimised HLO for a
+    layer of the pool being moved (:func:`pool_sized_moves`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_zappa_serverless_tpu.models import gpt2
+
+    if rehearse:
+        cfg = gpt2.GPT2Config(**TINY_GPT2)
+        slots, total = 4, 32
+    else:
+        cfg = gpt2.GPT2Config(d_model=1600, layers=48, heads=25,
+                              ffn_dim=6400)
+        slots, total = 8, 960
+    D, F, bf = cfg.d_model, cfg.ffn_dim, jnp.bfloat16
+
+    def sd(*shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def dense(i, o):
+        return {"kernel": sd(i, o), "bias": sd(o)}
+
+    def ln():
+        return {"scale": sd(D), "bias": sd(D)}
+
+    params = {"wte": sd(cfg.vocab_size, D), "wpe": sd(cfg.max_positions, D),
+              "ln_f": ln()}
+    for i in range(cfg.layers):
+        params[f"layer{i}"] = {
+            "ln1": ln(), "ln2": ln(), "q": dense(D, D), "k": dense(D, D),
+            "v": dense(D, D), "out": dense(D, D), "fc1": dense(D, F),
+            "fc2": dense(F, D)}
+    pool = sd(cfg.layers, slots, total, D)
+    i32, f32 = sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.float32)
+    segment = jax.jit(
+        lambda p, ck, cv, tok, pos, st, fin, temp, seeds, topk, topp:
+        gpt2.decode_segment(p, ck, cv, tok, pos, st, fin, temp, seeds, 8,
+                            cfg, bf, top_k=topk, top_p=topp),
+        donate_argnums=(1, 2))
+    text = segment.lower(params, pool, pool, i32, i32, i32,
+                         sd(slots, dtype=jnp.bool_), f32, i32, i32,
+                         f32).compile().as_text()
+    layer = slots * total * D
+    # What is there at all: the largest such move of a tenth of a layer up.
+    moves = pool_sized_moves(text, layer // 10)
+    print(f"segment [{cfg.layers} layers, {slots} slots x {total} x {D}]: "
+          f"one layer of the pool is {layer} elements; largest copy, slice "
+          "or transpose outside a fusion: "
+          + (f"{moves[0][0]} elements ({moves[0][1]})" if moves else
+             f"none of {layer // 10} elements or more"))
+    # Of the pool: whole rows of ``total x D`` (the embedding table, which
+    # the compiler lays out anew once a segment, is larger and is not).
+    whole = [m for m in moves if m[0] >= layer and m[0] % (total * D) == 0]
+    if not rehearse:
+        assert not whole, (
+            f"the segment program moves a whole layer of the pool "
+            f"{len(whole)} times a step:\n  "
+            + "\n  ".join(desc for _, desc in whole[:6]))
+    print(json.dumps({"pool_sized_moves": len(whole),
+                      "decode_kernel": "decode_attention" in text}))
 
 
 def _multichip_child(rehearse: bool) -> None:
@@ -745,8 +885,17 @@ def main(argv=None) -> int:
             run_child(f"import chip_smoke; "
                       f"chip_smoke._kernels_child({args.rehearse})",
                       args.rehearse, "kernels.log", timeout=600.0)
-            say("kernels: int8_matmul and flash_attention match their "
-                "references on the device")
+            say("kernels: int8_matmul, flash_attention and decode_attention "
+                "match their references on the device")
+            seg = run_child(f"import chip_smoke; "
+                            f"chip_smoke._segment_child({args.rehearse})",
+                            args.rehearse, "segment.log", timeout=900.0)
+            check(args.rehearse or seg["decode_kernel"],
+                  "the segment program compiled for the chip holds no "
+                  "decode_attention kernel")
+            say("segment: no copy, slice or transpose of a layer of the "
+                "slot pool in the compiled decode segment"
+                + (" (not asserted on the CPU)" if args.rehearse else ""))
             phase_sd15(sd15_cfg, probe, args.rehearse)
     except SmokeFailure as e:
         print(f"[smoke] FAIL after {time.monotonic() - t0:.0f}s: {e}",

@@ -106,8 +106,10 @@ def _split_heads(x, heads):
     return x.reshape(B, T, heads, D // heads)
 
 
-def _attn(q, k, v, mask_bias):
-    """q [B,Tq,H,Dh], k/v [B,Tk,H,Dh], mask_bias [B,1,Tq,Tk] → [B,Tq,H*Dh]."""
+def _attn(q, k, v, mask_bias, heads):
+    """Prefill attention, heads split out: q [B,Tq,D], k/v [B,Tk,D],
+    mask_bias [B,1,Tq,Tk] → [B,Tq,D]."""
+    q, k, v = (_split_heads(a, heads) for a in (q, k, v))
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k).astype(jnp.float32)
     probs = jax.nn.softmax(scores + mask_bias, axis=-1).astype(q.dtype)
@@ -116,14 +118,74 @@ def _attn(q, k, v, mask_bias):
     return out.reshape(B, Tq, -1)
 
 
-def _layer(p, x, mask_bias, cfg, write_kv, lora=None, lora_idx=None):
+def _attn_decode(q, cache_k, cache_v, layer, wpos, heads):
+    """Decode attention over one layer of the pool, read where it lies.
+
+    q [S, Tq, D] (a slot's one query, or the K+1 of a speculative verify),
+    cache_k / cache_v [L, S, T, D] the whole pool in its own layout (``D``
+    minor, no head split) and ``layer`` which of it to read, wpos [S, Tq]
+    the last position each query may read → [S, Tq, D].
+
+    :func:`_attn` makes ``(slot, head)`` batch dimensions, and a pool whose
+    heads lie side by side in ``D`` then has to be sliced out and moved to a
+    heads-major layout, K and V, every layer of every step: that copy was
+    three quarters of the decode step on the chip (PERF.md section 6, PR
+    26).  Here ``slot`` is the only batch dimension and the contraction
+    runs over all of ``D``: head ``h``'s query sits in its own 64 columns of
+    an ``[H, D]`` block with zeros elsewhere, so row ``h`` of
+    ``q_heads @ K^T`` is head ``h``'s scores and row ``h`` of ``probs @ V``
+    carries head ``h``'s output in those same columns.  ``H`` times the
+    multiply-adds of the head-split form, on a step bound by the bytes of
+    the pool.  Scores and softmax in float32, probabilities and values in
+    ``q``'s dtype; a position beyond ``wpos`` weighs exactly zero whatever
+    the row holds there.
+
+    On one TPU chip one query a slot goes to the Pallas kernel of the same
+    contraction (ops/decode_attention.py), which takes its blocks out of the
+    pool by index and stops at each slot's last written one; everything else
+    runs the ``jax.numpy`` form below, which reads all ``T`` positions: the
+    CPU, several queries a slot, and a process that addresses several
+    devices (a mesh: a Mosaic kernel is not partitioned automatically, and
+    the partitioner splits the einsums over ``D`` as it did the heads).
+    """
+    S, Tq, D = q.shape
+    dh = D // heads
+    q = q * dh ** -0.5
+    if (Tq == 1 and jax.default_backend() == "tpu"
+            and jax.device_count() == 1):
+        from ..ops.decode_attention import decode_attention, pick_block_t
+
+        # A pool length that no block of at most 512 positions divides
+        # would make one block a slot, too large for the kernel's VMEM.
+        if pick_block_t(cache_k.shape[2]) <= 512:
+            return decode_attention(q[:, 0], cache_k, cache_v, wpos[:, 0],
+                                    layer=layer, heads=heads)[:, None]
+    cache_k, cache_v = cache_k[layer], cache_v[layer]
+    T = cache_k.shape[1]
+    own = (jnp.arange(D) // dh)[None, :] == jnp.arange(heads)[:, None]
+    qh = jnp.where(own, q[:, :, None, :], 0)                   # [S,Tq,H,D]
+    scores = jnp.einsum("smd,std->smt", qh.reshape(S, Tq * heads, D),
+                        cache_k, preferred_element_type=jnp.float32)
+    keep = jnp.arange(T)[None, None, :] <= wpos[:, :, None]     # [S,Tq,T]
+    scores = jnp.where(keep[:, :, None, :],
+                       scores.reshape(S, Tq, heads, T), -1e9)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("smt,std->smd", probs.reshape(S, Tq * heads, T),
+                     cache_v, preferred_element_type=jnp.float32)
+    # Each head keeps its own columns: one non-zero term a column, so exact.
+    return jnp.where(own, out.reshape(S, Tq, heads, D),
+                     0).sum(2).astype(q.dtype)
+
+
+def _layer(p, x, cfg, attend, lora=None, lora_idx=None):
     """One transformer block: pre-LN attn + MLP, shared by prefill and decode.
 
-    ``write_kv(k, v)`` receives this block's fresh key/value projections
-    (computed from the same ``ln1`` activations as q), stores them however
-    the caller caches, and returns the head-split K/V the attention should
-    run against (full-sequence at prefill, the running cache at decode) —
-    the single point where the two phases differ.
+    ``attend(q, k, v)`` receives this block's fresh query/key/value
+    projections ([B, Tq, D], all from the same ``ln1`` activations), stores
+    K/V however the caller caches, and returns the attention output
+    [B, Tq, D] — the single point where the two phases differ: prefill
+    runs :func:`_attn` over the prompt's own K/V, decode runs
+    :func:`_attn_decode` over its layer of the running cache.
 
     ``lora``/``lora_idx`` (docs/ADAPTERS.md): this layer's stacked
     multi-tenant adapter factors and the per-row slot indices; each dense
@@ -147,9 +209,7 @@ def _layer(p, x, mask_bias, cfg, write_kv, lora=None, lora_idx=None):
     else:
         k_, v_ = ad("k", _dense(p["k"], h), h), ad("v", _dense(p["v"], h), h)
         q_ = ad("q", _dense(p["q"], h), h)
-    k_heads, v_heads = write_kv(k_, v_)
-    q = _split_heads(q_, cfg.heads)
-    ao = _attn(q, k_heads, v_heads, mask_bias)
+    ao = attend(q_, k_, v_)
     x = x + ad("out", _dense(p["out"], ao), ao)
     h = _ln(p["ln2"], x, cfg.ln_eps)
     h2 = jax.nn.gelu(ad("fc1", _dense(p["fc1"], h), h), approximate=True)
@@ -218,13 +278,13 @@ def prefill(params: dict, tokens: jax.Array, lengths: jax.Array,
     cache_k = jnp.zeros((cfg.layers, B, total, cfg.d_model), dtype)
     cache_v = jnp.zeros((cfg.layers, B, total, cfg.d_model), dtype)
     for i in range(cfg.layers):
-        def write_kv(k, v, i=i):
+        def attend(q, k, v, i=i):
             nonlocal cache_k, cache_v
             cache_k = cache_k.at[i, :, :P].set(k)
             cache_v = cache_v.at[i, :, :P].set(v)
-            return _split_heads(k, cfg.heads), _split_heads(v, cfg.heads)
+            return _attn(q, k, v, mask_bias, cfg.heads)
 
-        x = _layer(params[f"layer{i}"], x, mask_bias, cfg, write_kv,
+        x = _layer(params[f"layer{i}"], x, cfg, attend,
                    lora=_lora_of(params, i, adapter_idx),
                    lora_idx=adapter_idx)
     x = _ln(params["ln_f"], x, cfg.ln_eps)
@@ -346,7 +406,9 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     sampling-step counter (keeps fold_in(seed, t) aligned with the batched
     path), ``finished`` pins retired/empty slots — they still compute (the
     price of static shapes) but their ``pos`` freezes so they only overwrite
-    their own dead cache row.
+    their own dead cache row, and they attend to its first position alone.
+    Attention reads each layer of the pool where it lies, as far as each
+    slot has written (:func:`_attn_decode`).
 
     Returns (emits [S, seg], cache_k, cache_v, tok, pos, step, finished).
     Step t emits the token decided before it, exactly like :func:`generate`,
@@ -354,7 +416,6 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     """
     S = tok.shape[0]
     total = cache_k.shape[2]
-    kpos = jnp.arange(total)
     rows = jnp.arange(S)
     # Repetition penalty (fixed-batch lane only — the streaming lane's
     # slot pool would need a [S, V] presence buffer donated across
@@ -376,20 +437,21 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
             cache_k, cache_v, tok, pos, t, finished = carry
             pres = None
         wpos = jnp.minimum(pos, total - 1)
+        # A finished slot's token is pinned to EOS whatever it attends to:
+        # it reads its row's first position and no further.
+        last = jnp.where(finished, 0, wpos)
         x = (params["wte"].astype(dtype)[tok]
              + params["wpe"].astype(dtype)[jnp.minimum(wpos, cfg.max_positions - 1)]
              )[:, None, :]
-        mask_bias = jnp.where(kpos[None, :] <= wpos[:, None], 0.0,
-                              -1e9).astype(jnp.float32)[:, None, None, :]
         for i in range(cfg.layers):
-            def write_kv(k, v, i=i):
+            def attend(q, k, v, i=i):
                 nonlocal cache_k, cache_v
                 cache_k = cache_k.at[i, rows, wpos].set(k[:, 0])
                 cache_v = cache_v.at[i, rows, wpos].set(v[:, 0])
-                return (_split_heads(cache_k[i], cfg.heads),
-                        _split_heads(cache_v[i], cfg.heads))
+                return _attn_decode(q, cache_k, cache_v, i, last[:, None],
+                                    cfg.heads)
 
-            x = _layer(params[f"layer{i}"], x, mask_bias, cfg, write_kv,
+            x = _layer(params[f"layer{i}"], x, cfg, attend,
                        lora=_lora_of(params, i, adapter_idx),
                        lora_idx=adapter_idx)
         x = _ln(params["ln_f"], x, cfg.ln_eps)
@@ -441,11 +503,11 @@ def _paged_write(cache, layer, table, wpos, values, block_size):
     return cache.at[layer, bidx, off].set(values)
 
 
-def _paged_view(cache, layer, table, heads):
-    """One layer's virtual cache [S, MB*BS, D], head-split for attention."""
+def _paged_view(cache, layer, table):
+    """One layer's virtual cache [S, MB*BS, D], gathered through the table."""
     from ..ops.paged_attention import gather_kv
 
-    return _split_heads(gather_kv(cache[layer], table), heads)
+    return gather_kv(cache[layer], table)
 
 
 def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
@@ -485,14 +547,15 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
             & (kpos[None, None, :] < lengths[:, None, None]))
     mask_bias = jnp.where(keep, 0.0, -1e9).astype(jnp.float32)[:, None]
     for i in range(cfg.layers):
-        def write_kv(k, v, i=i):
+        def attend(q, k, v, i=i):
             nonlocal cache_k, cache_v
             cache_k = _paged_write(cache_k, i, table, wpos, k, block_size)
             cache_v = _paged_write(cache_v, i, table, wpos, v, block_size)
-            return (_paged_view(cache_k, i, table, cfg.heads),
-                    _paged_view(cache_v, i, table, cfg.heads))
+            return _attn(q, _paged_view(cache_k, i, table),
+                         _paged_view(cache_v, i, table), mask_bias,
+                         cfg.heads)
 
-        x = _layer(params[f"layer{i}"], x, mask_bias, cfg, write_kv,
+        x = _layer(params[f"layer{i}"], x, cfg, attend,
                    lora=_lora_of(params, i, adapter_idx),
                    lora_idx=adapter_idx)
     x = _ln(params["ln_f"], x, cfg.ln_eps)
@@ -516,27 +579,26 @@ def decode_segment_paged(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     so their frozen-position writes land in the shared trash page."""
     S = tok.shape[0]
     VT = table.shape[1] * block_size
-    kpos = jnp.arange(VT)
 
     def sstep(carry, _):
         cache_k, cache_v, tok, pos, t, finished = carry
         wpos = jnp.minimum(pos, VT - 1)
+        last = jnp.where(finished, 0, wpos)  # as decode_segment's
         x = (params["wte"].astype(dtype)[tok]
              + params["wpe"].astype(dtype)[
                  jnp.minimum(wpos, cfg.max_positions - 1)])[:, None, :]
-        mask_bias = jnp.where(kpos[None, :] <= wpos[:, None], 0.0,
-                              -1e9).astype(jnp.float32)[:, None, None, :]
         for i in range(cfg.layers):
-            def write_kv(k, v, i=i):
+            def attend(q, k, v, i=i):
                 nonlocal cache_k, cache_v
                 cache_k = _paged_write(cache_k, i, table, wpos[:, None],
                                        k, block_size)
                 cache_v = _paged_write(cache_v, i, table, wpos[:, None],
                                        v, block_size)
-                return (_paged_view(cache_k, i, table, cfg.heads),
-                        _paged_view(cache_v, i, table, cfg.heads))
+                return _attn_decode(q, _paged_view(cache_k, i, table)[None],
+                                    _paged_view(cache_v, i, table)[None],
+                                    0, last[:, None], cfg.heads)
 
-            x = _layer(params[f"layer{i}"], x, mask_bias, cfg, write_kv,
+            x = _layer(params[f"layer{i}"], x, cfg, attend,
                        lora=_lora_of(params, i, adapter_idx),
                        lora_idx=adapter_idx)
         x = _ln(params["ln_f"], x, cfg.ln_eps)
@@ -582,7 +644,6 @@ def propose_paged(params: dict, cache_k: jax.Array, cache_v: jax.Array,
 
     S = tok.shape[0]
     VT = table.shape[1] * block_size
-    kpos = jnp.arange(VT)
     draft_seeds = jnp.bitwise_xor(seeds, jnp.int32(DRAFT_SEED_SALT))
 
     def sstep(carry, _):
@@ -591,19 +652,18 @@ def propose_paged(params: dict, cache_k: jax.Array, cache_v: jax.Array,
         x = (params["wte"].astype(dtype)[cur]
              + params["wpe"].astype(dtype)[
                  jnp.minimum(wpos, cfg.max_positions - 1)])[:, None, :]
-        mask_bias = jnp.where(kpos[None, :] <= wpos[:, None], 0.0,
-                              -1e9).astype(jnp.float32)[:, None, None, :]
         for i in range(cfg.layers):
-            def write_kv(k_, v_, i=i):
+            def attend(q, k_, v_, i=i):
                 nonlocal cache_k, cache_v
                 cache_k = _paged_write(cache_k, i, table, wpos[:, None],
                                        k_, block_size)
                 cache_v = _paged_write(cache_v, i, table, wpos[:, None],
                                        v_, block_size)
-                return (_paged_view(cache_k, i, table, cfg.heads),
-                        _paged_view(cache_v, i, table, cfg.heads))
+                return _attn_decode(q, _paged_view(cache_k, i, table)[None],
+                                    _paged_view(cache_v, i, table)[None],
+                                    0, wpos[:, None], cfg.heads)
 
-            x = _layer(params[f"layer{i}"], x, mask_bias, cfg, write_kv)
+            x = _layer(params[f"layer{i}"], x, cfg, attend)
         x = _ln(params["ln_f"], x, cfg.ln_eps)
         logits = _logits(params, x[:, 0])
         nxt = _choose(logits, temperature, draft_seeds, t + 1, top_k, top_p)
@@ -649,18 +709,16 @@ def verify_paged(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     x = (params["wte"].astype(dtype)[toks]
          + params["wpe"].astype(dtype)[jnp.minimum(wp,
                                                    cfg.max_positions - 1)])
-    kpos = jnp.arange(VT)
-    mask_bias = jnp.where(kpos[None, None, :] <= wp[:, :, None], 0.0,
-                          -1e9).astype(jnp.float32)[:, None]
     for i in range(cfg.layers):
-        def write_kv(k, v, i=i):
+        def attend(q, k, v, i=i):
             nonlocal cache_k, cache_v
             cache_k = _paged_write(cache_k, i, table, wp, k, block_size)
             cache_v = _paged_write(cache_v, i, table, wp, v, block_size)
-            return (_paged_view(cache_k, i, table, cfg.heads),
-                    _paged_view(cache_v, i, table, cfg.heads))
+            return _attn_decode(q, _paged_view(cache_k, i, table)[None],
+                                _paged_view(cache_v, i, table)[None], 0, wp,
+                                cfg.heads)
 
-        x = _layer(params[f"layer{i}"], x, mask_bias, cfg, write_kv)
+        x = _layer(params[f"layer{i}"], x, cfg, attend)
     x = _ln(params["ln_f"], x, cfg.ln_eps)
     D = x.shape[-1]
     logits = _logits(params, x.reshape(S * K1, D)).reshape(S, K1, -1)

@@ -425,6 +425,11 @@ class GenerationScheduler:
         # int increments from the dispatch thread, read by the loop task.
         self.device_rounds = 0   # guarded-by: dispatch-serialized
         self.segment_rounds = 0  # guarded-by: dispatch-serialized
+        # How much of the pool a segment's attention has to read: per round,
+        # the positions of the slots still generating over slots x total
+        # (decode attention stops at a slot's last written position and
+        # reads one block of a finished slot: ops/decode_attention.py).
+        self.kv_live_sum = 0.0   # guarded-by: dispatch-serialized
         # Per-token timing (docs/OBSERVABILITY.md §9): streamed-token count
         # for the perf plane's rolling tok/s gauge, plus the split
         # first-token / inter-token histograms (the two move for different
@@ -533,6 +538,9 @@ class GenerationScheduler:
                                 "step": self._step, "fin": self._finished,
                                 "temp": self._temp, "seed": self._seed,
                                 "topk": self._topk, "topp": self._topp})
+            self.kv_live_sum += float(
+                np.minimum(self._pos[~self._finished] + 1, self.total).sum()
+            ) / (self.slots * self.total)
             emits, self._cache_k, self._cache_v, tok, pos, step, fin = \
                 self._segment(
                     self.params, self._cache_k, self._cache_v,
@@ -616,6 +624,8 @@ class GenerationScheduler:
                 "segment_rounds": self.segment_rounds,
                 "prefill_dispatches": self.prefill_dispatches,
                 "tokens_emitted": self.tokens_emitted,
+                "kv_live_share": {"sum": round(self.kv_live_sum, 6),
+                                  "count": self.segment_rounds},
                 "latency": {"ttft_ms": self.ttft_hist.snapshot(),
                             "itl_ms": self.itl_hist.snapshot()},
                 "host_phases": self.timeline.snapshot(),

@@ -1759,14 +1759,19 @@ class Server:
             try:
                 await asyncio.sleep(seconds)
             finally:
+                t_stop = _time.monotonic()
                 await loop.run_in_executor(None, jax.profiler.stop_trace)
+                stop_s = _time.monotonic() - t_stop
         finally:
             self._tracing = False
 
         def classify():
-            from ..utils.xplane import attribute_idle, op_time_breakdown
+            from ..utils.xplane import (attribute_idle, op_time_breakdown,
+                                        read_capture)
 
-            compute, counts, overlap, envelope = op_time_breakdown(out_dir)
+            capture = read_capture(out_dir)  # a million events, read once
+            compute, counts, overlap, envelope = op_time_breakdown(
+                out_dir, capture)
             ops = [{"op": fam, "ms": round(ns / 1e6, 3),
                     "count": counts.get(fam, 0)}
                    for fam, ns in compute.most_common(max(top, 1))]
@@ -1774,14 +1779,17 @@ class Server:
                     "device_compute_ms": round(sum(compute.values()) / 1e6, 3),
                     "overlap_ms": round(sum(overlap.values()) / 1e6, 3),
                     "envelope_ms": round(sum(envelope.values()) / 1e6, 3),
-                    **attribute_idle(out_dir)}
+                    **attribute_idle(out_dir, capture)}
 
         # A capture with no device plane (the CPU backend) classifies to
         # zero ops, an empty ``idle`` and no ``programs``; the answer still
         # carries the capture location.
+        t_classify = _time.monotonic()
         breakdown = await loop.run_in_executor(None, classify)
         log_event(log, "profile captured", dir=str(out_dir), seconds=seconds,
-                  ops=len(breakdown.get("ops", [])))
+                  ops=len(breakdown.get("ops", [])),
+                  stop_trace_s=round(stop_s, 2),
+                  classify_s=round(_time.monotonic() - t_classify, 2))
         return web.json_response({"dir": str(out_dir), "seconds": seconds,
                                   **breakdown})
 

@@ -49,16 +49,32 @@ def _family(op_name: str) -> str:
     return re.sub(r"[.\d]+$", "", op_name.split(" = ")[0].lstrip("%"))
 
 
-def op_time_breakdown(trace_dir):
+def op_time_breakdown(trace_dir, capture=None):
     """Aggregate a capture into (compute_ns, counts, overlap_ns,
     envelope_ns) Counters keyed by op family (HLO instruction name sans
-    %/trailing indices)."""
+    %/trailing indices).  ``capture`` is what :func:`read_capture` made of
+    the same directory: a capture with TPU planes is then not read again."""
     from jax.profiler import ProfileData
 
     compute: collections.Counter = collections.Counter()
     counts: collections.Counter = collections.Counter()
     overlap: collections.Counter = collections.Counter()
     envelope: collections.Counter = collections.Counter()
+    if capture is not None and capture[0]:
+        for plane in capture[0]:
+            for fam, ns in plane["async"]:
+                overlap[fam] += ns
+            for start, end, fam, is_op in plane["ops"]:
+                if not is_op:
+                    continue
+                if _ASYNC_NAME.search(fam):
+                    overlap[fam] += end - start
+                elif fam in _ENVELOPE:
+                    envelope[fam] += end - start
+                else:
+                    compute[fam] += end - start
+                    counts[fam] += 1
+        return compute, counts, overlap, envelope
     for pb in sorted(Path(trace_dir).rglob("*.xplane.pb")):
         for plane in ProfileData.from_file(str(pb)).planes:
             is_tpu = "TPU" in plane.name
@@ -101,17 +117,54 @@ _DISPATCH = ("prefill.", "insert.", "segment.")
 IN_PROGRAM = "in_program"  # idle between the operations of one program run
 
 
-def _read_capture(trace_dir):
-    """-> (device planes as {line name: line}, host annotations as
-    ``(start_ns, end_ns, phase, programs)`` sorted by start)."""
+def read_capture(trace_dir):
+    """One pass over a capture -> (device planes, host annotations).
+
+    A device plane (one that has an ``XLA Ops`` line) becomes ``{"ops":
+    [(start_ns, end_ns, family, is_op)] sorted by start, "async": [(family,
+    ns)] of its ``Async XLA Ops`` line, "mods": [(start_ns, end_ns, module
+    name)] sorted}``; ``is_op`` is false for the module and step envelopes
+    on the line (``jit_*``, no `` = ``), which count as busy time and not as
+    operations.  Host annotations are ``(start_ns, end_ns, phase,
+    programs)`` sorted by start.  A capture holds a million device events
+    with a few hundred names: each event is touched once, here, for both
+    reductions of ``POST /admin/profile``."""
     from jax.profiler import ProfileData
 
     device, host = [], []
+    families: dict[str, tuple[str, bool]] = {}
+
+    def family(name: str) -> tuple[str, bool]:
+        known = families.get(name)
+        if known is None:
+            known = families[name] = (
+                _family(name),
+                not name.startswith("jit_") and " = " in name)
+        return known
+
     for pb in sorted(Path(trace_dir).rglob("*.xplane.pb")):
         for plane in ProfileData.from_file(str(pb)).planes:
             lines = {line.name: line for line in plane.lines}
             if "XLA Ops" in lines:
-                device.append(lines)
+                ops = []
+                for ev in lines["XLA Ops"].events:
+                    start = int(ev.start_ns)
+                    ops.append((start, start + int(ev.duration_ns),
+                                *family(ev.name)))
+                ops.sort()
+                overlapped = []
+                if "Async XLA Ops" in lines:
+                    for ev in lines["Async XLA Ops"].events:
+                        fam, is_op = family(ev.name)
+                        if is_op:
+                            overlapped.append((fam, int(ev.duration_ns)))
+                mods = sorted(
+                    (int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
+                     ev.name.split("(")[0])
+                    for ev in lines["XLA Modules"].events) \
+                    if "XLA Modules" in lines else []
+                device.append({"ops": ops, "async": overlapped,
+                               "mods": mods})
                 continue
             for line in plane.lines:
                 for ev in line.events:
@@ -217,12 +270,13 @@ def _clock_check(runs: list[dict]) -> tuple[int, dict]:
         "device_early_ms": round(early / 1e6, 3)}
 
 
-def attribute_idle(trace_dir) -> dict:
+def attribute_idle(trace_dir, capture=None) -> dict:
     """``{"idle": ..., "programs": ...}`` of a capture: every idle gap between
     device runs booked to the host phase that covers it, and every device run
     named from inside the program.  Times are means over the device planes.
-    A capture without a device plane gives both empty."""
-    device, host = _read_capture(trace_dir)
+    A capture without a device plane gives both empty.  ``capture`` is what
+    :func:`read_capture` made of ``trace_dir``, where the caller has it."""
+    device, host = capture if capture is not None else read_capture(trace_dir)
     if not device:
         return {"idle": {}, "programs": {}}
     bounds, steps = _phase_steps(host)
@@ -232,29 +286,16 @@ def attribute_idle(trace_dir) -> dict:
     gaps: dict[tuple[str, str], dict] = {}
     programs: dict[str, dict] = {}
     clock = None
-    families: dict[str, str] = {}  # a million events, a few hundred names
-    for lines in device:
-        ops = []
-        for ev in lines["XLA Ops"].events:
-            name = ev.name
-            fam = families.get(name)
-            if fam is None:
-                fam = families[name] = _family(name)
-            start = int(ev.start_ns)
-            ops.append((start, start + int(ev.duration_ns), fam))
-        ops.sort()
-        mods = sorted((int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
-                       ev.name.split("(")[0])
-                      for ev in lines["XLA Modules"].events) \
-            if "XLA Modules" in lines else []
+    for plane in device:
+        ops, mods = plane["ops"], plane["mods"]
         if not ops:
             continue
         first = min(ops[0][0], mods[0][0] if mods else ops[0][0])
-        last = max(max(e for _, e, _ in ops),
+        last = max(max(e for _, e, _, _ in ops),
                    max((e for _, e, _ in mods), default=0))
         window += last - first
         end = -1
-        for s, e, _ in ops:  # the union of the operation intervals
+        for s, e, _, _ in ops:  # the union of the operation intervals
             if s > end:
                 busy += e - s
                 end = e
@@ -272,7 +313,7 @@ def attribute_idle(trace_dir) -> dict:
             p["runs"] += 1
             p["device_ns"] += r["end"] - r["start"]
             while k < len(ops) and ops[k][0] < r["end"]:
-                s, e, fam = ops[k]
+                s, e, fam, _ = ops[k]
                 if s >= r["start"] and fam not in _ENVELOPE:
                     p["ops"][fam] = p["ops"].get(fam, 0) + e - s
                 k += 1

@@ -24,6 +24,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from pytorch_zappa_serverless_tpu.ops.decode_attention import decode_attention
 from pytorch_zappa_serverless_tpu.ops.flash_attention import flash_attention
 from pytorch_zappa_serverless_tpu.ops.fused_decode import (
     fused_attn_step, fused_mlp_step)
@@ -86,6 +87,29 @@ def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk, h, d, causal):
         ((b, tq, h, d), jnp.bfloat16), ((b, tk, h, d), jnp.bfloat16),
         ((b, tk, h, d), jnp.bfloat16))
     assert "tpu_custom_call" in text
+
+
+# Decode attention over the slot pool [L, S, T, D], a middle layer: the
+# benchmark's two configurations (XL's d 1600 is 12.5 lane tiles; int8-large
+# pools 16 slots), and chip_smoke's GPT-2 small pool of 96 positions, which
+# no multiple of 16 up to 256 divides but itself.
+@pytest.mark.parametrize("layers,slots,total,d,heads", [
+    (48, 8, 960, 1600, 25), (36, 16, 960, 1280, 20), (12, 8, 96, 768, 12)],
+    ids=["xl", "large", "small"])
+def test_decode_attention_compiles_for_v5e(one_chip, layers, slots, total, d,
+                                           heads):
+    text = _compile(
+        lambda q, ck, cv, wpos: decode_attention(
+            q, ck, cv, wpos, layer=layers // 2, heads=heads),
+        one_chip,
+        ((slots, d), jnp.bfloat16), ((layers, slots, total, d), jnp.bfloat16),
+        ((layers, slots, total, d), jnp.bfloat16), ((slots,), jnp.int32))
+    assert "tpu_custom_call" in text
+    # The pool is the kernel's operand as it lies: nothing as large as one
+    # layer of it is sliced or copied on the way in.
+    import chip_smoke
+
+    assert not chip_smoke.pool_sized_moves(text, slots * total * d)
 
 
 # The two bf16 fused-decode entry points at GPT-2 small's step shape

@@ -1,0 +1,229 @@
+"""Decode attention that reads the slot pool where it lies.
+
+``models/gpt2._attn_decode`` (the ``jax.numpy`` form the CPU and the
+several-queries-a-slot callers run) and ``ops/decode_attention`` (the Pallas
+kernel of the same contraction, here through its interpret mode) against a
+float32 head-split reference: at the published widths of the benchmark's two
+configurations (25 heads of 64 at d 1600, 20 of 64 at d 1280; ``T`` and ``L``
+cut, not the widths), slots at different lengths (0, ``total - 1``, the clamp
+at ``total``), rows that hold garbage beyond their last written position,
+bfloat16 and float32 pools — the attention's output, and the logits of a
+whole decode step built on it.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pytorch_zappa_serverless_tpu.models import gpt2 as G
+from pytorch_zappa_serverless_tpu.ops.decode_attention import (
+    decode_attention, pick_block_t)
+
+WIDTHS = [(1600, 25), (1280, 20)]
+T, L = 48, 2
+# pos per slot: nothing but its own row, mid-block, a block edge, the last
+# row, and one past the pool (the segment clamps it to the last row).
+POS = [0, 5, 16, T - 1, T]
+
+
+def _pool(rng, S, D, dtype, garbage_beyond=None):
+    """A pool whose slots hold values up to their own length and zeros (or
+    ``garbage_beyond``) past it."""
+    wpos = np.minimum(np.asarray(POS[:S]), T - 1)
+    out = []
+    for _ in range(2):
+        a = rng.standard_normal((L, S, T, D)).astype(np.float32)
+        dead = np.arange(T)[None, :] > wpos[:, None]            # [S, T]
+        a[:, dead] = 0.0 if garbage_beyond is None else garbage_beyond
+        out.append(jnp.asarray(a, dtype))
+    return out[0], out[1], jnp.asarray(wpos, jnp.int32)
+
+
+def _reference(q, k, v, wpos, heads):
+    """Head-split attention in float32, highest precision: q [S, Tq, D],
+    k / v [S, T, D] one layer, wpos [S, Tq] → [S, Tq, D]."""
+    S, Tq, D = q.shape
+    dh = D // heads
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    qh = q.reshape(S, Tq, heads, dh) * dh ** -0.5
+    kh, vh = (a.reshape(S, -1, heads, dh) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh, precision="highest")
+    keep = jnp.arange(k.shape[1])[None, None, :] <= wpos[:, :, None]
+    s = jnp.where(keep[:, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vh,
+                      precision="highest").reshape(S, Tq, D)
+
+
+def _run(impl, q, ck, cv, layer, wpos, heads):
+    """The attention under test: [S, 1, D] in, [S, 1, D] out."""
+    if impl == "jnp":
+        return G._attn_decode(q, ck, cv, layer, wpos[:, None], heads)
+    dh = q.shape[-1] // heads
+    return decode_attention((q * dh ** -0.5)[:, 0], ck, cv, wpos,
+                            layer=layer, heads=heads, block_t=16,
+                            interpret=True)[:, None]
+
+
+def _tol(dtype):
+    return 2e-2 if dtype == jnp.bfloat16 else 2e-5
+
+
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
+def test_matches_float32_reference(D, heads, dtype, impl):
+    rng = np.random.default_rng(D)
+    S = len(POS)
+    ck, cv, wpos = _pool(rng, S, D, dtype)
+    q = jnp.asarray(rng.standard_normal((S, 1, D)), dtype)
+    for layer in range(L):
+        got = _run(impl, q, ck, cv, layer, wpos, heads)
+        want = _reference(q, ck[layer], cv[layer], wpos[:, None], heads)
+        assert got.shape == (S, 1, D) and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), atol=_tol(dtype),
+                                   rtol=_tol(dtype))
+    # The slot at position 0 reads its one row: the output is that row of V.
+    np.testing.assert_allclose(np.asarray(got[0, 0], np.float32),
+                               np.asarray(cv[L - 1, 0, 0], np.float32),
+                               atol=_tol(dtype), rtol=_tol(dtype))
+
+
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
+def test_garbage_beyond_wpos_changes_nothing(D, heads, dtype, impl):
+    """What a row holds past its slot's last written position (an earlier
+    request's keys, here 3e4 everywhere) weighs exactly zero."""
+    S = len(POS)
+    clean = _pool(np.random.default_rng(7), S, D, dtype)
+    dirty = _pool(np.random.default_rng(7), S, D, dtype, garbage_beyond=3e4)
+    q = jnp.asarray(np.random.default_rng(8).standard_normal((S, 1, D)),
+                    dtype)
+    a = _run(impl, q, clean[0], clean[1], 1, clean[2], heads)
+    b = _run(impl, q, dirty[0], dirty[1], 1, dirty[2], heads)
+    assert np.array_equal(np.asarray(a, np.float32),
+                          np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
+def test_several_queries_a_slot_equal_one_at_a_time(D, heads):
+    """The speculative verify's K+1 queries a slot, each with its own last
+    position, give what K+1 single-query calls give."""
+    rng = np.random.default_rng(3)
+    S, Tq = 3, 4
+    ck, cv, _ = _pool(rng, S, D, jnp.float32)
+    q = jnp.asarray(rng.standard_normal((S, Tq, D)), jnp.float32)
+    wp = jnp.asarray([[0, 1, 2, 3], [20, 21, 22, 23],
+                      [T - 4, T - 3, T - 2, T - 1]], jnp.int32)
+    many = G._attn_decode(q, ck, cv, 0, wp, heads)
+    for j in range(Tq):
+        one = G._attn_decode(q[:, j:j + 1], ck, cv, 0, wp[:, j:j + 1], heads)
+        np.testing.assert_allclose(np.asarray(many[:, j]),
+                                   np.asarray(one[:, 0]), atol=1e-5,
+                                   rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(many), np.asarray(_reference(q, ck[0], cv[0], wp, heads)),
+        atol=2e-5, rtol=2e-5)
+
+
+def _step_logits(params, ck, cv, tok, pos, cfg, dtype, attention):
+    """One decode step as ``decode_segment`` runs it, returning the logits;
+    ``attention(q, ck, cv, layer, wpos)`` is the part under test."""
+    S, total = tok.shape[0], ck.shape[2]
+    rows = jnp.arange(S)
+    wpos = jnp.minimum(pos, total - 1)
+    x = (params["wte"].astype(dtype)[tok]
+         + params["wpe"].astype(dtype)[wpos])[:, None, :]
+    for i in range(cfg.layers):
+        def attend(q, k, v, i=i):
+            nonlocal ck, cv
+            ck = ck.at[i, rows, wpos].set(k[:, 0])
+            cv = cv.at[i, rows, wpos].set(v[:, 0])
+            return attention(q, ck, cv, i, wpos)
+
+        x = G._layer(params[f"layer{i}"], x, cfg, attend)
+    return G._logits(params, G._ln(params["ln_f"], x, cfg.ln_eps)[:, 0])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
+def test_decode_step_logits_match_float32_reference(D, heads, dtype):
+    """A whole decode step at the published widths (one layer, a small
+    vocabulary): logits on the pool-layout attention against logits on the
+    float32 head-split reference, slots at 0, mid, ``total - 1`` and the
+    clamp at ``total``."""
+    cfg = G.GPT2Config(vocab_size=384, d_model=D, layers=1, heads=heads,
+                       ffn_dim=4 * D, max_positions=T, eos_id=383)
+    params = jax.tree.map(jnp.asarray, G.init_gpt2_params(1, cfg))
+    rng = np.random.default_rng(11)
+    S = len(POS)
+    ck, cv, _ = _pool(rng, S, D, dtype)
+    ck, cv = ck[:1] * 0.3, cv[:1] * 0.3
+    tok = jnp.asarray(rng.integers(0, 383, S), jnp.int32)
+    pos = jnp.asarray(POS, jnp.int32)
+    got = _step_logits(
+        params, ck, cv, tok, pos, cfg, dtype,
+        lambda q, k, v, i, w: G._attn_decode(q, k, v, i, w[:, None], heads))
+    want = _step_logits(
+        params, ck.astype(jnp.float32), cv.astype(jnp.float32), tok, pos,
+        cfg, jnp.float32,
+        lambda q, k, v, i, w: _reference(q, k[i], v[i], w[:, None], heads))
+    assert got.dtype == jnp.float32 and got.shape == (S, 384)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol,
+                               rtol=tol)
+
+
+def test_segment_finished_slot_touches_only_its_own_row():
+    """A finished slot keeps computing (static shapes) with its position
+    frozen: over a segment it rewrites one row of its own slot and nothing
+    of any other's, and the live slots' tokens do not depend on it."""
+    cfg = G.GPT2Config(vocab_size=96, d_model=32, layers=2, heads=2,
+                       ffn_dim=64, max_positions=T, eos_id=95)
+    params = jax.tree.map(jnp.asarray, G.init_gpt2_params(2, cfg))
+    rng = np.random.default_rng(5)
+    S = 3
+    ck = jnp.asarray(rng.standard_normal((2, S, T, 32)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((2, S, T, 32)), jnp.float32)
+    tok = jnp.asarray([3, 4, 5], jnp.int32)
+    pos = jnp.asarray([6, 9, T], jnp.int32)  # the last is past the pool
+    zeros = jnp.zeros((S,), jnp.int32)
+
+    def run(finished, ck, cv):
+        return G.decode_segment(params, ck, cv, tok, pos, zeros,
+                                jnp.asarray(finished), jnp.zeros((S,)),
+                                zeros, 4, cfg, jnp.float32)
+
+    emits, k2, _, _, pos2, _, fin2 = run([False, True, False], ck, cv)
+    assert pos2.tolist() == [10, 9, T + 4] and fin2.tolist()[1] is True
+    assert emits[1].tolist() == [95] * 4  # pinned to EOS
+    changed = np.argwhere(np.any(np.asarray(k2 != ck), axis=(0, 3)))
+    assert {(s, p) for s, p in changed.tolist()} == (
+        {(0, p) for p in range(6, 10)} | {(1, 9)} | {(2, T - 1)})
+    # Slot 1's row of the pool replaced by garbage: slots 0 and 2 emit the same.
+    dirty_k = ck.at[:, 1].set(3e4)
+    dirty_v = cv.at[:, 1].set(-3e4)
+    emits_dirty = run([False, True, False], dirty_k, dirty_v)[0]
+    assert emits_dirty[0].tolist() == emits[0].tolist()
+    assert emits_dirty[2].tolist() == emits[2].tolist()
+
+
+@pytest.mark.parametrize("total,want,block", [
+    (960, 256, 240), (960, 192, 192), (96, 256, 96), (100, 256, 100),
+    (1024, 256, 256), (48, 16, 16)])
+def test_pick_block_t_divides_the_pool(total, want, block):
+    assert pick_block_t(total, want) == block
+    assert total % block == 0
+
+
+def test_kernel_rejects_a_block_that_does_not_divide_the_pool():
+    z = jnp.zeros((1, 2, 48, 128), jnp.float32)
+    with pytest.raises(ValueError, match="does not divide"):
+        decode_attention(jnp.zeros((2, 128)), z, z, jnp.zeros((2,), jnp.int32),
+                         layer=0, heads=2, block_t=32, interpret=True)

@@ -66,7 +66,8 @@ async def test_profile_captures_live_traffic(aiohttp_client, tmp_path):
                                   json={"text": "hello tpu", "stream": False})
             assert r.status == 200
 
-        trace_req = client.post("/admin/profile", json={"seconds": 1.5})
+        # 3 s: on a loaded machine the traffic below takes more than one.
+        trace_req = client.post("/admin/profile", json={"seconds": 3.0})
         resp, _ = await asyncio.gather(trace_req, traffic())
         body = await resp.json()
         assert resp.status == 200, body
@@ -193,6 +194,28 @@ def test_attribute_idle_books_a_known_gap(synthetic_capture):
         "segment": {"runs": 1, "device_ms": pytest.approx(5.0),
                     "ops": {"copy": pytest.approx(3.0),
                             "fusion": pytest.approx(2.0)}}}
+
+
+def test_one_read_of_a_capture_serves_both_reductions(synthetic_capture):
+    """``/admin/profile`` reads a capture once (``read_capture``) and hands
+    it to both reductions: each gives what it gives from the directory."""
+    from pytorch_zappa_serverless_tpu.utils.xplane import (
+        op_time_breakdown, read_capture)
+
+    capture = read_capture(synthetic_capture)
+    device, host = capture
+    assert [len(p["ops"]) for p in device] == [4] and len(host) == 6
+    # The while is an envelope, the jit_ event no operation at all.
+    assert [(fam, is_op) for _, _, fam, is_op in device[0]["ops"]] == [
+        ("fusion", True), ("copy", True), ("while", True), ("fusion", True)]
+    alone = op_time_breakdown(synthetic_capture)
+    assert op_time_breakdown(synthetic_capture, capture) == alone
+    compute, counts, overlap, envelope = alone
+    assert dict(compute) == {"fusion": 3_800_000, "copy": 3_000_000}
+    assert dict(counts) == {"fusion": 2, "copy": 1}
+    assert dict(envelope) == {"while": 5_000_000} and not overlap
+    assert attribute_idle(synthetic_capture, capture) \
+        == attribute_idle(synthetic_capture)
 
 
 def test_join_survives_a_capture_that_begins_mid_round(tmp_path):
