@@ -21,13 +21,14 @@ async def stream_request(session: aiohttp.ClientSession, url: str,
     """POST one greedy request and read its stream to the end.
 
     The record holds when it was due and sent, when each token arrived, the
-    tokens, the ``done`` event's stats, and ``error`` where the stream was
-    refused, broke, or was not well formed (token events that differ from
-    the ``done`` event's list, or another length than was asked for).
+    tokens, the ``done`` event whole (and its ``stats`` beside it), and
+    ``error`` where the stream was refused, broke, or was not well formed
+    (token events that differ from the ``done`` event's list, or another
+    length than was asked for).
     """
     rec = {"due": due, "sent": None, "t_tokens": [], "tokens": [],
-           "t_end": None, "stats": {}, "error": None, "asked": max_new,
-           "prompt_len": len(ids)}
+           "t_end": None, "stats": {}, "done": None, "error": None,
+           "asked": max_new, "prompt_len": len(ids)}
     body = json.dumps({"input_ids": ids, "max_new_tokens": max_new}).encode()
     try:
         rec["sent"] = clock()
@@ -62,5 +63,6 @@ async def stream_request(session: aiohttp.ClientSession, url: str,
         elif len(rec["tokens"]) != max_new:
             rec["error"] = f"{len(rec['tokens'])} tokens, asked {max_new}"
         else:
+            rec["done"] = final
             rec["stats"] = final.get("stats", {})
     return rec

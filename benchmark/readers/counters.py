@@ -18,6 +18,15 @@ def read(ctx, kind: str):
         admitted = sum(1 for r in run["records"] if not r["error"])
         runs = delta("prefill_dispatches")
         return admitted / runs if runs else None
+    if kind == "kv_live_share":
+        # Per segment round, the positions the generating slots hold over
+        # slots x pool length; the scheduler keeps the sum and the count.
+        if "kv_live_share" not in after:
+            return None
+        rounds = after["kv_live_share"]["count"] \
+            - before["kv_live_share"]["count"]
+        return (after["kv_live_share"]["sum"]
+                - before["kv_live_share"]["sum"]) / rounds if rounds else None
     if kind == "compiles_in_window":
         return run["compiles_in_window"]
     if kind == "loop_lag_mean_ms":

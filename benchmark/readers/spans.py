@@ -10,9 +10,8 @@ leaves the metric out:
 - ``/metrics`` ``generation[model]["host_phases"]``: cumulative ``sum_ms`` and
   ``count`` per scheduler phase, read as deltas over the window, and the
   ``serialize``/``respond`` stage histograms of ``/admin/perf``;
-- the ``idle`` and ``programs`` blocks of the ``/admin/profile`` response:
-  the traced slice's idle time by host phase, and its device operations by
-  the program that ran them.
+- the ``idle`` block of the ``/admin/profile`` response: the traced slice's
+  idle time by host phase.
 """
 
 from __future__ import annotations
@@ -119,16 +118,8 @@ def read(ctx, kind: str, stats=(), q: float = 0.5, phases=()):
             return None
         ms, count = map(sum, zip(*(deltas[p] for p in phases)))
         return ms / count if count else None
-    profile = run.get("profile") or {}
-    if kind == "pool_copy_slice_pct":
-        seg = profile.get("programs", {}).get("segment")
-        if not seg or not seg["device_ms"]:
-            return None
-        return 100.0 * sum(ms for fam, ms in seg["ops"].items()
-                           if fam.startswith(("copy", "slice"))) \
-            / seg["device_ms"]
     if kind == "idle_attributed_pct":
-        idle = profile.get("idle")
+        idle = (run.get("profile") or {}).get("idle")
         if not idle or not idle["idle_ms"]:
             return None
         return 100.0 * (idle["idle_ms"] - idle["unattributed_ms"]) \

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+POOL_COPIES = ("copy", "slice", "slice-start", "slice-done")
+
 
 def prompt_ktok_per_s(ctx) -> float:
     """Real prompt tokens admitted per second of the window, in thousands."""
@@ -22,6 +24,15 @@ def read(ctx, kind: str):
             return None
         return seg["seconds"] / seg["runs"] * 1e3 \
             / ctx["serve"]["extra"]["segment_tokens"]
+    if kind == "pool_copy_slice_pct":
+        # The slot pool's own copies inside the segment program.
+        # ``copy-start`` and ``copy-done`` are the compiler's prefetch of
+        # weights into fast memory, not the pool.
+        seg = trace["programs"].get("segment")
+        if not seg or not seg["seconds"]:
+            return None
+        return 100.0 * sum(seg["ops"].get(fam, 0.0) for fam in POOL_COPIES) \
+            / seg["seconds"]
     if kind == "prefill_ms_per_ktok":
         # Prefill's share of the traced slice over the prompt tokens the
         # window admitted per second: the slice stands for the window.

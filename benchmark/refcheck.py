@@ -1,26 +1,21 @@
-"""Served greedy tokens against the plain reference, after the server has
-stopped (the HTTP API returns tokens, not logits).
+"""Served greedy tokens against the family's plain reference, after the
+server has stopped (the HTTP API returns tokens, not logits).
 
-For each reference sequence the served tokens are fed back to the reference
-(prompt + tokens served so far), so every position is judged on its own and
-one near-tie cannot spoil what follows.  The served token must be the
-reference's best, or lie within ``reference_tolerance`` of it in the
-reference's own logits: the server computes in bfloat16 and the reference in
-float32, so where the reference's two best are closer than the rounding
-error either is a right answer.
+What every family shares is here: a reference request that failed, or a
+prompt that sent twice, alone, gave two answers, fails the check before any
+reference runs; ``walk`` judges served tokens position by position.  What is
+compared beyond that is the family's own (``check`` of
+``benchmark/families/<family>.py``).
 """
 
 from __future__ import annotations
 
-import importlib
-
 import numpy as np
+
+from benchmark import families
 
 
 def check_reference(config: dict, serve: dict, checkpoint, runs: list) -> dict:
-    ref = importlib.import_module(f"benchmark.reference.{config['family']}")
-    arch = serve["extra"]["arch"]
-    tol = float(config["reference_tolerance"])
     for r in runs:
         if r["error"]:
             return {"ok": False, "note": f"reference request: {r['error']}"}
@@ -28,13 +23,20 @@ def check_reference(config: dict, serve: dict, checkpoint, runs: list) -> dict:
             return {"ok": False, "note": "the same prompt sent twice, alone, "
                     f"gave different greedy tokens: {r['tokens']} then "
                     f"{r['again']}"}
-    weights = ref.prepare(ref.load_tree(checkpoint), arch["layers"],
-                          serve["extra"]["params_dtype"] == "int8")
+    return families.load(config).check(config, serve, checkpoint, runs)
+
+
+def walk(logits_of, runs: list, tol: float) -> dict:
+    """For each reference sequence the served tokens are fed back to the
+    reference (prompt + tokens served so far), so every position is judged
+    on its own and one near-tie cannot spoil what follows.  ``logits_of(ids)``
+    gives the reference's logits ``[len(ids), vocab]``; the number compared
+    is the widest gap by which a served token lies under the reference's
+    best."""
     worst, exact, total = 0.0, 0, 0
     for r in runs:
         ids, toks = r["ids"], r["tokens"]
-        logits = ref.forward(weights, ids + toks[:-1], arch["layers"],
-                             arch["heads"], float(config["layer_norm_epsilon"]))
+        logits = logits_of(ids + toks[:-1])
         for j, tok in enumerate(toks):
             row = logits[len(ids) - 1 + j]
             deficit = float(np.max(row) - row[tok])

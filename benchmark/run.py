@@ -12,7 +12,11 @@ owner of the chip, warms every program the window can use, measures for
 reference.  ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
 is a run of its own that captures a device trace in mid-window and reports
 the per-layer metrics (``benchmark/layer_metrics/<metric>.json``, each read
-by ``benchmark/readers/<reader>.py``).
+by ``benchmark/readers/<reader>.py``).  What belongs to a model is in its
+family's module (``benchmark/families``); this file names none.  It reads
+``gen_slots``, ``segment_tokens``, ``max_new_tokens`` and ``arch.vocab_size``
+of ``serve.extra``: they are the slot scheduler's contract
+(``serving/generation.py``) with every model it serves, not one family's.
 
 This process drives the load over HTTP and never imports JAX while the
 server holds the chip.  ``--rehearse`` runs the same path on the CPU at the
@@ -48,22 +52,23 @@ from benchmark.client import percentile, stream_request  # noqa: E402
 from benchmark.server import Server, stage_weights  # noqa: E402
 
 HERE = ROOT / "benchmark"
-PROFILE_SECONDS = 3.0  # the traced slice in the middle of a --trace 1 window
 
 
 def say(msg: str) -> None:
     print(f"[bench] {msg}", flush=True)
 
 
-def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+def load_cell(name: str, benchmark_file: Path = ROOT / "BENCHMARK.json"
+              ) -> tuple[dict, dict, dict, dict]:
+    bench = json.loads(Path(benchmark_file).read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
-        raise SystemExit(f"no workload {name!r} in BENCHMARK.json; it has "
+        raise SystemExit(f"no workload {name!r} in {benchmark_file}; it has "
                          f"{sorted(cells)}")
     cell = cells[name]
     files = {c["name"]: c["file"] for c in bench["configs"]}
-    config = json.loads((ROOT / files[cell["config"]]).read_text())
+    path = ROOT / files[cell["config"]]
+    config = {**json.loads(path.read_text()), "file": str(path)}
     return bench, cell, config, traffic.load_mix(cell["traffic"])
 
 
@@ -215,6 +220,7 @@ async def measure(args, config: dict, mix: dict, srv: Server, scale: float,
                                      clock=time.perf_counter)
             out["reference_runs"].append(
                 {"ids": ids, "tokens": a["tokens"], "again": b["tokens"],
+                 "done": a["done"], "done_again": b["done"],
                  "error": a["error"] or b["error"]})
         out["split"]["reference_requests_s"] = time.monotonic() - t
 
@@ -223,12 +229,13 @@ async def measure(args, config: dict, mix: dict, srv: Server, scale: float,
         async with session.get(srv.url + "/admin/perf") as resp:
             perf_before = await resp.json()
 
-        async def profile():
-            await asyncio.sleep(max(args.seconds - PROFILE_SECONDS, 0) / 2)
+        capture_s = min(traffic.profile_seconds(mix), args.seconds)
+
+        async def profile():  # the traced slice, in the middle of the window
+            await asyncio.sleep((args.seconds - capture_s) / 2)
             async with session.post(
                     srv.url + "/admin/profile",
-                    json={"seconds": min(PROFILE_SECONDS, args.seconds),
-                          "top": 1}) as resp:
+                    json={"seconds": capture_s, "top": 1}) as resp:
                 if resp.status != 200:
                     raise SystemExit(f"/admin/profile -> {resp.status}: "
                                      f"{(await resp.text())[:300]}")
@@ -285,11 +292,14 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU, tiny widths; the last line says platform cpu")
+    ap.add_argument("--benchmark-file", type=Path,
+                    default=ROOT / "BENCHMARK.json",
+                    help="another list of cells than the repo's own")
     args = ap.parse_args()
     if not (ROOT / "pytorch_zappa_serverless_tpu" / "cli.py").is_file():
         raise SystemExit("the program (pytorch_zappa_serverless_tpu) is not "
                          "in this directory: nothing to measure")
-    bench, cell, config, mix = load_cell(args.workload)
+    bench, cell, config, mix = load_cell(args.workload, args.benchmark_file)
     serve, scale = serve_fragment(config, args.rehearse)
     split: dict[str, float] = {}
 
@@ -359,7 +369,8 @@ def main() -> int:
         trace = reduce_trace(run["profile"]["dir"], config["programs"])
         shutil.rmtree(run["profile"]["dir"], ignore_errors=True)  # tens of MB
         say(f"trace reduced in {time.monotonic() - t:.1f} s: "
-            + json.dumps(trace["programs"]))
+            + json.dumps({kind: [p["runs"], round(p["seconds"], 6)]
+                          for kind, p in trace["programs"].items()}))
         if trace["busy_s"] <= 0 and not args.rehearse:
             raise SystemExit("the trace holds no device operation")
         ctx = {"run": run, "trace": trace, "config": config, "serve": serve,
@@ -382,6 +393,12 @@ def main() -> int:
                 "value": end_to_end(m["name"], run, args.seconds),
                 "unit": m["unit"]}
     line.update(metrics=metrics, device=dev)
+    # What was compared, beside its limit, where a record of a run that is
+    # not correct keeps it: the end of standard error.
+    print(f"[bench] correct {bool(correct)}: request errors {len(errors)} "
+          f"(limit 0), compiles inside the window "
+          f"{run['compiles_in_window']} (limit 0); reference: {ref['note']}",
+          file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

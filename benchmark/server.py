@@ -38,9 +38,8 @@ def stage_weights(config: dict, serve: dict, rehearse: bool) -> Path:
     tag = f"{config['name']}-{w['seed']}" + ("-rehearse" if rehearse else "")
     path = WORK / "weights" / f"{tag}.tpu.safetensors"
     if not path.is_file():
-        arch = [f"{k}={v}" for k, v in serve["extra"]["arch"].items()]
         subprocess.run([sys.executable, str(HERE / "stage_weights.py"),
-                        str(path), w["dtype"], str(w["seed"]), *arch],
+                        str(path), config["file"], json.dumps(serve)],
                        check=True, cwd=str(ROOT), env=child_env(cpu=True))
     return path
 

@@ -1,15 +1,18 @@
 """Write a configuration's seeded weights once, as the staged checkpoint the
 server boots from (``checkpoint:``, the product's activation path).
 
-Run as a child with ``JAX_PLATFORMS=cpu``: it imports the program's builder
-(which imports JAX) and must not take the chip.  The tree comes from the
-program's own ``init_gpt2_params``; matrices are kept in ``dtype``
-(bfloat16 where the server holds them so, float32 where it quantizes them
-itself), vectors in float32, as the server's at-rest cast leaves them.
+    python3 benchmark/stage_weights.py <out> <configuration file> <serve fragment as JSON>
+
+Run as a child with ``JAX_PLATFORMS=cpu``: the family's ``init_tree``
+imports the program's builder (which imports JAX) and must not take the
+chip.  Matrices are kept in ``weights.dtype`` (bfloat16 where the server
+holds them so, float32 where it quantizes them itself), vectors in float32,
+as the server's at-rest cast leaves them.
 """
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 from pathlib import Path
@@ -17,17 +20,16 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 
-def main() -> int:
-    out, dtype, seed = Path(sys.argv[1]), sys.argv[2], int(sys.argv[3])
-    arch = {k: int(v) for k, v in (a.split("=") for a in sys.argv[4:])}
+def main(argv: list[str]) -> int:
+    out, config = Path(argv[0]), json.loads(Path(argv[1]).read_text())
+    serve = json.loads(argv[2])
     import ml_dtypes
-    import numpy as np
 
+    from benchmark import families
     from pytorch_zappa_serverless_tpu.engine.weights import save_native
-    from pytorch_zappa_serverless_tpu.models.gpt2 import (GPT2Config,
-                                                          init_gpt2_params)
 
-    tree = init_gpt2_params(seed, GPT2Config(**arch))
+    dtype, seed = config["weights"]["dtype"], int(config["weights"]["seed"])
+    tree = families.load(config).init_tree(seed, config, serve)
     if dtype == "bfloat16":
         def cast(node):
             if isinstance(node, dict):
@@ -45,4 +47,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))

@@ -58,11 +58,7 @@ def ctx():
                                    "phases": {"round.wakeup": 200.0,
                                               "unattributed": 15.0}}],
                          "clock": {"segments": 30, "ok": 30,
-                                   "lag_ms": {"min": 0.1, "max": 0.4}}},
-                "programs": {"segment": {
-                    "runs": 30, "device_ms": 2000.0,
-                    "ops": {"copy": 600.0, "slice-done": 100.0,
-                            "slice": 300.0, "fusion": 900.0}}}}}}
+                                   "lag_ms": {"min": 0.1, "max": 0.4}}}}}}
 
 
 def spec(name):
@@ -81,7 +77,6 @@ def spec(name):
     ("sse_ms_per_round", (10.0 + 30.0) / 100),
     ("host_turnaround_ms", (9 + 10 + 2 + 3) * 100.0 / 100),
     ("segment_launch_ms", 7.0),           # 700 ms over 100 launches
-    ("pool_copy_slice_pct", 50.0),        # copy + slice-done + slice
     ("idle_attributed_pct", 95.0),
     ("idle_attributed_pct.bulk", 95.0),
 ])
@@ -127,7 +122,7 @@ def test_every_spans_metric_is_in_the_benchmark():
                  "per_layer"]}
     mine = [json.loads(p.read_text()) for p in METRICS.glob("*.json")
             if json.loads(p.read_text())["reader"] == "spans"]
-    assert len(mine) == 14
+    assert len(mine) == 13
     for m in mine:
         entry = bench[m["name"]]
         assert {k: entry[k] for k in ("unit", "better", "source", "layer",

@@ -6,10 +6,17 @@ other) beside an ``XLA Modules`` line (one event per program run).  Busy time
 is the union of the operation intervals; the window is from the first device
 event to the last; both are averaged over the device planes.
 
-Programs are told apart by rules from the configuration's file
-(``programs``): the module's name and, where two programs share a name (both
-are lambdas to ``jax.jit``), whether an operation of a given family runs
-inside the module's interval.
+Programs are told apart as ``POST /admin/profile`` tells them: the program
+annotates each launch (``tpuserve.<kind>.launch``, stat ``programs``), one
+chip runs programs in the order they were launched, and each launch names
+the next runs that began after it.  A run that no launch in the capture
+claims (it was launched before the capture began) falls to the rules of the
+configuration's file (``programs``): the module's name and, where two
+programs share a name (both are lambdas to ``jax.jit``), whether an
+operation of a given family runs inside the module's interval.  Those rules
+alone took the run that the capture's end cuts for a prefill, because its
+``while`` never closes; its launch says what it is.  It counts with the part
+of it the capture holds.
 
     python3 benchmark/trace_reduce.py <capture dir>     # what a capture holds
 """
@@ -23,6 +30,10 @@ import sys
 from pathlib import Path
 
 ENVELOPES = {"while", "conditional", "call"}  # they span their body's ops
+LAUNCH = re.compile(r"tpuserve\.(\w+)\.launch")
+# A run may seem to begin this long before its launch did: the profiler sets
+# the device plane's clock against the host's to within about a millisecond.
+EARLY_NS = 2_000_000
 
 
 def family(op_name: str) -> str:
@@ -63,14 +74,41 @@ def classify(name: str, start: int, stop: int, rules: dict,
     return name
 
 
-def device_planes(capture_dir):
+def launched(mods: list[tuple[int, int, str]],
+             launches: list[tuple[int, str, int]]) -> list[str | None]:
+    """The kind each run of ``mods`` (sorted by start) was launched as: every
+    launch ``(start, kind, programs)``, in order, takes the next ``programs``
+    runs that began after it; ``None`` where no launch claims the run."""
+    kinds: list[str | None] = [None] * len(mods)
+    j = 0
+    for l_start, kind, programs in sorted(launches):
+        while j < len(mods) and mods[j][0] < l_start - EARLY_NS:
+            j += 1
+        kinds[j:j + programs] = [kind] * len(kinds[j:j + programs])
+        j = min(j + programs, len(mods))
+    return kinds
+
+
+def read_planes(capture_dir):
+    """``([(plane name, lines by name)] of the device planes, the launches
+    the host planes hold as (start_ns, kind, programs))``."""
     from jax.profiler import ProfileData
 
+    device, launches = [], []
     for pb in sorted(Path(capture_dir).rglob("*.xplane.pb")):
         for plane in ProfileData.from_file(str(pb)).planes:
             lines = {line.name: line for line in plane.lines}
             if "XLA Ops" in lines:
-                yield plane.name, lines
+                device.append((plane.name, lines))
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    named = LAUNCH.fullmatch(ev.name)
+                    if named:
+                        launches.append(
+                            (int(ev.start_ns), named.group(1),
+                             int(dict(ev.stats).get("programs", 1))))
+    return device, launches
 
 
 def reduce_trace(capture_dir, rules: dict) -> dict:
@@ -78,8 +116,10 @@ def reduce_trace(capture_dir, rules: dict) -> dict:
     ops: collections.Counter = collections.Counter()
     gaps: collections.Counter = collections.Counter()
     programs: dict[str, dict] = {}
-    for _, lines in device_planes(capture_dir):
+    device, launches = read_planes(capture_dir)
+    for _, lines in device:
         intervals, op_starts = [], collections.defaultdict(list)
+        timed = []  # (start, duration, family) of what is no envelope
         for ev in lines["XLA Ops"].events:
             start, dur = int(ev.start_ns), int(ev.duration_ns)
             intervals.append((start, start + dur))
@@ -87,6 +127,8 @@ def reduce_trace(capture_dir, rules: dict) -> dict:
             op_starts[fam].append(start)
             if fam not in ENVELOPES:
                 ops[fam] += dur
+                timed.append((start, dur, fam))
+        timed.sort()
         for starts in op_starts.values():
             starts.sort()
         if not intervals:
@@ -102,15 +144,23 @@ def reduce_trace(capture_dir, rules: dict) -> dict:
             first, last = min(first, mods[0][0]), max(last, mods[-1][1])
         window.append(last - first)
         prev = None
-        for start, stop, name in mods:
-            kind = classify(name, start, stop, rules, op_starts)
-            p = programs.setdefault(kind, {"runs": 0, "seconds": 0.0})
+        k = 0
+        for (start, stop, name), kind in zip(mods, launched(mods, launches)):
+            kind = kind or classify(name, start, stop, rules, op_starts)
+            p = programs.setdefault(kind, {"runs": 0, "seconds": 0.0,
+                                           "ops": collections.Counter()})
             p["runs"] += 1
             p["seconds"] += (stop - start) / 1e9
+            while k < len(timed) and timed[k][0] < stop:
+                if timed[k][0] >= start:  # an operation of this run
+                    p["ops"][timed[k][2]] += timed[k][1] / 1e9
+                k += 1
             if prev is not None and start > prev[0]:
                 gaps[f"{prev[1]}-{kind}"] += start - prev[0]
             prev = (stop, kind) if prev is None or stop > prev[0] else prev
     n = max(len(busy), 1)
+    for p in programs.values():
+        p["ops"] = dict(p["ops"].most_common())
     return {
         "chips": len(busy),
         "busy_s": sum(busy) / n / 1e9,
@@ -122,7 +172,10 @@ def reduce_trace(capture_dir, rules: dict) -> dict:
 
 
 def main() -> int:
-    for name, lines in device_planes(sys.argv[1]):
+    device, launches = read_planes(sys.argv[1])
+    print(f"{len(launches)} launches: "
+          f"{collections.Counter(kind for _, kind, _ in launches)}")
+    for name, lines in device:
         print(f"plane {name}")
         for lname, line in lines.items():
             events = list(line.events)

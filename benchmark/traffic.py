@@ -28,6 +28,13 @@ def load_mix(name: str) -> dict:
     return {**load_mix(base), **mix} if base else mix
 
 
+def profile_seconds(mix: dict) -> float:
+    """How long a ``--trace 1`` run's capture lasts in this mix.  A cell
+    states a length that holds 20-30 runs of its main program: ``stop_trace``
+    costs seconds for each, after the window and inside the run's limit."""
+    return float(mix.get("profile_seconds", 1.5))
+
+
 def quantile(dist: dict, q: float) -> float:
     kind = dist["dist"]
     if kind == "const":
