@@ -8,6 +8,7 @@ plain reference is ``benchmark/reference/gpt2.py``, the shape arithmetic
 
 from __future__ import annotations
 
+from benchmark.refcheck import walk
 from benchmark.reference import gpt2 as reference
 from benchmark.roofline import gpt2 as shapes
 
@@ -28,8 +29,6 @@ def check(config: dict, serve: dict, checkpoint, runs: list) -> dict:
     within ``reference_tolerance`` of it in the reference's own logits: the
     server computes in bfloat16, so where the reference's two best are
     closer than the rounding error either is a right answer."""
-    from benchmark.refcheck import walk
-
     arch = serve["extra"]["arch"]
     weights = reference.prepare(reference.load_tree(checkpoint),
                                 arch["layers"], _int8(serve))

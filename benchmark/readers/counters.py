@@ -23,10 +23,9 @@ def read(ctx, kind: str):
         # slots x pool length; the scheduler keeps the sum and the count.
         if "kv_live_share" not in after:
             return None
-        rounds = after["kv_live_share"]["count"] \
-            - before["kv_live_share"]["count"]
-        return (after["kv_live_share"]["sum"]
-                - before["kv_live_share"]["sum"]) / rounds if rounds else None
+        live0, live1 = before["kv_live_share"], after["kv_live_share"]
+        rounds = live1["count"] - live0["count"]
+        return (live1["sum"] - live0["sum"]) / rounds if rounds else None
     if kind == "compiles_in_window":
         return run["compiles_in_window"]
     if kind == "loop_lag_mean_ms":

@@ -52,15 +52,16 @@ from benchmark.client import percentile, stream_request  # noqa: E402
 from benchmark.server import Server, stage_weights  # noqa: E402
 
 HERE = ROOT / "benchmark"
+BENCHMARK_FILE = ROOT / "BENCHMARK.json"
 
 
 def say(msg: str) -> None:
     print(f"[bench] {msg}", flush=True)
 
 
-def load_cell(name: str, benchmark_file: Path = ROOT / "BENCHMARK.json"
+def load_cell(name: str, benchmark_file: Path = BENCHMARK_FILE
               ) -> tuple[dict, dict, dict, dict]:
-    bench = json.loads(Path(benchmark_file).read_text())
+    bench = json.loads(benchmark_file.read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise SystemExit(f"no workload {name!r} in {benchmark_file}; it has "
@@ -292,8 +293,7 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU, tiny widths; the last line says platform cpu")
-    ap.add_argument("--benchmark-file", type=Path,
-                    default=ROOT / "BENCHMARK.json",
+    ap.add_argument("--benchmark-file", type=Path, default=BENCHMARK_FILE,
                     help="another list of cells than the repo's own")
     args = ap.parse_args()
     if not (ROOT / "pytorch_zappa_serverless_tpu" / "cli.py").is_file():

@@ -56,7 +56,8 @@ def test_extends_overrides_single_values():
     base, child = traffic.load_mix("chat"), traffic.load_mix("chat-int8")
     raw = json.loads((Path(traffic.HERE) / "traffic" / "chat-int8.json")
                      .read_text())
-    assert set(raw) <= {"extends", "rate_per_s", "rate_from"}
+    assert set(raw) <= {"extends", "rate_per_s", "rate_from",
+                        "profile_seconds"}
     assert child["prompt_tokens"] == base["prompt_tokens"]
     assert child["rate_per_s"] != base["rate_per_s"]
 

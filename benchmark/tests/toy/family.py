@@ -6,6 +6,7 @@ builder because that is the decoder the program has."""
 
 from __future__ import annotations
 
+from benchmark.refcheck import walk
 from benchmark.tests.toy import reference
 
 
@@ -21,8 +22,6 @@ def init_tree(seed: int, config: dict, serve: dict) -> dict:
 def check(config: dict, serve: dict, checkpoint, runs: list) -> dict:
     """The walk every family may use, and one thing of the toy's own: the
     ``done`` event reached the check whole, both times."""
-    from benchmark.refcheck import walk
-
     for r in runs:
         for done in (r["done"], r["done_again"]):
             if done["tokens"] != r["tokens"] or "stats" not in done:
