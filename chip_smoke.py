@@ -28,7 +28,9 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
 - *restart*  the same config again: the boot must hit the compile cache.
 - *kernels*  ``int8_matmul``, ``flash_attention`` and ``decode_attention``
              with ``interpret=False`` at real shapes against ``jax.numpy``
-             references, on the chip.
+             references, on the chip; then ``decode_attention`` alone by the
+             profiler's clock, microseconds a layer at three fills of the two
+             serving pools beside the least their live bytes allow.
 - *segment*  the decode segment program at GPT-2 XL's serving shape (8 slots
              of 960 positions, 48 layers, compiled from shapes alone): its
              optimised HLO must hold no ``copy``, ``slice`` or ``transpose``
@@ -601,32 +603,121 @@ def _kernels_child(rehearse: bool) -> None:
         print(f"flash_attention q[{b},{tq},{h},{d}] kv {tk} causal={causal} "
               "matches its reference")
     # Decode attention over a slot pool [L, S, T, D]: the benchmark's two
-    # serving shapes, slots at 0, mid-block, a block edge and the last row.
+    # serving shapes, slots at 0, mid-block, a block edge and the last row,
+    # and a dead one, whose row of the pool holds NaN and is read nowhere.
     da = ([(2, 4, 32, 128, 2)] if rehearse else
           [(2, 8, 960, 1600, 25), (2, 16, 960, 1280, 20)])
     for layers, slots, total, d, heads in da:
         q = jnp.asarray(rng.standard_normal((slots, d)), jnp.bfloat16)
+        wpos = jnp.asarray(([0, -1, 5, total // 4 - 1, total // 4, total - 1]
+                            * 3)[:slots], jnp.int32)
         ck, cv = (jnp.asarray(rng.standard_normal((layers, slots, total, d)),
-                              jnp.bfloat16) for _ in range(2))
-        wpos = jnp.asarray(([0, 5, total // 4 - 1, total // 4, total - 1]
-                            * 4)[:slots], jnp.int32)
+                              jnp.bfloat16).at[:, 1].set(jnp.nan)
+                  for _ in range(2))
         dh = d // heads
         got = decode_attention(q * dh ** -0.5, ck, cv, wpos, layer=1,
                                heads=heads, interpret=interpret)
+        live = np.asarray(wpos) >= 0
         bias = jnp.where(jnp.arange(total)[None, :] <= wpos[:, None], 0.0,
                          -1e9)[:, None, None, :]
-        want = reference(*(a.reshape(slots, -1, heads, dh)
-                           for a in (q[:, None], ck[1], cv[1])), bias)
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want).reshape(slots, d),
+        want = reference(*(a.reshape(slots, -1, heads, dh)[live]
+                           for a in (q[:, None], ck[1], cv[1])), bias[live])
+        np.testing.assert_allclose(np.asarray(got, np.float32)[live],
+                                   np.asarray(want).reshape(-1, d),
                                    rtol=3e-2, atol=3e-2)
+        assert not np.asarray(got, np.float32)[~live].any(), "dead row not 0"
+        none = decode_attention(q, ck, cv, jnp.full((slots,), -1, jnp.int32),
+                                layer=0, heads=heads, interpret=interpret)
+        assert not np.asarray(none, np.float32).any(), "no live slot, not 0"
         print(f"decode_attention pool[{layers},{slots},{total},{d}] "
-              f"{heads} heads matches its reference")
+              f"{heads} heads matches its reference; dead slots give zeros")
+        for row in time_decode_attention(
+                lambda q, ck, cv, wpos, work, layer, block_t:
+                decode_attention(q, ck, cv, wpos, work, layer=layer,
+                                 heads=heads, block_t=block_t,
+                                 interpret=interpret),
+                slots, total, d, not rehearse):
+            print("decode_attention " + json.dumps(row))
     print("preprocess path: "
           + ("native (hostops.cpp built with g++)" if hostops.native_available()
              else "PIL (no native library: no compiler here)"))
     print(json.dumps({"int8_matmul": len(mm), "flash_attention": len(fa),
                       "decode_attention": len(da)}))
+
+
+_TIMED_CALLS = 16  # kernel calls chained in one timed program
+
+
+def decode_fills(slots: int, total: int) -> dict[str, list[int]]:
+    """``wpos`` of the three fills the kernel is timed at: the chat cells'
+    own (2 of 8 or 3 of 16 slots live at about 250 of 960 positions, the
+    others dead), every slot live at about half of ``total``, every slot full."""
+    live = (slots + 2) // 5
+    chat = [total * (215 + 70 * j // max(1, live - 1)) // 960
+            for j in range(live)]
+    half = [total // 2 - 64 * total // 960 + 128 * total // 960 * j // slots
+            for j in range(slots)]
+    return {"chat": chat + [-1] * (slots - live), "half": half,
+            "full": [total - 1] * slots}
+
+
+def time_decode_attention(attend, slots: int, total: int, d: int,
+                          on_device: bool, blocks=(None,)):
+    """Device microseconds of one ``decode_attention`` call, the kernel
+    alone, by the profiler's ``XLA Ops`` events of that name: one row a fill
+    (:func:`decode_fills`) and a block length of ``blocks`` (None: the
+    kernel's own choice), beside the least the chip could take over the live
+    positions' bytes (K and V in bfloat16 at 819 GB/s).  ``attend(q, ck,
+    cv, wpos, work, layer, block_t)`` is the kernel under the clock; ``work``
+    is its list of live blocks, built once a program as the segment builds
+    it once a step.  Off the device the rows carry no time."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.ops.decode_attention import (
+        pick_block_t, work_list)
+    from pytorch_zappa_serverless_tpu.utils.xplane import op_time_breakdown
+
+    layers = 2
+    rng = np.random.default_rng(SEED)
+    q = jnp.asarray(rng.standard_normal((slots, d)) * 0.1, jnp.bfloat16)
+    ck, cv = (jnp.asarray(rng.standard_normal((layers, slots, total, d)),
+                          jnp.bfloat16) for _ in range(2))
+    rows = []
+    for block_t in blocks:
+        bt = block_t or pick_block_t(total, d, jnp.bfloat16)
+
+        @jax.jit
+        def chain(q, ck, cv, wpos):
+            work = work_list(wpos, total, bt)
+            for j in range(_TIMED_CALLS):
+                q = q + attend(q, ck, cv, wpos, work, j % layers, bt)
+            return q
+
+        for fill, wpos in decode_fills(slots, total).items():
+            live = sum(w + 1 for w in wpos if w >= 0)
+            row = {"pool": [slots, total, d], "fill": fill, "block_t": bt,
+                   "live_positions": live,
+                   "read_positions": sum(-(-(w + 1) // bt) * bt
+                                         for w in wpos if w >= 0),
+                   "floor_us": round(live * d * 2 * 2 / 819e9 * 1e6, 2)}
+            wpos = jnp.asarray(wpos, jnp.int32)
+            chain(q, ck, cv, wpos).block_until_ready()
+            if on_device:
+                with tempfile.TemporaryDirectory(dir=OUT) as trace_dir:
+                    jax.profiler.start_trace(trace_dir)
+                    chain(q, ck, cv, wpos).block_until_ready()
+                    jax.profiler.stop_trace()
+                    compute, counts, _, _ = op_time_breakdown(trace_dir)
+                calls = counts["decode_attention"]
+                assert calls == _TIMED_CALLS, (calls, dict(counts))
+                row["us_a_layer"] = round(
+                    compute["decode_attention"] / calls / 1e3, 2)
+            rows.append(row)
+    return rows
 
 
 _MOVES = ("copy", "slice", "dynamic-slice", "transpose")
@@ -674,26 +765,19 @@ def pool_sized_moves(hlo_text: str, elements: int) -> list[tuple[int, str]]:
     return sorted(found, reverse=True)
 
 
-def _segment_child(rehearse: bool) -> None:
-    """Compile the decode segment at GPT-2 XL's serving shape, from shapes
-    alone (nothing is allocated), and look through its optimised HLO for a
-    layer of the pool being moved (:func:`pool_sized_moves`)."""
+def segment_program(cfg, slots: int, total: int, sharding=None):
+    """The decode segment (8 tokens) over a bfloat16 pool of ``slots`` x
+    ``total`` as a jitted function, and its arguments as shapes alone
+    (nothing is allocated), placed by ``sharding`` where one is given."""
     import jax
     import jax.numpy as jnp
 
     from pytorch_zappa_serverless_tpu.models import gpt2
 
-    if rehearse:
-        cfg = gpt2.GPT2Config(**TINY_GPT2)
-        slots, total = 4, 32
-    else:
-        cfg = gpt2.GPT2Config(d_model=1600, layers=48, heads=25,
-                              ffn_dim=6400)
-        slots, total = 8, 960
     D, F, bf = cfg.d_model, cfg.ffn_dim, jnp.bfloat16
 
     def sd(*shape, dtype=bf):
-        return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     def dense(i, o):
         return {"kernel": sd(i, o), "bias": sd(o)}
@@ -715,9 +799,26 @@ def _segment_child(rehearse: bool) -> None:
         gpt2.decode_segment(p, ck, cv, tok, pos, st, fin, temp, seeds, 8,
                             cfg, bf, top_k=topk, top_p=topp),
         donate_argnums=(1, 2))
-    text = segment.lower(params, pool, pool, i32, i32, i32,
-                         sd(slots, dtype=jnp.bool_), f32, i32, i32,
-                         f32).compile().as_text()
+    return segment, (params, pool, pool, i32, i32, i32,
+                     sd(slots, dtype=jnp.bool_), f32, i32, i32, f32)
+
+
+def _segment_child(rehearse: bool) -> None:
+    """Compile the decode segment at GPT-2 XL's serving shape, from shapes
+    alone (nothing is allocated), and look through its optimised HLO for a
+    layer of the pool being moved (:func:`pool_sized_moves`)."""
+    from pytorch_zappa_serverless_tpu.models import gpt2
+
+    if rehearse:
+        cfg = gpt2.GPT2Config(**TINY_GPT2)
+        slots, total = 4, 32
+    else:
+        cfg = gpt2.GPT2Config(d_model=1600, layers=48, heads=25,
+                              ffn_dim=6400)
+        slots, total = 8, 960
+    D = cfg.d_model
+    segment, args = segment_program(cfg, slots, total)
+    text = segment.lower(*args).compile().as_text()
     layer = slots * total * D
     # What is there at all: the largest such move of a tenth of a layer up.
     moves = pool_sized_moves(text, layer // 10)

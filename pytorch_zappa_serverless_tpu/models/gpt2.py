@@ -118,13 +118,46 @@ def _attn(q, k, v, mask_bias, heads):
     return out.reshape(B, Tq, -1)
 
 
-def _attn_decode(q, cache_k, cache_v, layer, wpos, heads):
+def _decode_kernel_block(Tq, total, d, dtype):
+    """The block length at which ops/decode_attention.py serves this call,
+    or None where the ``jax.numpy`` form of :func:`_attn_decode` does: the
+    CPU, several queries a slot, a process that addresses several devices (a
+    mesh: a Mosaic kernel is not partitioned automatically, and the
+    partitioner splits the einsums over ``D`` as it did the heads), and a
+    pool length that only a block too large for the kernel's VMEM divides."""
+    if (Tq != 1 or jax.default_backend() != "tpu"
+            or jax.device_count() != 1):
+        return None
+    from ..ops.decode_attention import fits_vmem, pick_block_t
+
+    bt = pick_block_t(total, d, dtype)
+    return bt if fits_vmem(bt, d, dtype) else None
+
+
+def _decode_work(last, total, d, dtype):
+    """The step's list of live ``(slot, block)`` pairs for the kernel
+    (ops/decode_attention.work_list), built once from ``last`` [S] and
+    shared by every layer's :func:`_attn_decode` over ``total`` positions
+    of width ``d``; None where the ``jax.numpy`` form runs, which needs
+    none."""
+    bt = _decode_kernel_block(1, total, d, dtype)
+    if bt is None:
+        return None
+    from ..ops.decode_attention import work_list
+
+    return work_list(last, total, bt)
+
+
+def _attn_decode(q, cache_k, cache_v, layer, wpos, heads, work=None):
     """Decode attention over one layer of the pool, read where it lies.
 
     q [S, Tq, D] (a slot's one query, or the K+1 of a speculative verify),
     cache_k / cache_v [L, S, T, D] the whole pool in its own layout (``D``
     minor, no head split) and ``layer`` which of it to read, wpos [S, Tq]
-    the last position each query may read → [S, Tq, D].
+    the last position each query may read → [S, Tq, D].  A negative
+    ``wpos`` marks a *dead* query (a finished or empty slot): it reads
+    nothing and its output row is zeros, whatever its row of the pool
+    holds.  ``work`` is :func:`_decode_work` of ``wpos[:, 0]``.
 
     :func:`_attn` makes ``(slot, head)`` batch dimensions, and a pool whose
     heads lie side by side in ``D`` then has to be sliced out and moved to a
@@ -141,25 +174,21 @@ def _attn_decode(q, cache_k, cache_v, layer, wpos, heads):
     the row holds there.
 
     On one TPU chip one query a slot goes to the Pallas kernel of the same
-    contraction (ops/decode_attention.py), which takes its blocks out of the
-    pool by index and stops at each slot's last written one; everything else
-    runs the ``jax.numpy`` form below, which reads all ``T`` positions: the
-    CPU, several queries a slot, and a process that addresses several
-    devices (a mesh: a Mosaic kernel is not partitioned automatically, and
-    the partitioner splits the einsums over ``D`` as it did the heads).
+    contraction (ops/decode_attention.py), which visits the live blocks of
+    the live slots and nothing else; everything else
+    (:func:`_decode_kernel_block`) runs the ``jax.numpy`` form below, which
+    reads all ``T`` positions.
     """
     S, Tq, D = q.shape
     dh = D // heads
     q = q * dh ** -0.5
-    if (Tq == 1 and jax.default_backend() == "tpu"
-            and jax.device_count() == 1):
-        from ..ops.decode_attention import decode_attention, pick_block_t
+    bt = _decode_kernel_block(Tq, cache_k.shape[2], D, cache_k.dtype)
+    if bt is not None:
+        from ..ops.decode_attention import decode_attention
 
-        # A pool length that no block of at most 512 positions divides
-        # would make one block a slot, too large for the kernel's VMEM.
-        if pick_block_t(cache_k.shape[2]) <= 512:
-            return decode_attention(q[:, 0], cache_k, cache_v, wpos[:, 0],
-                                    layer=layer, heads=heads)[:, None]
+        return decode_attention(q[:, 0], cache_k, cache_v, wpos[:, 0], work,
+                                layer=layer, heads=heads,
+                                block_t=bt)[:, None]
     cache_k, cache_v = cache_k[layer], cache_v[layer]
     T = cache_k.shape[1]
     own = (jnp.arange(D) // dh)[None, :] == jnp.arange(heads)[:, None]
@@ -173,8 +202,8 @@ def _attn_decode(q, cache_k, cache_v, layer, wpos, heads):
     out = jnp.einsum("smt,std->smd", probs.reshape(S, Tq * heads, T),
                      cache_v, preferred_element_type=jnp.float32)
     # Each head keeps its own columns: one non-zero term a column, so exact.
-    return jnp.where(own, out.reshape(S, Tq, heads, D),
-                     0).sum(2).astype(q.dtype)
+    out = jnp.where(own, out.reshape(S, Tq, heads, D), 0).sum(2)
+    return jnp.where((wpos >= 0)[:, :, None], out, 0).astype(q.dtype)
 
 
 def _layer(p, x, cfg, attend, lora=None, lora_idx=None):
@@ -406,9 +435,10 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     sampling-step counter (keeps fold_in(seed, t) aligned with the batched
     path), ``finished`` pins retired/empty slots — they still compute (the
     price of static shapes) but their ``pos`` freezes so they only overwrite
-    their own dead cache row, and they attend to its first position alone.
-    Attention reads each layer of the pool where it lies, as far as each
-    slot has written (:func:`_attn_decode`).
+    their own dead cache row, and attention counts them *dead*: they read
+    nothing and attend to zeros.  Attention reads each layer of the pool
+    where it lies, as far as each live slot has written
+    (:func:`_attn_decode`), from one list of live blocks a step.
 
     Returns (emits [S, seg], cache_k, cache_v, tok, pos, step, finished).
     Step t emits the token decided before it, exactly like :func:`generate`,
@@ -438,8 +468,9 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
             pres = None
         wpos = jnp.minimum(pos, total - 1)
         # A finished slot's token is pinned to EOS whatever it attends to:
-        # it reads its row's first position and no further.
-        last = jnp.where(finished, 0, wpos)
+        # it is dead to attention, which reads nothing of its row.
+        last = jnp.where(finished, -1, wpos)
+        work = _decode_work(last, total, cfg.d_model, cache_k.dtype)
         x = (params["wte"].astype(dtype)[tok]
              + params["wpe"].astype(dtype)[jnp.minimum(wpos, cfg.max_positions - 1)]
              )[:, None, :]
@@ -449,7 +480,7 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
                 cache_k = cache_k.at[i, rows, wpos].set(k[:, 0])
                 cache_v = cache_v.at[i, rows, wpos].set(v[:, 0])
                 return _attn_decode(q, cache_k, cache_v, i, last[:, None],
-                                    cfg.heads)
+                                    cfg.heads, work)
 
             x = _layer(params[f"layer{i}"], x, cfg, attend,
                        lora=_lora_of(params, i, adapter_idx),
@@ -583,7 +614,8 @@ def decode_segment_paged(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     def sstep(carry, _):
         cache_k, cache_v, tok, pos, t, finished = carry
         wpos = jnp.minimum(pos, VT - 1)
-        last = jnp.where(finished, 0, wpos)  # as decode_segment's
+        last = jnp.where(finished, -1, wpos)  # dead, as decode_segment's
+        work = _decode_work(last, VT, cfg.d_model, cache_k.dtype)
         x = (params["wte"].astype(dtype)[tok]
              + params["wpe"].astype(dtype)[
                  jnp.minimum(wpos, cfg.max_positions - 1)])[:, None, :]
@@ -596,7 +628,7 @@ def decode_segment_paged(params: dict, cache_k: jax.Array, cache_v: jax.Array,
                                        v, block_size)
                 return _attn_decode(q, _paged_view(cache_k, i, table)[None],
                                     _paged_view(cache_v, i, table)[None],
-                                    0, last[:, None], cfg.heads)
+                                    0, last[:, None], cfg.heads, work)
 
             x = _layer(params[f"layer{i}"], x, cfg, attend,
                        lora=_lora_of(params, i, adapter_idx),
@@ -1058,6 +1090,10 @@ def make_gpt2_servable(name: str, cfg_model):
         "admit_spec": admit_spec,
         "cache_shape": (cfg.layers, gen_slots, total, cfg.d_model),
         "cache_dtype": dtype,
+        # Positions decode attention reads a live slot's row in: the
+        # kernel's block length, or the whole row (the ``jax.numpy`` form).
+        "read_block": (_decode_kernel_block(1, total, cfg.d_model, dtype)
+                       or total),
         # Routed lane: admission prefills run bf16, the slot-pool segment
         # routes on the POOL size (the decode-row count of its program) —
         # consistent with the fixed-batch path at the same row count, so the

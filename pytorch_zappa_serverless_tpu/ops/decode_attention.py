@@ -1,13 +1,24 @@
-"""Pallas decode attention over the slot pool — one query a slot, read in place.
+"""Pallas decode attention over the slot pool — one query a slot, live blocks only.
 
 The slot pool is ``[L, S, T, D]`` with the heads side by side in ``D``.  The
 decode step's attention for layer ``l`` needs ``pool[l]`` and nothing else,
-and of it only the positions each slot has written (``wpos[s]``, a fifth of
-``T`` at the benchmark's chat traffic).  This kernel takes its blocks
-straight out of the 4-D pool through the BlockSpec index map (layer static),
-so nothing ``[S, T, D]``-sized is sliced, copied or transposed, and stops at
-each slot's last written block: a block past it is skipped (``pl.when``) and
-its index clamped to the last live one, so the pipeline issues no DMA for it.
+and of it only the positions each generating slot has written (``wpos[s]``,
+a fifth of ``T`` at the benchmark's chat traffic, where most slots generate
+nothing).  The kernel's iteration space is therefore the step's list of live
+``(slot, block)`` pairs (:func:`work_list`), and nothing else is visited: a
+slot with ``wpos < 0`` is *dead* — it is read nowhere and its output row is
+zeros — and a block past a slot's last written position costs no grid step,
+no DMA and no arithmetic.  The list is built once a step from ``wpos`` and
+shared by every layer's call through scalar prefetch.
+
+The blocks come straight out of the 4-D pool through the BlockSpec index
+maps (layer static, slot and block read from the list), so nothing
+``[S, T, D]``-sized is sliced, copied or transposed.  The grid is
+one-dimensional and its bound is the list's count, a value of the step: a
+call costs a constant plus a term in live blocks.  (A single grid step that
+walks the list with its own double-buffered copies measured the same on the
+v5e at ``D`` 1280, and Mosaic refuses its slice of the pool at ``D`` 1600,
+which is not a multiple of the 128 lanes: PERF.md section 6, PR 30.)
 
 Math, as ``models/gpt2._attn_decode``'s ``jax.numpy`` form: head ``h``'s
 query sits in its own ``D/H`` columns of an ``[H, D]`` block with zeros
@@ -15,13 +26,14 @@ elsewhere, so row ``h`` of ``q_heads @ K^T`` is head ``h``'s scores and row
 ``h`` of ``probs @ V`` carries its output in those same columns; scores and
 softmax in float32 (online over blocks: the sum over positions is
 reordered, nothing is left out), probabilities and values in the pool's
-dtype, a position beyond ``wpos`` weighs exactly zero.
+dtype, a position beyond ``wpos`` weighs exactly zero.  Running max,
+denominator and the ``[H, D]`` accumulator live in VMEM scratch across a
+slot's blocks (flash_attention.py's pattern): reset at its block 0, written
+out at its last.
 
-grid ``(S, T / block_t)``, positions innermost; running max, denominator and
-the ``[H, D]`` accumulator live in VMEM scratch across a slot's blocks
-(flash_attention.py's pattern).  ``interpret=True`` runs the same kernel on
-the CPU for tests/test_decode_attention.py; the serving path's choice of
-kernel or ``jax.numpy`` form is by backend, in ``models/gpt2._attn_decode``.
+``interpret=True`` runs the same kernel on the CPU for
+tests/test_decode_attention.py; the serving path's choice of kernel or
+``jax.numpy`` form is by backend, in ``models/gpt2._attn_decode``.
 """
 
 from __future__ import annotations
@@ -35,59 +47,96 @@ from jax.experimental.pallas import tpu as pltpu
 
 _MASKED = -1e9  # as the jnp form's: exp(_MASKED - max) is exactly 0
 
+# Bytes a block of K (or of V) aims at.  From the kernel alone on the v5e
+# (chip_smoke.py; PERF.md section 6, PR 30): a copy of half a MiB hides the
+# grid step that fetches the next one, so a full pool costs what blocks
+# twice as long cost, and a slot's last block wastes half as much.
+_SLAB_BYTES = 512 << 10
+# What K's and V's blocks, two buffers each, may take of the 16 MiB of VMEM
+# a kernel is given by default.
+_VMEM_BYTES = 8 << 20
 
-def pick_block_t(total: int, want: int = 256) -> int:
-    """Largest multiple of 16 (the bf16 sublane tile) ≤ ``want`` that divides
-    ``total``; ``total`` itself when none does (one block a slot)."""
-    for cand in range(min(want, total) // 16 * 16, 15, -16):
+
+def pick_block_t(total: int, d: int, dtype) -> int:
+    """Positions a block holds, from what the pool shows: the largest
+    multiple of the dtype's sublane tile (8 rows of 32 bits: 16 for
+    bfloat16) that divides ``total`` and keeps ``[block, d]`` within
+    ``_SLAB_BYTES``; ``total`` itself when no such multiple divides it (one
+    block a slot, which the caller weighs with :func:`fits_vmem`)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    tile = 8 * 4 // itemsize
+    want = min(_SLAB_BYTES // (d * itemsize), total)
+    for cand in range(want // tile * tile, 0, -tile):
         if total % cand == 0:
             return cand
     return total
 
 
-def _kernel(wpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            block_t: int, head_dim: int):
-    s, t = pl.program_id(0), pl.program_id(1)
-    last = wpos_ref[s]
+def fits_vmem(block_t: int, d: int, dtype) -> bool:
+    """Whether the pipeline's four blocks of ``block_t`` positions fit."""
+    return 4 * block_t * d * jnp.dtype(dtype).itemsize <= _VMEM_BYTES
+
+
+def work_list(wpos, total: int, block_t: int):
+    """The live ``(slot, block)`` pairs of a step, compacted in slot order.
+
+    wpos [S] int32, the last position each slot may read, negative for a
+    dead slot → ``(slot [W], block [W], count)`` int32 with ``W = S *
+    total / block_t``; entries from ``count`` on are padding the kernel
+    never visits.  Built once a step, outside the layer loop."""
+    S = wpos.shape[0]
+    per_slot = total // block_t
+    wpos = jnp.minimum(wpos.astype(jnp.int32), total - 1)
+    blocks = jnp.where(wpos >= 0, wpos // block_t + 1, 0)          # [S]
+    ends = jnp.cumsum(blocks)
+    i = jnp.arange(S * per_slot, dtype=jnp.int32)
+    slot = jnp.minimum((ends[None, :] <= i[:, None]).sum(1), S - 1)
+    block = jnp.clip(i - (ends - blocks)[slot], 0, per_slot - 1)
+    return (slot.astype(jnp.int32), block.astype(jnp.int32),
+            ends[-1].astype(jnp.int32))
+
+
+def _kernel(slot_ref, block_ref, wpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+            l_ref, acc_ref, *, block_t: int, head_dim: int):
+    i = pl.program_id(0)
+    b = block_ref[i]
+    last = wpos_ref[slot_ref[i]]
     rows, D = acc_ref.shape
 
     def own():
-        """Row h owns head h's columns; rows past the last head own none.
-        Built where it is used: a skipped block pays for none of it."""
+        """Row h owns head h's columns; rows past the last head own none."""
         row = jax.lax.broadcasted_iota(jnp.int32, (rows, D), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (rows, D), 1)
         return (col >= row * head_dim) & (col < (row + 1) * head_dim)
 
-    @pl.when(t == 0)
+    @pl.when(b == 0)
     def _():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(t * block_t <= last)
-    def _():
-        # The select runs in float32: the mask comes from int32 iotas, whose
-        # (8, 128) tiling Mosaic will not relayout to bfloat16's (16, 128).
-        k = k_ref[...]
-        qh = jnp.where(own(), q_ref[...].astype(jnp.float32),  # [1, D] -> rows
-                       0.0).astype(k.dtype)
-        scores = jax.lax.dot_general(
-            qh, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [rows, bt]
-        kpos = t * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        scores = jnp.where(kpos <= last, scores, _MASKED)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-        v = v_ref[...]
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    # Every block visited is live.  The select runs in float32: the mask
+    # comes from int32 iotas, whose (8, 128) tiling Mosaic will not relayout
+    # to bfloat16's (16, 128).
+    k = k_ref[...]
+    qh = jnp.where(own(), q_ref[...].astype(jnp.float32),      # [1, D] -> rows
+                   0.0).astype(k.dtype)
+    scores = jax.lax.dot_general(
+        qh, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                   # [rows, bt]
+    kpos = b * block_t + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    scores = jnp.where(kpos <= last, scores, _MASKED)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(scores - m_new)
+    l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+    v = v_ref[...]
+    acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
-    @pl.when(t == pl.num_programs(1) - 1)
+    @pl.when(b == last // block_t)
     def _():
         out = acc_ref[...] / l_ref[...]
         # Each head keeps its own columns: one non-zero term a column.
@@ -97,37 +146,43 @@ def _kernel(wpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("layer", "heads", "block_t",
                                              "interpret"))
-def decode_attention(q, cache_k, cache_v, wpos, *, layer: int, heads: int,
-                     block_t: int | None = None, interpret: bool = False):
+def decode_attention(q, cache_k, cache_v, wpos, work=None, *, layer: int,
+                     heads: int, block_t: int | None = None,
+                     interpret: bool = False):
     """q [S, D] (already scaled), cache_k / cache_v [L, S, T, D], wpos [S]
-    int32 the last position each slot may read (0 <= wpos < T) → [S, D]."""
+    int32 the last position each slot may read (``wpos < T``; negative: the
+    slot is dead, read nowhere, its row zeros) → [S, D].  ``work`` is
+    :func:`work_list` of the same ``wpos`` and block length, from a caller
+    that builds it once for many layers."""
     S, D = q.shape
     T = cache_k.shape[2]
-    bt = block_t or pick_block_t(T)
+    bt = block_t or pick_block_t(T, D, cache_k.dtype)
     if T % bt:
         raise ValueError(f"block_t {bt} does not divide the pool's {T} "
                          "positions")
+    wpos = wpos.astype(jnp.int32)
+    slot, block, count = work_list(wpos, T, bt) if work is None else work
     rows = -(-heads // 16) * 16  # the bf16 sublane tile
-
-    def kv_index(s, t, wpos_ref):
-        return layer, s, jnp.minimum(t, wpos_ref[s] // bt), 0
-
-    kv_spec = pl.BlockSpec((None, None, bt, D), kv_index)
-    q_spec = pl.BlockSpec((None, 1, D), lambda s, t, wpos_ref: (s, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, bt, D),
+        lambda i, slot, block, wpos: (layer, slot[i], block[i], 0))
+    row_spec = pl.BlockSpec((None, 1, D),
+                            lambda i, slot, block, wpos: (slot[i], 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, block_t=bt, head_dim=D // heads),
         out_shape=jax.ShapeDtypeStruct((S, 1, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(S, T // bt),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
+            num_scalar_prefetch=3,
+            grid=(count,),  # a dynamic bound: the live blocks and no more
+            in_specs=[row_spec, kv_spec, kv_spec],
+            out_specs=row_spec,
             scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, 1), jnp.float32),
                             pltpu.VMEM((rows, D), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="decode_attention",
-    )(wpos.astype(jnp.int32), q[:, None, :], cache_k, cache_v)
-    return out[:, 0, :]
+    )(slot, block, wpos, q[:, None, :], cache_k, cache_v)
+    # No grid step visits a dead slot, so nothing wrote its row.
+    return jnp.where((wpos >= 0)[:, None], out[:, 0, :], 0)

@@ -370,6 +370,9 @@ class GenerationScheduler:
         self.params = cm.servable.params
         self.slots: int = meta["slots"]
         self.total: int = meta["total"]
+        # Positions a live slot's row is read in (a model that does not say
+        # reads whole rows).
+        self.read_block: int = meta.get("read_block", self.total)
         self.eos_id: int = meta["eos_id"]
         self.max_new: int = meta["max_new"]
         self.seg: int = meta["segment_tokens"]
@@ -425,11 +428,13 @@ class GenerationScheduler:
         # int increments from the dispatch thread, read by the loop task.
         self.device_rounds = 0   # guarded-by: dispatch-serialized
         self.segment_rounds = 0  # guarded-by: dispatch-serialized
-        # How much of the pool a segment's attention has to read: per round,
-        # the positions of the slots still generating over slots x total
-        # (decode attention stops at a slot's last written position and
-        # reads one block of a finished slot: ops/decode_attention.py).
+        # How much of the pool a segment's attention has to read, and how
+        # much its copies cover: per round, the positions of the slots still
+        # generating over slots x total, as they are and each rounded up to
+        # ``read_block`` (decode attention visits the live blocks of the
+        # generating slots and nothing else: ops/decode_attention.py).
         self.kv_live_sum = 0.0   # guarded-by: dispatch-serialized
+        self.kv_read_sum = 0.0   # guarded-by: dispatch-serialized
         # Per-token timing (docs/OBSERVABILITY.md §9): streamed-token count
         # for the perf plane's rolling tok/s gauge, plus the split
         # first-token / inter-token histograms (the two move for different
@@ -538,8 +543,10 @@ class GenerationScheduler:
                                 "step": self._step, "fin": self._finished,
                                 "temp": self._temp, "seed": self._seed,
                                 "topk": self._topk, "topp": self._topp})
-            self.kv_live_sum += float(
-                np.minimum(self._pos[~self._finished] + 1, self.total).sum()
+            live = np.minimum(self._pos[~self._finished] + 1, self.total)
+            self.kv_live_sum += float(live.sum()) / (self.slots * self.total)
+            self.kv_read_sum += float(
+                (-(-live // self.read_block) * self.read_block).sum()
             ) / (self.slots * self.total)
             emits, self._cache_k, self._cache_v, tok, pos, step, fin = \
                 self._segment(
@@ -625,6 +632,8 @@ class GenerationScheduler:
                 "prefill_dispatches": self.prefill_dispatches,
                 "tokens_emitted": self.tokens_emitted,
                 "kv_live_share": {"sum": round(self.kv_live_sum, 6),
+                                  "count": self.segment_rounds},
+                "kv_read_share": {"sum": round(self.kv_read_sum, 6),
                                   "count": self.segment_rounds},
                 "latency": {"ttft_ms": self.ttft_hist.snapshot(),
                             "itl_ms": self.itl_hist.snapshot()},

@@ -760,21 +760,24 @@ class MetricsHub:
                    [({"model": m}, s["tokens_emitted"])
                     for m, s in gsnap.items()
                     if s.get("tokens_emitted") is not None])
-            # How much of the slot pool decode attention reads, per segment
-            # round (slot lanes): _sum / _count is the mean share.
-            live = {m: s["kv_live_share"] for m, s in gsnap.items()
-                    if s.get("kv_live_share", {}).get("count")}
-            if live:
-                lines.append("# HELP tpuserve_kv_live_share Positions of the "
-                             "generating slots over slots x total, per "
-                             "segment round")
-                lines.append("# TYPE tpuserve_kv_live_share summary")
-                for m, v in live.items():
+            # How much of the slot pool decode attention has to read
+            # (live), and how much its copies cover (read), per segment round
+            # (slot lanes): _sum / _count is the mean share.
+            for key, what in (("kv_live_share", "Positions of the generating "
+                               "slots"),
+                              ("kv_read_share", "Positions decode "
+                               "attention's copies cover")):
+                share = {m: s[key] for m, s in gsnap.items()
+                         if s.get(key, {}).get("count")}
+                if not share:
+                    continue
+                lines.append(f"# HELP tpuserve_{key} {what} over slots x "
+                             "total, per segment round")
+                lines.append(f"# TYPE tpuserve_{key} summary")
+                for m, v in share.items():
                     label = f'{{model="{_prom_label(m)}"}}'
-                    lines.append(f"tpuserve_kv_live_share_sum{label} "
-                                 f"{v['sum']}")
-                    lines.append(f"tpuserve_kv_live_share_count{label} "
-                                 f"{v['count']}")
+                    lines.append(f"tpuserve_{key}_sum{label} {v['sum']}")
+                    lines.append(f"tpuserve_{key}_count{label} {v['count']}")
         if self.adapters is not None and self.adapters.enabled:
             # Multi-tenant adapters (serving/adapters.py; docs/ADAPTERS.md):
             # per-tenant residency gauge, attach-latency histograms, and the

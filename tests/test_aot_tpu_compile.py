@@ -24,7 +24,12 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from pytorch_zappa_serverless_tpu.ops.decode_attention import decode_attention
+import chip_smoke
+from pytorch_zappa_serverless_tpu.models import gpt2
+from pytorch_zappa_serverless_tpu.ops import (
+    decode_attention as decode_attention_module)
+from pytorch_zappa_serverless_tpu.ops.decode_attention import (
+    decode_attention, pick_block_t, work_list)
 from pytorch_zappa_serverless_tpu.ops.flash_attention import flash_attention
 from pytorch_zappa_serverless_tpu.ops.fused_decode import (
     fused_attn_step, fused_mlp_step)
@@ -91,25 +96,50 @@ def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk, h, d, causal):
 
 # Decode attention over the slot pool [L, S, T, D], a middle layer: the
 # benchmark's two configurations (XL's d 1600 is 12.5 lane tiles; int8-large
-# pools 16 slots), and chip_smoke's GPT-2 small pool of 96 positions, which
-# no multiple of 16 up to 256 divides but itself.
-@pytest.mark.parametrize("layers,slots,total,d,heads", [
-    (48, 8, 960, 1600, 25), (36, 16, 960, 1280, 20), (12, 8, 96, 768, 12)],
-    ids=["xl", "large", "small"])
-def test_decode_attention_compiles_for_v5e(one_chip, layers, slots, total, d,
-                                           heads):
+# pools 16 slots), and chip_smoke's GPT-2 small pool of 96 positions.  The
+# grid's one bound is the count of the work list built from a traced ``wpos``.
+DECODE_POOLS = {"xl": (48, 8, 960, 1600, 25), "large": (36, 16, 960, 1280, 20),
+                "small": (12, 8, 96, 768, 12)}
+
+
+@pytest.mark.parametrize("pool", list(DECODE_POOLS))
+def test_decode_attention_compiles_for_v5e(one_chip, pool):
+    layers, slots, total, d, heads = DECODE_POOLS[pool]
+    bt = pick_block_t(total, d, jnp.bfloat16)
     text = _compile(
         lambda q, ck, cv, wpos: decode_attention(
-            q, ck, cv, wpos, layer=layers // 2, heads=heads),
+            q, ck, cv, wpos, work_list(wpos, total, bt), layer=layers // 2,
+            heads=heads, block_t=bt),
         one_chip,
         ((slots, d), jnp.bfloat16), ((layers, slots, total, d), jnp.bfloat16),
         ((layers, slots, total, d), jnp.bfloat16), ((slots,), jnp.int32))
     assert "tpu_custom_call" in text
     # The pool is the kernel's operand as it lies: nothing as large as one
     # layer of it is sliced or copied on the way in.
-    import chip_smoke
-
     assert not chip_smoke.pool_sized_moves(text, slots * total * d)
+
+
+def test_decode_segment_compiles_for_v5e_with_one_work_list_a_step(
+        one_chip, monkeypatch):
+    """The whole 8-token segment program at the ``small`` shape: every
+    layer's kernel inside the scan, fed by the step's one work list.  The
+    kernel is chosen by backend, and the backend here is the CPU, so the
+    test steers the choice."""
+    layers, slots, total, d, heads = DECODE_POOLS["small"]
+    monkeypatch.setattr(
+        gpt2, "_decode_kernel_block",
+        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
+                                     if Tq == 1 else None))
+    cfg = gpt2.GPT2Config(d_model=d, layers=layers, heads=heads,
+                          ffn_dim=4 * d)
+    built = []
+    monkeypatch.setattr(
+        decode_attention_module, "work_list",
+        lambda *a: built.append(a[1:]) or work_list(*a))
+    segment, args = chip_smoke.segment_program(cfg, slots, total, one_chip)
+    text = segment.lower(*args).compile().as_text()
+    assert built == [(total, pick_block_t(total, d, jnp.bfloat16))]
+    assert text.count("tpu_custom_call") >= layers
 
 
 # The two bf16 fused-decode entry points at GPT-2 small's step shape

@@ -8,8 +8,12 @@ configurations (25 heads of 64 at d 1600, 20 of 64 at d 1280; ``T`` and ``L``
 cut, not the widths), slots at different lengths (0, ``total - 1``, the clamp
 at ``total``), rows that hold garbage beyond their last written position,
 bfloat16 and float32 pools — the attention's output, and the logits of a
-whole decode step built on it.
+whole decode step built on it.  A slot with ``wpos < 0`` is dead: both forms
+read nothing of its row and return zeros for it, and the kernel visits the
+live blocks of its work list and no other.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,11 +21,13 @@ import numpy as np
 import pytest
 
 from pytorch_zappa_serverless_tpu.models import gpt2 as G
+from pytorch_zappa_serverless_tpu.ops import decode_attention as DA
 from pytorch_zappa_serverless_tpu.ops.decode_attention import (
-    decode_attention, pick_block_t)
+    decode_attention, fits_vmem, pick_block_t, work_list)
 
 WIDTHS = [(1600, 25), (1280, 20)]
 T, L = 48, 2
+BT = 16  # the block length of every kernel case here
 # pos per slot: nothing but its own row, mid-block, a block edge, the last
 # row, and one past the pool (the segment clamps it to the last row).
 POS = [0, 5, 16, T - 1, T]
@@ -62,7 +68,7 @@ def _run(impl, q, ck, cv, layer, wpos, heads):
         return G._attn_decode(q, ck, cv, layer, wpos[:, None], heads)
     dh = q.shape[-1] // heads
     return decode_attention((q * dh ** -0.5)[:, 0], ck, cv, wpos,
-                            layer=layer, heads=heads, block_t=16,
+                            layer=layer, heads=heads, block_t=BT,
                             interpret=True)[:, None]
 
 
@@ -129,6 +135,83 @@ def test_several_queries_a_slot_equal_one_at_a_time(D, heads):
     np.testing.assert_allclose(
         np.asarray(many), np.asarray(_reference(q, ck[0], cv[0], wp, heads)),
         atol=2e-5, rtol=2e-5)
+
+
+# wpos per slot, -1 a dead one: nobody live, one live, everybody at the last
+# row, slots that end on a block's last row and on the next block's first,
+# a slot of one position, and dead slots between live ones.
+RAGGED = {
+    "all-dead": [-1, -1, -1, -1],
+    "one-live": [-1, -1, 21, -1],
+    "all-full": [T - 1, T - 1, T - 1, T - 1],
+    "block-edge": [BT - 1, BT, 2 * BT - 1, 2 * BT],
+    "one-position": [0, -1, 0, 40],
+    "dead-between": [-1, 33, -1, 7],
+}
+
+
+def _ragged_pool(rng, wpos, D, dtype, dead_rows=0.0):
+    """Random K and V over ``len(wpos)`` slots; a dead slot's rows hold
+    ``dead_rows`` everywhere."""
+    dead = np.asarray(wpos) < 0
+    out = []
+    for _ in range(2):
+        a = rng.standard_normal((L, len(wpos), T, D)).astype(np.float32)
+        a[:, dead] = dead_rows
+        out.append(jnp.asarray(a, dtype))
+    return out
+
+
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
+@pytest.mark.parametrize("pattern", list(RAGGED))
+@pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
+def test_ragged_pool_live_rows_match_and_dead_rows_are_zero(D, heads,
+                                                            pattern, impl):
+    rng = np.random.default_rng(D + len(pattern))
+    wpos = jnp.asarray(RAGGED[pattern], jnp.int32)
+    ck, cv = _ragged_pool(rng, RAGGED[pattern], D, jnp.float32)
+    q = jnp.asarray(rng.standard_normal((len(wpos), 1, D)), jnp.float32)
+    got = np.asarray(_run(impl, q, ck, cv, 1, wpos, heads))
+    want = np.asarray(_reference(q, ck[1], cv[1],
+                                 jnp.maximum(wpos, 0)[:, None], heads))
+    live = np.asarray(wpos) >= 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
+def test_dead_slot_is_zeros_and_its_nan_reaches_nobody(D, heads, dtype, impl):
+    """NaN everywhere in a dead slot's rows of the pool: its own output is
+    zeros (finite), every other slot's is bit for bit what a clean pool
+    gives."""
+    wpos = jnp.asarray(RAGGED["dead-between"], jnp.int32)
+    clean = _ragged_pool(np.random.default_rng(9), RAGGED["dead-between"], D,
+                         dtype)
+    dirty = _ragged_pool(np.random.default_rng(9), RAGGED["dead-between"], D,
+                         dtype, dead_rows=np.nan)
+    q = jnp.asarray(np.random.default_rng(10).standard_normal(
+        (len(wpos), 1, D)), dtype)
+    a = np.asarray(_run(impl, q, *clean, 0, wpos, heads), np.float32)
+    b = np.asarray(_run(impl, q, *dirty, 0, wpos, heads), np.float32)
+    assert np.array_equal(a, b)
+    assert not b[[0, 2]].any() and b[[1, 3]].any()
+
+
+@pytest.mark.parametrize("pattern", list(RAGGED))
+def test_work_list_holds_the_live_blocks_in_slot_order(pattern):
+    wpos = RAGGED[pattern]
+    slot, block, count = work_list(jnp.asarray(wpos, jnp.int32), T, BT)
+    want = [(s, b) for s, w in enumerate(wpos) for b in range(w // BT + 1)
+            if w >= 0]
+    assert int(count) == len(want)
+    assert slot.shape == block.shape == (len(wpos) * T // BT,)
+    got = list(zip(slot.tolist(), block.tolist()))
+    assert got[:len(want)] == want
+    # What lies past the count is padding, and still a valid index.
+    assert all(0 <= s < len(wpos) and 0 <= b < T // BT for s, b in got)
 
 
 def _step_logits(params, ck, cv, tok, pos, cfg, dtype, attention):
@@ -214,12 +297,85 @@ def test_segment_finished_slot_touches_only_its_own_row():
     assert emits_dirty[2].tolist() == emits[2].tolist()
 
 
-@pytest.mark.parametrize("total,want,block", [
-    (960, 256, 240), (960, 192, 192), (96, 256, 96), (100, 256, 100),
-    (1024, 256, 256), (48, 16, 16)])
-def test_pick_block_t_divides_the_pool(total, want, block):
-    assert pick_block_t(total, want) == block
+def _through_kernel(monkeypatch):
+    """Make ``_attn_decode`` take the kernel here, interpreted, as it does
+    on one TPU chip: the choice is by backend, so the test steers it."""
+    monkeypatch.setattr(
+        G, "_decode_kernel_block",
+        lambda Tq, total, d, dtype: BT if Tq == 1 else None)
+    monkeypatch.setattr(DA, "decode_attention", functools.partial(
+        decode_attention, interpret=True))
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slots", "paged"])
+def test_segment_with_finished_slots_same_tokens_by_kernel_and_jnp(
+        monkeypatch, paged):
+    """A segment over a pool with finished and empty slots, and one that
+    finishes inside the segment, emits the same tokens through the kernel
+    (one work list a step) and through the ``jax.numpy`` form."""
+    cfg = G.GPT2Config(vocab_size=96, d_model=32, layers=2, heads=2,
+                       ffn_dim=64, max_positions=T, eos_id=95)
+    params = jax.tree.map(jnp.asarray, G.init_gpt2_params(2, cfg))
+    rng = np.random.default_rng(6)
+    S = 4
+    ck = jnp.asarray(rng.standard_normal((2, S, T, 32)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((2, S, T, 32)), jnp.float32)
+    tok = jnp.asarray([3, 95, 5, 95], jnp.int32)  # slot 3 finishes at once
+    pos = jnp.asarray([6, 9, 2 * BT - 2, 30], jnp.int32)
+    zeros = jnp.zeros((S,), jnp.int32)
+    finished = jnp.asarray([False, True, False, False])
+
+    def run():
+        if paged:  # one page a slot, so the view is the pool
+            return G.decode_segment_paged(
+                params, ck.reshape(2, S, T, 32), cv.reshape(2, S, T, 32),
+                jnp.arange(S, dtype=jnp.int32)[:, None], tok, pos, zeros,
+                finished, jnp.zeros((S,)), zeros, 6, cfg, T, jnp.float32)
+        return G.decode_segment(params, ck, cv, tok, pos, zeros, finished,
+                                jnp.zeros((S,)), zeros, 6, cfg, jnp.float32)
+
+    plain = run()
+    _through_kernel(monkeypatch)
+    kernel = run()
+    assert kernel[0].tolist() == plain[0].tolist()
+    assert plain[0][1].tolist() == [95] * 6 and plain[0][3, 1:].tolist() == [95] * 5
+    assert kernel[6].tolist() == plain[6].tolist() == [False, True, False, True]
+    np.testing.assert_allclose(np.asarray(kernel[1]), np.asarray(plain[1]),
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_segment_builds_one_work_list_a_step(monkeypatch):
+    """The list of live blocks is built once a step and shared by every
+    layer's kernel call: one ``work_list`` while the scan's body is traced,
+    ``layers`` ``pallas_call``s."""
+    _through_kernel(monkeypatch)
+    built = []
+    monkeypatch.setattr(DA, "work_list",
+                        lambda *a: built.append(a[1:]) or work_list(*a))
+    cfg = G.GPT2Config(vocab_size=96, d_model=32, layers=3, heads=2,
+                       ffn_dim=64, max_positions=T, eos_id=95)
+    params = jax.tree.map(jnp.asarray, G.init_gpt2_params(2, cfg))
+    S = 2
+    pool = jnp.zeros((3, S, T, 32), jnp.float32)
+    zeros = jnp.zeros((S,), jnp.int32)
+    text = str(jax.make_jaxpr(
+        lambda ck, cv: G.decode_segment(
+            params, ck, cv, zeros, zeros + 4, zeros, zeros > 0,
+            jnp.zeros((S,)), zeros, 4, cfg, jnp.float32))(pool, pool))
+    assert built == [(T, BT)]
+    assert text.count("pallas_call[") == cfg.layers
+
+
+@pytest.mark.parametrize("total,d,dtype,block,fits", [
+    (960, 1600, jnp.bfloat16, 160, True), (960, 1280, jnp.bfloat16, 192, True),
+    (96, 768, jnp.bfloat16, 96, True), (100, 1600, jnp.bfloat16, 100, True),
+    (1024, 1600, jnp.float32, 64, True), (48, 128, jnp.float32, 48, True),
+    (960, 65536, jnp.bfloat16, 960, False),
+    (4100, 1600, jnp.bfloat16, 4100, False)])
+def test_pick_block_t_divides_the_pool(total, d, dtype, block, fits):
+    assert pick_block_t(total, d, dtype) == block
     assert total % block == 0
+    assert fits_vmem(block, d, dtype) is fits
 
 
 def test_kernel_rejects_a_block_that_does_not_divide_the_pool():

@@ -227,6 +227,8 @@ async def test_kv_live_share_counts_the_generating_slots(engine):
     try:
         assert sched.gen_snapshot()["kv_live_share"] == {"sum": 0.0,
                                                          "count": 0}
+        assert sched.gen_snapshot()["kv_read_share"] == {"sum": 0.0,
+                                                         "count": 0}
         sample = cm.servable.preprocess({"input_ids": [5, 6, 7]})
         await asyncio.wait_for(sched.submit(sample, max_new=3).done, 60)
         live = sched.gen_snapshot()["kv_live_share"]
@@ -237,6 +239,12 @@ async def test_kv_live_share_counts_the_generating_slots(engine):
         assert rounds == sched.segment_rounds >= 1
         want = sum((3 + 3 * r + 1) / (2 * 20) for r in range(rounds))
         assert live["sum"] == pytest.approx(want, abs=1e-6)
+        # ``kv_read_share``: the same positions, each slot's rounded up to
+        # the block attention reads in.  The ``jax.numpy`` form serves here
+        # and reads whole rows: one row of two a round.
+        assert sched.read_block == 20
+        assert sched.gen_snapshot()["kv_read_share"] == {
+            "sum": pytest.approx(0.5 * rounds), "count": rounds}
     finally:
         await sched.stop()
 

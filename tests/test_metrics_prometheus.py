@@ -121,6 +121,7 @@ def _loaded_hub():
                  "device_rounds": 7, "segment_rounds": 5,
                  "prefill_dispatches": 2, "tokens_emitted": 10,
                  "kv_live_share": {"sum": 0.93, "count": 5},
+                 "kv_read_share": {"sum": 1.25, "count": 5},
                  "latency": _tok_lat},
         'pa"ged\\model': {
             "mode": "paged", "slots": 8, "active": 2, "prefilling": 1,
