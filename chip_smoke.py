@@ -772,7 +772,7 @@ def segment_program(cfg, slots: int, total: int, sharding=None):
     import jax
     import jax.numpy as jnp
 
-    from pytorch_zappa_serverless_tpu.models import gpt2
+    from pytorch_zappa_serverless_tpu.models import decoder, gpt2
 
     D, F, bf = cfg.d_model, cfg.ffn_dim, jnp.bfloat16
 
@@ -796,8 +796,9 @@ def segment_program(cfg, slots: int, total: int, sharding=None):
     i32, f32 = sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.float32)
     segment = jax.jit(
         lambda p, ck, cv, tok, pos, st, fin, temp, seeds, topk, topp:
-        gpt2.decode_segment(p, ck, cv, tok, pos, st, fin, temp, seeds, 8,
-                            cfg, bf, top_k=topk, top_p=topp),
+        decoder.decode_segment(gpt2.family(cfg), p,
+                               decoder.slot_pool(ck, cv), tok, pos, st, fin,
+                               temp, seeds, 8, bf, top_k=topk, top_p=topp),
         donate_argnums=(1, 2))
     return segment, (params, pool, pool, i32, i32, i32,
                      sd(slots, dtype=jnp.bool_), f32, i32, i32, f32)

@@ -1,0 +1,831 @@
+"""What every token-in, token-out decoder shares, each thing once.
+
+A family (models/gpt2.py is one) brings a block — how a token becomes a row,
+one layer, the final norm, the head — as a :class:`Family`, and gets back
+everything between the scheduler and the block: the cache seam
+(:class:`SlotPool`, :class:`PagedPool`), the one point at which the programs
+differ in *where* the cache is; the trunk over its layers; the segment's
+scan (models/whisper.py runs its own layers under it); the programs
+serving/generation.py jits; and the servable with the
+``meta["continuous"]`` contract of both schedulers.
+
+TPU-first structure, one jitted program per (batch, prompt-bucket):
+
+- **Prefill + scan split**: the whole prompt runs in ONE batched forward —
+  large MXU matmuls filling the KV cache for every position at once — and
+  only the ``max_new`` generated tokens pay the sequential ``lax.scan``.
+  A P-token prompt costs one forward, not P scan steps.
+- **Ragged prompts inside a bucket**: per-row ``length`` rides as an input;
+  attention masks key positions ``>= len_i`` during prefill, the first
+  generated token reads its logits from position ``len_i - 1``, and step t
+  writes its KV at per-row position ``len_i + t`` (a batched scatter), so
+  rows of different lengths share one compiled program with zero recompiles.
+"""
+
+from __future__ import annotations
+
+import hashlib
+from dataclasses import dataclass
+from typing import Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops import decode_attention
+from ..ops.paged_attention import gather_kv, paged_index
+from ..ops.sampling import (DRAFT_SEED_SALT, apply_repetition_penalty,
+                            choose)
+
+
+@dataclass(frozen=True)
+class Family:
+    """A family's block, and the numbers the programs read of it.
+
+    ``embed(params, tokens, dtype)`` → a row a token; ``positions(params,
+    dtype)`` → the learned position table added to them, or None.
+    ``layer(layer_params, x, attend, lora=None, lora_idx=None)`` → one block
+    over x [B, Tq, D]; it calls ``attend(q, k, v)`` once with its fresh
+    projections ([B, Tq, width]) and gets the attention output back — the
+    single point where the phases differ, which the programs fill in.
+    ``norm(params, x)`` is the final norm, ``head(params, x [N, D])`` the
+    float32 logits.  ``pre_tree(p)`` / ``dec_tree(p, rows)`` pick the weights
+    of a prefill or of a program of ``rows`` decode rows, for a family that
+    holds more than one tree.
+    """
+    embed: Callable
+    positions: Callable | None
+    layer: Callable
+    norm: Callable
+    head: Callable
+    layers: int
+    width: int  # of a cache row: a layer's K (and V) with heads side by side
+    heads: int
+    eos_id: int
+    max_positions: int
+    vocab_size: int
+    pre_tree: Callable = lambda p: p
+    dec_tree: Callable = lambda p, rows: p
+
+
+# ---------------------------------------------------------------------------
+# The cache seam
+# ---------------------------------------------------------------------------
+
+class SlotPool(NamedTuple):
+    """A row a slot: ``k``, ``v`` [L, S, T, D].  ``rows`` is ``arange(S)``,
+    made where the pool is (:func:`slot_pool`), outside any scan.  One query
+    a slot: nothing feeds it several."""
+    k: jax.Array
+    v: jax.Array
+    rows: jax.Array
+
+    @property
+    def positions(self) -> int:
+        return self.k.shape[2]
+
+    def write(self, layer, wpos, k, v):
+        """This layer's ``k``, ``v`` [S, 1, D] at ``wpos`` [S]."""
+        ck = self.k.at[layer, self.rows, wpos].set(k[:, 0])
+        return self._replace(k=ck,
+                             v=self.v.at[layer, self.rows, wpos].set(v[:, 0]))
+
+    def attend(self, layer, q, wpos, heads, work=None):
+        """Over this layer where it lies, each slot as far as ``wpos`` [S]
+        (negative: dead, reads nothing)."""
+        return decode_attention.attend(q, self.k, self.v, layer,
+                                       wpos[:, None], heads, work)
+
+
+def slot_pool(k, v) -> SlotPool:
+    return SlotPool(k, v, jnp.arange(k.shape[1]))
+
+
+class PagedPool(NamedTuple):
+    """Fixed-size pages ``k``, ``v`` [L, NB, BS, D] and a block table a row
+    [S, MB] (docs/GENERATION.md): writes route through the table, attention
+    runs over the gathered *virtual* cache — value-identical to the slot
+    pool at the positions a row has written, masked exact-zero beyond them,
+    so the bit-parity story of the slot pool carries over.  Finished and
+    empty rows carry an all-trash table row (serving/kvcache.py), so their
+    frozen-position writes land in the shared trash page."""
+    k: jax.Array
+    v: jax.Array
+    table: jax.Array
+    block_size: int
+
+    @property
+    def positions(self) -> int:
+        return self.table.shape[1] * self.block_size
+
+    def write(self, layer, wpos, k, v):
+        """This layer's ``k``, ``v`` [S, Tq, D] at ``wpos`` [S, Tq] (or [S]
+        for one query a slot), absolute and clipped to the virtual range."""
+        def put(pages, values):
+            bidx, off = paged_index(
+                self.table, wpos if wpos.ndim == 2 else wpos[:, None],
+                self.block_size)
+            return pages.at[layer, bidx, off].set(values)
+
+        ck = put(self.k, k)
+        return self._replace(k=ck, v=put(self.v, v))
+
+    def _virtual(self, pages, layer):
+        return gather_kv(pages[layer], self.table)
+
+    def view(self, layer):
+        """This layer's virtual cache, K and V [S, MB*BS, D]."""
+        return self._virtual(self.k, layer), self._virtual(self.v, layer)
+
+    def attend(self, layer, q, wpos, heads, work=None):
+        """Over this layer's virtual cache, each query as far as ``wpos``
+        [S, Tq] (or [S])."""
+        return decode_attention.attend(
+            q, self._virtual(self.k, layer)[None],
+            self._virtual(self.v, layer)[None], 0,
+            wpos if wpos.ndim == 2 else wpos[:, None], heads, work)
+
+
+def _attn(q, k, v, mask_bias, heads):
+    """Prefill attention, heads split out: q [B,Tq,D], k/v [B,Tk,D],
+    mask_bias [B,1,Tq,Tk] → [B,Tq,D]."""
+    def split(x):
+        B, T, D = x.shape
+        return x.reshape(B, T, heads, D // heads)
+
+    q, k, v = split(q), split(k), split(v)
+    scale = q.shape[-1] ** -0.5
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k).astype(jnp.float32)
+    probs = jax.nn.softmax(scores + mask_bias, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    B, Tq = out.shape[:2]
+    return out.reshape(B, Tq, -1)
+
+
+# ---------------------------------------------------------------------------
+# The trunk and the segment's scan
+# ---------------------------------------------------------------------------
+
+def _embed(fam: Family, params, tokens, pos, dtype, clamp=True):
+    """``tokens`` at ``pos`` as rows.  A learned position is clamped to its
+    table (defensive: the servable's guard keeps every stream inside it)."""
+    x = fam.embed(params, tokens, dtype)
+    if fam.positions is None:
+        return x
+    table = fam.positions(params, dtype)
+    return x + table[jnp.minimum(pos, fam.max_positions - 1) if clamp
+                     else pos]
+
+
+def _trunk(fam: Family, params, x, cache, attend, adapter_idx=None):
+    """Every layer of the family over ``x`` (embedded by the program, which
+    builds its masks after it), then the final norm → ``(x, cache)``.
+    ``attend(cache, i, q, k, v) -> (cache, out)`` stores layer ``i``'s K/V
+    however the program caches and returns the attention output.
+    ``adapter_idx`` [B] routes each row through its tenant's LoRA slot of
+    ``params["__adapters__"]`` (docs/ADAPTERS.md; 0 = base passthrough)."""
+    stacks = None if adapter_idx is None else params.get("__adapters__")
+    for i in range(fam.layers):
+        def layer_attend(q, k, v, i=i):
+            nonlocal cache
+            cache, out = attend(cache, i, q, k, v)
+            return out
+
+        x = fam.layer(params[f"layer{i}"], x, layer_attend,
+                      lora=None if stacks is None else stacks.get(f"layer{i}"),
+                      lora_idx=adapter_idx)
+    return fam.norm(params, x), cache
+
+
+def _write_then_attend(fam, wpos, last, work=None):
+    """The decode programs' ``attend``: this layer's K/V in at ``wpos``,
+    then each query over the layer as far as ``last``."""
+    def attend(pool, i, q, k, v):
+        pool = pool.write(i, wpos, k, v)
+        return pool, pool.attend(i, q, last, fam.heads, work)
+
+    return attend
+
+
+def _decode_logits(fam, params, pool, tok, wpos, last, work, dtype,
+                   adapter_idx=None):
+    """One token a slot through the trunk → (logits [S, V], pool)."""
+    x = _embed(fam, params, tok, wpos, dtype)[:, None, :]
+    x, pool = _trunk(fam, params, x, pool,
+                     _write_then_attend(fam, wpos, last, work), adapter_idx)
+    return fam.head(params, x[:, 0]), pool
+
+
+def segment_scan(step, cache, tok, pos, t, finished, seg: int, eos_id: int,
+                 seen=None):
+    """``seg`` steps of ``step`` over every slot, under the emit and finish
+    rules every streaming decoder here shares.
+
+    Per-slot carried state (all [S]): ``tok`` the next token to feed, ``pos``
+    its cache write position (= prompt_len + steps_generated), ``t`` the
+    sampling-step counter (keeps fold_in(seed, t) aligned with the batched
+    path), ``finished`` pins retired/empty slots.  Step t emits the token
+    decided *before* it, so a lone request's stream equals the fixed-batch
+    output bit-for-bit; a row pins to EOS after its first EOS, and its
+    ``pos`` freezes so it only overwrites its own dead cache row.
+    ``step(cache, tok, pos, t, finished, seen) -> (cache, nxt, seen)`` is the
+    model: it feeds ``tok`` at ``pos`` and decides the next token (drawing
+    with ``t + 1``); ``cache`` is whatever pytree it threads, ``seen`` the
+    fixed-batch lane's seen-token mask (None elsewhere).  Returns ``(emits
+    [S, seg], *cache's leaves, tok, pos, t, finished)``, the scheduler's
+    segment contract.
+    """
+    def body(carry, _):
+        cache, tok, pos, t, finished, seen = carry
+        cache, nxt, seen = step(cache, tok, pos, t, finished, seen)
+        emit = jnp.where(finished, eos_id, tok)
+        fin = finished | (tok == eos_id)
+        tok_next = jnp.where(fin, eos_id, nxt)
+        pos_next = jnp.where(fin, pos, pos + 1)
+        return (cache, tok_next, pos_next, t + 1, fin, seen), emit
+
+    carry, emits = jax.lax.scan(body, (cache, tok, pos, t, finished, seen),
+                                None, length=seg)
+    return (jnp.transpose(emits, (1, 0)), *jax.tree.leaves(carry[0]),
+            *carry[1:5])
+
+
+# ---------------------------------------------------------------------------
+# The programs
+# ---------------------------------------------------------------------------
+
+def prefill(fam: Family, params: dict, tokens: jax.Array, lengths: jax.Array,
+            total: int, dtype=jnp.bfloat16, adapter_idx=None):
+    """Whole-prompt forward: fills the KV cache, returns last-token logits.
+
+    tokens [B, P] int32 (zero-padded), lengths [B] int32, ``total`` the cache
+    size (P + max_new).  Returns (logits [B, V] at position length-1,
+    cache_k, cache_v [L, B, total, D]): rows for a slot pool, made here from
+    nothing, so each layer writes a prefix and attends its own fresh K/V.
+    """
+    B, P = tokens.shape
+    pos = jnp.arange(P)
+    x = _embed(fam, params, tokens, pos, dtype, clamp=False)
+    # Causal AND ragged: query i attends keys j<=i that are real (j < len).
+    causal = pos[None, :, None] >= pos[None, None, :]          # [1,P,P]
+    real = pos[None, None, :] < lengths[:, None, None]          # [B,1,P]
+    mask_bias = jnp.where(causal & real, 0.0, -1e9).astype(jnp.float32)[:, None]
+    cache = (jnp.zeros((fam.layers, B, total, fam.width), dtype),
+             jnp.zeros((fam.layers, B, total, fam.width), dtype))
+
+    def attend(cache, i, q, k, v):
+        ck = cache[0].at[i, :, :P].set(k)
+        return ((ck, cache[1].at[i, :, :P].set(v)),
+                _attn(q, k, v, mask_bias, fam.heads))
+
+    x, cache = _trunk(fam, params, x, cache, attend, adapter_idx)
+    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    return (fam.head(params, last),) + cache
+
+
+def _penalized(logits, seen, repetition_penalty, on):
+    """Runtime-gated like the top-k/top-p sort (ops/sampling.choose): the
+    knob is a jit input, so default penalty-1.0 traffic must not pay the
+    [B, V] selects — lax.cond runs only the taken branch."""
+    return jax.lax.cond(on, lambda args: apply_repetition_penalty(*args),
+                        lambda args: args[0],
+                        (logits, seen, repetition_penalty))
+
+
+def prefill_start(fam: Family, params: dict, tokens: jax.Array,
+                  lengths: jax.Array, temperature: jax.Array,
+                  seeds: jax.Array, total: int, dtype=jnp.bfloat16,
+                  top_k=None, top_p=None, repetition_penalty=None,
+                  presence=None, adapter_idx=None):
+    """Admission program: prefill a batch of requests and pick each one's
+    first token.
+
+    The same prefill as :func:`generate` (so the token chain is
+    bit-identical to the fixed-batch path), returned raw so the scheduler
+    can insert the cache rows into its slot pool.  Returns (first_tok [B],
+    cache_k, cache_v [L, B, total, D]).
+    """
+    logits, cache_k, cache_v = prefill(fam, params, tokens, lengths, total,
+                                       dtype, adapter_idx=adapter_idx)
+    if repetition_penalty is not None:
+        logits = _penalized(logits, presence, repetition_penalty,
+                            jnp.any(repetition_penalty != 1.0))
+    first = choose(logits, temperature, seeds,
+                   jnp.zeros(tokens.shape[:1], jnp.int32), top_k, top_p)
+    return first, cache_k, cache_v
+
+
+def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
+                   pos: jax.Array, step: jax.Array, finished: jax.Array,
+                   temperature: jax.Array, seeds: jax.Array, seg: int,
+                   dtype=jnp.bfloat16, top_k=None, top_p=None,
+                   repetition_penalty=None, presence=None, adapter_idx=None):
+    """Advance every slot of ``pool`` by ``seg`` tokens — the
+    continuous-batching program, over either pool.
+
+    The fixed-batch :func:`generate` runs all ``max_new`` steps in one
+    program: nothing surfaces until the scan ends, finished rows burn full
+    compute, and nobody can join.  Here the same per-step math runs in short
+    segments over a pool: between segments the host streams the emitted
+    tokens, retires finished slots, and prefills queued requests into the
+    free rows — so shapes stay static (one compiled program, reused forever)
+    while membership is dynamic.  Finished and empty slots still compute
+    (the price of static shapes), and attention counts them *dead*: it reads
+    each layer of the pool where it lies, as far as each live slot has
+    written, from one list of live blocks a step.  Returns (emits [S, seg],
+    cache_k, cache_v, tok, pos, step, finished), as :func:`segment_scan`.
+    """
+    total = pool.positions
+    # Repetition penalty (fixed-batch lane only, which is the slot pool —
+    # the streaming lane would need a [S, V] presence buffer donated across
+    # segments; declined there, loudly, in serving/server.py): the presence
+    # mask rides the scan carry, gaining each fed token before its logits
+    # are penalized, so history = prompt + generated-so-far exactly like
+    # HF's processor.  The in-carry scatter touches S elements of a donated
+    # buffer — noise.
+    if repetition_penalty is not None:
+        rep_on = jnp.any(repetition_penalty != 1.0)
+
+    def one(cache, tok, pos, t, finished, seen):
+        wpos = jnp.minimum(pos, total - 1)
+        # A finished slot's token is pinned to EOS whatever it attends to:
+        # it is dead to attention, which reads nothing of its row.
+        last = jnp.where(finished, -1, wpos)
+        work = decode_attention.step_work(last, total, fam.width,
+                                          cache[0].dtype)
+        logits, p = _decode_logits(
+            fam, params, pool._replace(k=cache[0], v=cache[1]), tok, wpos,
+            last, work, dtype, adapter_idx)
+        if seen is not None:
+            seen = seen.at[pool.rows, tok].set(True)
+            logits = _penalized(logits, seen, repetition_penalty, rep_on)
+        nxt = choose(logits, temperature, seeds, t + 1, top_k, top_p)
+        return (p.k, p.v), nxt, seen
+
+    return segment_scan(one, (pool.k, pool.v), tok, pos, step, finished, seg,
+                        fam.eos_id, presence)
+
+
+def generate(fam: Family, params: dict, tokens: jax.Array,
+             lengths: jax.Array, temperature: jax.Array, seeds: jax.Array,
+             max_new: int, dtype=jnp.bfloat16,
+             top_k: jax.Array | None = None,
+             top_p: jax.Array | None = None,
+             repetition_penalty: jax.Array | None = None,
+             adapter_idx: jax.Array | None = None) -> jax.Array:
+    """Prefill + scan generation (greedy or sampled per row).  Returns
+    [B, max_new] int32, EOS-padded after the first EOS.
+
+    One :func:`prefill_start` + a single ``max_new``-length
+    :func:`decode_segment` — the fixed-batch path IS the continuous-batching
+    program at seg=max_new, so batched and streaming serving share one
+    per-step decoder body and cannot drift apart.  ``params`` is the tree as
+    served: the family picks what each half runs with.
+    """
+    B, P = tokens.shape
+    presence = None
+    if repetition_penalty is not None:
+        # Seen-token mask from the prompt (HF semantics: the penalty's
+        # history is prompt + generated-so-far); pad positions excluded.
+        valid = jnp.arange(P)[None, :] < lengths[:, None]
+        presence = jnp.zeros((B, fam.vocab_size), bool).at[
+            jnp.arange(B)[:, None], tokens].max(valid)
+    first, cache_k, cache_v = prefill_start(
+        fam, fam.pre_tree(params), tokens, lengths, temperature, seeds,
+        P + max_new, dtype, top_k=top_k, top_p=top_p, repetition_penalty=repetition_penalty,
+        presence=presence, adapter_idx=adapter_idx)
+    step, finished = jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool)
+    emits, *_ = decode_segment(
+        fam, fam.dec_tree(params, B), slot_pool(cache_k, cache_v), first,
+        lengths, step, finished,
+        temperature, seeds, max_new, dtype, top_k=top_k, top_p=top_p,
+        repetition_penalty=repetition_penalty, presence=presence,
+        adapter_idx=adapter_idx)
+    return emits
+
+
+def prefill_chunk(fam: Family, params: dict, tokens: jax.Array,
+                  start: jax.Array, lengths: jax.Array, pool,
+                  temperature: jax.Array, seeds: jax.Array, top_k: jax.Array,
+                  top_p: jax.Array, dtype=jnp.bfloat16, adapter_idx=None):
+    """One bounded-cost prefill chunk over the paged pool.
+
+    ``tokens`` [G, C] is the chunk's token slice (zero-padded in the final
+    chunk), ``start`` [G] its absolute offset, ``lengths`` [G] the FULL
+    prompt length.  Queries at absolute positions ``start+i`` attend every
+    key ``j <= start+i`` with ``j < length`` — previous chunks' keys come
+    back out of the pool, so chaining chunks reproduces the monolithic
+    :func:`prefill` attention pattern exactly (tests/test_generation_v2.py
+    pins the logits).  The prefix KV cache (serving/prefixcache.py,
+    docs/PREFIX.md) rides this same contract for free: a warm admission's
+    first chunk simply starts at the cached offset, and positions below it
+    resolve through the table to FROZEN shared pages — bit-identical to the
+    keys a cold prefill would have written, so reuse needs no program of
+    its own.  Returns ``(first_tok [G], cache_k, cache_v)``; ``first_tok``
+    is only meaningful for rows whose final chunk this is (the last-position
+    gather clips into the chunk), which is how one compiled program serves
+    every chunk index.
+    """
+    G, C = tokens.shape
+    VT = pool.positions
+    pos = start[:, None] + jnp.arange(C)[None, :]                   # [G, C]
+    wpos = jnp.minimum(pos, VT - 1)
+    x = _embed(fam, params, tokens, pos, dtype)
+    kpos = jnp.arange(VT)
+    keep = ((kpos[None, None, :] <= pos[:, :, None])
+            & (kpos[None, None, :] < lengths[:, None, None]))
+    mask_bias = jnp.where(keep, 0.0, -1e9).astype(jnp.float32)[:, None]
+
+    def attend(pool, i, q, k, v):
+        pool = pool.write(i, wpos, k, v)
+        return pool, _attn(q, *pool.view(i), mask_bias, fam.heads)
+
+    x, pool = _trunk(fam, params, x, pool, attend, adapter_idx)
+    idx = jnp.clip(lengths - 1 - start, 0, C - 1)
+    last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+    first = choose(fam.head(params, last), temperature, seeds,
+                   jnp.zeros((G,), jnp.int32), top_k, top_p)
+    return first, pool.k, pool.v
+
+
+def propose(fam: Family, params: dict, pool, prev: jax.Array, tok: jax.Array,
+            pos: jax.Array, step: jax.Array, finished: jax.Array,
+            temperature: jax.Array, seeds: jax.Array, k: int,
+            dtype=jnp.bfloat16, top_k=None, top_p=None):
+    """Draft half of a speculative tick: ``k`` cheap decode steps proposing
+    the next ``k`` tokens per row, feeding each proposal back in.
+
+    Runs against the DRAFT rung's params and its own paged cache (same block
+    tables as the target — same positions).  The scan runs ``k + 1`` steps:
+    step 0 **backfills** ``prev`` (the chain token at ``pos - 1``) — after a
+    fully-accepted tick the draft never fed its last proposal, leaving a KV
+    hole at ``pos - 1`` that quietly degrades the next tick's acceptance;
+    re-feeding ``prev`` recomputes that position's KV (bit-identical when no
+    hole exists, so the backfill is idempotent).  Step 0's output is
+    discarded and step 1 force-feeds the already-decided ``tok``.  Returns
+    ``(proposals [S, k], draft_logits fp32 [S, k, V], cache_k, cache_v)``;
+    the raw logits stay on device for the verifier's rejection sampling
+    (ops/sampling.speculative_verify).  Sampled rows draw with a salted
+    seed chain (DRAFT_SEED_SALT) so proposals are independent of the plain
+    lane's and the verifier's draws.
+    """
+    S = tok.shape[0]
+    VT = pool.positions
+    draft_seeds = jnp.bitwise_xor(seeds, jnp.int32(DRAFT_SEED_SALT))
+
+    def sstep(carry, _):
+        cache_k, cache_v, cur, pos, t, first = carry
+        wpos = jnp.minimum(pos, VT - 1)
+        logits, p = _decode_logits(
+            fam, params, pool._replace(k=cache_k, v=cache_v), cur, wpos,
+            wpos, None, dtype)
+        nxt = choose(logits, temperature, draft_seeds, t + 1, top_k, top_p)
+        # Backfill step feeds the pending token next; proposal steps feed
+        # the model's own choice.
+        prop = jnp.where(finished, fam.eos_id, jnp.where(first, tok, nxt))
+        pos_next = jnp.where(finished, pos, pos + 1)
+        return ((p.k, p.v, prop, pos_next, jnp.where(first, t, t + 1),
+                 jnp.zeros_like(first)), (prop, logits))
+
+    init = (pool.k, pool.v, prev, jnp.maximum(pos - 1, 0), step,
+            jnp.ones((S,), bool))
+    carry, (props, logits) = jax.lax.scan(sstep, init, None, length=k + 1)
+    # Drop the backfill step's output: props[0] is the forced pending tok,
+    # logits[0] the distribution it was (already) decided from.
+    return (jnp.transpose(props[1:], (1, 0)),
+            jnp.transpose(logits[1:], (1, 0, 2)), carry[0], carry[1])
+
+
+def verify(fam: Family, params: dict, pool, toks: jax.Array, pos: jax.Array,
+           dtype=jnp.bfloat16):
+    """Target half of a speculative tick: ONE batched forward over the
+    pending token + K proposals per row.
+
+    ``toks`` [S, K+1] feeds at absolute positions ``pos..pos+K``: K/V for
+    every fed token are scattered into the pool first, then each query
+    attends it under ``kpos <= qpos`` — the same
+    write-then-read-own-position pattern as the decode step, so the target
+    logits at query ``i`` are exactly what ``K+1`` sequential decode steps
+    would have produced (the greedy ON==OFF parity contract).  Positions
+    past the acceptance point hold rejected-token K/V; the next tick's
+    writes overwrite them before any mask admits a read.  Returns
+    ``(logits fp32 [S, K+1, V], cache_k, cache_v)``.
+    """
+    S, K1 = toks.shape
+    p = pos[:, None] + jnp.arange(K1)[None, :]
+    wp = jnp.minimum(p, pool.positions - 1)
+    x = _embed(fam, params, toks, wp, dtype)
+    x, pool = _trunk(fam, params, x, pool, _write_then_attend(fam, wp, wp))
+    logits = fam.head(params, x.reshape(S * K1, -1)).reshape(S, K1, -1)
+    return logits, pool.k, pool.v
+
+
+# ---------------------------------------------------------------------------
+# The servable
+# ---------------------------------------------------------------------------
+
+def _fallback_tokenize(text: str, vocab_size: int) -> list[int]:
+    """Offline stub (same role as BERT's): whitespace words hashed into the
+    vocab; real deployments point extra.tokenizer at a tokenizer.json."""
+    return [int.from_bytes(hashlib.sha256(w.encode()).digest()[:4], "big")
+            % max(vocab_size - 1, 1) for w in text.split()]
+
+
+# The sampling knobs every admission carries: name, dtype on the device, and
+# "off" (whose Python type is the type of a request's value).
+KNOBS = (("temperature", np.float32, 0.0), ("seed", np.int32, 0),
+         ("top_k", np.int32, 0), ("top_p", np.float32, 1.0))
+
+
+def knob_spec(b: int) -> dict:
+    return {k: jax.ShapeDtypeStruct((b,), dt) for k, dt, _ in KNOBS}
+
+
+def knob_batch(sample) -> dict:
+    """A sample's knobs as batch-1 arrays (one it lacks is off)."""
+    return {k: np.asarray([sample.get(k, off)], dt) for k, dt, off in KNOBS}
+
+
+def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
+                  adapter_dims: dict | None = None, tp_rules=None):
+    """The servable of a token-in, token-out family: ``params`` is its host
+    tree as it will be served (quantized, routed: the family's business),
+    ``adapter_dims`` the ``(in, out)`` of every dense a LoRA may target."""
+    from ..engine.servable import Servable
+    from .vision_common import resolve_dtype
+
+    dtype = resolve_dtype(cfg_model.dtype)
+    max_new = int(cfg_model.extra.get("max_new_tokens", 32))
+    max_seq = max(cfg_model.seq_buckets)
+    if max_seq + max_new > fam.max_positions:
+        # Build-time guard: without it, decode positions past a learned
+        # position table would silently clamp to its last row (_embed's
+        # jnp.minimum is defensive, not a semantics).
+        raise ValueError(
+            f"{name}: max(seq_buckets) + max_new_tokens = {max_seq} + "
+            f"{max_new} exceeds the model's max_positions "
+            f"({fam.max_positions}); shrink seq_buckets or max_new_tokens")
+
+    adapters_on = int(getattr(cfg_model, "adapter_slots", 0)) > 0
+    if adapters_on:
+        # Multi-tenant LoRA slot pool (docs/ADAPTERS.md): fixed-shape zero
+        # stacks baked into the param tree — attach/detach replace leaves
+        # (same shapes, zero recompiles), slot 0 is the reserved base
+        # passthrough, and every request row gathers its own slot
+        # (ops/lora.py).  serving/adapters.AdapterManager owns the slots.
+        from ..ops.lora import zero_stacks
+
+        adapter_dims = adapter_dims or {}  # a family that names none has none
+        targets = tuple(cfg_model.adapter_targets) or ("q", "v")
+        unknown = [t for t in targets if t not in adapter_dims]
+        if unknown:
+            raise ValueError(f"{name}: unknown adapter_targets {unknown}; "
+                             f"supported: {sorted(adapter_dims)}")
+        dims = {t: adapter_dims[t] for t in targets}
+        slots = int(cfg_model.adapter_slots) + 1  # + reserved slot 0
+        rank = max(int(cfg_model.adapter_rank), 1)
+        params["__adapters__"] = {
+            f"layer{i}": zero_stacks(slots, rank, dims)
+            for i in range(fam.layers)}
+    params = jax.device_put(params)  # ONE batched tree transfer: per-leaf
+    # jnp.asarray serializes a host round-trip per buffer.
+
+    tokenizer = None
+    tok_path = cfg_model.extra.get("tokenizer")
+    if tok_path:
+        from tokenizers import Tokenizer
+
+        tokenizer = Tokenizer.from_file(str(tok_path))
+
+    default_temperature = float(cfg_model.extra.get("temperature", 0.0))
+
+    # Over-length policy (extra.overlength): generation defaults to "error"
+    # (a clean 400 — silently dropping context changes what gets generated);
+    # "truncate" keeps the TAIL (ids[-max_seq:], the HF left-truncation
+    # convention for causal LM: the continuation conditions on the most
+    # recent context, not the oldest).
+    overlength = str(cfg_model.extra.get("overlength", "error"))
+    if overlength not in ("truncate", "error"):
+        raise ValueError(f"{name}: extra.overlength must be 'truncate' or "
+                         f"'error', got {overlength!r}")
+
+    def _fit(ids: list[int]) -> list[int]:
+        if len(ids) > max_seq:
+            if overlength == "error":
+                raise ValueError(
+                    f"prompt is {len(ids)} tokens but the longest configured "
+                    f"seq bucket is {max_seq}; send a shorter prompt or set "
+                    f"extra.overlength='truncate' to keep the last {max_seq}")
+            ids = ids[-max_seq:]
+        return ids
+
+    def apply_fn(p, inputs):
+        # The batch is static per bucket: each compiled program bakes in
+        # its regime's weight tree (no runtime branch).
+        return {"tokens": generate(
+            fam, p, inputs["input_ids"], inputs["length"],
+            inputs["temperature"], inputs["seed"], max_new, dtype,
+            top_k=inputs["top_k"], top_p=inputs["top_p"],
+            repetition_penalty=inputs["repetition_penalty"],
+            adapter_idx=inputs.get("adapter_idx"))}
+
+    def input_spec(bucket):
+        b, s = bucket
+        spec = {"input_ids": jax.ShapeDtypeStruct((b, s), jnp.int32),
+                "length": jax.ShapeDtypeStruct((b,), jnp.int32),
+                **knob_spec(b),
+                "repetition_penalty": jax.ShapeDtypeStruct((b,),
+                                                           jnp.float32)}
+        if adapters_on:
+            # Per-row adapter slot index (docs/ADAPTERS.md): pad rows
+            # collate to 0 — the reserved base-passthrough slot.
+            spec["adapter_idx"] = jax.ShapeDtypeStruct((b,), jnp.int32)
+        return spec
+
+    def preprocess(payload):
+        given = payload if isinstance(payload, dict) else {}
+        if "input_ids" in given:
+            ids = [int(i) for i in given["input_ids"]]
+        else:
+            text = payload["text"] if isinstance(payload, dict) else str(
+                payload.decode() if isinstance(payload, bytes) else payload)
+            ids = (tokenizer.encode(text).ids if tokenizer is not None
+                   else _fallback_tokenize(text, fam.vocab_size))
+        arr = np.asarray(_fit(ids or [fam.eos_id]), np.int32)
+        # Knobs are off unless the request sets them.
+        sample = {"input_ids": arr, "length": np.int32(arr.shape[0]),
+                  **{k: dt(given.get(k, default_temperature
+                                     if k == "temperature" else off))
+                     for k, dt, off in KNOBS},
+                  "repetition_penalty": np.float32(
+                      given.get("repetition_penalty", 1.0))}
+        if adapters_on:
+            # Slot 0 = base passthrough; the server overwrites this with
+            # the resolved tenant's slot after the attach gate.
+            sample["adapter_idx"] = np.int32(0)
+        return sample
+
+    def postprocess(out, i):
+        toks = [int(t) for t in out["tokens"][i]]
+        if fam.eos_id in toks:
+            toks = toks[: toks.index(fam.eos_id)]
+        result = {"tokens": toks}
+        if tokenizer is not None:
+            result["text"] = tokenizer.decode(toks)
+        return result
+
+    def collate_lengths(samples, bucket, spec):
+        from ..engine.compiled import default_collate
+
+        batch = default_collate(samples, bucket, spec)
+        # Padded rows must have length>=1: position len-1 gathers row 0's
+        # garbage otherwise fine, but keep the index in range.
+        batch["length"] = np.maximum(batch["length"], 1)
+        return batch
+
+    # Continuous-batching contract (serving/generation.py): slot-pool decode
+    # in `segment_tokens`-step jitted segments with per-request admission via
+    # prefill + insert.  gen_slots bounds concurrent generations; the cache
+    # pool is [L, slots, max_seq+max_new, D].  Admission is model-shaped
+    # (whisper admits AUDIO), so the scheduler drives it through the generic
+    # trio: ``admit_len_of`` (sample -> bucket-size request),
+    # ``collate_admit`` (sample + bucket -> batch-1 payload dict; must carry
+    # "length" [1] and may carry "temperature"/"seed" [1] for the slot
+    # state), ``admit_spec`` (bucket -> payload ShapeDtypeStructs, used by
+    # multi-host followers to join the broadcast), and ``prefill`` takes the
+    # payload dict.
+    gen_slots = int(cfg_model.extra.get("gen_slots", 4))
+    segment_tokens = int(cfg_model.extra.get("segment_tokens", 8))
+    total = max_seq + max_new
+
+    def collate_admit(sample, bucket):
+        ids = np.asarray(sample["input_ids"], np.int32)
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, : ids.shape[0]] = ids
+        return {"input_ids": toks,
+                "length": np.asarray([max(ids.shape[0], 1)], np.int32),
+                **knob_batch(sample)}
+
+    def admit_spec(bucket):
+        return {"input_ids": jax.ShapeDtypeStruct((1, bucket), jnp.int32),
+                "length": jax.ShapeDtypeStruct((1,), jnp.int32),
+                **knob_spec(1)}
+
+    continuous = {
+        "slots": gen_slots,
+        "segment_tokens": segment_tokens,
+        "total": total,
+        "eos_id": fam.eos_id,
+        "max_new": max_new,
+        "prompt_buckets": tuple(sorted(int(s) for s in cfg_model.seq_buckets)),
+        "admit_len_of": lambda s: int(np.asarray(s["input_ids"]).shape[0]),
+        "collate_admit": collate_admit,
+        "admit_spec": admit_spec,
+        "cache_shape": (fam.layers, gen_slots, total, fam.width),
+        "cache_dtype": dtype,
+        # Positions decode attention reads a live slot's row in.
+        "read_block": decode_attention.read_block(total, fam.width, dtype),
+        # Routed lane: admission prefills run on the prefill tree, the
+        # slot-pool segment routes on the POOL size (the decode-row count of
+        # its program) — consistent with the fixed-batch path at the same
+        # row count, so the bit-identical fixed<->continuous parity property
+        # survives routing.
+        "prefill": (lambda p, payload:
+                    prefill_start(fam, fam.pre_tree(p), payload["input_ids"],
+                                  payload["length"], payload["temperature"],
+                                  payload["seed"], total, dtype,
+                                  top_k=payload["top_k"],
+                                  top_p=payload["top_p"])),
+        "segment": (lambda p, ck, cv, tok, pos, st, fin, temp, seeds,
+                    topk, topp:
+                    decode_segment(fam, fam.dec_tree(p, gen_slots),
+                                   slot_pool(ck, cv), tok, pos, st, fin,
+                                   temp, seeds, segment_tokens, dtype,
+                                   top_k=topk, top_p=topp)),
+        "detokenize": ((lambda toks: tokenizer.decode(toks))
+                       if tokenizer is not None else None),
+    }
+
+    # Block-paged contract (serving/generation.PagedGenerationScheduler;
+    # docs/GENERATION.md): pure fns parameterized by the pool layout, jitted
+    # + donated by the scheduler's factory.  Weight-tree routing mirrors the
+    # slot pool's: chunked prefill runs on the prefill tree, decode/propose/
+    # verify route on the pool size — verify uses the SAME tree as the plain
+    # segment so speculation-ON greedy output is byte-identical to
+    # speculation-OFF.
+    def _make_paged(block_size: int, spec_k: int):
+        bs, K = int(block_size), int(spec_k)
+
+        def dec(p):
+            return fam.dec_tree(p, gen_slots)
+
+        return {
+            # prefill_chunk/segment take a trailing per-row adapter slot
+            # index (docs/ADAPTERS.md): the paged scheduler carries it per
+            # stream, so tenants co-decode in one program.  The draft rung
+            # never sees adapters — the scheduler falls back to plain
+            # decode while any adapter stream is active.
+            "prefill_chunk": (
+                lambda p, toks, start, length, ck, cv, table, temp, seed,
+                topk, topp, aidx:
+                prefill_chunk(fam, fam.pre_tree(p), toks, start, length,
+                              PagedPool(ck, cv, table, bs), temp, seed, topk,
+                              topp, dtype,
+                              adapter_idx=aidx if adapters_on else None)),
+            "segment": (
+                lambda p, ck, cv, table, tok, pos, st, fin, temp, seeds,
+                topk, topp, aidx:
+                decode_segment(fam, dec(p), PagedPool(ck, cv, table, bs), tok,
+                               pos, st, fin, temp, seeds, segment_tokens,
+                               dtype, top_k=topk, top_p=topp,
+                               adapter_idx=aidx if adapters_on else None)),
+            "propose": (
+                lambda p, ck, cv, table, prev, tok, pos, st, fin, temp,
+                seeds, topk, topp:
+                propose(fam, dec(p), PagedPool(ck, cv, table, bs), prev, tok,
+                        pos, st, fin, temp, seeds, K, dtype, top_k=topk,
+                        top_p=topp)),
+            "verify": (
+                lambda p, ck, cv, table, toks, pos, fin:
+                verify(fam, dec(p), PagedPool(ck, cv, table, bs), toks, pos,
+                       dtype)),
+        }
+
+    continuous["paged"] = {
+        "make": _make_paged,
+        "cache_shape": (lambda num_blocks, block_size:
+                        (fam.layers, num_blocks, block_size, fam.width)),
+        # Host-side admission adapters: the scheduler is model-agnostic and
+        # builds its own chunk payloads from raw prompt ids + knobs.
+        "prompt_ids": (lambda s:
+                       np.asarray(s["input_ids"], np.int32).reshape(-1)),
+        "knobs": (lambda s: tuple(type(off)(s.get(k, off))
+                                  for k, _, off in KNOBS)),
+        # Per-stream adapter slot (docs/ADAPTERS.md): 0 = base passthrough;
+        # eviction continuations ({**s, ...} in extend_sample) preserve it.
+        "adapter_idx": (lambda s: int(np.asarray(
+            s.get("adapter_idx", 0)))),
+        # Eviction continuation (docs/GENERATION.md "Exhaustion policy"):
+        # prompt + tokens-emitted-so-far becomes the re-admission prompt.
+        "extend_sample": (lambda s, toks: {
+            **s, "input_ids": np.concatenate(
+                [np.asarray(s["input_ids"], np.int32).reshape(-1),
+                 np.asarray(toks, np.int32)]),
+            "length": np.int32(
+                np.asarray(s["input_ids"]).reshape(-1).shape[0] + len(toks))}),
+    }
+
+    meta = {"seq_len_of": lambda s: int(s["input_ids"].shape[0]),
+            "max_new_tokens": max_new, "collate": collate_lengths,
+            "continuous": continuous,
+            "tp_rules": tp_rules}
+    if adapters_on:
+        # Pool layout the AdapterManager builds host stacks against
+        # (serving/adapters.py): slot count INCLUDES the reserved slot 0.
+        meta["adapters"] = {"slots": slots, "rank": rank,
+                            "targets": tuple(cfg_model.adapter_targets),
+                            "dims": dims, "layers": fam.layers}
+    return Servable(
+        name=name, apply_fn=apply_fn, params=params, input_spec=input_spec,
+        preprocess=preprocess, postprocess=postprocess,
+        bucket_axes=("batch", "seq"), meta=meta)

@@ -6,7 +6,7 @@ must run under static shapes with no per-token recompile.  Design:
 
 - **One jitted program per request bucket**: log-mel [B,80,3000] → conv stem →
   4 pre-LN encoder layers → cross-K/V precompute → **prompt prefill in one
-  batched forward** (same structure as models/gpt2.py) → ``lax.scan`` over
+  batched forward** (same structure as models/decoder.py) → ``lax.scan`` over
   only the ``max_new`` generated tokens with a **fixed-size KV cache**
   indexed by the step counter.  No Python in the loop, no dynamic shapes,
   one compile, and the prompt never pays sequential steps.
@@ -31,12 +31,13 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .decoder import KNOBS, knob_batch, knob_spec, segment_scan
 
 
 @dataclass(frozen=True)
@@ -354,7 +355,8 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
                    top_k: jax.Array | None = None,
                    top_p: jax.Array | None = None):
     """Advance every slot by ``seg`` tokens — whisper's continuous-batching
-    kernel (mirror of models/gpt2.py ``decode_segment``; docstring there).
+    program: its own layers under models/decoder.py's ``segment_scan``, which
+    holds the emit and finish rules (docstring there).
 
     ``cache_k``/``cache_v`` are the packed pools from
     :func:`prefill_continuous` ([L, S, CL + total_self, D]); ``pos`` [S] is
@@ -367,6 +369,7 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     slot through ops/sampling.choose, same contract as gpt2.
     """
     from ..ops.sampling import choose
+
     dec = params["decoder"]
     S = tok.shape[0]
     CL = cfg.source_positions
@@ -375,8 +378,8 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
     rows = jnp.arange(S)
     scale = cfg.head_dim ** -0.5
 
-    def sstep(carry, _):
-        cache_k, cache_v, tok, pos, t, fin = carry
+    def one(cache, tok, pos, t, fin, seen):
+        cache_k, cache_v = cache
         wpos = jnp.minimum(pos, total_self - 1)
         x = (dec["embed_tokens"].astype(dtype)[tok]
              + dec["pos_embed"].astype(dtype)[
@@ -407,16 +410,10 @@ def decode_segment(params: dict, cache_k: jax.Array, cache_v: jax.Array,
             nxt = choose(logits, temperature,
                          jnp.zeros((S,), jnp.int32) if seeds is None
                          else seeds, t + 1, top_k, top_p)
-        emit = jnp.where(fin, cfg.eot_id, tok)
-        fin2 = fin | (tok == cfg.eot_id)
-        tok_next = jnp.where(fin2, cfg.eot_id, nxt)
-        pos_next = jnp.where(fin2, pos, pos + 1)
-        return (cache_k, cache_v, tok_next, pos_next, t + 1, fin2), emit
+        return (cache_k, cache_v), nxt, seen
 
-    (cache_k, cache_v, tok, pos, step, finished), emits = jax.lax.scan(
-        sstep, (cache_k, cache_v, tok, pos, step, finished), None, length=seg)
-    return (jnp.transpose(emits, (1, 0)), cache_k, cache_v, tok, pos, step,
-            finished)
+    return segment_scan(one, (cache_k, cache_v), tok, pos, step, finished,
+                        seg, cfg.eot_id)
 
 
 def decode_forced(params: dict, enc_out: jax.Array, tokens: jax.Array,
@@ -612,10 +609,9 @@ def make_whisper_servable(name: str, cfg_model) -> Any:
         # the fixed-batch :predict lane stays greedy (decode_greedy).
         knobs = {}
         if isinstance(payload, dict):
-            for key, cast in (("temperature", float), ("seed", int),
-                              ("top_k", int), ("top_p", float)):
+            for key, _, off in KNOBS:
                 if key in payload:
-                    knobs[key] = cast(payload[key])
+                    knobs[key] = type(off)(payload[key])
         samples = [{"mel": log_mel_spectrogram(w), **knobs} for w in windows]
         return samples[0] if len(samples) == 1 else samples
 
@@ -643,21 +639,13 @@ def make_whisper_servable(name: str, cfg_model) -> Any:
 
     def collate_admit(sample, bucket):
         return {"mel": np.asarray(sample["mel"], np.float32)[None],
-                "length": np.asarray([P], np.int32),
-                "temperature": np.asarray([sample.get("temperature", 0.0)],
-                                          np.float32),
-                "seed": np.asarray([sample.get("seed", 0)], np.int32),
-                "top_k": np.asarray([sample.get("top_k", 0)], np.int32),
-                "top_p": np.asarray([sample.get("top_p", 1.0)], np.float32)}
+                "length": np.asarray([P], np.int32), **knob_batch(sample)}
 
     def admit_spec(bucket):
         return {"mel": jax.ShapeDtypeStruct((1, cfg.n_mels, N_FRAMES),
                                             jnp.float32),
                 "length": jax.ShapeDtypeStruct((1,), jnp.int32),
-                "temperature": jax.ShapeDtypeStruct((1,), jnp.float32),
-                "seed": jax.ShapeDtypeStruct((1,), jnp.int32),
-                "top_k": jax.ShapeDtypeStruct((1,), jnp.int32),
-                "top_p": jax.ShapeDtypeStruct((1,), jnp.float32)}
+                **knob_spec(1)}
 
     continuous = {
         "slots": gen_slots,

@@ -20,20 +20,17 @@ walks the list with its own double-buffered copies measured the same on the
 v5e at ``D`` 1280, and Mosaic refuses its slice of the pool at ``D`` 1600,
 which is not a multiple of the 128 lanes: PERF.md section 6, PR 30.)
 
-Math, as ``models/gpt2._attn_decode``'s ``jax.numpy`` form: head ``h``'s
-query sits in its own ``D/H`` columns of an ``[H, D]`` block with zeros
-elsewhere, so row ``h`` of ``q_heads @ K^T`` is head ``h``'s scores and row
-``h`` of ``probs @ V`` carries its output in those same columns; scores and
-softmax in float32 (online over blocks: the sum over positions is
-reordered, nothing is left out), probabilities and values in the pool's
-dtype, a position beyond ``wpos`` weighs exactly zero.  Running max,
-denominator and the ``[H, D]`` accumulator live in VMEM scratch across a
-slot's blocks (flash_attention.py's pattern): reset at its block 0, written
-out at its last.
+Math, as the ``jax.numpy`` form's (:func:`attend`, whose docstring has the
+``[H, D]`` block that keeps the heads in place), with the softmax online
+over blocks: the sum over positions is reordered, nothing is left out.
+Running max, denominator and the ``[H, D]`` accumulator live in VMEM scratch
+across a slot's blocks (flash_attention.py's pattern): reset at its block 0,
+written out at its last.
 
-``interpret=True`` runs the same kernel on the CPU for
-tests/test_decode_attention.py; the serving path's choice of kernel or
-``jax.numpy`` form is by backend, in ``models/gpt2._attn_decode``.
+A model calls :func:`attend` (with :func:`step_work` once a step, and
+:func:`read_block` for what it reports), which takes the kernel or the
+``jax.numpy`` form by what it can observe (:func:`_kernel_block`).
+``interpret=True`` runs the kernel itself on the CPU for the tests.
 """
 
 from __future__ import annotations
@@ -186,3 +183,85 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, *, layer: int,
     )(slot, block, wpos, q[:, None, :], cache_k, cache_v)
     # No grid step visits a dead slot, so nothing wrote its row.
     return jnp.where((wpos >= 0)[:, None], out[:, 0, :], 0)
+
+
+def _kernel_block(Tq, total, d, dtype):
+    """The block length at which the kernel serves a call, or None where the
+    ``jax.numpy`` form of :func:`attend` does: the CPU, several queries a
+    slot, a process that addresses several devices (a mesh: a Mosaic kernel
+    is not partitioned automatically, and the partitioner splits the einsums
+    over ``D`` as it did the heads), and a pool length that only a block too
+    large for the kernel's VMEM divides."""
+    if (Tq != 1 or jax.default_backend() != "tpu"
+            or jax.device_count() != 1):
+        return None
+    bt = pick_block_t(total, d, dtype)
+    return bt if fits_vmem(bt, d, dtype) else None
+
+
+def read_block(total, d, dtype):
+    """Positions :func:`attend` reads of a live slot's row at a time: the
+    kernel's block length, or the whole row (the ``jax.numpy`` form)."""
+    return _kernel_block(1, total, d, dtype) or total
+
+
+def step_work(last, total, d, dtype):
+    """The step's :func:`work_list` for the kernel, built once from ``last``
+    [S] and shared by every layer's :func:`attend` over ``total`` positions
+    of width ``d``; None where the ``jax.numpy`` form runs, which needs
+    none."""
+    bt = _kernel_block(1, total, d, dtype)
+    return None if bt is None else work_list(last, total, bt)
+
+
+def attend(q, cache_k, cache_v, layer, wpos, heads, work=None):
+    """Decode attention over one layer of the pool, read where it lies.
+
+    q [S, Tq, D] (a slot's one query, or the K+1 of a speculative verify),
+    cache_k / cache_v [L, S, T, D] the whole pool in its own layout (``D``
+    minor, no head split) and ``layer`` which of it to read, wpos [S, Tq]
+    the last position each query may read → [S, Tq, D].  A negative
+    ``wpos`` marks a *dead* query (a finished or empty slot): it reads
+    nothing and its output row is zeros, whatever its row of the pool
+    holds.  ``work`` is :func:`step_work` of ``wpos[:, 0]``.
+
+    Head-split attention makes ``(slot, head)`` batch dimensions, and a pool
+    whose heads lie side by side in ``D`` then has to be sliced out and
+    moved to a heads-major layout, K and V, every layer of every step: that
+    copy was three quarters of the decode step on the chip (PERF.md section
+    6, PR 26).  Here ``slot`` is the only batch dimension and the
+    contraction runs over all of ``D``: head ``h``'s query sits in its own
+    columns of an ``[H, D]`` block with zeros elsewhere, so row ``h`` of
+    ``q_heads @ K^T`` is head ``h``'s scores and row ``h`` of ``probs @ V``
+    carries head ``h``'s output in those same columns.  ``H`` times the
+    multiply-adds of the head-split form, on a step bound by the bytes of
+    the pool.  Scores and softmax in float32, probabilities and values in
+    ``q``'s dtype; a position beyond ``wpos`` weighs exactly zero whatever
+    the row holds there.
+
+    The ``jax.numpy`` form below reads all ``T`` positions, and is what the
+    tests compare the kernel with.
+    """
+    S, Tq, D = q.shape
+    dh = D // heads
+    q = q * dh ** -0.5
+    bt = _kernel_block(Tq, cache_k.shape[2], D, cache_k.dtype)
+    if bt is not None:
+        return decode_attention(q[:, 0], cache_k, cache_v, wpos[:, 0], work,
+                                layer=layer, heads=heads,
+                                block_t=bt)[:, None]
+    cache_k, cache_v = cache_k[layer], cache_v[layer]
+    T = cache_k.shape[1]
+    own = (jnp.arange(D) // dh)[None, :] == jnp.arange(heads)[:, None]
+    qh = jnp.where(own, q[:, :, None, :], 0)                   # [S,Tq,H,D]
+    scores = jnp.einsum("smd,std->smt", qh.reshape(S, Tq * heads, D),
+                        cache_k, preferred_element_type=jnp.float32)
+    keep = jnp.arange(T)[None, None, :] <= wpos[:, :, None]     # [S,Tq,T]
+    scores = jnp.where(keep[:, :, None, :],
+                       scores.reshape(S, Tq, heads, T), -1e9)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("smt,std->smd", probs.reshape(S, Tq * heads, T),
+                     cache_v, preferred_element_type=jnp.float32)
+    # Each head keeps its own columns: one non-zero term a column, so exact.
+    out = jnp.where(own, out.reshape(S, Tq, heads, D), 0).sum(2)
+    return jnp.where((wpos >= 0)[:, :, None], out, 0).astype(q.dtype)

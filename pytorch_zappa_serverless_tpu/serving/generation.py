@@ -22,7 +22,7 @@ answer to both, built so every device program keeps static shapes:
 
 The token chain is bit-identical to the fixed-batch path: same prefill, same
 per-step math, and the sampling key is fold_in(seed, per-row step) on both
-paths (models/gpt2.py ``_choose``), verified in tests/test_generation_stream.py.
+paths (ops/sampling.py ``choose``), verified in tests/test_generation_stream.py.
 
 Concurrency shape (SURVEY §5 race-detection story): all device work runs on
 the engine's single dispatch thread via ``runner.run_fn``; the scheduler
@@ -133,7 +133,7 @@ def build_paged_kernels(cm, block_size: int, num_blocks: int, spec_k: int):
     """Jitted paged kernel set + pool allocator for one model.
 
     The servable's ``meta["continuous"]["paged"]["make"]`` supplies pure fns
-    parameterized by the pool layout (models/gpt2.py); this factory jits
+    parameterized by the pool layout (models/decoder.py); this factory jits
     them with cache donation — the page pool is updated in place across
     every chunk/segment/propose/verify dispatch, exactly like the slot
     pool's donation story.  Used for the target AND (with the draft model's
@@ -1096,8 +1096,8 @@ class PagedGenerationScheduler:
         self._topk = np.zeros((S,), np.int32)   # guarded-by: dispatch-serialized
         self._topp = np.ones((S,), np.float32)  # guarded-by: dispatch-serialized
         # Chain token at pos-1 per slot: the draft's backfill feed (a fully
-        # accepted tick leaves the draft one KV write behind; models/gpt2.py
-        # propose_paged).
+        # accepted tick leaves the draft one KV write behind;
+        # models/decoder.py propose).
         self._prev = np.zeros((S,), np.int32)  # guarded-by: dispatch-serialized
         # Per-slot adapter index (docs/ADAPTERS.md): 0 = base passthrough;
         # speculation falls back to plain decode while any slot carries one

@@ -25,6 +25,7 @@ import pytest
 
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.engine import weights as W
+from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.models import gpt2 as G
 from pytorch_zappa_serverless_tpu.ops import lora as L
 from pytorch_zappa_serverless_tpu.serving.adapters import (
@@ -103,9 +104,10 @@ def test_lora_slot0_passthrough_byte_identical():
     toks = jnp.asarray([[7, 8, 9, 0], [3, 4, 0, 0]], jnp.int32)
     lens = jnp.asarray([3, 2], jnp.int32)
     z, s = jnp.zeros((2,), jnp.float32), jnp.zeros((2,), jnp.int32)
-    base = np.asarray(G.generate(params, toks, lens, z, s, 6, cfg,
+    fam = G.family(cfg)
+    base = np.asarray(D.generate(fam, params, toks, lens, z, s, 6,
                                  jnp.float32))
-    thru = np.asarray(G.generate(with_stacks, toks, lens, z, s, 6, cfg,
+    thru = np.asarray(D.generate(fam, with_stacks, toks, lens, z, s, 6,
                                  jnp.float32,
                                  adapter_idx=jnp.zeros((2,), jnp.int32)))
     np.testing.assert_array_equal(base, thru)
@@ -122,11 +124,13 @@ def test_gpt2_cobatched_generate_matches_solo():
     lens = jnp.asarray([5, 5, 5], jnp.int32)
     z, s = jnp.zeros((3,), jnp.float32), jnp.zeros((3,), jnp.int32)
     aidx = jnp.asarray([1, 2, 0], jnp.int32)
-    mixed = np.asarray(G.generate(params, toks, lens, z, s, 8, cfg,
+    fam = G.family(cfg)
+    mixed = np.asarray(D.generate(fam, params, toks, lens, z, s, 8,
                                   jnp.float32, adapter_idx=aidx))
     for i in range(3):
-        solo = np.asarray(G.generate(params, toks[i:i + 1], lens[i:i + 1],
-                                     z[:1], s[:1], 8, cfg, jnp.float32,
+        solo = np.asarray(D.generate(fam, params, toks[i:i + 1],
+                                     lens[i:i + 1], z[:1], s[:1], 8,
+                                     jnp.float32,
                                      adapter_idx=aidx[i:i + 1]))
         np.testing.assert_array_equal(mixed[i], solo[0])
 

@@ -31,8 +31,6 @@ from pytorch_zappa_serverless_tpu.ops import (
 from pytorch_zappa_serverless_tpu.ops.decode_attention import (
     decode_attention, pick_block_t, work_list)
 from pytorch_zappa_serverless_tpu.ops.flash_attention import flash_attention
-from pytorch_zappa_serverless_tpu.ops.fused_decode import (
-    fused_attn_step, fused_mlp_step)
 from pytorch_zappa_serverless_tpu.ops.int8_matmul import int8_matmul
 
 
@@ -127,7 +125,7 @@ def test_decode_segment_compiles_for_v5e_with_one_work_list_a_step(
     test steers the choice."""
     layers, slots, total, d, heads = DECODE_POOLS["small"]
     monkeypatch.setattr(
-        gpt2, "_decode_kernel_block",
+        decode_attention_module, "_kernel_block",
         lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
                                      if Tq == 1 else None))
     cfg = gpt2.GPT2Config(d_model=d, layers=layers, heads=heads,
@@ -140,33 +138,3 @@ def test_decode_segment_compiles_for_v5e_with_one_work_list_a_step(
     text = segment.lower(*args).compile().as_text()
     assert built == [(total, pick_block_t(total, d, jnp.bfloat16))]
     assert text.count("tpu_custom_call") >= layers
-
-
-# The two bf16 fused-decode entry points at GPT-2 small's step shape
-# (S=8 slots, D=768, T=128 cache rows, F=3072).
-S, D, T, F, HEADS = 8, 768, 128, 3072, 12
-
-
-def test_fused_attn_step_compiles_for_v5e(one_chip):
-    text = _compile(
-        lambda x, lns, lnb, wqkv, bqkv, wout, bout, ck, cv, pos, mask:
-            fused_attn_step(x, lns, lnb, wqkv, bqkv, wout, bout, ck, cv, pos,
-                            mask, heads=HEADS, interpret=False),
-        one_chip,
-        ((S, D), jnp.bfloat16), ((D,), jnp.float32), ((D,), jnp.float32),
-        ((D, 3 * D), jnp.bfloat16), ((3 * D,), jnp.float32),
-        ((D, D), jnp.bfloat16), ((D,), jnp.float32),
-        ((T, S, D), jnp.bfloat16), ((T, S, D), jnp.bfloat16),
-        ((S,), jnp.int32), ((T, S, 1), jnp.float32))
-    assert "tpu_custom_call" in text
-
-
-def test_fused_mlp_step_compiles_for_v5e(one_chip):
-    text = _compile(
-        lambda x, lns, lnb, w1, b1, w2, b2:
-            fused_mlp_step(x, lns, lnb, w1, b1, w2, b2, interpret=False),
-        one_chip,
-        ((S, D), jnp.bfloat16), ((D,), jnp.float32), ((D,), jnp.float32),
-        ((D, F), jnp.bfloat16), ((F,), jnp.float32),
-        ((F, D), jnp.bfloat16), ((D,), jnp.float32))
-    assert "tpu_custom_call" in text

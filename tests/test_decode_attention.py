@@ -1,7 +1,7 @@
 """Decode attention that reads the slot pool where it lies.
 
-``models/gpt2._attn_decode`` (the ``jax.numpy`` form the CPU and the
-several-queries-a-slot callers run) and ``ops/decode_attention`` (the Pallas
+``ops/decode_attention``'s ``attend`` (the ``jax.numpy`` form the CPU and the
+several-queries-a-slot callers run) and its ``decode_attention`` (the Pallas
 kernel of the same contraction, here through its interpret mode) against a
 float32 head-split reference: at the published widths of the benchmark's two
 configurations (25 heads of 64 at d 1600, 20 of 64 at d 1280; ``T`` and ``L``
@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.models import gpt2 as G
 from pytorch_zappa_serverless_tpu.ops import decode_attention as DA
 from pytorch_zappa_serverless_tpu.ops.decode_attention import (
@@ -65,7 +66,7 @@ def _reference(q, k, v, wpos, heads):
 def _run(impl, q, ck, cv, layer, wpos, heads):
     """The attention under test: [S, 1, D] in, [S, 1, D] out."""
     if impl == "jnp":
-        return G._attn_decode(q, ck, cv, layer, wpos[:, None], heads)
+        return DA.attend(q, ck, cv, layer, wpos[:, None], heads)
     dh = q.shape[-1] // heads
     return decode_attention((q * dh ** -0.5)[:, 0], ck, cv, wpos,
                             layer=layer, heads=heads, block_t=BT,
@@ -126,9 +127,9 @@ def test_several_queries_a_slot_equal_one_at_a_time(D, heads):
     q = jnp.asarray(rng.standard_normal((S, Tq, D)), jnp.float32)
     wp = jnp.asarray([[0, 1, 2, 3], [20, 21, 22, 23],
                       [T - 4, T - 3, T - 2, T - 1]], jnp.int32)
-    many = G._attn_decode(q, ck, cv, 0, wp, heads)
+    many = DA.attend(q, ck, cv, 0, wp, heads)
     for j in range(Tq):
-        one = G._attn_decode(q[:, j:j + 1], ck, cv, 0, wp[:, j:j + 1], heads)
+        one = DA.attend(q[:, j:j + 1], ck, cv, 0, wp[:, j:j + 1], heads)
         np.testing.assert_allclose(np.asarray(many[:, j]),
                                    np.asarray(one[:, 0]), atol=1e-5,
                                    rtol=1e-5)
@@ -252,7 +253,7 @@ def test_decode_step_logits_match_float32_reference(D, heads, dtype):
     pos = jnp.asarray(POS, jnp.int32)
     got = _step_logits(
         params, ck, cv, tok, pos, cfg, dtype,
-        lambda q, k, v, i, w: G._attn_decode(q, k, v, i, w[:, None], heads))
+        lambda q, k, v, i, w: DA.attend(q, k, v, i, w[:, None], heads))
     want = _step_logits(
         params, ck.astype(jnp.float32), cv.astype(jnp.float32), tok, pos,
         cfg, jnp.float32,
@@ -279,9 +280,9 @@ def test_segment_finished_slot_touches_only_its_own_row():
     zeros = jnp.zeros((S,), jnp.int32)
 
     def run(finished, ck, cv):
-        return G.decode_segment(params, ck, cv, tok, pos, zeros,
-                                jnp.asarray(finished), jnp.zeros((S,)),
-                                zeros, 4, cfg, jnp.float32)
+        return D.decode_segment(G.family(cfg), params, D.slot_pool(ck, cv),
+                                tok, pos, zeros, jnp.asarray(finished),
+                                jnp.zeros((S,)), zeros, 4, jnp.float32)
 
     emits, k2, _, _, pos2, _, fin2 = run([False, True, False], ck, cv)
     assert pos2.tolist() == [10, 9, T + 4] and fin2.tolist()[1] is True
@@ -298,10 +299,10 @@ def test_segment_finished_slot_touches_only_its_own_row():
 
 
 def _through_kernel(monkeypatch):
-    """Make ``_attn_decode`` take the kernel here, interpreted, as it does
-    on one TPU chip: the choice is by backend, so the test steers it."""
+    """Make ``attend`` take the kernel here, interpreted, as it does on one
+    TPU chip: the choice is by backend, so the test steers it."""
     monkeypatch.setattr(
-        G, "_decode_kernel_block",
+        DA, "_kernel_block",
         lambda Tq, total, d, dtype: BT if Tq == 1 else None)
     monkeypatch.setattr(DA, "decode_attention", functools.partial(
         decode_attention, interpret=True))
@@ -327,12 +328,13 @@ def test_segment_with_finished_slots_same_tokens_by_kernel_and_jnp(
 
     def run():
         if paged:  # one page a slot, so the view is the pool
-            return G.decode_segment_paged(
-                params, ck.reshape(2, S, T, 32), cv.reshape(2, S, T, 32),
-                jnp.arange(S, dtype=jnp.int32)[:, None], tok, pos, zeros,
-                finished, jnp.zeros((S,)), zeros, 6, cfg, T, jnp.float32)
-        return G.decode_segment(params, ck, cv, tok, pos, zeros, finished,
-                                jnp.zeros((S,)), zeros, 6, cfg, jnp.float32)
+            pool = D.PagedPool(ck, cv,
+                               jnp.arange(S, dtype=jnp.int32)[:, None], T)
+        else:
+            pool = D.slot_pool(ck, cv)
+        return D.decode_segment(G.family(cfg), params, pool, tok, pos, zeros,
+                                finished, jnp.zeros((S,)), zeros, 6,
+                                jnp.float32)
 
     plain = run()
     _through_kernel(monkeypatch)
@@ -359,9 +361,10 @@ def test_segment_builds_one_work_list_a_step(monkeypatch):
     pool = jnp.zeros((3, S, T, 32), jnp.float32)
     zeros = jnp.zeros((S,), jnp.int32)
     text = str(jax.make_jaxpr(
-        lambda ck, cv: G.decode_segment(
-            params, ck, cv, zeros, zeros + 4, zeros, zeros > 0,
-            jnp.zeros((S,)), zeros, 4, cfg, jnp.float32))(pool, pool))
+        lambda ck, cv: D.decode_segment(
+            G.family(cfg), params, D.slot_pool(ck, cv), zeros, zeros + 4,
+            zeros, zeros > 0, jnp.zeros((S,)), zeros, 4,
+            jnp.float32))(pool, pool))
     assert built == [(T, BT)]
     assert text.count("pallas_call[") == cfg.layers
 

@@ -1,0 +1,262 @@
+"""models/decoder.py serves a family it has never seen, and its scan alone.
+
+The toy family below exists in this file only: RMS norm, no biases, a gated
+MLP, no learned positions.  It reaches the fixed-batch lane and both
+generation schedulers through ``decoder.make_servable`` with no line changed
+in the package, and is held to a plain float32 loop that keeps no cache.
+Then ``segment_scan`` with a stub for a model: the emit and finish rules
+every streaming decoder here shares.
+"""
+
+import asyncio
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
+from pytorch_zappa_serverless_tpu.models import decoder as D
+from pytorch_zappa_serverless_tpu.utils import registry
+
+pytest_plugins = "aiohttp.pytest_plugin"  # runs the coroutine tests
+
+VOCAB, WIDTH, HEADS, LAYERS, FFN, EOS = 96, 32, 2, 2, 48, 95
+
+
+def _rms(scale, x):
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + 1e-6)
+            * scale).astype(x.dtype)
+
+
+def _toy_layer(p, x, attend, lora=None, lora_idx=None):
+    h = _rms(p["n1"], x)
+    x = x + attend(h @ p["q"], h @ p["k"], h @ p["v"]) @ p["o"]
+    h = _rms(p["n2"], x)
+    return x + (jax.nn.silu(h @ p["gate"]) * (h @ p["up"])) @ p["down"]
+
+
+TOY = D.Family(
+    embed=lambda params, tokens, dtype: params["emb"].astype(dtype)[tokens],
+    positions=None, layer=_toy_layer,
+    norm=lambda params, x: _rms(params["norm"], x),
+    head=lambda params, x: (x @ params["head"]).astype(jnp.float32),
+    layers=LAYERS, width=WIDTH, heads=HEADS, eos_id=EOS, max_positions=64,
+    vocab_size=VOCAB)
+
+
+def _toy_params(seed=0):
+    g = np.random.default_rng(seed)
+
+    def w(*shape):
+        return (g.standard_normal(shape) * 0.3).astype(np.float32)
+
+    ones = np.ones((WIDTH,), np.float32)
+    params = {"emb": w(VOCAB, WIDTH), "norm": ones, "head": w(WIDTH, VOCAB)}
+    for i in range(LAYERS):
+        params[f"layer{i}"] = {
+            "n1": ones, "n2": ones, "q": w(WIDTH, WIDTH), "k": w(WIDTH, WIDTH),
+            "v": w(WIDTH, WIDTH), "o": w(WIDTH, WIDTH),
+            "gate": w(WIDTH, FFN), "up": w(WIDTH, FFN), "down": w(FFN, WIDTH)}
+    return params
+
+
+def _reference_logits(params, ids):
+    """The toy model over a whole sequence, float32, no cache: logits at
+    every position [len(ids), VOCAB]."""
+    n, dh = len(ids), WIDTH // HEADS
+    x = jnp.asarray(params["emb"])[jnp.asarray(ids)]
+    causal = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
+    for i in range(LAYERS):
+        p = params[f"layer{i}"]
+        h = _rms(p["n1"], x)
+        q, k, v = (jnp.reshape(h @ p[m], (n, HEADS, dh)) for m in "qkv")
+        s = jnp.einsum("qhd,khd->hqk", q, k, precision="highest") * dh ** -0.5
+        a = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), axis=-1)
+        x = x + jnp.einsum("hqk,khd->qhd", a, v,
+                           precision="highest").reshape(n, WIDTH) @ p["o"]
+        h = _rms(p["n2"], x)
+        x = x + (jax.nn.silu(h @ p["gate"]) * (h @ p["up"])) @ p["down"]
+    return _rms(params["norm"], x) @ params["head"]
+
+
+def _reference_greedy(params, ids, max_new):
+    ids, out = list(ids), []
+    for _ in range(max_new):
+        tok = int(jnp.argmax(_reference_logits(params, ids)[-1]))
+        if tok == EOS:
+            break
+        out.append(tok)
+        ids.append(tok)
+    return out
+
+
+@pytest.fixture()
+def engine(tmp_path, monkeypatch):
+    """The toy family on both lanes of one engine: ``toy`` (slot scheduler)
+    and ``toy_paged``, registered for this test only."""
+    from pytorch_zappa_serverless_tpu.engine.loader import build_engine
+
+    monkeypatch.setitem(
+        registry._REGISTRY, "toy_decoder",
+        lambda mc: D.make_servable(mc.name, mc, TOY, _toy_params()))
+    monkeypatch.setitem(registry._LATENCY_CLASS, "toy_decoder", "latency")
+    kw = dict(builder="toy_decoder", dtype="float32", batch_buckets=(1, 2),
+              seq_buckets=(16,), coalesce_ms=1.0,
+              extra={"max_new_tokens": 12, "gen_slots": 2,
+                     "segment_tokens": 3})
+    eng = build_engine(ServeConfig(
+        compile_cache_dir=str(tmp_path / "xla"), warmup_at_boot=False,
+        models=[ModelConfig(name="toy", **kw),
+                ModelConfig(name="toy_paged", kv_cache="paged",
+                            kv_block_size=4, **kw)]))
+    yield eng
+    eng.shutdown()
+
+
+def _schedulers(engine):
+    from pytorch_zappa_serverless_tpu.serving.generation import (
+        GenerationScheduler, PagedGenerationScheduler)
+
+    slot, paged = engine.model("toy"), engine.model("toy_paged")
+    return (GenerationScheduler(slot, engine.runner, slot.cfg),
+            PagedGenerationScheduler(paged, engine.runner, paged.cfg))
+
+
+async def _stream(sched, sample):
+    sched.start()
+    try:
+        return await asyncio.wait_for(sched.submit(sample).done, 60)
+    finally:
+        await sched.stop()
+
+
+async def test_toy_slot_stream_equals_fixed_batch_and_reference(engine):
+    cm = engine.model("toy")
+    for ids in ([5, 6, 7], [9, 10, 11, 12, 13], [3]):
+        sample = cm.servable.preprocess({"input_ids": ids})
+        fixed = cm.run_batch([sample])[0][0]["tokens"]
+        assert fixed == _reference_greedy(_toy_params(), ids, 12), ids
+        assert await _stream(_schedulers(engine)[0], sample) == fixed, ids
+
+
+async def test_toy_paged_stream_equals_slot_stream(engine):
+    cm = engine.model("toy")
+    for ids in ([5, 6, 7], list(range(20, 31))):  # 11 ids: three pages
+        sample = cm.servable.preprocess({"input_ids": ids})
+        slot, paged = _schedulers(engine)
+        got = await _stream(paged, sample)
+        assert got == await _stream(slot, sample) and got, ids
+
+
+async def test_toy_sampled_stream_equals_fixed_batch(engine):
+    cm = engine.model("toy")
+    sample = cm.servable.preprocess(
+        {"input_ids": [5, 6, 7], "temperature": 1.3, "seed": 11, "top_k": 5,
+         "top_p": 0.9})
+    fixed = cm.run_batch([sample])[0][0]["tokens"]
+    greedy = cm.run_batch([cm.servable.preprocess(
+        {"input_ids": [5, 6, 7]})])[0][0]["tokens"]
+    assert fixed and fixed != greedy
+    for sched in _schedulers(engine):
+        assert await _stream(sched, sample) == fixed
+
+
+def _pages(P, extra, BS):
+    """An empty paged pool with one row's table over pages 1..MB."""
+    MB = -(-(P + extra) // BS)
+    ck = jnp.zeros((LAYERS, MB + 2, BS, WIDTH), jnp.float32)
+    table = jnp.asarray(np.arange(1, MB + 1, dtype=np.int32)[None])
+    return D.PagedPool(ck, jnp.zeros_like(ck), table, BS)
+
+
+def test_toy_chunked_prefill_equals_monolithic_logits():
+    params = jax.tree.map(jnp.asarray, _toy_params())
+    P, BS, C = 13, 4, 4
+    ids = np.random.default_rng(1).integers(1, 90, (P,)).astype(np.int32)
+    lens, z1 = jnp.asarray([P], jnp.int32), jnp.zeros((1,), jnp.float32)
+    s1 = jnp.zeros((1,), jnp.int32)
+    logits, ck_ref, _ = D.prefill(TOY, params, jnp.asarray(ids[None]), lens,
+                                  P + 3, jnp.float32)
+    want = _reference_logits(_toy_params(), ids)[-1]
+    np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want),
+                               atol=2e-4, rtol=2e-4)
+    pool = _pages(P, 3, BS)
+    for start in range(0, P, C):
+        chunk = np.zeros((1, C), np.int32)
+        chunk[0, :len(ids[start:start + C])] = ids[start:start + C]
+        first, ck, cv = D.prefill_chunk(
+            TOY, params, jnp.asarray(chunk), jnp.asarray([start], jnp.int32),
+            lens, pool, z1, s1, s1, z1 + 1, jnp.float32)
+        pool = pool._replace(k=ck, v=cv)
+    assert int(first[0]) == int(jnp.argmax(logits[0]))
+    virt = np.asarray(pool.view(0)[0])[0, :P]
+    np.testing.assert_array_equal(virt, np.asarray(ck_ref[0, 0, :P]))
+
+
+def test_toy_verify_equals_sequential_decode_steps():
+    """K+1 queries in one forward read what K+1 one-query steps read."""
+    params = jax.tree.map(jnp.asarray, _toy_params())
+    ids = np.random.default_rng(2).integers(1, 90, (9,)).astype(np.int32)
+    P, K1, BS = 5, 4, 4
+    pool = _pages(P, K1, BS)
+    z1, s1 = jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32)
+    chunk = np.zeros((1, 8), np.int32)
+    chunk[0, :P] = ids[:P]
+    _, ck, cv = D.prefill_chunk(TOY, params, jnp.asarray(chunk), s1,
+                                jnp.asarray([P], jnp.int32), pool, z1, s1,
+                                s1, z1 + 1, jnp.float32)
+    pool = pool._replace(k=ck, v=cv)
+    pos = jnp.asarray([P], jnp.int32)
+    many, *_ = D.verify(TOY, params, pool, jnp.asarray(ids[None, P:]), pos,
+                        jnp.float32)
+    for j in range(K1):
+        one, ck, cv = D.verify(TOY, params, pool,
+                               jnp.asarray(ids[None, P + j:P + j + 1]),
+                               pos + j, jnp.float32)
+        pool = pool._replace(k=ck, v=cv)
+        np.testing.assert_allclose(np.asarray(many[0, j]),
+                                   np.asarray(one[0, 0]), atol=1e-5,
+                                   rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(many[0]),
+        np.asarray(_reference_logits(_toy_params(), ids)[P:]), atol=2e-4,
+        rtol=2e-4)
+
+
+# tok, finished at the start; the stub model answers tok + 1 (and 95 = EOS
+# after 12), so what the scan emits, pins and freezes can be written down.
+SCAN_CASES = {
+    "live": ([3, 7], [False, False],
+             [[3, 4, 5, 6], [7, 8, 9, 10]], [14, 24], [False, False]),
+    "finished_at_start": ([3, 7], [False, True],
+                          [[3, 4, 5, 6], [95, 95, 95, 95]], [14, 20],
+                          [False, True]),
+    "reaches_eos": ([11, 3], [False, False],
+                    [[11, 12, 95, 95], [3, 4, 5, 6]], [12, 24],
+                    [True, False]),
+}
+
+
+@pytest.mark.parametrize("case", list(SCAN_CASES))
+def test_segment_scan_emits_pins_and_freezes(case):
+    tok, fin, emits, pos_after, fin_after = SCAN_CASES[case]
+    fed = []
+
+    def stub(cache, tok, pos, t, finished, seen):
+        fed.append((tok, pos, t))
+        return cache + 1, jnp.where(tok >= 12, EOS, tok + 1), seen
+
+    out = D.segment_scan(stub, jnp.zeros((), jnp.int32), jnp.asarray(tok),
+                         jnp.asarray([10, 20]), jnp.asarray([5, 5]),
+                         jnp.asarray(fin), 4, EOS)
+    got_emits, cache, tok2, pos2, t2, fin2 = out
+    assert got_emits.tolist() == emits       # the token decided before
+    assert pos2.tolist() == pos_after        # a finished row's pos freezes
+    assert fin2.tolist() == fin_after
+    assert t2.tolist() == [9, 9] and int(cache) == 4  # four steps, all rows
+    # A finished row feeds EOS from then on, whatever the model answered.
+    assert [int(x) for x, f in zip(tok2, fin_after) if f] == [
+        EOS] * sum(fin_after)
+    assert len(fed) == 1  # traced once: one body, ``seg`` steps of it

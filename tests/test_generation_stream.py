@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
+from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.models import gpt2 as G
 
 pytest_plugins = "aiohttp.pytest_plugin"
@@ -55,20 +56,21 @@ def test_segment_chain_matches_one_shot_generate(temperature):
     temp = jnp.full((2,), temperature, jnp.float32)
     seeds = jnp.asarray([5, 9], jnp.int32)
     max_new = 9
-    want = np.asarray(G.generate(params, toks, lens, temp, seeds, max_new,
-                                 cfg, jnp.float32))
+    fam = G.family(cfg)
+    want = np.asarray(D.generate(fam, params, toks, lens, temp, seeds,
+                                 max_new, jnp.float32))
 
     total = 6 + max_new
-    first, ck, cv = G.prefill_start(params, toks, lens, temp, seeds, total,
-                                    cfg, jnp.float32)
+    first, ck, cv = D.prefill_start(fam, params, toks, lens, temp, seeds,
+                                    total, jnp.float32)
     tok, pos = first, lens
     step = jnp.zeros((2,), jnp.int32)
     fin = jnp.zeros((2,), bool)
     got = []
     for _ in range(3):  # 3 segments x 3 tokens = max_new
-        emits, ck, cv, tok, pos, step, fin = G.decode_segment(
-            params, ck, cv, tok, pos, step, fin, temp, seeds, 3, cfg,
-            jnp.float32)
+        emits, ck, cv, tok, pos, step, fin = D.decode_segment(
+            fam, params, D.slot_pool(ck, cv), tok, pos, step, fin, temp,
+            seeds, 3, jnp.float32)
         got.append(np.asarray(emits))
     np.testing.assert_array_equal(np.concatenate(got, axis=1), want)
 
@@ -83,24 +85,25 @@ def test_segment_frozen_rows_do_not_disturb_neighbors():
     z1 = jnp.zeros((1,), jnp.float32)
     s1 = jnp.zeros((1,), jnp.int32)
     total = 4 + 6
-    first, ck, cv = G.prefill_start(params, toks, lens, z1, s1, total, cfg,
+    fam = G.family(cfg)
+    first, ck, cv = D.prefill_start(fam, params, toks, lens, z1, s1, total,
                                     jnp.float32)
     # Solo row decode.
-    solo, *_ = G.decode_segment(params, ck, cv, first, lens, s1,
-                                jnp.zeros((1,), bool), z1, s1, 6, cfg,
+    solo, *_ = D.decode_segment(fam, params, D.slot_pool(ck, cv), first,
+                                lens, s1, jnp.zeros((1,), bool), z1, s1, 6,
                                 jnp.float32)
     # Same row in slot 0 of a 2-slot pool; slot 1 empty (finished, pos 0).
     L = cfg.layers
     ck2 = jnp.zeros((L, 2, total, cfg.d_model), jnp.float32).at[:, :1].set(ck)
     cv2 = jnp.zeros((L, 2, total, cfg.d_model), jnp.float32).at[:, :1].set(cv)
-    pooled, *_ = G.decode_segment(
-        params, ck2, cv2,
+    pooled, *_ = D.decode_segment(
+        fam, params, D.slot_pool(ck2, cv2),
         jnp.asarray([int(first[0]), cfg.eos_id], jnp.int32),
         jnp.asarray([int(lens[0]), 0], jnp.int32),
         jnp.zeros((2,), jnp.int32),
         jnp.asarray([False, True]),
         jnp.zeros((2,), jnp.float32), jnp.zeros((2,), jnp.int32),
-        6, cfg, jnp.float32)
+        6, jnp.float32)
     np.testing.assert_array_equal(np.asarray(pooled)[0], np.asarray(solo)[0])
     assert (np.asarray(pooled)[1] == cfg.eos_id).all()
 

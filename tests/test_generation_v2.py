@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
+from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.models import gpt2 as G
 from pytorch_zappa_serverless_tpu.serving.kvcache import (
     TRASH_BLOCK, BlockManager, KVPoolExhausted)
@@ -164,9 +165,10 @@ def test_chunked_prefill_matches_monolithic_logits_and_chain():
     topk, topp = jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.float32)
     total = P + max_new
     MB = -(-total // BS)
-    first_ref, ck_ref, _ = G.prefill_start(params, toks, lens, z1, s1,
-                                           total, cfg, jnp.float32)
-    want = np.asarray(G.generate(params, toks, lens, z1, s1, max_new, cfg,
+    fam = G.family(cfg)
+    first_ref, ck_ref, _ = D.prefill_start(fam, params, toks, lens, z1, s1,
+                                           total, jnp.float32)
+    want = np.asarray(D.generate(fam, params, toks, lens, z1, s1, max_new,
                                  jnp.float32))[0]
 
     ck = jnp.zeros((cfg.layers, MB + 2, BS, cfg.d_model), jnp.float32)
@@ -179,9 +181,10 @@ def test_chunked_prefill_matches_monolithic_logits_and_chain():
         sl = ids[start:start + C]
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :sl.shape[0]] = sl
-        first, ck, cv = G.prefill_chunk_paged(
-            params, jnp.asarray(chunk), jnp.asarray([start], jnp.int32),
-            lens, ck, cv, table, z1, s1, topk, topp, BS, cfg, jnp.float32)
+        first, ck, cv = D.prefill_chunk(
+            fam, params, jnp.asarray(chunk), jnp.asarray([start], jnp.int32),
+            lens, D.PagedPool(ck, cv, table, BS), z1, s1, topk, topp,
+            jnp.float32)
     # Same first token AND bitwise-identical cache rows at every written
     # prompt position (gathered virtually).
     assert int(first[0]) == int(first_ref[0])
@@ -194,9 +197,9 @@ def test_chunked_prefill_matches_monolithic_logits_and_chain():
     fin = jnp.zeros((1,), bool)
     got = []
     for _ in range(3):
-        emits, ck, cv, tok, pos, step, fin = G.decode_segment_paged(
-            params, ck, cv, table, tok, pos, step, fin, z1, s1, 3, cfg,
-            BS, jnp.float32, top_k=topk, top_p=topp)
+        emits, ck, cv, tok, pos, step, fin = D.decode_segment(
+            fam, params, D.PagedPool(ck, cv, table, BS), tok, pos, step, fin,
+            z1, s1, 3, jnp.float32, top_k=topk, top_p=topp)
         got.append(np.asarray(emits))
     np.testing.assert_array_equal(np.concatenate(got, axis=1)[0], want)
 

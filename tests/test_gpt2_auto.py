@@ -74,17 +74,19 @@ def test_small_batch_routes_int8_decode(sv_auto):
     import jax
     import jax.numpy as jnp
 
+    from pytorch_zappa_serverless_tpu.models import decoder as D
     from pytorch_zappa_serverless_tpu.models import gpt2 as G
 
     cfg = G.GPT2Config(**TINY_ARCH)
     inputs = _inputs(1)
     fn = jax.jit(sv_auto.apply_fn)
     got = np.asarray(fn(sv_auto.params, inputs)["tokens"])
-    want = np.asarray(G.generate(
-        sv_auto.params["bf16"], jnp.asarray(inputs["input_ids"]),
+    by_hand = G.family(cfg, pre_tree=lambda p: p["bf16"],
+                       dec_tree=lambda p, rows: p["int8"])
+    want = np.asarray(D.generate(
+        by_hand, sv_auto.params, jnp.asarray(inputs["input_ids"]),
         jnp.asarray(inputs["length"]), jnp.asarray(inputs["temperature"]),
-        jnp.asarray(inputs["seed"]), 8, cfg,
-        decode_params=sv_auto.params["int8"]))
+        jnp.asarray(inputs["seed"]), 8))
     np.testing.assert_array_equal(got, want)
     # Poison: zero the int8 lm-head scales -> every int8-decoded logit is 0
     # -> argmax 0 from the second token on.  b1 must change.

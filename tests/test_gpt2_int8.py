@@ -78,16 +78,17 @@ def test_int8_params_rewritten(sv_q):
 
 
 def test_int8_prefill_matches_dequantized_reference(sv_q):
+    from pytorch_zappa_serverless_tpu.models import decoder as D
     from pytorch_zappa_serverless_tpu.models import gpt2 as G
 
-    cfg = G.GPT2Config(**TINY_ARCH)
+    fam = G.family(G.GPT2Config(**TINY_ARCH))
     rng = np.random.default_rng(0)
     toks = rng.integers(1, 500, (2, 16)).astype(np.int32)
     lens = np.full((2,), 16, np.int32)
-    logits_q, ck_q, cv_q = G.prefill(sv_q.params, toks, lens, 24, cfg)
+    logits_q, ck_q, cv_q = D.prefill(fam, sv_q.params, toks, lens, 24)
     ref = _dequant_params({k: np.asarray(v) for k, v in sv_q.params.items()}
                           if not isinstance(sv_q.params, dict) else sv_q.params)
-    logits_r, ck_r, cv_r = G.prefill(ref, toks, lens, 24, cfg)
+    logits_r, ck_r, cv_r = D.prefill(fam, ref, toks, lens, 24)
     lq, lr = np.asarray(logits_q), np.asarray(logits_r)
     # lm head: kernel (int8 head) vs bf16 wte reference — error is head
     # quantization only, small relative to logit scale.

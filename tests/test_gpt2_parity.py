@@ -8,10 +8,19 @@ import torch
 
 from pytorch_zappa_serverless_tpu.config import ModelConfig
 from pytorch_zappa_serverless_tpu.engine.weights import convert_gpt2
+from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.models import gpt2 as G
 
 TINY_ARCH = {"d_model": 32, "layers": 2, "heads": 2, "ffn_dim": 128,
              "vocab_size": 500, "max_positions": 64}
+
+
+def _greedy(params, tokens, lengths, max_new, cfg, dtype):
+    """Greedy generation: temperature 0 on every row."""
+    B = tokens.shape[0]
+    return D.generate(G.family(cfg), params, tokens, lengths,
+                      jnp.zeros((B,), jnp.float32),
+                      jnp.zeros((B,), jnp.int32), max_new, dtype)
 
 
 def _torch_tiny():
@@ -46,7 +55,8 @@ def test_prefill_last_logits_parity_ragged(rng):
     for b, n in enumerate(lengths):
         toks[b, n:] = 0
     logits, ck, cv = jax.jit(
-        lambda p, t, l: G.prefill(p, t, l, P + 4, cfg, jnp.float32))(
+        lambda p, t, l: D.prefill(G.family(cfg), p, t, l, P + 4,
+                                  jnp.float32))(
             params, jnp.asarray(toks.astype(np.int32)), jnp.asarray(lengths))
     mask = (np.arange(P)[None] < lengths[:, None]).astype(np.int64)
     with torch.no_grad():
@@ -63,7 +73,7 @@ def test_greedy_matches_torch_generate(rng):
     prompt = rng.integers(1, 499, (1, 6)).astype(np.int64)
     max_new = 5
     ours = np.asarray(jax.jit(
-        lambda p, t, l: G.generate_greedy(p, t, l, max_new, cfg, jnp.float32))(
+        lambda p, t, l: _greedy(p, t, l, max_new, cfg, jnp.float32))(
             params, jnp.asarray(prompt.astype(np.int32)),
             jnp.asarray([6], jnp.int32)))
     with torch.no_grad():
@@ -76,7 +86,7 @@ def test_ragged_rows_independent():
     """A row's output must not depend on its co-batched neighbors' lengths."""
     params = jax.tree.map(jnp.asarray, G.init_gpt2_params(0, _tiny_cfg()))
     cfg = _tiny_cfg()
-    fn = jax.jit(lambda p, t, l: G.generate_greedy(p, t, l, 4, cfg, jnp.float32))
+    fn = jax.jit(lambda p, t, l: _greedy(p, t, l, 4, cfg, jnp.float32))
     g = np.random.default_rng(2)
     row = g.integers(1, 499, (1, 4)).astype(np.int32)
     solo = np.asarray(fn(params, jnp.asarray(np.pad(row, ((0, 0), (0, 4)))),
@@ -97,7 +107,7 @@ def _tiny_cfg():
 
 def test_eos_padding_semantics():
     params = jax.tree.map(jnp.asarray, G.init_gpt2_params(3, _tiny_cfg()))
-    out = np.asarray(G.generate_greedy(
+    out = np.asarray(_greedy(
         params, jnp.asarray(np.ones((1, 4), np.int32)),
         jnp.asarray([4], jnp.int32), 8, _tiny_cfg(), jnp.float32))[0]
     seen = False
@@ -147,8 +157,8 @@ class TestSampling:
     def _fn(self):
         params = jax.tree.map(jnp.asarray, G.init_gpt2_params(1, _tiny_cfg()))
         cfg = _tiny_cfg()
-        fn = jax.jit(lambda p, t, l, temp, s: G.generate(
-            p, t, l, temp, s, 6, cfg, jnp.float32))
+        fn = jax.jit(lambda p, t, l, temp, s: D.generate(
+            G.family(cfg), p, t, l, temp, s, 6, jnp.float32))
         toks = jnp.asarray(np.random.default_rng(0).integers(
             1, 499, (2, 4)).astype(np.int32))
         lens = jnp.asarray([4, 4], jnp.int32)
@@ -157,7 +167,7 @@ class TestSampling:
     def test_temp_zero_matches_greedy(self):
         params, fn, toks, lens = self._fn()
         zero = np.asarray(fn(params, toks, lens, jnp.zeros(2), jnp.zeros(2, jnp.int32)))
-        greedy = np.asarray(G.generate_greedy(
+        greedy = np.asarray(_greedy(
             jax.tree.map(jnp.asarray, G.init_gpt2_params(1, _tiny_cfg())),
             toks, lens, 6, _tiny_cfg(), jnp.float32))
         np.testing.assert_array_equal(zero, greedy)

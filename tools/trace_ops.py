@@ -148,6 +148,7 @@ def build_gpt2_decode():
     import jax
     import jax.numpy as jnp
 
+    from pytorch_zappa_serverless_tpu.models import decoder as D
     from pytorch_zappa_serverless_tpu.models import gpt2 as G
 
     cfg = G.SMALL
@@ -168,10 +169,12 @@ def build_gpt2_decode():
     }
 
     def fn(p, x):
-        emits, *_ = G.decode_segment(
-            p, x["ck"].astype(jnp.bfloat16), x["cv"].astype(jnp.bfloat16),
+        emits, *_ = D.decode_segment(
+            G.family(cfg), p,
+            D.slot_pool(x["ck"].astype(jnp.bfloat16),
+                        x["cv"].astype(jnp.bfloat16)),
             x["tok"], x["pos"], x["step"], x["fin"], x["temp"], x["seed"],
-            8, cfg, jnp.bfloat16)
+            8, jnp.bfloat16)
         return {"emits": emits}
 
     return jax.jit(fn), params, inputs
