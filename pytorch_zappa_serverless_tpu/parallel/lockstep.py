@@ -184,11 +184,11 @@ class LockstepDriver:
         k = state["kernels"]
         cm = self.engine.models[name]
         ck, cv = state["cache"]
-        emits, ck, cv, tok, pos, step, fin = k["segment"](
+        packed, ck, cv = k["segment"](
             cm.servable.params, ck, cv, st["tok"], st["pos"], st["step"],
             st["fin"], st["temp"], st["seed"], st["topk"], st["topp"])
         state["cache"] = (ck, cv)
-        np.asarray(emits)  # completion fence, mirroring the leader's fetch
+        np.asarray(packed)  # completion fence, mirroring the leader's fetch
 
     def follow(self) -> None:
         """Mirror host 0's dispatches until it shuts down (blocking)."""

@@ -14,7 +14,10 @@ answer to both, built so every device program keeps static shapes:
   clients (SSE), rows that hit EOS/budget **retire**, and queued requests
   **admit** into free slots: a per-prompt-bucket ``prefill`` computes the
   request's cache rows and a jitted ``dynamic_update_slice`` insert writes
-  them into the pool while other rows' state rides along untouched.
+  them into the pool while other rows' state rides along untouched.  When
+  nothing could be admitted anyway, the call that fetched a segment launches
+  the next one before it returns, and the tokens are fanned out while the
+  device works (``GenerationScheduler._segment_sync``; docs/GENERATION.md).
 - Compiled-program census in steady state: one segment program, one insert
   program, one prefill program per prompt bucket.  Caches are donated
   through segment/insert calls, so the pool is updated in place (no
@@ -96,9 +99,16 @@ def build_gen_kernels(cm, mesh=None):
         v_row = jax.lax.dynamic_slice(v_rows, src, (L, 1, T, D))
         return _insert_rows(cache_k, cache_v, k_row, v_row, slot)
 
+    def _pack(emits, cache_k, cache_v, tok, pos, step, fin):
+        """A segment's small results as ONE ``[S, seg + 4]`` int32 array
+        (emits, then tok, pos, step, fin): one copy to the host and one wait
+        a round, whatever model's ``segment_scan`` made them."""
+        carry = jnp.stack([tok, pos, step, fin.astype(jnp.int32)], axis=1)
+        return jnp.concatenate([emits, carry], axis=1), cache_k, cache_v
+
     kw_prefill = {"out_shardings": out_shardings(3)} if mesh is not None else {}
     kw_insert = {"out_shardings": out_shardings(2)} if mesh is not None else {}
-    kw_segment = {"out_shardings": out_shardings(7)} if mesh is not None else {}
+    kw_segment = {"out_shardings": out_shardings(3)} if mesh is not None else {}
 
     def alloc_cache():
         if replicated is not None:
@@ -122,8 +132,8 @@ def build_gen_kernels(cm, mesh=None):
         "insert": jax.jit(_insert_rows, donate_argnums=(0, 1), **kw_insert),
         "insert_from": jax.jit(_insert_from, donate_argnums=(0, 1),
                                **kw_insert),
-        "segment": jax.jit(meta["segment"], donate_argnums=(1, 2),
-                           **kw_segment),
+        "segment": jax.jit(lambda *a: _pack(*meta["segment"](*a)),
+                           donate_argnums=(1, 2), **kw_segment),
         "alloc_cache": alloc_cache,
         "meta": meta,
     }
@@ -353,6 +363,26 @@ def _note_token_latency(req: GenRequest, ttft_hist: Histogram,
     req.last_token_at = now
 
 
+def _retire(emits: np.ndarray, live: np.ndarray, budget: np.ndarray,
+            eos_id: int) -> tuple[np.ndarray, np.ndarray]:
+    """Which slots end in this segment, and after how many of its tokens.
+
+    THE rule of the slot lane's streams, over all slots at once: an EOS is
+    never surfaced and ends the stream; the budget ends it after the token
+    that spent it.  ``emits`` [S, seg] is the segment's output, ``live`` [S]
+    the slots that were generating when it was launched, ``budget`` [S] the
+    tokens each may still surface (at least 1 where live).  Returns ``(n
+    [S], done [S])``: the leading tokens of each row to surface (0 for a slot
+    that was not live) and whether the slot retires.
+    """
+    seg = emits.shape[1]
+    is_eos = emits == eos_id
+    first_eos = np.where(is_eos.any(axis=1), is_eos.argmax(axis=1), seg)
+    n = np.where(live, np.minimum(first_eos, budget), 0)
+    done = live & ((first_eos < seg) | (budget <= seg))
+    return n, done
+
+
 class GenerationScheduler:
     """Slot-pool continuous-batching loop for one generative model."""
 
@@ -411,10 +441,24 @@ class GenerationScheduler:
         self._seed = np.zeros((S,), np.int32)   # guarded-by: dispatch-serialized
         self._topk = np.zeros((S,), np.int32)   # guarded-by: dispatch-serialized
         self._topp = np.ones((S,), np.float32)  # guarded-by: dispatch-serialized
+        # Tokens each slot may still surface (``_set_slot``, ``_retire``).
+        self._budget = np.zeros((S,), np.int32)  # guarded-by: dispatch-serialized
+        # The launched segment whose results nobody has fetched yet: its
+        # packed device array (``build_gen_kernels``).  While it is set the
+        # slot state above is what that segment was launched with, and
+        # neither thread writes it: the loop admits and cancels nothing
+        # until the fetch has come back.
+        self._inflight = None  # guarded-by: dispatch-serialized
         self._active: dict[int, GenRequest] = {}  # guarded-by: event-loop
-        self._free = list(range(S))               # guarded-by: event-loop
-        self._pending: collections.deque[GenRequest] = collections.deque()  # guarded-by: event-loop
-        self._cancelled: set[GenRequest] = set()  # guarded-by: event-loop
+        # Written by the scheduler task (and ``submit``/``cancel``) alone.
+        # The dispatch thread reads, once a segment fetch and each in one
+        # atomic call, ``len(_free)``, ``len(_pending)`` and whether
+        # ``_cancelled`` is empty: whether the next segment may be launched
+        # before the event loop has seen this one.  A request that arrives
+        # after that read waits for the launched segment, as it would have.
+        self._free = list(range(S))               # guarded-by: dispatch-serialized
+        self._pending: collections.deque[GenRequest] = collections.deque()  # guarded-by: dispatch-serialized
+        self._cancelled: set[GenRequest] = set()  # guarded-by: dispatch-serialized
         self._max_pending = int(mc.max_concurrency)
         self._exit_on_fatal = exit_on_fatal
         self._wake = asyncio.Event()
@@ -428,6 +472,10 @@ class GenerationScheduler:
         # int increments from the dispatch thread, read by the loop task.
         self.device_rounds = 0   # guarded-by: dispatch-serialized
         self.segment_rounds = 0  # guarded-by: dispatch-serialized
+        # Segments launched by the call that fetched the one before
+        # (``_segment_sync``): the share of rounds whose fan-out ran while
+        # the device worked is chained_rounds / segment_rounds.
+        self.chained_rounds = 0  # guarded-by: dispatch-serialized
         # How much of the pool a segment's attention has to read, and how
         # much its copies cover: per round, the positions of the slots still
         # generating over slots x total, as they are and each rounded up to
@@ -481,11 +529,13 @@ class GenerationScheduler:
         with tl.phase("insert.launch", programs=1):
             self._cache_k, self._cache_v = self._insert(
                 self._cache_k, self._cache_v, k_row, v_row, np.int32(slot))
-            self._set_slot(slot, first_tok, payload, 0)
+            self._set_slot(slot, first_tok, payload, 0, req.max_new)
             self.device_rounds += 1
 
-    def _set_slot(self, slot: int, first_tok: int, payload: dict, j: int):
+    def _set_slot(self, slot: int, first_tok: int, payload: dict, j: int,
+                  budget: int):
         self._tok[slot] = first_tok
+        self._budget[slot] = budget
         self._pos[slot] = int(payload["length"][j])
         self._step[slot] = 0
         self._finished[slot] = False
@@ -530,41 +580,83 @@ class GenerationScheduler:
                 self._cache_k, self._cache_v = self._insert_from(
                     self._cache_k, self._cache_v, k_rows, v_rows,
                     np.int32(j), np.int32(slot))
-                self._set_slot(slot, int(first[j]), batched, j)
+                self._set_slot(slot, int(first[j]), batched, j, req.max_new)
             self.device_rounds += 1
 
-    def _segment_sync(self):
-        """One decode segment over the whole pool (dispatch thread)."""
-        tl = self.timeline
-        with tl.phase("segment.launch", programs=1):
+    def _launch_segment(self):
+        """Launch one decode segment over the whole pool (dispatch thread).
+        The fetch that follows, in this call or at the top of the next, asks
+        for the packed results while the segment still runs, so their copy
+        to the host is queued behind the program either way."""
+        with self.timeline.phase("segment.launch", programs=1):
             if self.lockstep is not None:
                 self.lockstep.lead_gen_segment(
                     self.name, {"tok": self._tok, "pos": self._pos,
                                 "step": self._step, "fin": self._finished,
                                 "temp": self._temp, "seed": self._seed,
                                 "topk": self._topk, "topp": self._topp})
-            live = np.minimum(self._pos[~self._finished] + 1, self.total)
-            self.kv_live_sum += float(live.sum()) / (self.slots * self.total)
+            self._inflight, self._cache_k, self._cache_v = self._segment(
+                self.params, self._cache_k, self._cache_v,
+                self._tok, self._pos, self._step, self._finished,
+                self._temp, self._seed, self._topk, self._topp)
+
+    def _segment_sync(self):
+        """One round's device work, in one call on the dispatch thread.
+
+        Launches a segment unless the call before left one running, waits
+        for it, decides who retires (``_retire``) and pins those slots; then,
+        if a slot is still generating and the loop could admit and cancel
+        nothing before the next segment anyway, launches that segment before
+        it returns, so that the event loop fans this one's tokens out while
+        the device works.  An admission never waits for a segment it would
+        not have waited for: with a request pending and a slot free (the
+        ones just retired count) the call returns without launching.  The
+        lockstep leader never launches ahead: each of its launches is paired
+        with a broadcast of the slot state the followers mirror.
+
+        Returns ``(emits [S, seg], n [S], done [S], fault)`` for
+        ``_distribute``; ``fault`` is what a launch made here raised, after
+        the fetched segment, whose tokens are still to be delivered.
+        """
+        if self._inflight is None:
+            self._launch_segment()
+        with self.timeline.phase("segment.fetch"):
+            # The slot state is still what the segment was launched with;
+            # what reads it comes before the wait, while the device works.
+            live = ~self._finished
+            reach = np.minimum(self._pos[live] + 1, self.total)
+            self.kv_live_sum += float(reach.sum()) / (self.slots * self.total)
             self.kv_read_sum += float(
-                (-(-live // self.read_block) * self.read_block).sum()
+                (-(-reach // self.read_block) * self.read_block).sum()
             ) / (self.slots * self.total)
-            emits, self._cache_k, self._cache_v, tok, pos, step, fin = \
-                self._segment(
-                    self.params, self._cache_k, self._cache_v,
-                    self._tok, self._pos, self._step, self._finished,
-                    self._temp, self._seed, self._topk, self._topp)
-        # Small fetches: [S, seg] emits + [S] carries; caches stay on device.
-        # np.array (copy), not np.asarray: device fetches come back read-only
-        # and the scheduler mutates these on retire/admit.
-        with tl.phase("segment.fetch"):
-            out = np.asarray(emits)
-            self._tok = np.array(tok)
-            self._pos = np.array(pos)
-            self._step = np.array(step)
-            self._finished = np.array(fin)
+            inflight, self._inflight = self._inflight, None
+            # The round's one blocking wait: [S, seg + 4], emits then the
+            # carries (``build_gen_kernels``); caches stay on device.  The
+            # carries are copied out: the fetch comes back read-only and
+            # admission writes them in place.
+            packed = np.asarray(inflight)
+            emits = packed[:, :-4]
+            self._tok, self._pos, self._step = (
+                packed[:, k].copy() for k in (-4, -3, -2))
+            self._finished = packed[:, -1] != 0
+            n, done = _retire(emits, live, self._budget, self.eos_id)
+            self._budget -= n
+            self._finished[done] = True
+            self._tok[done] = self.eos_id
             self.device_rounds += 1
             self.segment_rounds += 1
-        return out
+            free = len(self._free) + int(done.sum())
+            chain = (self.lockstep is None and not self._finished.all()
+                     and not self._cancelled
+                     and not (self._pending and free))
+        fault = None
+        if chain:
+            try:
+                self._launch_segment()
+                self.chained_rounds += 1
+            except Exception as e:  # delivered after this segment's tokens
+                fault = e
+        return emits, n, done, fault
 
     # -- client API ---------------------------------------------------------
     def submit(self, sample: dict, max_new: int | None = None,
@@ -629,6 +721,7 @@ class GenerationScheduler:
                 "active": len(self._active), "pending": len(self._pending),
                 "device_rounds": self.device_rounds,
                 "segment_rounds": self.segment_rounds,
+                "chained_rounds": self.chained_rounds,
                 "prefill_dispatches": self.prefill_dispatches,
                 "tokens_emitted": self.tokens_emitted,
                 "kv_live_share": {"sum": round(self.kv_live_sum, 6),
@@ -668,7 +761,15 @@ class GenerationScheduler:
                 self._wake.clear()
                 with tl.phase("round.idle"):
                     await self._wake.wait()
-            self._process_cancellations()
+            # With a launched segment running (``_segment_sync``) the round
+            # has nothing to do but fetch it: whoever arrived or hung up
+            # since the launch waits for it, as ever.  An arrival that finds
+            # a slot free is seen at the first loop top that can admit it
+            # (its wait is for the running round); one that finds none is
+            # seen now (its wait from here on is for a slot).
+            inflight = self._inflight is not None
+            if not inflight:
+                self._process_cancellations()
             t_top = time.perf_counter()
             round_no = tl.begin_round(active=len(self._active))
             # Admit into free slots (prefill runs on the dispatch thread, so
@@ -677,12 +778,14 @@ class GenerationScheduler:
             # into ONE batched prefill dispatch (_admit_batch_sync); the
             # lockstep leader keeps the proven per-admission broadcast.
             with tl.phase("round.admit_host"):
-                _note_seen(self._pending, t_top)
                 admits: list[tuple[GenRequest, int]] = []
-                while self._free and self._pending:
-                    req = self._pending.popleft()
-                    req.note_slotted(t_top, round_no)
-                    admits.append((req, self._free.pop()))
+                if not inflight or not self._free:
+                    _note_seen(self._pending, t_top)
+                if not inflight:
+                    while self._free and self._pending:
+                        req = self._pending.popleft()
+                        req.note_slotted(t_top, round_no)
+                        admits.append((req, self._free.pop()))
                 groups: dict[int, list] = {}
                 for req, slot in admits:
                     if self.lockstep is None:
@@ -805,15 +908,21 @@ class GenerationScheduler:
             if not self._active:
                 continue
             try:
-                emits = await self.runner.run_fn(self._segment_sync,
-                                                 model=self.name,
-                                                 trip=tl.trip("segment"))
+                emits, n, done, fault = await self.runner.run_fn(
+                    self._segment_sync, model=self.name,
+                    trip=tl.trip("segment"))
             except Exception as e:
-                # Device fault mid-segment (donated caches are gone): fail
-                # every in-flight request loudly and reset the pool.
-                log.exception("segment failed for %s", self.name)
+                emits, fault = None, e
+            if emits is not None:
+                with tl.phase("round.distribute"):
+                    self._distribute(emits, n, done)
+            if fault is not None:
+                # Device fault in a segment or in its launch (donated caches
+                # are gone): fail every in-flight request loudly and reset
+                # the pool.
+                log.error("segment failed for %s", self.name, exc_info=fault)
                 for slot, req in list(self._active.items()):
-                    req.finish(error=f"{type(e).__name__}: {e}")
+                    req.finish(error=f"{type(fault).__name__}: {fault}")
                 if self.lockstep is not None:
                     # Multi-host leader: resume-in-place would re-allocate
                     # the pool with a device_put collective the followers
@@ -825,9 +934,6 @@ class GenerationScheduler:
                                    "deployment; restart all hosts")
                     return
                 self._reset_pool()
-                continue
-            with tl.phase("round.distribute"):
-                self._distribute(emits)
 
     def _cache_deleted(self) -> bool:
         """True when a donating dispatch faulted after consuming the pool."""
@@ -841,7 +947,7 @@ class GenerationScheduler:
             return False
 
     def _reset_pool(self):
-        self._cache_k = self._cache_v = None
+        self._cache_k = self._cache_v = self._inflight = None
         self._finished[:] = True
         self._active.clear()
         self._free = list(range(self.slots))
@@ -872,43 +978,30 @@ class GenerationScheduler:
                          "the process supervisor restarts the world")
             os.kill(os.getpid(), signal.SIGINT)
 
-    def _emit(self, req: GenRequest, token: int) -> bool:
-        """Record one generated token; returns True when the request is done.
-
-        EOS is never surfaced as a token event (it terminates the stream);
-        budget exhaustion terminates after the token that spent it.
-        """
-        if token == self.eos_id:
-            return True
-        req.tokens.append(token)
-        req.events.put_nowait(token)
-        self.tokens_emitted += 1
-        _note_token_latency(req, self.ttft_hist, self.itl_hist)
-        return len(req.tokens) >= req.max_new
-
-    def _distribute(self, emits: np.ndarray):
-        """Fan segment output to requests; retire finished slots."""
+    def _distribute(self, emits: np.ndarray, n: np.ndarray, done: np.ndarray):
+        """Fan a fetched segment's tokens out to its requests and release the
+        slots that retired.  What is surfaced and who retires was decided
+        where the fetch landed (``_retire``, which also pinned the slots);
+        nothing is decided again here."""
         for slot, req in list(self._active.items()):
-            finished = False
             had_tokens = bool(req.tokens)
-            n_before = len(req.tokens)
-            for t in range(emits.shape[1]):
-                finished = self._emit(req, int(emits[slot, t]))
-                if finished:
-                    break
-            if req.span is not None and len(req.tokens) > n_before:
+            fresh = emits[slot, :n[slot]].tolist()
+            for token in fresh:
+                req.tokens.append(token)
+                req.events.put_nowait(token)
+                _note_token_latency(req, self.ttft_hist, self.itl_hist)
+            self.tokens_emitted += len(fresh)
+            if req.span is not None and fresh:
                 # One streaming tick per segment that emitted for this
                 # request: the waterfall shows token cadence, not just TTFT.
-                req.span.point("tick", tokens=len(req.tokens) - n_before,
+                req.span.point("tick", tokens=len(fresh),
                                total=len(req.tokens))
             if not had_tokens and req.tokens:
                 req.rounds_to_first_token = (self.device_rounds
                                              - req.rounds_at_submit)
                 req.segments_to_first_token = (self.segment_rounds
                                                - req.segments_at_submit)
-            if finished:
-                self._finished[slot] = True
-                self._tok[slot] = self.eos_id
+            if done[slot]:
                 del self._active[slot]
                 self._free.append(slot)
                 if req.span is not None and req.admitted is not None:

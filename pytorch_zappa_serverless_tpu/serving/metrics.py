@@ -760,6 +760,17 @@ class MetricsHub:
                    [({"model": m}, s["tokens_emitted"])
                     for m, s in gsnap.items()
                     if s.get("tokens_emitted") is not None])
+            # Decode segments fetched, and those of them that the call which
+            # fetched the segment before had already launched (slot lanes):
+            # chained / segment is the share of rounds whose tokens were
+            # fanned out while the device worked (docs/GENERATION.md).
+            for key, what in (("segment_rounds", "Decode segments fetched "
+                               "per model (:generate lanes)"),
+                              ("chained_rounds", "Decode segments launched "
+                               "by the call that fetched the one before")):
+                metric(f"tpuserve_{key}_total", "counter", what,
+                       [({"model": m}, s[key]) for m, s in gsnap.items()
+                        if s.get(key) is not None])
             # How much of the slot pool decode attention has to read
             # (live), and how much its copies cover (read), per segment round
             # (slot lanes): _sum / _count is the mean share.

@@ -492,6 +492,272 @@ async def test_backpressure_and_cancel(engine):
 
 
 # ---------------------------------------------------------------------------
+# The round: one wait, retirement where the fetch lands, launch before fan-out
+# ---------------------------------------------------------------------------
+
+_EOS = 499
+
+
+def _surface(row, live, budget):
+    """The stream's rule, one token at a time: an EOS is not surfaced and
+    ends the stream; the budget ends it after the token that spent it."""
+    if not live:
+        return 0, False
+    n = 0
+    for tok in row:
+        if tok == _EOS:
+            return n, True
+        n += 1
+        if n >= budget:
+            return n, True
+    return n, False
+
+
+@pytest.mark.parametrize("row,live,budget", [
+    pytest.param([1, 2, 3, 4], True, 9, id="neither"),
+    pytest.param([_EOS, _EOS, _EOS, _EOS], True, 9, id="eos-at-0"),
+    pytest.param([1, _EOS, _EOS, _EOS], True, 9, id="eos-at-1"),
+    pytest.param([1, 2, _EOS, _EOS], True, 9, id="eos-at-2"),
+    pytest.param([1, 2, 3, _EOS], True, 9, id="eos-at-3"),
+    pytest.param([1, 2, 3, 4], True, 1, id="budget-at-0"),
+    pytest.param([1, 2, 3, 4], True, 2, id="budget-at-1"),
+    pytest.param([1, 2, 3, 4], True, 3, id="budget-at-2"),
+    pytest.param([1, 2, 3, 4], True, 4, id="budget-at-3"),
+    pytest.param([1, 2, 3, 4], True, 5, id="budget-in-the-next-segment"),
+    pytest.param([1, _EOS, _EOS, _EOS], True, 3, id="eos-before-budget"),
+    pytest.param([1, 2, 3, _EOS], True, 2, id="budget-before-eos"),
+    pytest.param([1, 2, _EOS, _EOS], True, 2, id="budget-then-eos"),
+    pytest.param([_EOS, _EOS, _EOS, _EOS], False, 0, id="already-finished"),
+    pytest.param([1, 2, 3, 4], False, 7, id="dead-slot-with-stale-budget"),
+])
+def test_retirement_decision_is_the_streams_rule(row, live, budget):
+    """``_retire`` over a pool equals the token-by-token rule in every slot,
+    whatever its neighbours hold."""
+    from pytorch_zappa_serverless_tpu.serving.generation import _retire
+
+    rows = [([7, 8, 9, 10], True, 6), (row, live, budget),
+            ([7, _EOS, _EOS, _EOS], True, 1), ([_EOS] * 4, False, 0)]
+    n, done = _retire(np.asarray([r for r, _, _ in rows], np.int32),
+                      np.asarray([lv for _, lv, _ in rows]),
+                      np.asarray([b for _, _, b in rows], np.int32), _EOS)
+    want = [_surface(*r) for r in rows]
+    assert [(int(a), bool(b)) for a, b in zip(n, done)] == want
+    # The surfaced tokens are the row's first n, none of them an EOS.
+    assert _EOS not in rows[1][0][: int(n[1])]
+
+
+def _sampled(cm, i, **extra):
+    """A request whose chain wanders (greedy chains of the random tiny model
+    repeat one token): sampled under a fixed (seed, step) key chain, which
+    the fixed-batch lane reproduces bit for bit."""
+    return cm.servable.preprocess({"input_ids": [5 + i, 6, 7 + 2 * i],
+                                   "temperature": 1.3, "seed": 11 + i,
+                                   **extra})
+
+
+async def test_mixed_run_streams_match_fixed_batch_with_chained_rounds(
+        tmp_path):
+    """More requests than slots, differing budgets, one stream that meets
+    EOS, one cancelled mid-stream: every stream is the fixed-batch chain,
+    and rounds chained on the way."""
+    from pytorch_zappa_serverless_tpu.engine.loader import build_engine
+
+    def engine_with(**arch):
+        return build_engine(ServeConfig(
+            compile_cache_dir=str(tmp_path / "xla"), warmup_at_boot=False,
+            models=[_model_cfg(arch={**TINY_ARCH, **arch})]))
+
+    # The EOS of this run: the fifth token of request 0's own chain, so its
+    # stream ends after four tokens, in the middle of its second segment.
+    eng = engine_with()
+    try:
+        cm = eng.model("gpt2")
+        chain = cm.run_batch([_sampled(cm, 0)])[0][0]["tokens"]
+    finally:
+        eng.shutdown()
+    eos = chain[4]
+    assert eos not in chain[:4]
+    eng = engine_with(eos_id=eos)
+    sched = _scheduler(eng).start()
+    cm = eng.model("gpt2")
+    try:
+        assert sched.eos_id == eos
+        budgets = [12, 7, 2, 12, 5, 3]
+        samples = [_sampled(cm, i) for i in range(len(budgets))]
+        reqs = [sched.submit(s, max_new=b) for s, b in zip(samples, budgets)]
+        doomed = sched.submit(_sampled(cm, 9), max_new=12)
+        first = await asyncio.wait_for(doomed.events.get(), 120)
+        sched.cancel(doomed)
+        outs = await asyncio.wait_for(
+            asyncio.gather(*[r.done for r in reqs]), 120)
+        with pytest.raises(RuntimeError, match="cancelled"):
+            await asyncio.wait_for(doomed.done, 60)
+        for s, b, got in zip(samples, budgets, outs):
+            assert got == cm.run_batch([s])[0][0]["tokens"][:b]
+        assert outs[0] == chain[:4]  # EOS ended it, and was not surfaced
+        want = cm.run_batch([_sampled(cm, 9)])[0][0]["tokens"]
+        assert doomed.tokens[0] == first
+        assert doomed.tokens == want[: len(doomed.tokens)]
+        snap = sched.gen_snapshot()
+        assert 0 < snap["chained_rounds"] <= snap["segment_rounds"]
+        # A later request is served from the slots the others left.
+        last = _sampled(cm, 4)
+        assert await asyncio.wait_for(sched.submit(last).done, 60) \
+            == cm.run_batch([last])[0][0]["tokens"]
+    finally:
+        await sched.stop()
+        eng.shutdown()
+
+
+async def test_arrival_during_a_chained_segment_is_admitted_before_the_next(
+        engine):
+    """A request submitted while a chained segment runs waits for that one
+    segment, as it would have, and for no other: with it pending and a slot
+    free the fetch launches nothing, and the next program is its prefill."""
+    sched = _scheduler(engine)
+    cm = engine.model("gpt2")
+    events: list = []
+    segment, prefill = sched._segment, sched._prefill
+
+    def spy_segment(*a):  # dispatch thread
+        events.append("launch")
+        return segment(*a)
+
+    def spy_prefill(*a):
+        events.append(("prefill", sched.chained_rounds))
+        return prefill(*a)
+
+    sched._segment, sched._prefill = spy_segment, spy_prefill
+    late: dict = {}
+    distribute = sched._distribute
+
+    def spy_distribute(emits, n, done):
+        distribute(emits, n, done)
+        if sched._inflight is not None and not late:
+            # On the event loop, between a chained launch and its fetch.
+            late["chained"] = sched.chained_rounds
+            events.append("submit")
+            late["req"] = sched.submit(_sampled(cm, 1), max_new=3)
+
+    sched._distribute = spy_distribute
+    sched.start()
+    try:
+        a = sched.submit(_sampled(cm, 0), max_new=12)
+        await asyncio.wait_for(a.done, 120)
+        b = late["req"]
+        got = await asyncio.wait_for(b.done, 120)
+        assert got == cm.run_batch([_sampled(cm, 1)])[0][0]["tokens"][:3]
+        assert late["chained"] == 1  # the first fetch chained
+        at = events.index("submit")
+        assert events[:at] == [("prefill", 0), "launch", "launch"]
+        # Nothing was launched between the arrival and its admission, and
+        # the fetch that found it pending beside a free slot did not chain.
+        assert events[at + 1] == ("prefill", late["chained"])
+        assert events[at + 2] == "launch"
+        # The running segment and its own: what an arrival during a segment
+        # has always paid.
+        assert b.segments_to_first_token == 2
+        assert a.segments_to_first_token == 1
+        # A slot was free: all of its wait was for the running round.
+        assert b.timing_stats()["slot_wait_ms"] == 0.0
+        assert b.timing_stats()["round_wait_ms"] > 0.0
+    finally:
+        await sched.stop()
+
+
+async def test_arrival_with_no_slot_free_waits_for_a_slot_not_for_a_round(
+        engine):
+    """Rounds chain while a request waits for a slot (nothing is admissible);
+    it is seen at the loop top after its arrival, so that wait is booked to
+    ``slot_wait_ms`` and not to the round that happened to be running."""
+    sched = _scheduler(engine)
+    cm = engine.model("gpt2")
+    late: dict = {}
+    distribute = sched._distribute
+
+    def spy_distribute(emits, n, done):
+        distribute(emits, n, done)
+        if sched._inflight is not None and not late:
+            late["req"] = sched.submit(_sampled(cm, 2), max_new=3)
+
+    sched._distribute = spy_distribute
+    sched.start()
+    try:
+        holders = [sched.submit(_sampled(cm, i), max_new=12) for i in (0, 1)]
+        await asyncio.wait_for(asyncio.gather(*[r.done for r in holders]), 120)
+        c = late["req"]
+        got = await asyncio.wait_for(c.done, 120)
+        assert got == cm.run_batch([_sampled(cm, 2)])[0][0]["tokens"][:3]
+        st = c.timing_stats()
+        # Both slots were held for three more segments of the four.
+        assert st["slot_wait_ms"] > st["round_wait_ms"] >= 0.0
+        assert c.seen_at < c.slotted_at
+        assert sched.chained_rounds >= 3  # waiting for a slot stops no chain
+    finally:
+        await sched.stop()
+
+
+async def test_fault_in_a_chained_launch_delivers_the_fetched_tokens(engine):
+    """The launch a fetch makes fails: the fetched segment's tokens still
+    reach the stream, then the in-flight request fails with the error, the
+    pool resets and the next request is served."""
+    sched = _scheduler(engine)
+    cm = engine.model("gpt2")
+    segment = sched._segment
+    calls = {"n": 0}
+
+    def faulty(*a):
+        calls["n"] += 1
+        if calls["n"] == 2:  # the first chained launch
+            raise RuntimeError("injected launch fault")
+        return segment(*a)
+
+    sched._segment = faulty
+    sched.start()
+    try:
+        sample = _sampled(cm, 0)
+        want = cm.run_batch([sample])[0][0]["tokens"]
+        a = sched.submit(sample, max_new=12)
+        with pytest.raises(RuntimeError, match="injected launch fault"):
+            await asyncio.wait_for(a.done, 120)
+        assert a.tokens == want[:3]  # one segment of three, delivered
+        assert [a.events.get_nowait() for _ in range(4)] == want[:3] + [None]
+        assert sched.chained_rounds == 0 and sched.segment_rounds == 1
+        assert sched._inflight is None and sched._cache_k is None
+        assert sorted(sched._free) == [0, 1] and not sched._active
+        assert await asyncio.wait_for(sched.submit(sample).done, 120) == want
+        assert sched.chained_rounds > 0
+    finally:
+        await sched.stop()
+
+
+async def test_lockstep_leader_never_chains(engine):
+    """Each launch of the lockstep leader is paired with a broadcast of the
+    slot state the followers mirror: the round keeps its order."""
+    sched = _scheduler(engine)
+    cm = engine.model("gpt2")
+    led: list = []
+
+    class _Lockstep:
+        def lead_gen_admit(self, *a, **k):
+            pass
+
+        def lead_gen_segment(self, name, state):
+            led.append(state["fin"].copy())
+
+    sched.lockstep = _Lockstep()
+    sched.start()
+    try:
+        sample = _sampled(cm, 0)
+        got = await asyncio.wait_for(sched.submit(sample).done, 120)
+        assert got == cm.run_batch([sample])[0][0]["tokens"]
+        assert sched.chained_rounds == 0
+        assert len(led) == sched.segment_rounds == 4  # 12 tokens by 3
+    finally:
+        await sched.stop()
+
+
+# ---------------------------------------------------------------------------
 # HTTP surface
 # ---------------------------------------------------------------------------
 

@@ -118,7 +118,7 @@ def _loaded_hub():
                            "sum": 14.5, "count": 8}}
     hub.generation = lambda: {
         "gpt2": {"mode": "slot", "slots": 4, "active": 0, "pending": 0,
-                 "device_rounds": 7, "segment_rounds": 5,
+                 "device_rounds": 7, "segment_rounds": 5, "chained_rounds": 3,
                  "prefill_dispatches": 2, "tokens_emitted": 10,
                  "kv_live_share": {"sum": 0.93, "count": 5},
                  "kv_read_share": {"sum": 1.25, "count": 5},

@@ -30,7 +30,7 @@ from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.engine.loader import build_engine
 from pytorch_zappa_serverless_tpu.serving.server import Server, create_app
 from pytorch_zappa_serverless_tpu.serving.tracing import (
-    Tracer, format_traceparent, parse_traceparent)
+    PHASES, Tracer, format_traceparent, parse_traceparent)
 
 pytest_plugins = "aiohttp.pytest_plugin"
 
@@ -483,6 +483,8 @@ async def test_round_phases_tile_busy_rounds(aiohttp_client, tmp_path,
         before = await _gen_counters(client)
         await _generate(client, 2 * _GEN_SLOTS)
         after = await _gen_counters(client)
+        prom = await (await client.get(
+            "/metrics", params={"format": "prometheus"})).text()
         r = await client.get("/admin/trace?rounds=256&model=gpt2")
         rounds = (await r.json())["rounds"]["gpt2"]
     finally:
@@ -494,6 +496,15 @@ async def test_round_phases_tile_busy_rounds(aiohttp_client, tmp_path,
 
     assert delta("segment.launch") == delta("segment.fetch") \
         == after["segment_rounds"] - before["segment_rounds"] > 0
+    if kv_cache == "slot":
+        # Twice the slots' worth of requests: with one pending and no slot
+        # free, a fetch launches the next segment itself.
+        assert 0 < after["chained_rounds"] - before["chained_rounds"] \
+            <= after["segment_rounds"] - before["segment_rounds"]
+        for key in ("chained_rounds", "segment_rounds"):
+            assert f'tpuserve_{key}_total{{model="gpt2"}} {after[key]}' \
+                in prom
+    assert set(PHASES) == set(after["host_phases"])
     dispatches = "prefill_dispatches" if kv_cache == "slot" \
         else "device_rounds"
     prefills = delta("prefill.launch")
