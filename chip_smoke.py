@@ -29,13 +29,21 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
 - *kernels*  ``int8_matmul``, ``flash_attention`` and ``decode_attention``
              with ``interpret=False`` at real shapes against ``jax.numpy``
              references, on the chip; then ``decode_attention`` alone by the
-             profiler's clock, microseconds a layer at three fills of the two
-             serving pools beside the least their live bytes allow.
+             profiler's clock, microseconds a layer at three fills of the
+             serving pools beside the least their live bytes allow
+             (EvaByte's pool, 32 heads of 128 at ``D`` 4096, in spans with a
+             start).
 - *segment*  the decode segment program at GPT-2 XL's serving shape (8 slots
              of 960 positions, 48 layers, compiled from shapes alone): its
              optimised HLO must hold no ``copy``, ``slice`` or ``transpose``
              whose result is as large as one layer of the slot pool.  On the
              chip only; ``--rehearse`` prints what the CPU's compiler made.
+- *evabyte*  EvaByte at its published widths, 16 layers: the programs the
+             servable jits (``prefill_start`` over 4,090 bytes, then four
+             segments of 8 across position 4,096) against the plain
+             reference computed on the same device in float32 at ``highest``
+             precision, logits at every decoded position; and the control,
+             the reference with its weights through int8, which must fail.
 - *sd15*     Stable Diffusion 1.5 at 512x512, two steps, one ``:submit``
              polled to ``done`` (flash attention's only serving caller).
 """
@@ -61,6 +69,9 @@ PKG = ROOT / "pytorch_zappa_serverless_tpu"
 OUT = ROOT / "smoke_out"
 SEED = 20260926
 GEN_TOKENS = 16  # tokens asked of every :generate stream
+# EvaByte's programs in bfloat16 against the float32 reference, in logits of
+# spread about 0.8 (PERF.md section 6, PR 35, has both readings).
+EVABYTE_LOGIT_TOL = 0.1
 # --rehearse widths: d_model is one 128-lane tile, the int8 kernel's floor.
 TINY_GPT2 = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 256,
              "vocab_size": 512, "max_positions": 128}
@@ -605,21 +616,34 @@ def _kernels_child(rehearse: bool) -> None:
     # Decode attention over a slot pool [L, S, T, D]: the benchmark's two
     # serving shapes, slots at 0, mid-block, a block edge and the last row,
     # and a dead one, whose row of the pool holds NaN and is read nowhere.
-    da = ([(2, 4, 32, 128, 2)] if rehearse else
-          [(2, 8, 960, 1600, 25), (2, 16, 960, 1280, 20)])
-    for layers, slots, total, d, heads in da:
+    # The third pool is EvaByte's (32 heads of 128), read in spans with a
+    # start: a summary tier of ``lead`` rows below a ring.
+    da = ([(2, 4, 32, 128, 2, 0), (2, 4, 48, 128, 2, 16)] if rehearse else
+          [(2, 8, 960, 1600, 25, 0), (2, 16, 960, 1280, 20, 0),
+           (2, 8, 2880, 4096, 32, 832)])
+    for layers, slots, total, d, heads, lead in da:
         q = jnp.asarray(rng.standard_normal((slots, d)), jnp.bfloat16)
         wpos = jnp.asarray(([0, -1, 5, total // 4 - 1, total // 4, total - 1]
                             * 3)[:slots], jnp.int32)
+        first = None
+        if lead:  # spans that start and end inside blocks, around ``lead``
+            wpos = jnp.asarray(([lead, -1, lead + 5, lead + 904, total - 1,
+                                 lead + 64] * 3)[:slots], jnp.int32)
+            first = jnp.asarray(([lead, 7, lead - 3, lead - 256, lead - 700,
+                                  lead - 128] * 3)[:slots], jnp.int32)
+            wpos = jnp.where(wpos < 0, wpos, jnp.minimum(wpos, total - 1))
+            first = jnp.maximum(first, 0)
         ck, cv = (jnp.asarray(rng.standard_normal((layers, slots, total, d)),
                               jnp.bfloat16).at[:, 1].set(jnp.nan)
                   for _ in range(2))
         dh = d // heads
-        got = decode_attention(q * dh ** -0.5, ck, cv, wpos, layer=1,
-                               heads=heads, interpret=interpret)
+        got = decode_attention(q * dh ** -0.5, ck, cv, wpos, None, first,
+                               layer=1, heads=heads, interpret=interpret)
         live = np.asarray(wpos) >= 0
-        bias = jnp.where(jnp.arange(total)[None, :] <= wpos[:, None], 0.0,
-                         -1e9)[:, None, None, :]
+        seen = jnp.arange(total)[None, :] <= wpos[:, None]
+        if first is not None:
+            seen &= jnp.arange(total)[None, :] >= first[:, None]
+        bias = jnp.where(seen, 0.0, -1e9)[:, None, None, :]
         want = reference(*(a.reshape(slots, -1, heads, dh)[live]
                            for a in (q[:, None], ck[1], cv[1])), bias[live])
         np.testing.assert_allclose(np.asarray(got, np.float32)[live],
@@ -630,13 +654,16 @@ def _kernels_child(rehearse: bool) -> None:
                                 layer=0, heads=heads, interpret=interpret)
         assert not np.asarray(none, np.float32).any(), "no live slot, not 0"
         print(f"decode_attention pool[{layers},{slots},{total},{d}] "
-              f"{heads} heads matches its reference; dead slots give zeros")
+              f"{heads} heads"
+              + (f", spans from row {lead} down" if lead else "")
+              + " matches its reference; dead slots give zeros")
         for row in time_decode_attention(
-                lambda q, ck, cv, wpos, work, layer, block_t:
-                decode_attention(q, ck, cv, wpos, work, layer=layer,
+                lambda q, ck, cv, wpos, work, layer, block_t, first=None:
+                decode_attention(q, ck, cv, wpos, work, first, layer=layer,
                                  heads=heads, block_t=block_t,
                                  interpret=interpret),
-                slots, total, d, not rehearse):
+                slots, total, d, not rehearse,
+                fills=span_fills(slots, total, lead) if lead else None):
             print("decode_attention " + json.dumps(row))
     print("preprocess path: "
           + ("native (hostops.cpp built with g++)" if hostops.native_available()
@@ -661,8 +688,25 @@ def decode_fills(slots: int, total: int) -> dict[str, list[int]]:
             "full": [total - 1] * slots}
 
 
+def span_fills(slots: int, total: int, lead: int) -> dict:
+    """``(first, last)`` rows a slot of the three fills a pool read in spans
+    is timed at (a ring above row ``lead``, summaries below it, 128 a
+    finished window): the document cell's own (3 of 8 slots live, at about
+    4,600, 6,300 and 2,700 bytes: 2, 3 and 1 finished windows), every slot
+    live half way through its third window, every slot at its last row with
+    six windows finished."""
+    def at(windows, exact):
+        return max(lead - 128 * windows, 0), min(lead + exact, total - 1)
+
+    live = (slots + 2) // 5 + 1
+    docqa = [at(w, e) for w, e in ((2, 500), (3, 150), (1, 650))][:live]
+    return {"docqa": docqa + [(0, -1)] * (slots - live),
+            "half": [at(2, 1024)] * slots,
+            "full": [at(6, total)] * slots}
+
+
 def time_decode_attention(attend, slots: int, total: int, d: int,
-                          on_device: bool, blocks=(None,)):
+                          on_device: bool, blocks=(None,), fills=None):
     """Device microseconds of one ``decode_attention`` call, the kernel
     alone, by the profiler's ``XLA Ops`` events of that name: one row a fill
     (:func:`decode_fills`) and a block length of ``blocks`` (None: the
@@ -670,7 +714,9 @@ def time_decode_attention(attend, slots: int, total: int, d: int,
     positions' bytes (K and V in bfloat16 at 819 GB/s).  ``attend(q, ck,
     cv, wpos, work, layer, block_t)`` is the kernel under the clock; ``work``
     is its list of live blocks, built once a program as the segment builds
-    it once a step.  Off the device the rows carry no time."""
+    it once a step.  ``fills`` (:func:`span_fills`) times spans with a start,
+    which ``attend`` then takes as ``first``.  Off the device the rows carry
+    no time."""
     import tempfile
 
     import jax
@@ -691,25 +737,30 @@ def time_decode_attention(attend, slots: int, total: int, d: int,
         bt = block_t or pick_block_t(total, d, jnp.bfloat16)
 
         @jax.jit
-        def chain(q, ck, cv, wpos):
-            work = work_list(wpos, total, bt)
+        def chain(q, ck, cv, wpos, first):
+            work = work_list(wpos, total, bt, first)
             for j in range(_TIMED_CALLS):
-                q = q + attend(q, ck, cv, wpos, work, j % layers, bt)
+                q = q + (attend(q, ck, cv, wpos, work, j % layers, bt)
+                         if fills is None else
+                         attend(q, ck, cv, wpos, work, j % layers, bt, first))
             return q
 
-        for fill, wpos in decode_fills(slots, total).items():
-            live = sum(w + 1 for w in wpos if w >= 0)
+        spans = fills or {fill: [(0, w) for w in wpos] for fill, wpos
+                          in decode_fills(slots, total).items()}
+        for fill, span in spans.items():
+            live = sum(w - f + 1 for f, w in span if w >= 0)
             row = {"pool": [slots, total, d], "fill": fill, "block_t": bt,
                    "live_positions": live,
-                   "read_positions": sum(-(-(w + 1) // bt) * bt
-                                         for w in wpos if w >= 0),
+                   "read_positions": sum((w // bt - f // bt + 1) * bt
+                                         for f, w in span if w >= 0),
                    "floor_us": round(live * d * 2 * 2 / 819e9 * 1e6, 2)}
-            wpos = jnp.asarray(wpos, jnp.int32)
-            chain(q, ck, cv, wpos).block_until_ready()
+            first = jnp.asarray([f for f, _ in span], jnp.int32)
+            wpos = jnp.asarray([w for _, w in span], jnp.int32)
+            chain(q, ck, cv, wpos, first).block_until_ready()
             if on_device:
                 with tempfile.TemporaryDirectory(dir=OUT) as trace_dir:
                     jax.profiler.start_trace(trace_dir)
-                    chain(q, ck, cv, wpos).block_until_ready()
+                    chain(q, ck, cv, wpos, first).block_until_ready()
                     jax.profiler.stop_trace()
                     compute, counts, _, _ = op_time_breakdown(trace_dir)
                 calls = counts["decode_attention"]
@@ -838,6 +889,119 @@ def _segment_child(rehearse: bool) -> None:
             + "\n  ".join(desc for _, desc in whole[:6]))
     print(json.dumps({"pool_sized_moves": len(whole),
                       "decode_kernel": "decode_attention" in text}))
+
+
+def _evabyte_child(rehearse: bool) -> None:
+    """EvaByte's own programs against its plain reference, on one device.
+
+    The weights are drawn on the device (what a host draw of 3.2 billion
+    values would cost a minute for) as ``init_evabyte_params`` draws them;
+    the programs are the ones ``decoder.make_servable`` hands the scheduler
+    (``prefill_start`` and ``decode_segment`` over the family's rows), the
+    prompt ends six positions before a window's end, and the four segments
+    that follow cross it.  ``choose`` is watched, not replaced: it reports
+    the logits it was given, and the greedy bytes are the program's own."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import evabyte as reference
+    from pytorch_zappa_serverless_tpu.models import decoder, evabyte
+    from pytorch_zappa_serverless_tpu.ops import decode_attention as da
+
+    if rehearse:
+        cfg = evabyte.EvaByteConfig(
+            vocab_size=320, hidden_size=64, layers=2, heads=2,
+            intermediate_size=96, max_positions=512, window_size=32,
+            chunk_size=4, init_std=0.2, eos_id=320)
+        dtype, prompt, bucket, total = jnp.float32, 58, 64, 64 + 40
+    else:
+        cfg = evabyte.EvaByteConfig(layers=16, eos_id=320)
+        dtype, prompt, bucket, total = jnp.bfloat16, 4090, 4096, 12288 + 768
+    D, F, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED), 16 * cfg.layers))
+
+    def w(*shape):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * cfg.init_std).astype(dtype)
+
+    def pool():
+        return jnp.clip(jax.random.normal(next(keys), (D,)), -1.0,
+                        1.0) * cfg.init_std
+
+    params = {"embed": w(V, D), "norm": jnp.zeros((D,)),
+              "head": w(D, V * cfg.num_pred_heads)}
+    for i in range(cfg.layers):
+        params[f"layer{i}"] = {
+            "n1": jnp.zeros((D,)), "n2": jnp.zeros((D,)), "q": w(D, D),
+            "k": w(D, D), "v": w(D, D), "o": w(D, D), "mu": pool(),
+            "phi": pool(), "gate": w(D, F), "up": w(D, F), "down": w(F, D)}
+    align = min(da.block_rows(D, dtype), cfg.window_size)
+    fam = evabyte.family(cfg, evabyte.TwoTier(
+        cfg.window_size, cfg.chunk_size, cfg.heads, align))
+    T = fam.rows.count(total)
+    print(f"evabyte: {cfg.layers} layers of {D}, {T} rows a slot, read in "
+          f"blocks of {da.read_block(T, D, dtype)}")
+    assert rehearse or da.read_block(T, D, dtype) == 64, "the jnp form serves"
+
+    seen = {}
+    choose = decoder.choose
+
+    def watched(logits, temperature, seeds, t, top_k=None, top_p=None):
+        jax.debug.callback(
+            lambda lg, tt: seen.update({int(tt[0]): np.asarray(lg[0])}),
+            logits, t)
+        return choose(logits, temperature, seeds, t, top_k, top_p)
+
+    decoder.choose = watched
+    rng = np.random.default_rng(SEED)
+    ids = [int(t) for t in rng.integers(0, V, prompt)]
+    toks = np.zeros((1, bucket), np.int32)
+    toks[0, :prompt] = ids
+    z, zi = jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32)
+    prefill = jax.jit(lambda p, toks, lens: decoder.prefill_start(
+        fam, p, toks, lens, z, zi, total, dtype, top_k=zi, top_p=z + 1))
+    segment = jax.jit(
+        lambda p, ck, cv, tok, pos, st, fin: decoder.decode_segment(
+            fam, p, decoder.slot_pool(ck, cv, fam.rows), tok, pos, st, fin,
+            z, zi, 8, dtype, top_k=zi, top_p=z + 1), donate_argnums=(1, 2))
+    t0 = time.monotonic()
+    tok, ck, cv = prefill(params, jnp.asarray(toks),
+                          jnp.asarray([prompt], jnp.int32))
+    pos, st, fin = jnp.asarray([prompt], jnp.int32), zi, jnp.zeros((1,), bool)
+    served = []
+    for _ in range(4):
+        emits, ck, cv, tok, pos, st, fin = segment(params, ck, cv, tok, pos,
+                                                   st, fin)
+        served += [int(t) for t in np.asarray(emits)[0]]
+    jax.effects_barrier()
+    print(f"evabyte: prefill of {prompt} bytes and 4 segments in "
+          f"{time.monotonic() - t0:.1f} s (compiles included); bytes "
+          f"{served[:8]}...")
+    got = np.stack([seen[t] for t in range(32)])
+    config = {"num_attention_heads": cfg.heads,
+              "window_size": cfg.window_size, "chunk_size": cfg.chunk_size,
+              "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.rms_norm_eps,
+              "vocab_size": V}
+    report = {}
+    for name, int8 in (("float32", False), ("int8", True)):
+        t0 = time.monotonic()
+        ref = reference.forward(params, ids + served[:-1], config, cfg.layers,
+                                int8)[prompt - 1:]
+        deficit = max(float(np.max(r) - r[t]) for r, t in zip(ref, served))
+        report[name] = {"max_abs_logit_diff": float(np.max(np.abs(got - ref))),
+                        "deficit": deficit,
+                        "seconds": round(time.monotonic() - t0, 1)}
+    report["logit_std"] = float(np.std(got))
+    print("evabyte " + json.dumps(report))
+    # bfloat16 activations through the layers against float32: the logits
+    # (of spread ``logit_std``) within LOGIT_TOL everywhere; the reference
+    # through int8 weights further off than that, or the check could not
+    # tell the two precisions apart.
+    tol = 1e-3 if rehearse else EVABYTE_LOGIT_TOL
+    assert report["float32"]["max_abs_logit_diff"] <= tol, report
+    assert rehearse or report["int8"]["max_abs_logit_diff"] > tol, report
+    print(json.dumps(report))
 
 
 def _multichip_child(rehearse: bool) -> None:
@@ -998,6 +1162,11 @@ def main(argv=None) -> int:
             say("segment: no copy, slice or transpose of a layer of the "
                 "slot pool in the compiled decode segment"
                 + (" (not asserted on the CPU)" if args.rehearse else ""))
+            run_child(f"import chip_smoke; "
+                      f"chip_smoke._evabyte_child({args.rehearse})",
+                      args.rehearse, "evabyte.log", timeout=1200.0)
+            say("evabyte: prefill and decode through the two-tier pool "
+                "agree with the plain reference across a window's end")
             phase_sd15(sd15_cfg, probe, args.rehearse)
     except SmokeFailure as e:
         print(f"[smoke] FAIL after {time.monotonic() - t0:.0f}s: {e}",

@@ -38,20 +38,96 @@ from ..ops.sampling import (DRAFT_SEED_SALT, apply_repetition_penalty,
                             choose)
 
 
+class Rows:
+    """How the ``T`` rows of a slot hold its positions, and what a query
+    reads of them.  This one is a row a position: position ``p`` lies at row
+    ``p`` and a query there reads rows ``[0, p]``.  A family whose cache is
+    not that (models/evabyte.py keeps a ring of exact rows and a summary row
+    a chunk before it) brings its own; every method takes ``T`` so that one
+    object serves every pool length, and ``row`` / ``span`` are written in
+    operators alone, so that the scheduler counts with them in numpy what
+    the programs compute with them traced.  What a query reads is always one
+    contiguous span of rows: ops/decode_attention.py takes a first and a
+    last row and nothing more."""
+
+    def count(self, total: int) -> int:
+        """Rows a slot needs for ``total`` positions."""
+        return total
+
+    def positions(self, T: int) -> int:
+        """Positions ``T`` rows hold."""
+        return T
+
+    def row(self, pos, T: int):
+        """The row position ``pos`` is written to."""
+        return pos
+
+    def span(self, pos, T: int):
+        """``(first, last)`` rows a query at ``pos`` reads."""
+        return pos * 0, pos
+
+    def summaries(self, pos, T: int):
+        """How many rows of that span stand for more than one position."""
+        return pos * 0
+
+    def windows(self, n: int) -> int:
+        """Passes the prompt attention makes over a prompt of ``n``."""
+        return 1
+
+    def prefill_batch(self, bucket: int) -> int | None:
+        """Prompts of ``bucket`` one prefill dispatch may hold (None: as
+        many as are admitted together)."""
+        return None
+
+    def settle(self, p, k, v, layer, slots, pos):
+        """After position ``pos`` [S] of ``layer`` was written into ``k``,
+        ``v`` [L, S, T, D]: whatever else the rows keep of it (``p`` is the
+        layer's parameters).  Nothing here."""
+        return k, v
+
+    def prompt(self, heads: int, lengths, P: int):
+        """The prompt attention of a prefill over ``P`` positions of which
+        row b holds ``lengths[b]``: ``attend(p, cache, i, q, k, v) ->
+        (cache, out [B, P, D])`` leaves in ``cache`` (K and V [L, B, T, D],
+        zeros at first) the rows of layer ``i`` that a decode step will
+        read, and returns the attention output (``p``: the layer's
+        parameters).  Here causal and ragged in one softmax over ``[P, P]``
+        scores, and the rows are the prompt's own K and V."""
+        pos = jnp.arange(P)
+        # Causal AND ragged: query i attends keys j<=i that are real (j < len).
+        causal = pos[None, :, None] >= pos[None, None, :]          # [1,P,P]
+        real = pos[None, None, :] < lengths[:, None, None]          # [B,1,P]
+        mask_bias = jnp.where(causal & real, 0.0, -1e9).astype(jnp.float32)[:, None]
+
+        def attend(p, cache, i, q, k, v):
+            ck = cache[0].at[i, :, :P].set(k)
+            return ((ck, cache[1].at[i, :, :P].set(v)),
+                    _attn(q, k, v, mask_bias, heads))
+
+        return attend
+
+
+ROWS = Rows()  # a row a position
+
+
 @dataclass(frozen=True)
 class Family:
     """A family's block, and the numbers the programs read of it.
 
     ``embed(params, tokens, dtype)`` → a row a token; ``positions(params,
     dtype)`` → the learned position table added to them, or None.
-    ``layer(layer_params, x, attend, lora=None, lora_idx=None)`` → one block
-    over x [B, Tq, D]; it calls ``attend(q, k, v)`` once with its fresh
-    projections ([B, Tq, width]) and gets the attention output back — the
-    single point where the phases differ, which the programs fill in.
-    ``norm(params, x)`` is the final norm, ``head(params, x [N, D])`` the
-    float32 logits.  ``pre_tree(p)`` / ``dec_tree(p, rows)`` pick the weights
-    of a prefill or of a program of ``rows`` decode rows, for a family that
-    holds more than one tree.
+    ``layer(layer_params, x, attend, pos, lora=None, lora_idx=None)`` → one
+    block over x [B, Tq, D] whose rows stand at the absolute positions
+    ``pos`` ([B, Tq] or [Tq] int32: what a rotary family turns its queries
+    and keys by; the same in prefill, insert and decode); it calls
+    ``attend(q, k, v)`` once with its fresh projections ([B, Tq, width]) and
+    gets the attention output back — the single point where the phases
+    differ, which the programs fill in.  ``norm(params, x)`` is the final
+    norm, ``head(params, x [N, D])`` the float32 logits.  ``pre_tree(p)`` /
+    ``dec_tree(p, rows)`` pick the weights of a prefill or of a program of
+    ``rows`` decode rows, for a family that holds more than one tree.
+    ``rows`` says how a slot's cache rows hold its positions (:class:`Rows`:
+    a row a position unless the family brings its own).
     """
     embed: Callable
     positions: Callable | None
@@ -66,6 +142,7 @@ class Family:
     vocab_size: int
     pre_tree: Callable = lambda p: p
     dec_tree: Callable = lambda p, rows: p
+    rows: Rows = ROWS
 
 
 # ---------------------------------------------------------------------------
@@ -73,32 +150,44 @@ class Family:
 # ---------------------------------------------------------------------------
 
 class SlotPool(NamedTuple):
-    """A row a slot: ``k``, ``v`` [L, S, T, D].  ``rows`` is ``arange(S)``,
-    made where the pool is (:func:`slot_pool`), outside any scan.  One query
-    a slot: nothing feeds it several."""
+    """A row a slot: ``k``, ``v`` [L, S, T, D].  ``slots`` is ``arange(S)``,
+    made where the pool is (:func:`slot_pool`), outside any scan, and
+    ``rows`` how the ``T`` rows hold a slot's positions.  One query a slot:
+    nothing feeds it several."""
     k: jax.Array
     v: jax.Array
-    rows: jax.Array
+    slots: jax.Array
+    rows: Rows
 
     @property
     def positions(self) -> int:
-        return self.k.shape[2]
+        return self.rows.positions(self.k.shape[2])
 
-    def write(self, layer, wpos, k, v):
-        """This layer's ``k``, ``v`` [S, 1, D] at ``wpos`` [S]."""
-        ck = self.k.at[layer, self.rows, wpos].set(k[:, 0])
-        return self._replace(k=ck,
-                             v=self.v.at[layer, self.rows, wpos].set(v[:, 0]))
+    def span(self, pos):
+        """``(first, last)`` rows a query at ``pos`` [S] reads."""
+        return self.rows.span(pos, self.k.shape[2])
 
-    def attend(self, layer, q, wpos, heads, work=None):
-        """Over this layer where it lies, each slot as far as ``wpos`` [S]
-        (negative: dead, reads nothing)."""
+    def write(self, layer, pos, k, v, p=None):
+        """This layer's ``k``, ``v`` [S, 1, D] for position ``pos`` [S]
+        (``p``: the layer's parameters, for what the rows keep beside)."""
+        row = self.rows.row(pos, self.k.shape[2])
+        ck = self.k.at[layer, self.slots, row].set(k[:, 0])
+        cv = self.v.at[layer, self.slots, row].set(v[:, 0])
+        ck, cv = self.rows.settle(p, ck, cv, layer, self.slots, pos)
+        return self._replace(k=ck, v=cv)
+
+    def attend(self, layer, q, span, heads, work=None):
+        """Over this layer where it lies, each slot over its ``span`` of
+        rows ``(first, last)`` [S] (``last`` negative: dead, reads
+        nothing)."""
+        first, last = span
         return decode_attention.attend(q, self.k, self.v, layer,
-                                       wpos[:, None], heads, work)
+                                       last[:, None], heads, work,
+                                       first[:, None])
 
 
-def slot_pool(k, v) -> SlotPool:
-    return SlotPool(k, v, jnp.arange(k.shape[1]))
+def slot_pool(k, v, rows: Rows = ROWS) -> SlotPool:
+    return SlotPool(k, v, jnp.arange(k.shape[1]), rows)
 
 
 class PagedPool(NamedTuple):
@@ -118,7 +207,11 @@ class PagedPool(NamedTuple):
     def positions(self) -> int:
         return self.table.shape[1] * self.block_size
 
-    def write(self, layer, wpos, k, v):
+    def span(self, pos):
+        """A page table holds a row a position: ``[0, pos]``."""
+        return jnp.zeros_like(pos), pos
+
+    def write(self, layer, wpos, k, v, p=None):
         """This layer's ``k``, ``v`` [S, Tq, D] at ``wpos`` [S, Tq] (or [S]
         for one query a slot), absolute and clipped to the virtual range."""
         def put(pages, values):
@@ -137,9 +230,10 @@ class PagedPool(NamedTuple):
         """This layer's virtual cache, K and V [S, MB*BS, D]."""
         return self._virtual(self.k, layer), self._virtual(self.v, layer)
 
-    def attend(self, layer, q, wpos, heads, work=None):
-        """Over this layer's virtual cache, each query as far as ``wpos``
-        [S, Tq] (or [S])."""
+    def attend(self, layer, q, span, heads, work=None):
+        """Over this layer's virtual cache, each query as far as the last
+        row of its ``span``, [S, Tq] (or [S])."""
+        wpos = span[1]
         return decode_attention.attend(
             q, self._virtual(self.k, layer)[None],
             self._virtual(self.v, layer)[None], 0,
@@ -177,9 +271,10 @@ def _embed(fam: Family, params, tokens, pos, dtype, clamp=True):
                      else pos]
 
 
-def _trunk(fam: Family, params, x, cache, attend, adapter_idx=None):
-    """Every layer of the family over ``x`` (embedded by the program, which
-    builds its masks after it), then the final norm → ``(x, cache)``.
+def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None):
+    """Every layer of the family over ``x`` at the positions ``pos``
+    (embedded by the program, which builds its masks after it), then the
+    final norm → ``(x, cache)``.
     ``attend(cache, i, q, k, v) -> (cache, out)`` stores layer ``i``'s K/V
     however the program caches and returns the attention output.
     ``adapter_idx`` [B] routes each row through its tenant's LoRA slot of
@@ -191,28 +286,29 @@ def _trunk(fam: Family, params, x, cache, attend, adapter_idx=None):
             cache, out = attend(cache, i, q, k, v)
             return out
 
-        x = fam.layer(params[f"layer{i}"], x, layer_attend,
+        x = fam.layer(params[f"layer{i}"], x, layer_attend, pos,
                       lora=None if stacks is None else stacks.get(f"layer{i}"),
                       lora_idx=adapter_idx)
     return fam.norm(params, x), cache
 
 
-def _write_then_attend(fam, wpos, last, work=None):
-    """The decode programs' ``attend``: this layer's K/V in at ``wpos``,
-    then each query over the layer as far as ``last``."""
+def _write_then_attend(fam, params, wpos, span, work=None):
+    """The decode programs' ``attend``: this layer's K/V in for position
+    ``wpos``, then each query over its ``span`` of the layer's rows."""
     def attend(pool, i, q, k, v):
-        pool = pool.write(i, wpos, k, v)
-        return pool, pool.attend(i, q, last, fam.heads, work)
+        pool = pool.write(i, wpos, k, v, params[f"layer{i}"])
+        return pool, pool.attend(i, q, span, fam.heads, work)
 
     return attend
 
 
-def _decode_logits(fam, params, pool, tok, wpos, last, work, dtype,
+def _decode_logits(fam, params, pool, tok, wpos, span, work, dtype,
                    adapter_idx=None):
     """One token a slot through the trunk → (logits [S, V], pool)."""
     x = _embed(fam, params, tok, wpos, dtype)[:, None, :]
-    x, pool = _trunk(fam, params, x, pool,
-                     _write_then_attend(fam, wpos, last, work), adapter_idx)
+    x, pool = _trunk(fam, params, x, wpos[:, None], pool,
+                     _write_then_attend(fam, params, wpos, span, work),
+                     adapter_idx)
     return fam.head(params, x[:, 0]), pool
 
 
@@ -258,27 +354,25 @@ def prefill(fam: Family, params: dict, tokens: jax.Array, lengths: jax.Array,
             total: int, dtype=jnp.bfloat16, adapter_idx=None):
     """Whole-prompt forward: fills the KV cache, returns last-token logits.
 
-    tokens [B, P] int32 (zero-padded), lengths [B] int32, ``total`` the cache
-    size (P + max_new).  Returns (logits [B, V] at position length-1,
-    cache_k, cache_v [L, B, total, D]): rows for a slot pool, made here from
-    nothing, so each layer writes a prefix and attends its own fresh K/V.
+    tokens [B, P] int32 (zero-padded), lengths [B] int32, ``total`` the
+    positions the cache is for (P + max_new).  Returns (logits [B, V] at
+    position length-1, cache_k, cache_v [L, B, T, D]): rows for a slot pool
+    (``T`` rows hold ``total`` positions as the family's ``rows`` lay them
+    out), made here from nothing, so each layer attends its own fresh K/V
+    (``Rows.prompt``) and leaves the rows a decode step will read.
     """
     B, P = tokens.shape
     pos = jnp.arange(P)
     x = _embed(fam, params, tokens, pos, dtype, clamp=False)
-    # Causal AND ragged: query i attends keys j<=i that are real (j < len).
-    causal = pos[None, :, None] >= pos[None, None, :]          # [1,P,P]
-    real = pos[None, None, :] < lengths[:, None, None]          # [B,1,P]
-    mask_bias = jnp.where(causal & real, 0.0, -1e9).astype(jnp.float32)[:, None]
-    cache = (jnp.zeros((fam.layers, B, total, fam.width), dtype),
-             jnp.zeros((fam.layers, B, total, fam.width), dtype))
+    T = fam.rows.count(total)
+    prompt = fam.rows.prompt(fam.heads, lengths, P)
+    cache = (jnp.zeros((fam.layers, B, T, fam.width), dtype),
+             jnp.zeros((fam.layers, B, T, fam.width), dtype))
 
     def attend(cache, i, q, k, v):
-        ck = cache[0].at[i, :, :P].set(k)
-        return ((ck, cache[1].at[i, :, :P].set(v)),
-                _attn(q, k, v, mask_bias, fam.heads))
+        return prompt(params[f"layer{i}"], cache, i, q, k, v)
 
-    x, cache = _trunk(fam, params, x, cache, attend, adapter_idx)
+    x, cache = _trunk(fam, params, x, pos, cache, attend, adapter_idx)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return (fam.head(params, last),) + cache
 
@@ -335,7 +429,7 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
     written, from one list of live blocks a step.  Returns (emits [S, seg],
     cache_k, cache_v, tok, pos, step, finished), as :func:`segment_scan`.
     """
-    total = pool.positions
+    total, T = pool.positions, pool.k.shape[2]
     # Repetition penalty (fixed-batch lane only, which is the slot pool —
     # the streaming lane would need a [S, V] presence buffer donated across
     # segments; declined there, loudly, in serving/server.py): the presence
@@ -350,14 +444,15 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
         wpos = jnp.minimum(pos, total - 1)
         # A finished slot's token is pinned to EOS whatever it attends to:
         # it is dead to attention, which reads nothing of its row.
-        last = jnp.where(finished, -1, wpos)
-        work = decode_attention.step_work(last, total, fam.width,
-                                          cache[0].dtype)
+        first, last = pool.span(wpos)
+        last = jnp.where(finished, -1, last)
+        work = decode_attention.step_work(last, T, fam.width,
+                                          cache[0].dtype, first)
         logits, p = _decode_logits(
             fam, params, pool._replace(k=cache[0], v=cache[1]), tok, wpos,
-            last, work, dtype, adapter_idx)
+            (first, last), work, dtype, adapter_idx)
         if seen is not None:
-            seen = seen.at[pool.rows, tok].set(True)
+            seen = seen.at[pool.slots, tok].set(True)
             logits = _penalized(logits, seen, repetition_penalty, rep_on)
         nxt = choose(logits, temperature, seeds, t + 1, top_k, top_p)
         return (p.k, p.v), nxt, seen
@@ -396,8 +491,8 @@ def generate(fam: Family, params: dict, tokens: jax.Array,
         presence=presence, adapter_idx=adapter_idx)
     step, finished = jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool)
     emits, *_ = decode_segment(
-        fam, fam.dec_tree(params, B), slot_pool(cache_k, cache_v), first,
-        lengths, step, finished,
+        fam, fam.dec_tree(params, B), slot_pool(cache_k, cache_v, fam.rows),
+        first, lengths, step, finished,
         temperature, seeds, max_new, dtype, top_k=top_k, top_p=top_p,
         repetition_penalty=repetition_penalty, presence=presence,
         adapter_idx=adapter_idx)
@@ -440,7 +535,7 @@ def prefill_chunk(fam: Family, params: dict, tokens: jax.Array,
         pool = pool.write(i, wpos, k, v)
         return pool, _attn(q, *pool.view(i), mask_bias, fam.heads)
 
-    x, pool = _trunk(fam, params, x, pool, attend, adapter_idx)
+    x, pool = _trunk(fam, params, x, pos, pool, attend, adapter_idx)
     idx = jnp.clip(lengths - 1 - start, 0, C - 1)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     first = choose(fam.head(params, last), temperature, seeds,
@@ -478,7 +573,7 @@ def propose(fam: Family, params: dict, pool, prev: jax.Array, tok: jax.Array,
         wpos = jnp.minimum(pos, VT - 1)
         logits, p = _decode_logits(
             fam, params, pool._replace(k=cache_k, v=cache_v), cur, wpos,
-            wpos, None, dtype)
+            pool.span(wpos), None, dtype)
         nxt = choose(logits, temperature, draft_seeds, t + 1, top_k, top_p)
         # Backfill step feeds the pending token next; proposal steps feed
         # the model's own choice.
@@ -515,7 +610,8 @@ def verify(fam: Family, params: dict, pool, toks: jax.Array, pos: jax.Array,
     p = pos[:, None] + jnp.arange(K1)[None, :]
     wp = jnp.minimum(p, pool.positions - 1)
     x = _embed(fam, params, toks, wp, dtype)
-    x, pool = _trunk(fam, params, x, pool, _write_then_attend(fam, wp, wp))
+    x, pool = _trunk(fam, params, x, wp, pool,
+                     _write_then_attend(fam, params, wp, pool.span(wp)))
     logits = fam.head(params, x.reshape(S * K1, -1)).reshape(S, K1, -1)
     return logits, pool.k, pool.v
 
@@ -565,6 +661,16 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
             f"{name}: max(seq_buckets) + max_new_tokens = {max_seq} + "
             f"{max_new} exceeds the model's max_positions "
             f"({fam.max_positions}); shrink seq_buckets or max_new_tokens")
+
+    paged_ok = type(fam.rows) is Rows
+    if not paged_ok and getattr(cfg_model, "kv_cache", "slot") == "paged":
+        # A page table holds a row a position and the chunked prefill reads
+        # it back as one: a family whose rows are laid out otherwise has no
+        # paged lane, and says so here rather than serve something else.
+        raise ValueError(
+            f"{name}: kv_cache='paged' cannot serve this family: its cache "
+            f"rows are not a row a position ({type(fam.rows).__name__}); "
+            "use kv_cache='slot'")
 
     adapters_on = int(getattr(cfg_model, "adapter_slots", 0)) > 0
     if adapters_on:
@@ -697,6 +803,7 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
     gen_slots = int(cfg_model.extra.get("gen_slots", 4))
     segment_tokens = int(cfg_model.extra.get("segment_tokens", 8))
     total = max_seq + max_new
+    T = fam.rows.count(total)  # rows a slot holds for ``total`` positions
 
     def collate_admit(sample, bucket):
         ids = np.asarray(sample["input_ids"], np.int32)
@@ -721,10 +828,13 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         "admit_len_of": lambda s: int(np.asarray(s["input_ids"]).shape[0]),
         "collate_admit": collate_admit,
         "admit_spec": admit_spec,
-        "cache_shape": (fam.layers, gen_slots, total, fam.width),
+        "cache_shape": (fam.layers, gen_slots, T, fam.width),
         "cache_dtype": dtype,
-        # Positions decode attention reads a live slot's row in.
-        "read_block": decode_attention.read_block(total, fam.width, dtype),
+        # Rows decode attention reads a live slot's row in.
+        "read_block": decode_attention.read_block(T, fam.width, dtype),
+        # What the scheduler counts with, in numpy (spans, summaries, the
+        # passes of a prompt's attention, prompts a prefill dispatch).
+        "rows": fam.rows,
         # Routed lane: admission prefills run on the prefill tree, the
         # slot-pool segment routes on the POOL size (the decode-row count of
         # its program) — consistent with the fixed-batch path at the same
@@ -739,7 +849,8 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         "segment": (lambda p, ck, cv, tok, pos, st, fin, temp, seeds,
                     topk, topp:
                     decode_segment(fam, fam.dec_tree(p, gen_slots),
-                                   slot_pool(ck, cv), tok, pos, st, fin,
+                                   slot_pool(ck, cv, fam.rows), tok, pos,
+                                   st, fin,
                                    temp, seeds, segment_tokens, dtype,
                                    top_k=topk, top_p=topp)),
         "detokenize": ((lambda toks: tokenizer.decode(toks))
@@ -791,7 +902,7 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
                        dtype)),
         }
 
-    continuous["paged"] = {
+    continuous["paged"] = None if not paged_ok else {
         "make": _make_paged,
         "cache_shape": (lambda num_blocks, block_size:
                         (fam.layers, num_blocks, block_size, fam.width)),

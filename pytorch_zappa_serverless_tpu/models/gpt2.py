@@ -160,7 +160,7 @@ def family(cfg: GPT2Config, **tree_hooks) -> Family:
     return Family(
         embed=lambda params, tokens, dtype: params["wte"].astype(dtype)[tokens],
         positions=lambda params, dtype: params["wpe"].astype(dtype),
-        layer=(lambda p, x, attend, lora=None, lora_idx=None:
+        layer=(lambda p, x, attend, pos, lora=None, lora_idx=None:
                _layer(p, x, cfg, attend, lora, lora_idx)),
         norm=lambda params, x: _ln(params["ln_f"], x, cfg.ln_eps),
         head=_logits, layers=cfg.layers, width=cfg.d_model, heads=cfg.heads,

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .decoder import KNOBS, knob_batch, knob_spec, segment_scan
+from .decoder import KNOBS, ROWS, knob_batch, knob_spec, segment_scan
 
 
 @dataclass(frozen=True)
@@ -651,6 +651,7 @@ def make_whisper_servable(name: str, cfg_model) -> Any:
         "slots": gen_slots,
         "segment_tokens": segment_tokens,
         "total": total_self,
+        "rows": ROWS,  # self-attention: a row a position
         "eos_id": cfg.eot_id,
         "max_new": max_new,
         # One admission bucket: every request is one fixed-size mel window.

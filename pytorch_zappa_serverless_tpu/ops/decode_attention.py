@@ -11,6 +11,12 @@ zeros — and a block past a slot's last written position costs no grid step,
 no DMA and no arithmetic.  The list is built once a step from ``wpos`` and
 shared by every layer's call through scalar prefetch.
 
+What a slot reads is a *span* of its row, ``[first, wpos]``: a cache whose
+rows are the positions reads from row 0 (``first`` left out), one that keeps
+its oldest positions elsewhere (models/evabyte.py: summaries below a ring of
+exact rows) says where its span starts, and the blocks before it are no more
+visited than those after it.
+
 The blocks come straight out of the 4-D pool through the BlockSpec index
 maps (layer static, slot and block read from the list), so nothing
 ``[S, T, D]``-sized is sliced, copied or transposed.  The grid is
@@ -54,16 +60,28 @@ _SLAB_BYTES = 512 << 10
 _VMEM_BYTES = 8 << 20
 
 
-def pick_block_t(total: int, d: int, dtype) -> int:
-    """Positions a block holds, from what the pool shows: the largest
-    multiple of the dtype's sublane tile (8 rows of 32 bits: 16 for
-    bfloat16) that divides ``total`` and keeps ``[block, d]`` within
-    ``_SLAB_BYTES``; ``total`` itself when no such multiple divides it (one
-    block a slot, which the caller weighs with :func:`fits_vmem`)."""
+def _slab(d: int, dtype) -> tuple[int, int]:
+    """The dtype's sublane tile (8 rows of 32 bits: 16 for bfloat16) and the
+    positions in whole tiles that ``_SLAB_BYTES`` hold at width ``d``."""
     itemsize = jnp.dtype(dtype).itemsize
     tile = 8 * 4 // itemsize
-    want = min(_SLAB_BYTES // (d * itemsize), total)
-    for cand in range(want // tile * tile, 0, -tile):
+    return tile, _SLAB_BYTES // (d * itemsize) // tile * tile
+
+
+def block_rows(d: int, dtype) -> int:
+    """Positions a block of K aims at, at width ``d`` (a tile at least): a
+    pool whose length is a multiple of it is read in blocks that long."""
+    return max(_slab(d, dtype))
+
+
+def pick_block_t(total: int, d: int, dtype) -> int:
+    """Positions a block holds, from what the pool shows: the largest
+    multiple of the dtype's sublane tile that divides ``total`` and keeps
+    ``[block, d]`` within ``_SLAB_BYTES``; ``total`` itself when no such
+    multiple divides it (one block a slot, which the caller weighs with
+    :func:`fits_vmem`)."""
+    tile, want = _slab(d, dtype)
+    for cand in range(min(want, total) // tile * tile, 0, -tile):
         if total % cand == 0:
             return cand
     return total
@@ -74,30 +92,41 @@ def fits_vmem(block_t: int, d: int, dtype) -> bool:
     return 4 * block_t * d * jnp.dtype(dtype).itemsize <= _VMEM_BYTES
 
 
-def work_list(wpos, total: int, block_t: int):
+def _first_row(first, wpos):
+    """``first`` [S] as int32 rows inside ``[0, wpos]`` (None: row 0)."""
+    if first is None:
+        return jnp.zeros_like(wpos)
+    return jnp.clip(first.astype(jnp.int32), 0, jnp.maximum(wpos, 0))
+
+
+def work_list(wpos, total: int, block_t: int, first=None):
     """The live ``(slot, block)`` pairs of a step, compacted in slot order.
 
     wpos [S] int32, the last position each slot may read, negative for a
-    dead slot → ``(slot [W], block [W], count)`` int32 with ``W = S *
-    total / block_t``; entries from ``count`` on are padding the kernel
-    never visits.  Built once a step, outside the layer loop."""
+    dead slot, and ``first`` [S] the first (None: 0) → ``(slot [W], block
+    [W], count)`` int32 with ``W = S * total / block_t``; entries from
+    ``count`` on are padding the kernel never visits.  Built once a step,
+    outside the layer loop."""
     S = wpos.shape[0]
     per_slot = total // block_t
     wpos = jnp.minimum(wpos.astype(jnp.int32), total - 1)
-    blocks = jnp.where(wpos >= 0, wpos // block_t + 1, 0)          # [S]
+    lead = _first_row(first, wpos) // block_t                       # [S]
+    blocks = jnp.where(wpos >= 0, wpos // block_t + 1 - lead, 0)
     ends = jnp.cumsum(blocks)
     i = jnp.arange(S * per_slot, dtype=jnp.int32)
     slot = jnp.minimum((ends[None, :] <= i[:, None]).sum(1), S - 1)
-    block = jnp.clip(i - (ends - blocks)[slot], 0, per_slot - 1)
+    block = jnp.clip(i - (ends - blocks)[slot] + lead[slot], 0,
+                     per_slot - 1)
     return (slot.astype(jnp.int32), block.astype(jnp.int32),
             ends[-1].astype(jnp.int32))
 
 
-def _kernel(slot_ref, block_ref, wpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-            l_ref, acc_ref, *, block_t: int, head_dim: int):
+def _kernel(slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref, v_ref,
+            o_ref, m_ref, l_ref, acc_ref, *, block_t: int, head_dim: int):
     i = pl.program_id(0)
     b = block_ref[i]
     last = wpos_ref[slot_ref[i]]
+    first = first_ref[slot_ref[i]]
     rows, D = acc_ref.shape
 
     def own():
@@ -106,7 +135,7 @@ def _kernel(slot_ref, block_ref, wpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (rows, D), 1)
         return (col >= row * head_dim) & (col < (row + 1) * head_dim)
 
-    @pl.when(b == 0)
+    @pl.when(b == first // block_t)
     def _():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -122,7 +151,7 @@ def _kernel(slot_ref, block_ref, wpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
         qh, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                   # [rows, bt]
     kpos = b * block_t + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(kpos <= last, scores, _MASKED)
+    scores = jnp.where((kpos >= first) & (kpos <= last), scores, _MASKED)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
@@ -143,14 +172,15 @@ def _kernel(slot_ref, block_ref, wpos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
 
 @functools.partial(jax.jit, static_argnames=("layer", "heads", "block_t",
                                              "interpret"))
-def decode_attention(q, cache_k, cache_v, wpos, work=None, *, layer: int,
-                     heads: int, block_t: int | None = None,
+def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
+                     layer: int, heads: int, block_t: int | None = None,
                      interpret: bool = False):
     """q [S, D] (already scaled), cache_k / cache_v [L, S, T, D], wpos [S]
     int32 the last position each slot may read (``wpos < T``; negative: the
-    slot is dead, read nowhere, its row zeros) → [S, D].  ``work`` is
-    :func:`work_list` of the same ``wpos`` and block length, from a caller
-    that builds it once for many layers."""
+    slot is dead, read nowhere, its row zeros), ``first`` [S] the first
+    (None: 0) → [S, D].  ``work`` is :func:`work_list` of the same
+    ``wpos``, ``first`` and block length, from a caller that builds it once
+    for many layers."""
     S, D = q.shape
     T = cache_k.shape[2]
     bt = block_t or pick_block_t(T, D, cache_k.dtype)
@@ -158,18 +188,20 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, *, layer: int,
         raise ValueError(f"block_t {bt} does not divide the pool's {T} "
                          "positions")
     wpos = wpos.astype(jnp.int32)
-    slot, block, count = work_list(wpos, T, bt) if work is None else work
+    slot, block, count = (work_list(wpos, T, bt, first) if work is None
+                          else work)
+    first = _first_row(first, wpos)
     rows = -(-heads // 16) * 16  # the bf16 sublane tile
     kv_spec = pl.BlockSpec(
         (None, None, bt, D),
-        lambda i, slot, block, wpos: (layer, slot[i], block[i], 0))
-    row_spec = pl.BlockSpec((None, 1, D),
-                            lambda i, slot, block, wpos: (slot[i], 0, 0))
+        lambda i, slot, block, wpos, first: (layer, slot[i], block[i], 0))
+    row_spec = pl.BlockSpec(
+        (None, 1, D), lambda i, slot, block, wpos, first: (slot[i], 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, block_t=bt, head_dim=D // heads),
         out_shape=jax.ShapeDtypeStruct((S, 1, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(count,),  # a dynamic bound: the live blocks and no more
             in_specs=[row_spec, kv_spec, kv_spec],
             out_specs=row_spec,
@@ -180,7 +212,7 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, *, layer: int,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="decode_attention",
-    )(slot, block, wpos, q[:, None, :], cache_k, cache_v)
+    )(slot, block, wpos, first, q[:, None, :], cache_k, cache_v)
     # No grid step visits a dead slot, so nothing wrote its row.
     return jnp.where((wpos >= 0)[:, None], out[:, 0, :], 0)
 
@@ -205,16 +237,16 @@ def read_block(total, d, dtype):
     return _kernel_block(1, total, d, dtype) or total
 
 
-def step_work(last, total, d, dtype):
+def step_work(last, total, d, dtype, first=None):
     """The step's :func:`work_list` for the kernel, built once from ``last``
-    [S] and shared by every layer's :func:`attend` over ``total`` positions
-    of width ``d``; None where the ``jax.numpy`` form runs, which needs
-    none."""
+    (and ``first``) [S] and shared by every layer's :func:`attend` over
+    ``total`` positions of width ``d``; None where the ``jax.numpy`` form
+    runs, which needs none."""
     bt = _kernel_block(1, total, d, dtype)
-    return None if bt is None else work_list(last, total, bt)
+    return None if bt is None else work_list(last, total, bt, first)
 
 
-def attend(q, cache_k, cache_v, layer, wpos, heads, work=None):
+def attend(q, cache_k, cache_v, layer, wpos, heads, work=None, first=None):
     """Decode attention over one layer of the pool, read where it lies.
 
     q [S, Tq, D] (a slot's one query, or the K+1 of a speculative verify),
@@ -223,7 +255,9 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None):
     the last position each query may read → [S, Tq, D].  A negative
     ``wpos`` marks a *dead* query (a finished or empty slot): it reads
     nothing and its output row is zeros, whatever its row of the pool
-    holds.  ``work`` is :func:`step_work` of ``wpos[:, 0]``.
+    holds.  ``first`` [S, Tq] is the first position each query reads
+    (None: 0).  ``work`` is :func:`step_work` of ``wpos[:, 0]`` (and
+    ``first[:, 0]``).
 
     Head-split attention makes ``(slot, head)`` batch dimensions, and a pool
     whose heads lie side by side in ``D`` then has to be sliced out and
@@ -248,6 +282,7 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None):
     bt = _kernel_block(Tq, cache_k.shape[2], D, cache_k.dtype)
     if bt is not None:
         return decode_attention(q[:, 0], cache_k, cache_v, wpos[:, 0], work,
+                                None if first is None else first[:, 0],
                                 layer=layer, heads=heads,
                                 block_t=bt)[:, None]
     cache_k, cache_v = cache_k[layer], cache_v[layer]
@@ -257,6 +292,8 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None):
     scores = jnp.einsum("smd,std->smt", qh.reshape(S, Tq * heads, D),
                         cache_k, preferred_element_type=jnp.float32)
     keep = jnp.arange(T)[None, None, :] <= wpos[:, :, None]     # [S,Tq,T]
+    if first is not None:
+        keep &= jnp.arange(T)[None, None, :] >= first[:, :, None]
     scores = jnp.where(keep[:, :, None, :],
                        scores.reshape(S, Tq, heads, T), -1e9)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)

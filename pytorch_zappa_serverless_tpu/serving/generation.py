@@ -258,6 +258,9 @@ class GenRequest:
     segments_at_submit: int = 0
     rounds_to_first_token: int | None = None
     segments_to_first_token: int | None = None
+    # Passes the prompt's attention made in the prefill (one unless the
+    # family's rows keep windows: models/decoder.Rows.windows).
+    prefill_windows: int | None = None
     # Token events stream here ([] sentinel-free: a None marks completion).
     events: asyncio.Queue = field(default_factory=asyncio.Queue)
     done: asyncio.Future = field(default_factory=asyncio.Future)
@@ -400,9 +403,16 @@ class GenerationScheduler:
         self.params = cm.servable.params
         self.slots: int = meta["slots"]
         self.total: int = meta["total"]
-        # Positions a live slot's row is read in (a model that does not say
+        # How a slot's rows hold its positions (models/decoder.Rows): the
+        # rows a slot needs, the span ``(first, last)`` a position reads of
+        # them and how many of those stand for more than one position, the
+        # passes a prompt's attention makes, and the prompts one prefill
+        # dispatch may hold (None: all admitted together).
+        self._rows = meta["rows"]
+        self.rows: int = self._rows.count(self.total)
+        # Rows a live slot's span is read in (a model that does not say
         # reads whole rows).
-        self.read_block: int = meta.get("read_block", self.total)
+        self.read_block: int = meta.get("read_block", self.rows)
         self.eos_id: int = meta["eos_id"]
         self.max_new: int = meta["max_new"]
         self.seg: int = meta["segment_tokens"]
@@ -477,12 +487,20 @@ class GenerationScheduler:
         # the device worked is chained_rounds / segment_rounds.
         self.chained_rounds = 0  # guarded-by: dispatch-serialized
         # How much of the pool a segment's attention has to read, and how
-        # much its copies cover: per round, the positions of the slots still
-        # generating over slots x total, as they are and each rounded up to
-        # ``read_block`` (decode attention visits the live blocks of the
-        # generating slots and nothing else: ops/decode_attention.py).
+        # much its copies cover: per round, the rows the spans of the slots
+        # still generating hold over slots x rows, as they are and each
+        # span widened to whole ``read_block``s (decode attention visits the
+        # live blocks of the generating slots and nothing else:
+        # ops/decode_attention.py).  Beside them the same rows uncounted by
+        # the pool's size, the summaries among them, and the positions they
+        # stand for; and how often a span's start moved during a segment (a
+        # window completed).
         self.kv_live_sum = 0.0   # guarded-by: dispatch-serialized
         self.kv_read_sum = 0.0   # guarded-by: dispatch-serialized
+        self.span_rows_sum = 0      # guarded-by: dispatch-serialized
+        self.summary_rows_sum = 0   # guarded-by: dispatch-serialized
+        self.live_positions_sum = 0  # guarded-by: dispatch-serialized
+        self.window_rolls = 0       # guarded-by: dispatch-serialized
         # Per-token timing (docs/OBSERVABILITY.md §9): streamed-token count
         # for the perf plane's rolling tok/s gauge, plus the split
         # first-token / inter-token histograms (the two move for different
@@ -492,6 +510,10 @@ class GenerationScheduler:
         self.itl_hist = Histogram(TOKEN_LATENCY_BUCKETS_MS)
         # Host phases of every round, on both threads (serving/tracing.py).
         self.timeline = RoundTimeline(self.name)
+        log_event(log, "generation lane ready", model=self.name, mode="slot",
+                  slots=self.slots, positions=self.total, rows=self.rows,
+                  read_block=self.read_block,
+                  prompt_buckets=list(self.prompt_buckets))
 
     # -- device kernels (all called on the runner's dispatch thread) --------
     def _ensure_cache(self):
@@ -510,7 +532,9 @@ class GenerationScheduler:
     def _admit_sync(self, req: GenRequest, slot: int):
         """Prefill one request and splice it into the pool (dispatch thread)."""
         tl = self.timeline
-        with tl.phase("prefill.launch", programs=1, batch=1):
+        req.prefill_windows = self._rows.windows(self._admit_len_of(req.sample))
+        with tl.phase("prefill.launch", programs=1, batch=1,
+                      windows=req.prefill_windows):
             bucket = self._bucket_for(self._admit_len_of(req.sample))
             payload = self._collate_admit(req.sample, bucket)
             if self.lockstep is not None:
@@ -562,7 +586,11 @@ class GenerationScheduler:
         """
         tl = self.timeline
         B = len(group)
-        with tl.phase("prefill.launch", programs=1, batch=B, bucket=bucket):
+        windows = [self._rows.windows(int(p["length"][0])) for _, _, p in group]
+        for (req, _, _), n in zip(group, windows):
+            req.prefill_windows = n
+        with tl.phase("prefill.launch", programs=1, batch=B, bucket=bucket,
+                      windows=max(windows)):
             Bp = 1 << (B - 1).bit_length()
             payloads = [p for _, _, p in group]
             batched = {
@@ -624,11 +652,18 @@ class GenerationScheduler:
             # The slot state is still what the segment was launched with;
             # what reads it comes before the wait, while the device works.
             live = ~self._finished
-            reach = np.minimum(self._pos[live] + 1, self.total)
-            self.kv_live_sum += float(reach.sum()) / (self.slots * self.total)
+            at = np.minimum(self._pos[live], self.total - 1)
+            first, last = self._rows.span(at, self.rows)
+            held = int((last - first + 1).sum())
+            self.span_rows_sum += held
+            self.summary_rows_sum += int(
+                self._rows.summaries(at, self.rows).sum())
+            self.live_positions_sum += int(at.sum()) + len(at)
+            self.kv_live_sum += held / (self.slots * self.rows)
+            rb = self.read_block
             self.kv_read_sum += float(
-                (-(-reach // self.read_block) * self.read_block).sum()
-            ) / (self.slots * self.total)
+                ((last // rb - first // rb + 1) * rb).sum()
+            ) / (self.slots * self.rows)
             inflight, self._inflight = self._inflight, None
             # The round's one blocking wait: [S, seg + 4], emits then the
             # carries (``build_gen_kernels``); caches stay on device.  The
@@ -639,6 +674,9 @@ class GenerationScheduler:
             self._tok, self._pos, self._step = (
                 packed[:, k].copy() for k in (-4, -3, -2))
             self._finished = packed[:, -1] != 0
+            self.window_rolls += int((self._rows.span(np.minimum(
+                self._pos[live], self.total - 1), self.rows)[0]
+                != first).sum())
             n, done = _retire(emits, live, self._budget, self.eos_id)
             self._budget -= n
             self._finished[done] = True
@@ -728,6 +766,13 @@ class GenerationScheduler:
                                   "count": self.segment_rounds},
                 "kv_read_share": {"sum": round(self.kv_read_sum, 6),
                                   "count": self.segment_rounds},
+                "span_rows": {"sum": self.span_rows_sum,
+                              "count": self.segment_rounds},
+                "summary_rows": {"sum": self.summary_rows_sum,
+                                 "count": self.segment_rounds},
+                "live_positions": {"sum": self.live_positions_sum,
+                                   "count": self.segment_rounds},
+                "window_rolls": self.window_rolls,
                 "latency": {"ttft_ms": self.ttft_hist.snapshot(),
                             "itl_ms": self.itl_hist.snapshot()},
                 "host_phases": self.timeline.snapshot(),
@@ -802,7 +847,14 @@ class GenerationScheduler:
                     else:
                         groups.setdefault(-1 - slot, []).append(
                             (req, slot, None))
-                group_list = list(groups.items())
+                # A dispatch holds as many prompts of a bucket as the model
+                # says one may (all of them, where it says nothing).
+                group_list = [
+                    (bucket, group[i:i + n])
+                    for bucket, group in groups.items()
+                    for n in [(self._rows.prefill_batch(bucket) if bucket >= 0
+                               else None) or len(group)]
+                    for i in range(0, len(group), n)]
             for gi, (bucket, group) in enumerate(group_list):
                 try:
                     if bucket >= 0:  # single-host: batched (B=1 included)

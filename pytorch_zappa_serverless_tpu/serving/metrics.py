@@ -767,25 +767,37 @@ class MetricsHub:
             for key, what in (("segment_rounds", "Decode segments fetched "
                                "per model (:generate lanes)"),
                               ("chained_rounds", "Decode segments launched "
-                               "by the call that fetched the one before")):
+                               "by the call that fetched the one before"),
+                              ("window_rolls", "Times a generating slot's "
+                               "span moved its start during a segment (a "
+                               "window of its cache completed)")):
                 metric(f"tpuserve_{key}_total", "counter", what,
                        [({"model": m}, s[key]) for m, s in gsnap.items()
                         if s.get(key) is not None])
             # How much of the slot pool decode attention has to read
             # (live), and how much its copies cover (read), per segment round
             # (slot lanes): _sum / _count is the mean share.
-            for key, what in (("kv_live_share", "Positions of the generating "
-                               "slots"),
-                              ("kv_read_share", "Positions decode "
-                               "attention's copies cover")):
-                share = {m: s[key] for m, s in gsnap.items()
-                         if s.get(key, {}).get("count")}
-                if not share:
+            # The same spans in rows (what they hold, the summary rows among
+            # them, the positions they stand for), not divided by the pool.
+            for key, what in (
+                    ("kv_live_share", "Rows the generating slots' spans "
+                     "hold over slots x rows"),
+                    ("kv_read_share", "Rows decode attention's copies cover "
+                     "over slots x rows"),
+                    ("span_rows", "Cache rows the generating slots' spans "
+                     "hold"),
+                    ("summary_rows", "Rows of those spans that stand for "
+                     "more than one position"),
+                    ("live_positions", "Positions the generating slots have "
+                     "written")):
+                held = {m: s[key] for m, s in gsnap.items()
+                        if s.get(key, {}).get("count")}
+                if not held:
                     continue
-                lines.append(f"# HELP tpuserve_{key} {what} over slots x "
-                             "total, per segment round")
+                lines.append(f"# HELP tpuserve_{key} {what}, per segment "
+                             "round")
                 lines.append(f"# TYPE tpuserve_{key} summary")
-                for m, v in share.items():
+                for m, v in held.items():
                     label = f'{{model="{_prom_label(m)}"}}'
                     lines.append(f"tpuserve_{key}_sum{label} {v['sum']}")
                     lines.append(f"tpuserve_{key}_count{label} {v['count']}")

@@ -1748,6 +1748,16 @@ class Server:
         out_dir.mkdir(parents=True, exist_ok=True)
         self._tracing = True
         loop = asyncio.get_running_loop()
+
+        def rows_held() -> dict:
+            """What the slot lanes' spans held so far (``gen_snapshot``):
+            taken as the capture begins and ends, so that a reader has the
+            rows of the very rounds whose device time the capture holds."""
+            keys = ("span_rows", "summary_rows", "live_positions")
+            return {name: {k: snap[k] for k in keys}
+                    for name, sched in self.schedulers.items()
+                    for snap in [sched.gen_snapshot()] if keys[0] in snap}
+
         try:
             # start/stop serialize the capture buffer: keep them (and the
             # reduction below) off the event loop so /healthz and predicts
@@ -1756,9 +1766,12 @@ class Server:
             # would 500 every later capture).
             await loop.run_in_executor(None, jax.profiler.start_trace,
                                        str(out_dir))
+            held = rows_held()
             try:
                 await asyncio.sleep(seconds)
             finally:
+                held = {m: {"before": held[m], "after": after}
+                        for m, after in rows_held().items() if m in held}
                 t_stop = _time.monotonic()
                 await loop.run_in_executor(None, jax.profiler.stop_trace)
                 stop_s = _time.monotonic() - t_stop
@@ -1791,7 +1804,7 @@ class Server:
                   stop_trace_s=round(stop_s, 2),
                   classify_s=round(_time.monotonic() - t_classify, 2))
         return web.json_response({"dir": str(out_dir), "seconds": seconds,
-                                  **breakdown})
+                                  "generation": held, **breakdown})
 
     async def handle_predict(self, request):
         return await self._predict(request.match_info["name"], request)
@@ -2512,6 +2525,8 @@ class Server:
                 out["stats"] = {
                     "rounds_to_first_token": gen.rounds_to_first_token,
                     "segments_to_first_token": gen.segments_to_first_token,
+                    **({"prefill_windows": gen.prefill_windows}
+                       if gen.prefill_windows is not None else {}),
                     **gen.timing_stats(),
                 }
             if gen.spec_proposed:

@@ -96,21 +96,26 @@ def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk, h, d, causal):
 # benchmark's two configurations (XL's d 1600 is 12.5 lane tiles; int8-large
 # pools 16 slots), and chip_smoke's GPT-2 small pool of 96 positions.  The
 # grid's one bound is the count of the work list built from a traced ``wpos``.
+# ``eva``: EvaByte's pool (16 layers, 8 slots of 2,880 rows, 32 heads of 128),
+# whose slots read a span with a start.
 DECODE_POOLS = {"xl": (48, 8, 960, 1600, 25), "large": (36, 16, 960, 1280, 20),
-                "small": (12, 8, 96, 768, 12)}
+                "small": (12, 8, 96, 768, 12),
+                "eva": (16, 8, 2880, 4096, 32)}
 
 
 @pytest.mark.parametrize("pool", list(DECODE_POOLS))
 def test_decode_attention_compiles_for_v5e(one_chip, pool):
     layers, slots, total, d, heads = DECODE_POOLS[pool]
     bt = pick_block_t(total, d, jnp.bfloat16)
+    assert pool != "eva" or bt == 64
     text = _compile(
-        lambda q, ck, cv, wpos: decode_attention(
-            q, ck, cv, wpos, work_list(wpos, total, bt), layer=layers // 2,
-            heads=heads, block_t=bt),
+        lambda q, ck, cv, wpos, first: decode_attention(
+            q, ck, cv, wpos, work_list(wpos, total, bt, first), first,
+            layer=layers // 2, heads=heads, block_t=bt),
         one_chip,
         ((slots, d), jnp.bfloat16), ((layers, slots, total, d), jnp.bfloat16),
-        ((layers, slots, total, d), jnp.bfloat16), ((slots,), jnp.int32))
+        ((layers, slots, total, d), jnp.bfloat16), ((slots,), jnp.int32),
+        ((slots,), jnp.int32))
     assert "tpu_custom_call" in text
     # The pool is the kernel's operand as it lies: nothing as large as one
     # layer of it is sliced or copied on the way in.
@@ -133,7 +138,7 @@ def test_decode_segment_compiles_for_v5e_with_one_work_list_a_step(
     built = []
     monkeypatch.setattr(
         decode_attention_module, "work_list",
-        lambda *a: built.append(a[1:]) or work_list(*a))
+        lambda *a: built.append(a[1:3]) or work_list(*a))
     segment, args = chip_smoke.segment_program(cfg, slots, total, one_chip)
     text = segment.lower(*args).compile().as_text()
     assert built == [(total, pick_block_t(total, d, jnp.bfloat16))]

@@ -27,6 +27,8 @@ from pytorch_zappa_serverless_tpu.ops.decode_attention import (
     decode_attention, fits_vmem, pick_block_t, work_list)
 
 WIDTHS = [(1600, 25), (1280, 20)]
+# And EvaByte's row (32 heads of 128), whose slots read a span with a start.
+WIDTHS_SPAN = WIDTHS + [(4096, 32)]
 T, L = 48, 2
 BT = 16  # the block length of every kernel case here
 # pos per slot: nothing but its own row, mid-block, a block edge, the last
@@ -47,9 +49,10 @@ def _pool(rng, S, D, dtype, garbage_beyond=None):
     return out[0], out[1], jnp.asarray(wpos, jnp.int32)
 
 
-def _reference(q, k, v, wpos, heads):
+def _reference(q, k, v, wpos, heads, first=None):
     """Head-split attention in float32, highest precision: q [S, Tq, D],
-    k / v [S, T, D] one layer, wpos [S, Tq] → [S, Tq, D]."""
+    k / v [S, T, D] one layer, wpos [S, Tq] (and ``first`` [S, Tq], the
+    first row read) → [S, Tq, D]."""
     S, Tq, D = q.shape
     dh = D // heads
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
@@ -57,19 +60,22 @@ def _reference(q, k, v, wpos, heads):
     kh, vh = (a.reshape(S, -1, heads, dh) for a in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh, precision="highest")
     keep = jnp.arange(k.shape[1])[None, None, :] <= wpos[:, :, None]
+    if first is not None:
+        keep &= jnp.arange(k.shape[1])[None, None, :] >= first[:, :, None]
     s = jnp.where(keep[:, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, vh,
                       precision="highest").reshape(S, Tq, D)
 
 
-def _run(impl, q, ck, cv, layer, wpos, heads):
+def _run(impl, q, ck, cv, layer, wpos, heads, first=None):
     """The attention under test: [S, 1, D] in, [S, 1, D] out."""
     if impl == "jnp":
-        return DA.attend(q, ck, cv, layer, wpos[:, None], heads)
+        return DA.attend(q, ck, cv, layer, wpos[:, None], heads,
+                         first=None if first is None else first[:, None])
     dh = q.shape[-1] // heads
-    return decode_attention((q * dh ** -0.5)[:, 0], ck, cv, wpos,
-                            layer=layer, heads=heads, block_t=BT,
+    return decode_attention((q * dh ** -0.5)[:, 0], ck, cv, wpos, None,
+                            first, layer=layer, heads=heads, block_t=BT,
                             interpret=True)[:, None]
 
 
@@ -148,7 +154,23 @@ RAGGED = {
     "block-edge": [BT - 1, BT, 2 * BT - 1, 2 * BT],
     "one-position": [0, -1, 0, 40],
     "dead-between": [-1, 33, -1, 7],
+    # Spans with a start (``FIRST``): starting and ending inside blocks, a
+    # row alone in mid-block and on a block's edges, and dead slots between
+    # two spans (a dead slot's start is whatever its position says).
+    "span-inside-blocks": [40, 30, 47, 33],
+    "span-one-row": [7, 16, 31, 47],
+    "span-dead-between": [-1, 29, -1, 47],
 }
+FIRST = {
+    "span-inside-blocks": [5, 20, 0, 17],
+    "span-one-row": [7, 16, 31, 47],
+    "span-dead-between": [12, 10, 0, 33],
+}
+
+
+def _first(pattern):
+    return (jnp.asarray(FIRST[pattern], jnp.int32) if pattern in FIRST
+            else None)
 
 
 def _ragged_pool(rng, wpos, D, dtype, dead_rows=0.0):
@@ -165,16 +187,18 @@ def _ragged_pool(rng, wpos, D, dtype, dead_rows=0.0):
 
 @pytest.mark.parametrize("impl", ["jnp", "kernel"])
 @pytest.mark.parametrize("pattern", list(RAGGED))
-@pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
+@pytest.mark.parametrize("D,heads", WIDTHS_SPAN, ids=["xl", "large", "eva"])
 def test_ragged_pool_live_rows_match_and_dead_rows_are_zero(D, heads,
                                                             pattern, impl):
     rng = np.random.default_rng(D + len(pattern))
-    wpos = jnp.asarray(RAGGED[pattern], jnp.int32)
+    wpos, first = jnp.asarray(RAGGED[pattern], jnp.int32), _first(pattern)
     ck, cv = _ragged_pool(rng, RAGGED[pattern], D, jnp.float32)
     q = jnp.asarray(rng.standard_normal((len(wpos), 1, D)), jnp.float32)
-    got = np.asarray(_run(impl, q, ck, cv, 1, wpos, heads))
-    want = np.asarray(_reference(q, ck[1], cv[1],
-                                 jnp.maximum(wpos, 0)[:, None], heads))
+    got = np.asarray(_run(impl, q, ck, cv, 1, wpos, heads, first))
+    want = np.asarray(_reference(
+        q, ck[1], cv[1], jnp.maximum(wpos, 0)[:, None], heads,
+        None if first is None else jnp.minimum(first, jnp.maximum(
+            wpos, 0))[:, None]))
     live = np.asarray(wpos) >= 0
     np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
     assert not got[~live].any()
@@ -204,9 +228,11 @@ def test_dead_slot_is_zeros_and_its_nan_reaches_nobody(D, heads, dtype, impl):
 @pytest.mark.parametrize("pattern", list(RAGGED))
 def test_work_list_holds_the_live_blocks_in_slot_order(pattern):
     wpos = RAGGED[pattern]
-    slot, block, count = work_list(jnp.asarray(wpos, jnp.int32), T, BT)
-    want = [(s, b) for s, w in enumerate(wpos) for b in range(w // BT + 1)
-            if w >= 0]
+    slot, block, count = work_list(jnp.asarray(wpos, jnp.int32), T, BT,
+                                   _first(pattern))
+    lead = FIRST.get(pattern, [0] * len(wpos))
+    want = [(s, b) for s, w in enumerate(wpos)
+            for b in range(lead[s] // BT, w // BT + 1) if w >= 0]
     assert int(count) == len(want)
     assert slot.shape == block.shape == (len(wpos) * T // BT,)
     got = list(zip(slot.tolist(), block.tolist()))
@@ -353,7 +379,7 @@ def test_segment_builds_one_work_list_a_step(monkeypatch):
     _through_kernel(monkeypatch)
     built = []
     monkeypatch.setattr(DA, "work_list",
-                        lambda *a: built.append(a[1:]) or work_list(*a))
+                        lambda *a: built.append(a[1:3]) or work_list(*a))
     cfg = G.GPT2Config(vocab_size=96, d_model=32, layers=3, heads=2,
                        ffn_dim=64, max_positions=T, eos_id=95)
     params = jax.tree.map(jnp.asarray, G.init_gpt2_params(2, cfg))

@@ -30,7 +30,7 @@ def _rms(scale, x):
             * scale).astype(x.dtype)
 
 
-def _toy_layer(p, x, attend, lora=None, lora_idx=None):
+def _toy_layer(p, x, attend, pos, lora=None, lora_idx=None):
     h = _rms(p["n1"], x)
     x = x + attend(h @ p["q"], h @ p["k"], h @ p["v"]) @ p["o"]
     h = _rms(p["n2"], x)

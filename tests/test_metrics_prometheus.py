@@ -122,6 +122,10 @@ def _loaded_hub():
                  "prefill_dispatches": 2, "tokens_emitted": 10,
                  "kv_live_share": {"sum": 0.93, "count": 5},
                  "kv_read_share": {"sum": 1.25, "count": 5},
+                 "span_rows": {"sum": 3561, "count": 5},
+                 "summary_rows": {"sum": 768, "count": 5},
+                 "live_positions": {"sum": 14900, "count": 5},
+                 "window_rolls": 2,
                  "latency": _tok_lat},
         'pa"ged\\model': {
             "mode": "paged", "slots": 8, "active": 2, "prefilling": 1,
