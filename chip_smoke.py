@@ -28,11 +28,13 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
 - *restart*  the same config again: the boot must hit the compile cache.
 - *kernels*  ``int8_matmul``, ``flash_attention`` and ``decode_attention``
              with ``interpret=False`` at real shapes against ``jax.numpy``
-             references, on the chip; then ``decode_attention`` alone by the
-             profiler's clock, microseconds a layer at three fills of the
-             serving pools beside the least their live bytes allow
-             (EvaByte's pool, 32 heads of 128 at ``D`` 4096, in spans with a
-             start).
+             references, on the chip; then ``int8_matmul`` alone by the
+             profiler's clock, microseconds a call at the five shapes of
+             GPT-2 large's decode step beside the least their bytes allow,
+             and ``decode_attention`` alone, microseconds a layer at three
+             fills of the serving pools beside the least their live bytes
+             allow (EvaByte's pool, 32 heads of 128 at ``D`` 4096, in spans
+             with a start).
 - *segment*  the decode segment program at GPT-2 XL's serving shape (8 slots
              of 960 positions, 48 layers, compiled from shapes alone): its
              optimised HLO must hold no ``copy``, ``slice`` or ``transpose``
@@ -559,6 +561,8 @@ def _kernels_child(rehearse: bool) -> None:
     (``interpret=False``; the interpreter under ``--rehearse``), against
     ``jax.numpy`` references at the tolerances of tests/test_int8_matmul.py
     and tests/test_flash_attention.py (its bf16 case)."""
+    import functools
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -574,7 +578,8 @@ def _kernels_child(rehearse: bool) -> None:
     interpret = rehearse
     rng = np.random.default_rng(SEED)
     mm = ([(8, 128, 256)] if rehearse else
-          [(8, 768, 2304), (8, 768, 50257), (8, 3072, 768), (128, 768, 3072)])
+          [(8, 768, 2304), (8, 768, 50257), (8, 3072, 768), (128, 768, 3072),
+           (16, 1280, 3840), (16, 5120, 1280)])
     for m, k, n in mm:
         x = jnp.asarray(rng.standard_normal((m, k)) * 0.5, jnp.bfloat16)
         w_q, scale = quantize_per_channel(
@@ -587,6 +592,15 @@ def _kernels_child(rehearse: bool) -> None:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want), rtol=2e-2, atol=2e-2)
         print(f"int8_matmul [{m},{k}]x[{k},{n}] matches its reference")
+    # GPT-2 large's decode step at 16 slots: qkv, out, fc1, fc2, and the
+    # head, whose logits are float32.
+    for row in time_int8_matmul(
+            functools.partial(int8_matmul, interpret=interpret),
+            [(8, 128, 256, None)] if rehearse else
+            [(16, 1280, 3840, None), (16, 1280, 1280, None),
+             (16, 1280, 5120, None), (16, 5120, 1280, None),
+             (16, 1280, 50257, jnp.float32)], not rehearse):
+        print("int8_matmul " + json.dumps(row))
 
     fa = ([(1, 256, 256, 2, 64, False), (1, 128, 128, 2, 64, True)]
           if rehearse else
@@ -705,6 +719,67 @@ def span_fills(slots: int, total: int, lead: int) -> dict:
             "full": [at(6, total)] * slots}
 
 
+def _device_us(run, name: str) -> float:
+    """Device microseconds of one call of the kernel ``name`` inside
+    ``run()``, a program that chains ``_TIMED_CALLS`` of them: the mean of
+    the profiler's ``XLA Ops`` events of that name."""
+    import tempfile
+
+    import jax
+
+    from pytorch_zappa_serverless_tpu.utils.xplane import op_time_breakdown
+
+    with tempfile.TemporaryDirectory(dir=OUT) as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        run().block_until_ready()
+        jax.profiler.stop_trace()
+        compute, counts, _, _ = op_time_breakdown(trace_dir)
+    assert counts[name] == _TIMED_CALLS, (counts[name], dict(counts))
+    return round(compute[name] / counts[name] / 1e3, 2)
+
+
+def time_int8_matmul(matmul, shapes, on_device: bool):
+    """Device microseconds of one ``int8_matmul`` call, the kernel alone:
+    one row a shape ``(M, K, N, out_dtype)`` (K in whole lane tiles), with
+    the blocks and grid steps its plan gives, beside the least the chip
+    could take over the weight's bytes as stored (819 GB/s).  ``matmul(x,
+    w_q, scale, out_dtype=)`` is the kernel under the clock; the weight is
+    stored as the int8 lane stores a head (``pad_weights``).  Off the device
+    the rows carry no time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.ops.int8_matmul import (
+        pad_weights, plan_summary)
+
+    rng = np.random.default_rng(SEED)
+    rows = []
+    for m, k, n, out_dtype in shapes:
+        w_q, scale = pad_weights(
+            rng.integers(-127, 128, (k, n), dtype=np.int8),
+            rng.random(n, np.float32) * 1e-3 + 1e-4)
+        row = {"shape": [m, k, n], **plan_summary(m, *w_q.shape),
+               "floor_us": round(w_q.size / 819e9 * 1e6, 2)}
+        x = jnp.asarray(rng.standard_normal((m, k)) * 0.5, jnp.bfloat16)
+        w_q, scale = jnp.asarray(w_q), jnp.asarray(scale)
+
+        @jax.jit
+        def chain(x, w_q, scale):
+            for _ in range(_TIMED_CALLS):  # each call waits for the last
+                y = matmul(x, w_q, scale, out_dtype=out_dtype)
+                x = x + (y[:, :1] * 1e-6).astype(x.dtype)
+            return x
+
+        chain(x, w_q, scale).block_until_ready()
+        if on_device:
+            row["us_a_call"] = _device_us(
+                lambda: chain(x, w_q, scale), "int8_matmul")
+            row["gb_per_s"] = round(w_q.size / row["us_a_call"] / 1e3, 1)
+        rows.append(row)
+    return rows
+
+
 def time_decode_attention(attend, slots: int, total: int, d: int,
                           on_device: bool, blocks=(None,), fills=None):
     """Device microseconds of one ``decode_attention`` call, the kernel
@@ -717,15 +792,12 @@ def time_decode_attention(attend, slots: int, total: int, d: int,
     it once a step.  ``fills`` (:func:`span_fills`) times spans with a start,
     which ``attend`` then takes as ``first``.  Off the device the rows carry
     no time."""
-    import tempfile
-
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from pytorch_zappa_serverless_tpu.ops.decode_attention import (
         pick_block_t, work_list)
-    from pytorch_zappa_serverless_tpu.utils.xplane import op_time_breakdown
 
     layers = 2
     rng = np.random.default_rng(SEED)
@@ -758,15 +830,8 @@ def time_decode_attention(attend, slots: int, total: int, d: int,
             wpos = jnp.asarray([w for _, w in span], jnp.int32)
             chain(q, ck, cv, wpos, first).block_until_ready()
             if on_device:
-                with tempfile.TemporaryDirectory(dir=OUT) as trace_dir:
-                    jax.profiler.start_trace(trace_dir)
-                    chain(q, ck, cv, wpos, first).block_until_ready()
-                    jax.profiler.stop_trace()
-                    compute, counts, _, _ = op_time_breakdown(trace_dir)
-                calls = counts["decode_attention"]
-                assert calls == _TIMED_CALLS, (calls, dict(counts))
-                row["us_a_layer"] = round(
-                    compute["decode_attention"] / calls / 1e3, 2)
+                row["us_a_layer"] = _device_us(
+                    lambda: chain(q, ck, cv, wpos, first), "decode_attention")
             rows.append(row)
     return rows
 

@@ -24,7 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.logging import get_logger, log_event
 from .decoder import Family, make_servable
+
+log = get_logger("models.gpt2")
 
 
 @dataclass(frozen=True)
@@ -234,8 +237,8 @@ def make_gpt2_servable(name: str, cfg_model):
         rejects int8/auto + mesh), so the Megatron per-head TP layout
         question never arises for the fused node.
         """
-        from ..ops.int8_matmul import (pad_weights, quantize_per_channel,
-                                       quantize_tree)
+        from ..ops.int8_matmul import (DECODE_ROWS, pad_weights, plan_summary,
+                                       quantize_per_channel, quantize_tree)
         from .vision_common import cast_params_at_rest
 
         for i in range(cfg.layers):
@@ -253,6 +256,15 @@ def make_gpt2_servable(name: str, cfg_model):
         lm_q, lm_scale = quantize_per_channel(
             np.asarray(tree["wte"]).T.copy(), axis=0)
         tree["lm_q"], tree["lm_scale"] = pad_weights(lm_q, lm_scale)
+
+        # How a decode step's rows walk each matrix of a step: one plan for
+        # any number of rows up to ``decode_rows``.
+        log_event(log, "int8 lane built", model=name, decode_rows=DECODE_ROWS,
+                  decode_plan={
+                      "head": plan_summary(DECODE_ROWS, *tree["lm_q"].shape),
+                      **{n: plan_summary(DECODE_ROWS, *p["kernel_q"].shape)
+                         for n, p in tree["layer0"].items()
+                         if "kernel_q" in p}})
         return cast_params_at_rest(tree, jnp.bfloat16)
 
     if (int(getattr(cfg_model, "adapter_slots", 0)) > 0

@@ -31,7 +31,8 @@ from pytorch_zappa_serverless_tpu.ops import (
 from pytorch_zappa_serverless_tpu.ops.decode_attention import (
     decode_attention, pick_block_t, work_list)
 from pytorch_zappa_serverless_tpu.ops.flash_attention import flash_attention
-from pytorch_zappa_serverless_tpu.ops.int8_matmul import int8_matmul
+from pytorch_zappa_serverless_tpu.ops.int8_matmul import (
+    int8_matmul, padded_columns)
 
 
 @pytest.fixture(scope="module")
@@ -61,15 +62,24 @@ def _compile(fn, one_chip, *shapes):
 
 
 # GPT-2 small: decode qkv, the 50257-vocab lm head, fc2 (K=3072), and a
-# prefill-sized M on fc1.
-@pytest.mark.parametrize("m,k,n", [
-    (8, 768, 2304), (8, 768, 50257), (8, 3072, 768), (128, 768, 3072)],
-    ids=lambda v: str(v))
-def test_int8_matmul_compiles_for_v5e(one_chip, m, k, n):
+# prefill-sized M on fc1.  GPT-2 large at 16 slots: the decode step's five
+# shapes (the head as ``pad_weights`` stores it, its logits float32), whose
+# blocks hold all of K, and a prefill's rows on qkv, which walk it.
+@pytest.mark.parametrize("m,k,n,out_dtype", [
+    (8, 768, 2304, None), (8, 768, 50257, None), (8, 3072, 768, None),
+    (128, 768, 3072, None),
+    (16, 1280, 3840, None), (16, 1280, 1280, None), (16, 1280, 5120, None),
+    (16, 5120, 1280, None),
+    (16, 1280, padded_columns(1280, 50257), jnp.float32),
+    (8, 768, padded_columns(768, 50257), jnp.float32),
+    (2048, 1280, 3840, None)],
+    ids=lambda v: getattr(v, "__name__", str(v)))
+def test_int8_matmul_compiles_for_v5e(one_chip, m, k, n, out_dtype):
     text = _compile(
-        lambda x, w, s: int8_matmul(x, w, s, interpret=False), one_chip,
+        lambda x, w, s: int8_matmul(x, w, s, out_dtype=out_dtype,
+                                    interpret=False), one_chip,
         ((m, k), jnp.bfloat16), ((k, n), jnp.int8), ((n,), jnp.float32))
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" in text and "int8_matmul" in text
 
 
 # SD-1.5 UNet self-attention at 512x512 (4096 tokens, CFG batch 2 and the b4
