@@ -26,15 +26,24 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
              weights; ``:predict`` / ``:generate`` requests, ``/healthz``,
              ``/metrics``, SIGINT.
 - *restart*  the same config again: the boot must hit the compile cache.
-- *kernels*  ``int8_matmul``, ``flash_attention`` and ``decode_attention``
-             with ``interpret=False`` at real shapes against ``jax.numpy``
-             references, on the chip; then ``int8_matmul`` alone by the
-             profiler's clock, microseconds a call at the five shapes of
+- *kernels*  ``int8_matmul``, ``flash_attention``, ``prompt_attention`` and
+             ``decode_attention`` with ``interpret=False`` at real shapes
+             against ``jax.numpy`` references, on the chip; then
+             ``int8_matmul`` alone by the profiler's clock,
+             microseconds a call at the five shapes of
              GPT-2 large's decode step beside the least their bytes allow,
              and ``decode_attention`` alone, microseconds a layer at three
              fills of the serving pools beside the least their live bytes
              allow (EvaByte's pool, 32 heads of 128 at ``D`` 4096, in spans
-             with a start).
+             with a start), and a prefill's prompt attention in both its
+             forms, microseconds a layer at the benchmark's prefill shapes.
+- *burst*    the servable's own prefill at ``gpt2-large-int8``'s and
+             ``gpt2-xl``'s published widths and all their layers, at the
+             admission batches a burst forms (8 and 16 prompts of 512 and
+             768), then ``insert`` and two segments: as built (the prompt
+             attention's kernel) against the same programs with the picker
+             held to the ``jax.numpy`` form, and a float32 attention as the
+             arbiter.
 - *segment*  the decode segment program at GPT-2 XL's serving shape (8 slots
              of 960 positions, 48 layers, compiled from shapes alone): its
              optimised HLO must hold no ``copy``, ``slice`` or ``transpose``
@@ -571,7 +580,8 @@ def _kernels_child(rehearse: bool) -> None:
     from pytorch_zappa_serverless_tpu.ops.decode_attention import (
         decode_attention)
     from pytorch_zappa_serverless_tpu.ops.flash_attention import (
-        flash_attention)
+        flash_attention, masked_attention, prompt_attention, prompt_block,
+        prompt_mask)
     from pytorch_zappa_serverless_tpu.ops.int8_matmul import (
         int8_matmul, quantize_per_channel)
 
@@ -627,6 +637,43 @@ def _kernels_child(rehearse: bool) -> None:
                                    np.asarray(want), rtol=3e-2, atol=3e-2)
         print(f"flash_attention q[{b},{tq},{h},{d}] kv {tk} causal={causal} "
               "matches its reference")
+    # A prefill's prompt attention, causal and ragged, rows with the heads
+    # side by side: GPT-2 XL's and large's admission batches, and the
+    # batches a burst admits on the int8 lane (20 heads, 8 and 16 prompts of
+    # 512 and 768).  Real rows against the other form's, every row finite,
+    # the rows of a block of queries wholly past a length zeros.
+    pa = ([(3, 288, 5)] if rehearse else
+          [(8, 512, 20), (16, 512, 20), (4, 768, 20), (8, 768, 20),
+           (16, 768, 20), (4, 768, 25), (8, 512, 25), (1, 512, 25),
+           (16, 256, 20)])
+    for b, p, h in pa:
+        q, k, v = (jnp.asarray(rng.standard_normal((b, p, h * 64)),
+                               jnp.bfloat16) for _ in range(3))
+        lengths = edge_lengths(b, p)
+        n = jnp.asarray(lengths, jnp.int32)
+        got = np.asarray(prompt_attention(q, k, v, n, heads=h,
+                                          interpret=interpret), np.float32)
+        want = np.asarray(masked_attention(q, k, v, prompt_mask(n, p), h),
+                          np.float32)
+        block = prompt_block(p)
+        for row, length in enumerate(lengths):
+            np.testing.assert_allclose(got[row, :length], want[row, :length],
+                                       rtol=3e-2, atol=3e-2)
+            dead = -(-length // block) * block
+            assert not got[row, dead:].any(), (row, length, "not zeros")
+        assert np.isfinite(got).all(), "a padded row is not finite"
+        print(f"prompt_attention [{b},{p},{h},64] lengths "
+              f"{sorted(lengths)} matches the jax.numpy form; rows of "
+              "skipped blocks are zeros")
+    # Both forms alone at the benchmark's prefill shapes: the table the
+    # picker's rule (ops/flash_attention.prompt_form) is read from.
+    for row in time_prompt_attention(
+            {"kernel": lambda q, k, v, n, heads: prompt_attention(
+                q, k, v, n, heads=heads, interpret=interpret),
+             "einsum": lambda q, k, v, n, heads: masked_attention(
+                 q, k, v, prompt_mask(n, q.shape[1]), heads)},
+            [(3, 96, 5)] if rehearse else PROMPT_SHAPES, not rehearse):
+        print("prompt_attention " + json.dumps(row))
     # Decode attention over a slot pool [L, S, T, D]: the benchmark's two
     # serving shapes, slots at 0, mid-block, a block edge and the last row,
     # and a dead one, whose row of the pool holds NaN and is read nowhere.
@@ -683,6 +730,7 @@ def _kernels_child(rehearse: bool) -> None:
           + ("native (hostops.cpp built with g++)" if hostops.native_available()
              else "PIL (no native library: no compiler here)"))
     print(json.dumps({"int8_matmul": len(mm), "flash_attention": len(fa),
+                      "prompt_attention": len(pa),
                       "decode_attention": len(da)}))
 
 
@@ -719,21 +767,29 @@ def span_fills(slots: int, total: int, lead: int) -> dict:
             "full": [at(6, total)] * slots}
 
 
-def _device_us(run, name: str) -> float:
-    """Device microseconds of one call of the kernel ``name`` inside
-    ``run()``, a program that chains ``_TIMED_CALLS`` of them: the mean of
-    the profiler's ``XLA Ops`` events of that name."""
+def _device_ns(run):
+    """``(compute, counts)`` of one profiled ``run()``: device nanoseconds
+    and events of the profiler's ``XLA Ops`` by operation name."""
     import tempfile
 
     import jax
 
     from pytorch_zappa_serverless_tpu.utils.xplane import op_time_breakdown
 
+    OUT.mkdir(exist_ok=True)  # the phase may run alone, without main()
     with tempfile.TemporaryDirectory(dir=OUT) as trace_dir:
         jax.profiler.start_trace(trace_dir)
         run().block_until_ready()
         jax.profiler.stop_trace()
         compute, counts, _, _ = op_time_breakdown(trace_dir)
+    return compute, counts
+
+
+def _device_us(run, name: str) -> float:
+    """Device microseconds of one call of the kernel ``name`` inside
+    ``run()``, a program that chains ``_TIMED_CALLS`` of them: the mean of
+    the profiler's ``XLA Ops`` events of that name."""
+    compute, counts = _device_ns(run)
     assert counts[name] == _TIMED_CALLS, (counts[name], dict(counts))
     return round(compute[name] / counts[name] / 1e3, 2)
 
@@ -836,6 +892,73 @@ def time_decode_attention(attend, slots: int, total: int, d: int,
     return rows
 
 
+# The benchmark's prefill shapes ``(batch, bucket, heads)`` at heads of 64:
+# GPT-2 XL's and GPT-2 large's, admission batches by bucket.
+PROMPT_SHAPES = (
+    [(b, p, 25) for p in (512, 768) for b in (1, 2, 4, 8)]
+    + [(b, p, 20) for p in (256, 512, 768) for b in (1, 8, 16)])
+
+
+def prompt_lengths(batch: int, bucket: int) -> list[int]:
+    """Ragged lengths of a timed or checked prefill batch: a bucket 70-100%
+    full, as the benchmark's buckets are, shortest first."""
+    return [bucket * (70 + 30 * (j + 1) // batch) // 100 for j in range(batch)]
+
+
+def edge_lengths(batch: int, bucket: int) -> list[int]:
+    """Ragged lengths of a checked prefill batch, from 1 to ``bucket``: the
+    edges of the kernel's blocks of 256 queries first (one short of, at and
+    one past them), then an even spread."""
+    edges = [n for n in (257, 1, bucket, 256, 513, 255, 512) if n <= bucket]
+    spread = [max(1, bucket * (j + 1) // (batch + 1)) for j in range(batch)]
+    return list(dict.fromkeys(edges + spread))[:batch]
+
+
+def time_prompt_attention(forms: dict, shapes, on_device: bool):
+    """Device microseconds a layer of a prefill's prompt attention in each
+    of ``forms`` (``{name: attend(q, k, v, lengths, heads)}``: the kernel
+    and the ``jax.numpy`` form), alone: one row a shape ``(batch, bucket,
+    heads)`` (heads of 64, bfloat16, :func:`prompt_lengths`) and a form.
+    ``us_a_layer`` is everything the chained program runs over
+    its calls (the kernel, or the fusions that write and read the scores),
+    ``kernel_us`` the profiler's events named ``prompt_attention`` alone;
+    beside them the float32 scores' bytes, which the picker's rule reads.
+    Off the device the rows carry no time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.default_rng(SEED)
+    rows = []
+    for batch, bucket, heads in shapes:
+        d = heads * 64
+        q, k, v = (jnp.asarray(rng.standard_normal((batch, bucket, d)) * 0.5,
+                               jnp.bfloat16) for _ in range(3))
+        lengths = jnp.asarray(prompt_lengths(batch, bucket), jnp.int32)
+        for form, attend in forms.items():
+            @jax.jit
+            def chain(q, k, v, lengths):
+                for _ in range(_TIMED_CALLS):  # each waits for the last
+                    q = q + attend(q, k, v, lengths, heads) * 0.01
+                return q
+
+            row = {"shape": [batch, bucket, d], "form": form,
+                   "score_mb": round(batch * heads * bucket ** 2 * 4
+                                     / 2 ** 20, 1)}
+            chain(q, k, v, lengths).block_until_ready()
+            if on_device:
+                compute, counts = _device_ns(lambda: chain(q, k, v, lengths))
+                row["us_a_layer"] = round(
+                    sum(compute.values()) / _TIMED_CALLS / 1e3, 2)
+                if form == "kernel":
+                    name = "prompt_attention"
+                    assert counts[name] == _TIMED_CALLS, dict(counts)
+                    row["kernel_us"] = round(
+                        compute[name] / _TIMED_CALLS / 1e3, 2)
+            rows.append(row)
+    return rows
+
+
 _MOVES = ("copy", "slice", "dynamic-slice", "transpose")
 
 
@@ -881,18 +1004,15 @@ def pool_sized_moves(hlo_text: str, elements: int) -> list[tuple[int, str]]:
     return sorted(found, reverse=True)
 
 
-def segment_program(cfg, slots: int, total: int, sharding=None):
-    """The decode segment (8 tokens) over a bfloat16 pool of ``slots`` x
-    ``total`` as a jitted function, and its arguments as shapes alone
-    (nothing is allocated), placed by ``sharding`` where one is given."""
+def _gpt2_shapes(cfg, sharding):
+    """``(sd, params)``: a bfloat16 shape maker placed by ``sharding`` where
+    one is given, and GPT-2's parameter tree as shapes alone."""
     import jax
     import jax.numpy as jnp
 
-    from pytorch_zappa_serverless_tpu.models import decoder, gpt2
+    D, F = cfg.d_model, cfg.ffn_dim
 
-    D, F, bf = cfg.d_model, cfg.ffn_dim, jnp.bfloat16
-
-    def sd(*shape, dtype=bf):
+    def sd(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     def dense(i, o):
@@ -908,16 +1028,48 @@ def segment_program(cfg, slots: int, total: int, sharding=None):
             "ln1": ln(), "ln2": ln(), "q": dense(D, D), "k": dense(D, D),
             "v": dense(D, D), "out": dense(D, D), "fc1": dense(D, F),
             "fc2": dense(F, D)}
-    pool = sd(cfg.layers, slots, total, D)
+    return sd, params
+
+
+def segment_program(cfg, slots: int, total: int, sharding=None):
+    """The decode segment (8 tokens) over a bfloat16 pool of ``slots`` x
+    ``total`` as a jitted function, and its arguments as shapes alone
+    (nothing is allocated), placed by ``sharding`` where one is given."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_zappa_serverless_tpu.models import decoder, gpt2
+
+    sd, params = _gpt2_shapes(cfg, sharding)
+    pool = sd(cfg.layers, slots, total, cfg.d_model)
     i32, f32 = sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.float32)
     segment = jax.jit(
         lambda p, ck, cv, tok, pos, st, fin, temp, seeds, topk, topp:
         decoder.decode_segment(gpt2.family(cfg), p,
                                decoder.slot_pool(ck, cv), tok, pos, st, fin,
-                               temp, seeds, 8, bf, top_k=topk, top_p=topp),
+                               temp, seeds, 8, jnp.bfloat16, top_k=topk,
+                               top_p=topp),
         donate_argnums=(1, 2))
     return segment, (params, pool, pool, i32, i32, i32,
                      sd(slots, dtype=jnp.bool_), f32, i32, i32, f32)
+
+
+def prefill_program(cfg, batch: int, bucket: int, total: int, sharding=None):
+    """The admission prefill of ``batch`` prompts of ``bucket`` positions
+    into rows of ``total`` (bfloat16) as a jitted function, and its
+    arguments as shapes alone, as :func:`segment_program`."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_zappa_serverless_tpu.models import decoder, gpt2
+
+    sd, params = _gpt2_shapes(cfg, sharding)
+    prefill = jax.jit(
+        lambda p, tokens, lengths:
+        decoder.prefill(gpt2.family(cfg), p, tokens, lengths, total,
+                        jnp.bfloat16))
+    return prefill, (params, sd(batch, bucket, dtype=jnp.int32),
+                     sd(batch, dtype=jnp.int32))
 
 
 def _segment_child(rehearse: bool) -> None:
@@ -1069,6 +1221,267 @@ def _evabyte_child(rehearse: bool) -> None:
     print(json.dumps(report))
 
 
+# What a burst admits on the int8 lane (16 slots, buckets 512 and 768), and
+# two of GPT-2 XL's admission batches that take the kernel: (batch, bucket).
+BURST_INT8 = [(8, 512), (16, 512), (4, 768), (8, 768), (16, 768)]
+BURST_XL = [(4, 768), (8, 512)]
+BURST_LOGIT_TOL = 0.05   # the benchmark's reference tolerance, in logits
+# K and V rows of the two forms, over the root mean square of the rows:
+# their mean and their largest difference.
+BURST_ROWS_MEAN_TOL, BURST_ROWS_MAX_TOL = 0.02, 0.25
+
+
+def _burst_child(rehearse: bool) -> None:
+    """A burst's prefills through the servable's own programs, in both forms
+    of the prompt attention, on one device.
+
+    ``gpt2-large-int8`` and ``gpt2-xl`` at their published widths and all
+    their layers, built by the model's own builder as the benchmark's
+    configurations build them; the jitted ``insert_from`` and ``segment``
+    are ``build_gen_kernels``'s, the prefill is the servable's own.  Each
+    (batch, bucket) is prefilled, spliced into the slot pool and decoded
+    for two segments, once with the picker held to the ``jax.numpy`` form
+    (a patch here, in this process: the product has no switch) and once as
+    built; a third prefill, the ``jax.numpy`` form with the attention alone
+    in float32, is the arbiter between them.  The two sides must agree:
+    the K and V rows up to each length within bfloat16 rounding through
+    the layers, the kernel's no farther from the arbiter's than the other
+    form's are (nor its first logits), first tokens and the 16 decoded
+    tokens a slot equal up to a near-tie, and nothing non-finite in the
+    rows or the pool.  ``choose`` is watched, not replaced."""
+    import functools
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.config import ModelConfig
+    from pytorch_zappa_serverless_tpu.models import decoder, gpt2
+    from pytorch_zappa_serverless_tpu.ops import flash_attention as fa
+    from pytorch_zappa_serverless_tpu.serving.generation import (
+        build_gen_kernels)
+
+    if rehearse:
+        # The CPU picks the jax.numpy form: steer "as built" to the kernel,
+        # under the interpreter, so that the phase's control flow runs.
+        arch = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 256,
+                "vocab_size": 300, "max_positions": 64, "eos_id": 300}
+        lanes = [("gpt2-int8", arch, "int8", 4, [(4, 32)], (32,), 16)]
+        def kernel_form(*shape):
+            return "kernel"
+        fa.prompt_attention = functools.partial(fa.prompt_attention,
+                                                interpret=True)
+    else:
+        large = {"d_model": 1280, "layers": 36, "heads": 20, "ffn_dim": 5120,
+                 "vocab_size": 50257, "max_positions": 1024, "eos_id": 50257}
+        xl = {**large, "d_model": 1600, "layers": 48, "heads": 25,
+              "ffn_dim": 6400}
+        lanes = [("gpt2-large-int8", large, "int8", 16, BURST_INT8,
+                  (256, 512, 768), 192),
+                 ("gpt2-xl", xl, "bfloat16", 8, BURST_XL, (512, 768), 192)]
+        kernel_form = fa.prompt_form
+
+    seen = {}
+    choose = decoder.choose
+
+    def watched(logits, temperature, seeds, t, top_k=None, top_p=None):
+        jax.debug.callback(
+            lambda lg, tt: seen.update({int(tt[0]): np.asarray(lg)}),
+            logits, t)
+        return choose(logits, temperature, seeds, t, top_k, top_p)
+
+    decoder.choose = watched
+    masked = fa.masked_attention
+
+    def in_float32(q, k, v, mask_bias, heads):
+        with jax.default_matmul_precision("highest"):  # these einsums alone
+            return masked(*(a.astype(jnp.float32) for a in (q, k, v)),
+                          mask_bias, heads).astype(q.dtype)
+
+    def einsum_form(*shape):
+        return "einsum"
+
+    rng = np.random.default_rng(SEED)
+    report, failed = {}, []
+    for name, arch, params_dtype, slots, shapes, buckets, max_new in lanes:
+        t0 = time.monotonic()
+        servable = gpt2.make_gpt2_servable("gpt2", ModelConfig(
+            name="gpt2", seq_buckets=buckets, dtype="bfloat16", extra={
+                "params_dtype": params_dtype, "max_new_tokens": max_new,
+                "gen_slots": slots, "segment_tokens": 8, "arch": arch}))
+        if params_dtype == "bfloat16":  # at rest as the engine keeps them
+            from pytorch_zappa_serverless_tpu.models.vision_common import (
+                cast_params_at_rest)
+            servable.params = cast_params_at_rest(servable.params,
+                                                  jnp.bfloat16)
+        params = jax.device_put(servable.params)
+        kernels = build_gen_kernels(types.SimpleNamespace(servable=servable),
+                                    None)
+        meta = kernels["meta"]
+        print(f"burst: {name} built in {time.monotonic() - t0:.0f} s, "
+              f"{slots} slots of {meta['total']} positions")
+
+        @jax.jit
+        def apart(xs, ys, lengths):
+            """(largest, mean) difference of two sides' K and V rows up to
+            each length, and the root mean square of the first side's."""
+            real = (jnp.arange(xs[0].shape[2])[None, :]
+                    < lengths[:, None])[None, :, :, None]
+            count = lengths.sum() * xs[0].shape[0] * xs[0].shape[3] * 2.0
+            gaps = [jnp.where(real, jnp.abs(x.astype(jnp.float32)
+                                            - y.astype(jnp.float32)), 0.0)
+                    for x, y in zip(xs, ys)]
+            squares = sum(jnp.where(real, x.astype(jnp.float32) ** 2,
+                                    0.0).sum() for x in xs)
+            return (jnp.maximum(gaps[0].max(), gaps[1].max()),
+                    (gaps[0].sum() + gaps[1].sum()) / count,
+                    jnp.sqrt(squares / count))
+
+        def prefilled(rule, payload):
+            """(first [B], first logits [B, V], (K rows, V rows)) of one
+            prefill traced under ``rule``, every row finite."""
+            fa.prompt_form = rule
+            # A function of its own a side: jit keeps a function's trace,
+            # and this one has to be made under the rule.
+            lowered = jax.jit(
+                lambda p, payload: meta["prefill"](p, payload)).lower(
+                    params, payload)
+            batch, bucket = payload["input_ids"].shape
+            scores = (f"{batch}x{arch['heads']}x{bucket}x{bucket}xf32"
+                      in lowered.as_text())
+            form = rule(batch, arch["heads"], bucket, 64)
+            assert scores == (form != "kernel"), (name, form, scores)
+            seen.clear()
+            first, k_rows, v_rows = lowered.compile()(params, payload)
+            jax.effects_barrier()
+            assert bool(jnp.isfinite(k_rows).all()
+                        & jnp.isfinite(v_rows).all()), \
+                "a prefill's rows hold a value that is not finite"
+            return np.asarray(first), seen[0], (k_rows, v_rows)
+
+        def decoded(first, rows, lengths):
+            """(tokens [B, 17], logits by step) of two segments over the
+            slot pool after the prefill's rows were spliced into it: token
+            ``t`` of a slot is the choice from logits ``t``, the prefill's
+            being 0."""
+            B = len(lengths)
+            ck, cv = kernels["alloc_cache"]()
+            for j in range(B):
+                ck, cv = kernels["insert_from"](ck, cv, *rows, np.int32(j),
+                                                np.int32(j))
+            S = meta["slots"]
+            tok = np.zeros((S,), np.int32)
+            tok[:B] = first
+            pos = np.zeros((S,), np.int32)
+            pos[:B] = lengths
+            st = np.zeros((S,), np.int32)
+            fin = np.ones((S,), bool)
+            fin[:B] = False
+            zf, zi = np.zeros((S,), np.float32), np.zeros((S,), np.int32)
+            emits = []
+            seen.clear()
+            for _ in range(2):
+                packed, ck, cv = kernels["segment"](
+                    params, ck, cv, tok, pos, st, fin, zf, zi, zi, zf + 1)
+                packed = np.asarray(packed)
+                emits.append(packed[:B, :8])
+                tok, pos, st = (packed[:, 8 + i].copy() for i in range(3))
+                fin = packed[:, 11].astype(bool)
+            jax.effects_barrier()
+            assert bool(jnp.isfinite(ck).all() & jnp.isfinite(cv).all()), \
+                "the pool holds a value that is not finite"
+            # A step emits the token decided before it: the 16 emitted are
+            # the prefill's and 15 steps', and the 16th step's is the carry.
+            seq = np.concatenate(emits + [tok[:B, None]], axis=1)
+            assert (seq[:, 0] == first).all()
+            return seq, dict(seen)
+
+        for batch, bucket in shapes:
+            t0 = time.monotonic()
+            lengths = np.asarray(edge_lengths(batch, bucket), np.int32)
+            tokens = rng.integers(0, arch["vocab_size"],
+                                  (batch, bucket)).astype(np.int32)
+            payload = {"input_ids": tokens, "length": lengths,
+                       **{k: np.full((batch,), off, dt)
+                          for k, dt, off in decoder.KNOBS}}
+            assert kernel_form(batch, arch["heads"], bucket, 64) == "kernel"
+            # The arbiter: the same program with the attention alone in
+            # float32 (its inputs are the bfloat16 q, k and v of the other
+            # two), prefill only.  Then the jax.numpy form, whose
+            # temporaries are the large ones, then the program as built.
+            fa.masked_attention = in_float32
+            _, lg_i, rows_i = prefilled(einsum_form, payload)
+            fa.masked_attention = masked
+            f_e, lg_e0, rows_e = prefilled(einsum_form, payload)
+            e_i = [float(x) for x in apart(rows_e, rows_i, lengths)]
+            seq_e, lg_e = decoded(f_e, rows_e, lengths)
+            f_k, lg_k0, rows_k = prefilled(kernel_form, payload)
+            k_i = [float(x) for x in apart(rows_k, rows_i, lengths)]
+            k_e = [float(x) for x in apart(rows_k, rows_e, lengths)]
+            del rows_i, rows_e
+            seq_k, lg_k = decoded(f_k, rows_k, lengths)
+            del rows_k
+            lg_e[0], lg_k[0] = lg_e0, lg_k0
+            for seq, lg in ((seq_e, lg_e), (seq_k, lg_k)):  # greedy, aligned
+                assert all((seq[:, t] == lg[t][:batch].argmax(-1)).all()
+                           for t in range(seq.shape[1]))
+            # Served tokens: equal, or parted at a near-tie.
+            partings = []
+            for row in range(batch):
+                for t in range(seq_e.shape[1]):
+                    e, k = int(seq_e[row, t]), int(seq_k[row, t])
+                    if e != k:  # what follows has another prompt
+                        partings.append({
+                            "length": int(lengths[row]), "step": t,
+                            "einsum_gap": round(float(
+                                lg_e[t][row, e] - lg_e[t][row, k]), 5),
+                            "kernel_gap": round(float(
+                                lg_k[t][row, k] - lg_k[t][row, e]), 5),
+                            "logits_apart": round(float(np.max(np.abs(
+                                lg_e[t][row] - lg_k[t][row]))), 5)})
+                        break
+            first_apart = {
+                pair: round(float(np.max(np.abs(x[:batch] - y[:batch]))), 5)
+                for pair, x, y in (("kernel_ideal", lg_k0, lg_i),
+                                   ("einsum_ideal", lg_e0, lg_i),
+                                   ("kernel_einsum", lg_k0, lg_e0))}
+            rms = e_i[2]
+            line = {"rows_rms": round(rms, 4),
+                    "rows_apart_max_mean": {
+                        "kernel_ideal": [round(k_i[0], 5), round(k_i[1], 6)],
+                        "einsum_ideal": [round(e_i[0], 5), round(e_i[1], 6)],
+                        "kernel_einsum": [round(k_e[0], 5), round(k_e[1], 6)]},
+                    "first_logits_apart": first_apart,
+                    "logit_std": round(float(np.std(lg_k0)), 4),
+                    "slots_parted": len(partings), "of": batch,
+                    "partings": partings,
+                    "seconds": round(time.monotonic() - t0, 1)}
+            report[f"{name} [{batch}, {bucket}]"] = line
+            print(f"burst {name} [{batch}, {bucket}] lengths "
+                  f"{sorted(int(n) for n in lengths)} " + json.dumps(line),
+                  flush=True)
+            # bfloat16 keeps 8 bits: a rounding is 0.4% of a value, and a
+            # row of the last layer has two a layer behind it; the largest
+            # of 10^8 to 10^9 differences lies six deviations out.  The
+            # kernel keeps float32 scores where the other form rounds them,
+            # so it may not lie farther from the float32 attention than
+            # that form does.  Two sets of logits ``x`` apart can order a
+            # pair of tokens up to ``2 x`` apart differently.
+            if (k_e[1] > BURST_ROWS_MEAN_TOL * rms
+                    or k_e[0] > BURST_ROWS_MAX_TOL * rms
+                    or k_i[1] > 1.25 * e_i[1]
+                    or first_apart["kernel_ideal"] > max(
+                        BURST_LOGIT_TOL, 1.25 * first_apart["einsum_ideal"])
+                    or any(max(p["einsum_gap"], p["kernel_gap"])
+                           > 2 * BURST_LOGIT_TOL for p in partings)):
+                failed.append((name, batch, bucket, line))
+        del params, kernels, servable
+    fa.prompt_form = kernel_form
+    assert not failed, failed
+    print(json.dumps({"burst": report}))
+
+
 def _multichip_child(rehearse: bool) -> None:
     """``mesh: {data: 2, model: 2}`` through ``build_engine`` against the
     same models on one device: where the shards sit, that the step holds a
@@ -1215,9 +1628,15 @@ def main(argv=None) -> int:
                           args.rehearse)
             run_child(f"import chip_smoke; "
                       f"chip_smoke._kernels_child({args.rehearse})",
-                      args.rehearse, "kernels.log", timeout=600.0)
-            say("kernels: int8_matmul, flash_attention and decode_attention "
-                "match their references on the device")
+                      args.rehearse, "kernels.log", timeout=1500.0)
+            say("kernels: int8_matmul, flash_attention, prompt_attention and "
+                "decode_attention match their references on the device")
+            run_child(f"import chip_smoke; "
+                      f"chip_smoke._burst_child({args.rehearse})",
+                      args.rehearse, "burst.log", timeout=2400.0)
+            say("burst: the int8 lane's and XL's large admission prefills "
+                "agree in both forms of the prompt attention, through "
+                "insert and two segments")
             seg = run_child(f"import chip_smoke; "
                             f"chip_smoke._segment_child({args.rehearse})",
                             args.rehearse, "segment.log", timeout=900.0)

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import decode_attention
+from ..ops.flash_attention import masked_attention as _attn
+from ..ops.flash_attention import prompt_attend, prompt_form
 from ..ops.paged_attention import gather_kv, paged_index
 from ..ops.sampling import (DRAFT_SEED_SALT, apply_repetition_penalty,
                             choose)
@@ -92,19 +94,19 @@ class Rows:
         zeros at first) the rows of layer ``i`` that a decode step will
         read, and returns the attention output (``p``: the layer's
         parameters).  Here causal and ragged in one softmax over ``[P, P]``
-        scores, and the rows are the prompt's own K and V."""
-        pos = jnp.arange(P)
-        # Causal AND ragged: query i attends keys j<=i that are real (j < len).
-        causal = pos[None, :, None] >= pos[None, None, :]          # [1,P,P]
-        real = pos[None, None, :] < lengths[:, None, None]          # [B,1,P]
-        mask_bias = jnp.where(causal & real, 0.0, -1e9).astype(jnp.float32)[:, None]
-
+        scores (ops/flash_attention.prompt_attend keeps them on the chip
+        where it can), and the rows are the prompt's own K and V."""
         def attend(p, cache, i, q, k, v):
             ck = cache[0].at[i, :, :P].set(k)
             return ((ck, cache[1].at[i, :, :P].set(v)),
-                    _attn(q, k, v, mask_bias, heads))
+                    prompt_attend(q, k, v, lengths, heads))
 
         return attend
+
+    def prompt_form(self, batch: int, heads: int, P: int, head_dim: int) -> str:
+        """The form :meth:`prompt`'s attention takes for ``batch`` prompts
+        of ``P`` positions: what the scheduler logs and counts by."""
+        return prompt_form(batch, heads, P, head_dim)
 
 
 ROWS = Rows()  # a row a position
@@ -238,22 +240,6 @@ class PagedPool(NamedTuple):
             q, self._virtual(self.k, layer)[None],
             self._virtual(self.v, layer)[None], 0,
             wpos if wpos.ndim == 2 else wpos[:, None], heads, work)
-
-
-def _attn(q, k, v, mask_bias, heads):
-    """Prefill attention, heads split out: q [B,Tq,D], k/v [B,Tk,D],
-    mask_bias [B,1,Tq,Tk] → [B,Tq,D]."""
-    def split(x):
-        B, T, D = x.shape
-        return x.reshape(B, T, heads, D // heads)
-
-    q, k, v = split(q), split(k), split(v)
-    scale = q.shape[-1] ** -0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k).astype(jnp.float32)
-    probs = jax.nn.softmax(scores + mask_bias, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    B, Tq = out.shape[:2]
-    return out.reshape(B, Tq, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -835,6 +821,9 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         # What the scheduler counts with, in numpy (spans, summaries, the
         # passes of a prompt's attention, prompts a prefill dispatch).
         "rows": fam.rows,
+        # The form the prompt attention of a (batch, bucket) prefill takes.
+        "prompt_form": lambda batch, bucket: fam.rows.prompt_form(
+            batch, fam.heads, bucket, fam.width // fam.heads),
         # Routed lane: admission prefills run on the prefill tree, the
         # slot-pool segment routes on the POOL size (the decode-row count of
         # its program) — consistent with the fixed-batch path at the same

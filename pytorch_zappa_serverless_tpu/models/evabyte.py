@@ -197,6 +197,9 @@ class TwoTier(Rows):
         return (k.at[layer, slots, at].set(kbar.astype(k.dtype)),
                 v.at[layer, slots, at].set(vbar.astype(v.dtype)))
 
+    def prompt_form(self, batch, heads, P, head_dim) -> str:
+        return "windows"
+
     def prompt(self, heads: int, lengths, P: int):
         """Window by window, a block of ``block_q`` queries at a time: the
         exact causal attention inside the block's window joined with the

@@ -31,6 +31,12 @@ Design (standard TPU flash attention, written for this zoo's shapes):
   tested on CPU (tests/test_flash_attention.py) and compiled by Mosaic on
   the chip.
 
+A decoder's prefill has a second kernel, :func:`prompt_attention`: causal,
+ragged, over rows ``[B, P, D]`` with the heads side by side, read where the
+projections left them.  models/decoder.py reaches it through
+:func:`prompt_attend`, which takes it or the ``jax.numpy`` form
+(:func:`masked_attention`) by :func:`prompt_form`'s rule.
+
 Degenerate rows (every key masked) produce a uniform distribution over the
 masked keys rather than NaN — the -1e9 finite mask convention; no zoo model
 issues such rows.
@@ -193,6 +199,217 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
         interpret=interpret,
     )(*operands)
     return jnp.transpose(out[:, :, :Tq, :D], (0, 2, 1, 3))
+
+
+# ---------------------------------------------------------------------------
+# The prompt attention of a decoder's prefill
+# ---------------------------------------------------------------------------
+
+def _prompt_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
+                   sm_scale: float, block: int, head_dim: int, width: int):
+    b, t = pl.program_id(0), pl.program_id(1)
+    length = len_ref[b]
+    P, W = q_ref.shape
+    per = W // head_dim                    # heads side by side in a lane tile
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block, W), 1)
+    # Head h's own lanes of the tile (None: all of them), and as a query
+    # sees them.
+    on_head = [None if per == 1 else lane // head_dim == h for h in range(per)]
+    own = on_head
+    if scratch:
+        # The last tile hangs over the rows' end (``width % W``).  Whatever
+        # lies there must not reach a score (0 x NaN): a query sees its
+        # head's lanes without them, and K is cleaned into the scratch.  In
+        # V it only reaches output lanes that are not written back.  (The
+        # selects run in float32: a mask from int32 iotas has their (8, 128)
+        # tiling, not bfloat16's.)
+        real = t * W + lane < width
+        own = [real if m is None else m & real for m in on_head]
+        k_ref, raw = scratch[0], k_ref
+        k_lane = jax.lax.broadcasted_iota(jnp.int32, (P, W), 1)
+        k_ref[...] = jnp.where(t * W + k_lane < width,
+                               raw[...].astype(jnp.float32),
+                               0.0).astype(k_ref.dtype)
+    below = (jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+             <= jax.lax.broadcasted_iota(jnp.int32, (block, block), 0))
+
+    def scores(qh, rows):
+        return jax.lax.dot_general(qh, k_ref[rows, :], (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def values(p, rows):
+        return jnp.dot(p.astype(v_ref.dtype), v_ref[rows, :],
+                       preferred_element_type=jnp.float32)
+
+    # A block of queries after the other, each over the keys at or below
+    # it: its own block under the diagonal's mask, the blocks before it
+    # whole.  The slices are static, so nothing past the diagonal is read
+    # or computed and no running state is carried.
+    for i in range(P // block):
+        own_rows, before = pl.ds(i * block, block), pl.ds(0, i * block)
+
+        @pl.when(i * block < length)
+        def _live():
+            q = q_ref[own_rows, :].astype(jnp.float32) * sm_scale
+            out = None
+            for h in range(per):
+                # Head h's query on its own lanes and zeros on the others':
+                # the contraction over the whole tile is head h's scores,
+                # and ``p @ v`` carries head h's output on those same lanes.
+                qh = (q if own[h] is None
+                      else jnp.where(own[h], q, 0.0)).astype(k_ref.dtype)
+                s = jnp.where(below, scores(qh, own_rows), _NEG_INF)
+                m = s.max(axis=-1, keepdims=True)
+                if i:
+                    s_before = scores(qh, before)
+                    m = jnp.maximum(m, s_before.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - m)
+                l = p.sum(axis=-1, keepdims=True)
+                o = values(p, own_rows)
+                if i:
+                    p = jnp.exp(s_before - m)
+                    l = l + p.sum(axis=-1, keepdims=True)
+                    o = o + values(p, before)
+                o = o * (1.0 / l)          # l >= 1: the diagonal is kept
+                out = o if h == 0 else jnp.where(on_head[h], o, out)
+            o_ref[own_rows, :] = out.astype(o_ref.dtype)
+
+        # No query of the block is real: nothing reads its rows.
+        @pl.when(i * block >= length)
+        def _dead():
+            o_ref[own_rows, :] = jnp.zeros((block, W), o_ref.dtype)
+
+
+def _tile_width(head_dim: int) -> int | None:
+    """Lanes a block of the prompt kernel spans: whole heads in whole lane
+    tiles, or None where the head size allows neither."""
+    if _LANES % head_dim == 0:
+        return _LANES
+    return head_dim if head_dim % _LANES == 0 else None
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "block", "interpret"))
+def prompt_attention(q, k, v, lengths, *, heads: int,
+                     block: int | None = None, interpret: bool = False):
+    """Causal, ragged self-attention of a prompt, the scores never in HBM.
+
+    q, k, v [B, P, D] with the heads side by side in ``D`` (as a decoder's
+    projections leave them and as its cache keeps them), lengths [B] int32
+    → [B, P, D] in ``q``'s dtype.  Query ``i`` of row ``b`` reads keys
+    ``j <= i``, in one softmax.  That is all a real query (``i <
+    lengths[b]``) may read, so the causal mask is the ragged one on every
+    row that is used; rows ``i >= lengths[b]`` hold finite values that
+    mean nothing (zeros where a whole block of queries is past the length,
+    which is all ``lengths`` is for: it rides in by scalar prefetch).
+
+    Nothing is transposed or padded on the way in: a grid step holds the
+    ``[P, 128]`` lanes of one row of the batch where they lie, two heads of
+    64 (one of 128, four of 32), K and V whole.  Each head's query is
+    masked to its own lanes, so the 128-deep contraction is that head's
+    scores and the MXU does what it would on a head padded to its lanes.
+    Scores in float32 off the MXU, probabilities in the inputs' dtype
+    against V with a float32 accumulator.
+    """
+    B, P, D = q.shape
+    hd = D // heads
+    W = _tile_width(hd)
+    if W is None:
+        raise ValueError(f"heads of {hd} fill no whole lane tiles")
+    block = block or prompt_block(P)
+    Pp = _round_up(P, block)
+    if Pp != P:  # zero rows, past every length
+        q, k, v = (jnp.pad(a, ((0, 0), (0, Pp - P), (0, 0)))
+                   for a in (q, k, v))
+    spec = pl.BlockSpec((None, Pp, W), lambda b, t, lens: (b, 0, t))
+    out = pl.pallas_call(
+        functools.partial(_prompt_kernel, sm_scale=hd ** -0.5, block=block,
+                          head_dim=hd, width=D),
+        out_shape=jax.ShapeDtypeStruct((B, Pp, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, pl.cdiv(D, W)),
+            in_specs=[spec, spec, spec], out_specs=spec,
+            scratch_shapes=([pltpu.VMEM((Pp, W), k.dtype)] if D % W else [])),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="prompt_attention",
+    )(lengths.astype(jnp.int32), q, k, v)
+    return out[:, :P]
+
+
+# Positions a prompt may have for the kernel: K and V lie whole in VMEM a
+# lane tile at a time, and a block of queries is unrolled after the other.
+PROMPT_KERNEL_MAX_POSITIONS = 2048
+
+
+def prompt_block(P: int) -> int:
+    """Queries a block of the prompt kernel holds.  On the v5e a block costs
+    about 0.36 us beside its scores (324 G elements a second): at
+    ``[4, 768, 25 x 64]`` 64, 128, 256, 384 and 768 read 374, 233, 181, 189
+    and 208 us a layer (PR 39's chip runs; PERF.md section 6, PR 40)."""
+    return min(256, _round_up(P, 16))
+
+
+def masked_attention(q, k, v, mask_bias, heads: int):
+    """The ``jax.numpy`` form, scores materialised: q [B,Tq,D], k/v [B,Tk,D]
+    with the heads side by side, mask_bias [B,1,Tq,Tk] float32 → [B,Tq,D]."""
+    def split(x):
+        B, T, D = x.shape
+        return x.reshape(B, T, heads, D // heads)
+
+    q, k, v = split(q), split(k), split(v)
+    scale = q.shape[-1] ** -0.5
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k).astype(jnp.float32)
+    probs = jax.nn.softmax(scores + mask_bias, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    B, Tq = out.shape[:2]
+    return out.reshape(B, Tq, -1)
+
+
+def prompt_mask(lengths, P: int):
+    """Causal and ragged as an additive bias [B, 1, P, P]: query i reads
+    keys j <= i that are real (j < length)."""
+    pos = jnp.arange(P)
+    causal = pos[None, :, None] >= pos[None, None, :]              # [1,P,P]
+    real = pos[None, None, :] < lengths[:, None, None]              # [B,1,P]
+    return jnp.where(causal & real, 0.0, _NEG_INF).astype(jnp.float32)[:, None]
+
+
+# Bytes of float32 scores ``[B, H, P, P]`` from which a prompt's attention
+# takes the kernel.  From both forms alone on the v5e at the benchmark's 17
+# prefill shapes (chip_smoke.py *kernels*; PERF.md section 6, PR 40): while
+# its scores stay under about 100 MiB the ``jax.numpy`` form costs a quarter
+# of one pass over them and wins or ties (0.42-1.10x at 5 to 80 MiB); from
+# 100 MiB on it pays about 2.5 passes and the kernel wins, 1.28x at 100 MiB
+# and 2.8-4.6x from 112 MiB on.
+PROMPT_KERNEL_MIN_SCORE_BYTES = 96 << 20
+
+
+def prompt_form(batch: int, heads: int, P: int, head_dim: int) -> str:
+    """Which form :func:`prompt_attend` takes at these shapes, from what it
+    can observe: ``"kernel"`` on one TPU device where the heads fill whole
+    lane tiles, the prompt is ``PROMPT_KERNEL_MAX_POSITIONS`` at most and
+    the float32 scores the other form would write are
+    ``PROMPT_KERNEL_MIN_SCORE_BYTES`` at least; else ``"einsum"`` (the CPU;
+    a mesh, where a Mosaic kernel is not partitioned automatically and the
+    partitioner splits the einsums over the heads; small batches of short
+    prompts, whose scores XLA's fusions keep cheaply)."""
+    if (jax.default_backend() != "tpu" or jax.device_count() != 1
+            or _tile_width(head_dim) is None
+            or P > PROMPT_KERNEL_MAX_POSITIONS):
+        return "einsum"
+    return ("kernel" if batch * heads * P * P * 4
+            >= PROMPT_KERNEL_MIN_SCORE_BYTES else "einsum")
+
+
+def prompt_attend(q, k, v, lengths, heads: int):
+    """A prefill's prompt attention: q, k, v [B, P, D] with the heads side
+    by side, lengths [B] → [B, P, D], causal and ragged in one softmax, by
+    the kernel or the ``jax.numpy`` form as :func:`prompt_form` says."""
+    B, P, D = q.shape
+    if prompt_form(B, heads, P, D // heads) == "kernel":
+        return prompt_attention(q, k, v, lengths, heads=heads)
+    return masked_attention(q, k, v, prompt_mask(lengths, P), heads)
 
 
 # Streaming beats materialised scores once the score tensor stops fitting in

@@ -439,6 +439,12 @@ class GenerationScheduler:
         # but never concurrently — the task awaits every run_fn round-trip
         # before touching them again.
         self.prefill_dispatches = 0  # guarded-by: dispatch-serialized
+        # Those of them whose prompt attention took the kernel, by what the
+        # model says of the dispatch's (padded batch, bucket); a model that
+        # does not say has a prompt path of its own.
+        self.prefill_kernel_dispatches = 0  # guarded-by: dispatch-serialized
+        self._prompt_form = meta.get("prompt_form",
+                                     lambda batch, bucket: "own")
         self._cache_k = None  # guarded-by: dispatch-serialized
         self._cache_v = None  # guarded-by: dispatch-serialized
         # Host-owned slot state, passed into every segment (tiny h2d).
@@ -513,7 +519,18 @@ class GenerationScheduler:
         log_event(log, "generation lane ready", model=self.name, mode="slot",
                   slots=self.slots, positions=self.total, rows=self.rows,
                   read_block=self.read_block,
-                  prompt_buckets=list(self.prompt_buckets))
+                  prompt_buckets=list(self.prompt_buckets),
+                  prompt_forms=self._prompt_forms())
+
+    def _prompt_forms(self) -> dict:
+        """Per prefill bucket and admission batch (a power of two, as
+        ``_admit_batch_sync`` pads them), the form the model says its prompt
+        attention takes."""
+        return {str(bucket): {
+            str(1 << i): self._prompt_form(1 << i, bucket) for i in range(
+                (min(self._rows.prefill_batch(bucket) or self.slots,
+                     self.slots) - 1).bit_length() + 1)}
+            for bucket in self.prompt_buckets}
 
     # -- device kernels (all called on the runner's dispatch thread) --------
     def _ensure_cache(self):
@@ -532,10 +549,12 @@ class GenerationScheduler:
     def _admit_sync(self, req: GenRequest, slot: int):
         """Prefill one request and splice it into the pool (dispatch thread)."""
         tl = self.timeline
-        req.prefill_windows = self._rows.windows(self._admit_len_of(req.sample))
+        n = self._admit_len_of(req.sample)
+        req.prefill_windows = self._rows.windows(n)
+        bucket = self._bucket_for(n)
+        form = self._prompt_form(1, bucket)
         with tl.phase("prefill.launch", programs=1, batch=1,
-                      windows=req.prefill_windows):
-            bucket = self._bucket_for(self._admit_len_of(req.sample))
+                      windows=req.prefill_windows, form=form):
             payload = self._collate_admit(req.sample, bucket)
             if self.lockstep is not None:
                 self.lockstep.lead_gen_admit(self.name, slot, bucket, payload)
@@ -548,6 +567,7 @@ class GenerationScheduler:
             self._ensure_cache()
             first, k_row, v_row = self._prefill(self.params, payload)
             self.prefill_dispatches += 1
+            self.prefill_kernel_dispatches += form == "kernel"
         with tl.phase("prefill.fetch"):
             first_tok = int(np.asarray(first)[0])
         with tl.phase("insert.launch", programs=1):
@@ -589,9 +609,10 @@ class GenerationScheduler:
         windows = [self._rows.windows(int(p["length"][0])) for _, _, p in group]
         for (req, _, _), n in zip(group, windows):
             req.prefill_windows = n
+        Bp = 1 << (B - 1).bit_length()
+        form = self._prompt_form(Bp, bucket)
         with tl.phase("prefill.launch", programs=1, batch=B, bucket=bucket,
-                      windows=max(windows)):
-            Bp = 1 << (B - 1).bit_length()
+                      windows=max(windows), form=form):
             payloads = [p for _, _, p in group]
             batched = {
                 k: np.concatenate([p[k] for p in payloads]
@@ -601,6 +622,7 @@ class GenerationScheduler:
             self._ensure_cache()
             first, k_rows, v_rows = self._prefill(self.params, batched)
             self.prefill_dispatches += 1
+            self.prefill_kernel_dispatches += form == "kernel"
         with tl.phase("prefill.fetch"):
             first = np.asarray(first)  # blocks until the device is done
         with tl.phase("insert.launch", programs=B):
@@ -761,6 +783,7 @@ class GenerationScheduler:
                 "segment_rounds": self.segment_rounds,
                 "chained_rounds": self.chained_rounds,
                 "prefill_dispatches": self.prefill_dispatches,
+                "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
                 "tokens_emitted": self.tokens_emitted,
                 "kv_live_share": {"sum": round(self.kv_live_sum, 6),
                                   "count": self.segment_rounds},

@@ -770,7 +770,12 @@ class MetricsHub:
                                "by the call that fetched the one before"),
                               ("window_rolls", "Times a generating slot's "
                                "span moved its start during a segment (a "
-                               "window of its cache completed)")):
+                               "window of its cache completed)"),
+                              ("prefill_dispatches", "Prefill programs "
+                               "launched per model (slot lanes)"),
+                              ("prefill_kernel_dispatches", "Those of them "
+                               "whose prompt attention took the Pallas "
+                               "kernel (ops/flash_attention.prompt_form)")):
                 metric(f"tpuserve_{key}_total", "counter", what,
                        [({"model": m}, s[key]) for m, s in gsnap.items()
                         if s.get(key) is not None])

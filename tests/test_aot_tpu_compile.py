@@ -30,7 +30,10 @@ from pytorch_zappa_serverless_tpu.ops import (
     decode_attention as decode_attention_module)
 from pytorch_zappa_serverless_tpu.ops.decode_attention import (
     decode_attention, pick_block_t, work_list)
-from pytorch_zappa_serverless_tpu.ops.flash_attention import flash_attention
+from pytorch_zappa_serverless_tpu.ops import (
+    flash_attention as flash_attention_module)
+from pytorch_zappa_serverless_tpu.ops.flash_attention import (
+    PROMPT_KERNEL_MAX_POSITIONS, flash_attention, prompt_attention)
 from pytorch_zappa_serverless_tpu.ops.int8_matmul import (
     int8_matmul, padded_columns)
 
@@ -100,6 +103,48 @@ def test_flash_attention_compiles_for_v5e(one_chip, b, tq, tk, h, d, causal):
         ((b, tq, h, d), jnp.bfloat16), ((b, tk, h, d), jnp.bfloat16),
         ((b, tk, h, d), jnp.bfloat16))
     assert "tpu_custom_call" in text
+
+
+# A prefill's prompt attention over rows [B, P, H x 64] where they lie: the
+# benchmark's admission batches (XL's 25 heads are 12.5 lane tiles, so the
+# last block hangs over the rows' end), a bucket of one block, and the
+# longest prompt the picker hands the kernel.
+@pytest.mark.parametrize("b,p,h", [
+    (4, 768, 25), (8, 512, 25), (1, 512, 25), (16, 256, 20), (16, 768, 20),
+    (8, 512, 20), (1, PROMPT_KERNEL_MAX_POSITIONS, 25),
+], ids=lambda v: str(v))
+def test_prompt_attention_compiles_for_v5e(one_chip, b, p, h):
+    text = _compile(
+        lambda q, k, v, n: prompt_attention(q, k, v, n, heads=h), one_chip,
+        *[((b, p, h * 64), jnp.bfloat16)] * 3, ((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_prefill_with_the_prompt_kernel_holds_no_score_array(one_chip,
+                                                             monkeypatch):
+    """A two-layer prefill at GPT-2 XL's widths, four prompts of 768: with
+    the kernel (chosen by backend, and the backend here is the CPU, so the
+    test steers the choice) no float32 ``[B, H, P, P]`` or ``[B, 1, P, P]``
+    array is left in the program; with the other form both are there."""
+    cfg = gpt2.GPT2Config(d_model=1600, layers=2, heads=25, ffn_dim=6400)
+    prefill, args = chip_smoke.prefill_program(cfg, 4, 768, 960, one_chip)
+    scores, mask = "4x25x768x768xf32", "4x1x768x768xf32"
+    text = prefill.lower(*args).as_text()
+    assert scores in text and mask in text
+    monkeypatch.setattr(flash_attention_module, "prompt_form",
+                        lambda *shape: "kernel")
+    prefill, args = chip_smoke.prefill_program(cfg, 4, 768, 960, one_chip)
+    lowered = prefill.lower(*args)
+    text = lowered.as_text()
+    assert scores not in text and mask not in text and "768x768" not in text
+    compiled = lowered.compile().as_text()
+    assert compiled.count("tpu_custom_call") == cfg.layers
+    # The kernel reads the projections where the matmuls left them: nothing
+    # is transposed, and q, k and v are not copied on the way in.
+    moves = [line for _, line in chip_smoke.pool_sized_moves(
+        compiled, 4 * 768 * 1600) if "4,768,1600" in line]
+    assert len(moves) <= cfg.layers and not any(
+        "transpose" in line for line in moves), moves
 
 
 # Decode attention over the slot pool [L, S, T, D], a middle layer: the

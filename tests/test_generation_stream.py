@@ -252,6 +252,40 @@ async def test_kv_live_share_counts_the_generating_slots(engine):
         await sched.stop()
 
 
+async def test_boot_log_names_the_prompt_form_of_every_prefill(engine,
+                                                               monkeypatch):
+    """``generation lane ready`` says, per prefill bucket and admission
+    batch (the powers of two up to the slots), which form the prompt
+    attention takes, by calling the picker's own rule."""
+    from pytorch_zappa_serverless_tpu.ops import flash_attention as F
+    from pytorch_zappa_serverless_tpu.serving import generation
+
+    lines = []
+    monkeypatch.setattr(generation, "log_event",
+                        lambda log, msg, **fields: lines.append((msg, fields)))
+
+    def forms():
+        del lines[:]
+        sched = _scheduler(engine)
+        fields, = [f for msg, f in lines if msg == "generation lane ready"]
+        return sched, fields["prompt_forms"]
+
+    sched, got = forms()
+    batches = [str(1 << i) for i in range(sched.slots.bit_length())
+               if 1 << i <= sched.slots]
+    assert got == {str(b): dict.fromkeys(batches, "einsum")
+                   for b in sched.prompt_buckets}
+    calls = []
+    monkeypatch.setattr(
+        "pytorch_zappa_serverless_tpu.models.decoder.prompt_form",
+        lambda *shape: calls.append(shape) or "kernel")
+    _, got = forms()
+    assert set(got[str(sched.prompt_buckets[0])].values()) == {"kernel"}
+    # (batch, heads, bucket, head size), what the rule is written on
+    assert calls == [(int(b), 2, 8, 16) for b in batches]
+    assert F.prompt_form(*calls[0]) == "einsum"
+
+
 async def test_burst_admissions_coalesce_into_one_prefill(engine):
     """A burst of same-bucket requests admits with ONE batched prefill
     dispatch (VERDICT r3 #5) — and the chains still match the fixed-batch

@@ -119,7 +119,8 @@ def _loaded_hub():
     hub.generation = lambda: {
         "gpt2": {"mode": "slot", "slots": 4, "active": 0, "pending": 0,
                  "device_rounds": 7, "segment_rounds": 5, "chained_rounds": 3,
-                 "prefill_dispatches": 2, "tokens_emitted": 10,
+                 "prefill_dispatches": 2, "prefill_kernel_dispatches": 1,
+                 "tokens_emitted": 10,
                  "kv_live_share": {"sum": 0.93, "count": 5},
                  "kv_read_share": {"sum": 1.25, "count": 5},
                  "span_rows": {"sum": 3561, "count": 5},
