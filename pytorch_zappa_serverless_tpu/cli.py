@@ -53,9 +53,12 @@ def _select_device(args, cfg, what: str) -> None:
 
 def cmd_serve(args) -> int:
     from .serving.server import run
+    from .utils import boot
 
     cfg = load_config(args.config, args.profile)
+    boot.stamp("import")
     _select_device(args, cfg, "serve")
+    boot.stamp("backend")
     if args.port:
         cfg.port = args.port
     if args.host:

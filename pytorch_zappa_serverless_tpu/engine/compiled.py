@@ -15,18 +15,14 @@ and ~1.3x typical.
 from __future__ import annotations
 
 import itertools
-import time
 from typing import Any, Sequence
 
 import jax
 import numpy as np
 
 from ..config import ModelConfig
-from ..utils.logging import get_logger, log_event
-from .cache import CompileClock, timed
+from .cache import CompileClock
 from .servable import Servable
-
-log = get_logger("engine.compiled")
 
 
 def default_collate(samples: Sequence[dict[str, np.ndarray]], bucket: tuple[int, ...],
@@ -185,6 +181,7 @@ class CompiledModel:
         # compile cache still applies.
         self._jit = jax.jit(servable.apply_fn)
         self._warmed: set[tuple[int, ...]] = set()
+        self._seen: set = set()  # what this lane has compiled (the ledger's)
         # Multi-process lockstep lead hook (parallel/lockstep.py), set by
         # build_engine on process 0 of a multi-host world: run_batch
         # broadcasts each collated batch to the follower loops before
@@ -236,17 +233,28 @@ class CompiledModel:
         return jax.tree.map(np.asarray, out)
 
     # -- compilation --------------------------------------------------------
+    def _first_dispatch(self, bucket: tuple[int, ...], batch, wait):
+        """A bucket's first run: where its program compiles or is restored
+        from the cache.  Booked as a first use in the engine's ledger
+        (``engine/cache.py``), whose listeners time the stages from inside;
+        the bucket is warm from here on, so /healthz ``buckets_compiled`` and
+        /v1/models tell the truth whether boot warmed it or a request did."""
+        with self.clock.open(self.servable.name, "predict",
+                             {"bucket": list(bucket)},
+                             seen=self._seen) as use:
+            out = self._jit(self.servable.params, batch)
+            use.launched()
+            out = wait(out)
+        self._warmed.add(bucket)
+        return out
+
     def _warm_bucket(self, bucket: tuple[int, ...]):
         spec = self.servable.input_spec(bucket)
         # Same placement as serving: warmup must compile the SPMD program the
         # request path will hit, or the first real request recompiles.
-        dummy = self._place({k: np.zeros(s.shape, s.dtype) for k, s in spec.items()})
-        _, secs = timed(lambda: jax.block_until_ready(
-            self._jit(self.servable.params, dummy)))
-        self.clock.record(self.servable.name, bucket, secs)
-        self._warmed.add(bucket)
-        log_event(log, "compiled", model=self.servable.name, bucket=list(bucket),
-                  seconds=round(secs, 3))
+        self._first_dispatch(bucket, self._place(
+            {k: np.zeros(s.shape, s.dtype) for k, s in spec.items()}),
+            jax.block_until_ready)
 
     def warmup(self):
         """Compile every bucket at boot (hits the persistent cache on re-boot)."""
@@ -271,13 +279,11 @@ class CompiledModel:
         spec = self.servable.input_spec(bucket)
         dummy = [{k: np.zeros(s.shape[1:], s.dtype) for k, s in spec.items()}
                  for _ in range(bucket[0])]
-        _, secs = timed(
-            lambda: self.chunk_finalize(self._warm_chunk_steps(dummy), dummy))
-        self.clock.record(self.servable.name, (*bucket, "chunked"), secs)
+        with self.clock.open(self.servable.name, "predict",
+                             {"bucket": list(bucket),
+                              "chunks": ch["num_chunks"]}, seen=self._seen):
+            self.chunk_finalize(self._warm_chunk_steps(dummy), dummy)
         self._chunk_warmed = True
-        log_event(log, "compiled chunked", model=self.servable.name,
-                  bucket=list(bucket), chunks=ch["num_chunks"],
-                  seconds=round(secs, 3))
 
     def _warm_chunk_steps(self, dummy):
         ch = self.servable.meta["chunked"]
@@ -411,21 +417,12 @@ class CompiledModel:
         # data under jit, so this single device_put is the whole DP story).
         with jax.profiler.TraceAnnotation("h2d"):
             batch = self._place(batch)
-        first_dispatch = bucket not in self._warmed
         with jax.profiler.TraceAnnotation("device"):
-            t0 = time.perf_counter()
-            out = self._jit(self.servable.params, batch)
-            out = self._fetch(out)  # blocks until ready
-        if first_dispatch:
-            # Lazy-compile bookkeeping (warmup_at_boot: false, the dev
-            # default): the bucket is warm from here on, and its first-call
-            # seconds land on the compile clock so /healthz buckets_compiled
-            # and /v1/models tell the truth either way.
-            secs = time.perf_counter() - t0
-            self.clock.record(self.servable.name, bucket, secs)
-            self._warmed.add(bucket)
-            log_event(log, "compiled lazily", model=self.servable.name,
-                      bucket=list(bucket), seconds=round(secs, 3))
+            if bucket in self._warmed:
+                out = self._fetch(  # blocks until ready
+                    self._jit(self.servable.params, batch))
+            else:  # lazy compile (warmup_at_boot: false, the dev default)
+                out = self._first_dispatch(bucket, batch, self._fetch)
         with jax.profiler.TraceAnnotation("postprocess"):
             return ([self.servable.postprocess(out, i) for i in range(len(samples))],
                     bucket)

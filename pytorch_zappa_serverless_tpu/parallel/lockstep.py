@@ -160,35 +160,47 @@ class LockstepDriver:
         """Per-model mirrored generation kernels + cache pool (lazy)."""
         state = self._gen.get(name)
         if state is None:
-            from ..serving.generation import build_gen_kernels
+            from ..serving.generation import build_gen_kernels, slot_program
+            from ..serving.tracing import RoundTimeline
 
             cm = self.engine.models[name]
             kernels = build_gen_kernels(cm, self.engine.mesh)
             state = self._gen[name] = {
                 "kernels": kernels,
                 "cache": kernels["alloc_cache"](),
+                # The leader's launch phases at the same points, so that a
+                # mirrored program's first use is booked as the leader's is.
+                "timeline": RoundTimeline(name, clock=cm.clock,
+                                          program_of=slot_program),
             }
         return state
 
-    def _follow_gen_admit(self, name: str, slot: int, payload: dict):
+    def _follow_gen_admit(self, name: str, slot: int, bucket: int,
+                          payload: dict):
         state = self._gen_state(name)
-        k = state["kernels"]
+        k, tl = state["kernels"], state["timeline"]
         cm = self.engine.models[name]
-        first, k_row, v_row = k["prefill"](cm.servable.params, payload)
-        ck, cv = state["cache"]
-        state["cache"] = k["insert"](ck, cv, k_row, v_row, np.int32(slot))
-        np.asarray(first)  # completion fence, mirroring the leader's fetch
+        with tl.phase("prefill.launch", programs=1, batch=1, bucket=bucket):
+            first, k_row, v_row = k["prefill"](cm.servable.params, payload)
+        with tl.phase("insert.launch", programs=1):
+            ck, cv = state["cache"]
+            state["cache"] = k["insert"](ck, cv, k_row, v_row,
+                                         np.int32(slot))
+        with tl.phase("prefill.fetch"):
+            np.asarray(first)  # completion fence, mirroring the leader's
 
     def _follow_gen_segment(self, name: str, st: dict):
         state = self._gen_state(name)
-        k = state["kernels"]
+        k, tl = state["kernels"], state["timeline"]
         cm = self.engine.models[name]
         ck, cv = state["cache"]
-        packed, ck, cv = k["segment"](
-            cm.servable.params, ck, cv, st["tok"], st["pos"], st["step"],
-            st["fin"], st["temp"], st["seed"], st["topk"], st["topp"])
-        state["cache"] = (ck, cv)
-        np.asarray(packed)  # completion fence, mirroring the leader's fetch
+        with tl.phase("segment.launch", programs=1):
+            packed, ck, cv = k["segment"](
+                cm.servable.params, ck, cv, st["tok"], st["pos"], st["step"],
+                st["fin"], st["temp"], st["seed"], st["topk"], st["topp"])
+            state["cache"] = (ck, cv)
+        with tl.phase("segment.fetch"):
+            np.asarray(packed)  # completion fence, mirroring the leader's
 
     def follow(self) -> None:
         """Mirror host 0's dispatches until it shuts down (blocking)."""
@@ -223,7 +235,7 @@ class LockstepDriver:
                              for key, v in spec.items()}
                     payload = {k: np.asarray(v)
                                for k, v in self._broadcast(zeros).items()}
-                    self._follow_gen_admit(name, s, payload)
+                    self._follow_gen_admit(name, s, b, payload)
                     continue
                 if op == OP_GEN_SEGMENT:
                     S = cm.servable.meta["continuous"]["slots"]

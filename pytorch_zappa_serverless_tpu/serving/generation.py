@@ -56,6 +56,35 @@ from .tracing import RoundTimeline
 log = get_logger("serving.generation")
 
 
+def _pow2(n: int) -> int:
+    return 1 << (int(n) - 1).bit_length()
+
+
+def slot_program(phase: str, attrs: dict) -> tuple[str, dict] | None:
+    """Which of :func:`build_gen_kernels`' programs a launch phase of the
+    slot lane runs, and the shapes that make it a program of its own (the
+    first-use ledger's ``program`` and ``key``, ``engine/cache.py``)."""
+    if phase == "prefill.launch":
+        key = {"batch": _pow2(attrs["batch"]), "bucket": attrs["bucket"]}
+        if attrs.get("form") is not None:
+            key["form"] = attrs["form"]
+        return "prefill", key
+    if phase == "insert.launch":
+        return (("insert_from", {"batch": attrs["rows"]}) if "rows" in attrs
+                else ("insert", {}))
+    return ("segment", {}) if phase == "segment.launch" else None
+
+
+def paged_program(phase: str, attrs: dict) -> tuple[str, dict] | None:
+    """The same for :func:`build_paged_kernels`' programs; a launch's page
+    copies, its draft rung and ``spec_verify`` fold into its entry."""
+    if phase == "prefill.launch":
+        return "prefill_chunk", {"batch": _pow2(attrs["batch"]),
+                                 "bucket": attrs["bucket"]}
+    return ((attrs.get("kind", "segment"), {}) if phase == "segment.launch"
+            else None)
+
+
 def build_gen_kernels(cm, mesh=None):
     """The jitted prefill/insert/segment trio + cache allocator for one model.
 
@@ -514,8 +543,10 @@ class GenerationScheduler:
         self.tokens_emitted = 0  # guarded-by: event-loop
         self.ttft_hist = Histogram(TOKEN_LATENCY_BUCKETS_MS)
         self.itl_hist = Histogram(TOKEN_LATENCY_BUCKETS_MS)
-        # Host phases of every round, on both threads (serving/tracing.py).
-        self.timeline = RoundTimeline(self.name)
+        # Host phases of every round, on both threads (serving/tracing.py);
+        # a launch that compiles is a first use in the engine's ledger.
+        self.timeline = RoundTimeline(self.name, clock=cm.clock,
+                                      program_of=slot_program)
         log_event(log, "generation lane ready", model=self.name, mode="slot",
                   slots=self.slots, positions=self.total, rows=self.rows,
                   read_block=self.read_block,
@@ -553,7 +584,7 @@ class GenerationScheduler:
         req.prefill_windows = self._rows.windows(n)
         bucket = self._bucket_for(n)
         form = self._prompt_form(1, bucket)
-        with tl.phase("prefill.launch", programs=1, batch=1,
+        with tl.phase("prefill.launch", programs=1, batch=1, bucket=bucket,
                       windows=req.prefill_windows, form=form):
             payload = self._collate_admit(req.sample, bucket)
             if self.lockstep is not None:
@@ -609,7 +640,7 @@ class GenerationScheduler:
         windows = [self._rows.windows(int(p["length"][0])) for _, _, p in group]
         for (req, _, _), n in zip(group, windows):
             req.prefill_windows = n
-        Bp = 1 << (B - 1).bit_length()
+        Bp = _pow2(B)
         form = self._prompt_form(Bp, bucket)
         with tl.phase("prefill.launch", programs=1, batch=B, bucket=bucket,
                       windows=max(windows), form=form):
@@ -625,7 +656,7 @@ class GenerationScheduler:
             self.prefill_kernel_dispatches += form == "kernel"
         with tl.phase("prefill.fetch"):
             first = np.asarray(first)  # blocks until the device is done
-        with tl.phase("insert.launch", programs=B):
+        with tl.phase("insert.launch", programs=B, rows=Bp):
             for j, (req, slot, payload) in enumerate(group):
                 self._cache_k, self._cache_v = self._insert_from(
                     self._cache_k, self._cache_v, k_rows, v_rows,
@@ -799,7 +830,8 @@ class GenerationScheduler:
                 "latency": {"ttft_ms": self.ttft_hist.snapshot(),
                             "itl_ms": self.itl_hist.snapshot()},
                 "host_phases": self.timeline.snapshot(),
-                "lane_wait": self.timeline.lane_wait_snapshot()}
+                "lane_wait": self.timeline.lane_wait_snapshot(),
+                "programs": self.cm.clock.programs(self.name)}
 
     def start(self):
         if self._task is None:
@@ -1313,7 +1345,8 @@ class PagedGenerationScheduler:
         # Host phases of every round, the slot scheduler's names at the same
         # boundaries (serving/tracing.py); swap, migration and command paths
         # carry none and show as untiled time.
-        self.timeline = RoundTimeline(self.name)
+        self.timeline = RoundTimeline(self.name, clock=cm.clock,
+                                      program_of=paged_program)
 
     # -- sizing ---------------------------------------------------------------
     def _chunk_plan(self, n: int, start: int = 0) -> list[tuple[int, int]]:
@@ -1358,7 +1391,7 @@ class PagedGenerationScheduler:
         dispatch-thread sync fn below touches only device state).  Padding
         rows (pow2 group) replicate zeros with an all-trash table."""
         G = len(jobs)
-        Gp = 1 << (G - 1).bit_length()
+        Gp = _pow2(G)
         toks = np.zeros((Gp, bucket), np.int32)
         start = np.zeros((Gp,), np.int32)
         length = np.ones((Gp,), np.int32)
@@ -1593,6 +1626,7 @@ class PagedGenerationScheduler:
                           "detached": len(self._detached)},
             "host_phases": self.timeline.snapshot(),
             "lane_wait": self.timeline.lane_wait_snapshot(),
+            "programs": self.cm.clock.programs(self.name),
         }
         if self._prefix is not None:
             out["prefix"] = self._prefix.snapshot()

@@ -242,13 +242,14 @@ class MetricsHub:
                 "lanes": engine.runner.lane_stats(),
             }
             out["cold_start"] = {"seconds": round(engine.cold_start_seconds, 3),
-                                 "compile_entries": engine.clock.entries,
+                                 "compile_entries": list(engine.clock.entries),
                                  "compile_seconds_total": round(engine.clock.total_seconds, 3)}
             per_model = getattr(engine.clock, "per_model", None)
             if per_model is not None:
-                # CompileClock totals per model: how many executables each
-                # model has compiled this process and their cumulative wall
-                # time — the cold-start cost the lifecycle estimate learns.
+                # The first-use ledger's totals per model (every lane's):
+                # how many programs each model has met this process and
+                # their cumulative wall time (launch + first run) — the
+                # cold-start cost the lifecycle estimate learns.
                 out["cold_start"]["compile_by_model"] = per_model()
             resident = getattr(engine.runner, "resident_bytes", None)
             if resident is not None:
@@ -455,13 +456,20 @@ class MetricsHub:
             if per_model is not None:
                 clock_by_model = per_model()
                 metric("tpuserve_compile_entries", "gauge",
-                       "CompileClock entries recorded per model",
+                       "First uses of a program recorded per model",
                        [({"model": m}, v["entries"])
                         for m, v in clock_by_model.items()])
                 metric("tpuserve_model_compile_seconds_total", "counter",
                        "Cumulative XLA compile/warmup seconds per model",
                        [({"model": m}, v["seconds"])
                         for m, v in clock_by_model.items()])
+                # The ledger again, by what an operator alerts on: a first
+                # use in serving hours is a compile on the request path.
+                metric("tpuserve_program_first_uses_total", "counter",
+                       "Programs a lane met for the first time, by the "
+                       "persistent cache's answer (hit|miss|uncached)",
+                       [({"model": m, "program": p, "outcome": o}, n)
+                        for (m, p, o), n in engine.clock.first_uses().items()])
             resident = getattr(engine.runner, "resident_bytes", None)
             if resident is not None:
                 by_model = resident()

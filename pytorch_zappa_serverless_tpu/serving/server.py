@@ -43,6 +43,7 @@ import numpy as np
 from aiohttp import web
 
 from ..config import ServeConfig
+from ..utils import boot
 from ..utils.device import device_info, device_memory
 from ..utils.logging import current_trace_id, get_logger, log_event
 from ..engine.loader import Engine, build_engine
@@ -551,6 +552,7 @@ class Server:
             # executor so health endpoints could come up first if wanted.
             loop = asyncio.get_running_loop()
             self.engine = await loop.run_in_executor(None, build_engine, self.cfg)
+            boot.stamp("engine")
         if self.engine.lockstep is not None:
             import jax
 
@@ -3371,6 +3373,8 @@ class Server:
                 row["itl_p50_ms"] = itl
         # Read here, at scrape time, and never on the request path.
         snap["device_memory"] = device_memory()
+        # Spawn to healthy in four intervals (utils/boot.py).
+        snap["boot"] = boot.split()
         return web.json_response(snap)
 
     # -- admin: chaos + drain ------------------------------------------------
@@ -3490,4 +3494,9 @@ def run(cfg: ServeConfig):
     # budget configured, SIGTERM flips to draining and exits after in-flight
     # work finishes (docs/RESILIENCE.md) instead of aiohttp's immediate stop.
     server._handle_signals = True
-    web.run_app(server.app, host=cfg.host, port=cfg.port)
+
+    def bound(banner: str) -> None:  # aiohttp's word that the socket listens
+        boot.stamp("http")
+        print(banner, flush=True)
+
+    web.run_app(server.app, host=cfg.host, port=cfg.port, print=bound)

@@ -45,6 +45,8 @@ import time
 import uuid
 from collections import deque
 
+from ..utils.scope import on_thread
+
 # 00-<16-byte trace id>-<8-byte span id>-<flags>, lowercase hex (W3C level 1).
 _TRACEPARENT = re.compile(
     r"^([0-9a-f]{2})-([0-9a-f]{32})-([0-9a-f]{16})-([0-9a-f]{2})$")
@@ -433,7 +435,10 @@ PHASES = ("round.idle", "round.admit_host", "round.lane_wait",
 
 
 class _Phase:
-    """One timed phase: stamps outermost, the annotation inside them."""
+    """One timed phase: stamps outermost, the annotation inside them.  While
+    it is open it is its thread's scope for the compile listeners
+    (``engine/cache.py``): a program that compiles inside a launch phase is
+    booked as that launch's first use."""
 
     __slots__ = ("tl", "name", "attrs", "ann", "t0")
 
@@ -447,11 +452,21 @@ class _Phase:
         self.t0 = time.perf_counter_ns()
         self.ann = self.tl.annotation(self.name, **self.attrs)
         self.ann.__enter__()
+        on_thread.scope = self
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        on_thread.scope = None
         self.ann.__exit__(exc_type, exc, tb)
-        self.tl.book(self.name, self.t0, time.perf_counter_ns(), self.attrs)
+        t1 = time.perf_counter_ns()
+        if self.tl.open_uses:
+            self.tl.settle(self, t1)
+        self.tl.book(self.name, self.t0, t1, self.attrs)
+
+    def first_use(self):
+        """The ledger's open first use for a compile heard while this phase
+        is open; None where the phase launches no program."""
+        return self.tl.first_use(self)
 
 
 class _Trip:
@@ -509,12 +524,25 @@ class RoundTimeline:
 
     No lock: every phase has exactly one writer thread, and the scheduler
     task and the dispatch thread alternate through awaited round-trips.
+
+    A launch phase inside which a program compiled is that program's first
+    use: ``clock`` (``engine/cache.CompileClock``) keeps its entry, named by
+    ``program_of(phase name, attrs) -> (program, key) | None``, the lane's own
+    account of which program a launch phase runs.
     """
 
-    def __init__(self, model: str, ring: int = 256):
+    def __init__(self, model: str, ring: int = 256, clock=None,
+                 program_of=None):
         from jax.profiler import TraceAnnotation
 
         self.model = model
+        self.clock = clock
+        self.program_of = program_of
+        # First uses not yet whole, ``(launch phase, engine/cache.FirstUse)``:
+        # kept from the first compile heard inside the launch until the fetch
+        # of that kind returns.  Empty but for the rounds that compile.
+        self.open_uses: list[tuple] = []  # guarded-by: dispatch-serialized
+        self._seen: set = set()           # guarded-by: dispatch-serialized
         self._annotate = TraceAnnotation
         self.round = 0  # guarded-by: dispatch-serialized
         self.sum_ns = dict.fromkeys(PHASES, 0)  # guarded-by: dispatch-serialized
@@ -544,6 +572,36 @@ class RoundTimeline:
 
     def trip(self, kind: str) -> _Trip:
         return _Trip(self, kind)
+
+    def first_use(self, phase: _Phase):
+        """The open first use for a compile heard inside ``phase`` (begun at
+        the first such compile), or None: not a launch, or no ledger."""
+        for held, use in self.open_uses:
+            if held is phase:
+                return use
+        named = (self.program_of(phase.name, phase.attrs)
+                 if self.program_of and self.clock is not None else None)
+        if named is None:
+            return None
+        use = self.clock.open(self.model, *named, seen=self._seen,
+                              round=self.round, t0_ns=phase.t0)
+        self.open_uses.append((phase, use))
+        return use
+
+    def settle(self, phase: _Phase, t1_ns: int) -> None:
+        """``phase`` ends while first uses are open: the launch that compiled
+        gets its wall, and the next fetch of its kind its first run."""
+        for held, use in list(self.open_uses):
+            fetch = held.name.replace(".launch", ".fetch")
+            if held is phase:
+                use.launched(t1_ns)
+                whole = fetch not in PHASES  # no fetch of its own
+            else:
+                whole = use.launched_ns is not None and phase.name == fetch
+                if whole:
+                    use.ran(t1_ns)
+            if whole:
+                self.open_uses.remove((held, use))
 
     def book(self, name: str, t0_ns: int, t1_ns: int, attrs: dict) -> None:
         self.sum_ns[name] += t1_ns - t0_ns

@@ -100,14 +100,157 @@ def test_resolver_same_path_on_two_calls(tmp_path, monkeypatch):
 
 
 def test_compile_clock_per_model_totals():
-    clock = CompileClock()
-    clock.record("resnet18", (1,), 1.0)
-    clock.record("resnet18", (4,), 0.5)
-    clock.record("gpt2", (1, 64), 2.25)
+    clock, seen = CompileClock(), set()
+    for model, bucket, launch, run in (("resnet18", (1,), 0.75, 0.25),
+                                       ("resnet18", (4,), 0.5, None),
+                                       ("gpt2", (1, 64), 2.0, 0.25)):
+        clock.open(model, "predict", {"bucket": list(bucket)},
+                   seen=seen).entry.update(launch_s=launch, first_run_s=run)
     per = clock.per_model()
     assert per["resnet18"] == {"entries": 2, "seconds": 1.5}
     assert per["gpt2"] == {"entries": 1, "seconds": 2.25}
     assert clock.total_seconds == pytest.approx(3.75)
+    # The same entries as the ledger keeps every lane's: a kind's first key
+    # is ``first``, a second key of it ``shape``.
+    assert [(e["program"], e["key"], e["cause"]) for e in clock.snapshot()] == [
+        ("predict", {"bucket": [1]}, "first"),
+        ("predict", {"bucket": [4]}, "shape"),
+        ("predict", {"bucket": [1, 64]}, "first")]
+    assert clock.first_uses() == {("resnet18", "predict", "uncached"): 2,
+                                  ("gpt2", "predict", "uncached"): 1}
+
+
+# -- the ledger: a first use's stages, heard from inside jax -------------------
+
+ENTRY_KEYS = {"model", "program", "key", "outcome", "cause", "compiles",
+              "trace_s", "lower_s", "cache_read_s", "backend_s", "launch_s",
+              "first_run_s", "round"}
+
+
+def _first_use(clock, fn, *args, key=None, seen=None):
+    with clock.open("m", "step", key or {},
+                    seen=set() if seen is None else seen) as use:
+        out = fn(*args)
+        use.launched()
+        jax.block_until_ready(out)
+    return clock.entries[-1]
+
+
+def test_first_use_books_its_stages_inside_the_launch(tmp_path):
+    import jax.numpy as jnp
+
+    setup_compile_cache(tmp_path / "xla")
+    clock = CompileClock()
+    e = _first_use(clock, jax.jit(lambda x: jnp.tanh(x @ x).sum()),
+                   jnp.ones((64, 64)))
+    assert set(e) == ENTRY_KEYS  # nothing that only a dropped export needed
+    assert e["trace_s"] > 0 and e["lower_s"] > 0 and e["backend_s"] > 0
+    assert e["compiles"] == 1 and e["outcome"] in ("miss", "hit")
+    assert (e["trace_s"] + e["lower_s"] + e["cache_read_s"] + e["backend_s"]
+            <= e["launch_s"])
+    assert e["first_run_s"] >= 0 and e["cause"] == "first"
+    assert clock.total_seconds == pytest.approx(
+        e["launch_s"] + e["first_run_s"])
+
+
+def test_an_inner_jit_counts_its_trace_once(tmp_path):
+    import jax.numpy as jnp
+
+    setup_compile_cache(tmp_path / "xla")
+    inner = jax.jit(lambda x: jnp.sin(x) * 2.0)
+
+    def outer(x):
+        return inner(inner(x) + 1.0).sum()
+
+    heard = []
+
+    def raw(event, seconds, **kw):  # what jax itself reports, nested or not
+        if heard is not None and event.endswith("/jaxpr_trace_duration"):
+            heard.append(seconds)
+
+    jax.monitoring.register_event_duration_secs_listener(raw)
+    clock, x = CompileClock(), jnp.ones((32, 32))
+    try:
+        e = _first_use(clock, jax.jit(outer), x)
+    finally:
+        traces, heard = list(heard), None
+    # The inner function traces inside the outer trace and fires the same
+    # event: the outermost alone is booked (it ends last and holds the
+    # others), and the stages stay inside the launch.
+    assert len(traces) >= 2 and e["trace_s"] == pytest.approx(traces[-1])
+    assert e["trace_s"] < sum(traces)
+    assert e["trace_s"] + e["lower_s"] + e["backend_s"] <= e["launch_s"]
+
+
+def test_the_persistent_cache_answers_miss_then_hit(tmp_path):
+    import jax.numpy as jnp
+
+    setup_compile_cache(tmp_path / "xla-outcomes")
+    clock, seen = CompileClock(), set()
+
+    def fresh():  # one program in a new function: no process-local cache
+        return jax.jit(lambda x: jnp.cos(x @ x.T).mean() * 41.0)
+
+    x = jnp.ones((48, 24))
+    first = _first_use(clock, fresh(), x, key={"rows": 48}, seen=seen)
+    again = _first_use(clock, fresh(), x, key={"rows": 48}, seen=seen)
+    assert first["outcome"] == "miss" and first["cache_read_s"] == 0
+    assert again["outcome"] == "hit" and again["cache_read_s"] > 0
+    assert (first["cause"], again["cause"]) == ("first", "retrace")
+    sums = clock.programs("m")
+    assert sums["first_uses"] == 2
+    assert sums["backend_miss_s"] == pytest.approx(first["backend_s"], abs=1e-5)
+    assert sums["backend_hit_s"] == pytest.approx(again["backend_s"], abs=1e-5)
+    assert clock.first_uses() == {("m", "step", "miss"): 1,
+                                  ("m", "step", "hit"): 1}
+
+
+def test_a_compile_with_no_scope_open_is_not_booked(tmp_path):
+    """A builder's eager operations compile with no first use open on their
+    thread: the ledger keeps nothing of them (no ``other`` entry)."""
+    import jax.numpy as jnp
+
+    setup_compile_cache(tmp_path / "xla")
+    clock, x = CompileClock(), jnp.ones(5)
+    jax.block_until_ready(jax.jit(lambda x: x * 3.0 + 7.0)(x))
+    assert clock.entries == [] and cache_mod.on_thread.scope is None
+    assert clock.programs("builder")["first_uses"] == 0
+
+
+def test_a_ledger_fault_never_fails_a_compile(tmp_path, monkeypatch):
+    import jax.numpy as jnp
+
+    setup_compile_cache(tmp_path / "xla")
+    clock = CompileClock()
+    monkeypatch.setattr(cache_mod.FirstUse, "book", lambda *a, **k: 1 / 0)
+    with clock.open("m", "step", {}, seen=set()):
+        out = jax.jit(lambda x: x - 11.0)(jnp.ones(3))
+    assert float(out[0]) == -10.0
+
+
+def test_boot_stamps_tile_the_process_own_start_to_the_last_point(monkeypatch):
+    """``utils/boot.py``: the intervals run from the process's start, each
+    from the point before it; a point's first stamp stands; the split ends at
+    the first point boot has not passed (an in-process server stamps none)."""
+    import time
+
+    from pytorch_zappa_serverless_tpu.utils import boot
+
+    monkeypatch.setattr(boot, "_stamps", {})
+    assert boot.split() == {}
+    assert boot._START <= time.perf_counter()  # this process began before now
+    boot.stamp("import")
+    boot.stamp("engine")  # ``backend`` not passed: the split stops before it
+    first = boot.split()
+    assert set(first) == {"import_s"} and first["import_s"] > 0
+    boot.stamp("backend")
+    boot.stamp("import")  # a second stamp of a point changes nothing
+    assert boot.split()["import_s"] == first["import_s"]
+    monkeypatch.setattr(boot, "_START", 100.0)
+    monkeypatch.setattr(boot, "_stamps", {"import": 106.0, "backend": 112.5,
+                                          "engine": 117.0, "http": 117.25})
+    assert boot.split() == {"import_s": 6.0, "backend_s": 6.5,
+                            "engine_s": 4.5, "http_s": 0.25}
 
 
 def _cfg(cache_dir):

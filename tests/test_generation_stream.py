@@ -286,6 +286,67 @@ async def test_boot_log_names_the_prompt_form_of_every_prefill(engine,
     assert F.prompt_form(*calls[0]) == "einsum"
 
 
+async def test_first_uses_of_the_lanes_programs_are_booked_once(engine):
+    """The slot lane's first prefill, insert and segment each leave one
+    entry in the engine's ledger, with the stages heard from inside jax
+    inside the launch's wall; the same shapes again leave none; a new padded
+    batch is a first use of cause ``shape``; and the ``:predict`` lane's
+    first dispatch writes an entry of the same shape into the same ledger."""
+    cm = engine.model("gpt2")
+    sched = _scheduler(engine).start()
+    clock = engine.clock
+    try:
+        one = cm.servable.preprocess({"input_ids": [5, 6, 7]})
+        await asyncio.wait_for(sched.submit(one, max_new=4).done, 120)
+        first = clock.snapshot()
+        assert [(e["program"], e["key"], e["cause"]) for e in first] == [
+            ("prefill", {"batch": 1, "bucket": 8, "form": "einsum"}, "first"),
+            ("insert_from", {"batch": 1}, "first"),
+            ("segment", {}, "first")]
+        for e in first:
+            assert e["model"] == "gpt2" and e["outcome"] == "miss"
+            assert e["trace_s"] > 0 and e["lower_s"] > 0 and e["backend_s"] > 0
+            assert (e["trace_s"] + e["lower_s"] + e["cache_read_s"]
+                    + e["backend_s"]) <= e["launch_s"]
+            assert e["round"] == 1
+        prefill, insert, segment = first
+        # The pool's zeros compile inside the first prefill's launch and
+        # fold into its entry (where this process has not made them before).
+        assert 1 <= prefill["compiles"] <= 3 and insert["compiles"] == 1
+        assert prefill["first_run_s"] > 0 and segment["first_run_s"] > 0
+        assert insert["first_run_s"] is None  # no fetch of its own
+        await asyncio.wait_for(sched.submit(one, max_new=4).done, 120)
+        assert len(clock.entries) == 3  # the same keys: nothing new
+        pair = [cm.servable.preprocess({"input_ids": [3 + i, 4 + i]})
+                for i in range(2)]
+        before = sched.gen_snapshot()["programs"]
+        await asyncio.wait_for(asyncio.gather(
+            *[sched.submit(s, max_new=4).done for s in pair]), 120)
+        # What the benchmark's ``first_uses_in_window`` reads: the counter
+        # moves by exactly the programs a burst of a new batch size compiles.
+        after = sched.gen_snapshot()["programs"]
+        assert after["first_uses"] - before["first_uses"] == 2
+        assert [(e["program"], e["key"].get("batch"), e["cause"])
+                for e in clock.snapshot()[3:]] == [
+            ("prefill", 2, "shape"), ("insert_from", 2, "shape")]
+        snap = sched.gen_snapshot()["programs"]
+        assert snap["first_uses"] == 5 and snap["backend_hit_s"] == 0
+        assert clock.first_uses() == {("gpt2", "prefill", "miss"): 2,
+                                      ("gpt2", "insert_from", "miss"): 2,
+                                      ("gpt2", "segment", "miss"): 1}
+        assert snap["launch_s"] + snap["first_run_s"] == pytest.approx(
+            clock.per_model()["gpt2"]["seconds"], abs=2e-3)
+        cm.run_batch([one])
+        predict = clock.snapshot()[-1]
+        assert predict["program"] == "predict" \
+            and predict["key"] == {"bucket": [1, 8]}
+        assert set(predict) == set(prefill)  # one entry shape, every lane
+        assert predict["trace_s"] > 0 and predict["launch_s"] > 0 \
+            and predict["first_run_s"] >= 0
+    finally:
+        await sched.stop()
+
+
 async def test_burst_admissions_coalesce_into_one_prefill(engine):
     """A burst of same-bucket requests admits with ONE batched prefill
     dispatch (VERDICT r3 #5) — and the chains still match the fixed-batch

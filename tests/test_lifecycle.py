@@ -221,6 +221,21 @@ def test_deadline_aware_cold_admission(tmp_path):
     asyncio.run(scenario())
 
 
+def test_estimate_reads_a_generation_lanes_first_uses(tmp_path):
+    """A model whose generation programs have run is no longer estimated at
+    the configured prior: the ledger the lanes write (engine/cache.py) is the
+    history ``estimate_warm_ms`` reads, whichever lane wrote it."""
+    mgr, server, _, _ = _mgr(tmp_path, activation_estimate_ms=5000.0)
+    assert mgr.estimate_warm_ms("m") == 5000.0
+    clock, seen = server.engine.clock, set()
+    for program, launch, run in (("prefill", 1.5, 0.25), ("segment", 2.0,
+                                                           None)):
+        clock.open("m", program, {}, seen=seen, round=1).entry.update(
+            launch_s=launch, first_run_s=run)
+    assert clock.per_model()["m"] == {"entries": 2, "seconds": 3.75}
+    assert mgr.estimate_warm_ms("m") == 3750.0 + 500.0
+
+
 def test_lru_eviction_respects_budget_and_pinned(tmp_path):
     """hbm_budget_bytes evicts LRU-first, never PINNED, never the model
     whose activation triggered enforcement; all-pinned stays over budget."""

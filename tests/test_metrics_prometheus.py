@@ -486,3 +486,43 @@ def test_device_memory_gauge_renders_where_the_backend_counts(monkeypatch):
     assert 'tpuserve_device_memory_bytes{device="1",kind="in_use"} 7' in text
     assert 'device="1",kind="peak"' not in text
     assert mod.check(text, mod.load_manifest()) == []
+
+
+def test_the_first_use_ledger_renders_under_declared_names():
+    """The ledger's family (ISSUE 42) is in the manifest, carries the label
+    set it declares, and counts what the ledger holds: first uses by program
+    and outcome.  The seconds by stage have no Prometheus family: their
+    reader is the benchmark, over ``/metrics`` ``generation[model].programs``."""
+    from pytorch_zappa_serverless_tpu.engine.cache import CompileClock
+
+    mod = _check_metrics_mod()
+    manifest = mod.load_manifest()["families"]
+    assert manifest["tpuserve_program_first_uses_total"]["labels"] == [
+        "model", "outcome", "program"]
+    assert "tpuserve_program_stage_seconds_total" not in manifest
+    clock, seen = CompileClock(), set()
+    name = 'mo"del\\weird'
+    for program, outcome in (("prefill", "hit"), ("prefill", "hit"),
+                             ("segment", "miss")):
+        clock.open(name, program, {"bucket": len(clock.entries)},
+                   seen=seen).entry.update(outcome=outcome, launch_s=6.0)
+    clock.open("resnet18", "predict", {"bucket": [4]},
+               seen=seen).entry["launch_s"] = 2.0
+    runner = DeviceRunner()
+    try:
+        engine = SimpleNamespace(runner=runner, cold_start_seconds=1.0,
+                                 clock=clock, models={})
+        text = MetricsHub().render_prometheus(engine)
+    finally:
+        runner.shutdown()
+    assert mod.check(text, mod.load_manifest()) == []
+    esc = name.replace("\\", "\\\\").replace('"', '\\"')
+    assert (f'tpuserve_program_first_uses_total{{model="{esc}",'
+            f'outcome="hit",program="prefill"}} 2') in text
+    assert (f'tpuserve_program_first_uses_total{{model="{esc}",'
+            f'outcome="miss",program="segment"}} 1') in text
+    assert ('tpuserve_program_first_uses_total{model="resnet18",'
+            'outcome="uncached",program="predict"} 1') in text
+    assert "tpuserve_program_stage_seconds_total" not in text
+    assert 'tpuserve_compile_entries{model="resnet18"} 1' in text
+    assert f'tpuserve_model_compile_seconds_total{{model="{esc}"}} 18.0' in text

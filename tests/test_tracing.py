@@ -519,8 +519,7 @@ async def test_round_phases_tile_busy_rounds(aiohttp_client, tmp_path,
     assert sum(v["count"] for v in after["lane_wait"].values()) \
         == after["host_phases"]["round.lane_wait"]["count"]
 
-    wall = covered = 0
-    busy = 0
+    shares = []
     for this, nxt in zip(rounds, rounds[1:]):
         phases = this["phases"]
         names = [p["phase"] for p in phases]
@@ -537,11 +536,58 @@ async def test_round_phases_tile_busy_rounds(aiohttp_client, tmp_path,
             for (_, end), (start, _) in zip(line, line[1:]):
                 assert start >= end, (this["round"], line)
         assert all(t0 <= p["t0_ns"] and p["t1_ns"] <= t1 for p in phases)
-        wall += t1 - t0
-        covered += sum(p["t1_ns"] - p["t0_ns"] for p in phases)
-        busy += 1
-    assert busy >= 3, [r["round"] for r in rounds]
-    assert covered / wall >= floor, (covered / wall, busy)
+        shares.append(sum(p["t1_ns"] - p["t0_ns"] for p in phases)
+                      / (t1 - t0))
+    assert len(shares) >= 3, [r["round"] for r in rounds]
+    # The median round: a thread the machine preempted between two phases
+    # stretches the rounds it struck, in which nothing of the program ran,
+    # and says nothing of what the phases leave uncovered in the others.
+    shares.sort()
+    assert shares[len(shares) // 2] >= floor, shares
+
+
+@pytest.mark.parametrize("kv_cache", ["slot", "paged"])
+async def test_a_first_use_carries_the_round_of_the_launch_that_compiled(
+        aiohttp_client, tmp_path, kv_cache):
+    """Both schedulers book a program's first use through the same scope, the
+    launch phase that held the compile: the entry's ``round`` names a round
+    of the ring with that launch in it, its ``launch_s`` is that phase's own
+    interval, and ``/metrics`` sums the same entries.  The ring itself
+    carries no copy of the entry (ISSUE 42: nothing reads one there)."""
+    cfg = _gen_cfg(tmp_path, kv_cache)
+    cfg.warmup_at_boot = False  # the lane's own programs alone
+    engine = build_engine(cfg)
+    try:
+        client = await aiohttp_client(create_app(cfg, engine=engine))
+        await _generate(client, 1, max_new=4)
+        counters = await _gen_counters(client)
+        r = await client.get("/admin/trace?rounds=256&model=gpt2")
+        rounds = {rnd["round"]: rnd["phases"]
+                  for rnd in (await r.json())["rounds"]["gpt2"]}
+        entries = engine.clock.snapshot()
+    finally:
+        engine.shutdown()
+    launch_of = {"prefill": "prefill.launch", "insert_from": "insert.launch",
+                 "prefill_chunk": "prefill.launch",
+                 "segment": "segment.launch"}
+    want = {"slot": ["prefill", "insert_from", "segment"],
+            "paged": ["prefill_chunk", "segment"]}[kv_cache]
+    assert [e["program"] for e in entries] == want
+    for e in entries:
+        assert (e["model"], e["outcome"], e["cause"]) == ("gpt2", "miss",
+                                                          "first")
+        assert e["trace_s"] > 0 and e["lower_s"] > 0 and e["backend_s"] > 0
+        assert (e["trace_s"] + e["lower_s"] + e["cache_read_s"]
+                + e["backend_s"]) <= e["launch_s"]
+        walls = [(p["t1_ns"] - p["t0_ns"]) / 1e9 for p in rounds[e["round"]]
+                 if p["phase"] == launch_of[e["program"]]]
+        assert e["launch_s"] in walls, (e, walls)
+    assert not any("first_use" in p for ps in rounds.values() for p in ps)
+    programs = counters["programs"]
+    assert programs["first_uses"] == len(want)
+    assert programs["backend_miss_s"] > 0 == programs["backend_hit_s"]
+    assert programs["launch_s"] == pytest.approx(
+        sum(e["launch_s"] for e in entries), abs=1e-5)
 
 
 @pytest.mark.parametrize("kv_cache", ["slot", "paged"])
@@ -653,3 +699,22 @@ def test_cli_tail_trace_and_grep_filters(tmp_path, capsys):
 
     # Missing file is a clean exit code 2, not a traceback.
     assert cli_main(["tail", str(tmp_path / "nope.log")]) == 2
+
+
+@pytest.mark.parametrize("module", ["serving.acceptors", "serving.fleet",
+                                    "serving.tracing"])
+def test_jax_free_processes_stay_jax_free(module):
+    """The acceptor workers and the fleet router import ``serving/tracing``
+    for its request ids; its scope for the compile listeners
+    (``utils/scope.py``) must not bring jax or the engine with it."""
+    import subprocess
+    import sys
+
+    code = (f"import sys, pytorch_zappa_serverless_tpu.{module}; "
+            "print(sorted(m for m in sys.modules if m == 'jax' "
+            "or m.endswith('.engine.cache')))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, cwd=Path(__file__).resolve().parents[1],
+                         timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
