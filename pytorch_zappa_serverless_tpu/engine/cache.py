@@ -144,12 +144,30 @@ def _stage_ends(event: str, seconds: float, **kw) -> None:
         return
     request, on_thread.request = on_thread.request, None
     try:
-        scope = on_thread.scope
-        use = scope.first_use() if scope is not None else None
+        use = _open_use()
         if use is not None:
             use.book(stage, seconds, request)
     except Exception:  # a fault of the ledger's must never fail a compile
         log.exception("program ledger: dropped a %s event", stage)
+
+
+def _open_use() -> "FirstUse | None":
+    """The first use open on this thread's scope (a scheduler's launch phase
+    begins one at the first thing it hears), or None."""
+    scope = on_thread.scope
+    return scope.first_use() if scope is not None else None
+
+
+def layer_traced() -> None:
+    """The Python of a trunk's layer body ran (models/decoder.py ``_trunk``
+    calls this from inside it, so only while a program is traced): one more
+    ``layer_traces`` for the first use open on the tracing thread."""
+    try:
+        use = _open_use()
+        if use is not None:
+            use.entry["layer_traces"] += 1
+    except Exception:  # as the listeners: never fail a trace
+        log.exception("program ledger: dropped a layer trace")
 
 
 class FirstUse:
@@ -220,15 +238,21 @@ class CompileClock:
     """The ledger of program first uses, for every lane.
 
     One entry for each first use of a jitted program: ``{model, program, key,
-    outcome, cause, compiles, trace_s, lower_s, cache_read_s, backend_s,
-    launch_s, first_run_s, round}``.  ``key`` is what made the program new
-    (prompt bucket, padded batch, the prompt attention's form; for
-    ``:predict`` the bucket).  ``outcome`` is the persistent cache's answer to
-    the compile that took longest: ``hit``, ``miss``, or ``uncached`` where
-    no request used the cache.  ``cause`` says why the lane met a new program:
+    outcome, cause, compiles, layer_traces, trace_s, lower_s, cache_read_s,
+    backend_s, launch_s, first_run_s, round}``.  ``key`` is what made the
+    program new (prompt bucket, padded batch, the prompt attention's form;
+    for ``:predict`` the bucket).  ``outcome`` is the persistent cache's
+    answer to the compile that took longest: ``hit``, ``miss``, or
+    ``uncached`` where no request used the cache.  ``cause`` says why the
+    lane met a new program:
     ``first`` (its first of that kind), ``shape`` (a key new to a kind in
     use: a new bucket or padded batch), ``retrace`` (a key the lane had
     compiled: a jit cache lost or a weak-type flip, never expected).
+    ``layer_traces`` counts how often the Python of a decoder trunk's layer
+    body ran while the program was traced (:func:`layer_traced`): 1 a trunk
+    where every layer called the one traced body, the family's ``layers``
+    where each was traced anew (a layer whose tree or dtypes differ from its
+    neighbours'), 0 for a program with no trunk.
     ``backend_s`` is JAX's ``backend_compile_duration`` less ``cache_read_s``:
     on a hit the hashing of the module for its key and the bookkeeping round
     the read, on a miss XLA's compile.  ``launch_s`` is the wall of the scope
@@ -255,9 +279,9 @@ class CompileClock:
         seen.update((kind, exact))
         entry = {"model": model, "program": program, "key": dict(key),
                  "outcome": "uncached", "cause": cause, "compiles": 0,
-                 "trace_s": 0.0, "lower_s": 0.0, "cache_read_s": 0.0,
-                 "backend_s": 0.0, "launch_s": None, "first_run_s": None,
-                 "round": round}
+                 "layer_traces": 0, "trace_s": 0.0, "lower_s": 0.0,
+                 "cache_read_s": 0.0, "backend_s": 0.0, "launch_s": None,
+                 "first_run_s": None, "round": round}
         with self._lock:
             self.entries.append(entry)
         return FirstUse(entry, t0_ns)

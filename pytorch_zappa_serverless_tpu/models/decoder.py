@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..engine.cache import layer_traced
 from ..ops import decode_attention
 from ..ops.flash_attention import masked_attention as _attn
 from ..ops.flash_attention import prompt_attend, prompt_form
@@ -82,8 +83,9 @@ class Rows:
         return None
 
     def settle(self, p, k, v, layer, slots, pos):
-        """After position ``pos`` [S] of ``layer`` was written into ``k``,
-        ``v`` [L, S, T, D]: whatever else the rows keep of it (``p`` is the
+        """After position ``pos`` [S] of ``layer`` (its index, an int32
+        scalar the trunk hands on traced) was written into ``k``, ``v``
+        [L, S, T, D]: whatever else the rows keep of it (``p`` is the
         layer's parameters).  Nothing here."""
         return k, v
 
@@ -91,11 +93,12 @@ class Rows:
         """The prompt attention of a prefill over ``P`` positions of which
         row b holds ``lengths[b]``: ``attend(p, cache, i, q, k, v) ->
         (cache, out [B, P, D])`` leaves in ``cache`` (K and V [L, B, T, D],
-        zeros at first) the rows of layer ``i`` that a decode step will
-        read, and returns the attention output (``p``: the layer's
-        parameters).  Here causal and ragged in one softmax over ``[P, P]``
-        scores (ops/flash_attention.prompt_attend keeps them on the chip
-        where it can), and the rows are the prompt's own K and V."""
+        zeros at first) the rows of layer ``i`` (an index as data: a traced
+        int32 scalar) that a decode step will read, and returns the
+        attention output (``p``: the layer's parameters).  Here causal and
+        ragged in one softmax over ``[P, P]`` scores
+        (ops/flash_attention.prompt_attend keeps them on the chip where it
+        can), and the rows are the prompt's own K and V."""
         def attend(p, cache, i, q, k, v):
             ck = cache[0].at[i, :, :P].set(k)
             return ((ck, cache[1].at[i, :, :P].set(v)),
@@ -155,7 +158,9 @@ class SlotPool(NamedTuple):
     """A row a slot: ``k``, ``v`` [L, S, T, D].  ``slots`` is ``arange(S)``,
     made where the pool is (:func:`slot_pool`), outside any scan, and
     ``rows`` how the ``T`` rows hold a slot's positions.  One query a slot:
-    nothing feeds it several."""
+    nothing feeds it several.  ``layer`` is an index as data wherever a
+    method takes one (the trunk hands it on as a traced int32 scalar); so
+    it is for the page table below."""
     k: jax.Array
     v: jax.Array
     slots: jax.Array
@@ -261,41 +266,70 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None):
     """Every layer of the family over ``x`` at the positions ``pos``
     (embedded by the program, which builds its masks after it), then the
     final norm → ``(x, cache)``.
-    ``attend(cache, i, q, k, v) -> (cache, out)`` stores layer ``i``'s K/V
-    however the program caches and returns the attention output.
+    ``attend(p, cache, i, q, k, v) -> (cache, out)`` stores layer ``i``'s K/V
+    however the program caches and returns the attention output; ``p`` is
+    the layer's parameters, ``cache`` a pair of arrays ``(k, v)``.
     ``adapter_idx`` [B] routes each row through its tenant's LoRA slot of
-    ``params["__adapters__"]`` (docs/ADAPTERS.md; 0 = base passthrough)."""
+    ``params["__adapters__"]`` (docs/ADAPTERS.md; 0 = base passthrough).
+
+    The layer is one jitted function of ``(parameters, x, cache, index,
+    lora)`` and every layer calls that same object, so a program traces the
+    family's block once and lowers it once, as one private function with a
+    ``call`` a layer.  XLA inlines the calls before it assigns layouts, so
+    the executable holds the unrolled program's matmuls, kernels and cache
+    writes; it simplifies the one body first, with the layer's weights as
+    that function's parameters, and may fuse the small operations round
+    them otherwise than it did a layer at a time (a reshape straight after
+    a matmul it folds into a copy of the weight: models/evabyte.py keeps
+    one apart, tests/test_aot_tpu_compile.py watches for it).  One trace
+    holds while nothing but those arguments differs between layers: the
+    index is data (``jnp.int32``), what the body closes over (``pos``,
+    ``attend``'s masks and spans, ``adapter_idx``) is the same object at
+    every layer, and every ``layer{i}`` has one tree structure and one
+    dtype a leaf.  A layer that differs is traced again (never wrongly
+    served), and the ledger's ``layer_traces`` says so."""
     stacks = None if adapter_idx is None else params.get("__adapters__")
-    for i in range(fam.layers):
-        def layer_attend(q, k, v, i=i):
+
+    @jax.jit
+    def layer(p, x, cache, i, lora):
+        layer_traced()
+
+        def layer_attend(q, k, v):
             nonlocal cache
-            cache, out = attend(cache, i, q, k, v)
+            cache, out = attend(p, cache, i, q, k, v)
             return out
 
-        x = fam.layer(params[f"layer{i}"], x, layer_attend, pos,
-                      lora=None if stacks is None else stacks.get(f"layer{i}"),
+        x = fam.layer(p, x, layer_attend, pos, lora=lora,
                       lora_idx=adapter_idx)
+        return x, cache
+
+    for i in range(fam.layers):
+        x, cache = layer(
+            params[f"layer{i}"], x, cache, jnp.int32(i),
+            None if stacks is None else stacks.get(f"layer{i}"))
     return fam.norm(params, x), cache
 
 
-def _write_then_attend(fam, params, wpos, span, work=None):
-    """The decode programs' ``attend``: this layer's K/V in for position
-    ``wpos``, then each query over its ``span`` of the layer's rows."""
-    def attend(pool, i, q, k, v):
-        pool = pool.write(i, wpos, k, v, params[f"layer{i}"])
-        return pool, pool.attend(i, q, span, fam.heads, work)
+def _write_then_attend(fam, pool, wpos, span, work=None):
+    """The decode programs' ``attend`` over ``pool``'s layout: this layer's
+    K/V in for position ``wpos``, then each query over its ``span`` of the
+    layer's rows."""
+    def attend(p, cache, i, q, k, v):
+        here = pool._replace(k=cache[0], v=cache[1]).write(i, wpos, k, v, p)
+        return (here.k, here.v), here.attend(i, q, span, fam.heads, work)
 
     return attend
 
 
-def _decode_logits(fam, params, pool, tok, wpos, span, work, dtype,
+def _decode_logits(fam, params, pool, cache, tok, wpos, span, work, dtype,
                    adapter_idx=None):
-    """One token a slot through the trunk → (logits [S, V], pool)."""
+    """One token a slot through the trunk, over ``pool``'s layout holding
+    ``cache`` (k, v) → (logits [S, V], (k, v))."""
     x = _embed(fam, params, tok, wpos, dtype)[:, None, :]
-    x, pool = _trunk(fam, params, x, wpos[:, None], pool,
-                     _write_then_attend(fam, params, wpos, span, work),
-                     adapter_idx)
-    return fam.head(params, x[:, 0]), pool
+    x, cache = _trunk(fam, params, x, wpos[:, None], cache,
+                      _write_then_attend(fam, pool, wpos, span, work),
+                      adapter_idx)
+    return fam.head(params, x[:, 0]), cache
 
 
 def segment_scan(step, cache, tok, pos, t, finished, seg: int, eos_id: int,
@@ -355,10 +389,7 @@ def prefill(fam: Family, params: dict, tokens: jax.Array, lengths: jax.Array,
     cache = (jnp.zeros((fam.layers, B, T, fam.width), dtype),
              jnp.zeros((fam.layers, B, T, fam.width), dtype))
 
-    def attend(cache, i, q, k, v):
-        return prompt(params[f"layer{i}"], cache, i, q, k, v)
-
-    x, cache = _trunk(fam, params, x, pos, cache, attend, adapter_idx)
+    x, cache = _trunk(fam, params, x, pos, cache, prompt, adapter_idx)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return (fam.head(params, last),) + cache
 
@@ -434,14 +465,14 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
         last = jnp.where(finished, -1, last)
         work = decode_attention.step_work(last, T, fam.width,
                                           cache[0].dtype, first)
-        logits, p = _decode_logits(
-            fam, params, pool._replace(k=cache[0], v=cache[1]), tok, wpos,
-            (first, last), work, dtype, adapter_idx)
+        logits, cache = _decode_logits(fam, params, pool, cache, tok, wpos,
+                                       (first, last), work, dtype,
+                                       adapter_idx)
         if seen is not None:
             seen = seen.at[pool.slots, tok].set(True)
             logits = _penalized(logits, seen, repetition_penalty, rep_on)
         nxt = choose(logits, temperature, seeds, t + 1, top_k, top_p)
-        return (p.k, p.v), nxt, seen
+        return cache, nxt, seen
 
     return segment_scan(one, (pool.k, pool.v), tok, pos, step, finished, seg,
                         fam.eos_id, presence)
@@ -517,16 +548,17 @@ def prefill_chunk(fam: Family, params: dict, tokens: jax.Array,
             & (kpos[None, None, :] < lengths[:, None, None]))
     mask_bias = jnp.where(keep, 0.0, -1e9).astype(jnp.float32)[:, None]
 
-    def attend(pool, i, q, k, v):
-        pool = pool.write(i, wpos, k, v)
-        return pool, _attn(q, *pool.view(i), mask_bias, fam.heads)
+    def attend(p, cache, i, q, k, v):
+        here = pool._replace(k=cache[0], v=cache[1]).write(i, wpos, k, v)
+        return (here.k, here.v), _attn(q, *here.view(i), mask_bias, fam.heads)
 
-    x, pool = _trunk(fam, params, x, pos, pool, attend, adapter_idx)
+    x, cache = _trunk(fam, params, x, pos, (pool.k, pool.v), attend,
+                      adapter_idx)
     idx = jnp.clip(lengths - 1 - start, 0, C - 1)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     first = choose(fam.head(params, last), temperature, seeds,
                    jnp.zeros((G,), jnp.int32), top_k, top_p)
-    return first, pool.k, pool.v
+    return (first,) + cache
 
 
 def propose(fam: Family, params: dict, pool, prev: jax.Array, tok: jax.Array,
@@ -557,15 +589,15 @@ def propose(fam: Family, params: dict, pool, prev: jax.Array, tok: jax.Array,
     def sstep(carry, _):
         cache_k, cache_v, cur, pos, t, first = carry
         wpos = jnp.minimum(pos, VT - 1)
-        logits, p = _decode_logits(
-            fam, params, pool._replace(k=cache_k, v=cache_v), cur, wpos,
+        logits, (cache_k, cache_v) = _decode_logits(
+            fam, params, pool, (cache_k, cache_v), cur, wpos,
             pool.span(wpos), None, dtype)
         nxt = choose(logits, temperature, draft_seeds, t + 1, top_k, top_p)
         # Backfill step feeds the pending token next; proposal steps feed
         # the model's own choice.
         prop = jnp.where(finished, fam.eos_id, jnp.where(first, tok, nxt))
         pos_next = jnp.where(finished, pos, pos + 1)
-        return ((p.k, p.v, prop, pos_next, jnp.where(first, t, t + 1),
+        return ((cache_k, cache_v, prop, pos_next, jnp.where(first, t, t + 1),
                  jnp.zeros_like(first)), (prop, logits))
 
     init = (pool.k, pool.v, prev, jnp.maximum(pos - 1, 0), step,
@@ -596,10 +628,10 @@ def verify(fam: Family, params: dict, pool, toks: jax.Array, pos: jax.Array,
     p = pos[:, None] + jnp.arange(K1)[None, :]
     wp = jnp.minimum(p, pool.positions - 1)
     x = _embed(fam, params, toks, wp, dtype)
-    x, pool = _trunk(fam, params, x, wp, pool,
-                     _write_then_attend(fam, params, wp, pool.span(wp)))
+    x, cache = _trunk(fam, params, x, wp, (pool.k, pool.v),
+                      _write_then_attend(fam, pool, wp, pool.span(wp)))
     logits = fam.head(params, x.reshape(S * K1, -1)).reshape(S, K1, -1)
-    return logits, pool.k, pool.v
+    return (logits,) + cache
 
 
 # ---------------------------------------------------------------------------

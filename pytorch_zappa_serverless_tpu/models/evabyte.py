@@ -103,8 +103,19 @@ def _layer(p, x, cfg: EvaByteConfig, attend, pos):
         # analysis, 1.1 GB so (PERF.md section 6, PR 35).
         p, x = jax.lax.optimization_barrier((p, x))
     h = _norm(p["n1"], x, cfg.rms_norm_eps, dt)
-    q = _rope(h @ p["q"], pos, cfg.heads, cfg.rope_theta)
-    k = _rope(h @ p["k"], pos, cfg.heads, cfg.rope_theta)
+    q, k = h @ p["q"], h @ p["k"]
+    if x.shape[1] == 1:
+        # A decode step: the projections are whole before they are split
+        # into heads.  The trunk calls this block as one function of its
+        # weights, and XLA simplifies a function called from several sites
+        # before it inlines it: with nothing between the matmul and
+        # ``_rope``'s reshape it folds the reshape into a transposed copy of
+        # the weight, which after inlining is 32 copies of 33.5 MB a segment
+        # launch and 1.1 GB of temporaries (compiled for a described v5e:
+        # PERF.md section 6, PR 43).
+        q, k = jax.lax.optimization_barrier((q, k))
+    q = _rope(q, pos, cfg.heads, cfg.rope_theta)
+    k = _rope(k, pos, cfg.heads, cfg.rope_theta)
     a = attend(q, k, h @ p["v"]).astype(dt)
     x = x + (a @ p["o"]).astype(jnp.float32)
     n = _norm(p["n2"], x, cfg.rms_norm_eps, dt)

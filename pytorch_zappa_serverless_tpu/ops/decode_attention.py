@@ -18,8 +18,10 @@ exact rows) says where its span starts, and the blocks before it are no more
 visited than those after it.
 
 The blocks come straight out of the 4-D pool through the BlockSpec index
-maps (layer static, slot and block read from the list), so nothing
-``[S, T, D]``-sized is sliced, copied or transposed.  The grid is
+maps (the layer a prefetched scalar, slot and block read from the list), so
+nothing ``[S, T, D]``-sized is sliced, copied or transposed, and one traced
+call serves every layer: the layer's index is data (models/decoder.py's
+trunk traces its layer once a program).  The grid is
 one-dimensional and its bound is the list's count, a value of the step: a
 call costs a constant plus a term in live blocks.  (A single grid step that
 walks the list with its own double-buffered copies measured the same on the
@@ -121,8 +123,9 @@ def work_list(wpos, total: int, block_t: int, first=None):
             ends[-1].astype(jnp.int32))
 
 
-def _kernel(slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref, v_ref,
-            o_ref, m_ref, l_ref, acc_ref, *, block_t: int, head_dim: int):
+def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
+            v_ref, o_ref, m_ref, l_ref, acc_ref, *, block_t: int,
+            head_dim: int):
     i = pl.program_id(0)
     b = block_ref[i]
     last = wpos_ref[slot_ref[i]]
@@ -170,17 +173,17 @@ def _kernel(slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref, v_ref,
             axis=0, keepdims=True).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("layer", "heads", "block_t",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("heads", "block_t", "interpret"))
 def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
-                     layer: int, heads: int, block_t: int | None = None,
+                     layer, heads: int, block_t: int | None = None,
                      interpret: bool = False):
-    """q [S, D] (already scaled), cache_k / cache_v [L, S, T, D], wpos [S]
-    int32 the last position each slot may read (``wpos < T``; negative: the
-    slot is dead, read nowhere, its row zeros), ``first`` [S] the first
-    (None: 0) → [S, D].  ``work`` is :func:`work_list` of the same
-    ``wpos``, ``first`` and block length, from a caller that builds it once
-    for many layers."""
+    """q [S, D] (already scaled), cache_k / cache_v [L, S, T, D], ``layer``
+    which of the pool to read (an int32 scalar, traced or not: it is data,
+    prefetched beside the work list), wpos [S] int32 the last position each
+    slot may read (``wpos < T``; negative: the slot is dead, read nowhere,
+    its row zeros), ``first`` [S] the first (None: 0) → [S, D].  ``work``
+    is :func:`work_list` of the same ``wpos``, ``first`` and block length,
+    from a caller that builds it once for many layers."""
     S, D = q.shape
     T = cache_k.shape[2]
     bt = block_t or pick_block_t(T, D, cache_k.dtype)
@@ -191,17 +194,20 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
     slot, block, count = (work_list(wpos, T, bt, first) if work is None
                           else work)
     first = _first_row(first, wpos)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     rows = -(-heads // 16) * 16  # the bf16 sublane tile
     kv_spec = pl.BlockSpec(
         (None, None, bt, D),
-        lambda i, slot, block, wpos, first: (layer, slot[i], block[i], 0))
+        lambda i, layer, slot, block, wpos, first:
+        (layer[0], slot[i], block[i], 0))
     row_spec = pl.BlockSpec(
-        (None, 1, D), lambda i, slot, block, wpos, first: (slot[i], 0, 0))
+        (None, 1, D),
+        lambda i, layer, slot, block, wpos, first: (slot[i], 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, block_t=bt, head_dim=D // heads),
         out_shape=jax.ShapeDtypeStruct((S, 1, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(count,),  # a dynamic bound: the live blocks and no more
             in_specs=[row_spec, kv_spec, kv_spec],
             out_specs=row_spec,
@@ -212,7 +218,7 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="decode_attention",
-    )(slot, block, wpos, first, q[:, None, :], cache_k, cache_v)
+    )(layer, slot, block, wpos, first, q[:, None, :], cache_k, cache_v)
     # No grid step visits a dead slot, so nothing wrote its row.
     return jnp.where((wpos >= 0)[:, None], out[:, 0, :], 0)
 
@@ -251,7 +257,8 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None, first=None):
 
     q [S, Tq, D] (a slot's one query, or the K+1 of a speculative verify),
     cache_k / cache_v [L, S, T, D] the whole pool in its own layout (``D``
-    minor, no head split) and ``layer`` which of it to read, wpos [S, Tq]
+    minor, no head split) and ``layer`` which of it to read (an int, or a
+    traced int32 scalar), wpos [S, Tq]
     the last position each query may read → [S, Tq, D].  A negative
     ``wpos`` marks a *dead* query (a finished or empty slot): it reads
     nothing and its output row is zeros, whatever its row of the pool

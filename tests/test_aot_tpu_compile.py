@@ -25,7 +25,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
-from pytorch_zappa_serverless_tpu.models import gpt2
+from pytorch_zappa_serverless_tpu.models import decoder, evabyte, gpt2
 from pytorch_zappa_serverless_tpu.ops import (
     decode_attention as decode_attention_module)
 from pytorch_zappa_serverless_tpu.ops.decode_attention import (
@@ -175,6 +175,157 @@ def test_decode_attention_compiles_for_v5e(one_chip, pool):
     # The pool is the kernel's operand as it lies: nothing as large as one
     # layer of it is sliced or copied on the way in.
     assert not chip_smoke.pool_sized_moves(text, slots * total * d)
+
+
+def test_decode_attention_takes_its_layer_as_data_on_v5e(one_chip):
+    """The layer is an argument of the compiled program (a prefetched scalar
+    the index maps read), so one compile reaches every layer of a 3-layer
+    pool; still the kernel, and still nothing pool-sized moved."""
+    layers, slots, total, d, heads = 3, 8, 960, 1600, 25
+    bt = pick_block_t(total, d, jnp.bfloat16)
+    text = _compile(
+        lambda q, ck, cv, wpos, layer: decode_attention(
+            q, ck, cv, wpos, layer=layer, heads=heads, block_t=bt),
+        one_chip,
+        ((slots, d), jnp.bfloat16), ((layers, slots, total, d), jnp.bfloat16),
+        ((layers, slots, total, d), jnp.bfloat16), ((slots,), jnp.int32),
+        ((), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert not chip_smoke.pool_sized_moves(text, slots * total * d)
+
+
+def _kinds(hlo_text: str) -> dict:
+    """Operations of an optimised HLO module outside fused computations,
+    counted by kind; a fusion by the name the compiler gave it."""
+    import collections
+    import re
+
+    fused = set(re.findall(r"\bcalls=%?([\w.\-]+)", hlo_text))
+    head = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+    inst = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?\s([\w\-]+)\(")
+    counts, comp = collections.Counter(), ""
+    for line in hlo_text.splitlines():
+        m = head.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = inst.match(line)
+        if m and comp not in fused:
+            name, op = m.groups()
+            counts[re.sub(r"[.\d]+$", "", name) if op == "fusion"
+                   else op] += 1
+    return dict(counts)
+
+
+# What carries a step's bytes and arithmetic, by opcode over the whole module
+# (inside fused computations too, where a matmul is a ``convolution``): the
+# matmuls, the Mosaic kernels, the cache writes in place, the scan, the
+# sampler's sort.
+_HEAVY = ("convolution", "custom-call", "dynamic-update-slice", "scatter",
+          "while", "conditional", "sort")
+
+
+def _heavy(hlo_text: str) -> dict:
+    return {op: hlo_text.count(f" {op}(") for op in _HEAVY}
+
+
+@pytest.mark.parametrize("program", ["prefill", "segment"])
+def test_shared_layer_compiles_to_the_loop_s_program_on_v5e(
+        one_chip, monkeypatch, program):
+    """Four layers at GPT-2 XL's widths, with the kernels a chip would take
+    (the pickers ask the backend, which is the CPU here, so the test steers
+    them): the trunk that calls one traced layer and the Python loop it
+    replaced (tests/test_trunk.py keeps it) compile to one program.  XLA
+    inlines the calls and folds the layer's index, so no ``call`` is left
+    and the prefill's operations are the loop's kind for kind.  The segment
+    is held to that in what carries its bytes (``_HEAVY``), in what it moves
+    and in its temporaries: XLA simplifies a function called from several
+    sites before it inlines it, and there it merges the mean that ``_ln``
+    and ``jnp.var`` each compute of one row (three operations of ``[8]``
+    floats fewer a layer norm) and fuses the small operations round the
+    matmuls otherwise (PERF.md section 6, PR 43)."""
+    from test_trunk import loop_trunk
+
+    cfg = gpt2.GPT2Config(d_model=1600, layers=4, heads=25, ffn_dim=6400)
+    monkeypatch.setattr(
+        decode_attention_module, "_kernel_block",
+        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
+                                     if Tq == 1 else None))
+    monkeypatch.setattr(flash_attention_module, "prompt_form",
+                        lambda *shape: "kernel")
+
+    def compiled(calls):
+        if program == "prefill":
+            fn, args = chip_smoke.prefill_program(cfg, 4, 768, 960, one_chip)
+        else:
+            fn, args = chip_smoke.segment_program(cfg, 8, 960, one_chip)
+        lowered = fn.lower(*args)
+        assert lowered.as_text().count("call @layer(") == calls
+        done = lowered.compile()
+        return done.as_text(), done.memory_analysis().temp_size_in_bytes
+
+    shared, shared_temp = compiled(cfg.layers)
+    monkeypatch.setattr(decoder, "_trunk", loop_trunk)
+    loop, loop_temp = compiled(0)
+    assert " call(" not in shared  # inlined: nothing is left a call
+    assert shared.count("tpu_custom_call") == loop.count("tpu_custom_call") \
+        >= cfg.layers
+    assert _heavy(shared) == _heavy(loop)
+    if program == "prefill":
+        assert _kinds(shared) == _kinds(loop)
+    else:
+        assert sum(_kinds(shared).values()) <= sum(_kinds(loop).values())
+        # Nothing as large as a layer of the pool, or as a weight, is moved
+        # that the loop's program does not move.
+        for elements in (8 * 960 * 1600, 1600 * 1600):
+            assert len(chip_smoke.pool_sized_moves(shared, elements)) \
+                <= len(chip_smoke.pool_sized_moves(loop, elements))
+    assert abs(shared_temp - loop_temp) <= 0.01 * loop_temp
+
+
+def test_evabyte_segment_copies_no_weight_on_v5e(one_chip, monkeypatch):
+    """Two layers of EvaByte at the published widths, the 8-slot segment:
+    the trunk hands the block its weights as the arguments of a function
+    called twice, which XLA simplifies before it inlines it, and a reshape
+    folded into a weight there comes out as a copy of the weight a launch
+    (33.5 MB each for ``q`` and ``k``: 136 MB of temporaries at two layers
+    before ``models/evabyte.py`` kept the projections whole).  The program's
+    temporaries stay under one such matrix."""
+    cfg = evabyte.EvaByteConfig(layers=2, eos_id=320)
+    D, F, slots = cfg.hidden_size, cfg.intermediate_size, 8
+    monkeypatch.setattr(
+        decode_attention_module, "_kernel_block",
+        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
+                                     if Tq == 1 else None))
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def vec():
+        return sd(D, dtype=jnp.float32)
+
+    params = {"embed": sd(cfg.vocab_size, D), "norm": vec(),
+              "head": sd(D, cfg.vocab_size * cfg.num_pred_heads)}
+    for i in range(cfg.layers):
+        params[f"layer{i}"] = {
+            "n1": vec(), "n2": vec(), "mu": vec(), "phi": vec(),
+            "q": sd(D, D), "k": sd(D, D), "v": sd(D, D), "o": sd(D, D),
+            "gate": sd(D, F), "up": sd(D, F), "down": sd(F, D)}
+    fam = evabyte.family(cfg, evabyte.TwoTier(
+        cfg.window_size, cfg.chunk_size, cfg.heads, 64))
+    pool = sd(cfg.layers, slots, fam.rows.count(12288 + 768), D)
+    i32, f32 = sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.float32)
+    segment = jax.jit(
+        lambda p, ck, cv, tok, pos, st, fin, temp, seeds, topk, topp:
+        decoder.decode_segment(fam, p, decoder.slot_pool(ck, cv, fam.rows),
+                               tok, pos, st, fin, temp, seeds, 8,
+                               jnp.bfloat16, top_k=topk, top_p=topp),
+        donate_argnums=(1, 2))
+    compiled = segment.lower(params, pool, pool, i32, i32, i32,
+                             sd(slots, dtype=jnp.bool_), f32, i32, i32,
+                             f32).compile()
+    assert compiled.as_text().count("tpu_custom_call") == cfg.layers
+    assert compiled.memory_analysis().temp_size_in_bytes < D * D * 2
 
 
 def test_decode_segment_compiles_for_v5e_with_one_work_list_a_step(

@@ -123,8 +123,8 @@ def test_compile_clock_per_model_totals():
 # -- the ledger: a first use's stages, heard from inside jax -------------------
 
 ENTRY_KEYS = {"model", "program", "key", "outcome", "cause", "compiles",
-              "trace_s", "lower_s", "cache_read_s", "backend_s", "launch_s",
-              "first_run_s", "round"}
+              "layer_traces", "trace_s", "lower_s", "cache_read_s",
+              "backend_s", "launch_s", "first_run_s", "round"}
 
 
 def _first_use(clock, fn, *args, key=None, seen=None):

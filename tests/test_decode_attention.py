@@ -106,6 +106,31 @@ def test_matches_float32_reference(D, heads, dtype, impl):
 
 
 @pytest.mark.parametrize("impl", ["jnp", "kernel"])
+def test_one_trace_reads_whichever_layer_it_is_handed(impl):
+    """The layer is data: one jitted call, traced once, reaches every layer
+    of a 3-layer pool (the kernel through a prefetched scalar its index maps
+    read), and each answer is that layer's."""
+    D, heads, S = 1280, 20, len(POS)
+    rng = np.random.default_rng(9)
+    two, more = (_pool(rng, S, D, jnp.float32) for _ in range(2))
+    ck, cv = (jnp.concatenate([a, b[:1]]) for a, b in zip(two[:2], more[:2]))
+    wpos = two[2]
+    q = jnp.asarray(rng.standard_normal((S, 1, D)), jnp.float32)
+    traces = []
+
+    @jax.jit
+    def run(layer):
+        traces.append(layer)
+        return _run(impl, q, ck, cv, layer, wpos, heads)
+
+    for layer in range(3):
+        want = _reference(q, ck[layer], cv[layer], wpos[:, None], heads)
+        np.testing.assert_allclose(np.asarray(run(jnp.int32(layer))),
+                                   np.asarray(want), atol=2e-5, rtol=2e-5)
+    assert len(traces) == 1 and ck.shape[0] == 3
+
+
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("D,heads", WIDTHS, ids=["xl", "large"])
@@ -375,7 +400,8 @@ def test_segment_with_finished_slots_same_tokens_by_kernel_and_jnp(
 def test_segment_builds_one_work_list_a_step(monkeypatch):
     """The list of live blocks is built once a step and shared by every
     layer's kernel call: one ``work_list`` while the scan's body is traced,
-    ``layers`` ``pallas_call``s."""
+    and the kernel inside the one traced layer that is called ``layers``
+    times with its index as data."""
     _through_kernel(monkeypatch)
     built = []
     monkeypatch.setattr(DA, "work_list",
@@ -392,7 +418,7 @@ def test_segment_builds_one_work_list_a_step(monkeypatch):
             zeros, zeros > 0, jnp.zeros((S,)), zeros, 4,
             jnp.float32))(pool, pool))
     assert built == [(T, BT)]
-    assert text.count("pallas_call[") == cfg.layers
+    assert "pallas_call[" in text and text.count("name=layer") == cfg.layers
 
 
 @pytest.mark.parametrize("total,d,dtype,block,fits", [
