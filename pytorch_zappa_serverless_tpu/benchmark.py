@@ -434,12 +434,11 @@ def _scan_correct_decode(cost: dict, servable, batch: int, max_new: int):
     cont = servable.meta.get("continuous")
     if not cont:
         return
-    L, _, total, D = cont["cache_shape"]
-    dt = cont["cache_dtype"]
+    (L, _, total, D), dt = cont["cache_leaves"][0]
     segment = cont["segment"]
 
     def body(p, st):
-        return segment(p, st["cache_k"], st["cache_v"], st["tok"], st["pos"],
+        return segment(p, (st["cache_k"], st["cache_v"]), st["tok"], st["pos"],
                        st["step"], st["fin"], st["temp"], st["seed"],
                        st["topk"], st["topp"])[0]
 

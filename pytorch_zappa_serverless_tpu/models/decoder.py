@@ -101,7 +101,7 @@ class Rows:
         can), and the rows are the prompt's own K and V."""
         def attend(p, cache, i, q, k, v):
             ck = cache[0].at[i, :, :P].set(k)
-            return ((ck, cache[1].at[i, :, :P].set(v)),
+            return ((ck, cache[1].at[i, :, :P].set(v)) + cache[2:],
                     prompt_attend(q, k, v, lengths, heads))
 
         return attend
@@ -133,6 +133,18 @@ class Family:
     ``rows`` decode rows, for a family that holds more than one tree.
     ``rows`` says how a slot's cache rows hold its positions (:class:`Rows`:
     a row a position unless the family brings its own).
+
+    What a slot keeps (:func:`cache_leaves`): K and V rows ``width`` wide in
+    ``kv_layers`` of the layers (None: in all), and whatever ``state``
+    declares after them, ``(layers that hold it, shape a slot, dtype)`` a
+    leaf.  ``cache_index(i)`` is where layer ``i`` finds its own part of
+    those leaves (the trunk hands it on as data); ``kv_heads`` says that the
+    ``heads`` queries share fewer K/V heads (``width`` is then the K/V
+    heads' and the query block is wider; None: a K/V head a query head).  A
+    family with ``state`` or ``counters`` has its layer called with two more
+    keywords, ``state=`` and ``count=`` (:func:`_trunk`); ``counters`` says,
+    ``(name, what it counts)`` each, the int32 counts a decode step sums
+    (:func:`segment_scan`).
     """
     embed: Callable
     positions: Callable | None
@@ -148,6 +160,19 @@ class Family:
     pre_tree: Callable = lambda p: p
     dec_tree: Callable = lambda p, rows: p
     rows: Rows = ROWS
+    kv_layers: int | None = None
+    kv_heads: int | None = None
+    state: tuple = ()
+    cache_index: Callable = lambda i: i
+    counters: tuple = ()
+
+
+def cache_leaves(fam: Family, slots: int, T: int, dtype) -> tuple:
+    """``(shape, dtype)`` of every leaf of a cache of ``slots`` slots of
+    ``T`` rows, the slot axis second: K, V, then the family's state."""
+    kv = ((fam.kv_layers or fam.layers, slots, T, fam.width), dtype)
+    return (kv, kv) + tuple(((n, slots, *shape), dt)
+                            for n, shape, dt in fam.state)
 
 
 # ---------------------------------------------------------------------------
@@ -262,15 +287,27 @@ def _embed(fam: Family, params, tokens, pos, dtype, clamp=True):
                      else pos]
 
 
-def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None):
+def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
+           lengths=None):
     """Every layer of the family over ``x`` at the positions ``pos``
     (embedded by the program, which builds its masks after it), then the
-    final norm → ``(x, cache)``.
+    final norm → ``(x, cache, counts)``.
     ``attend(p, cache, i, q, k, v) -> (cache, out)`` stores layer ``i``'s K/V
     however the program caches and returns the attention output; ``p`` is
-    the layer's parameters, ``cache`` a pair of arrays ``(k, v)``.
+    the layer's parameters, ``cache`` the tuple of leaves (K, V, then the
+    family's state: :func:`cache_leaves`) and ``i`` the layer's index into
+    them (``fam.cache_index``).
     ``adapter_idx`` [B] routes each row through its tenant's LoRA slot of
     ``params["__adapters__"]`` (docs/ADAPTERS.md; 0 = base passthrough).
+
+    A family that declares state gets it as it gets ``attend``, from the
+    program: its layer is called with ``state=``, and ``state(update)``
+    runs ``update(mine, lengths) -> (mine, out)`` over the layer's own part
+    of every leaf after K and V (``leaf[i]``: zeros in a prefill, whose
+    ``lengths`` [B] say how much of each prompt is real; the slots' own in a
+    decode step, where ``lengths`` is None) and writes what comes back in
+    its place.  ``count=`` takes the layer's int32 counts (``fam.counters``);
+    their sum over the layers is ``counts`` (None where nothing counted).
 
     The layer is one jitted function of ``(parameters, x, cache, index,
     lora)`` and every layer calls that same object, so a program traces the
@@ -287,27 +324,45 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None):
     ``attend``'s masks and spans, ``adapter_idx``) is the same object at
     every layer, and every ``layer{i}`` has one tree structure and one
     dtype a leaf.  A layer that differs is traced again (never wrongly
-    served), and the ledger's ``layer_traces`` says so."""
+    served), and the ledger's ``layer_traces`` says so: a family of three
+    kinds of layer (models/nemotron_h.py) is three traces."""
     stacks = None if adapter_idx is None else params.get("__adapters__")
+    hooked = bool(fam.state or fam.counters)
 
     @jax.jit
     def layer(p, x, cache, i, lora):
         layer_traced()
+        counts = None
 
         def layer_attend(q, k, v):
             nonlocal cache
             cache, out = attend(p, cache, i, q, k, v)
             return out
 
-        x = fam.layer(p, x, layer_attend, pos, lora=lora,
-                      lora_idx=adapter_idx)
-        return x, cache
+        def layer_state(update):
+            nonlocal cache
+            mine, out = update(tuple(leaf[i] for leaf in cache[2:]), lengths)
+            cache = cache[:2] + tuple(
+                leaf.at[i].set(m) for leaf, m in zip(cache[2:], mine))
+            return out
 
+        def layer_count(c):
+            nonlocal counts
+            counts = c
+
+        hooks = {"state": layer_state, "count": layer_count} if hooked else {}
+        x = fam.layer(p, x, layer_attend, pos, lora=lora,
+                      lora_idx=adapter_idx, **hooks)
+        return x, cache, counts
+
+    counts = None
     for i in range(fam.layers):
-        x, cache = layer(
-            params[f"layer{i}"], x, cache, jnp.int32(i),
+        x, cache, c = layer(
+            params[f"layer{i}"], x, cache, jnp.int32(fam.cache_index(i)),
             None if stacks is None else stacks.get(f"layer{i}"))
-    return fam.norm(params, x), cache
+        if c is not None:
+            counts = c if counts is None else counts + c
+    return fam.norm(params, x), cache, counts
 
 
 def _write_then_attend(fam, pool, wpos, span, work=None):
@@ -316,7 +371,8 @@ def _write_then_attend(fam, pool, wpos, span, work=None):
     layer's rows."""
     def attend(p, cache, i, q, k, v):
         here = pool._replace(k=cache[0], v=cache[1]).write(i, wpos, k, v, p)
-        return (here.k, here.v), here.attend(i, q, span, fam.heads, work)
+        return ((here.k, here.v) + cache[2:],
+                here.attend(i, q, span, fam.heads, work))
 
     return attend
 
@@ -324,16 +380,16 @@ def _write_then_attend(fam, pool, wpos, span, work=None):
 def _decode_logits(fam, params, pool, cache, tok, wpos, span, work, dtype,
                    adapter_idx=None):
     """One token a slot through the trunk, over ``pool``'s layout holding
-    ``cache`` (k, v) → (logits [S, V], (k, v))."""
+    ``cache`` (its leaves) → (logits [S, V], cache, counts)."""
     x = _embed(fam, params, tok, wpos, dtype)[:, None, :]
-    x, cache = _trunk(fam, params, x, wpos[:, None], cache,
-                      _write_then_attend(fam, pool, wpos, span, work),
-                      adapter_idx)
-    return fam.head(params, x[:, 0]), cache
+    x, cache, counts = _trunk(fam, params, x, wpos[:, None], cache,
+                              _write_then_attend(fam, pool, wpos, span, work),
+                              adapter_idx)
+    return fam.head(params, x[:, 0]), cache, counts
 
 
 def segment_scan(step, cache, tok, pos, t, finished, seg: int, eos_id: int,
-                 seen=None):
+                 seen=None, counters: int = 0):
     """``seg`` steps of ``step`` over every slot, under the emit and finish
     rules every streaming decoder here shares.
 
@@ -347,23 +403,28 @@ def segment_scan(step, cache, tok, pos, t, finished, seg: int, eos_id: int,
     ``step(cache, tok, pos, t, finished, seen) -> (cache, nxt, seen)`` is the
     model: it feeds ``tok`` at ``pos`` and decides the next token (drawing
     with ``t + 1``); ``cache`` is whatever pytree it threads, ``seen`` the
-    fixed-batch lane's seen-token mask (None elsewhere).  Returns ``(emits
-    [S, seg], *cache's leaves, tok, pos, t, finished)``, the scheduler's
-    segment contract.
+    fixed-batch lane's seen-token mask (None elsewhere).  A model that
+    counts returns a fourth thing, ``counters`` int32 counts of the step,
+    which are summed over the segment.  Returns ``(emits [S, seg], *cache's
+    leaves, tok, pos, t, finished)`` and, where ``counters``, those sums
+    [counters] last: the scheduler's segment contract.
     """
     def body(carry, _):
-        cache, tok, pos, t, finished, seen = carry
-        cache, nxt, seen = step(cache, tok, pos, t, finished, seen)
+        cache, tok, pos, t, finished, seen, tally = carry
+        cache, nxt, seen, *counts = step(cache, tok, pos, t, finished, seen)
+        if counters:
+            tally = tally + counts[0]
         emit = jnp.where(finished, eos_id, tok)
         fin = finished | (tok == eos_id)
         tok_next = jnp.where(fin, eos_id, nxt)
         pos_next = jnp.where(fin, pos, pos + 1)
-        return (cache, tok_next, pos_next, t + 1, fin, seen), emit
+        return (cache, tok_next, pos_next, t + 1, fin, seen, tally), emit
 
-    carry, emits = jax.lax.scan(body, (cache, tok, pos, t, finished, seen),
-                                None, length=seg)
+    tally = jnp.zeros((counters,), jnp.int32) if counters else None
+    carry, emits = jax.lax.scan(
+        body, (cache, tok, pos, t, finished, seen, tally), None, length=seg)
     return (jnp.transpose(emits, (1, 0)), *jax.tree.leaves(carry[0]),
-            *carry[1:5])
+            *carry[1:5], *([carry[6]] if counters else []))
 
 
 # ---------------------------------------------------------------------------
@@ -376,20 +437,23 @@ def prefill(fam: Family, params: dict, tokens: jax.Array, lengths: jax.Array,
 
     tokens [B, P] int32 (zero-padded), lengths [B] int32, ``total`` the
     positions the cache is for (P + max_new).  Returns (logits [B, V] at
-    position length-1, cache_k, cache_v [L, B, T, D]): rows for a slot pool
-    (``T`` rows hold ``total`` positions as the family's ``rows`` lay them
-    out), made here from nothing, so each layer attends its own fresh K/V
-    (``Rows.prompt``) and leaves the rows a decode step will read.
+    position length-1, *the cache's leaves): rows for a slot pool, K and V
+    [L, B, T, D] (``T`` rows hold ``total`` positions as the family's
+    ``rows`` lay them out) and whatever state the family declares
+    (:func:`cache_leaves`), made here from nothing, so each layer attends
+    its own fresh K/V (``Rows.prompt``), starts its state from zeros, and
+    leaves what a decode step will read.
     """
     B, P = tokens.shape
     pos = jnp.arange(P)
     x = _embed(fam, params, tokens, pos, dtype, clamp=False)
     T = fam.rows.count(total)
     prompt = fam.rows.prompt(fam.heads, lengths, P)
-    cache = (jnp.zeros((fam.layers, B, T, fam.width), dtype),
-             jnp.zeros((fam.layers, B, T, fam.width), dtype))
+    cache = tuple(jnp.zeros(shape, dt)
+                  for shape, dt in cache_leaves(fam, B, T, dtype))
 
-    x, cache = _trunk(fam, params, x, pos, cache, prompt, adapter_idx)
+    x, cache, _ = _trunk(fam, params, x, pos, cache, prompt, adapter_idx,
+                         lengths)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return (fam.head(params, last),) + cache
 
@@ -414,23 +478,24 @@ def prefill_start(fam: Family, params: dict, tokens: jax.Array,
     The same prefill as :func:`generate` (so the token chain is
     bit-identical to the fixed-batch path), returned raw so the scheduler
     can insert the cache rows into its slot pool.  Returns (first_tok [B],
-    cache_k, cache_v [L, B, total, D]).
+    *the cache's leaves), K and V [L, B, T, D] first.
     """
-    logits, cache_k, cache_v = prefill(fam, params, tokens, lengths, total,
-                                       dtype, adapter_idx=adapter_idx)
+    logits, *cache = prefill(fam, params, tokens, lengths, total, dtype,
+                             adapter_idx=adapter_idx)
     if repetition_penalty is not None:
         logits = _penalized(logits, presence, repetition_penalty,
                             jnp.any(repetition_penalty != 1.0))
     first = choose(logits, temperature, seeds,
                    jnp.zeros(tokens.shape[:1], jnp.int32), top_k, top_p)
-    return first, cache_k, cache_v
+    return (first, *cache)
 
 
 def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
                    pos: jax.Array, step: jax.Array, finished: jax.Array,
                    temperature: jax.Array, seeds: jax.Array, seg: int,
                    dtype=jnp.bfloat16, top_k=None, top_p=None,
-                   repetition_penalty=None, presence=None, adapter_idx=None):
+                   repetition_penalty=None, presence=None, adapter_idx=None,
+                   state=()):
     """Advance every slot of ``pool`` by ``seg`` tokens — the
     continuous-batching program, over either pool.
 
@@ -443,8 +508,12 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
     while membership is dynamic.  Finished and empty slots still compute
     (the price of static shapes), and attention counts them *dead*: it reads
     each layer of the pool where it lies, as far as each live slot has
-    written, from one list of live blocks a step.  Returns (emits [S, seg],
-    cache_k, cache_v, tok, pos, step, finished), as :func:`segment_scan`.
+    written, from one list of live blocks a step.  ``state`` is the pool's
+    leaves after K and V (a slot's state needs no span: a finished slot's
+    goes on changing, nothing reads it, and the insert that re-uses the slot
+    overwrites it whole).  Returns (emits [S, seg], *the cache's leaves,
+    tok, pos, step, finished) and the family's counts, as
+    :func:`segment_scan`.
     """
     total, T = pool.positions, pool.k.shape[2]
     # Repetition penalty (fixed-batch lane only, which is the slot pool —
@@ -463,19 +532,22 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
         # it is dead to attention, which reads nothing of its row.
         first, last = pool.span(wpos)
         last = jnp.where(finished, -1, last)
-        work = decode_attention.step_work(last, T, fam.width,
-                                          cache[0].dtype, first)
-        logits, cache = _decode_logits(fam, params, pool, cache, tok, wpos,
-                                       (first, last), work, dtype,
-                                       adapter_idx)
+        # Grouped queries read the pool in the ``jax.numpy`` form, which
+        # needs no list of blocks.
+        work = None if fam.kv_heads else decode_attention.step_work(
+            last, T, fam.width, cache[0].dtype, first)
+        logits, cache, counts = _decode_logits(
+            fam, params, pool, cache, tok, wpos, (first, last), work, dtype,
+            adapter_idx)
         if seen is not None:
             seen = seen.at[pool.slots, tok].set(True)
             logits = _penalized(logits, seen, repetition_penalty, rep_on)
         nxt = choose(logits, temperature, seeds, t + 1, top_k, top_p)
-        return cache, nxt, seen
+        return (cache, nxt, seen, *([counts] if fam.counters else []))
 
-    return segment_scan(one, (pool.k, pool.v), tok, pos, step, finished, seg,
-                        fam.eos_id, presence)
+    return segment_scan(one, (pool.k, pool.v, *state), tok, pos, step,
+                        finished, seg, fam.eos_id, presence,
+                        len(fam.counters))
 
 
 def generate(fam: Family, params: dict, tokens: jax.Array,
@@ -502,7 +574,7 @@ def generate(fam: Family, params: dict, tokens: jax.Array,
         valid = jnp.arange(P)[None, :] < lengths[:, None]
         presence = jnp.zeros((B, fam.vocab_size), bool).at[
             jnp.arange(B)[:, None], tokens].max(valid)
-    first, cache_k, cache_v = prefill_start(
+    first, cache_k, cache_v, *state = prefill_start(
         fam, fam.pre_tree(params), tokens, lengths, temperature, seeds,
         P + max_new, dtype, top_k=top_k, top_p=top_p, repetition_penalty=repetition_penalty,
         presence=presence, adapter_idx=adapter_idx)
@@ -512,7 +584,7 @@ def generate(fam: Family, params: dict, tokens: jax.Array,
         first, lengths, step, finished,
         temperature, seeds, max_new, dtype, top_k=top_k, top_p=top_p,
         repetition_penalty=repetition_penalty, presence=presence,
-        adapter_idx=adapter_idx)
+        adapter_idx=adapter_idx, state=tuple(state))
     return emits
 
 
@@ -552,8 +624,8 @@ def prefill_chunk(fam: Family, params: dict, tokens: jax.Array,
         here = pool._replace(k=cache[0], v=cache[1]).write(i, wpos, k, v)
         return (here.k, here.v), _attn(q, *here.view(i), mask_bias, fam.heads)
 
-    x, cache = _trunk(fam, params, x, pos, (pool.k, pool.v), attend,
-                      adapter_idx)
+    x, cache, _ = _trunk(fam, params, x, pos, (pool.k, pool.v), attend,
+                         adapter_idx)
     idx = jnp.clip(lengths - 1 - start, 0, C - 1)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     first = choose(fam.head(params, last), temperature, seeds,
@@ -589,7 +661,7 @@ def propose(fam: Family, params: dict, pool, prev: jax.Array, tok: jax.Array,
     def sstep(carry, _):
         cache_k, cache_v, cur, pos, t, first = carry
         wpos = jnp.minimum(pos, VT - 1)
-        logits, (cache_k, cache_v) = _decode_logits(
+        logits, (cache_k, cache_v), _ = _decode_logits(
             fam, params, pool, (cache_k, cache_v), cur, wpos,
             pool.span(wpos), None, dtype)
         nxt = choose(logits, temperature, draft_seeds, t + 1, top_k, top_p)
@@ -628,8 +700,8 @@ def verify(fam: Family, params: dict, pool, toks: jax.Array, pos: jax.Array,
     p = pos[:, None] + jnp.arange(K1)[None, :]
     wp = jnp.minimum(p, pool.positions - 1)
     x = _embed(fam, params, toks, wp, dtype)
-    x, cache = _trunk(fam, params, x, wp, (pool.k, pool.v),
-                      _write_then_attend(fam, pool, wp, pool.span(wp)))
+    x, cache, _ = _trunk(fam, params, x, wp, (pool.k, pool.v),
+                         _write_then_attend(fam, pool, wp, pool.span(wp)))
     logits = fam.head(params, x.reshape(S * K1, -1)).reshape(S, K1, -1)
     return (logits,) + cache
 
@@ -680,15 +752,16 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
             f"{max_new} exceeds the model's max_positions "
             f"({fam.max_positions}); shrink seq_buckets or max_new_tokens")
 
-    paged_ok = type(fam.rows) is Rows
+    paged_ok = type(fam.rows) is Rows and not fam.state
     if not paged_ok and getattr(cfg_model, "kv_cache", "slot") == "paged":
         # A page table holds a row a position and the chunked prefill reads
-        # it back as one: a family whose rows are laid out otherwise has no
-        # paged lane, and says so here rather than serve something else.
+        # it back as one: a family whose rows are laid out otherwise, or
+        # that keeps state which is no row, has no paged lane, and says so
+        # here rather than serve something else.
         raise ValueError(
             f"{name}: kv_cache='paged' cannot serve this family: its cache "
-            f"rows are not a row a position ({type(fam.rows).__name__}); "
-            "use kv_cache='slot'")
+            f"is not a row a position ({type(fam.rows).__name__}, "
+            f"{len(fam.state)} leaves of state); use kv_cache='slot'")
 
     adapters_on = int(getattr(cfg_model, "adapter_slots", 0)) > 0
     if adapters_on:
@@ -810,8 +883,9 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
     # Continuous-batching contract (serving/generation.py): slot-pool decode
     # in `segment_tokens`-step jitted segments with per-request admission via
     # prefill + insert.  gen_slots bounds concurrent generations; the cache
-    # pool is [L, slots, max_seq+max_new, D].  Admission is model-shaped
-    # (whisper admits AUDIO), so the scheduler drives it through the generic
+    # pool is a tuple of leaves, K and V [L, slots, T, D] first.  Admission
+    # is model-shaped (whisper admits AUDIO), so the scheduler drives it
+    # through the generic
     # trio: ``admit_len_of`` (sample -> bucket-size request),
     # ``collate_admit`` (sample + bucket -> batch-1 payload dict; must carry
     # "length" [1] and may carry "temperature"/"seed" [1] for the slot
@@ -846,16 +920,22 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         "admit_len_of": lambda s: int(np.asarray(s["input_ids"]).shape[0]),
         "collate_admit": collate_admit,
         "admit_spec": admit_spec,
-        "cache_shape": (fam.layers, gen_slots, T, fam.width),
-        "cache_dtype": dtype,
-        # Rows decode attention reads a live slot's row in.
-        "read_block": decode_attention.read_block(T, fam.width, dtype),
+        # The pool's leaves, ``(shape, dtype)`` each, the slot axis second:
+        # K, V, then the family's state.
+        "cache_leaves": cache_leaves(fam, gen_slots, T, dtype),
+        "counters": dict(fam.counters),  # name -> what it counts
+        "cache_dtype": dtype,  # of the paged lane's pages
+        # Rows decode attention reads a live slot's row in (grouped queries
+        # read whole rows: the ``jax.numpy`` form).
+        "read_block": (T if fam.kv_heads else
+                       decode_attention.read_block(T, fam.width, dtype)),
         # What the scheduler counts with, in numpy (spans, summaries, the
         # passes of a prompt's attention, prompts a prefill dispatch).
         "rows": fam.rows,
         # The form the prompt attention of a (batch, bucket) prefill takes.
         "prompt_form": lambda batch, bucket: fam.rows.prompt_form(
-            batch, fam.heads, bucket, fam.width // fam.heads),
+            batch, fam.heads, bucket,
+            fam.width // (fam.kv_heads or fam.heads)),
         # Routed lane: admission prefills run on the prefill tree, the
         # slot-pool segment routes on the POOL size (the decode-row count of
         # its program) — consistent with the fixed-batch path at the same
@@ -867,13 +947,14 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
                                   payload["seed"], total, dtype,
                                   top_k=payload["top_k"],
                                   top_p=payload["top_p"])),
-        "segment": (lambda p, ck, cv, tok, pos, st, fin, temp, seeds,
+        "segment": (lambda p, cache, tok, pos, st, fin, temp, seeds,
                     topk, topp:
                     decode_segment(fam, fam.dec_tree(p, gen_slots),
-                                   slot_pool(ck, cv, fam.rows), tok, pos,
+                                   slot_pool(*cache[:2], fam.rows), tok, pos,
                                    st, fin,
                                    temp, seeds, segment_tokens, dtype,
-                                   top_k=topk, top_p=topp)),
+                                   top_k=topk, top_p=topp,
+                                   state=cache[2:])),
         "detokenize": ((lambda toks: tokenizer.decode(toks))
                        if tokenizer is not None else None),
     }

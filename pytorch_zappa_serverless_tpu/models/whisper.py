@@ -659,16 +659,16 @@ def make_whisper_servable(name: str, cfg_model) -> Any:
         "admit_len_of": lambda s: 1,
         "collate_admit": collate_admit,
         "admit_spec": admit_spec,
-        "cache_shape": (cfg.decoder_layers, gen_slots, CL + total_self,
-                        cfg.d_model),
-        "cache_dtype": dtype,
+        # The pool's leaves, K and V: cross rows, then self rows.
+        "cache_leaves": (((cfg.decoder_layers, gen_slots, CL + total_self,
+                           cfg.d_model), dtype),) * 2,
         "prefill": (lambda p, payload: prefill_continuous(
             p, payload["mel"], prompt_ids, total_self, cfg, dtype,
             temperature=payload["temperature"], seeds=payload["seed"],
             top_k=payload["top_k"], top_p=payload["top_p"])),
-        "segment": (lambda p, ck, cv, tok, pos, st, fin, temp, seeds,
+        "segment": (lambda p, cache, tok, pos, st, fin, temp, seeds,
                     topk, topp:
-                    decode_segment(p, ck, cv, tok, pos, st, fin,
+                    decode_segment(p, *cache, tok, pos, st, fin,
                                    segment_tokens, cfg, dtype,
                                    temperature=temp, seeds=seeds,
                                    top_k=topk, top_p=topp)),

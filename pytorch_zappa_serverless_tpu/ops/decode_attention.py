@@ -282,10 +282,18 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None, first=None):
 
     The ``jax.numpy`` form below reads all ``T`` positions, and is what the
     tests compare the kernel with.
+
+    A pool narrower than ``q`` holds fewer K/V heads than there are query
+    heads (``heads // kv_heads`` queries share each): those are read in a
+    ``jax.numpy`` form of their own (:func:`_attend_grouped`), whatever the
+    backend; the kernel takes one K/V head a query head.
     """
     S, Tq, D = q.shape
     dh = D // heads
     q = q * dh ** -0.5
+    if cache_k.shape[-1] != D:
+        return _attend_grouped(q, cache_k[layer], cache_v[layer], wpos,
+                               first, heads)
     bt = _kernel_block(Tq, cache_k.shape[2], D, cache_k.dtype)
     if bt is not None:
         return decode_attention(q[:, 0], cache_k, cache_v, wpos[:, 0], work,
@@ -309,3 +317,24 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None, first=None):
     # Each head keeps its own columns: one non-zero term a column, so exact.
     out = jnp.where(own, out.reshape(S, Tq, heads, D), 0).sum(2)
     return jnp.where((wpos >= 0)[:, :, None], out, 0).astype(q.dtype)
+
+
+def _attend_grouped(q, k, v, wpos, first, heads):
+    """q [S, Tq, heads * dh] (scaled) over one layer's k, v [S, T, kv_heads *
+    dh]: the pool is ``heads // kv_heads`` times narrower than the queries,
+    so splitting it by head moves little, and each K/V head is scored
+    against its group of queries."""
+    S, Tq, D = q.shape
+    T, dh = k.shape[1], D // heads
+    kv = k.shape[-1] // dh
+    qg = q.reshape(S, Tq, kv, heads // kv, dh)
+    scores = jnp.einsum("sqhgd,sthd->sqhgt", qg, k.reshape(S, T, kv, dh),
+                        preferred_element_type=jnp.float32)
+    keep = jnp.arange(T)[None, None, :] <= wpos[:, :, None]     # [S,Tq,T]
+    if first is not None:
+        keep &= jnp.arange(T)[None, None, :] >= first[:, :, None]
+    scores = jnp.where(keep[:, :, None, None, :], scores, -1e9)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("sqhgt,sthd->sqhgd", probs, v.reshape(S, T, kv, dh))
+    return jnp.where((wpos >= 0)[:, :, None], out.reshape(S, Tq, D),
+                     0).astype(q.dtype)

@@ -181,10 +181,9 @@ class LockstepDriver:
         k, tl = state["kernels"], state["timeline"]
         cm = self.engine.models[name]
         with tl.phase("prefill.launch", programs=1, batch=1, bucket=bucket):
-            first, k_row, v_row = k["prefill"](cm.servable.params, payload)
+            first, *rows = k["prefill"](cm.servable.params, payload)
         with tl.phase("insert.launch", programs=1):
-            ck, cv = state["cache"]
-            state["cache"] = k["insert"](ck, cv, k_row, v_row,
+            state["cache"] = k["insert"](state["cache"], tuple(rows),
                                          np.int32(slot))
         with tl.phase("prefill.fetch"):
             np.asarray(first)  # completion fence, mirroring the leader's
@@ -193,12 +192,12 @@ class LockstepDriver:
         state = self._gen_state(name)
         k, tl = state["kernels"], state["timeline"]
         cm = self.engine.models[name]
-        ck, cv = state["cache"]
         with tl.phase("segment.launch", programs=1):
-            packed, ck, cv = k["segment"](
-                cm.servable.params, ck, cv, st["tok"], st["pos"], st["step"],
-                st["fin"], st["temp"], st["seed"], st["topk"], st["topp"])
-            state["cache"] = (ck, cv)
+            packed, *cache = k["segment"](
+                cm.servable.params, state["cache"], st["tok"], st["pos"],
+                st["step"], st["fin"], st["temp"], st["seed"], st["topk"],
+                st["topp"])
+            state["cache"] = tuple(cache)
         with tl.phase("segment.fetch"):
             np.asarray(packed)  # completion fence, mirroring the leader's
 

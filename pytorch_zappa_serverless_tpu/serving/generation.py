@@ -7,8 +7,10 @@ finished row burns full compute for the rest of the scan and a queued
 request waits for the entire batch (VERDICT r2 #2).  This module is the TPU
 answer to both, built so every device program keeps static shapes:
 
-- A fixed pool of ``slots`` decode rows with one shared KV cache
-  ``[L, S, total, D]`` resident on device, advanced by short jitted
+- A fixed pool of ``slots`` decode rows with one shared cache resident on
+  device (a tuple of leaves the model declares, each with the slot axis
+  second: K and V ``[L, S, T, D]``, then whatever state a slot keeps that
+  is no row a position), advanced by short jitted
   **segments** (``segment_tokens`` steps of the model's ``decode_segment``).
 - Between segments — host control, no recompiles — emitted tokens stream to
   clients (SSE), rows that hit EOS/budget **retire**, and queued requests
@@ -100,69 +102,74 @@ def build_gen_kernels(cm, mesh=None):
     import jax.numpy as jnp
 
     meta = cm.servable.meta["continuous"]
-    out_shardings = None
     replicated = None
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
 
         replicated = NamedSharding(mesh, PartitionSpec())
 
-        def out_shardings(n):  # noqa: E731 — tuple of replicated specs
-            return tuple([replicated] * n)
+    leaves = meta["cache_leaves"]
+    n_leaves = len(leaves)
 
-    def _insert_rows(cache_k, cache_v, k_row, v_row, slot):
-        idx = (jnp.int32(0), slot, jnp.int32(0), jnp.int32(0))
-        return (jax.lax.dynamic_update_slice(cache_k, k_row, idx),
-                jax.lax.dynamic_update_slice(cache_v, v_row, idx))
+    def _insert_rows(cache, rows, slot):
+        """Every leaf's row of one request into ``slot``: the slot's K and
+        V rows and its state are overwritten whole."""
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                leaf, row, (jnp.int32(0), slot) + (jnp.int32(0),)
+                * (leaf.ndim - 2))
+            for leaf, row in zip(cache, rows))
 
-    def _insert_from(cache_k, cache_v, k_rows, v_rows, j, slot):
+    def _insert_from(cache, rows, j, slot):
         """Splice row ``j`` of a BATCHED prefill's cache into ``slot``.
 
         One compiled program serves every (j, slot) pair — both ride as
         scalar inputs — so burst admission (N requests -> one prefill
         dispatch) costs N cheap insert dispatches, not N programs.
         """
-        L, _, T, D = cache_k.shape
-        src = (jnp.int32(0), j, jnp.int32(0), jnp.int32(0))
-        k_row = jax.lax.dynamic_slice(k_rows, src, (L, 1, T, D))
-        v_row = jax.lax.dynamic_slice(v_rows, src, (L, 1, T, D))
-        return _insert_rows(cache_k, cache_v, k_row, v_row, slot)
+        return _insert_rows(cache, tuple(
+            jax.lax.dynamic_slice(
+                batched, (jnp.int32(0), j) + (jnp.int32(0),)
+                * (leaf.ndim - 2), leaf.shape[:1] + (1,) + leaf.shape[2:])
+            for leaf, batched in zip(cache, rows)), slot)
 
-    def _pack(emits, cache_k, cache_v, tok, pos, step, fin):
-        """A segment's small results as ONE ``[S, seg + 4]`` int32 array
-        (emits, then tok, pos, step, fin): one copy to the host and one wait
-        a round, whatever model's ``segment_scan`` made them."""
+    def _pack(emits, *rest):
+        """A segment's small results as ONE ``[S, seg + 4 + C]`` int32 array
+        (emits, then tok, pos, step, fin, then the model's ``C`` counts of
+        the segment, the same in every row): one copy to the host and one
+        wait a round, whatever model's ``segment_scan`` made them.  The
+        cache's leaves follow it."""
+        tok, pos, step, fin = rest[n_leaves:n_leaves + 4]
         carry = jnp.stack([tok, pos, step, fin.astype(jnp.int32)], axis=1)
-        return jnp.concatenate([emits, carry], axis=1), cache_k, cache_v
+        counts = [jnp.broadcast_to(c, (emits.shape[0],) + c.shape)
+                  for c in rest[n_leaves + 4:]]
+        return (jnp.concatenate([emits, carry] + counts, axis=1),
+                *rest[:n_leaves])
 
-    kw_prefill = {"out_shardings": out_shardings(3)} if mesh is not None else {}
-    kw_insert = {"out_shardings": out_shardings(2)} if mesh is not None else {}
-    kw_segment = {"out_shardings": out_shardings(3)} if mesh is not None else {}
+    kw = {"out_shardings": replicated} if mesh is not None else {}
 
     def alloc_cache():
+        """One allocation a leaf: a shared buffer would double-donate on the
+        first segment call."""
         if replicated is not None:
-            z = np.zeros(meta["cache_shape"], meta["cache_dtype"])
             # device_put COPIES onto the mesh — no aliasing hazard here.
-            return (jax.device_put(z, replicated),
-                    jax.device_put(np.copy(z), replicated))
+            return tuple(jax.device_put(np.zeros(shape, dt), replicated)
+                         for shape, dt in leaves)
         # Device-native zeros, NOT jnp.asarray(np.zeros(...)): the CPU
         # client zero-copies aligned numpy arrays, and these buffers are
         # DONATED through every insert/segment — donating a buffer that
         # aliases numpy-owned memory tears the pool (see the paged
         # allocator's note; caught there as flaky verify corruption and
         # segfaults under the 8-virtual-device harness).
-        return (jnp.zeros(meta["cache_shape"],
-                          meta["cache_dtype"]).block_until_ready(),
-                jnp.zeros(meta["cache_shape"],
-                          meta["cache_dtype"]).block_until_ready())
+        return tuple(jnp.zeros(shape, dt).block_until_ready()
+                     for shape, dt in leaves)
 
     return {
-        "prefill": jax.jit(meta["prefill"], **kw_prefill),
-        "insert": jax.jit(_insert_rows, donate_argnums=(0, 1), **kw_insert),
-        "insert_from": jax.jit(_insert_from, donate_argnums=(0, 1),
-                               **kw_insert),
+        "prefill": jax.jit(meta["prefill"], **kw),
+        "insert": jax.jit(_insert_rows, donate_argnums=(0,), **kw),
+        "insert_from": jax.jit(_insert_from, donate_argnums=(0,), **kw),
         "segment": jax.jit(lambda *a: _pack(*meta["segment"](*a)),
-                           donate_argnums=(1, 2), **kw_segment),
+                           donate_argnums=(1,), **kw),
         "alloc_cache": alloc_cache,
         "meta": meta,
     }
@@ -474,8 +481,13 @@ class GenerationScheduler:
         self.prefill_kernel_dispatches = 0  # guarded-by: dispatch-serialized
         self._prompt_form = meta.get("prompt_form",
                                      lambda batch, bucket: "own")
-        self._cache_k = None  # guarded-by: dispatch-serialized
-        self._cache_v = None  # guarded-by: dispatch-serialized
+        # The pool: the model's cache leaves, K and V first.
+        self._cache = None  # guarded-by: dispatch-serialized
+        # What the model's decode step counts (``meta["counters"]``: name ->
+        # what it counts), summed a segment round: ``{name: sum}`` over
+        # ``segment_rounds`` rounds.
+        self._counters = dict(meta.get("counters", {}))
+        self.counter_sums = dict.fromkeys(self._counters, 0)  # guarded-by: dispatch-serialized
         # Host-owned slot state, passed into every segment (tiny h2d).
         S = self.slots
         self._tok = np.zeros((S,), np.int32)    # guarded-by: dispatch-serialized
@@ -551,7 +563,11 @@ class GenerationScheduler:
                   slots=self.slots, positions=self.total, rows=self.rows,
                   read_block=self.read_block,
                   prompt_buckets=list(self.prompt_buckets),
-                  prompt_forms=self._prompt_forms())
+                  prompt_forms=self._prompt_forms(),
+                  cache_leaves=[
+                      {"shape": list(shape), "dtype": str(np.dtype(dt)),
+                       "bytes": int(np.prod(shape)) * np.dtype(dt).itemsize}
+                      for shape, dt in meta["cache_leaves"]])
 
     def _prompt_forms(self) -> dict:
         """Per prefill bucket and admission batch (a power of two, as
@@ -565,10 +581,8 @@ class GenerationScheduler:
 
     # -- device kernels (all called on the runner's dispatch thread) --------
     def _ensure_cache(self):
-        if self._cache_k is None:
-            # Two separate allocations — a shared buffer would double-donate
-            # on the first segment call.
-            self._cache_k, self._cache_v = self._alloc_cache()
+        if self._cache is None:
+            self._cache = self._alloc_cache()
 
     def _bucket_for(self, n: int) -> int:
         for b in self.prompt_buckets:
@@ -596,14 +610,14 @@ class GenerationScheduler:
             # post-payload (deadlocked before this ordering: leader in the
             # alloc allgather, follower in the header broadcast).
             self._ensure_cache()
-            first, k_row, v_row = self._prefill(self.params, payload)
+            first, *rows = self._prefill(self.params, payload)
             self.prefill_dispatches += 1
             self.prefill_kernel_dispatches += form == "kernel"
         with tl.phase("prefill.fetch"):
             first_tok = int(np.asarray(first)[0])
         with tl.phase("insert.launch", programs=1):
-            self._cache_k, self._cache_v = self._insert(
-                self._cache_k, self._cache_v, k_row, v_row, np.int32(slot))
+            self._cache = self._insert(self._cache, tuple(rows),
+                                       np.int32(slot))
             self._set_slot(slot, first_tok, payload, 0, req.max_new)
             self.device_rounds += 1
 
@@ -651,16 +665,15 @@ class GenerationScheduler:
                 for k in payloads[0]
             }
             self._ensure_cache()
-            first, k_rows, v_rows = self._prefill(self.params, batched)
+            first, *rows = self._prefill(self.params, batched)
             self.prefill_dispatches += 1
             self.prefill_kernel_dispatches += form == "kernel"
         with tl.phase("prefill.fetch"):
             first = np.asarray(first)  # blocks until the device is done
         with tl.phase("insert.launch", programs=B, rows=Bp):
             for j, (req, slot, payload) in enumerate(group):
-                self._cache_k, self._cache_v = self._insert_from(
-                    self._cache_k, self._cache_v, k_rows, v_rows,
-                    np.int32(j), np.int32(slot))
+                self._cache = self._insert_from(
+                    self._cache, tuple(rows), np.int32(j), np.int32(slot))
                 self._set_slot(slot, int(first[j]), batched, j, req.max_new)
             self.device_rounds += 1
 
@@ -676,10 +689,11 @@ class GenerationScheduler:
                                 "step": self._step, "fin": self._finished,
                                 "temp": self._temp, "seed": self._seed,
                                 "topk": self._topk, "topp": self._topp})
-            self._inflight, self._cache_k, self._cache_v = self._segment(
-                self.params, self._cache_k, self._cache_v,
+            self._inflight, *cache = self._segment(
+                self.params, self._cache,
                 self._tok, self._pos, self._step, self._finished,
                 self._temp, self._seed, self._topk, self._topp)
+            self._cache = tuple(cache)
 
     def _segment_sync(self):
         """One round's device work, in one call on the dispatch thread.
@@ -718,15 +732,18 @@ class GenerationScheduler:
                 ((last // rb - first // rb + 1) * rb).sum()
             ) / (self.slots * self.rows)
             inflight, self._inflight = self._inflight, None
-            # The round's one blocking wait: [S, seg + 4], emits then the
-            # carries (``build_gen_kernels``); caches stay on device.  The
-            # carries are copied out: the fetch comes back read-only and
-            # admission writes them in place.
+            # The round's one blocking wait: [S, seg + 4 + C], emits, the
+            # carries, then the model's counts (``build_gen_kernels``);
+            # caches stay on device.  The carries are copied out: the fetch
+            # comes back read-only and admission writes them in place.
             packed = np.asarray(inflight)
-            emits = packed[:, :-4]
+            seg = self.seg
+            emits = packed[:, :seg]
             self._tok, self._pos, self._step = (
-                packed[:, k].copy() for k in (-4, -3, -2))
-            self._finished = packed[:, -1] != 0
+                packed[:, seg + k].copy() for k in range(3))
+            self._finished = packed[:, seg + 3] != 0
+            for k, name in enumerate(self.counter_sums):
+                self.counter_sums[name] += int(packed[0, seg + 4 + k])
             self.window_rolls += int((self._rows.span(np.minimum(
                 self._pos[live], self.total - 1), self.rows)[0]
                 != first).sum())
@@ -827,6 +844,10 @@ class GenerationScheduler:
                 "live_positions": {"sum": self.live_positions_sum,
                                    "count": self.segment_rounds},
                 "window_rolls": self.window_rolls,
+                **{name: {"sum": total, "count": self.segment_rounds}
+                   for name, total in self.counter_sums.items()},
+                **({"step_counters": self._counters}
+                   if self._counters else {}),
                 "latency": {"ttft_ms": self.ttft_hist.snapshot(),
                             "itl_ms": self.itl_hist.snapshot()},
                 "host_phases": self.timeline.snapshot(),
@@ -1044,17 +1065,16 @@ class GenerationScheduler:
 
     def _cache_deleted(self) -> bool:
         """True when a donating dispatch faulted after consuming the pool."""
-        if self._cache_k is None:
+        if self._cache is None:
             return False
         try:
             return any(leaf.is_deleted()
-                       for leaf in jax.tree.leaves((self._cache_k,
-                                                    self._cache_v)))
+                       for leaf in jax.tree.leaves(self._cache))
         except Exception:  # non-jax leaves (tests with fakes): assume live
             return False
 
     def _reset_pool(self):
-        self._cache_k = self._cache_v = self._inflight = None
+        self._cache = self._inflight = None
         self._finished[:] = True
         self._active.clear()
         self._free = list(range(self.slots))

@@ -792,6 +792,10 @@ class MetricsHub:
             # (slot lanes): _sum / _count is the mean share.
             # The same spans in rows (what they hold, the summary rows among
             # them, the positions they stand for), not divided by the pool.
+            # A lane whose model counts (``step_counters``: name -> what it
+            # counts) adds its own pairs, under its own names.
+            counted = {key: what for s in gsnap.values()
+                       for key, what in s.get("step_counters", {}).items()}
             for key, what in (
                     ("kv_live_share", "Rows the generating slots' spans "
                      "hold over slots x rows"),
@@ -802,7 +806,8 @@ class MetricsHub:
                     ("summary_rows", "Rows of those spans that stand for "
                      "more than one position"),
                     ("live_positions", "Positions the generating slots have "
-                     "written")):
+                     "written"),
+                    *counted.items()):
                 held = {m: s[key] for m, s in gsnap.items()
                         if s.get(key, {}).get("count")}
                 if not held:

@@ -1756,7 +1756,8 @@ class Server:
             taken as the capture begins and ends, so that a reader has the
             rows of the very rounds whose device time the capture holds."""
             keys = ("span_rows", "summary_rows", "live_positions")
-            return {name: {k: snap[k] for k in keys}
+            return {name: {k: snap[k] for k in keys
+                           + tuple(sched.counter_sums)}
                     for name, sched in self.schedulers.items()
                     for snap in [sched.gen_snapshot()] if keys[0] in snap}
 

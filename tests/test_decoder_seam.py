@@ -260,3 +260,109 @@ def test_segment_scan_emits_pins_and_freezes(case):
     assert [int(x) for x, f in zip(tok2, fin_after) if f] == [
         EOS] * sum(fin_after)
     assert len(fed) == 1  # traced once: one body, ``seg`` steps of it
+
+
+# -- the cache as a tuple of leaves: the families that had two keep their text ----
+
+_GPT2_ARCH = {"vocab_size": 96, "d_model": 32, "layers": 3, "heads": 2,
+              "ffn_dim": 64, "max_positions": 64, "eos_id": 95}
+_W8A16_ARCH = {"vocab_size": 512, "d_model": 128, "layers": 2, "heads": 2,
+               "ffn_dim": 256, "max_positions": 64, "eos_id": 511}
+_EVA_ARCH = {"vocab_size": 48, "hidden_size": 32, "layers": 3, "heads": 2,
+             "intermediate_size": 48, "max_positions": 256, "window_size": 32,
+             "chunk_size": 4, "num_pred_heads": 2, "rope_theta": 100.0,
+             "init_std": 0.3, "eos_id": 48}
+_SLOT = {"max_new_tokens": 8, "gen_slots": 3, "segment_tokens": 4}
+LOWERED = {
+    "gpt2": ("gpt2", "bfloat16", (16,), {
+        **_SLOT, "arch": _GPT2_ARCH, "params_dtype": "bfloat16"}),
+    "w8a16": ("gpt2", "bfloat16", (16,), {
+        **_SLOT, "arch": _W8A16_ARCH, "params_dtype": "int8",
+        "quantize_min_size": 1024}),
+    "evabyte": ("evabyte", "float32", (64,), {
+        **_SLOT, "max_new_tokens": 16, "arch": _EVA_ARCH}),
+}
+# sha256 of ``jit(...).lower(...).as_text()`` (no source locations in it) of
+# the slot lane's three programs as PR 43 (commit 877073a) lowered them for
+# the CPU, with the JAX this repository is installed with (0.9.0): a batch-2
+# prefill of the one bucket, ``insert_from`` and the segment.  They prove one
+# thing, for the PR that widened the cache (45): that the widening reached
+# none of these programs.  A later PR that changes one of them on purpose, or
+# a new JAX, re-pins: the failing assertion prints the digest to put here.
+PR43_TEXT = {
+    "gpt2-prefill": "ca360374ede8002f70a7eb5f586f49847abe161da3c44e783a8cf38e8a4dbb91",
+    "gpt2-insert_from": "4e61d46cc57026cd839e6e7d6bdcfca72a32c269f680cbbf3ea6f7d521ceeaeb",
+    "gpt2-segment": "10d533186dec952c3a85af5ea6c15b7a9823b631f115cb90bde581500960262d",
+    "w8a16-prefill": "328dc41de9f207272f677c6fe6f8795b41daea04efe5ae9798287d380e6594b8",
+    "w8a16-insert_from": "67eee799606b6452e3e618fb7df7cc74166492a4914bef4c9cb7de504dd7b715",
+    "w8a16-segment": "9bf805388e93f7aeb0522d545d2d4cf0ac9dfdb36063b75ba8ec4e5f074a791e",
+    "evabyte-prefill": "2ffb62e598843ff9fd4650457781c211fa97743a81efbc429051f62bddd15d1a",
+    "evabyte-insert_from": "6a5a3413001d7b8098f95a92dcb15865443048d8d2ff60c5466d832028dd5a6b",
+    "evabyte-segment": "3bfe5dd77b49871f1811c4874f48af3bbc6abbed36914d17bfe25a37d7742175",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_programs():
+    """``{family: {program: lowered text}}``, each family built once."""
+    import types
+
+    from pytorch_zappa_serverless_tpu.serving.generation import (
+        build_gen_kernels)
+    from pytorch_zappa_serverless_tpu.utils.registry import get_model_builder
+
+    made = {}
+
+    def of(name):
+        if name in made:
+            return made[name]
+        builder, dtype, buckets, extra = LOWERED[name]
+        sv = get_model_builder(builder)(ModelConfig(
+            name=builder, dtype=dtype, batch_buckets=(2,),
+            seq_buckets=buckets, extra=extra))
+        k = build_gen_kernels(types.SimpleNamespace(servable=sv))
+        meta = sv.meta["continuous"]
+        S = meta["slots"]
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), sv.params)
+        payload = {key: jax.ShapeDtypeStruct((2,) + v.shape[1:], v.dtype)
+                   for key, v in meta["admit_spec"](buckets[0]).items()}
+        rows = jax.eval_shape(k["prefill"], params, payload)[1:]
+        cache = tuple(jax.ShapeDtypeStruct(shape, dt)
+                      for shape, dt in meta["cache_leaves"])
+        assert len(cache) == 2 and not meta["counters"]
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+
+        def per_slot(dt):
+            return jax.ShapeDtypeStruct((S,), dt)
+
+        made[name] = {
+            "prefill": k["prefill"].lower(params, payload),
+            "insert_from": k["insert_from"].lower(cache, tuple(rows), i32,
+                                                  i32),
+            "segment": k["segment"].lower(
+                params, cache, *(per_slot(dt) for dt in (
+                    jnp.int32, jnp.int32, jnp.int32, jnp.bool_, jnp.float32,
+                    jnp.int32, jnp.int32, jnp.float32)))}
+        return made[name]
+
+    return of
+
+
+@pytest.mark.parametrize("case", list(PR43_TEXT))
+def test_two_leaf_families_lower_to_the_text_they_had(case, lowered_programs):
+    """The cache became a tuple of declared leaves, the trunk hands a layer
+    its index through ``fam.cache_index`` and may hand it state and a
+    counter: for GPT-2 (bfloat16 and W8A16) and EvaByte, whose cache is K and
+    V and nothing else, none of that reaches the lowered module."""
+    import hashlib
+
+    family, program = case.split("-")
+    text = lowered_programs(family)[program].as_text()
+    digest = hashlib.sha256(text.encode()).hexdigest()
+    assert digest == PR43_TEXT[case], (
+        f"{case} lowers to other text than is pinned (jax {jax.__version__}, "
+        f"pinned under 0.9.0; {len(text.splitlines())} lines).  If this PR "
+        f"meant to change that program, or JAX moved, pin {digest!r} in "
+        f"PR43_TEXT; if not, the seam leaked into a family it should not "
+        f"touch")

@@ -231,7 +231,7 @@ async def test_served_stream_is_the_reference_s_greedy_across_windows(engine):
     meta = cm.servable.meta["continuous"]
     # 32 exact rows + 72 / 4 summaries = 50, in whole blocks of 32 (a block
     # of the decode kernel at this width is longer than a window).
-    assert meta["cache_shape"] == (3, 3, 64, 32)
+    assert [shape for shape, _ in meta["cache_leaves"]] == [(3, 3, 64, 32)] * 2
     assert meta["paged"] is None
     rng = np.random.default_rng(2)
     # 30: crosses position 32 inside its first segment; 44: prefills two

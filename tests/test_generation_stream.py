@@ -502,15 +502,15 @@ async def test_mid_round_pool_reset_requeues_unprocessed_groups(tmp_path):
         real_insert_from = sched._insert_from
         state = {"faulted": False}
 
-        def _bad_insert_from(ck, cv, k_rows, v_rows, j, slot):
+        def _bad_insert_from(cache, rows, j, slot):
             if not state["faulted"]:
                 state["faulted"] = True
                 # Simulate a dispatch that faulted AFTER consuming its
                 # donated operands: the pool buffers are gone.
-                for leaf in jax.tree.leaves((ck, cv)):
+                for leaf in jax.tree.leaves(cache):
                     leaf.delete()
                 raise RuntimeError("injected post-donation fault")
-            return real_insert_from(ck, cv, k_rows, v_rows, j, slot)
+            return real_insert_from(cache, rows, j, slot)
 
         sched._insert_from = _bad_insert_from
         sched.start()
@@ -818,7 +818,7 @@ async def test_fault_in_a_chained_launch_delivers_the_fetched_tokens(engine):
         assert a.tokens == want[:3]  # one segment of three, delivered
         assert [a.events.get_nowait() for _ in range(4)] == want[:3] + [None]
         assert sched.chained_rounds == 0 and sched.segment_rounds == 1
-        assert sched._inflight is None and sched._cache_k is None
+        assert sched._inflight is None and sched._cache is None
         assert sorted(sched._free) == [0, 1] and not sched._active
         assert await asyncio.wait_for(sched.submit(sample).done, 120) == want
         assert sched.chained_rounds > 0

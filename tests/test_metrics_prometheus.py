@@ -126,6 +126,14 @@ def _loaded_hub():
                  "span_rows": {"sum": 3561, "count": 5},
                  "summary_rows": {"sum": 768, "count": 5},
                  "live_positions": {"sum": 14900, "count": 5},
+                 "expert_assignments_held": {"sum": 7040, "count": 5},
+                 "experts_touched": {"sum": 3800, "count": 5},
+                 "expert_load_max": {"sum": 160, "count": 5},
+                 "step_counters": {
+                     "expert_assignments_held": "Rows routed to the experts "
+                                                "held here",
+                     "experts_touched": "Held experts that a row reached",
+                     "expert_load_max": "The most rows on one held expert"},
                  "window_rolls": 2,
                  "latency": _tok_lat},
         'pa"ged\\model': {
@@ -462,6 +470,23 @@ def test_exposition_matches_checked_in_manifest():
     mutated = text.replace('tpuserve_requests_total{model="resnet18"}',
                            'tpuserve_requests_total{rogue="x"}', 1)
     assert any("label set" in p for p in mod.check(mutated, manifest))
+
+
+def test_a_lane_s_own_step_counters_render_under_the_names_it_gives():
+    """The hub names no model's counters: a lane says what its model's decode
+    step counts (``step_counters``: name -> what it counts) and the hub gives
+    each pair a summary under that name (the manifest declares the names in
+    use; this one is not in it and is not linted)."""
+    hub = _loaded_hub()
+    gen = hub.generation()
+    gen["gpt2"]["rows_skipped"] = {"sum": 9, "count": 3}
+    gen["gpt2"]["step_counters"]["rows_skipped"] = "Rows a step skipped"
+    hub.generation = lambda: gen
+    text = hub.render_prometheus()
+    assert ("# HELP tpuserve_rows_skipped Rows a step skipped, per segment "
+            "round") in text
+    assert 'tpuserve_rows_skipped_sum{model="gpt2"} 9' in text
+    assert 'tpuserve_experts_touched_count{model="gpt2"} 5' in text
 
 
 def test_device_memory_gauge_renders_where_the_backend_counts(monkeypatch):

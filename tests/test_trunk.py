@@ -31,9 +31,11 @@ from pytorch_zappa_serverless_tpu.ops import lora as L
 from pytorch_zappa_serverless_tpu.utils.registry import get_model_builder
 
 
-def loop_trunk(fam, params, x, pos, cache, attend, adapter_idx=None):
+def loop_trunk(fam, params, x, pos, cache, attend, adapter_idx=None,
+               lengths=None):
     """The trunk as it was before the shared body: a Python loop that calls
-    the family's block afresh a layer, with the layer's index a Python int."""
+    the family's block afresh a layer, with the layer's index a Python int
+    (for the families whose cache is K and V rows and nothing else)."""
     stacks = None if adapter_idx is None else params.get("__adapters__")
     for i in range(fam.layers):
         p = params[f"layer{i}"]
@@ -46,7 +48,7 @@ def loop_trunk(fam, params, x, pos, cache, attend, adapter_idx=None):
         x = fam.layer(p, x, layer_attend, pos,
                       lora=None if stacks is None else stacks.get(f"layer{i}"),
                       lora_idx=adapter_idx)
-    return fam.norm(params, x), cache
+    return fam.norm(params, x), cache, None
 
 
 # -- the families -------------------------------------------------------------

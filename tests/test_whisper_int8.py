@@ -130,11 +130,10 @@ def test_int8_continuous_segment_runs(sv_q):
 
     cont = sv_q.servable_meta_continuous if hasattr(
         sv_q, "servable_meta_continuous") else sv_q.meta["continuous"]
-    L, S, T, D = cont["cache_shape"]
-    ck = jnp.zeros((L, S, T, D), cont["cache_dtype"])
-    cv = jnp.zeros((L, S, T, D), cont["cache_dtype"])
+    S = cont["slots"]
+    cache = tuple(jnp.zeros(shape, dt) for shape, dt in cont["cache_leaves"])
     emits, *_ = cont["segment"](
-        sv_q.params, ck, cv, jnp.zeros((S,), jnp.int32),
+        sv_q.params, cache, jnp.zeros((S,), jnp.int32),
         jnp.ones((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
         jnp.zeros((S,), bool), jnp.zeros((S,), jnp.float32),
         jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32),
