@@ -597,9 +597,12 @@ def _kernels_child(rehearse: bool) -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from pytorch_zappa_serverless_tpu.models.evabyte import TwoTier
     from pytorch_zappa_serverless_tpu.ops import hostops
     from pytorch_zappa_serverless_tpu.ops.decode_attention import (
         decode_attention)
+    from pytorch_zappa_serverless_tpu.ops import (
+        flash_attention as flash_attention_module)
     from pytorch_zappa_serverless_tpu.ops.flash_attention import (
         flash_attention, masked_attention, prompt_attention, prompt_block,
         prompt_mask)
@@ -695,6 +698,38 @@ def _kernels_child(rehearse: bool) -> None:
                  q, k, v, prompt_mask(n, q.shape[1]), heads)},
             [(3, 96, 5)] if rehearse else PROMPT_SHAPES, not rehearse):
         print("prompt_attention " + json.dumps(row))
+    # EvaByte's windowed prompt attention alone, its forms at its four
+    # prefill buckets and the cell's two prompts (models/evabyte.py
+    # ``TwoTier.attention``: a window's exact keys and the summaries of the
+    # windows before it in one softmax).
+    eva = (TwoTier(64, 4, 2, 8, block_q=32) if rehearse
+           else TwoTier(2048, 16, 32, 64))
+    if rehearse:  # ``TwoTier`` reaches the kernel by its module
+        flash_attention_module.prompt_attention = functools.partial(
+            prompt_attention, interpret=True)
+    for p, length, heads in ([(192, 150, 2)] if rehearse
+                             else [(6144, 5739, 32), (4096, 2049, 32)]):
+        q, k, v, kbar, vbar = (
+            jnp.asarray(rng.standard_normal((1, n, heads * 128)) * 0.5,
+                        jnp.bfloat16)
+            for n in (p, p, p, p // eva.chunk, p // eva.chunk))
+        got, want = (np.asarray(eva.attention(
+            form, heads, q, k, v, kbar, vbar, jnp.asarray([length])),
+            np.float32) for form in EVA_FORMS)
+        np.testing.assert_allclose(got[0, :length], want[0, :length],
+                                   rtol=3e-2, atol=3e-2)
+        assert np.isfinite(got).all(), "a padded row is not finite"
+        print(f"eva prompt attention [1,{p},{heads},128] length {length}: "
+              "the kernel matches the windows form on every real row")
+    for row in time_prompt_attention(
+            {form: lambda q, k, v, n, heads, kbar, vbar, form=form:
+             eva.attention(form, heads, q, k, v, kbar, vbar, n)
+             for form in EVA_FORMS},
+            [(1, 192, 2, [150])] if rehearse else EVA_PROMPT_SHAPES,
+            not rehearse, head_dim=128, chunk=eva.chunk,
+            score_elements=lambda b, h, p: b * h * eva.block_q * (
+                eva.window + p // eva.chunk)):
+        print("eva_prompt_attention " + json.dumps(row))
     # Decode attention over a slot pool [L, S, T, D]: the benchmark's two
     # serving shapes, slots at 0, mid-block, a block edge and the last row,
     # and a dead one, whose row of the pool holds NaN and is read nowhere.
@@ -789,28 +824,38 @@ def span_fills(slots: int, total: int, lead: int) -> dict:
 
 
 def _device_ns(run):
-    """``(compute, counts)`` of one profiled ``run()``: device nanoseconds
-    and events of the profiler's ``XLA Ops`` by operation name."""
+    """``(compute, counts, busy)`` of one profiled ``run()``: device
+    nanoseconds and events of the profiler's ``XLA Ops`` by operation name
+    (a loop's envelope left out, its body's operations counted), and the
+    union of all their intervals, which holds a loop whole."""
     import tempfile
 
     import jax
 
-    from pytorch_zappa_serverless_tpu.utils.xplane import op_time_breakdown
+    from pytorch_zappa_serverless_tpu.utils.xplane import (
+        op_time_breakdown, read_capture)
 
     OUT.mkdir(exist_ok=True)  # the phase may run alone, without main()
     with tempfile.TemporaryDirectory(dir=OUT) as trace_dir:
         jax.profiler.start_trace(trace_dir)
         run().block_until_ready()
         jax.profiler.stop_trace()
-        compute, counts, _, _ = op_time_breakdown(trace_dir)
-    return compute, counts
+        capture = read_capture(trace_dir)
+        compute, counts, _, _ = op_time_breakdown(trace_dir, capture)
+    busy = until = 0
+    for plane in capture[0]:
+        for start, end, _, is_op in plane["ops"]:
+            if is_op and end > until:
+                busy += end - max(start, until)
+                until = end
+    return compute, counts, busy
 
 
 def _device_us(run, name: str) -> float:
     """Device microseconds of one call of the kernel ``name`` inside
     ``run()``, a program that chains ``_TIMED_CALLS`` of them: the mean of
     the profiler's ``XLA Ops`` events of that name."""
-    compute, counts = _device_ns(run)
+    compute, counts, _ = _device_ns(run)
     assert counts[name] == _TIMED_CALLS, (counts[name], dict(counts))
     return round(compute[name] / counts[name] / 1e3, 2)
 
@@ -935,44 +980,69 @@ def edge_lengths(batch: int, bucket: int) -> list[int]:
     return list(dict.fromkeys(edges + spread))[:batch]
 
 
-def time_prompt_attention(forms: dict, shapes, on_device: bool):
+EVA_FORMS = ("kernel", "windows")  # of ``TwoTier.attention``
+
+# EvaByte's prefill buckets at the published widths (a prompt a dispatch, 32
+# heads of 128, windows of 2,048, a summary a chunk of 16): each bucket full,
+# then the two prompts of the benchmark's cell.
+EVA_PROMPT_SHAPES = (
+    [(1, p, 32, [p]) for p in (4096, 6144, 8192, 12288)]
+    + [(1, 4096, 32, [2923]), (1, 6144, 32, [5739])])
+
+
+def time_prompt_attention(forms: dict, shapes, on_device: bool,
+                          head_dim: int = 64, chunk: int | None = None,
+                          score_elements=None):
     """Device microseconds a layer of a prefill's prompt attention in each
     of ``forms`` (``{name: attend(q, k, v, lengths, heads)}``: the kernel
     and the ``jax.numpy`` form), alone: one row a shape ``(batch, bucket,
-    heads)`` (heads of 64, bfloat16, :func:`prompt_lengths`) and a form.
-    ``us_a_layer`` is everything the chained program runs over
-    its calls (the kernel, or the fusions that write and read the scores),
-    ``kernel_us`` the profiler's events named ``prompt_attention`` alone;
-    beside them the float32 scores' bytes, which the picker's rule reads.
-    Off the device the rows carry no time."""
+    heads[, lengths])`` (heads of ``head_dim``, bfloat16,
+    :func:`prompt_lengths` where none are given) and a form.  With
+    ``chunk`` the forms are a windowed family's, ``attend(q, k, v, lengths,
+    heads, kbar, vbar)`` with a summary ``[batch, bucket / chunk, D]`` a
+    chunk.  ``us_a_layer`` is everything the chained program runs over its
+    calls (the kernel, or the fusions that write and read the scores; a
+    scan whole, by the union of the device's intervals), ``kernel_us`` the
+    profiler's events named ``prompt_attention`` alone; beside them the
+    bytes of the float32 scores the ``jax.numpy`` form writes at once
+    (``score_elements(batch, heads, bucket)``: ``[B, H, P, P]`` unless
+    said), which the picker's rule reads.  Off the device the rows carry no
+    time."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     rng = np.random.default_rng(SEED)
     rows = []
-    for batch, bucket, heads in shapes:
-        d = heads * 64
+    for batch, bucket, heads, *given in shapes:
+        d = heads * head_dim
         q, k, v = (jnp.asarray(rng.standard_normal((batch, bucket, d)) * 0.5,
                                jnp.bfloat16) for _ in range(3))
-        lengths = jnp.asarray(prompt_lengths(batch, bucket), jnp.int32)
+        summaries = () if chunk is None else tuple(
+            jnp.asarray(rng.standard_normal((batch, bucket // chunk, d))
+                        * 0.5, jnp.bfloat16) for _ in range(2))
+        lengths = jnp.asarray(
+            given[0] if given else prompt_lengths(batch, bucket), jnp.int32)
+        elements = (score_elements(batch, heads, bucket) if score_elements
+                    else batch * heads * bucket ** 2)
         for form, attend in forms.items():
             @jax.jit
-            def chain(q, k, v, lengths):
+            def chain(q, k, v, lengths, *summaries):
                 for _ in range(_TIMED_CALLS):  # each waits for the last
-                    q = q + attend(q, k, v, lengths, heads) * 0.01
+                    q = q + attend(q, k, v, lengths, heads, *summaries) * 0.01
                 return q
 
             row = {"shape": [batch, bucket, d], "form": form,
-                   "score_mb": round(batch * heads * bucket ** 2 * 4
-                                     / 2 ** 20, 1)}
-            chain(q, k, v, lengths).block_until_ready()
+                   "score_mb": round(elements * 4 / 2 ** 20, 1)}
+            if given:
+                row["lengths"] = given[0]
+            chain(q, k, v, lengths, *summaries).block_until_ready()
             if on_device:
-                compute, counts = _device_ns(lambda: chain(q, k, v, lengths))
-                row["us_a_layer"] = round(
-                    sum(compute.values()) / _TIMED_CALLS / 1e3, 2)
+                compute, counts, busy = _device_ns(
+                    lambda: chain(q, k, v, lengths, *summaries))
+                row["us_a_layer"] = round(busy / _TIMED_CALLS / 1e3, 2)
+                name = "prompt_attention"
                 if form == "kernel":
-                    name = "prompt_attention"
                     assert counts[name] == _TIMED_CALLS, dict(counts)
                     row["kernel_us"] = round(
                         compute[name] / _TIMED_CALLS / 1e3, 2)
@@ -1181,6 +1251,10 @@ def _evabyte_child(rehearse: bool) -> None:
     print(f"evabyte: {cfg.layers} layers of {D}, {T} rows a slot, read in "
           f"blocks of {da.read_block(T, D, dtype)}")
     assert rehearse or da.read_block(T, D, dtype) == 64, "the jnp form serves"
+    form = fam.rows.prompt_form(1, cfg.heads, bucket, D // cfg.heads)
+    print(f"evabyte: the prompt attention of a [1, {bucket}] prefill takes "
+          f"form {form}")
+    assert form == ("windows" if rehearse else "kernel"), form
 
     seen = {}
     choose = decoder.choose

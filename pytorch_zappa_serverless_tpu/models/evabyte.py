@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import decode_attention
+from ..ops import decode_attention, flash_attention
 from .decoder import Family, Rows, make_servable
 
 
@@ -209,63 +209,116 @@ class TwoTier(Rows):
                 v.at[layer, slots, at].set(vbar.astype(v.dtype)))
 
     def prompt_form(self, batch, heads, P, head_dim) -> str:
-        return "windows"
+        """``"kernel"`` under ops/flash_attention.prompt_form's rule, read
+        for one window (the longest prompt the kernel is handed) and for
+        the float32 scores a block of the ``jax.numpy`` form writes
+        (``[batch, heads, block_q, window + summaries]``); else
+        ``"windows"``: the CPU, a mesh, a tiny configuration."""
+        scores = batch * heads * self.block_q * (
+            self.window + self.windows(P) * self.window // self.chunk)
+        return ("kernel" if flash_attention.prompt_form(
+            batch, heads, self.window, head_dim, scores) == "kernel"
+            else "windows")
+
+    def attention(self, form: str, heads: int, q, k, v, kbar, vbar, lengths,
+                  real=None):
+        """The prompt attention alone in one of its forms: q, k, v
+        [B, Pw, D] over whole windows, ``kbar``, ``vbar`` [B, Pw / chunk, D]
+        every chunk's summary in their dtype, lengths [B] (``real``
+        [B, Pw]: which positions they leave real, where the caller has it)
+        → [B, Pw, D].  A query reads its own window's keys at or below it
+        and the summaries of the windows before, in one softmax; rows past
+        a length hold finite values that mean nothing."""
+        with jax.named_scope("eva_prefill_attend"):
+            if form == "kernel":
+                return self._kernel_windows(heads, q, k, v, kbar, vbar,
+                                            lengths)
+            if real is None:
+                real = jnp.arange(q.shape[1])[None, :] < lengths[:, None]
+            return self._scan_blocks(heads, q, k, v, kbar, vbar, real)
+
+    def _kernel_windows(self, heads, q, k, v, kbar, vbar, lengths):
+        """Form ``kernel``: the prompt's windows as rows of the batch of
+        ops/flash_attention.prompt_attention (a reshape: they lie one after
+        the other), each causal inside itself over what of it is real, and
+        window ``w`` reading the first ``w * window / chunk`` summaries of
+        its prompt as the kernel's prefix.  The scores stay in VMEM, and
+        nothing past the diagonal or past a length is computed."""
+        W, per = self.window, self.window // self.chunk
+        B, Pw, D = q.shape
+        nW = Pw // W
+        w = jnp.arange(nW)
+        lens = jnp.clip(lengths[:, None] - w * W, 0, W).reshape(B * nW)
+        counts = jnp.broadcast_to(per * w, (B, nW)).reshape(B * nW)
+        out = flash_attention.prompt_attention(
+            *(a.reshape(B * nW, W, D) for a in (q, k, v)), lens, heads=heads,
+            prefix=(kbar, vbar, counts) if nW > 1 else None)
+        return out.reshape(B, Pw, D)
+
+    def _scan_blocks(self, heads, q, k, v, kbar, vbar, real):
+        """Form ``windows``, in ``jax.numpy``: a ``lax.scan`` over blocks of
+        ``block_q`` queries, each block's float32 scores ``[B, heads,
+        block_q, window + Pw / chunk]`` written out and read back."""
+        W, c, Bq = self.window, self.chunk, self.block_q
+        B, Pw, D = q.shape
+        dh, per = D // heads, W // c
+
+        def split(a):
+            return a.reshape(B, -1, heads, dh)
+
+        sk, sv = split(kbar), split(vbar)
+
+        def block(_, b):
+            w = b * Bq // W
+            qpos = b * Bq + jnp.arange(Bq)
+            kpos = w * W + jnp.arange(W)
+            qb = split(jax.lax.dynamic_slice_in_dim(q, b * Bq, Bq, 1))
+            kw = jax.lax.dynamic_slice_in_dim(k, w * W, W, 1)
+            vw = jax.lax.dynamic_slice_in_dim(v, w * W, W, 1)
+            here = jax.lax.dynamic_slice_in_dim(real, w * W, W, 1)
+            qb = qb * dh ** -0.5
+            exact = jnp.einsum("bqhd,bkhd->bhqk", qb, split(kw),
+                               preferred_element_type=jnp.float32)
+            keep = ((kpos[None, :] <= qpos[:, None])[None]
+                    & here[:, None, :])[:, None]           # [B,1,Bq,W]
+            exact = jnp.where(keep, exact, -1e9)
+            past = jnp.einsum("bqhd,bjhd->bhqj", qb, sk,
+                              preferred_element_type=jnp.float32)
+            past = jnp.where(jnp.arange(Pw // c) < per * w, past, -1e9)
+            probs = jax.nn.softmax(
+                jnp.concatenate([past, exact], axis=-1), axis=-1)
+            probs = probs.astype(v.dtype)
+            out = (jnp.einsum("bhqj,bjhd->bqhd", probs[..., : Pw // c], sv)
+                   + jnp.einsum("bhqk,bkhd->bqhd", probs[..., Pw // c:],
+                                split(vw)))
+            return None, out.reshape(B, Bq, D)
+
+        _, outs = jax.lax.scan(block, None, jnp.arange(Pw // Bq))
+        return jnp.moveaxis(outs, 0, 1).reshape(B, Pw, D)
 
     def prompt(self, heads: int, lengths, P: int):
-        """Window by window, a block of ``block_q`` queries at a time: the
-        exact causal attention inside the block's window joined with the
-        summaries of the windows before it in one softmax; then the rows a
-        decode step reads, the summaries of every chunk and the ring as the
-        prompt's last window leaves it.  No ``[P, P]`` array."""
-        W, c, Bq = self.window, self.chunk, self.block_q
+        """Window by window: the exact causal attention inside a query's
+        window joined with the summaries of the windows before it in one
+        softmax (:meth:`attention`, in the form :meth:`prompt_form` says);
+        then the rows a decode step reads, the summaries of every chunk and
+        the ring as the prompt's last window leaves it.  No ``[P, P]``
+        array."""
+        W, c = self.window, self.chunk
         Pw = -(-P // W) * W        # whole windows; the padding is not real
-        nC, per = -(-P // c), W // c
+        nC = -(-P // c)
         real = jnp.arange(Pw)[None, :] < lengths[:, None]          # [B, Pw]
 
         def attend(p, cache, i, q, k, v):
             B, _, D = q.shape
-            dh = D // heads
             pad = ((0, 0), (0, Pw - P), (0, 0))
             q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
             kbar, vbar = summarize(p, k.reshape(B, Pw // c, c, D),
                                    v.reshape(B, Pw // c, c, D),
                                    real.reshape(B, Pw // c, c), heads)
             kbar, vbar = kbar.astype(k.dtype), vbar.astype(v.dtype)
-
-            def split(a):
-                return a.reshape(B, -1, heads, dh)
-
-            sk, sv = split(kbar), split(vbar)
-
-            def block(_, b):
-                w = b * Bq // W
-                qpos = b * Bq + jnp.arange(Bq)
-                kpos = w * W + jnp.arange(W)
-                qb = split(jax.lax.dynamic_slice_in_dim(q, b * Bq, Bq, 1))
-                kw = jax.lax.dynamic_slice_in_dim(k, w * W, W, 1)
-                vw = jax.lax.dynamic_slice_in_dim(v, w * W, W, 1)
-                here = jax.lax.dynamic_slice_in_dim(real, w * W, W, 1)
-                qb = qb * dh ** -0.5
-                exact = jnp.einsum("bqhd,bkhd->bhqk", qb, split(kw),
-                                   preferred_element_type=jnp.float32)
-                keep = ((kpos[None, :] <= qpos[:, None])[None]
-                        & here[:, None, :])[:, None]           # [B,1,Bq,W]
-                exact = jnp.where(keep, exact, -1e9)
-                past = jnp.einsum("bqhd,bjhd->bhqj", qb, sk,
-                                  preferred_element_type=jnp.float32)
-                past = jnp.where(jnp.arange(Pw // c) < per * w, past, -1e9)
-                probs = jax.nn.softmax(
-                    jnp.concatenate([past, exact], axis=-1), axis=-1)
-                probs = probs.astype(v.dtype)
-                out = (jnp.einsum("bhqj,bjhd->bqhd", probs[..., : Pw // c],
-                                  sv)
-                       + jnp.einsum("bhqk,bkhd->bqhd", probs[..., Pw // c:],
-                                    split(vw)))
-                return None, out.reshape(B, Bq, D)
-
-            with jax.named_scope("eva_prefill_attend"):
-                _, outs = jax.lax.scan(block, None, jnp.arange(Pw // Bq))
-            out = jnp.moveaxis(outs, 0, 1).reshape(B, Pw, D)[:, :P]
+            out = self.attention(
+                self.prompt_form(B, heads, P, D // heads), heads, q, k, v,
+                kbar, vbar, lengths, real)[:, :P]
 
             # The rows: chunk j's summary at R - 1 - j, and the ring as the
             # last window each prompt reaches leaves it (rows past the

@@ -35,7 +35,9 @@ A decoder's prefill has a second kernel, :func:`prompt_attention`: causal,
 ragged, over rows ``[B, P, D]`` with the heads side by side, read where the
 projections left them.  models/decoder.py reaches it through
 :func:`prompt_attend`, which takes it or the ``jax.numpy`` form
-(:func:`masked_attention`) by :func:`prompt_form`'s rule.
+(:func:`masked_attention`) by :func:`prompt_form`'s rule; models/evabyte.py
+hands it a long prompt's windows as rows of the batch and the summaries of
+the windows before each as a prefix of keys.
 
 Degenerate rows (every key masked) produce a uniform distribution over the
 masked keys rather than NaN — the -1e9 finite mask convention; no zoo model
@@ -205,8 +207,12 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
 # The prompt attention of a decoder's prefill
 # ---------------------------------------------------------------------------
 
-def _prompt_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
-                   sm_scale: float, block: int, head_dim: int, width: int):
+def _prompt_kernel(len_ref, *refs, sm_scale: float, block: int,
+                   head_dim: int, width: int, prefix: bool = False):
+    if prefix:
+        count_ref, q_ref, k_ref, v_ref, pk_ref, pv_ref, o_ref, *scratch = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, *scratch = refs
     b, t = pl.program_id(0), pl.program_id(1)
     length = len_ref[b]
     P, W = q_ref.shape
@@ -225,26 +231,45 @@ def _prompt_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
         # tiling, not bfloat16's.)
         real = t * W + lane < width
         own = [real if m is None else m & real for m in on_head]
-        k_ref, raw = scratch[0], k_ref
-        k_lane = jax.lax.broadcasted_iota(jnp.int32, (P, W), 1)
-        k_ref[...] = jnp.where(t * W + k_lane < width,
-                               raw[...].astype(jnp.float32),
-                               0.0).astype(k_ref.dtype)
+
+        def clean(raw, into):
+            at = jax.lax.broadcasted_iota(jnp.int32, raw.shape, 1)
+            into[...] = jnp.where(t * W + at < width,
+                                  raw[...].astype(jnp.float32),
+                                  0.0).astype(into.dtype)
+            return into
+
+        k_ref = clean(k_ref, scratch[0])
+        if prefix:
+            pk_ref = clean(pk_ref, scratch[1])
     below = (jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
              <= jax.lax.broadcasted_iota(jnp.int32, (block, block), 0))
+    if prefix:
+        # The prefix's rows that are real, by the row's count.  A row past
+        # it is kept out of the scores by the mask and out of the values by
+        # a zero (a probability of 0 against a NaN is a NaN).
+        J = pk_ref.shape[0]
+        count = count_ref[b]
+        given = jax.lax.broadcasted_iota(jnp.int32, (block, J), 1) < count
+        pv = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, (J, W), 0) < count,
+            pv_ref[...].astype(jnp.float32), 0.0).astype(pv_ref.dtype)
 
-    def scores(qh, rows):
-        return jax.lax.dot_general(qh, k_ref[rows, :], (((1,), (1,)), ((), ())),
+    whole = slice(None)
+
+    def scores(qh, keys, rows):
+        return jax.lax.dot_general(qh, keys[rows, :],
+                                   (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
 
-    def values(p, rows):
-        return jnp.dot(p.astype(v_ref.dtype), v_ref[rows, :],
+    def values(p, of, rows):
+        return jnp.dot(p.astype(of.dtype), of[rows, :],
                        preferred_element_type=jnp.float32)
 
     # A block of queries after the other, each over the keys at or below
     # it: its own block under the diagonal's mask, the blocks before it
-    # whole.  The slices are static, so nothing past the diagonal is read
-    # or computed and no running state is carried.
+    # whole, and the prefix's real rows.  The slices are static, so nothing
+    # past the diagonal is read or computed and no running state is carried.
     for i in range(P // block):
         own_rows, before = pl.ds(i * block, block), pl.ds(0, i * block)
 
@@ -258,18 +283,26 @@ def _prompt_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
                 # and ``p @ v`` carries head h's output on those same lanes.
                 qh = (q if own[h] is None
                       else jnp.where(own[h], q, 0.0)).astype(k_ref.dtype)
-                s = jnp.where(below, scores(qh, own_rows), _NEG_INF)
+                s = jnp.where(below, scores(qh, k_ref, own_rows), _NEG_INF)
                 m = s.max(axis=-1, keepdims=True)
                 if i:
-                    s_before = scores(qh, before)
+                    s_before = scores(qh, k_ref, before)
                     m = jnp.maximum(m, s_before.max(axis=-1, keepdims=True))
+                if prefix:
+                    s_prefix = jnp.where(given, scores(qh, pk_ref, whole),
+                                         _NEG_INF)
+                    m = jnp.maximum(m, s_prefix.max(axis=-1, keepdims=True))
                 p = jnp.exp(s - m)
                 l = p.sum(axis=-1, keepdims=True)
-                o = values(p, own_rows)
+                o = values(p, v_ref, own_rows)
                 if i:
                     p = jnp.exp(s_before - m)
                     l = l + p.sum(axis=-1, keepdims=True)
-                    o = o + values(p, before)
+                    o = o + values(p, v_ref, before)
+                if prefix:
+                    p = jnp.exp(s_prefix - m)
+                    l = l + p.sum(axis=-1, keepdims=True)
+                    o = o + values(p, pv, whole)
                 o = o * (1.0 / l)          # l >= 1: the diagonal is kept
                 out = o if h == 0 else jnp.where(on_head[h], o, out)
             o_ref[own_rows, :] = out.astype(o_ref.dtype)
@@ -289,7 +322,7 @@ def _tile_width(head_dim: int) -> int | None:
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "block", "interpret"))
-def prompt_attention(q, k, v, lengths, *, heads: int,
+def prompt_attention(q, k, v, lengths, *, heads: int, prefix=None,
                      block: int | None = None, interpret: bool = False):
     """Causal, ragged self-attention of a prompt, the scores never in HBM.
 
@@ -302,13 +335,20 @@ def prompt_attention(q, k, v, lengths, *, heads: int,
     mean nothing (zeros where a whole block of queries is past the length,
     which is all ``lengths`` is for: it rides in by scalar prefetch).
 
+    ``prefix`` = ``(pk, pv, counts)`` gives every query of row ``b``
+    ``counts[b]`` keys more, unmasked and in the same softmax: the first
+    ``counts[b]`` rows of ``pk``, ``pv`` [G, J, D], which ``B / G``
+    consecutive rows of the batch share (a long prompt's windows, stacked
+    as rows of the batch, each reading the summaries of the windows before
+    it: models/evabyte.py).  What lies past a count reaches no output.
+
     Nothing is transposed or padded on the way in: a grid step holds the
     ``[P, 128]`` lanes of one row of the batch where they lie, two heads of
-    64 (one of 128, four of 32), K and V whole.  Each head's query is
-    masked to its own lanes, so the 128-deep contraction is that head's
-    scores and the MXU does what it would on a head padded to its lanes.
-    Scores in float32 off the MXU, probabilities in the inputs' dtype
-    against V with a float32 accumulator.
+    64 (one of 128, four of 32), K and V whole, and the prefix's ``[J,
+    128]``.  Each head's query is masked to its own lanes, so the 128-deep
+    contraction is that head's scores and the MXU does what it would on a
+    head padded to its lanes.  Scores in float32 off the MXU, probabilities
+    in the inputs' dtype against V with a float32 accumulator.
     """
     B, P, D = q.shape
     hd = D // heads
@@ -320,20 +360,35 @@ def prompt_attention(q, k, v, lengths, *, heads: int,
     if Pp != P:  # zero rows, past every length
         q, k, v = (jnp.pad(a, ((0, 0), (0, Pp - P), (0, 0)))
                    for a in (q, k, v))
-    spec = pl.BlockSpec((None, Pp, W), lambda b, t, lens: (b, 0, t))
+    spec = pl.BlockSpec((None, Pp, W), lambda b, t, *_: (b, 0, t))
+    scalars, operands, in_specs = [lengths], [q, k, v], [spec, spec, spec]
+    scratch = [pltpu.VMEM((Pp, W), k.dtype)] if D % W else []
+    if prefix is not None:
+        pk, pv, counts = prefix
+        G, J, _ = pk.shape
+        Jp = _round_up(J, _LANES)  # whole lanes of scores; zeros, uncounted
+        if Jp != J:
+            pk, pv = (jnp.pad(a, ((0, 0), (0, Jp - J), (0, 0)))
+                      for a in (pk, pv))
+        shared = pl.BlockSpec((None, Jp, W),
+                              lambda b, t, *_: (b // (B // G), 0, t))
+        scalars.append(counts)
+        operands += [pk, pv]
+        in_specs += [shared, shared]
+        if D % W:
+            scratch.append(pltpu.VMEM((Jp, W), pk.dtype))
     out = pl.pallas_call(
         functools.partial(_prompt_kernel, sm_scale=hd ** -0.5, block=block,
-                          head_dim=hd, width=D),
+                          head_dim=hd, width=D, prefix=prefix is not None),
         out_shape=jax.ShapeDtypeStruct((B, Pp, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, pl.cdiv(D, W)),
-            in_specs=[spec, spec, spec], out_specs=spec,
-            scratch_shapes=([pltpu.VMEM((Pp, W), k.dtype)] if D % W else [])),
+            num_scalar_prefetch=len(scalars), grid=(B, pl.cdiv(D, W)),
+            in_specs=in_specs, out_specs=spec, scratch_shapes=scratch),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="prompt_attention",
-    )(lengths.astype(jnp.int32), q, k, v)
+    )(*(a.astype(jnp.int32) for a in scalars), *operands)
     return out[:, :P]
 
 
@@ -385,21 +440,26 @@ def prompt_mask(lengths, P: int):
 PROMPT_KERNEL_MIN_SCORE_BYTES = 96 << 20
 
 
-def prompt_form(batch: int, heads: int, P: int, head_dim: int) -> str:
+def prompt_form(batch: int, heads: int, P: int, head_dim: int,
+                scores: int | None = None) -> str:
     """Which form :func:`prompt_attend` takes at these shapes, from what it
     can observe: ``"kernel"`` on one TPU device where the heads fill whole
     lane tiles, the prompt is ``PROMPT_KERNEL_MAX_POSITIONS`` at most and
-    the float32 scores the other form would write are
-    ``PROMPT_KERNEL_MIN_SCORE_BYTES`` at least; else ``"einsum"`` (the CPU;
-    a mesh, where a Mosaic kernel is not partitioned automatically and the
-    partitioner splits the einsums over the heads; small batches of short
-    prompts, whose scores XLA's fusions keep cheaply)."""
+    the float32 scores the other form would write at once (``scores``
+    elements: ``[batch, heads, P, P]`` unless a windowed family says what
+    its block holds) are ``PROMPT_KERNEL_MIN_SCORE_BYTES`` at least; else
+    ``"einsum"`` (the CPU; a mesh, where a Mosaic kernel is not partitioned
+    automatically and the partitioner splits the einsums over the heads;
+    small batches of short prompts, whose scores XLA's fusions keep
+    cheaply)."""
     if (jax.default_backend() != "tpu" or jax.device_count() != 1
             or _tile_width(head_dim) is None
             or P > PROMPT_KERNEL_MAX_POSITIONS):
         return "einsum"
-    return ("kernel" if batch * heads * P * P * 4
-            >= PROMPT_KERNEL_MIN_SCORE_BYTES else "einsum")
+    if scores is None:
+        scores = batch * heads * P * P
+    return ("kernel" if scores * 4 >= PROMPT_KERNEL_MIN_SCORE_BYTES
+            else "einsum")
 
 
 def prompt_attend(q, k, v, lengths, heads: int):

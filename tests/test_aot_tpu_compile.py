@@ -20,6 +20,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
@@ -283,23 +284,9 @@ def test_shared_layer_compiles_to_the_loop_s_program_on_v5e(
     assert abs(shared_temp - loop_temp) <= 0.01 * loop_temp
 
 
-def test_evabyte_segment_copies_no_weight_on_v5e(one_chip, monkeypatch):
-    """Two layers of EvaByte at the published widths, the 8-slot segment:
-    the trunk hands the block its weights as the arguments of a function
-    called twice, which XLA simplifies before it inlines it, and a reshape
-    folded into a weight there comes out as a copy of the weight a launch
-    (33.5 MB each for ``q`` and ``k``: 136 MB of temporaries at two layers
-    before ``models/evabyte.py`` kept the projections whole).  The program's
-    temporaries stay under one such matrix."""
-    cfg = evabyte.EvaByteConfig(layers=2, eos_id=320)
-    D, F, slots = cfg.hidden_size, cfg.intermediate_size, 8
-    monkeypatch.setattr(
-        decode_attention_module, "_kernel_block",
-        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
-                                     if Tq == 1 else None))
-
-    def sd(*shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+def _evabyte_shapes(cfg, sd):
+    """EvaByte's parameter tree as shapes (``sd(*shape, dtype=)``)."""
+    D, F = cfg.hidden_size, cfg.intermediate_size
 
     def vec():
         return sd(D, dtype=jnp.float32)
@@ -311,6 +298,28 @@ def test_evabyte_segment_copies_no_weight_on_v5e(one_chip, monkeypatch):
             "n1": vec(), "n2": vec(), "mu": vec(), "phi": vec(),
             "q": sd(D, D), "k": sd(D, D), "v": sd(D, D), "o": sd(D, D),
             "gate": sd(D, F), "up": sd(D, F), "down": sd(F, D)}
+    return params
+
+
+def test_evabyte_segment_copies_no_weight_on_v5e(one_chip, monkeypatch):
+    """Two layers of EvaByte at the published widths, the 8-slot segment:
+    the trunk hands the block its weights as the arguments of a function
+    called twice, which XLA simplifies before it inlines it, and a reshape
+    folded into a weight there comes out as a copy of the weight a launch
+    (33.5 MB each for ``q`` and ``k``: 136 MB of temporaries at two layers
+    before ``models/evabyte.py`` kept the projections whole).  The program's
+    temporaries stay under one such matrix."""
+    cfg = evabyte.EvaByteConfig(layers=2, eos_id=320)
+    D, slots = cfg.hidden_size, 8
+    monkeypatch.setattr(
+        decode_attention_module, "_kernel_block",
+        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
+                                     if Tq == 1 else None))
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = _evabyte_shapes(cfg, sd)
     fam = evabyte.family(cfg, evabyte.TwoTier(
         cfg.window_size, cfg.chunk_size, cfg.heads, 64))
     pool = sd(cfg.layers, slots, fam.rows.count(12288 + 768), D)
@@ -326,6 +335,66 @@ def test_evabyte_segment_copies_no_weight_on_v5e(one_chip, monkeypatch):
                              f32).compile()
     assert compiled.as_text().count("tpu_custom_call") == cfg.layers
     assert compiled.memory_analysis().temp_size_in_bytes < D * D * 2
+
+
+def test_evabyte_prefill_with_the_prompt_kernel_holds_no_score_block(
+        one_chip, monkeypatch):
+    """Two layers of EvaByte at the published widths, one prompt of 12,288
+    positions (six windows, 768 summaries), in both forms of its prompt
+    attention.  Form ``windows`` (what the picker says here, the backend
+    being the CPU) writes a block's float32 scores ``[1, 32, 512, 2048 +
+    768]``; with the kernel (the test steers the picker) the program calls
+    ``prompt_attention`` once a layer and no float32 array with the 32
+    heads and a block's 512 queries in its shape is as large as ``[32,
+    512, 2048]``.  Its temporaries: the rotation leaves ``q`` and ``k`` a
+    head at a time as ``[128, P]`` (the compiler's choice, which the scan
+    read as it lay) and the kernel reads rows, so each is laid out anew on
+    the way in and this two-layer program holds one ``[P, 4096]`` bfloat16
+    array more at its peak than the other form's (1,041.6 against 940.5
+    MB; the 16-layer program the cell runs 929.9 against 926.8: PERF.md
+    section 6, PR 46).  Not more than that one array."""
+    import re
+
+    cfg = evabyte.EvaByteConfig(layers=2, eos_id=320)
+    P, total = 12288, 12288 + 768
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = _evabyte_shapes(cfg, sd)
+    rows = evabyte.TwoTier(cfg.window_size, cfg.chunk_size, cfg.heads, 64)
+    fam = evabyte.family(cfg, rows)
+
+    def compiled():
+        done = jax.jit(lambda p, tokens, lengths: decoder.prefill(
+            fam, p, tokens, lengths, total, jnp.bfloat16)).lower(
+                params, sd(1, P, dtype=jnp.int32),
+                sd(1, dtype=jnp.int32)).compile()
+        return done.as_text(), done.memory_analysis().temp_size_in_bytes
+
+    def score_blocks(text):
+        """float32 arrays of a block's scores or larger: 32 heads and 512
+        queries among their dimensions."""
+        found = set()
+        for dims in re.findall(r"f32\[([\d,]+)\]", text):
+            shape = [int(d) for d in dims.split(",")]
+            if {32, 512} <= set(shape) \
+                    and np.prod(shape) >= 32 * 512 * 2048:
+                found.add(tuple(shape))
+        return found
+
+    assert rows.prompt_form(1, cfg.heads, P, 128) == "windows"
+    windows, windows_temp = compiled()
+    assert "tpu_custom_call" not in windows
+    assert (1, 32, 512, 2048 + 768) in score_blocks(windows)
+    monkeypatch.setattr(flash_attention_module, "prompt_form",
+                        lambda *shape: "kernel")
+    assert rows.prompt_form(1, cfg.heads, P, 128) == "kernel"
+    kernel, kernel_temp = compiled()
+    assert kernel.count("tpu_custom_call") == cfg.layers
+    assert "prompt_attention" in kernel
+    assert not score_blocks(kernel)
+    assert kernel_temp <= windows_temp + 1.01 * P * cfg.hidden_size * 2
 
 
 def test_decode_segment_compiles_for_v5e_with_one_work_list_a_step(

@@ -15,6 +15,7 @@ the mathematics left out of the reference) moves them by more than 1e-2.
 """
 
 import asyncio
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from benchmark.reference import evabyte as reference
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.models import evabyte as E
+from pytorch_zappa_serverless_tpu.ops import flash_attention as F
 
 pytest_plugins = "aiohttp.pytest_plugin"  # runs the coroutine tests
 
@@ -155,6 +157,95 @@ def test_rows_of_the_published_layout(total, T):
     assert rows.row(np.asarray([5000, 2048]), T).tolist() == [R + 904, R]
     assert rows.windows(4096) == 2 and rows.windows(4097) == 3
     assert rows.prefill_batch(4096) == 1 and rows.prefill_batch(512) == 4
+
+
+# ---------------------------------------------------------------------------
+# The prompt attention's two forms
+# ---------------------------------------------------------------------------
+
+def _force_kernel(monkeypatch):
+    """``TwoTier.prompt`` takes the kernel, which runs under the
+    interpreter (two heads of 16 in one lane tile that hangs over the
+    rows' 32 lanes)."""
+    monkeypatch.setattr(E.TwoTier, "prompt_form", lambda *a: "kernel")
+    monkeypatch.setattr(F, "prompt_attention", functools.partial(
+        F.prompt_attention, interpret=True))
+
+
+def _prefill(tokens, lengths, total):
+    fam, params = _family(), jax.tree.map(jnp.asarray, _params())
+    return jax.jit(lambda p, t, n: D.prefill(fam, p, t, n, total,
+                                             jnp.float32))(
+        params, jnp.asarray(tokens), jnp.asarray(lengths, jnp.int32))
+
+
+# One, two and three windows of 32, the last of them ragged: a row that
+# ends inside its first window beside one that fills the bucket, a window
+# of which one position is real, a prompt one short of a window's end.
+@pytest.mark.parametrize("P,lengths", [
+    (32, [32, 9]), (64, [64, 33, 20]), (64, [47]), (96, [96, 65, 31]),
+    (96, [70, 95, 64])], ids=str)
+def test_prompt_with_the_kernel_forced_matches_the_windows_form(
+        monkeypatch, P, lengths):
+    tokens = np.random.default_rng(3).integers(
+        0, 47, (len(lengths), P)).astype(np.int32)
+    want = _prefill(tokens, lengths, P + 16)
+    _force_kernel(monkeypatch)
+    got = _prefill(tokens, lengths, P + 16)
+    np.testing.assert_allclose(got[0], want[0], atol=TOL, rtol=TOL)
+    R = want[1].shape[2] - W
+    for b, n in enumerate(lengths):
+        # The rows a decode step reads: the summary of every chunk the
+        # prompt completed (chunk j at row R - 1 - j) and the ring as far
+        # as the prompt's last window got.
+        rows = slice(R - n // C, R + n % W)
+        for mine, theirs in zip(got[1:], want[1:]):
+            np.testing.assert_allclose(mine[:, b, rows], theirs[:, b, rows],
+                                       atol=TOL, rtol=TOL)
+    assert all(np.isfinite(np.asarray(a)).all() for a in got)
+
+
+@pytest.mark.parametrize("P,lengths", [(64, [60, 27]), (96, [90, 64])],
+                         ids=str)
+def test_greedy_bytes_equal_with_the_kernel_forced(monkeypatch, P, lengths):
+    """A segment of 8 that crosses a window's end in every row (60 -> 64,
+    27 -> 32, 90 -> 96; 64 opens a window with its first byte)."""
+    fam, params = _family(), jax.tree.map(jnp.asarray, _params())
+    tokens = jnp.asarray(np.random.default_rng(4).integers(
+        0, 47, (len(lengths), P)).astype(np.int32))
+    z = jnp.zeros((len(lengths),), jnp.float32)
+
+    def greedy():
+        return np.asarray(jax.jit(lambda p, t, n: D.generate(
+            fam, p, t, n, z, z.astype(jnp.int32), 8, jnp.float32))(
+                params, tokens, jnp.asarray(lengths, jnp.int32)))
+
+    want = greedy()
+    _force_kernel(monkeypatch)
+    np.testing.assert_array_equal(greedy(), want)
+
+
+def test_prompt_form_is_windows_off_the_chip_and_on_a_mesh(monkeypatch):
+    published = E.TwoTier(2048, 16, 32, 64)
+    buckets = (4096, 6144, 8192, 12288)  # benchmark/configs/evabyte-16l.json
+    assert {published.prompt_form(1, 32, P, 128) for P in buckets} == {
+        "windows"}                       # the backend here is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    assert {published.prompt_form(1, 32, P, 128) for P in buckets} == {
+        "windows"}
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert [published.prompt_form(1, 32, P, 128) for P in buckets] == [
+        "kernel"] * 4
+    # The rule is ops/flash_attention.prompt_form's: a block of float32
+    # scores under its line, heads that fill no lane tiles or a window
+    # longer than the kernel holds keep the scan.
+    assert _family().rows.prompt_form(4, 2, 96, 16) == "windows"
+    assert published.prompt_form(1, 32, 4096, 80) == "windows"
+    assert E.TwoTier(4096, 16, 32, 64).prompt_form(1, 32, 8192, 128) \
+        == "windows"
+    assert E.TwoTier(2048, 16, 32, 64, block_q=256).prompt_form(
+        1, 32, 4096, 128) == "windows"   # 72 MiB a block
 
 
 def test_linear_rows_are_a_row_a_position():

@@ -36,23 +36,78 @@ def _qkv(rng, B, P, dtype, width=HEADS * HEAD_DIM):
             for _ in range(3))
 
 
-@pytest.mark.parametrize("B,P,lengths,block,dtype,tol", [
-    (1, 96, [96], 64, jnp.bfloat16, 3e-2),
-    (1, 160, [1], 64, jnp.bfloat16, 3e-2),
-    (3, 96, [1, 96, 51], 64, jnp.bfloat16, 3e-2),
-    (3, 160, [160, 7, 129], 64, jnp.bfloat16, 3e-2),
-    (3, 160, [64, 128, 65], 32, jnp.float32, 2e-5),
-    (3, 96, [96, 33, 1], None, jnp.float32, 2e-5),   # the kernel's own block
+# A window's worth of summaries in these tests: a prefix holds two windows'.
+PER = 8
+
+
+def _prefix(rng, B, counts, dtype, width=HEADS * HEAD_DIM, poison=None):
+    """``(pk, pv, counts)`` for a batch of ``B``: one prefix of ``2 * PER``
+    rows a row of the batch, or one that all rows share where the counts
+    come as a tuple (a prompt's windows); None where there are no counts.
+    ``poison`` fills what lies past a row's count (of a shared prefix:
+    past the largest)."""
+    if counts is None:
+        return None
+    G = 1 if isinstance(counts, tuple) else B
+    pk, pv = (np.asarray(rng.standard_normal((G, 2 * PER, width)), np.float32)
+              for _ in range(2))
+    if poison is not None:
+        for g in range(G):
+            past = max(counts) if G == 1 else counts[g]
+            pk[g, past:], pv[g, past:] = poison, poison
+    return (jnp.asarray(pk, dtype), jnp.asarray(pv, dtype),
+            jnp.asarray(counts, jnp.int32))
+
+
+def _reference(q, k, v, n, heads, prefix=None):
+    """A float32 attention over ``concat(prefix[:count], keys)``: the
+    ``jax.numpy`` form under the causal, ragged mask, the prefix's counted
+    rows open to every query and the others taken out."""
+    B, P, _ = q.shape
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    bias = F.prompt_mask(n, P)
+    if prefix is not None:
+        pk, pv, counts = prefix
+        G, J, _ = pk.shape
+        kept = jnp.arange(J)[None, :] < counts[:, None]            # [B, J]
+
+        def rows(a):  # each row of the batch its own copy, cleaned
+            a = jnp.repeat(a.astype(jnp.float32), B // G, axis=0)
+            return jnp.where(kept[:, :, None], a, 0.0)
+
+        k = jnp.concatenate([rows(pk), k], axis=1)
+        v = jnp.concatenate([rows(pv), v], axis=1)
+        bias = jnp.concatenate(
+            [jnp.broadcast_to(jnp.where(kept, 0.0, -1e9)[:, None, None, :],
+                              (B, 1, P, J)), bias], axis=-1)
+    return np.asarray(F.masked_attention(q, k, v, bias, heads), np.float32)
+
+
+@pytest.mark.parametrize("B,P,lengths,block,dtype,tol,counts", [
+    (1, 96, [96], 64, jnp.bfloat16, 3e-2, None),
+    (1, 160, [1], 64, jnp.bfloat16, 3e-2, None),
+    (3, 96, [1, 96, 51], 64, jnp.bfloat16, 3e-2, None),
+    (3, 160, [160, 7, 129], 64, jnp.bfloat16, 3e-2, None),
+    (3, 160, [64, 128, 65], 32, jnp.float32, 2e-5, None),
+    (3, 96, [96, 33, 1], None, jnp.float32, 2e-5, None),  # the kernel's block
+    # With a prefix: no row of it, a window's worth, two windows' worth; a
+    # prefix a row, and one that a prompt's three windows share.
+    (3, 96, [1, 96, 51], 64, jnp.bfloat16, 3e-2, [0, PER, 2 * PER]),
+    (3, 160, [160, 7, 129], 64, jnp.bfloat16, 3e-2, (0, PER, 2 * PER)),
+    (3, 160, [64, 128, 65], 32, jnp.float32, 2e-5, [2 * PER, 3, PER]),
+    (3, 96, [96, 96, 33], None, jnp.float32, 2e-5, (0, PER, 2 * PER)),
+    (1, 160, [1], 64, jnp.float32, 2e-5, [PER]),
 ], ids=lambda v: getattr(v, "__name__", str(v)))
 def test_kernel_matches_the_masked_form_on_every_real_row(
-        rng, B, P, lengths, block, dtype, tol):
+        rng, B, P, lengths, block, dtype, tol, counts):
     q, k, v = _qkv(rng, B, P, dtype)
     n = jnp.asarray(lengths, jnp.int32)
-    got = np.asarray(F.prompt_attention(q, k, v, n, heads=HEADS,
-                                        block=block, interpret=True),
+    prefix = _prefix(rng, B, counts, dtype)
+    got = np.asarray(F.prompt_attention(q, k, v, n, heads=HEADS, block=block,
+                                        prefix=prefix, interpret=True),
                      np.float32)
-    want = np.asarray(F.masked_attention(q, k, v, F.prompt_mask(n, P), HEADS),
-                      np.float32)
+    want = (_reference(q, k, v, n, HEADS, prefix) if prefix else np.asarray(
+        F.masked_attention(q, k, v, F.prompt_mask(n, P), HEADS), np.float32))
     assert got.shape == want.shape and np.isfinite(got).all()
     for b, length in enumerate(lengths):
         np.testing.assert_allclose(got[b, :length], want[b, :length],
@@ -72,13 +127,17 @@ def test_kernel_at_other_blocks(rng, block):
                                    atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("heads,head_dim", [(3, 128), (8, 32), (2, 64)])
-def test_kernel_head_sizes_that_fill_lane_tiles(rng, heads, head_dim):
+@pytest.mark.parametrize("heads,head_dim,counts", [
+    (3, 128, None), (8, 32, None), (2, 64, None),
+    (3, 128, [PER, 2 * PER]), (3, 128, (0, 2 * PER)), (2, 64, [2 * PER, 5]),
+    (8, 32, (PER, 0))], ids=str)
+def test_kernel_head_sizes_that_fill_lane_tiles(rng, heads, head_dim, counts):
     q, k, v = _qkv(rng, 2, 64, jnp.float32, heads * head_dim)
     n = jnp.asarray([64, 20], jnp.int32)
+    prefix = _prefix(rng, 2, counts, jnp.float32, heads * head_dim)
     got = np.asarray(F.prompt_attention(q, k, v, n, heads=heads, block=32,
-                                        interpret=True))
-    want = np.asarray(F.masked_attention(q, k, v, F.prompt_mask(n, 64), heads))
+                                        prefix=prefix, interpret=True))
+    want = _reference(q, k, v, n, heads, prefix)
     for b, length in enumerate((64, 20)):
         np.testing.assert_allclose(got[b, :length], want[b, :length],
                                    atol=2e-5, rtol=2e-5)
@@ -90,12 +149,37 @@ def test_kernel_refuses_heads_that_fill_no_lane_tile():
         F.prompt_attention(x, x, x, jnp.asarray([32]), heads=2, interpret=True)
 
 
-def test_a_q_block_past_the_length_comes_out_zeros(rng):
+@pytest.mark.parametrize("counts", [None, [2 * PER]], ids=str)
+def test_a_q_block_past_the_length_comes_out_zeros(rng, counts):
     q, k, v = _qkv(rng, 1, 128, jnp.float32)
-    got = np.asarray(F.prompt_attention(q, k, v, jnp.asarray([40]),
-                                        heads=HEADS, block=64,
-                                        interpret=True))
+    got = np.asarray(F.prompt_attention(
+        q, k, v, jnp.asarray([40]), heads=HEADS, block=64,
+        prefix=_prefix(rng, 1, counts, jnp.float32), interpret=True))
     assert not got[0, 64:].any() and got[0, :64].any()
+
+
+@pytest.mark.parametrize("heads,head_dim", [(2, 128), (HEADS, HEAD_DIM)],
+                         ids=str)
+@pytest.mark.parametrize("poison", [np.nan, np.inf, -np.inf],
+                         ids=["nan", "inf", "-inf"])
+def test_what_lies_past_a_prefix_s_count_reaches_no_output(rng, poison, heads,
+                                                           head_dim):
+    """A prefix's rows past a row's count hold NaN or Inf, in keys and
+    values alike (in EvaByte's prefill they are the summaries of windows
+    that come later): every output is what clean rows give, all finite.
+    The counts: none of the prefix, a window's worth, both windows'."""
+    width = heads * head_dim
+    q, k, v = _qkv(rng, 3, 64, jnp.float32, width)
+    n = jnp.asarray([64, 20, 33], jnp.int32)
+    counts = [0, PER, 2 * PER - 1]
+    dirty = _prefix(rng, 3, counts, jnp.float32, width, poison=poison)
+    got = np.asarray(F.prompt_attention(q, k, v, n, heads=heads, block=32,
+                                        prefix=dirty, interpret=True))
+    assert np.isfinite(got).all()
+    want = _reference(q, k, v, n, heads, dirty)
+    for b, length in enumerate((64, 20, 33)):
+        np.testing.assert_allclose(got[b, :length], want[b, :length],
+                                   atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("poison", [np.nan, np.inf, -np.inf],
