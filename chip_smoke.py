@@ -55,6 +55,13 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
              reference computed on the same device in float32 at ``highest``
              precision, logits at every decoded position; and the control,
              the reference with its weights through int8, which must fail.
+- *lfm2*     LFM2 at the benchmark cell's widths, ten layers: the grouped
+             ``decode_attention``, the gated ``expert_matmul`` and the
+             prompt attention's flash form alone by the profiler's clock,
+             then the cell's 16 reference prompts (600 and 3,000 tokens)
+             through ``prefill_start``, ``insert`` and segments against the
+             plain reference on the same device, by the logits and by the
+             cell's own ``judge``; and the int8 control, which must fail.
 - *sd15*     Stable Diffusion 1.5 at 512x512, two steps, one ``:submit``
              polled to ``done`` (flash attention's only serving caller).
 
@@ -102,6 +109,12 @@ EVABYTE_LOGIT_TOL = 0.1
 # limits (benchmark/families/nemotron_h.py ``judge``).
 NEMOTRON_LOGIT_TOL = 0.6
 NEMOTRON_RMS_TOL = 0.05
+# LFM2's programs in bfloat16 against the float32 reference, in logits of
+# spread 0.90: the root mean square of all differences read 0.0752 sound and
+# 0.1385 under the int8 control (PERF.md section 6, PR 48).  The largest
+# difference is reported and not judged: it is one flipped expert's on either
+# side (0.905 and 1.056).
+LFM2_RMS_TOL = 0.105
 # --rehearse widths: d_model is one 128-lane tile, the int8 kernel's floor.
 TINY_GPT2 = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 256,
              "vocab_size": 512, "max_positions": 128}
@@ -1316,6 +1329,33 @@ def _evabyte_child(rehearse: bool) -> None:
     print(json.dumps(report))
 
 
+def _against_reference(forward, judge, config, runs, got, control,
+                       judged_tokens=None) -> dict:
+    """A child's served logits ``got`` (one ``[tokens, V]`` a run) against
+    ``forward(run, control)``, the plain reference's at the same positions:
+    the largest and the root-mean-square difference over all of them, and
+    the cell's own comparison of the served tokens (the family's ``judge``
+    with the configuration's limits, over the first ``judged_tokens`` a run
+    where the cell asks for fewer than were served)."""
+    import numpy as np
+
+    t0 = time.monotonic()
+    worst, squares, count, refs = 0.0, 0.0, 0, []
+    for run, mine in zip(runs, got):
+        ref = forward(run, control)
+        worst = max(worst, float(np.max(np.abs(mine - ref))))
+        squares += float(np.sum(np.square(mine - ref)))
+        count += mine.size
+        refs.append(ref[:judged_tokens])
+    judged = judge(config, [{**r, "tokens": r["tokens"][:judged_tokens]}
+                            for r in runs], refs)
+    return {"max_abs_logit_diff": worst,
+            "rms_logit_diff": (squares / count) ** 0.5,
+            "far_share": judged["worst"], "ok": judged["ok"],
+            "judged": judged["note"],
+            "seconds": round(time.monotonic() - t0, 1)}
+
+
 def _nemotron_child(rehearse: bool) -> None:
     """Nemotron-H's programs against its plain reference, on one device, at
     the benchmark cell's widths and sizes (``benchmark/configs/
@@ -1456,28 +1496,19 @@ def _nemotron_child(rehearse: bool) -> None:
     controls = (None,) if rehearse else (None, "int8")
     runs = [{"ids": held[slot][0], "tokens": emits[slot].tolist()}
             for slot in picked]
+    # The logits each served token was chosen from: the prefill's, then every
+    # step's but the last.
+    got = [np.stack([held[slot][1]]
+                    + [seen[t][slot] for t in range(steps - 1)])
+           for slot in picked]
+
+    def forward(run, control):
+        return reference.forward(params, run["ids"] + run["tokens"][:-1],
+                                 keys, control)[len(run["ids"]) - 1:]
+
     for control in controls:
-        t0 = time.monotonic()
-        worst, squares, count, refs = 0.0, 0.0, 0, []
-        for slot in picked:
-            ids, at_prefill = held[slot]
-            served = emits[slot].tolist()
-            got = np.stack([at_prefill]
-                           + [seen[t][slot] for t in range(steps - 1)])
-            ref = reference.forward(params, ids + served[:-1], keys,
-                                    control)[len(ids) - 1:]
-            worst = max(worst, float(np.max(np.abs(got - ref))))
-            squares += float(np.sum(np.square(got - ref)))
-            count += got.size
-            refs.append(ref)
-        # The cell's own comparison, with the configuration's limits.
-        judged = bench_family.judge(config, runs, refs)
-        report[control or "float32"] = {
-            "max_abs_logit_diff": worst,
-            "rms_logit_diff": (squares / count) ** 0.5,
-            "far_share": judged["worst"], "ok": judged["ok"],
-            "judged": judged["note"],
-            "seconds": round(time.monotonic() - t0, 1)}
+        report[control or "float32"] = _against_reference(
+            forward, bench_family.judge, config, runs, got, control)
         print(f"nemotron: reference {control or 'float32'} over slots "
               f"{picked}: " + json.dumps(report[control or "float32"]),
               flush=True)
@@ -1531,6 +1562,366 @@ def _nemotron_child(rehearse: bool) -> None:
         report["int8"]["rms_logit_diff"] > NEMOTRON_RMS_TOL
         and report["int8"]["max_abs_logit_diff"] > NEMOTRON_LOGIT_TOL
         and not report["int8"]["ok"]), report
+    print(json.dumps(report))
+
+
+def _busy_us(run) -> float:
+    """Device microseconds of one of the ``_TIMED_CALLS`` calls a profiled
+    ``run()`` chains: the union of every device interval, so a form that is
+    XLA's fusions and a form that is one kernel are held to one clock."""
+    return round(_device_ns(run)[2] / _TIMED_CALLS / 1e3, 2)
+
+
+def time_grouped_attention(shapes, on_device: bool, interpret: bool):
+    """``decode_attention`` with grouped queries, alone, beside the
+    ``jax.numpy`` grouped form over the same pool: a row a shape ``(slots,
+    total, kv_heads, head_dim, heads, live)`` (every slot holds ``live``
+    rows) and a form, with the least the chip could take over the live rows'
+    bytes (K and V, bfloat16, 819 GB/s).  The ``jax.numpy`` form reads all
+    ``total`` rows of every slot.  Off the device the rows carry no time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.ops import decode_attention as da
+
+    rng = np.random.default_rng(SEED)
+    rows, pools = [], {}
+    for slots, total, kv, dh, heads, live in shapes:
+        d = kv * dh
+        if (slots, total, d) not in pools:
+            pools.clear()  # one pool at a time on the device
+            pools[slots, total, d] = [jnp.asarray(
+                rng.standard_normal((2, slots, total, d), dtype=np.float32),
+                jnp.bfloat16) for _ in range(2)]
+        ck, cv = pools[slots, total, d]
+        q = jnp.asarray(rng.standard_normal((slots, heads * dh)) * 0.1,
+                        jnp.bfloat16)
+        wpos = jnp.full((slots,), live - 1, jnp.int32)
+        bt = da.pick_block_t(total, d, jnp.bfloat16)
+
+        def kernel(q, ck, cv, wpos, work, layer):
+            return da.decode_attention(q, ck, cv, wpos, work, layer=layer,
+                                       heads=heads, block_t=bt,
+                                       interpret=interpret)
+
+        def numpy_form(q, ck, cv, wpos, work, layer):
+            return da._attend_grouped(q[:, None], ck[layer], cv[layer],
+                                      wpos[:, None], None, heads)[:, 0]
+
+        floor = slots * live * d * 2 * 2 / 819e9 * 1e6
+        got = {}
+        for form, attend in (("kernel", kernel), ("jax.numpy", numpy_form)):
+            @jax.jit
+            def chain(q, ck, cv, wpos):
+                work = da.work_list(wpos, total, bt)
+                for j in range(_TIMED_CALLS):
+                    q = q + attend(q, ck, cv, wpos, work, j % 2)
+                return q
+
+            chain(q, ck, cv, wpos).block_until_ready()
+            got[form] = attend(q, ck, cv, wpos, da.work_list(wpos, total, bt),
+                               0)
+            row = {"pool": [slots, total, d], "heads": heads, "live": live,
+                   "form": form, "block_t": bt, "floor_us": round(floor, 2)}
+            if on_device:
+                row["us_a_layer"] = _busy_us(lambda: chain(q, ck, cv, wpos))
+                row["gb_per_s"] = round(
+                    floor * 819 / row["us_a_layer"], 1)
+                row["share_of_819"] = round(floor / row["us_a_layer"], 3)
+            rows.append(row)
+        off = float(jnp.max(jnp.abs(got["kernel"].astype(jnp.float32)
+                                    - got["jax.numpy"].astype(jnp.float32))))
+        assert off < 0.05, (slots, total, kv, live, off)
+    return rows
+
+
+def time_gated_experts(rows_an_expert, on_device: bool, interpret: bool,
+                       experts: int = 64, width: int = 2048, inner: int = 1536):
+    """The gated ``expert_matmul`` (``silu(x W1) * (x W3)``) and the plain
+    one after it (``W2``), alone, at ``rows_an_expert`` rows on each of
+    ``experts`` experts: device microseconds a call beside the share of 819
+    GB/s (every expert's matrices once, the rows in and out) and of 197
+    TFLOP/s (two operations a weight a row), and the largest relative
+    difference from ``jax.lax.ragged_dot``.  Off the device no time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul as em
+
+    rng = np.random.default_rng(SEED)
+
+    def w(*shape):
+        return jnp.asarray(rng.standard_normal(shape, dtype=np.float32) * 0.02,
+                           jnp.bfloat16)
+
+    w1, w3, w2 = (w(experts, width, inner), w(experts, width, inner),
+                  w(experts, inner, width))
+    out = []
+    for r in rows_an_expert:
+        M = experts * r
+        sizes = jnp.full((experts,), r, jnp.int32)
+        x = jnp.asarray(rng.standard_normal((M, width)), jnp.bfloat16)
+        y = jnp.asarray(rng.standard_normal((M, inner)) * 0.1, jnp.bfloat16)
+        want = (jax.nn.silu(jax.lax.ragged_dot(
+            x, w1, sizes, preferred_element_type=jnp.float32))
+            * jax.lax.ragged_dot(x, w3, sizes,
+                                 preferred_element_type=jnp.float32))
+        got = em.expert_matmul_kernel(x, w1, sizes, w3, interpret=interpret)
+        off = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)
+                            / (jnp.abs(want) + 1e-2)))
+        assert off <= 2 ** -6, (r, off)  # one rounding to bfloat16 apart
+        for name, a, mats in (("gated", x, (w1, w3)), ("down", y, (w2,))):
+            @jax.jit
+            def chain(a, *mats):
+                for _ in range(_TIMED_CALLS):  # each waits for the last
+                    o = em.expert_matmul_kernel(a, mats[0], sizes, *mats[1:],
+                                                interpret=interpret)
+                    a = a + (o[:, :1] * 1e-6).astype(a.dtype)
+                return a
+
+            chain(a, *mats).block_until_ready()
+            n_out = inner if name == "gated" else width
+            moved = (len(mats) * experts * width * inner + M * a.shape[1]
+                     + M * n_out) * 2
+            flops = 2 * M * width * inner * len(mats)
+            row = {"rows_an_expert": r, "call": name, "tile": em.pick_tile(
+                M, experts), "max_rel_diff": round(off, 5)}
+            if on_device:
+                us = _busy_us(lambda: chain(a, *mats))
+                row.update(us_a_call=us,
+                           share_of_819=round(moved / 819e9 * 1e6 / us, 3),
+                           share_of_197=round(flops / 197e12 * 1e6 / us, 3))
+            out.append(row)
+    return out
+
+
+def _lfm2_child(rehearse: bool) -> None:
+    """LFM2's kernels alone, then its programs against its plain reference,
+    on one device, at the benchmark cell's widths (``benchmark/configs/
+    lfm2-24b-10l.json``; its ``rehearse`` widths on the CPU).
+
+    Alone, by the profiler's clock: ``decode_attention`` with grouped
+    queries at 32 slots of 2k, 4k and 8k live rows and at Nemotron-H's shape
+    beside the ``jax.numpy`` grouped form; the gated ``expert_matmul`` at 2,
+    128, 384 and 512 rows an expert; the prompt attention's two forms at the
+    four buckets (the ``jax.numpy`` form where its scores fit).
+
+    Then the servable (``decoder.make_servable`` over the tree the benchmark
+    stages: the program's own seeded weights, the routers' biases balanced)
+    and the programs ``build_gen_kernels`` jits, as the scheduler runs them:
+    the cell's own 16 reference prompts (600 and 3,000 tokens, drawn as
+    ``benchmark/run.py`` draws them), each prefilled alone, inserted into a
+    pool of 32 slots and decoded for two segments, the first 3,000-token
+    one for four.  ``choose`` is watched, not replaced: it reports the logits
+    it was given.  The reference's full forward pass over prompt + served
+    tokens gives the largest and the root-mean-square logit difference and,
+    through the cell's own ``check`` rule (``judge``), how many served
+    tokens lie far; the same against the reference through int8, which must
+    fail by each."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import traffic
+    from benchmark.families import lfm2 as bench_family
+    from benchmark.reference import lfm2 as reference
+    from pytorch_zappa_serverless_tpu.config import ModelConfig
+    from pytorch_zappa_serverless_tpu.models import decoder, lfm2
+    from pytorch_zappa_serverless_tpu.serving.generation import (
+        build_gen_kernels)
+
+    on_device = not rehearse
+    config = json.loads((ROOT / "benchmark" / "configs"
+                         / "lfm2-24b-10l.json").read_text())
+    serve = config["serve"]
+    buckets, extra = serve["seq_buckets"], dict(serve["extra"])
+    dtype, scale = "bfloat16", 1.0
+    if rehearse:
+        buckets = config["rehearse"]["seq_buckets"]
+        extra.update(config["rehearse"]["extra"])
+        dtype, scale = "float32", config["rehearse"]["scale"]
+        config["weights"]["dtype"] = "float32"
+    cfg = lfm2.config_from_arch(extra["arch"])
+    report = {}
+
+    # -- the kernels alone ------------------------------------------------
+    S = extra["gen_slots"]
+    T = lfm2.family(cfg, jnp.dtype(dtype)).rows.count(
+        buckets[-1] + extra["max_new_tokens"])
+    shapes = [(S, T, cfg.kv_heads, cfg.head_dim, cfg.heads,
+               max(T * live // 8704, 1)) for live in (2048, 4096, 8192)]
+    if not rehearse:
+        shapes.append((32, 1024, 2, 128, 32, 768))   # Nemotron-H's pool
+    report["decode_attention grouped"] = time_grouped_attention(
+        shapes, on_device, interpret=rehearse)
+    for row in report["decode_attention grouped"]:
+        print("lfm2 decode_attention " + json.dumps(row), flush=True)
+    report["expert_matmul gated"] = time_gated_experts(
+        (2, 4) if rehearse else (2, 128, 384, 512), on_device,
+        interpret=rehearse, experts=cfg.experts_held, width=cfg.hidden_size,
+        inner=cfg.expert_width)
+    for row in report["expert_matmul gated"]:
+        print("lfm2 expert_matmul " + json.dumps(row), flush=True)
+    kv, heads, dh = cfg.kv_heads, cfg.heads, cfg.head_dim
+
+    def prompt_in(form):
+        """The family's own prompt attention, held to ``form``."""
+        rows = lfm2.GroupedFlashRows(kv)
+        rows.prompt_form = lambda *shape: form
+
+        def attend(q, k, v, lengths, heads_):
+            P = q.shape[1]
+            cache = tuple(jnp.zeros((1, 1, P, kv * dh), q.dtype)
+                          for _ in range(2))
+            return rows.prompt(heads, lengths, P)(None, cache, 0, q, k, v)[1]
+
+        return attend
+
+    rng = np.random.default_rng(SEED)
+    report["prompt attention"] = []
+    for P in buckets:
+        q = jnp.asarray(rng.standard_normal((1, P, heads * dh)) * 0.5,
+                        jnp.bfloat16)
+        k, v = (jnp.asarray(rng.standard_normal((1, P, kv * dh)) * 0.5,
+                            jnp.bfloat16) for _ in range(2))
+        lengths = jnp.asarray([P - 7], jnp.int32)
+        outs = {}
+        for form, attend in (("flash", prompt_in("flash")),
+                             ("jax.numpy", prompt_in("grouped"))):
+            if form == "jax.numpy" and heads * P * P * 4 > 3 << 30:
+                continue  # its float32 scores alone are past 3 GiB
+            @jax.jit
+            def chain(q, k, v, lengths):
+                for _ in range(_TIMED_CALLS):
+                    q = q + attend(q, k, v, lengths, heads) * 0.01
+                return q
+
+            outs[form] = attend(q, k, v, lengths, heads)
+            row = {"shape": [1, P, heads * dh], "form": form,
+                   "score_mb": round(heads * P * P * 4 / 2 ** 20, 1)}
+            chain(q, k, v, lengths).block_until_ready()
+            if on_device:
+                row["us_a_layer"] = _busy_us(
+                    lambda: chain(q, k, v, lengths))
+                row["share_of_197"] = round(
+                    2 * 2 * heads * dh * P * P / 2 / 197e12 * 1e6
+                    / row["us_a_layer"], 3)
+            report["prompt attention"].append(row)
+            print("lfm2 prompt_attention " + json.dumps(row), flush=True)
+        if len(outs) == 2:
+            off = float(jnp.max(jnp.abs(
+                (outs["flash"] - outs["jax.numpy"])[:, :P - 7].astype(
+                    jnp.float32))))
+            assert off < 0.05, (P, off)
+        del q, k, v, outs
+
+    # -- the programs against the reference -----------------------------------
+    t0 = time.monotonic()
+    tree = bench_family.init_tree(config["weights"]["seed"], config,
+                                  {"extra": extra})
+    print(f"lfm2: {len(cfg.layer_types)} layers drawn and their routers "
+          f"balanced in {time.monotonic() - t0:.0f} s", flush=True)
+    sv = decoder.make_servable(
+        "lfm2", ModelConfig(name="lfm2", dtype=dtype, batch_buckets=(1,),
+                            seq_buckets=buckets, extra=extra),
+        lfm2.family(cfg, jnp.dtype(dtype)), tree)
+    del tree
+    params, meta = sv.params, sv.meta["continuous"]
+    kernels = build_gen_kernels(types.SimpleNamespace(servable=sv))
+    S, seg, V = meta["slots"], meta["segment_tokens"], cfg.vocab_size
+    print("lfm2: cache leaves " + json.dumps(
+        [[list(shape), str(np.dtype(dt))]
+         for shape, dt in meta["cache_leaves"]])
+        + f", read_block {meta['read_block']}, prompt forms "
+        + json.dumps({b: meta["prompt_form"](1, b) for b in buckets}),
+        flush=True)
+
+    seen = []
+    choose = decoder.choose
+
+    def watched(logits, temperature, seeds, t, top_k=None, top_p=None):
+        jax.debug.callback(lambda lg: seen.append(np.asarray(lg)), logits)
+        return choose(logits, temperature, seeds, t, top_k, top_p)
+
+    decoder.choose = watched
+    # The cell's reference prompts, as benchmark/run.py draws them.
+    rng = np.random.default_rng(config["weights"]["seed"] + 1)
+    prompts = [traffic.token_ids(rng, max(2, round(n * scale)), V)
+               for n in config["reference_prompts"]]
+    new = min(16, extra["max_new_tokens"])
+    long_one = max(range(len(prompts)), key=lambda j: len(prompts[j]))
+    cache = kernels["alloc_cache"]()
+    zf, zi = np.zeros(S, np.float32), np.zeros(S, np.int32)
+    runs, got = [], []
+    t0 = time.monotonic()
+    for j, ids in enumerate(prompts):
+        bucket = next(b for b in buckets if b >= len(ids))
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :len(ids)] = ids
+        payload = {"input_ids": toks,
+                   "length": np.asarray([len(ids)], np.int32),
+                   "temperature": np.zeros(1, np.float32),
+                   "seed": np.zeros(1, np.int32),
+                   "top_k": np.zeros(1, np.int32),
+                   "top_p": np.ones(1, np.float32)}
+        seen.clear()
+        first, *rows = kernels["prefill"](params, payload)
+        slot = j % S
+        cache = kernels["insert_from"](cache, tuple(rows), np.int32(0),
+                                       np.int32(slot))
+        del rows
+        tok, pos, fin = zi.copy(), zi.copy(), np.ones(S, bool)
+        tok[slot], pos[slot], fin[slot] = int(np.asarray(first)[0]), \
+            len(ids), False
+        st, emits = zi.copy(), []
+        steps = 2 * new if j == long_one else new  # four segments for one
+        for _ in range(steps // seg):
+            packed, *cache = kernels["segment"](params, tuple(cache), tok,
+                                                pos, st, fin, zf, zi, zi,
+                                                zf + 1)
+            packed = np.asarray(packed)
+            emits.append(packed[:, :seg])
+            tok, pos, st = (packed[:, seg + k].copy() for k in range(3))
+        jax.effects_barrier()
+        served = np.concatenate(emits, axis=1)[slot].tolist()
+        # The logits each served token was chosen from: the prefill's, then
+        # every step's but the last.
+        got.append(np.stack([seen[0][0]]
+                            + [lg[slot] for lg in seen[1:steps]]))
+        runs.append({"ids": ids, "tokens": served})
+    decoder.choose = choose
+    del cache
+    print(f"lfm2: {len(prompts)} prompts of {sorted({len(p) for p in prompts})}"
+          f" tokens prefilled alone, inserted and decoded in "
+          f"{time.monotonic() - t0:.0f} s (compiles included)", flush=True)
+
+    keys = bench_family.published({"extra": extra})
+    report["logit_std"] = float(np.std(got[0][0]))
+
+    def forward(run, control):
+        return reference.forward(params, run["ids"] + run["tokens"][:-1],
+                                 keys, control, len(run["tokens"]))
+
+    for control in (None,) if rehearse else (None, "int8"):
+        # Judged over the 16 tokens a prompt the cell asks for.
+        report[control or "float32"] = _against_reference(
+            forward, bench_family.judge, config, runs, got, control, new)
+        print(f"lfm2: reference {control or 'float32'}: "
+              + json.dumps(report[control or "float32"]), flush=True)
+    stats = jax.local_devices()[0].memory_stats() or {}
+    report["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+    print("lfm2 " + json.dumps(report))
+    limit = 1e-3 if rehearse else LFM2_RMS_TOL
+    assert report["float32"]["rms_logit_diff"] <= limit, report["float32"]
+    assert report["float32"]["ok"], report["float32"]
+    # The precision below must fail, by the logits and by the cell's own
+    # comparison of the served tokens.
+    assert rehearse or (report["int8"]["rms_logit_diff"] > LFM2_RMS_TOL
+                        and not report["int8"]["ok"]), report["int8"]
     print(json.dumps(report))
 
 
@@ -2135,6 +2526,14 @@ def main(argv=None) -> int:
             say("nemotron: prefill batches, inserts and 256 decode steps "
                 "over a full pool agree with the plain reference; the "
                 "reference in the precision below does not")
+            run_child(f"import chip_smoke; "
+                      f"chip_smoke._lfm2_child({args.rehearse})",
+                      args.rehearse, "lfm2.log", timeout=3000.0)
+            say("lfm2: the grouped decode kernel, the gated expert matmul "
+                "and the flash prompt form match their jax.numpy forms; the "
+                "cell's reference prompts through prefill, insert and "
+                "segments agree with the plain reference; the reference in "
+                "the precision below does not")
             phase_sd15(sd15_cfg, probe, args.rehearse)
     except SmokeFailure as e:
         print(f"[smoke] FAIL after {time.monotonic() - t0:.0f}s: {e}",

@@ -532,10 +532,8 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
         # it is dead to attention, which reads nothing of its row.
         first, last = pool.span(wpos)
         last = jnp.where(finished, -1, last)
-        # Grouped queries read the pool in the ``jax.numpy`` form, which
-        # needs no list of blocks.
-        work = None if fam.kv_heads else decode_attention.step_work(
-            last, T, fam.width, cache[0].dtype, first)
+        work = decode_attention.step_work(last, T, fam.width, cache[0].dtype,
+                                          first)
         logits, cache, counts = _decode_logits(
             fam, params, pool, cache, tok, wpos, (first, last), work, dtype,
             adapter_idx)
@@ -925,10 +923,9 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         "cache_leaves": cache_leaves(fam, gen_slots, T, dtype),
         "counters": dict(fam.counters),  # name -> what it counts
         "cache_dtype": dtype,  # of the paged lane's pages
-        # Rows decode attention reads a live slot's row in (grouped queries
-        # read whole rows: the ``jax.numpy`` form).
-        "read_block": (T if fam.kv_heads else
-                       decode_attention.read_block(T, fam.width, dtype)),
+        # Rows decode attention reads a live slot's row in, by the pool's
+        # width (grouped queries share it).
+        "read_block": decode_attention.read_block(T, fam.width, dtype),
         # What the scheduler counts with, in numpy (spans, summaries, the
         # passes of a prompt's attention, prompts a prefill dispatch).
         "rows": fam.rows,

@@ -35,6 +35,15 @@ Running max, denominator and the ``[H, D]`` accumulator live in VMEM scratch
 across a slot's blocks (flash_attention.py's pattern): reset at its block 0,
 written out at its last.
 
+Grouped queries (``heads`` queries over a pool of fewer K/V heads, so the
+pool is narrower than the queries) take the same kernel, the same list and
+the same blocks of K and V, each read once for all the queries: only the
+query block differs.  It is built outside the kernel as ``[heads, D]`` with
+head ``h``'s query in the columns of its K/V head ``h // group`` and zeros
+elsewhere, and row ``h`` of the ``[heads, D]`` result carries head ``h``'s
+output in those same columns, picked out afterwards.  ``D`` is the pool's
+width wherever a block is sized.
+
 A model calls :func:`attend` (with :func:`step_work` once a step, and
 :func:`read_block` for what it reports), which takes the kernel or the
 ``jax.numpy`` form by what it can observe (:func:`_kernel_block`).
@@ -125,7 +134,7 @@ def work_list(wpos, total: int, block_t: int, first=None):
 
 def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
             v_ref, o_ref, m_ref, l_ref, acc_ref, *, block_t: int,
-            head_dim: int):
+            head_dim: int, grouped: bool = False):
     i = pl.program_id(0)
     b = block_ref[i]
     last = wpos_ref[slot_ref[i]]
@@ -148,8 +157,11 @@ def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
     # comes from int32 iotas, whose (8, 128) tiling Mosaic will not relayout
     # to bfloat16's (16, 128).
     k = k_ref[...]
-    qh = jnp.where(own(), q_ref[...].astype(jnp.float32),      # [1, D] -> rows
-                   0.0).astype(k.dtype)
+    if grouped:
+        qh = q_ref[...]        # [rows, D], each head in its K/V head's columns
+    else:
+        qh = jnp.where(own(), q_ref[...].astype(jnp.float32),  # [1, D] -> rows
+                       0.0).astype(k.dtype)
     scores = jax.lax.dot_general(
         qh, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                   # [rows, bt]
@@ -168,24 +180,32 @@ def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
     @pl.when(b == last // block_t)
     def _():
         out = acc_ref[...] / l_ref[...]
-        # Each head keeps its own columns: one non-zero term a column.
-        o_ref[...] = jnp.where(own(), out, 0.0).sum(
-            axis=0, keepdims=True).astype(o_ref.dtype)
+        if grouped:
+            # Row h whole: the caller picks its K/V head's columns out.
+            o_ref[...] = out.astype(o_ref.dtype)
+        else:
+            # Each head keeps its own columns: one non-zero term a column.
+            o_ref[...] = jnp.where(own(), out, 0.0).sum(
+                axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "block_t", "interpret"))
 def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
                      layer, heads: int, block_t: int | None = None,
                      interpret: bool = False):
-    """q [S, D] (already scaled), cache_k / cache_v [L, S, T, D], ``layer``
-    which of the pool to read (an int32 scalar, traced or not: it is data,
-    prefetched beside the work list), wpos [S] int32 the last position each
-    slot may read (``wpos < T``; negative: the slot is dead, read nowhere,
-    its row zeros), ``first`` [S] the first (None: 0) → [S, D].  ``work``
-    is :func:`work_list` of the same ``wpos``, ``first`` and block length,
-    from a caller that builds it once for many layers."""
-    S, D = q.shape
-    T = cache_k.shape[2]
+    """q [S, heads * dh] (already scaled), cache_k / cache_v [L, S, T, D],
+    ``layer`` which of the pool to read (an int32 scalar, traced or not: it
+    is data, prefetched beside the work list), wpos [S] int32 the last
+    position each slot may read (``wpos < T``; negative: the slot is dead,
+    read nowhere, its row zeros), ``first`` [S] the first (None: 0) → [S,
+    heads * dh].  ``D`` is ``heads * dh``, or narrower where the queries are
+    grouped over ``D / dh`` K/V heads.  ``work`` is :func:`work_list` of the
+    same ``wpos``, ``first`` and block length, from a caller that builds it
+    once for many layers."""
+    S, Dq = q.shape
+    T, D = cache_k.shape[2:]
+    dh = Dq // heads
+    grouped = D != Dq
     bt = block_t or pick_block_t(T, D, cache_k.dtype)
     if T % bt:
         raise ValueError(f"block_t {bt} does not divide the pool's {T} "
@@ -196,16 +216,26 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
     first = _first_row(first, wpos)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     rows = -(-heads // 16) * 16  # the bf16 sublane tile
+    if grouped:
+        # Head h's query in the columns of K/V head h // group, [rows, D] a
+        # slot (the rows past the last head zeros).
+        kv = D // dh
+        owner = jnp.arange(heads) * kv // heads      # head h's K/V head
+        q = jnp.where((owner[:, None] == jnp.arange(kv))[None, :, :, None],
+                      q.reshape(S, heads, 1, dh), 0).reshape(S, heads, D)
+        q = jnp.pad(q, ((0, 0), (0, rows - heads), (0, 0)))
+    else:
+        q = q[:, None, :]
     kv_spec = pl.BlockSpec(
         (None, None, bt, D),
         lambda i, layer, slot, block, wpos, first:
         (layer[0], slot[i], block[i], 0))
     row_spec = pl.BlockSpec(
-        (None, 1, D),
+        (None, q.shape[1], D),
         lambda i, layer, slot, block, wpos, first: (slot[i], 0, 0))
     out = pl.pallas_call(
-        functools.partial(_kernel, block_t=bt, head_dim=D // heads),
-        out_shape=jax.ShapeDtypeStruct((S, 1, D), q.dtype),
+        functools.partial(_kernel, block_t=bt, head_dim=dh, grouped=grouped),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(count,),  # a dynamic bound: the live blocks and no more
@@ -218,9 +248,15 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="decode_attention",
-    )(layer, slot, block, wpos, first, q[:, None, :], cache_k, cache_v)
+    )(layer, slot, block, wpos, first, q, cache_k, cache_v)
     # No grid step visits a dead slot, so nothing wrote its row.
-    return jnp.where((wpos >= 0)[:, None], out[:, 0, :], 0)
+    live = (wpos >= 0)[:, None]
+    if not grouped:
+        return jnp.where(live, out[:, 0, :], 0)
+    # Row h's own columns: those of its K/V head.
+    out = jnp.take_along_axis(out[:, :heads].reshape(S, heads, kv, dh),
+                              owner[None, :, None, None], axis=2)
+    return jnp.where(live, out.reshape(S, Dq), 0)
 
 
 def _kernel_block(Tq, total, d, dtype):
@@ -284,22 +320,23 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None, first=None):
     tests compare the kernel with.
 
     A pool narrower than ``q`` holds fewer K/V heads than there are query
-    heads (``heads // kv_heads`` queries share each): those are read in a
-    ``jax.numpy`` form of their own (:func:`_attend_grouped`), whatever the
-    backend; the kernel takes one K/V head a query head.
+    heads (``heads // kv_heads`` queries share each): the kernel takes them
+    as it takes the others, by the pool's width, and where it does not run
+    they are read in a ``jax.numpy`` form of their own
+    (:func:`_attend_grouped`).
     """
     S, Tq, D = q.shape
     dh = D // heads
     q = q * dh ** -0.5
-    if cache_k.shape[-1] != D:
-        return _attend_grouped(q, cache_k[layer], cache_v[layer], wpos,
-                               first, heads)
-    bt = _kernel_block(Tq, cache_k.shape[2], D, cache_k.dtype)
+    bt = _kernel_block(Tq, *cache_k.shape[2:], cache_k.dtype)
     if bt is not None:
         return decode_attention(q[:, 0], cache_k, cache_v, wpos[:, 0], work,
                                 None if first is None else first[:, 0],
                                 layer=layer, heads=heads,
                                 block_t=bt)[:, None]
+    if cache_k.shape[-1] != D:
+        return _attend_grouped(q, cache_k[layer], cache_v[layer], wpos,
+                               first, heads)
     cache_k, cache_v = cache_k[layer], cache_v[layer]
     T = cache_k.shape[1]
     own = (jnp.arange(D) // dh)[None, :] == jnp.arange(heads)[:, None]

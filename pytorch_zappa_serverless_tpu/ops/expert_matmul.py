@@ -18,7 +18,10 @@ chip, under a mesh and in the tests, ``jax.lax.ragged_dot``, chosen by what
 the process can observe, as ops/decode_attention.attend chooses its form.
 The kernel takes an expert's matrix whole in ``K`` and in blocks of ``N``
 sized by their bytes: a decode step is bound by the experts' bytes (a handful
-of rows an expert), and each matrix reached is read once.
+of rows an expert), and each matrix reached is read once.  An expert is
+``W2 relu(W1 u)^2`` (``relu2``) or gated, ``W2 (silu(W1 u) * (W3 u))``: the
+gated form hands the kernel ``W1`` and ``W3`` together (``up=``), a block of
+each a grid step, and the product is taken in float32 before it is rounded.
 
 ``interpret=True`` runs the kernel itself on the CPU for the tests.
 """
@@ -63,6 +66,16 @@ def group_sizes(group, held: int):
         jnp.int32)
 
 
+# What :func:`counters` counts, ``(name, what)`` each, as a family declares
+# them (models/decoder.Family.counters).
+COUNTERS = (("expert_assignments_held",
+             "Rows routed to the experts held here"),
+            ("experts_touched", "Held experts that at least one row "
+             "reached, a layer a step"),
+            ("expert_load_max", "The most rows on one held expert, "
+             "a layer a step"))
+
+
 def counters(sizes):
     """What a layer's routing did here, int32 [3]: rows routed to held
     experts, held experts with at least one row, the most rows on one."""
@@ -91,13 +104,18 @@ def work_list(sizes, rows: int, tile: int):
         jnp.int32)
 
 
-def _kernel(offs_ref, group_ref, tile_ref, x_ref, w_ref, o_ref, *, tile: int,
+def _kernel(offs_ref, group_ref, tile_ref, x_ref, *refs, tile: int,
             relu2: bool):
+    *w_refs, o_ref = refs
     i = pl.program_id(1)
     g = group_ref[i]
-    acc = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    acc = jnp.dot(x_ref[...], w_refs[0][...],
+                  preferred_element_type=jnp.float32)
     if relu2:
         acc = jnp.square(jnp.maximum(acc, 0.0))
+    if len(w_refs) == 2:  # gated: silu(x @ gate) * (x @ up)
+        acc = jax.nn.silu(acc) * jnp.dot(x_ref[...], w_refs[1][...],
+                                         preferred_element_type=jnp.float32)
     row = tile_ref[i] * tile + jax.lax.broadcasted_iota(jnp.int32, acc.shape,
                                                         0)
     mine = (row >= offs_ref[g]) & (row < offs_ref[g + 1])
@@ -128,18 +146,22 @@ def pick_tile(rows: int, groups: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("relu2", "tile", "interpret"))
-def expert_matmul_kernel(x, w, sizes, *, relu2: bool = False,
+def expert_matmul_kernel(x, w, sizes, up=None, *, relu2: bool = False,
                          tile: int | None = None, interpret: bool = False):
     """x [M, K] sorted by group, w [G, K, N], sizes [G] → [M, N] in ``x``'s
-    dtype; rows past ``sizes.sum()`` hold nothing meaningful."""
+    dtype; rows past ``sizes.sum()`` hold nothing meaningful.  With ``up``
+    [G, K, N] the gated form, ``silu(x @ w) * (x @ up)``: both of an
+    expert's blocks in one grid step, each half the bytes."""
     M, K = x.shape
     G, _, N = w.shape
     tm = tile or pick_tile(M, G)
     rows = -(-M // tm) * tm
     if rows != M:
         x = jnp.pad(x, ((0, rows - M), (0, 0)))
-    tn = pick_block_n(K, N, w.dtype.itemsize)
+    mats = (w,) if up is None else (w, up)
+    tn = pick_block_n(K, N, w.dtype.itemsize * len(mats))
     offs, group, tiles, count = work_list(sizes.astype(jnp.int32), rows, tm)
+    w_spec = pl.BlockSpec((None, K, tn), lambda n, i, offs, g, t: (g[i], 0, n))
     out = pl.pallas_call(
         functools.partial(_kernel, tile=tm, relu2=relu2),
         out_shape=jax.ShapeDtypeStruct((rows, N), x.dtype),
@@ -150,15 +172,14 @@ def expert_matmul_kernel(x, w, sizes, *, relu2: bool = False,
             grid=(N // tn, count),
             in_specs=[
                 pl.BlockSpec((tm, K), lambda n, i, offs, g, t: (t[i], 0)),
-                pl.BlockSpec((None, K, tn),
-                             lambda n, i, offs, g, t: (g[i], 0, n))],
+                *[w_spec] * len(mats)],
             out_specs=pl.BlockSpec((tm, tn),
                                    lambda n, i, offs, g, t: (t[i], n))),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="expert_matmul",
-    )(offs, group, tiles, x, w)
+    )(offs, group, tiles, x, *mats)
     return out[:M]
 
 
@@ -169,33 +190,43 @@ def _use_kernel() -> bool:
     return jax.default_backend() == "tpu" and jax.device_count() == 1
 
 
-def expert_matmul(x, w, sizes, relu2: bool = False):
+def expert_matmul(x, w, sizes, relu2: bool = False, up=None):
     """Each group's rows of x [M, K] (sorted by group) times its expert's
     matrix w [G, K, N] → [M, N]; ``relu2`` squares the positive part of the
-    float32 product before it is rounded to ``x``'s dtype.  Rows past
-    ``sizes.sum()`` belong to no group here: the caller masks them."""
+    float32 product before it is rounded to ``x``'s dtype, and ``up`` [G, K,
+    N] makes it the gate of ``silu(x @ w) * (x @ up)``, float32 until the
+    product is rounded.  Rows past ``sizes.sum()`` belong to no group here:
+    the caller masks them."""
     if _use_kernel():
-        return expert_matmul_kernel(x, w, sizes, relu2=relu2)
-    out = jax.lax.ragged_dot(x, w, sizes.astype(jnp.int32),
-                             preferred_element_type=jnp.float32)
+        return expert_matmul_kernel(x, w, sizes, up, relu2=relu2)
+
+    def dot(m):
+        return jax.lax.ragged_dot(x, m, sizes.astype(jnp.int32),
+                                  preferred_element_type=jnp.float32)
+
+    out = dot(w)
     if relu2:
         out = jnp.square(jnp.maximum(out, 0.0))
+    if up is not None:
+        out = jax.nn.silu(out) * dot(up)
     return out.astype(x.dtype)
 
 
-def experts(u, w1, w2, weights, group):
-    """The held experts' part of the layer: u [N, K] the rows in the latent
-    width, w1 [held, K, F] and w2 [held, F, K] the experts' matrices,
-    ``weights`` and ``group`` [N, top_k] from :func:`route` → ``(out [N, K]
-    float32, sizes [held])``: ``sum_e weight_e W2_e relu(W1_e u)^2`` over the
-    assignments that fall on a held expert."""
+def experts(u, w1, w2, weights, group, w3=None):
+    """The held experts' part of the layer: u [N, K] the rows in the width
+    the experts read, w1 [held, K, F] and w2 [held, F, K] the experts'
+    matrices, ``weights`` and ``group`` [N, top_k] from :func:`route` →
+    ``(out [N, K] float32, sizes [held])``: ``sum_e weight_e W2_e relu(W1_e
+    u)^2`` over the assignments that fall on a held expert, or, with w3
+    [held, K, F], the gated expert ``W2_e (silu(W1_e u) * (W3_e u))``."""
     N, top_k = group.shape
     held = w1.shape[0]
     flat = group.reshape(-1)
     order = jnp.argsort(flat, stable=True)       # assignment rows by group
     sizes = group_sizes(group, held)
     rows = u[order // top_k]                     # [N * top_k, K]
-    y = expert_matmul(expert_matmul(rows, w1, sizes, relu2=True), w2, sizes)
+    y = expert_matmul(expert_matmul(rows, w1, sizes, relu2=w3 is None, up=w3),
+                      w2, sizes)
     y = jnp.where((jnp.arange(N * top_k) < sizes.sum())[:, None], y, 0)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(N * top_k))
     y = y[back].reshape(N, top_k, -1).astype(jnp.float32)

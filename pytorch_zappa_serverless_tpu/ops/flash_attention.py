@@ -199,6 +199,7 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
             pltpu.VMEM((block_q, d_p), jnp.float32),      # fp32 accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(*operands)
     return jnp.transpose(out[:, :, :Tq, :D], (0, 2, 1, 3))
 

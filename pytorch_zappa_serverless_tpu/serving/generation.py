@@ -718,19 +718,16 @@ class GenerationScheduler:
         with self.timeline.phase("segment.fetch"):
             # The slot state is still what the segment was launched with;
             # what reads it comes before the wait, while the device works.
+            # It is booked after the wait, beside ``segment_rounds``: a
+            # scrape during the wait would see sums one round ahead of their
+            # count (a capture of 8 rounds read a share 12% high by it).
             live = ~self._finished
             at = np.minimum(self._pos[live], self.total - 1)
             first, last = self._rows.span(at, self.rows)
             held = int((last - first + 1).sum())
-            self.span_rows_sum += held
-            self.summary_rows_sum += int(
-                self._rows.summaries(at, self.rows).sum())
-            self.live_positions_sum += int(at.sum()) + len(at)
-            self.kv_live_sum += held / (self.slots * self.rows)
+            summaries = int(self._rows.summaries(at, self.rows).sum())
             rb = self.read_block
-            self.kv_read_sum += float(
-                ((last // rb - first // rb + 1) * rb).sum()
-            ) / (self.slots * self.rows)
+            read = float(((last // rb - first // rb + 1) * rb).sum())
             inflight, self._inflight = self._inflight, None
             # The round's one blocking wait: [S, seg + 4 + C], emits, the
             # carries, then the model's counts (``build_gen_kernels``);
@@ -751,6 +748,11 @@ class GenerationScheduler:
             self._budget -= n
             self._finished[done] = True
             self._tok[done] = self.eos_id
+            self.span_rows_sum += held
+            self.summary_rows_sum += summaries
+            self.live_positions_sum += int(at.sum()) + len(at)
+            self.kv_live_sum += held / (self.slots * self.rows)
+            self.kv_read_sum += read / (self.slots * self.rows)
             self.device_rounds += 1
             self.segment_rounds += 1
             free = len(self._free) + int(done.sum())

@@ -418,3 +418,142 @@ def test_decode_segment_compiles_for_v5e_with_one_work_list_a_step(
     text = segment.lower(*args).compile().as_text()
     assert built == [(total, pick_block_t(total, d, jnp.bfloat16))]
     assert text.count("tpu_custom_call") >= layers
+
+
+# Decode attention with grouped queries: the pool is the K/V heads' width and
+# the block is sized by it.  LFM2's (32 queries over 8 heads of 64, 32 slots
+# of 8,704 rows) and Nemotron-H's (32 over 2 heads of 128, 1,024 rows).
+GROUPED_POOLS = {"lfm2": (2, 32, 8704, 8, 64, 32, 512),
+                 "nemotron": (1, 32, 1024, 2, 128, 32, 1024)}
+
+
+@pytest.mark.parametrize("pool", list(GROUPED_POOLS))
+def test_grouped_decode_attention_compiles_for_v5e(one_chip, pool):
+    layers, slots, total, kv, dh, heads, block = GROUPED_POOLS[pool]
+    d = kv * dh
+    bt = pick_block_t(total, d, jnp.bfloat16)
+    assert bt == block
+    text = _compile(
+        lambda q, ck, cv, wpos, first: decode_attention(
+            q, ck, cv, wpos, work_list(wpos, total, bt, first), first,
+            layer=layers - 1, heads=heads, block_t=bt),
+        one_chip,
+        ((slots, heads * dh), jnp.bfloat16),
+        ((layers, slots, total, d), jnp.bfloat16),
+        ((layers, slots, total, d), jnp.bfloat16), ((slots,), jnp.int32),
+        ((slots,), jnp.int32))
+    assert "tpu_custom_call" in text and "decode_attention" in text
+    assert not chip_smoke.pool_sized_moves(text, slots * total * d)
+
+
+# The gated grouped matmul at LFM2's widths: a decode step's 128 assignment
+# rows over 64 experts, and a prefill's 32,768.
+@pytest.mark.parametrize("rows", [128, 32768])
+def test_gated_expert_matmul_compiles_for_v5e(one_chip, rows):
+    from pytorch_zappa_serverless_tpu.ops.expert_matmul import (
+        expert_matmul_kernel)
+
+    text = _compile(
+        lambda x, gate, up, sizes: expert_matmul_kernel(x, gate, sizes, up),
+        one_chip, ((rows, 2048), jnp.bfloat16),
+        *[((64, 2048, 1536), jnp.bfloat16)] * 2, ((64,), jnp.int32))
+    assert "tpu_custom_call" in text and "expert_matmul" in text
+
+
+def _lfm2_shapes(cfg, sd):
+    """LFM2's parameter tree as shapes (``sd(*shape, dtype=)``)."""
+    D = cfg.hidden_size
+
+    def vec(n=D):
+        return sd(n, dtype=jnp.float32)
+
+    params = {"embed": sd(cfg.vocab_size, D), "norm": vec()}
+    for i, kind in enumerate(cfg.layer_types):
+        p = {"operator_norm": vec(), "ffn_norm": vec()}
+        if kind == "conv":
+            p.update(in_proj=sd(D, 3 * D), out_proj=sd(D, D),
+                     conv_w=sd(cfg.conv_kernel, D, dtype=jnp.float32))
+        else:
+            q, kv = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+            p.update(q=sd(D, q), k=sd(D, kv), v=sd(D, kv), o=sd(q, D),
+                     q_norm=vec(cfg.head_dim), k_norm=vec(cfg.head_dim))
+        if i < cfg.dense_layers:
+            F = cfg.dense_width
+            p.update(w1=sd(D, F), w3=sd(D, F), w2=sd(F, D))
+        else:
+            E, F = cfg.experts_held, cfg.expert_width
+            p.update(router=sd(D, cfg.experts_published),
+                     expert_bias=vec(cfg.experts_published),
+                     w1=sd(E, D, F), w3=sd(E, D, F), w2=sd(E, F, D))
+        params[f"layer{i}"] = p
+    return params
+
+
+@pytest.mark.parametrize("program", ["segment", "prefill"])
+def test_lfm2_programs_compile_for_v5e_and_hold_no_score_array(
+        one_chip, monkeypatch, program):
+    """The benchmark's ten layers of LFM2 at the published widths, with the
+    kernels a chip takes (the pickers ask the backend, which is the CPU
+    here, so the test steers them).  The 32-slot segment over 8,704 rows:
+    ``decode_attention`` once an attention layer and ``expert_matmul`` twice
+    an expert layer, and temporaries under 0.3 GB (the query blocks, the
+    routed rows; 11.7 GB of weights and pool are arguments).  The prefill of
+    one prompt of 8,192 positions: the flash form, so no float32 array with
+    the 32 heads and two dimensions of 8,192 (``[1, 32, P, P]`` is 8.6 GB),
+    and temporaries under 1.5 GB: what is left of 16 GB beside 11.7."""
+    import functools
+    import re
+
+    from pytorch_zappa_serverless_tpu.models import lfm2
+    from pytorch_zappa_serverless_tpu.ops import (
+        expert_matmul as expert_matmul_module)
+
+    cfg = lfm2.config_from_arch({"layer_types": lfm2.PUBLISHED.layer_types[:10],
+                                 "eos_id": 65536})
+    slots, P, total = 32, 8192, 8192 + 384
+    monkeypatch.setattr(
+        decode_attention_module, "_kernel_block",
+        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
+                                     if Tq == 1 else None))
+    monkeypatch.setattr(expert_matmul_module, "_use_kernel", lambda: True)
+    monkeypatch.setattr(lfm2.GroupedFlashRows, "prompt_form",
+                        lambda self, *shape: "flash")
+    monkeypatch.setattr(lfm2, "flash_attention", functools.partial(
+        flash_attention, interpret=False))
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = _lfm2_shapes(cfg, sd)
+    fam = lfm2.family(cfg)
+    T = fam.rows.count(total)
+    leaves = [sd(*shape, dtype=dt) for shape, dt in decoder.cache_leaves(
+        fam, slots, T, jnp.bfloat16)]
+    assert [leaf.shape for leaf in leaves] == [
+        (2, 32, 8704, 512), (2, 32, 8704, 512), (8, 32, 2, 2048)]
+    if program == "segment":
+        i32, f32 = sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.float32)
+        done = jax.jit(
+            lambda p, ck, cv, tail, tok, pos, st, fin, temp, seeds, topk,
+            topp: decoder.decode_segment(
+                fam, p, decoder.slot_pool(ck, cv, fam.rows), tok, pos, st,
+                fin, temp, seeds, 8, jnp.bfloat16, top_k=topk, top_p=topp,
+                state=(tail,)),
+            donate_argnums=(1, 2, 3)).lower(
+                params, *leaves, i32, i32, i32, sd(slots, dtype=jnp.bool_),
+                f32, i32, i32, f32).compile()
+        text = done.as_text()
+        assert text.count("decode_attention") >= 2
+        assert text.count("tpu_custom_call") == 2 + 2 * 8
+        assert done.memory_analysis().temp_size_in_bytes < 0.3e9
+        return
+    done = jax.jit(lambda p, tokens, lengths: decoder.prefill(
+        fam, p, tokens, lengths, total, jnp.bfloat16)).lower(
+            params, sd(1, P, dtype=jnp.int32), sd(1, dtype=jnp.int32)
+        ).compile()
+    text = done.as_text()
+    assert text.count("tpu_custom_call") == 2 + 2 * 8
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        shape = [int(d) for d in dims.split(",")]
+        assert not (32 in shape and shape.count(P) >= 2), shape
+    assert done.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -272,6 +272,13 @@ _EVA_ARCH = {"vocab_size": 48, "hidden_size": 32, "layers": 3, "heads": 2,
              "intermediate_size": 48, "max_positions": 256, "window_size": 32,
              "chunk_size": 4, "num_pred_heads": 2, "rope_theta": 100.0,
              "init_std": 0.3, "eos_id": 48}
+_NEMOTRON_ARCH = {
+    "vocab_size": 96, "vocab_published": 384, "hidden_size": 64,
+    "pattern": "MEM*EME", "heads": 4, "kv_heads": 2, "head_dim": 16,
+    "mamba_heads": 8, "mamba_head_dim": 8, "ssm_state": 16, "n_groups": 2,
+    "chunk_size": 8, "experts_published": 16, "experts_held": 4,
+    "expert_offset": 4, "top_k": 3, "latent_size": 32, "expert_width": 48,
+    "shared_width": 80, "max_positions": 512, "init_std": 0.1, "eos_id": 96}
 _SLOT = {"max_new_tokens": 8, "gen_slots": 3, "segment_tokens": 4}
 LOWERED = {
     "gpt2": ("gpt2", "bfloat16", (16,), {
@@ -281,6 +288,8 @@ LOWERED = {
         "quantize_min_size": 1024}),
     "evabyte": ("evabyte", "float32", (64,), {
         **_SLOT, "max_new_tokens": 16, "arch": _EVA_ARCH}),
+    "nemotron": ("nemotron_h", "bfloat16", (16,), {
+        **_SLOT, "arch": _NEMOTRON_ARCH}),
 }
 # sha256 of ``jit(...).lower(...).as_text()`` (no source locations in it) of
 # the slot lane's three programs as PR 43 (commit 877073a) lowered them for
@@ -330,7 +339,8 @@ def lowered_programs():
         rows = jax.eval_shape(k["prefill"], params, payload)[1:]
         cache = tuple(jax.ShapeDtypeStruct(shape, dt)
                       for shape, dt in meta["cache_leaves"])
-        assert len(cache) == 2 and not meta["counters"]
+        assert name == "nemotron" or (
+            len(cache) == 2 and not meta["counters"])
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
 
         def per_slot(dt):
@@ -366,3 +376,52 @@ def test_two_leaf_families_lower_to_the_text_they_had(case, lowered_programs):
         f"meant to change that program, or JAX moved, pin {digest!r} in "
         f"PR43_TEXT; if not, the seam leaked into a family it should not "
         f"touch")
+
+
+# Nemotron-H's three programs as PR 46 (commit 385add5) lowered them for the
+# CPU, pinned by the PR that gave ops/decode_attention.py grouped queries in
+# its kernel and ops/expert_matmul.py a gated form (48): off the chip the
+# grouped ``jax.numpy`` form and the ``relu2`` path of ``ragged_dot`` are
+# what they were.  On the chip its attention layer takes the kernel now, which
+# no CPU lowering shows (PERF.md section 6, PR 48 has the chip's readings).
+PR46_NEMOTRON_TEXT = {
+    "nemotron-prefill": "fb2850b2605d104e8f8c6ba2bb6634fe5566040ea09dac06ac2db1cf995beafb",
+    "nemotron-insert_from": "7e893d85d6bad170505cb2f66bec24d7d818a1dc9820b29e8140f57af40424dc",
+    "nemotron-segment": "e5eb8a1f461000c1bcb465db0d1385e2ea55ed080a1b99cd18c33a01cd54be02",
+}
+
+
+@pytest.mark.parametrize("case", list(PR46_NEMOTRON_TEXT))
+def test_nemotron_lowers_to_the_text_it_had_before_the_grouped_kernel(
+        case, lowered_programs):
+    import hashlib
+
+    family, program = case.split("-")
+    text = lowered_programs(family)[program].as_text()
+    digest = hashlib.sha256(text.encode()).hexdigest()
+    assert digest == PR46_NEMOTRON_TEXT[case], (
+        f"{case} lowers to other text than is pinned (jax {jax.__version__}, "
+        f"pinned under 0.9.0).  If this PR meant to change that program, or "
+        f"JAX moved, pin {digest!r} in PR46_NEMOTRON_TEXT")
+
+
+def test_lfm2_declares_its_leaves_and_its_counters():
+    """The third family with more than K and V: two attention layers' rows
+    the K/V heads wide, and the last two rows of ``u`` of each of the eight
+    convolutions, through the same ``cache_leaves`` and ``state=``."""
+    from pytorch_zappa_serverless_tpu.models import lfm2
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul
+
+    cfg = lfm2.config_from_arch(
+        {"layer_types": lfm2.PUBLISHED.layer_types[:10]})
+    fam = lfm2.family(cfg)
+    assert (fam.layers, fam.kv_layers, fam.kv_heads, fam.heads, fam.width) \
+        == (10, 2, 8, 32, 512)
+    assert fam.state == ((8, (2, 2048), jnp.bfloat16),)
+    assert fam.positions is None and fam.counters == expert_matmul.COUNTERS
+    assert [fam.cache_index(i) for i in range(10)] == [
+        0, 1, 0, 2, 3, 4, 1, 5, 6, 7]
+    T = fam.rows.count(8192 + 384)
+    assert D.cache_leaves(fam, 32, T, jnp.bfloat16) == (
+        ((2, 32, 8704, 512), jnp.bfloat16), ((2, 32, 8704, 512), jnp.bfloat16),
+        ((8, 32, 2, 2048), jnp.bfloat16))

@@ -252,6 +252,48 @@ async def test_kv_live_share_counts_the_generating_slots(engine):
         await sched.stop()
 
 
+async def test_a_scrape_during_the_wait_sees_sums_and_count_of_the_same_rounds(
+        engine):
+    """Every ``{sum, count}`` pair of ``gen_snapshot`` is booked together,
+    after the round's one wait: a scrape that lands inside the wait (most of
+    a round, on the chip) reads the rows of as many rounds as it counts.  A
+    profile capture divides one by the other over a handful of rounds."""
+    sched = _scheduler(engine).start()
+    cm = engine.model("gpt2")
+    scraped = []
+
+    class Fetched:
+        def __init__(self, packed):
+            self.packed = packed
+
+        def __array__(self, dtype=None, copy=None):
+            scraped.append(sched.gen_snapshot())
+            return np.asarray(self.packed)
+
+    segment = sched._segment
+
+    def watched(*args):
+        packed, *cache = segment(*args)
+        return (Fetched(packed), *cache)
+
+    sched._segment = watched
+    try:
+        sample = cm.servable.preprocess({"input_ids": [5, 6, 7]})
+        await asyncio.wait_for(sched.submit(sample, max_new=7).done, 60)
+        assert len(scraped) >= 2
+        for snap in scraped + [sched.gen_snapshot()]:
+            rounds = snap["span_rows"]["count"]
+            # One slot at position 3, 3 more positions a round.
+            held = sum(3 + 3 * r + 1 for r in range(rounds))
+            assert snap["span_rows"]["sum"] == held
+            assert snap["live_positions"]["sum"] == held
+            assert snap["kv_live_share"]["sum"] == pytest.approx(
+                held / (2 * 20), abs=1e-6)
+            assert snap["kv_read_share"]["sum"] == pytest.approx(0.5 * rounds)
+    finally:
+        await sched.stop()
+
+
 async def test_boot_log_names_the_prompt_form_of_every_prefill(engine,
                                                                monkeypatch):
     """``generation lane ready`` says, per prefill bucket and admission
