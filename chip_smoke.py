@@ -1636,14 +1636,32 @@ def time_grouped_attention(shapes, on_device: bool, interpret: bool):
     return rows
 
 
+def expert_sizes(kind: str, experts: int, rows_an_expert: int, rng):
+    """Rows on each of ``experts`` experts, int64 [experts]: ``uniform``
+    gives every expert ``rows_an_expert`` (every group then begins on a tile
+    boundary, which a router's groups do not); ``routed`` draws ``experts x
+    rows_an_expert`` assignments evenly over the experts, as a balanced
+    router spreads a prompt's ``top_k`` x P."""
+    import numpy as np
+
+    if kind == "uniform":
+        return np.full(experts, rows_an_expert, np.int64)
+    return rng.multinomial(experts * rows_an_expert,
+                           np.ones(experts) / experts)
+
+
 def time_gated_experts(rows_an_expert, on_device: bool, interpret: bool,
-                       experts: int = 64, width: int = 2048, inner: int = 1536):
+                       experts: int = 64, width: int = 2048,
+                       inner: int = 1536, kinds=("uniform", "routed")):
     """The gated ``expert_matmul`` (``silu(x W1) * (x W3)``) and the plain
-    one after it (``W2``), alone, at ``rows_an_expert`` rows on each of
-    ``experts`` experts: device microseconds a call beside the share of 819
-    GB/s (every expert's matrices once, the rows in and out) and of 197
-    TFLOP/s (two operations a weight a row), and the largest relative
-    difference from ``jax.lax.ragged_dot``.  Off the device no time."""
+    one after it (``W2``), alone, at a mean of ``rows_an_expert`` rows on
+    each of ``experts`` experts, with the sizes of each of ``kinds``
+    (:func:`expert_sizes`): device microseconds a call beside the share of
+    819 GB/s (every expert's matrices once, the rows in and out) and of 197
+    TFLOP/s (two operations a weight a row), the grid steps a block of
+    columns the call made (``visits``) over the fewest tiles that hold its
+    groups (``least``), and the largest relative difference from
+    ``jax.lax.ragged_dot``.  Off the device no time."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1659,25 +1677,42 @@ def time_gated_experts(rows_an_expert, on_device: bool, interpret: bool,
     w1, w3, w2 = (w(experts, width, inner), w(experts, width, inner),
                   w(experts, inner, width))
     out = []
-    for r in rows_an_expert:
+    for r, kind in ((r, k) for r in rows_an_expert for k in kinds):
         M = experts * r
-        sizes = jnp.full((experts,), r, jnp.int32)
+        drawn = expert_sizes(kind, experts, r, rng)
+        sizes = jnp.asarray(drawn, jnp.int32)
         x = jnp.asarray(rng.standard_normal((M, width)), jnp.bfloat16)
         y = jnp.asarray(rng.standard_normal((M, inner)) * 0.1, jnp.bfloat16)
         want = (jax.nn.silu(jax.lax.ragged_dot(
             x, w1, sizes, preferred_element_type=jnp.float32))
             * jax.lax.ragged_dot(x, w3, sizes,
                                  preferred_element_type=jnp.float32))
-        got = em.expert_matmul_kernel(x, w1, sizes, w3, interpret=interpret)
+        chosen = em.plan(M, width, inner, experts, 2)
+        kw = {"interpret": interpret}
+        if chosen.regime == "tiles":
+            # The rows where ``experts`` would write them, each group on a
+            # multiple of the tile: the kernel alone.
+            at = em.lay_out(sizes, M, chosen.tile)
+            rows = em.laid_rows(M, experts, chosen.tile)
+            x, y = (jnp.zeros((rows, a.shape[1]), a.dtype).at[at].set(a)
+                    for a in (x, y))
+            kw.update(tile=chosen.tile, laid_out=chosen.tile)
+            visits = int(em.tile_list(sizes, rows // chosen.tile,
+                                      chosen.tile)[-1])
+        else:
+            at = jnp.arange(M)
+            visits = int(em.work_list(
+                sizes, -(-M // chosen.tile) * chosen.tile, chosen.tile)[3])
+        got = em.expert_matmul_kernel(x, w1, sizes, w3, **kw)[at]
         off = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)
                             / (jnp.abs(want) + 1e-2)))
-        assert off <= 2 ** -6, (r, off)  # one rounding to bfloat16 apart
+        assert off <= 2 ** -6, (r, kind, off)  # one rounding to bfloat16
         for name, a, mats in (("gated", x, (w1, w3)), ("down", y, (w2,))):
             @jax.jit
             def chain(a, *mats):
                 for _ in range(_TIMED_CALLS):  # each waits for the last
                     o = em.expert_matmul_kernel(a, mats[0], sizes, *mats[1:],
-                                                interpret=interpret)
+                                                **kw)
                     a = a + (o[:, :1] * 1e-6).astype(a.dtype)
                 return a
 
@@ -1686,8 +1721,11 @@ def time_gated_experts(rows_an_expert, on_device: bool, interpret: bool,
             moved = (len(mats) * experts * width * inner + M * a.shape[1]
                      + M * n_out) * 2
             flops = 2 * M * width * inner * len(mats)
-            row = {"rows_an_expert": r, "call": name, "tile": em.pick_tile(
-                M, experts), "max_rel_diff": round(off, 5)}
+            row = {"rows_an_expert": r, "sizes": kind, "call": name,
+                   "regime": chosen.regime, "tile": chosen.tile,
+                   "visits": visits,
+                   "least": int(-(-drawn // chosen.tile).sum()),
+                   "max_rel_diff": round(off, 5)}
             if on_device:
                 us = _busy_us(lambda: chain(a, *mats))
                 row.update(us_a_call=us,
@@ -1705,8 +1743,9 @@ def _lfm2_child(rehearse: bool) -> None:
     Alone, by the profiler's clock: ``decode_attention`` with grouped
     queries at 32 slots of 2k, 4k and 8k live rows and at Nemotron-H's shape
     beside the ``jax.numpy`` grouped form; the gated ``expert_matmul`` at 2,
-    128, 384 and 512 rows an expert; the prompt attention's two forms at the
-    four buckets (the ``jax.numpy`` form where its scores fit).
+    128, 256, 384 and 512 rows an expert, the sizes even and as a router
+    draws them, each by the kernel's own plan; the prompt attention's two
+    forms at the four buckets (the ``jax.numpy`` form where its scores fit).
 
     Then the servable (``decoder.make_servable`` over the tree the benchmark
     stages: the program's own seeded weights, the routers' biases balanced)
@@ -1761,7 +1800,7 @@ def _lfm2_child(rehearse: bool) -> None:
     for row in report["decode_attention grouped"]:
         print("lfm2 decode_attention " + json.dumps(row), flush=True)
     report["expert_matmul gated"] = time_gated_experts(
-        (2, 4) if rehearse else (2, 128, 384, 512), on_device,
+        (2, 4) if rehearse else (2, 128, 256, 384, 512), on_device,
         interpret=rehearse, experts=cfg.experts_held, width=cfg.hidden_size,
         inner=cfg.expert_width)
     for row in report["expert_matmul gated"]:
@@ -2094,7 +2133,7 @@ def _burst_child(rehearse: bool) -> None:
                 tok, pos, st = (packed[:, 8 + i].copy() for i in range(3))
                 fin = packed[:, 11].astype(bool)
             jax.effects_barrier()
-            assert bool(jnp.isfinite(ck).all() & jnp.isfinite(cv).all()), \
+            assert all(bool(jnp.isfinite(leaf).all()) for leaf in cache[:2]), \
                 "the pool holds a value that is not finite"
             # A step emits the token decided before it: the 16 emitted are
             # the prefill's and 15 steps', and the 16th step's is the carry.

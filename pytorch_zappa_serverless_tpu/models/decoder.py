@@ -144,7 +144,9 @@ class Family:
     family with ``state`` or ``counters`` has its layer called with two more
     keywords, ``state=`` and ``count=`` (:func:`_trunk`); ``counters`` says,
     ``(name, what it counts)`` each, the int32 counts a decode step sums
-    (:func:`segment_scan`).
+    (:func:`segment_scan`).  ``expert_plan(rows)`` is what the family's
+    routed experts run for ``rows`` rows of a program
+    (ops/expert_matmul.plan_summary), for the lane's boot log.
     """
     embed: Callable
     positions: Callable | None
@@ -165,6 +167,7 @@ class Family:
     state: tuple = ()
     cache_index: Callable = lambda i: i
     counters: tuple = ()
+    expert_plan: Callable | None = None
 
 
 def cache_leaves(fam: Family, slots: int, T: int, dtype) -> tuple:
@@ -922,6 +925,7 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         # K, V, then the family's state.
         "cache_leaves": cache_leaves(fam, gen_slots, T, dtype),
         "counters": dict(fam.counters),  # name -> what it counts
+        "expert_plan": fam.expert_plan,  # rows of a program -> its plan
         "cache_dtype": dtype,  # of the paged lane's pages
         # Rows decode attention reads a live slot's row in, by the pool's
         # width (grouped queries share it).

@@ -286,6 +286,9 @@ def family(cfg: LFM2Config, dtype=jnp.bfloat16) -> Family:
                 (cfg.conv_kernel - 1, cfg.hidden_size), dtype),),
         cache_index=index.__getitem__,
         counters=expert_matmul.COUNTERS,
+        expert_plan=lambda rows: expert_matmul.plan_summary(
+            rows, cfg.top_k, cfg.hidden_size, cfg.expert_width,
+            cfg.experts_held, True, jnp.dtype(dtype).itemsize),
         eos_id=cfg.eos_id, max_positions=cfg.max_positions,
         vocab_size=cfg.vocab_size,
         rows=GroupedFlashRows(cfg.kv_heads,

@@ -338,6 +338,9 @@ def family(cfg: NemotronHConfig, dtype=jnp.bfloat16) -> Family:
                    "reached, a layer a step"),
                   ("expert_load_max", "The most rows on one held expert, "
                    "a layer a step")),
+        expert_plan=lambda rows: expert_matmul.plan_summary(
+            rows, cfg.top_k, cfg.latent_size, cfg.expert_width,
+            cfg.experts_held, False, jnp.dtype(dtype).itemsize),
         eos_id=cfg.eos_id, max_positions=cfg.max_positions,
         vocab_size=cfg.vocab_size,
         rows=GroupedRows(cfg.kv_heads))

@@ -564,6 +564,7 @@ class GenerationScheduler:
                   read_block=self.read_block,
                   prompt_buckets=list(self.prompt_buckets),
                   prompt_forms=self._prompt_forms(),
+                  expert_plans=self._expert_plans(),
                   cache_leaves=[
                       {"shape": list(shape), "dtype": str(np.dtype(dt)),
                        "bytes": int(np.prod(shape)) * np.dtype(dt).itemsize}
@@ -578,6 +579,18 @@ class GenerationScheduler:
                 (min(self._rows.prefill_batch(bucket) or self.slots,
                      self.slots) - 1).bit_length() + 1)}
             for bucket in self.prompt_buckets}
+
+    def _expert_plans(self) -> dict:
+        """What a family with routed experts says their grouped matmul runs
+        (ops/expert_matmul.plan_summary): for the segment's rows, and per
+        prefill bucket for the rows of its largest dispatch."""
+        plan = self.cm.servable.meta["continuous"].get("expert_plan")
+        if plan is None:
+            return {}
+        return {"segment": plan(self.slots), **{
+            str(bucket): plan(bucket * min(
+                self._rows.prefill_batch(bucket) or self.slots, self.slots))
+            for bucket in self.prompt_buckets}}
 
     # -- device kernels (all called on the runner's dispatch thread) --------
     def _ensure_cache(self):

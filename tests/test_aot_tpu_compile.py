@@ -446,18 +446,100 @@ def test_grouped_decode_attention_compiles_for_v5e(one_chip, pool):
     assert not chip_smoke.pool_sized_moves(text, slots * total * d)
 
 
-# The gated grouped matmul at LFM2's widths: a decode step's 128 assignment
-# rows over 64 experts, and a prefill's 32,768.
-@pytest.mark.parametrize("rows", [128, 32768])
-def test_gated_expert_matmul_compiles_for_v5e(one_chip, rows):
-    from pytorch_zappa_serverless_tpu.ops.expert_matmul import (
-        expert_matmul_kernel)
+# ``experts`` at the rows a program hands it, by the kernel's own plan:
+# LFM2's gated experts at a decode step's 32 rows and at its four buckets (4
+# assignments a token), and Nemotron-H's prefill dispatch (8 x 512 tokens,
+# 22 a token, ``relu2``, a quarter held): the sort, the rows laid out, both
+# calls, the un-sort; the ``tiles`` regime inside the VMEM it asks for,
+# which the compiler refuses a kernel that passes.
+EXPERT_CALLS = {
+    "lfm2 segment": (32, 4, 2048, 1536, 64, True),
+    "lfm2 2048": (2048, 4, 2048, 1536, 64, True),
+    "lfm2 4096": (4096, 4, 2048, 1536, 64, True),
+    "lfm2 6144": (6144, 4, 2048, 1536, 64, True),
+    "lfm2 8192": (8192, 4, 2048, 1536, 64, True),
+    "nemotron-h prefill": (4096, 22, 1024, 2688, 128, False),
+}
 
-    text = _compile(
-        lambda x, gate, up, sizes: expert_matmul_kernel(x, gate, sizes, up),
-        one_chip, ((rows, 2048), jnp.bfloat16),
-        *[((64, 2048, 1536), jnp.bfloat16)] * 2, ((64,), jnp.int32))
-    assert "tpu_custom_call" in text and "expert_matmul" in text
+
+@pytest.mark.parametrize("call", list(EXPERT_CALLS))
+def test_gated_expert_matmul_compiles_for_v5e(one_chip, monkeypatch, call):
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul as E
+
+    monkeypatch.setattr(E, "_use_kernel", lambda: True)
+    tokens, top_k, K, F, G, gated = EXPERT_CALLS[call]
+    chosen = E.plan(tokens * top_k, K, F, G, 2 if gated else 1)
+    assert chosen.regime == ("tiles" if tokens > 32 else "stream")
+    assert (chosen.vmem or 0) <= 64 << 20   # of a v5e core's 128 MiB
+
+    def layer(u, w1, w3, w2, weights, group):
+        return E.experts(u, w1, w2, weights, group, w3=w3 if gated else None)
+
+    text = _compile(layer, one_chip, ((tokens, K), jnp.bfloat16),
+                    *[((G, K, F), jnp.bfloat16)] * 2,
+                    ((G, F, K), jnp.bfloat16),
+                    ((tokens, top_k), jnp.float32),
+                    ((tokens, top_k), jnp.int32))
+    assert text.count("tpu_custom_call") >= 2 and "expert_matmul" in text
+
+
+# sha256 of a decode step's calls as they lower for the described chip, the
+# parent's (PR 48) letter for letter: the text outside the kernels' bodies,
+# and each body as MLIR printed without source locations (which any edit
+# above the kernel moves).  A plan for the prefill regime leaves them be.
+EXPERT_DECODE_TEXT = {
+    "lfm2":
+        "82f56701e5b561a7c11ae305a446047b7273c05ea2445ae071742287b137e02d",
+    "nemotron-h":
+        "6cde6a61f6837a5f623759bb6ba4cd153b85e4771d7c4176f2c0985957a35016",
+}
+
+
+def _text_without_locations(text: str) -> str:
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    body = re.compile(r'(\\22body\\22: ?\\22)([A-Za-z0-9+/=]+)(\\22)')
+    ctx = jax_mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        bodies = [ir.Module.parse(base64.b64decode(m.group(2))).operation
+                  .get_asm(enable_debug_info=False)
+                  for m in body.finditer(text)]
+    assert bodies
+    return body.sub(r"\1X\3", text) + "\n".join(bodies)
+
+
+@pytest.mark.parametrize("family", list(EXPERT_DECODE_TEXT))
+def test_a_decode_steps_expert_calls_lower_to_the_parents_text(
+        one_chip, monkeypatch, family):
+    import hashlib
+
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul as E
+
+    monkeypatch.setattr(E, "_use_kernel", lambda: True)
+    slots, top_k, K, F, G, gated = {
+        "lfm2": (32, 4, 2048, 1536, 64, True),
+        "nemotron-h": (32, 22, 1024, 2688, 128, False)}[family]
+    assert E.plan(slots * top_k, K, F, G, 1 + gated).regime == "stream"
+
+    def step(u, w1, w3, w2, weights, group):
+        return E.experts(u, w1, w2, weights, group, w3=w3 if gated else None)
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (
+                ((slots, K), jnp.bfloat16), ((G, K, F), jnp.bfloat16),
+                ((G, K, F), jnp.bfloat16), ((G, F, K), jnp.bfloat16),
+                ((slots, top_k), jnp.float32), ((slots, top_k), jnp.int32))]
+    text = _text_without_locations(jax.jit(step).lower(*args).as_text())
+    assert text.count("expert_matmul") >= 2
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == EXPERT_DECODE_TEXT[family]
 
 
 def _lfm2_shapes(cfg, sd):

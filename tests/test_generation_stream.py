@@ -328,6 +328,25 @@ async def test_boot_log_names_the_prompt_form_of_every_prefill(engine,
     assert F.prompt_form(*calls[0]) == "einsum"
 
 
+async def test_boot_log_lists_the_experts_plan_by_program(engine,
+                                                          monkeypatch):
+    """``generation lane ready`` lists under ``expert_plans`` what a family
+    with routed experts says of the segment's rows and of each bucket's
+    largest prefill dispatch; a family without says nothing."""
+    from pytorch_zappa_serverless_tpu.serving import generation
+
+    lines = []
+    monkeypatch.setattr(generation, "log_event",
+                        lambda log, msg, **fields: lines.append((msg, fields)))
+    sched = _scheduler(engine)
+    assert lines[-1][1]["expert_plans"] == {}
+    meta = engine.model("gpt2").servable.meta["continuous"]
+    monkeypatch.setitem(meta, "expert_plan", lambda rows: {"rows": rows})
+    assert sched._expert_plans() == {
+        "segment": {"rows": sched.slots},
+        **{str(b): {"rows": b * sched.slots} for b in sched.prompt_buckets}}
+
+
 async def test_first_uses_of_the_lanes_programs_are_booked_once(engine):
     """The slot lane's first prefill, insert and segment each leave one
     entry in the engine's ledger, with the stages heard from inside jax

@@ -337,30 +337,194 @@ def test_flash_prompt_form_is_the_form_that_writes_its_scores(
 LAYOUTS = {"2 rows an expert, all held": [2, 2, 2, 2, 2, 2],
            "2 rows an expert, one empty": [2, 3, 0, 2, 2, 3],
            "70 rows an expert": [70, 70, 70, 70, 70, 70],
-           "70 rows an expert, one empty": [70, 84, 0, 70, 70, 56]}
+           "70 rows an expert, one empty": [70, 84, 0, 70, 70, 56],
+           # What a router makes of a prefill, which sizes drawn evenly lack.
+           "groups that straddle tiles": [100, 90, 200, 130, 60, 140],
+           "one smaller than a tile": [128, 7, 256, 129, 127, 1],
+           "one empty, one of several tiles": [0, 700, 0, 31, 128, 5],
+           "three quarters of the rows unheld": [40, 0, 150, 10, 3, 17]}
+# Rows past the groups' own: a last group that is held elsewhere.
+UNHELD = {"three quarters of the rows unheld": 660}
 
 
-@pytest.mark.parametrize("form", ["kernel, interpreted", "ragged_dot"])
+def _wanted(x, w, up, sizes, relu2):
+    out = jax.lax.ragged_dot(x, w, sizes)
+    if relu2:
+        out = jnp.square(jnp.maximum(out, 0))
+    return out if up is None else jax.nn.silu(out) * jax.lax.ragged_dot(
+        x, up, sizes)
+
+
+@pytest.mark.parametrize("form", ["kernel, interpreted", "ragged_dot",
+                                  "tiles, interpreted"])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
 def test_gated_expert_matmul_is_ragged_dot_s(layout, form):
     rng = np.random.default_rng(0)
     sizes = np.asarray(LAYOUTS[layout], np.int32)
-    G, K, N, M_ = 6, 32, 48, int(sizes.sum())
+    held = int(sizes.sum())
+    G, K, N, M_ = 6, 32, 48, held + UNHELD.get(layout, 0)
     x = jnp.asarray(rng.standard_normal((M_, K)), jnp.float32)
     gate, up = (jnp.asarray(rng.standard_normal((G, K, N)), jnp.float32)
                 for _ in range(2))
     with jax.default_matmul_precision("highest"):
-        want = (jax.nn.silu(jax.lax.ragged_dot(x, gate, jnp.asarray(sizes)))
-                * jax.lax.ragged_dot(x, up, jnp.asarray(sizes)))
+        want = _wanted(x, gate, up, jnp.asarray(sizes), False)
         if form == "ragged_dot":
             got = [E.expert_matmul(x, gate, jnp.asarray(sizes), up=up)]
+        elif form == "tiles, interpreted":
+            got = [_through_tiles(x, gate, up, sizes, False, tile)
+                   for tile in TILES]
         else:
             got = [E.expert_matmul_kernel(x, gate, jnp.asarray(sizes), up,
                                           tile=tile, interpret=True)
                    for tile in (8, 16)]
     for out in got:
         assert out.shape == (M_, N)
-        assert np.abs(np.asarray(out) - np.asarray(want)).max() < 1e-3
+        assert np.abs(np.asarray(out) - np.asarray(want))[:held].max() < 1e-3
+
+
+# Every row tile the ``tiles`` regime's plan can pick, and a small one.
+TILES = sorted({16} | {E.plan(rows * 64, 2048, 1536, 64, 2).tile
+                       for rows in range(128, 1025, 32)})
+
+
+def _through_tiles(x, w, up, sizes, relu2, tile, align=E._ROW_ALIGN):
+    """``x`` (sorted by group) with each group begun on a multiple of
+    ``align`` rows, through the kernel of the ``tiles`` regime, which
+    writes each on a multiple of ``tile``, and read back row for row."""
+    sizes, rows = jnp.asarray(sizes), x.shape[0]
+    align = min(align, tile)
+    laid = jnp.zeros((E.laid_rows(rows, len(sizes), tile, align),
+                      x.shape[1]), x.dtype).at[
+        E.lay_out(sizes, rows, align)].set(x, mode="drop")
+    out = E.expert_matmul_kernel(laid, w, sizes, up, relu2=relu2, tile=tile,
+                                 interpret=True, laid_out=align)
+    at = E.lay_out(sizes, rows, tile)
+    # No tile of the result holds two groups' rows.
+    owner = np.repeat(np.arange(len(sizes)), np.asarray(sizes))
+    tile_of = np.asarray(at)[:len(owner)] // tile
+    assert all(len(set(owner[tile_of == t])) == 1 for t in set(tile_of))
+    return jnp.take(out, at, axis=0, mode="fill", fill_value=0)
+
+
+@pytest.mark.parametrize("kind", ["plain", "relu2"])
+@pytest.mark.parametrize("layout", list(LAYOUTS)[4:])
+def test_tiles_regime_is_ragged_dot_in_every_form(layout, kind):
+    """The plain and the squared call of the ``tiles`` regime (the gated
+    one is above), with blocks of ``N`` narrower than the matrix, so that
+    the kernel's own fetch walks groups and blocks both."""
+    relu2 = kind == "relu2"
+    rng = np.random.default_rng(1)
+    sizes = np.asarray(LAYOUTS[layout], np.int32)
+    held = int(sizes.sum())
+    G, K, N, M_ = 6, 32, 256, held + UNHELD.get(layout, 0)
+    x = jnp.asarray(rng.standard_normal((M_, K)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((G, K, N)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = _wanted(x, w, None, jnp.asarray(sizes), relu2)
+        for tile in TILES:
+            # As the first call reads its rows, and as the second does.
+            for align in (E._ROW_ALIGN, tile):
+                got = _through_tiles(x, w, None, sizes, relu2, tile, align)
+                assert np.abs(np.asarray(got) - np.asarray(want)
+                              )[:held].max() < 1e-3
+
+
+def test_tiles_regime_walks_blocks_of_n(monkeypatch):
+    """Blocks of 128 of 256 columns: every (block, group) pair waits for a
+    fetch of its own, the last one starts none."""
+    monkeypatch.setattr(E, "_TILES_BLOCK_BYTES", 32 * 128 * 4)
+    assert E._tiles_blocks(32, 256, 1, 4, 16)[0] == 128
+    rng = np.random.default_rng(2)
+    sizes = np.asarray([40, 0, 17, 1, 0, 90], np.int32)
+    x = jnp.asarray(rng.standard_normal((int(sizes.sum()), 32)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((6, 32, 256)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = _wanted(x, w, None, jnp.asarray(sizes), False)
+        got = _through_tiles(x, w, None, sizes, False, 16)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-3
+
+
+def test_a_tile_list_names_whole_tiles_of_one_group():
+    sizes = jnp.asarray([3, 0, 17, 16, 0, 1], jnp.int32)
+    # 37 rows held, 3 held elsewhere; groups on multiples of 8, the tile.
+    assert E.laid_rows(40, 6, 8) == (40 + 6 * 7) // 8 * 8
+    assert E.lay_out(sizes, 40, 8).tolist() == (
+        [0, 1, 2] + list(range(8, 25)) + list(range(32, 48)) + [48]
+        + [E._NOWHERE] * 3)
+    group, first, upto, rank, count = E.tile_list(sizes, 10, 8)
+    # 1 + 3 + 2 + 1 tiles, none for an empty group: the least there can be.
+    assert int(count) == 7 == int(-(-np.asarray(sizes) // 8).sum())
+    assert group.tolist()[:7] == [0, 2, 2, 2, 3, 3, 5]
+    assert first.tolist()[:7] == [0, 8, 16, 24, 32, 40, 48]
+    # Where the kernel finds the group to fetch next, and its buffer.
+    assert upto.tolist() == [1, 1, 4, 6, 6, 7]
+    assert rank.tolist() == [0, 1, 1, 2, 3, 3]
+    # Groups on multiples of 4 rows, tiles of 8 all the same: a tile begins
+    # where its group does, and a tile more at the end is there to be read.
+    assert E.laid_rows(40, 6, 8, 4) == (40 + 6 * 3) // 4 * 4 + 8
+    assert E.lay_out(sizes, 40, 4).tolist()[:21] == (
+        [0, 1, 2] + list(range(4, 21)) + [24])
+    assert E.tile_list(sizes, 10, 8, 4)[1].tolist()[:7] == [
+        0, 4, 12, 20, 24, 32, 40]
+
+
+# The plan, pinned: LFM2's four buckets (4 assignments a token over 64
+# experts), its segment (32 slots), Nemotron-H's prefill dispatch (8 x 512
+# tokens, 22 a token, 128 held) and its segment.
+@pytest.mark.parametrize("rows, shape, regime, tile, block_n, vmem", [
+    (2048 * 4, (2048, 1536, 64, 2), "tiles", 128, 1536, 32 << 20),
+    (4096 * 4, (2048, 1536, 64, 2), "tiles", 128, 1536, 32 << 20),
+    (6144 * 4, (2048, 1536, 64, 2), "tiles", 128, 1536, 32 << 20),
+    (8192 * 4, (2048, 1536, 64, 2), "tiles", 128, 1536, 32 << 20),
+    (8192 * 4, (1536, 2048, 64, 1), "tiles", 128, 2048, 20709376),
+    (32 * 4, (2048, 1536, 64, 2), "stream", 16, 512, None),
+    (32 * 4, (1536, 2048, 64, 1), "stream", 16, 1024, None),
+    (8 * 512 * 22, (1024, 2688, 128, 1), "tiles", 128, 2688, 19857408),
+    (8 * 512 * 22, (2688, 1024, 128, 1), "tiles", 128, 1024, 18153472),
+    (32 * 22, (1024, 2688, 128, 1), "stream", 16, 896, None),
+    (32 * 22, (2688, 1024, 128, 1), "stream", 16, 512, None)])
+def test_the_plan_by_shape(rows, shape, regime, tile, block_n, vmem):
+    K, N, G, mats = shape
+    chosen = E.plan(rows, K, N, G, mats)
+    assert chosen[:3] == (regime, tile, block_n) and chosen.vmem == vmem
+    # Both calls of an expert share the rows' layout: one tile.
+    assert E.plan(rows, N, K, G, 1).tile == tile
+
+
+@pytest.mark.parametrize("kind", ["gated", "relu2"])
+@pytest.mark.parametrize("tokens, regime", [(40, "stream"), (330, "tiles")])
+@pytest.mark.parametrize("unheld", [0.0, 0.75, 1.0])
+def test_experts_is_the_same_sum_in_both_regimes(monkeypatch, kind, tokens,
+                                                 regime, unheld):
+    """:func:`experts` end to end through the kernels (interpreted; the
+    process steered to them) against the ``ragged_dot`` path: the sort, the
+    rows laid out on tile boundaries, the un-sort, and nothing of an
+    assignment whose expert is held elsewhere (or of any, where none of a
+    program's rows falls on a held expert)."""
+    import functools
+
+    rng = np.random.default_rng(4)
+    top_k, held, K, F = 2, 4, 32, 48
+    u = jnp.asarray(rng.standard_normal((tokens, K)), jnp.float32)
+    w1, w3 = (jnp.asarray(rng.standard_normal((held, K, F)) * 0.2,
+                          jnp.float32) for _ in range(2))
+    w2 = jnp.asarray(rng.standard_normal((held, F, K)) * 0.2, jnp.float32)
+    w3 = w3 if kind == "gated" else None
+    group = rng.integers(0, held, (tokens, top_k))
+    group[rng.random((tokens, top_k)) < unheld] = held
+    group[:, 0][group[:, 0] == 1] = 2   # an expert with few rows
+    group, weights = jnp.asarray(group, jnp.int32), jnp.asarray(
+        rng.random((tokens, top_k)), jnp.float32)
+    assert E.plan(tokens * top_k, K, F, held).regime == regime
+    with jax.default_matmul_precision("highest"):
+        want, sizes = E.experts(u, w1, w2, weights, group, w3=w3)
+        monkeypatch.setattr(E, "_use_kernel", lambda: True)
+        monkeypatch.setattr(E, "expert_matmul_kernel", functools.partial(
+            E.expert_matmul_kernel, interpret=True))
+        got, sizes_ = E.experts(u, w1, w2, weights, group, w3=w3)
+    assert sizes.tolist() == sizes_.tolist()
+    assert (np.abs(np.asarray(want)).max() > 0.1) == (unheld < 1)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-4
 
 
 def test_gated_blocks_hold_half_the_columns():
@@ -447,6 +611,11 @@ def test_the_servable_declares_its_pool_and_one_prompt_a_dispatch(servable):
     assert meta["rows"].prefill_batch(16) == 1
     assert meta["prompt_form"](1, 16) == "grouped"
     assert meta["read_block"] == 32  # off the chip: whole rows
+    # What the lane's boot log lists: the experts' plan for a program's rows.
+    assert meta["expert_plan"](16) == E.plan_summary(
+        16, CFG.top_k, CFG.hidden_size, CFG.expert_width, CFG.experts_held,
+        True, 4)
+    assert meta["expert_plan"](16)["regime"] == "stream"
 
 
 def test_paged_lane_is_refused_at_build(servable):
