@@ -40,7 +40,7 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
 - *burst*    the servable's own prefill at ``gpt2-large-int8``'s and
              ``gpt2-xl``'s published widths and all their layers, at the
              admission batches a burst forms (8 and 16 prompts of 512 and
-             768), then ``insert`` and two segments: as built (the prompt
+             768) into the pool, then two segments: as built (the prompt
              attention's kernel) against the same programs with the picker
              held to the ``jax.numpy`` form, and a float32 attention as the
              arbiter.
@@ -59,7 +59,7 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
              ``decode_attention``, the gated ``expert_matmul`` and the
              prompt attention's flash form alone by the profiler's clock,
              then the cell's 16 reference prompts (600 and 3,000 tokens)
-             through ``prefill_start``, ``insert`` and segments against the
+             through ``prefill_start`` into the pool and segments against the
              plain reference on the same device, by the logits and by the
              cell's own ``judge``; and the int8 control, which must fail.
 - *sd15*     Stable Diffusion 1.5 at 512x512, two steps, one ``:submit``
@@ -795,6 +795,8 @@ def _kernels_child(rehearse: bool) -> None:
                 slots, total, d, not rehearse,
                 fills=span_fills(slots, total, lead) if lead else None):
             print("decode_attention " + json.dumps(row))
+    print("admission_prefill " + json.dumps(
+        time_admission_prefill(not rehearse)))
     print("preprocess path: "
           + ("native (hostops.cpp built with g++)" if hostops.native_available()
              else "PIL (no native library: no compiler here)"))
@@ -1066,10 +1068,13 @@ def time_prompt_attention(forms: dict, shapes, on_device: bool,
 _MOVES = ("copy", "slice", "dynamic-slice", "transpose")
 
 
-def pool_sized_moves(hlo_text: str, elements: int) -> list[tuple[int, str]]:
+def pool_sized_moves(hlo_text: str, elements: int,
+                     ops: tuple = ()) -> list[tuple[int, str]]:
     """The instructions of an optimised HLO module that materialise a copy,
-    a slice or a transposition of at least ``elements`` elements, largest
-    first, as ``(elements, "computation: instruction = shape op")``.
+    a slice or a transposition (or one of ``ops`` besides: a prefill is
+    asked for ``pad`` and ``broadcast`` too, what a cache made of zeros
+    compiles to) of at least ``elements`` elements, largest first, as
+    ``(elements, "computation: instruction = shape op")``.
 
     Counted: ``copy``, ``slice``, ``dynamic-slice`` and ``transpose`` (their
     asynchronous ``-start`` / ``-done`` halves too) outside fused
@@ -1097,8 +1102,8 @@ def pool_sized_moves(hlo_text: str, elements: int) -> list[tuple[int, str]]:
         name, dtype, dims, op = m.groups()
         base = re.sub(r"-(start|done)$", "", op)
         named = op == "fusion" and any(
-            part in _MOVES for part in re.split(r"[_.]", name))
-        if base not in _MOVES and not named:
+            part in _MOVES + ops for part in re.split(r"[_.]", name))
+        if base not in _MOVES + ops and not named:
             continue
         n = 1
         for d in dims.split(","):
@@ -1106,6 +1111,76 @@ def pool_sized_moves(hlo_text: str, elements: int) -> list[tuple[int, str]]:
         if n >= elements:
             found.append((n, f"{comp}: {name} = {dtype}[{dims}] {op}"))
     return sorted(found, reverse=True)
+
+
+def prefill_pool_moves(hlo_text: str, rows: int, width: int) -> list:
+    """What a compiled prefill moves of the pool beside writing its rows:
+    :func:`pool_sized_moves`, a ``pad`` or a ``broadcast`` among them, of a
+    slot's rows of one layer or more (``rows`` x ``width`` elements) with
+    the pool's ``rows`` among its dimensions (an activation ``[B, P, D]``
+    or a weight has not).  A prefill that makes no cache of its own and
+    copies nothing into the pool afterwards has none."""
+    import re
+
+    return [m for m in pool_sized_moves(hlo_text, rows * width,
+                                        ("pad", "broadcast"))
+            if re.search(rf"\[(\d+,)*{rows}(,\d+)*\]", m[1])]
+
+
+def time_admission_prefill(on_device: bool) -> dict:
+    """GPT-2 XL's admission prefill of four prompts of 768 into slots of the
+    benchmark's pool (8 x 960), alone: the device's milliseconds a run by
+    the profiler (the mean of three, each writing other slots of the pool it
+    was handed back), the heaviest operations of a run, and what its
+    compiled text still moves of the pool (:func:`prefill_pool_moves`: a
+    cache of zeros, a copy of a slot).  Off the device a tiny shape and no
+    time."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_zappa_serverless_tpu.models import gpt2
+
+    if on_device:
+        cfg = gpt2.GPT2Config(d_model=1600, layers=48, heads=25, ffn_dim=6400)
+        batch, bucket, slots, total = 4, 768, 8, 960
+    else:
+        cfg = gpt2.GPT2Config(**TINY_GPT2)
+        batch, bucket, slots, total = 4, 16, 8, 32
+    runs = 3
+    prefill, shapes = prefill_program(cfg, batch, bucket, slots, total)
+    compiled = prefill.lower(*shapes).compile()
+    moves = prefill_pool_moves(compiled.as_text(), total, cfg.d_model)
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED), 1024))
+    params = jax.tree.map(
+        lambda sd: (jax.random.normal(next(keys), sd.shape, jnp.float32)
+                    * 0.02).astype(sd.dtype), shapes[0])
+    pool = [jnp.zeros(sd.shape, sd.dtype) for sd in shapes[1:3]]
+    tokens = jax.random.randint(next(keys), (batch, bucket), 0,
+                                cfg.vocab_size - 1, jnp.int32)
+    lengths = jnp.full((batch,), bucket, jnp.int32)
+
+    def run():
+        nonlocal pool
+        for n in range(runs):
+            at = (jnp.arange(batch, dtype=jnp.int32) + 3 * n) % slots
+            logits, *pool = compiled(params, *pool, at, tokens, lengths)
+        jax.block_until_ready(pool)
+        return logits
+
+    run()
+    row = {"shape": [batch, bucket], "pool": [cfg.layers, slots, total,
+                                              cfg.d_model],
+           "pool_moves": [desc.split(": ", 1)[1] for _, desc in moves[:4]],
+           "pool_moves_count": len(moves),
+           "temp_mb": round(
+               compiled.memory_analysis().temp_size_in_bytes / 2 ** 20, 1)}
+    if on_device:
+        compute, _, busy = _device_ns(run)
+        row["device_ms_a_run"] = round(busy / runs / 1e6, 3)
+        row["ops_ms_a_run"] = {
+            name: round(ns / runs / 1e6, 3) for name, ns in sorted(
+                compute.items(), key=lambda kv: -kv[1])[:8]}
+    return row
 
 
 def _gpt2_shapes(cfg, sharding):
@@ -1158,21 +1233,25 @@ def segment_program(cfg, slots: int, total: int, sharding=None):
                      sd(slots, dtype=jnp.bool_), f32, i32, i32, f32)
 
 
-def prefill_program(cfg, batch: int, bucket: int, total: int, sharding=None):
+def prefill_program(cfg, batch: int, bucket: int, slots: int, total: int,
+                    sharding=None):
     """The admission prefill of ``batch`` prompts of ``bucket`` positions
-    into rows of ``total`` (bfloat16) as a jitted function, and its
-    arguments as shapes alone, as :func:`segment_program`."""
+    into a bfloat16 pool of ``slots`` x ``total``, which it is given to
+    write into (donated) with the slot of each prompt, as a jitted function,
+    and its arguments as shapes alone, as :func:`segment_program`."""
     import jax
     import jax.numpy as jnp
 
     from pytorch_zappa_serverless_tpu.models import decoder, gpt2
 
     sd, params = _gpt2_shapes(cfg, sharding)
+    pool = sd(cfg.layers, slots, total, cfg.d_model)
     prefill = jax.jit(
-        lambda p, tokens, lengths:
-        decoder.prefill(gpt2.family(cfg), p, tokens, lengths, total,
-                        jnp.bfloat16))
-    return prefill, (params, sd(batch, bucket, dtype=jnp.int32),
+        lambda p, ck, cv, at, tokens, lengths:
+        decoder.prefill(gpt2.family(cfg), p, tokens, lengths, (ck, cv), at,
+                        jnp.bfloat16), donate_argnums=(1, 2))
+    return prefill, (params, pool, pool, sd(batch, dtype=jnp.int32),
+                     sd(batch, bucket, dtype=jnp.int32),
                      sd(batch, dtype=jnp.int32))
 
 
@@ -1284,15 +1363,16 @@ def _evabyte_child(rehearse: bool) -> None:
     toks = np.zeros((1, bucket), np.int32)
     toks[0, :prompt] = ids
     z, zi = jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32)
-    prefill = jax.jit(lambda p, toks, lens: decoder.prefill_start(
-        fam, p, toks, lens, z, zi, total, dtype, top_k=zi, top_p=z + 1))
+    prefill = jax.jit(lambda p, cache, toks, lens: decoder.prefill_start(
+        fam, p, toks, lens, z, zi, cache, zi, dtype, top_k=zi, top_p=z + 1),
+        donate_argnums=(1,))
     segment = jax.jit(
         lambda p, ck, cv, tok, pos, st, fin: decoder.decode_segment(
             fam, p, decoder.slot_pool(ck, cv, fam.rows), tok, pos, st, fin,
             z, zi, 8, dtype, top_k=zi, top_p=z + 1), donate_argnums=(1, 2))
     t0 = time.monotonic()
-    tok, ck, cv = prefill(params, jnp.asarray(toks),
-                          jnp.asarray([prompt], jnp.int32))
+    tok, ck, cv = prefill(params, decoder.zero_cache(fam, 1, total, dtype),
+                          jnp.asarray(toks), jnp.asarray([prompt], jnp.int32))
     pos, st, fin = jnp.asarray([prompt], jnp.int32), zi, jnp.zeros((1,), bool)
     served = []
     for _ in range(4):
@@ -1366,7 +1446,7 @@ def _nemotron_child(rehearse: bool) -> None:
     ``benchmark/families/nemotron_h.py``), and the jitted programs are
     ``build_gen_kernels``'s, as
     the scheduler runs them: a prefill of 1, 2, 4 and 8 prompts at every
-    bucket, each prompt's rows inserted into a pool of every slot (the later
+    bucket, each prompt's rows written into a pool of every slot (the later
     ones re-use a slot), then 256 decode steps with every slot live.
     ``choose`` is watched, not replaced: it reports the logits it was given.
     Then the reference's full forward pass over prompt + served tokens, a
@@ -1446,22 +1526,22 @@ def _nemotron_child(rehearse: bool) -> None:
                        "top_k": np.zeros(B, np.int32),
                        "top_p": np.ones(B, np.float32)}
             seen.clear()
-            first, *rows = kernels["prefill"](params, payload)
+            first, *cache = kernels["prefill"](
+                params, tuple(cache),
+                np.asarray([(n + j) % S for j in range(B)], np.int32),
+                payload)
             first = np.asarray(first)
             jax.effects_barrier()
             for j, m in enumerate(lens):
                 slot = n % S
-                cache = kernels["insert_from"](cache, tuple(rows),
-                                               np.int32(j), np.int32(slot))
                 held[slot] = ([int(t) for t in toks[j, :m]], seen[0][j])
                 tok[slot], pos[slot] = first[j], m
                 n += 1
-            del rows
     live = sorted(held)
     fin = np.ones(S, bool)
     fin[live] = False
     print(f"nemotron: {n} prompts prefilled in batches of 1, 2, 4, 8 at "
-          f"{buckets} and inserted into {len(live)} of {S} slots in "
+          f"{buckets} into {len(live)} of {S} slots in "
           f"{time.monotonic() - t0:.0f} s (compiles included)", flush=True)
     zf, zi = np.zeros(S, np.float32), np.zeros(S, np.int32)
     st = zi.copy()
@@ -1751,8 +1831,8 @@ def _lfm2_child(rehearse: bool) -> None:
     stages: the program's own seeded weights, the routers' biases balanced)
     and the programs ``build_gen_kernels`` jits, as the scheduler runs them:
     the cell's own 16 reference prompts (600 and 3,000 tokens, drawn as
-    ``benchmark/run.py`` draws them), each prefilled alone, inserted into a
-    pool of 32 slots and decoded for two segments, the first 3,000-token
+    ``benchmark/run.py`` draws them), each prefilled alone into a slot of a
+    pool of 32 and decoded for two segments, the first 3,000-token
     one for four.  ``choose`` is watched, not replaced: it reports the logits
     it was given.  The reference's full forward pass over prompt + served
     tokens gives the largest and the root-mean-square logit difference and,
@@ -1816,7 +1896,9 @@ def _lfm2_child(rehearse: bool) -> None:
             P = q.shape[1]
             cache = tuple(jnp.zeros((1, 1, P, kv * dh), q.dtype)
                           for _ in range(2))
-            return rows.prompt(heads, lengths, P)(None, cache, 0, q, k, v)[1]
+            put = decoder.slot_put(jnp.zeros((1,), jnp.int32))
+            return rows.prompt(heads, lengths, P, put)(
+                None, cache, jnp.int32(0), q, k, v)[1]
 
         return attend
 
@@ -1908,11 +1990,10 @@ def _lfm2_child(rehearse: bool) -> None:
                    "top_k": np.zeros(1, np.int32),
                    "top_p": np.ones(1, np.float32)}
         seen.clear()
-        first, *rows = kernels["prefill"](params, payload)
         slot = j % S
-        cache = kernels["insert_from"](cache, tuple(rows), np.int32(0),
-                                       np.int32(slot))
-        del rows
+        first, *cache = kernels["prefill"](params, tuple(cache),
+                                           np.asarray([slot], np.int32),
+                                           payload)
         tok, pos, fin = zi.copy(), zi.copy(), np.ones(S, bool)
         tok[slot], pos[slot], fin[slot] = int(np.asarray(first)[0]), \
             len(ids), False
@@ -1935,7 +2016,7 @@ def _lfm2_child(rehearse: bool) -> None:
     decoder.choose = choose
     del cache
     print(f"lfm2: {len(prompts)} prompts of {sorted({len(p) for p in prompts})}"
-          f" tokens prefilled alone, inserted and decoded in "
+          f" tokens prefilled alone into a slot and decoded in "
           f"{time.monotonic() - t0:.0f} s (compiles included)", flush=True)
 
     keys = bench_family.published({"extra": extra})
@@ -1980,9 +2061,9 @@ def _burst_child(rehearse: bool) -> None:
 
     ``gpt2-large-int8`` and ``gpt2-xl`` at their published widths and all
     their layers, built by the model's own builder as the benchmark's
-    configurations build them; the jitted ``insert_from`` and ``segment``
-    are ``build_gen_kernels``'s, the prefill is the servable's own.  Each
-    (batch, bucket) is prefilled, spliced into the slot pool and decoded
+    configurations build them; the jitted ``segment`` and the pool are
+    ``build_gen_kernels``'s, the prefill is the servable's own.  Each
+    (batch, bucket) is prefilled into a fresh slot pool and decoded
     for two segments, once with the picker held to the ``jax.numpy`` form
     (a patch here, in this process: the product has no switch) and once as
     built; a third prefill, the ``jax.numpy`` form with the attention alone
@@ -2069,6 +2150,8 @@ def _burst_child(rehearse: bool) -> None:
         def apart(xs, ys, lengths):
             """(largest, mean) difference of two sides' K and V rows up to
             each length, and the root mean square of the first side's."""
+            xs, ys = ([a[:, :lengths.shape[0]] for a in side]
+                      for side in (xs, ys))  # the slots that were written
             real = (jnp.arange(xs[0].shape[2])[None, :]
                     < lengths[:, None])[None, :, :, None]
             count = lengths.sum() * xs[0].shape[0] * xs[0].shape[3] * 2.0
@@ -2082,21 +2165,26 @@ def _burst_child(rehearse: bool) -> None:
                     jnp.sqrt(squares / count))
 
         def prefilled(rule, payload):
-            """(first [B], first logits [B, V], (K rows, V rows)) of one
-            prefill traced under ``rule``, every row finite."""
+            """(first [B], first logits [B, V], the pool's (K, V)) of one
+            prefill into slots 0 to B - 1 of a fresh pool, traced under
+            ``rule``, every row finite."""
             fa.prompt_form = rule
             # A function of its own a side: jit keeps a function's trace,
             # and this one has to be made under the rule.
-            lowered = jax.jit(
-                lambda p, payload: meta["prefill"](p, payload)).lower(
-                    params, payload)
             batch, bucket = payload["input_ids"].shape
+            at = np.arange(batch, dtype=np.int32)
+            pool = kernels["alloc_cache"]()
+            lowered = jax.jit(
+                lambda p, cache, at, payload: meta["prefill"](
+                    p, cache, at, payload), donate_argnums=(1,)).lower(
+                        params, pool, at, payload)
             scores = (f"{batch}x{arch['heads']}x{bucket}x{bucket}xf32"
                       in lowered.as_text())
             form = rule(batch, arch["heads"], bucket, 64)
             assert scores == (form != "kernel"), (name, form, scores)
             seen.clear()
-            first, k_rows, v_rows = lowered.compile()(params, payload)
+            first, k_rows, v_rows = lowered.compile()(params, pool, at,
+                                                       payload)
             jax.effects_barrier()
             assert bool(jnp.isfinite(k_rows).all()
                         & jnp.isfinite(v_rows).all()), \
@@ -2105,14 +2193,11 @@ def _burst_child(rehearse: bool) -> None:
 
         def decoded(first, rows, lengths):
             """(tokens [B, 17], logits by step) of two segments over the
-            slot pool after the prefill's rows were spliced into it: token
-            ``t`` of a slot is the choice from logits ``t``, the prefill's
-            being 0."""
+            slot pool the prefill wrote (a copy: the segment donates what
+            it is given, and ``rows`` is compared again): token ``t`` of a
+            slot is the choice from logits ``t``, the prefill's being 0."""
             B = len(lengths)
-            cache = kernels["alloc_cache"]()
-            for j in range(B):
-                cache = kernels["insert_from"](cache, rows, np.int32(j),
-                                               np.int32(j))
+            cache = tuple(jnp.copy(leaf) for leaf in rows)
             S = meta["slots"]
             tok = np.zeros((S,), np.int32)
             tok[:B] = first
@@ -2312,8 +2397,12 @@ def _multichip_child(rehearse: bool) -> None:
 
         def kv(eng):
             cm = eng.model("gpt2")
-            prefill = jax.jit(cm.servable.meta["continuous"]["prefill"])
-            _, k, v = prefill(cm.servable.params, cm._place(payload))
+            meta = cm.servable.meta["continuous"]
+            pool = tuple(np.zeros(shape, dt)
+                         for shape, dt in meta["cache_leaves"])
+            _, k, v = jax.jit(meta["prefill"])(
+                cm.servable.params, pool, np.arange(4, dtype=np.int32),
+                cm._place(payload))
             return [np.asarray(a, np.float32)[:, i, :len(p)]
                     for a in (k, v) for i, p in enumerate(prompts)]
 
@@ -2437,10 +2526,15 @@ def _retrieval_child(rehearse: bool, order: tuple) -> None:
                    "length": jax.ShapeDtypeStruct((batch,), jnp.int32),
                    **{k: jax.ShapeDtypeStruct((batch,), dt)
                       for k, dt, _ in decoder.KNOBS}}
-        return (jax.jit(servable.meta["continuous"]["prefill"]),
-                (jax.tree.map(sd, servable.params), payload))
+        meta = servable.meta["continuous"]
+        pool = tuple(jax.ShapeDtypeStruct(shape, dt)
+                     for shape, dt in meta["cache_leaves"])
+        return (jax.jit(meta["prefill"], donate_argnums=(1,)),
+                (jax.tree.map(sd, servable.params), pool,
+                 jax.ShapeDtypeStruct((batch,), jnp.int32), payload))
 
-    programs = {"xl_prefill": lambda: prefill_program(xl, *shape, total),
+    programs = {"xl_prefill": lambda: prefill_program(xl, *shape, slots,
+                                                      total),
                 "xl_segment": lambda: segment_program(xl, slots, total),
                 "int8_prefill": int8_prefill}
     heard = {}
@@ -2544,7 +2638,7 @@ def main(argv=None) -> int:
                       args.rehearse, "burst.log", timeout=2400.0)
             say("burst: the int8 lane's and XL's large admission prefills "
                 "agree in both forms of the prompt attention, through "
-                "insert and two segments")
+                "the pool and two segments")
             seg = run_child(f"import chip_smoke; "
                             f"chip_smoke._segment_child({args.rehearse})",
                             args.rehearse, "segment.log", timeout=900.0)
@@ -2562,7 +2656,7 @@ def main(argv=None) -> int:
             run_child(f"import chip_smoke; "
                       f"chip_smoke._nemotron_child({args.rehearse})",
                       args.rehearse, "nemotron.log", timeout=2400.0)
-            say("nemotron: prefill batches, inserts and 256 decode steps "
+            say("nemotron: prefill batches into the pool and 256 decode steps "
                 "over a full pool agree with the plain reference; the "
                 "reference in the precision below does not")
             run_child(f"import chip_smoke; "
@@ -2570,7 +2664,7 @@ def main(argv=None) -> int:
                       args.rehearse, "lfm2.log", timeout=3000.0)
             say("lfm2: the grouped decode kernel, the gated expert matmul "
                 "and the flash prompt form match their jax.numpy forms; the "
-                "cell's reference prompts through prefill, insert and "
+                "cell's reference prompts through prefill into the pool and "
                 "segments agree with the plain reference; the reference in "
                 "the precision below does not")
             phase_sd15(sd15_cfg, probe, args.rehearse)

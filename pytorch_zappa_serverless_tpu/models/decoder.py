@@ -89,19 +89,22 @@ class Rows:
         layer's parameters).  Nothing here."""
         return k, v
 
-    def prompt(self, heads: int, lengths, P: int):
+    def prompt(self, heads: int, lengths, P: int, put):
         """The prompt attention of a prefill over ``P`` positions of which
         row b holds ``lengths[b]``: ``attend(p, cache, i, q, k, v) ->
-        (cache, out [B, P, D])`` leaves in ``cache`` (K and V [L, B, T, D],
-        zeros at first) the rows of layer ``i`` (an index as data: a traced
+        (cache, out [B, P, D])`` leaves in ``cache`` (the pool's leaves, K
+        and V first) the rows of layer ``i`` (an index as data: a traced
         int32 scalar) that a decode step will read, and returns the
-        attention output (``p``: the layer's parameters).  Here causal and
-        ragged in one softmax over ``[P, P]`` scores
+        attention output (``p``: the layer's parameters).  ``put(leaf, i,
+        values [B, n, D], row=0)`` is the only write there is
+        (:func:`slot_put`): prompt b's ``n`` rows from ``row`` on, wherever
+        the pool keeps prompt b.  Rows it is not handed keep what they held,
+        and nothing may read those before a decode step has written them.
+        Here causal and ragged in one softmax over ``[P, P]`` scores
         (ops/flash_attention.prompt_attend keeps them on the chip where it
         can), and the rows are the prompt's own K and V."""
         def attend(p, cache, i, q, k, v):
-            ck = cache[0].at[i, :, :P].set(k)
-            return ((ck, cache[1].at[i, :, :P].set(v)) + cache[2:],
+            return ((put(cache[0], i, k), put(cache[1], i, v)) + cache[2:],
                     prompt_attend(q, k, v, lengths, heads))
 
         return attend
@@ -124,7 +127,7 @@ class Family:
     ``layer(layer_params, x, attend, pos, lora=None, lora_idx=None)`` → one
     block over x [B, Tq, D] whose rows stand at the absolute positions
     ``pos`` ([B, Tq] or [Tq] int32: what a rotary family turns its queries
-    and keys by; the same in prefill, insert and decode); it calls
+    and keys by; the same in prefill and decode); it calls
     ``attend(q, k, v)`` once with its fresh projections ([B, Tq, width]) and
     gets the attention output back — the single point where the phases
     differ, which the programs fill in.  ``norm(params, x)`` is the final
@@ -225,6 +228,34 @@ def slot_pool(k, v, rows: Rows = ROWS) -> SlotPool:
     return SlotPool(k, v, jnp.arange(k.shape[1]), rows)
 
 
+def slot_put(slots):
+    """The write of a prefill into the pool where it lies: ``put(leaf,
+    layer, values, row=0)`` puts ``values[b]`` (rows ``[n, D]`` of K or V,
+    or a slot's whole state) at ``leaf[layer, slots[b], row:]`` for each of
+    the ``B`` prompts, and nothing else of the leaf is touched.  The slot
+    axis is the leaves' second (:func:`cache_leaves`), and this is the one
+    place a prefill knows it: a family's ``Rows.prompt`` is handed ``put``
+    and never sees a slot.  ``B`` updates in place, in order (two prompts
+    given one slot: the later one stays; a batch's padding is given its
+    first prompt's slot and writes that prompt's values again)."""
+    def put(leaf, layer, values, row=0):
+        at = (jnp.int32(row),) + (jnp.int32(0),) * (leaf.ndim - 3)
+        for b in range(values.shape[0]):
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, values[b][None, None].astype(leaf.dtype),
+                (layer, slots[b]) + at)
+        return leaf
+
+    return put
+
+
+def zero_cache(fam: Family, slots: int, total: int, dtype) -> tuple:
+    """A pool of ``slots`` slots for ``total`` positions, every leaf zeros:
+    what the fixed-batch path prefills into."""
+    return tuple(jnp.zeros(shape, dt) for shape, dt in cache_leaves(
+        fam, slots, fam.rows.count(total), dtype))
+
+
 class PagedPool(NamedTuple):
     """Fixed-size pages ``k``, ``v`` [L, NB, BS, D] and a block table a row
     [S, MB] (docs/GENERATION.md): writes route through the table, attention
@@ -291,7 +322,7 @@ def _embed(fam: Family, params, tokens, pos, dtype, clamp=True):
 
 
 def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
-           lengths=None):
+           lengths=None, put=None):
     """Every layer of the family over ``x`` at the positions ``pos``
     (embedded by the program, which builds its masks after it), then the
     final norm → ``(x, cache, counts)``.
@@ -306,11 +337,13 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
     A family that declares state gets it as it gets ``attend``, from the
     program: its layer is called with ``state=``, and ``state(update)``
     runs ``update(mine, lengths) -> (mine, out)`` over the layer's own part
-    of every leaf after K and V (``leaf[i]``: zeros in a prefill, whose
-    ``lengths`` [B] say how much of each prompt is real; the slots' own in a
-    decode step, where ``lengths`` is None) and writes what comes back in
-    its place.  ``count=`` takes the layer's int32 counts (``fam.counters``);
-    their sum over the layers is ``counts`` (None where nothing counted).
+    of every leaf after K and V (the slots' own ``leaf[i]`` in a decode
+    step, where ``lengths`` is None; zeros a prompt in a prefill, whatever
+    the prompt's slot held, where ``lengths`` [B] say how much of each
+    prompt is real) and writes what comes back in its place (a prefill
+    through ``put``, :func:`slot_put`, as it writes its rows).  ``count=``
+    takes the layer's int32 counts (``fam.counters``); their sum over the
+    layers is ``counts`` (None where nothing counted).
 
     The layer is one jitted function of ``(parameters, x, cache, index,
     lora)`` and every layer calls that same object, so a program traces the
@@ -331,6 +364,17 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
     kinds of layer (models/nemotron_h.py) is three traces."""
     stacks = None if adapter_idx is None else params.get("__adapters__")
     hooked = bool(fam.state or fam.counters)
+    if put is None:  # a decode step: the slots' own state, set in place
+        def held(leaf, i):
+            return leaf[i]
+
+        def keep(leaf, i, mine):
+            return leaf.at[i].set(mine)
+    else:  # a prefill: from zeros, into the prompts' slots
+        def held(leaf, i):
+            return jnp.zeros(x.shape[:1] + leaf.shape[2:], leaf.dtype)
+
+        keep = put
 
     @jax.jit
     def layer(p, x, cache, i, lora):
@@ -344,9 +388,10 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
 
         def layer_state(update):
             nonlocal cache
-            mine, out = update(tuple(leaf[i] for leaf in cache[2:]), lengths)
+            mine, out = update(tuple(held(leaf, i) for leaf in cache[2:]),
+                               lengths)
             cache = cache[:2] + tuple(
-                leaf.at[i].set(m) for leaf, m in zip(cache[2:], mine))
+                keep(leaf, i, m) for leaf, m in zip(cache[2:], mine))
             return out
 
         def layer_count(c):
@@ -435,28 +480,30 @@ def segment_scan(step, cache, tok, pos, t, finished, seg: int, eos_id: int,
 # ---------------------------------------------------------------------------
 
 def prefill(fam: Family, params: dict, tokens: jax.Array, lengths: jax.Array,
-            total: int, dtype=jnp.bfloat16, adapter_idx=None):
-    """Whole-prompt forward: fills the KV cache, returns last-token logits.
+            cache: tuple, slots: jax.Array, dtype=jnp.bfloat16,
+            adapter_idx=None):
+    """Whole-prompt forward into the slot pool, returns last-token logits.
 
-    tokens [B, P] int32 (zero-padded), lengths [B] int32, ``total`` the
-    positions the cache is for (P + max_new).  Returns (logits [B, V] at
-    position length-1, *the cache's leaves): rows for a slot pool, K and V
-    [L, B, T, D] (``T`` rows hold ``total`` positions as the family's
-    ``rows`` lay them out) and whatever state the family declares
-    (:func:`cache_leaves`), made here from nothing, so each layer attends
-    its own fresh K/V (``Rows.prompt``), starts its state from zeros, and
-    leaves what a decode step will read.
+    tokens [B, P] int32 (zero-padded), lengths [B] int32, ``cache`` the
+    pool's leaves (:func:`cache_leaves`: K and V [L, S, T, D], ``T`` rows
+    a slot as the family's ``rows`` lay positions out, then whatever state
+    the family declares) and ``slots`` [B] int32 the slot each prompt was
+    given.  Returns (logits [B, V] at position length-1, *the pool's
+    leaves): each layer attends its own fresh K/V (``Rows.prompt``), starts
+    its state from zeros, and writes what a decode step will read straight
+    to ``leaf[layer, slots[b]]`` (:func:`slot_put`).  No cache of the batch
+    is made and nothing is copied afterwards.  What a slot held in the rows
+    a prefill does not write stays there, and no decode step reads a row it
+    or the prefill has not written.
     """
     B, P = tokens.shape
     pos = jnp.arange(P)
     x = _embed(fam, params, tokens, pos, dtype, clamp=False)
-    T = fam.rows.count(total)
-    prompt = fam.rows.prompt(fam.heads, lengths, P)
-    cache = tuple(jnp.zeros(shape, dt)
-                  for shape, dt in cache_leaves(fam, B, T, dtype))
+    put = slot_put(slots)
+    prompt = fam.rows.prompt(fam.heads, lengths, P, put)
 
-    x, cache, _ = _trunk(fam, params, x, pos, cache, prompt, adapter_idx,
-                         lengths)
+    x, cache, _ = _trunk(fam, params, x, pos, tuple(cache), prompt,
+                         adapter_idx, lengths, put)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     return (fam.head(params, last),) + cache
 
@@ -472,19 +519,19 @@ def _penalized(logits, seen, repetition_penalty, on):
 
 def prefill_start(fam: Family, params: dict, tokens: jax.Array,
                   lengths: jax.Array, temperature: jax.Array,
-                  seeds: jax.Array, total: int, dtype=jnp.bfloat16,
-                  top_k=None, top_p=None, repetition_penalty=None,
-                  presence=None, adapter_idx=None):
-    """Admission program: prefill a batch of requests and pick each one's
-    first token.
+                  seeds: jax.Array, cache: tuple, slots: jax.Array,
+                  dtype=jnp.bfloat16, top_k=None, top_p=None,
+                  repetition_penalty=None, presence=None, adapter_idx=None):
+    """Admission program: prefill a batch of requests into the slots they
+    were given and pick each one's first token.
 
     The same prefill as :func:`generate` (so the token chain is
-    bit-identical to the fixed-batch path), returned raw so the scheduler
-    can insert the cache rows into its slot pool.  Returns (first_tok [B],
-    *the cache's leaves), K and V [L, B, T, D] first.
+    bit-identical to the fixed-batch path), over the scheduler's pool, which
+    it donates as it does to a segment.  Returns (first_tok [B], *the pool's
+    leaves), K and V [L, S, T, D] first.
     """
-    logits, *cache = prefill(fam, params, tokens, lengths, total, dtype,
-                             adapter_idx=adapter_idx)
+    logits, *cache = prefill(fam, params, tokens, lengths, cache, slots,
+                             dtype, adapter_idx=adapter_idx)
     if repetition_penalty is not None:
         logits = _penalized(logits, presence, repetition_penalty,
                             jnp.any(repetition_penalty != 1.0))
@@ -513,10 +560,10 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
     each layer of the pool where it lies, as far as each live slot has
     written, from one list of live blocks a step.  ``state`` is the pool's
     leaves after K and V (a slot's state needs no span: a finished slot's
-    goes on changing, nothing reads it, and the insert that re-uses the slot
-    overwrites it whole).  Returns (emits [S, seg], *the cache's leaves,
-    tok, pos, step, finished) and the family's counts, as
-    :func:`segment_scan`.
+    goes on changing, nothing reads it, and the prefill that re-uses the
+    slot starts from zeros and overwrites it whole).  Returns (emits
+    [S, seg], *the cache's leaves, tok, pos, step, finished) and the
+    family's counts, as :func:`segment_scan`.
     """
     total, T = pool.positions, pool.k.shape[2]
     # Repetition penalty (fixed-batch lane only, which is the slot pool —
@@ -561,11 +608,12 @@ def generate(fam: Family, params: dict, tokens: jax.Array,
     """Prefill + scan generation (greedy or sampled per row).  Returns
     [B, max_new] int32, EOS-padded after the first EOS.
 
-    One :func:`prefill_start` + a single ``max_new``-length
-    :func:`decode_segment` — the fixed-batch path IS the continuous-batching
-    program at seg=max_new, so batched and streaming serving share one
-    per-step decoder body and cannot drift apart.  ``params`` is the tree as
-    served: the family picks what each half runs with.
+    One :func:`prefill_start` into a pool of zeros made here, a slot a row
+    of the batch, + a single ``max_new``-length :func:`decode_segment` — the
+    fixed-batch path IS the continuous-batching program at seg=max_new, so
+    batched and streaming serving share one per-step decoder body and
+    cannot drift apart.  ``params`` is the tree as served: the family picks
+    what each half runs with.
     """
     B, P = tokens.shape
     presence = None
@@ -577,7 +625,8 @@ def generate(fam: Family, params: dict, tokens: jax.Array,
             jnp.arange(B)[:, None], tokens].max(valid)
     first, cache_k, cache_v, *state = prefill_start(
         fam, fam.pre_tree(params), tokens, lengths, temperature, seeds,
-        P + max_new, dtype, top_k=top_k, top_p=top_p, repetition_penalty=repetition_penalty,
+        zero_cache(fam, B, P + max_new, dtype), jnp.arange(B), dtype,
+        top_k=top_k, top_p=top_p, repetition_penalty=repetition_penalty,
         presence=presence, adapter_idx=adapter_idx)
     step, finished = jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool)
     emits, *_ = decode_segment(
@@ -882,17 +931,17 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         return batch
 
     # Continuous-batching contract (serving/generation.py): slot-pool decode
-    # in `segment_tokens`-step jitted segments with per-request admission via
-    # prefill + insert.  gen_slots bounds concurrent generations; the cache
-    # pool is a tuple of leaves, K and V [L, slots, T, D] first.  Admission
-    # is model-shaped (whisper admits AUDIO), so the scheduler drives it
-    # through the generic
-    # trio: ``admit_len_of`` (sample -> bucket-size request),
+    # in `segment_tokens`-step jitted segments with admission by a prefill
+    # that writes into the pool.  gen_slots bounds concurrent generations;
+    # the cache pool is a tuple of leaves, K and V [L, slots, T, D] first.
+    # Admission is model-shaped (whisper admits AUDIO), so the scheduler
+    # drives it through the generic trio: ``admit_len_of`` (sample ->
+    # bucket-size request),
     # ``collate_admit`` (sample + bucket -> batch-1 payload dict; must carry
     # "length" [1] and may carry "temperature"/"seed" [1] for the slot
     # state), ``admit_spec`` (bucket -> payload ShapeDtypeStructs, used by
     # multi-host followers to join the broadcast), and ``prefill`` takes the
-    # payload dict.
+    # pool's leaves, the slots [B] and the payload dict.
     gen_slots = int(cfg_model.extra.get("gen_slots", 4))
     segment_tokens = int(cfg_model.extra.get("segment_tokens", 8))
     total = max_seq + max_new
@@ -942,10 +991,10 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         # its program) — consistent with the fixed-batch path at the same
         # row count, so the bit-identical fixed<->continuous parity property
         # survives routing.
-        "prefill": (lambda p, payload:
+        "prefill": (lambda p, cache, slots, payload:
                     prefill_start(fam, fam.pre_tree(p), payload["input_ids"],
                                   payload["length"], payload["temperature"],
-                                  payload["seed"], total, dtype,
+                                  payload["seed"], cache, slots, dtype,
                                   top_k=payload["top_k"],
                                   top_p=payload["top_p"])),
         "segment": (lambda p, cache, tok, pos, st, fin, temp, seeds,

@@ -229,9 +229,9 @@ class GroupedFlashRows(GroupedRows):
             return "flash"
         return "grouped"
 
-    def prompt(self, heads: int, lengths, P: int):
+    def prompt(self, heads: int, lengths, P: int, put):
         if self.prompt_form(lengths.shape[0], heads, P, None) == "grouped":
-            return super().prompt(heads, lengths, P)
+            return super().prompt(heads, lengths, P, put)
         kv = self.kv_heads
 
         def attend(p, cache, i, q, k, v):
@@ -242,8 +242,7 @@ class GroupedFlashRows(GroupedRows):
                       for a in (k, v)]
             out = flash_attention(q.reshape(B, P, heads, dh), *shared,
                                   causal=True)
-            return ((cache[0].at[i, :, :P].set(k),
-                     cache[1].at[i, :, :P].set(v)) + cache[2:],
+            return ((put(cache[0], i, k), put(cache[1], i, v)) + cache[2:],
                     out.reshape(B, P, heads * dh))
 
         return attend

@@ -272,7 +272,7 @@ class GroupedRows(Rows):
     def prompt_form(self, batch, heads, P, head_dim) -> str:
         return "grouped"
 
-    def prompt(self, heads: int, lengths, P: int):
+    def prompt(self, heads: int, lengths, P: int, put):
         kv = self.kv_heads
         keep = ((jnp.arange(P)[None, :] <= jnp.arange(P)[:, None])[None]
                 & (jnp.arange(P)[None, None, :] < lengths[:, None, None]))
@@ -287,8 +287,7 @@ class GroupedRows(Rows):
             scores = jnp.where(keep[:, None, None], scores, -1e9)
             probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
             out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vh)
-            return ((cache[0].at[i, :, :P].set(k),
-                     cache[1].at[i, :, :P].set(v)) + cache[2:],
+            return ((put(cache[0], i, k), put(cache[1], i, v)) + cache[2:],
                     out.reshape(B, P, heads * dh))
 
         return attend

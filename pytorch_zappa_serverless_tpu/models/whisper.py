@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .decoder import KNOBS, ROWS, knob_batch, knob_spec, segment_scan
+from .decoder import (KNOBS, ROWS, knob_batch, knob_spec, segment_scan,
+                      slot_put)
 
 
 @dataclass(frozen=True)
@@ -319,8 +320,9 @@ def prefill_continuous(params: dict, mel: jax.Array, prompt_ids: tuple,
     ``[L, B, source_positions + total_self, D]``: cross-attention K/V in the
     first ``source_positions`` time slots, the self-attention cache after.
     Packing (rather than a second cache pytree) keeps the scheduler's
-    insert/segment plumbing (serving/generation.py ``_insert_rows``) exactly
-    as gpt2 uses it — the cache stays one opaque (k, v) pair per model.
+    prefill/segment plumbing exactly as gpt2 uses it — the cache stays one
+    opaque (k, v) pair per model, and the servable's ``prefill`` writes
+    these rows into the slots of the pool (models/decoder.py ``slot_put``).
     """
     enc = encode(params, mel, cfg, dtype)
     prompt = jnp.tile(jnp.asarray(prompt_ids, jnp.int32)[None],
@@ -647,6 +649,19 @@ def make_whisper_servable(name: str, cfg_model) -> Any:
                 "length": jax.ShapeDtypeStruct((1,), jnp.int32),
                 **knob_spec(1)}
 
+    def admit(p, cache, slots, payload):
+        """The scheduler's admission program: the packed rows of every
+        request, whole, over the slot of the pool it was given."""
+        first, *rows = prefill_continuous(
+            p, payload["mel"], prompt_ids, total_self, cfg, dtype,
+            temperature=payload["temperature"], seeds=payload["seed"],
+            top_k=payload["top_k"], top_p=payload["top_p"])
+        put = slot_put(slots)
+        for i in range(cfg.decoder_layers):
+            cache = tuple(put(leaf, jnp.int32(i), packed[i])
+                          for leaf, packed in zip(cache, rows))
+        return (first, *cache)
+
     continuous = {
         "slots": gen_slots,
         "segment_tokens": segment_tokens,
@@ -662,10 +677,7 @@ def make_whisper_servable(name: str, cfg_model) -> Any:
         # The pool's leaves, K and V: cross rows, then self rows.
         "cache_leaves": (((cfg.decoder_layers, gen_slots, CL + total_self,
                            cfg.d_model), dtype),) * 2,
-        "prefill": (lambda p, payload: prefill_continuous(
-            p, payload["mel"], prompt_ids, total_self, cfg, dtype,
-            temperature=payload["temperature"], seeds=payload["seed"],
-            top_k=payload["top_k"], top_p=payload["top_p"])),
+        "prefill": admit,
         "segment": (lambda p, cache, tok, pos, st, fin, temp, seeds,
                     topk, topp:
                     decode_segment(p, *cache, tok, pos, st, fin,

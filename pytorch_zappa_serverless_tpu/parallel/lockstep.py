@@ -180,11 +180,12 @@ class LockstepDriver:
         state = self._gen_state(name)
         k, tl = state["kernels"], state["timeline"]
         cm = self.engine.models[name]
-        with tl.phase("prefill.launch", programs=1, batch=1, bucket=bucket):
-            first, *rows = k["prefill"](cm.servable.params, payload)
-        with tl.phase("insert.launch", programs=1):
-            state["cache"] = k["insert"](state["cache"], tuple(rows),
-                                         np.int32(slot))
+        with tl.phase("prefill.launch", programs=1, batch=1, bucket=bucket,
+                      slots=str(slot)):
+            first, *cache = k["prefill"](cm.servable.params, state["cache"],
+                                         np.asarray([slot], np.int32),
+                                         payload)
+            state["cache"] = tuple(cache)
         with tl.phase("prefill.fetch"):
             np.asarray(first)  # completion fence, mirroring the leader's
 

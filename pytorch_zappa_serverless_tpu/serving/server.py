@@ -725,7 +725,7 @@ class Server:
                     log_event(log, "generation lane disabled "
                                    "(multi-host, no lead)", model=name)
                     return
-                # Follower topology: every prefill/insert/segment this
+                # Follower topology: every prefill and segment this
                 # scheduler dispatches is broadcast to the follower
                 # loops first (parallel/lockstep.py OP_GEN_*), so SSE
                 # streaming + continuous batching serve cross-host too.

@@ -429,9 +429,8 @@ class Tracer:
 # between them.  PERF.md, the benchmark's readers and ``attribute_idle``
 # (utils/xplane.py) share these names.
 PHASES = ("round.idle", "round.admit_host", "round.lane_wait",
-          "prefill.launch", "prefill.fetch", "insert.launch",
-          "segment.launch", "segment.fetch", "round.wakeup",
-          "round.distribute")
+          "prefill.launch", "prefill.fetch", "segment.launch",
+          "segment.fetch", "round.wakeup", "round.distribute")
 
 
 class _Phase:

@@ -113,7 +113,7 @@ _ANNOTATION = "tpuserve."
 # Phases that run on the dispatch thread; the ``round.*`` ones run on the
 # event loop (``round.lane_wait`` ends on the dispatch thread, and no event
 # loop phase of the same scheduler covers its time).
-_DISPATCH = ("prefill.", "insert.", "segment.")
+_DISPATCH = ("prefill.", "segment.")
 IN_PROGRAM = "in_program"  # idle between the operations of one program run
 
 

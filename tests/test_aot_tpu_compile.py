@@ -128,13 +128,13 @@ def test_prefill_with_the_prompt_kernel_holds_no_score_array(one_chip,
     test steers the choice) no float32 ``[B, H, P, P]`` or ``[B, 1, P, P]``
     array is left in the program; with the other form both are there."""
     cfg = gpt2.GPT2Config(d_model=1600, layers=2, heads=25, ffn_dim=6400)
-    prefill, args = chip_smoke.prefill_program(cfg, 4, 768, 960, one_chip)
+    prefill, args = chip_smoke.prefill_program(cfg, 4, 768, 8, 960, one_chip)
     scores, mask = "4x25x768x768xf32", "4x1x768x768xf32"
     text = prefill.lower(*args).as_text()
     assert scores in text and mask in text
     monkeypatch.setattr(flash_attention_module, "prompt_form",
                         lambda *shape: "kernel")
-    prefill, args = chip_smoke.prefill_program(cfg, 4, 768, 960, one_chip)
+    prefill, args = chip_smoke.prefill_program(cfg, 4, 768, 8, 960, one_chip)
     lowered = prefill.lower(*args)
     text = lowered.as_text()
     assert scores not in text and mask not in text and "768x768" not in text
@@ -257,7 +257,8 @@ def test_shared_layer_compiles_to_the_loop_s_program_on_v5e(
 
     def compiled(calls):
         if program == "prefill":
-            fn, args = chip_smoke.prefill_program(cfg, 4, 768, 960, one_chip)
+            fn, args = chip_smoke.prefill_program(cfg, 4, 768, 8, 960,
+                                                  one_chip)
         else:
             fn, args = chip_smoke.segment_program(cfg, 8, 960, one_chip)
         lowered = fn.lower(*args)
@@ -337,6 +338,92 @@ def test_evabyte_segment_copies_no_weight_on_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < D * D * 2
 
 
+def _evabyte_prefill(fam, params, sd, slots, P, total):
+    """EvaByte's prefill of one prompt of ``P`` positions into a pool of
+    ``slots`` slots for ``total``, which it donates, lowered from shapes."""
+    pool = sd(fam.layers, slots, fam.rows.count(total), fam.width)
+    return jax.jit(
+        lambda p, ck, cv, at, tokens, lengths: decoder.prefill(
+            fam, p, tokens, lengths, (ck, cv), at, jnp.bfloat16),
+        donate_argnums=(1, 2)).lower(
+            params, pool, pool, sd(1, dtype=jnp.int32),
+            sd(1, P, dtype=jnp.int32), sd(1, dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("family", ["xl", "evabyte"])
+def test_prefill_writes_into_the_pool_and_moves_none_of_it(
+        one_chip, monkeypatch, family):
+    """The admission prefill at GPT-2 XL's widths (four prompts of 768 into
+    8 slots of 960 rows) and at EvaByte's (one prompt of 12,288 positions
+    into 8 slots of 2,880 rows: the ring, the summaries), four and two
+    layers, with the kernels a chip takes: the pool goes in and comes out in
+    one buffer (every byte of K and V is aliased), and the program makes no
+    cache of zeros (no ``pad``, no ``broadcast``) and copies, slices or
+    transposes nothing as large as a slot's rows of one layer with the
+    pool's row count in its shape (``chip_smoke.prefill_pool_moves``)."""
+    monkeypatch.setattr(flash_attention_module, "prompt_form",
+                        lambda *shape: "kernel")
+    if family == "xl":
+        cfg = gpt2.GPT2Config(d_model=1600, layers=4, heads=25, ffn_dim=6400)
+        slots, rows, width = 8, 960, cfg.d_model
+        prefill, args = chip_smoke.prefill_program(cfg, 4, 768, slots, rows,
+                                                   one_chip)
+        done = prefill.lower(*args).compile()
+    else:
+        cfg = evabyte.EvaByteConfig(layers=2, eos_id=320)
+
+        def sd(*shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        fam = evabyte.family(cfg, evabyte.TwoTier(
+            cfg.window_size, cfg.chunk_size, cfg.heads, 64))
+        slots, total, width = 8, 12288 + 768, cfg.hidden_size
+        rows = fam.rows.count(total)
+        assert rows == 2880
+        done = _evabyte_prefill(fam, _evabyte_shapes(cfg, sd), sd, slots,
+                                12288, total).compile()
+    text = done.as_text()
+    assert text.count("tpu_custom_call") == cfg.layers
+    pool_bytes = 2 * cfg.layers * slots * rows * width * 2
+    assert done.memory_analysis().alias_size_in_bytes >= pool_bytes
+    assert chip_smoke.prefill_pool_moves(text, rows, width) == []
+
+
+@pytest.mark.parametrize("options", ["defaults", "no_remat"])
+def test_evabyte_prefill_recomputes_nothing_with_the_options_it_is_given(
+        one_chip, monkeypatch, options):
+    """The benchmark's sixteen layers of EvaByte (6.5 GB of weights as
+    shapes), one prompt of 6,144 positions into the 8-slot pool (6 GB,
+    donated).  With the compiler's defaults the rematerialisation pass
+    recomputes dozens to hundreds of instructions of it, a layer's V
+    projection among them, as if the pool were held twice (with 4 slots it recomputes
+    nothing; the chip read the prefill 8% slower than the parent's, PERF.md
+    section 6, PR 50); with what ``build_gen_kernels`` compiles a prefill
+    with on a TPU it recomputes nothing and its temporaries are no larger.
+    Should ``defaults`` fail, this libtpu no longer does it and the option
+    can go (``serving/generation._prefill_compiler_options``)."""
+    from pytorch_zappa_serverless_tpu.serving.generation import _NO_REMAT
+
+    monkeypatch.setattr(flash_attention_module, "prompt_form",
+                        lambda *shape: "kernel")
+    cfg = evabyte.EvaByteConfig(layers=16, eos_id=320)
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fam = evabyte.family(cfg, evabyte.TwoTier(
+        cfg.window_size, cfg.chunk_size, cfg.heads, 64))
+    done = _evabyte_prefill(fam, _evabyte_shapes(cfg, sd), sd, 8, 6144,
+                            12288 + 768).compile(
+        compiler_options=_NO_REMAT if options == "no_remat" else {})
+    recomputed = done.as_text().count(".remat = ")
+    assert done.memory_analysis().temp_size_in_bytes < 0.6e9
+    if options == "no_remat":
+        assert recomputed == 0
+    else:
+        assert recomputed > 0
+
+
 def test_evabyte_prefill_with_the_prompt_kernel_holds_no_score_block(
         one_chip, monkeypatch):
     """Two layers of EvaByte at the published widths, one prompt of 12,288
@@ -366,10 +453,7 @@ def test_evabyte_prefill_with_the_prompt_kernel_holds_no_score_block(
     fam = evabyte.family(cfg, rows)
 
     def compiled():
-        done = jax.jit(lambda p, tokens, lengths: decoder.prefill(
-            fam, p, tokens, lengths, total, jnp.bfloat16)).lower(
-                params, sd(1, P, dtype=jnp.int32),
-                sd(1, dtype=jnp.int32)).compile()
+        done = _evabyte_prefill(fam, params, sd, 8, P, total).compile()
         return done.as_text(), done.memory_analysis().temp_size_in_bytes
 
     def score_blocks(text):
@@ -629,10 +713,12 @@ def test_lfm2_programs_compile_for_v5e_and_hold_no_score_array(
         assert text.count("tpu_custom_call") == 2 + 2 * 8
         assert done.memory_analysis().temp_size_in_bytes < 0.3e9
         return
-    done = jax.jit(lambda p, tokens, lengths: decoder.prefill(
-        fam, p, tokens, lengths, total, jnp.bfloat16)).lower(
-            params, sd(1, P, dtype=jnp.int32), sd(1, dtype=jnp.int32)
-        ).compile()
+    done = jax.jit(
+        lambda p, ck, cv, tail, at, tokens, lengths: decoder.prefill(
+            fam, p, tokens, lengths, (ck, cv, tail), at, jnp.bfloat16),
+        donate_argnums=(1, 2, 3)).lower(
+            params, *leaves, sd(1, dtype=jnp.int32),
+            sd(1, P, dtype=jnp.int32), sd(1, dtype=jnp.int32)).compile()
     text = done.as_text()
     assert text.count("tpu_custom_call") == 2 + 2 * 8
     for dims in re.findall(r"f32\[([\d,]+)\]", text):

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fresh_pool
+
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.utils import registry
@@ -177,8 +179,8 @@ def test_toy_chunked_prefill_equals_monolithic_logits():
     ids = np.random.default_rng(1).integers(1, 90, (P,)).astype(np.int32)
     lens, z1 = jnp.asarray([P], jnp.int32), jnp.zeros((1,), jnp.float32)
     s1 = jnp.zeros((1,), jnp.int32)
-    logits, ck_ref, _ = D.prefill(TOY, params, jnp.asarray(ids[None]), lens,
-                                  P + 3, jnp.float32)
+    logits, ck_ref, _ = fresh_pool.prefill(
+        TOY, params, jnp.asarray(ids[None]), lens, P + 3, jnp.float32)
     want = _reference_logits(_toy_params(), ids)[-1]
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
@@ -292,21 +294,21 @@ LOWERED = {
         **_SLOT, "arch": _NEMOTRON_ARCH}),
 }
 # sha256 of ``jit(...).lower(...).as_text()`` (no source locations in it) of
-# the slot lane's three programs as PR 43 (commit 877073a) lowered them for
-# the CPU, with the JAX this repository is installed with (0.9.0): a batch-2
-# prefill of the one bucket, ``insert_from`` and the segment.  They prove one
-# thing, for the PR that widened the cache (45): that the widening reached
-# none of these programs.  A later PR that changes one of them on purpose, or
-# a new JAX, re-pins: the failing assertion prints the digest to put here.
+# the slot lane's programs for the CPU, with the JAX this repository is
+# installed with (0.9.0): a batch-2 prefill of the one bucket and the
+# segment.  The segments are as PR 43 (commit 877073a) lowered them: they
+# proved, for the PR that widened the cache (45), that the widening reached
+# none of these programs, and for the PR that made the prefill write into
+# the pool (50), which re-pinned the prefills and took the ``insert_from``
+# cases away with the program, that it left the segment alone.  A later PR
+# that changes one of them on purpose, or a new JAX, re-pins: the failing
+# assertion prints the digest to put here.
 PR43_TEXT = {
-    "gpt2-prefill": "ca360374ede8002f70a7eb5f586f49847abe161da3c44e783a8cf38e8a4dbb91",
-    "gpt2-insert_from": "4e61d46cc57026cd839e6e7d6bdcfca72a32c269f680cbbf3ea6f7d521ceeaeb",
+    "gpt2-prefill": "d064dd8bc4cbe57c1846c3e58f568f12d23351b284060ab8560459ddd5c65443",
     "gpt2-segment": "10d533186dec952c3a85af5ea6c15b7a9823b631f115cb90bde581500960262d",
-    "w8a16-prefill": "328dc41de9f207272f677c6fe6f8795b41daea04efe5ae9798287d380e6594b8",
-    "w8a16-insert_from": "67eee799606b6452e3e618fb7df7cc74166492a4914bef4c9cb7de504dd7b715",
+    "w8a16-prefill": "83d1231ce022f565667befa7f30ecc3ac81598c349323f6e5fae41c1251da75b",
     "w8a16-segment": "9bf805388e93f7aeb0522d545d2d4cf0ac9dfdb36063b75ba8ec4e5f074a791e",
-    "evabyte-prefill": "2ffb62e598843ff9fd4650457781c211fa97743a81efbc429051f62bddd15d1a",
-    "evabyte-insert_from": "6a5a3413001d7b8098f95a92dcb15865443048d8d2ff60c5466d832028dd5a6b",
+    "evabyte-prefill": "ceb0c1ff0aa233c33d0c162ed80694eb826a46b0fdcd32488898860a97f950f3",
     "evabyte-segment": "3bfe5dd77b49871f1811c4874f48af3bbc6abbed36914d17bfe25a37d7742175",
 }
 
@@ -336,20 +338,18 @@ def lowered_programs():
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), sv.params)
         payload = {key: jax.ShapeDtypeStruct((2,) + v.shape[1:], v.dtype)
                    for key, v in meta["admit_spec"](buckets[0]).items()}
-        rows = jax.eval_shape(k["prefill"], params, payload)[1:]
         cache = tuple(jax.ShapeDtypeStruct(shape, dt)
                       for shape, dt in meta["cache_leaves"])
         assert name == "nemotron" or (
             len(cache) == 2 and not meta["counters"])
-        i32 = jax.ShapeDtypeStruct((), jnp.int32)
 
         def per_slot(dt):
             return jax.ShapeDtypeStruct((S,), dt)
 
         made[name] = {
-            "prefill": k["prefill"].lower(params, payload),
-            "insert_from": k["insert_from"].lower(cache, tuple(rows), i32,
-                                                  i32),
+            "prefill": k["prefill"].lower(
+                params, cache, jax.ShapeDtypeStruct((2,), jnp.int32),
+                payload),
             "segment": k["segment"].lower(
                 params, cache, *(per_slot(dt) for dt in (
                     jnp.int32, jnp.int32, jnp.int32, jnp.bool_, jnp.float32,
@@ -378,15 +378,15 @@ def test_two_leaf_families_lower_to_the_text_they_had(case, lowered_programs):
         f"touch")
 
 
-# Nemotron-H's three programs as PR 46 (commit 385add5) lowered them for the
-# CPU, pinned by the PR that gave ops/decode_attention.py grouped queries in
+# Nemotron-H's programs for the CPU (the segment as PR 46, commit 385add5,
+# lowered it; the prefill as PR 50 made it, into the pool), pinned by the PR
+# that gave ops/decode_attention.py grouped queries in
 # its kernel and ops/expert_matmul.py a gated form (48): off the chip the
 # grouped ``jax.numpy`` form and the ``relu2`` path of ``ragged_dot`` are
 # what they were.  On the chip its attention layer takes the kernel now, which
 # no CPU lowering shows (PERF.md section 6, PR 48 has the chip's readings).
 PR46_NEMOTRON_TEXT = {
-    "nemotron-prefill": "fb2850b2605d104e8f8c6ba2bb6634fe5566040ea09dac06ac2db1cf995beafb",
-    "nemotron-insert_from": "7e893d85d6bad170505cb2f66bec24d7d818a1dc9820b29e8140f57af40424dc",
+    "nemotron-prefill": "22b0d4aedf479043e526b79475cf7c324249f33a549c275933944f6016b8c240",
     "nemotron-segment": "e5eb8a1f461000c1bcb465db0d1385e2ea55ed080a1b99cd18c33a01cd54be02",
 }
 

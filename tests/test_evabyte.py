@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fresh_pool
+
 from benchmark.reference import evabyte as reference
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.models import decoder as D
@@ -72,8 +74,9 @@ def _forced_run(monkeypatch, lengths, bucket, segments, seg):
     lens = jnp.asarray(lengths, jnp.int32)
     z, zi = jnp.zeros((S,), jnp.float32), jnp.zeros((S,), jnp.int32)
     total = bucket + segments * seg
-    tok, ck, cv = D.prefill_start(fam, params, jnp.asarray(prompts), lens, z,
-                                  zi, total, jnp.float32)
+    tok, ck, cv = D.prefill_start(
+        fam, params, jnp.asarray(prompts), lens, z, zi,
+        D.zero_cache(fam, S, total, jnp.float32), jnp.arange(S), jnp.float32)
     assert ck.shape == (CFG.layers, S, fam.rows.count(total), 32)
     pos, step, fin = lens, zi, jnp.zeros((S,), bool)
     for _ in range(segments):
@@ -174,8 +177,8 @@ def _force_kernel(monkeypatch):
 
 def _prefill(tokens, lengths, total):
     fam, params = _family(), jax.tree.map(jnp.asarray, _params())
-    return jax.jit(lambda p, t, n: D.prefill(fam, p, t, n, total,
-                                             jnp.float32))(
+    return jax.jit(lambda p, t, n: fresh_pool.prefill(
+        fam, p, t, n, total, jnp.float32))(
         params, jnp.asarray(tokens), jnp.asarray(lengths, jnp.int32))
 
 

@@ -61,8 +61,9 @@ def test_segment_chain_matches_one_shot_generate(temperature):
                                  max_new, jnp.float32))
 
     total = 6 + max_new
-    first, ck, cv = D.prefill_start(fam, params, toks, lens, temp, seeds,
-                                    total, jnp.float32)
+    first, ck, cv = D.prefill_start(
+        fam, params, toks, lens, temp, seeds,
+        D.zero_cache(fam, 2, total, jnp.float32), jnp.arange(2), jnp.float32)
     tok, pos = first, lens
     step = jnp.zeros((2,), jnp.int32)
     fin = jnp.zeros((2,), bool)
@@ -86,8 +87,9 @@ def test_segment_frozen_rows_do_not_disturb_neighbors():
     s1 = jnp.zeros((1,), jnp.int32)
     total = 4 + 6
     fam = G.family(cfg)
-    first, ck, cv = D.prefill_start(fam, params, toks, lens, z1, s1, total,
-                                    jnp.float32)
+    first, ck, cv = D.prefill_start(
+        fam, params, toks, lens, z1, s1,
+        D.zero_cache(fam, 1, total, jnp.float32), s1, jnp.float32)
     # Solo row decode.
     solo, *_ = D.decode_segment(fam, params, D.slot_pool(ck, cv), first,
                                 lens, s1, jnp.zeros((1,), bool), z1, s1, 6,
@@ -348,7 +350,7 @@ async def test_boot_log_lists_the_experts_plan_by_program(engine,
 
 
 async def test_first_uses_of_the_lanes_programs_are_booked_once(engine):
-    """The slot lane's first prefill, insert and segment each leave one
+    """The slot lane's first prefill and segment each leave one
     entry in the engine's ledger, with the stages heard from inside jax
     inside the launch's wall; the same shapes again leave none; a new padded
     batch is a first use of cause ``shape``; and the ``:predict`` lane's
@@ -362,7 +364,6 @@ async def test_first_uses_of_the_lanes_programs_are_booked_once(engine):
         first = clock.snapshot()
         assert [(e["program"], e["key"], e["cause"]) for e in first] == [
             ("prefill", {"batch": 1, "bucket": 8, "form": "einsum"}, "first"),
-            ("insert_from", {"batch": 1}, "first"),
             ("segment", {}, "first")]
         for e in first:
             assert e["model"] == "gpt2" and e["outcome"] == "miss"
@@ -370,14 +371,13 @@ async def test_first_uses_of_the_lanes_programs_are_booked_once(engine):
             assert (e["trace_s"] + e["lower_s"] + e["cache_read_s"]
                     + e["backend_s"]) <= e["launch_s"]
             assert e["round"] == 1
-        prefill, insert, segment = first
+        prefill, segment = first
         # The pool's zeros compile inside the first prefill's launch and
         # fold into its entry (where this process has not made them before).
-        assert 1 <= prefill["compiles"] <= 3 and insert["compiles"] == 1
+        assert 1 <= prefill["compiles"] <= 3 and segment["compiles"] == 1
         assert prefill["first_run_s"] > 0 and segment["first_run_s"] > 0
-        assert insert["first_run_s"] is None  # no fetch of its own
         await asyncio.wait_for(sched.submit(one, max_new=4).done, 120)
-        assert len(clock.entries) == 3  # the same keys: nothing new
+        assert len(clock.entries) == 2  # the same keys: nothing new
         pair = [cm.servable.preprocess({"input_ids": [3 + i, 4 + i]})
                 for i in range(2)]
         before = sched.gen_snapshot()["programs"]
@@ -386,14 +386,12 @@ async def test_first_uses_of_the_lanes_programs_are_booked_once(engine):
         # What the benchmark's ``first_uses_in_window`` reads: the counter
         # moves by exactly the programs a burst of a new batch size compiles.
         after = sched.gen_snapshot()["programs"]
-        assert after["first_uses"] - before["first_uses"] == 2
+        assert after["first_uses"] - before["first_uses"] == 1
         assert [(e["program"], e["key"].get("batch"), e["cause"])
-                for e in clock.snapshot()[3:]] == [
-            ("prefill", 2, "shape"), ("insert_from", 2, "shape")]
+                for e in clock.snapshot()[2:]] == [("prefill", 2, "shape")]
         snap = sched.gen_snapshot()["programs"]
-        assert snap["first_uses"] == 5 and snap["backend_hit_s"] == 0
+        assert snap["first_uses"] == 3 and snap["backend_hit_s"] == 0
         assert clock.first_uses() == {("gpt2", "prefill", "miss"): 2,
-                                      ("gpt2", "insert_from", "miss"): 2,
                                       ("gpt2", "segment", "miss"): 1}
         assert snap["launch_s"] + snap["first_run_s"] == pytest.approx(
             clock.per_model()["gpt2"]["seconds"], abs=2e-3)
@@ -560,10 +558,10 @@ async def test_mid_round_pool_reset_requeues_unprocessed_groups(tmp_path):
     try:
         cm = eng.model("gpt2")
         sched = GenerationScheduler(cm, eng.runner, cm.cfg)
-        real_insert_from = sched._insert_from
+        real_prefill = sched._prefill
         state = {"faulted": False}
 
-        def _bad_insert_from(cache, rows, j, slot):
+        def _bad_prefill(params, cache, slots, payload):
             if not state["faulted"]:
                 state["faulted"] = True
                 # Simulate a dispatch that faulted AFTER consuming its
@@ -571,9 +569,9 @@ async def test_mid_round_pool_reset_requeues_unprocessed_groups(tmp_path):
                 for leaf in jax.tree.leaves(cache):
                     leaf.delete()
                 raise RuntimeError("injected post-donation fault")
-            return real_insert_from(cache, rows, j, slot)
+            return real_prefill(params, cache, slots, payload)
 
-        sched._insert_from = _bad_insert_from
+        sched._prefill = _bad_prefill
         sched.start()
         try:
             # Two buckets -> two groups in one admission round; bucket-4
@@ -596,6 +594,77 @@ async def test_mid_round_pool_reset_requeues_unprocessed_groups(tmp_path):
             for got in outs:
                 assert got and got == want[: len(got)]
             assert len({r.slot for r in long}) == 2
+        finally:
+            await sched.stop()
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("fault", [False, True])
+async def test_a_round_s_next_prefill_is_launched_behind_the_one_before(
+        tmp_path, fault):
+    """Two buckets make two admission groups in one round: the second
+    group's prefill is launched before the first group's tokens are fetched
+    (no host turn between two prefills on the device), every stream is the
+    fixed-batch chain, and a launch that raises fails its own group in its
+    own turn and nobody else (the pool was not donated: the lane lives).
+    A shape's first use is not launched so: the round that compiles both
+    runs them one after the other, as the ledger of first uses books them."""
+    from pytorch_zappa_serverless_tpu.engine.loader import build_engine
+    from pytorch_zappa_serverless_tpu.serving.generation import (
+        GenerationScheduler)
+
+    cfg = ServeConfig(
+        compile_cache_dir=str(tmp_path / "xla"), warmup_at_boot=False,
+        models=[ModelConfig(
+            name="gpt2", dtype="float32", batch_buckets=(1, 2),
+            seq_buckets=(4, 8), coalesce_ms=1.0,
+            extra={"max_new_tokens": 6, "arch": TINY_ARCH, "gen_slots": 4,
+                   "segment_tokens": 3})])
+    eng = build_engine(cfg)
+    try:
+        cm = eng.model("gpt2")
+        sched = GenerationScheduler(cm, eng.runner, cm.cfg)
+        events = []
+        real_prefill, real_set_slot = sched._prefill, sched._set_slot
+
+        def prefill(params, cache, slots, payload):
+            events.append(("launch", payload["input_ids"].shape[1]))
+            if fault and events == [("launch", 4), ("launch", 8)]:
+                raise RuntimeError("injected launch fault")  # once
+            return real_prefill(params, cache, slots, payload)
+
+        def set_slot(slot, *rest):
+            events.append(("fetched", slot))
+            return real_set_slot(slot, *rest)
+
+        sched._prefill, sched._set_slot = prefill, set_slot
+        samples = [cm.servable.preprocess({"input_ids": ids}) for ids in (
+            [5], [6], list(range(1, 7)), list(range(2, 8)))]
+        cold = [sched.submit(s, max_new=2) for s in samples]
+        sched.start()
+        try:
+            for req in cold:
+                await asyncio.wait_for(req.done, 120)
+            assert [e for e in events if e[0] == "launch"] == [
+                ("launch", 4), ("launch", 8)]
+            assert events[1][0] == "fetched"  # the first use: one at a time
+            events.clear()
+            reqs = [sched.submit(s, max_new=4) for s in samples]
+            want = [cm.run_batch([s])[0][0]["tokens"][:4] for s in samples]
+            for req, tokens in zip(reqs[:2], want[:2]):
+                assert await asyncio.wait_for(req.done, 60) == tokens
+            for req, tokens in zip(reqs[2:], want[2:]):
+                if fault:
+                    with pytest.raises(RuntimeError, match="injected"):
+                        await asyncio.wait_for(req.done, 60)
+                else:
+                    assert await asyncio.wait_for(req.done, 60) == tokens
+            assert events[:3] == [("launch", 4), ("launch", 8),
+                                  ("fetched", reqs[0].slot)]
+            assert sched.fatal is None and sched._ahead is None
+            again = sched.submit(samples[2], max_new=4)
+            assert await asyncio.wait_for(again.done, 60) == want[2]
         finally:
             await sched.stop()
     finally:

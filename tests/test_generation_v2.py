@@ -166,8 +166,9 @@ def test_chunked_prefill_matches_monolithic_logits_and_chain():
     total = P + max_new
     MB = -(-total // BS)
     fam = G.family(cfg)
-    first_ref, ck_ref, _ = D.prefill_start(fam, params, toks, lens, z1, s1,
-                                           total, jnp.float32)
+    first_ref, ck_ref, _ = D.prefill_start(
+        fam, params, toks, lens, z1, s1,
+        D.zero_cache(fam, 1, total, jnp.float32), s1, jnp.float32)
     want = np.asarray(D.generate(fam, params, toks, lens, z1, s1, max_new,
                                  jnp.float32))[0]
 

@@ -16,6 +16,8 @@ config (the interpret-mode Pallas kernel makes full-size CPU runs minutes):
 import numpy as np
 import pytest
 
+import fresh_pool
+
 from pytorch_zappa_serverless_tpu.config import ModelConfig
 from pytorch_zappa_serverless_tpu import models as _zoo  # noqa: F401
 from pytorch_zappa_serverless_tpu.utils.registry import get_model_builder
@@ -85,10 +87,11 @@ def test_int8_prefill_matches_dequantized_reference(sv_q):
     rng = np.random.default_rng(0)
     toks = rng.integers(1, 500, (2, 16)).astype(np.int32)
     lens = np.full((2,), 16, np.int32)
-    logits_q, ck_q, cv_q = D.prefill(fam, sv_q.params, toks, lens, 24)
+    logits_q, ck_q, cv_q = fresh_pool.prefill(fam, sv_q.params, toks, lens,
+                                              24)
     ref = _dequant_params({k: np.asarray(v) for k, v in sv_q.params.items()}
                           if not isinstance(sv_q.params, dict) else sv_q.params)
-    logits_r, ck_r, cv_r = D.prefill(fam, ref, toks, lens, 24)
+    logits_r, ck_r, cv_r = fresh_pool.prefill(fam, ref, toks, lens, 24)
     lq, lr = np.asarray(logits_q), np.asarray(logits_r)
     # lm head: kernel (int8 head) vs bf16 wte reference — error is head
     # quantization only, small relative to logit scale.

@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 import torch
 
+import fresh_pool
+
 from pytorch_zappa_serverless_tpu.config import ModelConfig
 from pytorch_zappa_serverless_tpu.engine.weights import convert_gpt2
 from pytorch_zappa_serverless_tpu.models import decoder as D
@@ -55,8 +57,8 @@ def test_prefill_last_logits_parity_ragged(rng):
     for b, n in enumerate(lengths):
         toks[b, n:] = 0
     logits, ck, cv = jax.jit(
-        lambda p, t, l: D.prefill(G.family(cfg), p, t, l, P + 4,
-                                  jnp.float32))(
+        lambda p, t, l: fresh_pool.prefill(G.family(cfg), p, t, l, P + 4,
+                                           jnp.float32))(
             params, jnp.asarray(toks.astype(np.int32)), jnp.asarray(lengths))
     mask = (np.arange(P)[None] < lengths[:, None]).astype(np.int64)
     with torch.no_grad():

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fresh_pool
+
 from benchmark.reference import lfm2 as reference
 from pytorch_zappa_serverless_tpu.config import ModelConfig
 from pytorch_zappa_serverless_tpu.engine.cache import CompileClock
@@ -89,29 +91,31 @@ PROGRAM_CASES = {
 
 
 def _admit(kernels, params, cache, prompts, slots):
-    """One batched prefill (padded to a power of two) and an insert a
-    request → ``(cache, first tokens)``."""
-    B = 1 << (len(prompts) - 1).bit_length()
+    """One batched prefill (padded to a power of two with copies of its
+    first prompt, which are given that prompt's slot, as the scheduler pads)
+    into ``slots`` of the pool → ``(cache, first tokens)``."""
+    prompts = list(prompts) + [prompts[0]] * (
+        (1 << (len(prompts) - 1).bit_length()) - len(prompts))
+    slots = list(slots) + [slots[0]] * (len(prompts) - len(slots))
+    B = len(prompts)
     toks = np.zeros((B, 16), np.int32)
     for j, ids in enumerate(prompts):
         toks[j, :len(ids)] = ids
-    lens = np.asarray([len(p) for p in prompts] + [1] * (B - len(prompts)),
-                      np.int32)
-    payload = {"input_ids": toks, "length": lens,
+    payload = {"input_ids": toks,
+               "length": np.asarray([len(p) for p in prompts], np.int32),
                "temperature": np.zeros(B, np.float32),
                "seed": np.zeros(B, np.int32), "top_k": np.zeros(B, np.int32),
                "top_p": np.ones(B, np.float32)}
-    first, *rows = kernels["prefill"](params, payload)
-    for j, slot in enumerate(slots):
-        cache = kernels["insert_from"](cache, tuple(rows), np.int32(j),
-                                       np.int32(slot))
+    first, *cache = kernels["prefill"](params, tuple(cache),
+                                       np.asarray(slots, np.int32), payload)
+    cache = tuple(cache)
     return cache, np.asarray(first)
 
 
 @pytest.mark.parametrize("case", list(PROGRAM_CASES))
-def test_prefill_insert_and_segment_give_the_reference_s_logits(
+def test_prefill_and_segment_give_the_reference_s_logits(
         case, tree, servable):
-    """``prefill_start``, ``insert_from`` and ``decode_segment`` as the
+    """``prefill_start`` into the pool and ``decode_segment`` as the
     scheduler jits them; then, because a segment returns tokens, the same
     step (``_decode_logits``) over the same pool for the logits of every
     position a segment decoded."""
@@ -184,9 +188,9 @@ def test_prefill_logits_are_the_reference_s_and_a_lower_precision_is_not(
     toks = rng.integers(0, 96, (3, 24)).astype(np.int32)
     lens = np.asarray([24, 7, 17], np.int32)
     with jax.default_matmul_precision("highest"):
-        logits, *cache = D.prefill(fam, params, jnp.asarray(toks),
-                                   jnp.asarray(lens), 40, jnp.float32)
-        half, *_ = D.prefill(
+        logits, *cache = fresh_pool.prefill(fam, params, jnp.asarray(toks),
+                                            jnp.asarray(lens), 40, jnp.float32)
+        half, *_ = fresh_pool.prefill(
             M.family(CFG, jnp.bfloat16),
             jax.tree.map(lambda a: a.astype(jnp.bfloat16) if a.ndim >= 2
                          else a, params),
@@ -214,10 +218,10 @@ def test_decode_continues_from_a_prefill_s_state(tree):
     n, k = 10, 6
     one = jnp.asarray([n], jnp.int32)
     with jax.default_matmul_precision("highest"):
-        _, *short = D.prefill(fam, params, jnp.asarray(ids), one, 24,
-                              jnp.float32)
-        full_logits, *full = D.prefill(fam, params, jnp.asarray(ids), one + k,
+        _, *short = fresh_pool.prefill(fam, params, jnp.asarray(ids), one,
                                        24, jnp.float32)
+        full_logits, *full = fresh_pool.prefill(
+            fam, params, jnp.asarray(ids), one + k, 24, jnp.float32)
         pool = D.slot_pool(*short[:2], fam.rows)
         cache = tuple(short)
         for t in range(k):
@@ -237,8 +241,8 @@ def test_a_one_token_prompt_keeps_a_zero_row_before_it(tree):
     fam = M.family(CFG, jnp.float32)
     params = jax.tree.map(jnp.asarray, tree)
     ids = jnp.asarray([[5, 0, 0, 0, 0, 0, 0, 0]], jnp.int32)
-    _, _, _, tail = D.prefill(fam, params, ids, jnp.asarray([1], jnp.int32),
-                              12, jnp.float32)
+    _, _, _, tail = fresh_pool.prefill(
+        fam, params, ids, jnp.asarray([1], jnp.int32), 12, jnp.float32)
     tail = np.asarray(tail)
     assert not tail[:, 0, 0].any() and tail[:, 0, 1].any()
 
@@ -316,13 +320,14 @@ def test_flash_prompt_form_is_the_form_that_writes_its_scores(
             for _ in range(2))
     lengths = jnp.asarray([P, short], jnp.int32)
     cache = tuple(jnp.zeros((2, B, P + 8, kv * dh)) for _ in range(2))
-    want_cache, want = GroupedRows(kv).prompt(heads, lengths, P)(
+    put = D.slot_put(jnp.arange(B))
+    want_cache, want = GroupedRows(kv).prompt(heads, lengths, P, put)(
         None, cache, jnp.int32(1), q, k, v)
     rows = M.GroupedFlashRows(kv)
     assert rows.prompt_form(B, heads, P, dh) == "grouped"  # the CPU's
     monkeypatch.setattr(M.GroupedFlashRows, "prompt_form",
                         lambda self, *shape: "flash")
-    got_cache, got = rows.prompt(heads, lengths, P)(
+    got_cache, got = rows.prompt(heads, lengths, P, put)(
         None, cache, jnp.int32(1), q, k, v)
     for b, n in enumerate([P, short]):  # rows past a length mean nothing
         assert np.abs(np.asarray(got[b, :n]) - np.asarray(want[b, :n])
@@ -597,7 +602,7 @@ def test_layer_traces_is_three_for_the_segment_and_the_prefill(servable):
     payload = {k: jnp.zeros(v.shape, v.dtype)
                for k, v in meta["admit_spec"](8).items()}
     with clock.open("m", "prefill", {"batch": 1, "bucket": 8}, seen=set()):
-        jax.jit(meta["prefill"])(servable.params,
+        jax.jit(meta["prefill"])(servable.params, cache, zi[:1],
                                  {**payload, "length": jnp.ones(1, jnp.int32)})
     assert clock.snapshot()[-1]["layer_traces"] == 3
 

@@ -399,7 +399,7 @@ def test_leader_death_releases_follower_then_world_restarts(tmp_path):
 @pytest.mark.slow
 def test_streaming_generation_mirrors_on_multihost(tmp_path):
     """SSE/continuous-batching on a CROSS-HOST TP mesh: the leader's
-    scheduler broadcasts every prefill/insert/segment (OP_GEN_*), the
+    scheduler broadcasts every prefill and segment (OP_GEN_*), the
     follower mirrors them, and the streamed tokens equal a single-process
     run of the same scheduler."""
     port = "29751"

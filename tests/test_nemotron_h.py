@@ -17,6 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fresh_pool
+
 from benchmark.reference import nemotron_h as reference
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.engine.cache import CompileClock
@@ -74,29 +76,31 @@ PROGRAM_CASES = {
 
 
 def _admit(kernels, meta, params, cache, prompts, slots):
-    """One batched prefill (padded to a power of two) and an insert a
-    request → ``(cache, first tokens)``."""
-    B = 1 << (len(prompts) - 1).bit_length()
+    """One batched prefill (padded to a power of two with copies of its
+    first prompt, which are given that prompt's slot, as the scheduler pads)
+    into ``slots`` of the pool → ``(cache, first tokens)``."""
+    prompts = list(prompts) + [prompts[0]] * (
+        (1 << (len(prompts) - 1).bit_length()) - len(prompts))
+    slots = list(slots) + [slots[0]] * (len(prompts) - len(slots))
+    B = len(prompts)
     toks = np.zeros((B, 16), np.int32)
     for j, ids in enumerate(prompts):
         toks[j, :len(ids)] = ids
-    lens = np.asarray([len(p) for p in prompts] + [1] * (B - len(prompts)),
-                      np.int32)
-    payload = {"input_ids": toks, "length": lens,
+    payload = {"input_ids": toks,
+               "length": np.asarray([len(p) for p in prompts], np.int32),
                "temperature": np.zeros(B, np.float32),
                "seed": np.zeros(B, np.int32), "top_k": np.zeros(B, np.int32),
                "top_p": np.ones(B, np.float32)}
-    first, *rows = kernels["prefill"](params, payload)
-    for j, slot in enumerate(slots):
-        cache = kernels["insert_from"](cache, tuple(rows), np.int32(j),
-                                       np.int32(slot))
+    first, *cache = kernels["prefill"](params, tuple(cache),
+                                       np.asarray(slots, np.int32), payload)
+    cache = tuple(cache)
     return cache, np.asarray(first)
 
 
 @pytest.mark.parametrize("case", list(PROGRAM_CASES))
-def test_prefill_insert_and_segment_give_the_reference_s_logits(
+def test_prefill_and_segment_give_the_reference_s_logits(
         case, tree, servable):
-    """``prefill_start``, ``insert_from`` and ``decode_segment`` as the
+    """``prefill_start`` into the pool and ``decode_segment`` as the
     scheduler jits them; then, because a segment returns tokens, the same
     step (``_decode_logits``) over the same pool for the logits of every
     position a segment decoded."""
@@ -165,8 +169,8 @@ def test_prefill_logits_are_the_reference_s(tree):
     toks = rng.integers(0, 96, (3, 24)).astype(np.int32)
     lens = np.asarray([24, 7, 17], np.int32)
     with jax.default_matmul_precision("highest"):
-        logits, *cache = D.prefill(fam, params, jnp.asarray(toks),
-                                   jnp.asarray(lens), 40, jnp.float32)
+        logits, *cache = fresh_pool.prefill(fam, params, jnp.asarray(toks),
+                                            jnp.asarray(lens), 40, jnp.float32)
     assert [c.shape for c in cache] == [
         (1, 3, 40, 32), (1, 3, 40, 32), (3, 3, 8, 8, 16), (3, 3, 3, 128)]
     for b in range(3):
@@ -217,10 +221,10 @@ def test_decode_continues_from_a_prefill_s_state(tree):
     n, k = 10, 6
     one = jnp.asarray([n], jnp.int32)
     with jax.default_matmul_precision("highest"):
-        _, *short = D.prefill(fam, params, jnp.asarray(ids), one, 24,
-                              jnp.float32)
-        _, *full = D.prefill(fam, params, jnp.asarray(ids), one + k, 24,
-                             jnp.float32)
+        _, *short = fresh_pool.prefill(fam, params, jnp.asarray(ids), one,
+                                       24, jnp.float32)
+        _, *full = fresh_pool.prefill(fam, params, jnp.asarray(ids), one + k,
+                                      24, jnp.float32)
         pool = D.slot_pool(*short[:2], fam.rows)
         cache = tuple(short)
         for t in range(k):
@@ -404,7 +408,7 @@ def test_layer_traces_is_three_for_the_segment_and_the_prefill(servable):
     payload = {k: jnp.zeros(v.shape, v.dtype)
                for k, v in meta["admit_spec"](8).items()}
     with clock.open("m", "prefill", {"batch": 1, "bucket": 8}, seen=set()):
-        jax.jit(meta["prefill"])(servable.params,
+        jax.jit(meta["prefill"])(servable.params, cache, zi[:1],
                                  {**payload, "length": jnp.ones(1, jnp.int32)})
     assert clock.snapshot()[-1]["layer_traces"] == 3
 

@@ -135,7 +135,7 @@ def synthetic_capture(tmp_path):
         device  prefill [1000, 3000)   gap [3000, 4000)   segment [4000, 9000)
                 (its one operation ends at 2800: 200 us idle inside the run)
         host    prefill.launch [500, 1200)  prefill.fetch [1200, 3100)
-                insert.launch [3100, 3400)  (no phase over [3400, 3700))
+                round.wakeup [3100, 3400)   (no phase over [3400, 3700))
                 segment.launch [3700, 4100) segment.fetch [4100, 9050)
     """
     device = _plane(1, "/device:TPU:0", [
@@ -146,11 +146,11 @@ def synthetic_capture(tmp_path):
         "%while.2 = (s32[]) while(%t)", "%copy.11 = f32[8] copy(%q)"])
     host = _plane(2, "/host:CPU", [
         _line(7, "python", [(1, 500, 700, {1: 1}), (2, 1200, 1900, {}),
-                            (3, 3100, 300, {1: 0}), (4, 3700, 400, {1: 1}),
+                            (3, 3100, 300, {}), (4, 3700, 400, {1: 1}),
                             (5, 4100, 4950, {})]),
         _line(8, "python", [(6, 100, 300, {})]),
     ], ["tpuserve.prefill.launch", "tpuserve.prefill.fetch",
-        "tpuserve.insert.launch", "tpuserve.segment.launch",
+        "tpuserve.round.wakeup", "tpuserve.segment.launch",
         "tpuserve.segment.fetch", "tpuserve.round.admit_host"], ["programs"])
     out = tmp_path / "capture"
     out.mkdir()
@@ -167,10 +167,10 @@ def test_attribute_idle_books_a_known_gap(synthetic_capture):
     assert idle["busy_ms"] == pytest.approx(6.8)
     assert idle["idle_ms"] == pytest.approx(1.2)
     # The gap between the runs: 100 us still in prefill.fetch, 300 in
-    # insert.launch, 300 in segment.launch, 300 that no phase covers; the
+    # round.wakeup, 300 in segment.launch, 300 that no phase covers; the
     # 200 us before the prefill run's end are idle inside a program.
     assert idle["by_phase"] == {
-        "insert.launch": pytest.approx(0.3),
+        "round.wakeup": pytest.approx(0.3),
         "segment.launch": pytest.approx(0.3),
         "in_program": pytest.approx(0.2),
         "prefill.fetch": pytest.approx(0.1)}
@@ -220,49 +220,53 @@ def test_one_read_of_a_capture_serves_both_reductions(synthetic_capture):
 
 def test_join_survives_a_capture_that_begins_mid_round(tmp_path):
     """The first run's launch is not in the capture, and the device plane's
-    clock is 300 us early (an insert run seems to start before its launch
-    did): the join neither hands the first run to the first launch nor loses
-    the insert and shifts every later name by one, and the gaps are booked
-    with the clock put right."""
+    clock is 300 us early (the second prefill's run seems to start before
+    its launch did): the join neither hands the first run to the first
+    launch nor loses a prefill and shifts every later name by one, and the
+    gaps are booked with the clock put right."""
     device = _plane(1, "/device:TPU:0", [
         _line(1, "XLA Modules", [(1, 1500, 8000, {}), (1, 11000, 9000, {}),
-                                 (2, 20200, 500, {}), (2, 21500, 500, {}),
+                                 (1, 20200, 500, {}), (1, 21500, 500, {}),
                                  (1, 23000, 9000, {})]),
-        _line(2, "XLA Ops", [(3, 1500, 8000, {}), (3, 11000, 9000, {}),
-                             (3, 20200, 500, {}), (3, 21500, 500, {}),
-                             (3, 23000, 9000, {})]),
-    ], ["jit__lambda_(7)", "jit__insert_from(9)",
-        "%fusion.3 = f32[8] fusion(%p)"])
+        _line(2, "XLA Ops", [(2, 1500, 8000, {}), (2, 11000, 9000, {}),
+                             (2, 20200, 500, {}), (2, 21500, 500, {}),
+                             (2, 23000, 9000, {})]),
+    ], ["jit__lambda_(7)", "%fusion.3 = f32[8] fusion(%p)"])
     host = _plane(2, "/host:CPU", [
         # round.distribute of the round whose segment.launch came too early
-        # to be recorded, then: prefill, two inserts, segment.
-        _line(7, "python", [(6, 1000, 200, {})]),
+        # to be recorded, then: three prefills of one round, its segment.
+        _line(7, "python", [(5, 1000, 200, {})]),
         _line(8, "python", [(1, 10000, 1500, {1: 1}), (2, 11500, 8900, {}),
-                            (3, 20500, 1500, {1: 2}), (4, 22000, 1500, {1: 1}),
-                            (5, 23500, 9000, {})]),
+                            (1, 20500, 400, {1: 1}), (2, 20900, 200, {}),
+                            (1, 21200, 700, {1: 1}), (2, 21900, 500, {}),
+                            (3, 22500, 1000, {1: 1}), (4, 23500, 9000, {})]),
     ], ["tpuserve.prefill.launch", "tpuserve.prefill.fetch",
-        "tpuserve.insert.launch", "tpuserve.segment.launch",
-        "tpuserve.segment.fetch", "tpuserve.round.distribute"], ["programs"])
+        "tpuserve.segment.launch", "tpuserve.segment.fetch",
+        "tpuserve.round.distribute"], ["programs"])
     out = tmp_path / "capture"
     out.mkdir()
     (out / "a.xplane.pb").write_bytes(
         ProfileData.text_proto_to_serialized_xspace(device + host))
     got = attribute_idle(out)
     assert {k: v["runs"] for k, v in got["programs"].items()} \
-        == {"jit__lambda_": 1, "prefill": 1, "insert": 2, "segment": 1}
+        == {"jit__lambda_": 1, "prefill": 3, "segment": 1}
     # The fetch returned 500 us after the segment's run by the planes' own
     # clocks, 200 us with the device's put right.
     assert got["idle"]["clock"] == {
         "segments": 1, "ok": 1, "lag_ms": {"min": 0.2, "max": 0.2},
         "device_early_ms": 0.3}
     gaps = {(g["before"], g["after"]): g for g in got["idle"]["gaps"]}
-    assert list(gaps) == [("jit__lambda_", "prefill"), ("insert", "segment"),
-                          ("insert", "insert"), ("prefill", "insert")]
+    assert set(gaps) == {("jit__lambda_", "prefill"), ("prefill", "segment"),
+                         ("prefill", "prefill")}
     # Device 20000-20200 is host 20300-20500: the fetch had returned at
-    # 20400, the insert launch began at 20500.
-    assert gaps[("prefill", "insert")]["phases"] == {
-        "prefill.fetch": pytest.approx(0.1),
-        "unattributed": pytest.approx(0.1)}
+    # 20400, the next prefill's launch began at 20500.  Device 20700-21500
+    # is host 21000-21800: 100 us of the second fetch, 100 of nothing, 600
+    # of the third launch.
+    assert gaps[("prefill", "prefill")]["count"] == 2
+    assert gaps[("prefill", "prefill")]["phases"] == {
+        "prefill.launch": pytest.approx(0.6),
+        "prefill.fetch": pytest.approx(0.2),
+        "unattributed": pytest.approx(0.2)}
 
 
 def test_attribute_idle_without_annotations_or_device(tmp_path):

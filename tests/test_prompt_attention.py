@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 import chip_smoke
+import fresh_pool
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.engine.loader import build_engine
 from pytorch_zappa_serverless_tpu.models import decoder as D
@@ -228,8 +229,8 @@ def _force_kernel(monkeypatch):
 def _prefill(tokens, lengths, total):
     fam = G.family(CFG)
     params = jax.tree.map(jnp.asarray, G.init_gpt2_params(5, CFG))
-    return jax.jit(lambda p, t, n: D.prefill(fam, p, t, n, total,
-                                             jnp.float32))(
+    return jax.jit(lambda p, t, n: fresh_pool.prefill(
+        fam, p, t, n, total, jnp.float32))(
         params, jnp.asarray(tokens), jnp.asarray(lengths, jnp.int32))
 
 
@@ -260,7 +261,7 @@ def test_prefill_on_the_cpu_lowers_to_the_masked_form(monkeypatch):
               jax.ShapeDtypeStruct((2,), jnp.int32))
 
     def text():
-        return jax.jit(lambda p, t, n: D.prefill(
+        return jax.jit(lambda p, t, n: fresh_pool.prefill(
             fam, p, t, n, 104, jnp.bfloat16)).lower(*shapes).as_text()
 
     built = text()

@@ -434,7 +434,7 @@ async def test_generation_trace_spans(aiohttp_client, tmp_path):
 _GEN_ARCH = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 512,
              "vocab_size": 512, "max_positions": 128}
 _GEN_SLOTS = 2
-_DISPATCH_PHASES = ("prefill.", "insert.", "segment.", "round.lane_wait")
+_DISPATCH_PHASES = ("prefill.", "segment.", "round.lane_wait")
 
 
 def _gen_cfg(tmp_path, kv_cache):
@@ -567,10 +567,10 @@ async def test_a_first_use_carries_the_round_of_the_launch_that_compiled(
         entries = engine.clock.snapshot()
     finally:
         engine.shutdown()
-    launch_of = {"prefill": "prefill.launch", "insert_from": "insert.launch",
+    launch_of = {"prefill": "prefill.launch",
                  "prefill_chunk": "prefill.launch",
                  "segment": "segment.launch"}
-    want = {"slot": ["prefill", "insert_from", "segment"],
+    want = {"slot": ["prefill", "segment"],
             "paged": ["prefill_chunk", "segment"]}[kv_cache]
     assert [e["program"] for e in entries] == want
     for e in entries:

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fresh_pool
+
 from pytorch_zappa_serverless_tpu.config import ModelConfig
 from pytorch_zappa_serverless_tpu.engine.cache import CompileClock
 from pytorch_zappa_serverless_tpu.models import decoder as D
@@ -32,7 +34,7 @@ from pytorch_zappa_serverless_tpu.utils.registry import get_model_builder
 
 
 def loop_trunk(fam, params, x, pos, cache, attend, adapter_idx=None,
-               lengths=None):
+               lengths=None, put=None):
     """The trunk as it was before the shared body: a Python loop that calls
     the family's block afresh a layer, with the layer's index a Python int
     (for the families whose cache is K and V rows and nothing else)."""
@@ -133,7 +135,8 @@ def _program(name, fam, dtype, adapters):
         return jnp.asarray(g.standard_normal(shape), dtype)
 
     if name == "prefill":
-        return (lambda p, t, n: D.prefill(fam, p, t, n, total, dtype, aidx),
+        return (lambda p, t, n: fresh_pool.prefill(fam, p, t, n, total,
+                                                   dtype, aidx),
                 (toks, lens))
     if name == "segment":
         T = fam.rows.count(total)
