@@ -8,7 +8,6 @@ Zappa gives the reference ``deploy / update / tail / undeploy`` plus local
 - ``warm``         build + AOT-compile everything, populating the persistent
                    compile cache, then exit — the warm-pool primer that makes
                    the next boot near-instant (== ``keep_warm``)
-- ``bench``        measure the BASELINE metrics against a running engine
 - ``list-models``  show the registered zoo
 - ``deploy``       render deploy artifacts (Cloud Run + warm pool; see deploy/)
 - ``stage``        build the deployable asset tree: convert checkpoints once,
@@ -199,12 +198,6 @@ def cmd_list_models(args) -> int:
     for name in list_models():
         print(name)
     return 0
-
-
-def cmd_bench(args) -> int:
-    from .benchmark import main as bench_main
-
-    return bench_main(all_lines=args.all)
 
 
 def cmd_profile(args) -> int:
@@ -757,11 +750,6 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--json", action="store_true",
                     help="raw /admin/perf JSON instead of the table")
     sp.set_defaults(fn=cmd_perf)
-
-    sp = sub.add_parser("bench", help="emit the BASELINE metric JSON line")
-    sp.add_argument("--all", action="store_true",
-                    help="also print one JSON line per BASELINE config")
-    sp.set_defaults(fn=cmd_bench)
 
     sp = sub.add_parser("profile", help="capture a jax.profiler trace from a running server")
     sp.add_argument("--url", default="http://127.0.0.1:8000")

@@ -252,8 +252,8 @@ class ServeConfig:
     warmup_at_boot: bool = True
     # Two-level priority dispatch (engine/runner.py): latency-class dispatches
     # jump ahead of queued throughput work between device calls.  False
-    # restores the single-FIFO lane (the pre-QoS behavior; the mixed_path
-    # bench uses it as the head-of-line-blocking comparison point).
+    # restores the single-FIFO lane (the pre-QoS behavior, kept as the
+    # comparison point for head-of-line blocking on one engine).
     priority_dispatch: bool = True
     # Device mesh shape for multi-chip serving, e.g. {"data": 4, "model": 2}.
     # Empty → single-device (the v5e-1 target).
@@ -449,8 +449,9 @@ class ServeConfig:
     # sampler, and the rolling per-model throughput gauges — all surfaced on
     # GET /admin/perf, `tpuserve perf`, and the tpuserve_ingest_ms/
     # tpuserve_loop_lag_*/tpuserve_perf_* metric families.  False turns the
-    # whole plane off (no threads, no timers, no histogram writes); the
-    # BENCH_SERVERPATH section measures the on-vs-off overhead (<1% p50).
+    # whole plane off (no threads, no timers, no histogram writes).  What
+    # the plane costs when on has no measurement in the tree (ROADMAP
+    # Design 4).
     perfplane: bool = True
     # Event-loop lag probe cadence (also the gauge sampling cadence).
     perf_loop_lag_interval_s: float = 0.25

@@ -61,7 +61,7 @@ def setup_compile_cache(cache_dir: str | Path | None = None) -> str:
     too: jax initializes its persistent-cache object lazily once and then
     ignores later ``jax_compilation_cache_dir`` updates, so the cache
     object is reset whenever the dir changes (tests re-pointing per case,
-    the lifecycle bench's fresh-dir-per-cold-trial path).
+    a fresh directory for each cold trial of a measurement).
     """
     global _configured
     _listen()

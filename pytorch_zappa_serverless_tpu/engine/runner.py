@@ -63,8 +63,8 @@ class _DaemonDispatchPool:
     work.  ``submit`` (the Executor-compatible entry health probes use)
     routes to the latency lane — a liveness check must never sit behind a
     throughput backlog.  With ``priority_enabled`` False the pop order is
-    strict cross-lane FIFO by enqueue sequence (the pre-QoS behavior; the
-    mixed_path bench's comparison point).
+    strict cross-lane FIFO by enqueue sequence (the pre-QoS behavior, the
+    comparison point for head-of-line blocking).
     """
 
     def __init__(self, thread_name: str = "tpu-dispatch"):
@@ -445,8 +445,8 @@ class DeviceRunner:
         """Toggle the two-level lane (ServeConfig.priority_dispatch).
 
         False = strict cross-lane FIFO — the pre-QoS single queue, kept as a
-        runtime toggle so the mixed_path bench can measure head-of-line
-        blocking on the same engine.
+        runtime toggle so head-of-line blocking can be measured on the same
+        engine.
         """
         self._pool.set_priority(enabled)
 

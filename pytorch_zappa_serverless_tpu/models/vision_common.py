@@ -30,10 +30,10 @@ def cast_params_at_rest(params, dtype):
     scales and biases stay fp32 for the fp32 norm paths.
 
     THE single definition of the at-rest predicate; engine/compiled.py (the
-    serving path), benchmark._servable (which must bench what serving runs)
-    and the gpt2 int8 lane all call it, so the bench cannot silently diverge
-    from serving again (r2's sd15 benched fp32-at-rest by exactly this
-    drift).
+    serving path), the tools that time a model outside the server
+    (tools/trace_ops.py, tools/profile_sd15.py) and the gpt2 int8 lane all
+    call it, so a measurement cannot silently diverge from serving again
+    (r2's sd15 was timed fp32-at-rest by exactly this drift).
     """
     import jax
 

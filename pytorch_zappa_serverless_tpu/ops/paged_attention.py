@@ -25,7 +25,7 @@ primitives are the whole device-side contract:
 
 XLA lowers both to dynamic-gather/scatter HLOs; the gather reads the same
 bytes per step a contiguous cache read would, so the paged lane's step cost
-matches the slot pool's (BENCH_GENERATION section).  On TPU the Pallas
+should match the slot pool's (no cell runs it: unmeasured).  On TPU the Pallas
 upgrade path is the official ``pltpu`` paged-attention kernel (one async DMA
 per page, double-buffered — accelerator guide §9-11): these functions are
 the semantics it would replace, kept jnp-level so the CPU backend runs the

@@ -50,8 +50,8 @@ Surfaces: ``GET /admin/autoscale`` + the ``tpuserve autoscale`` CLI table
 (per-key forecast, window, next planned action), the manifest-pinned
 ``tpuserve_autoscale_*`` Prometheus families (serving/metrics.py; the
 router renders ``tpuserve_autoscale_scale_events_total``), and the
-``BENCH_AUTOSCALE=1`` policy-sweep bench section (tools/replay.py
-``--policy-sweep``).  docs/AUTOSCALE.md is the operator story.
+policy sweep over replayed traces (tools/replay.py ``--policy-sweep``).
+docs/AUTOSCALE.md is the operator story.
 
 Concurrency: the plane is event-loop-confined like the lifecycle and
 adapter managers — arrivals are noted from the server middleware, the tick

@@ -473,8 +473,8 @@ class GenerationScheduler:
         self._prefill = kernels["prefill"]
         self._segment = kernels["segment"]
         self._alloc_cache = kernels["alloc_cache"]
-        # Observability: device prefill dispatches (the burst-admission
-        # bench asserts a burst coalesces into few of these).  Slot state
+        # Observability: device prefill dispatches (a burst should
+        # coalesce into few of these: test_generation_stream.py).  Slot state
         # and the caches below are "dispatch-serialized": mutated by the
         # *_sync kernels on the dispatch thread AND by the scheduler task,
         # but never concurrently — the task awaits every run_fn round-trip
@@ -673,8 +673,8 @@ class GenerationScheduler:
         prefill program per (bucket, pow2-batch), not per burst size); pad
         rows are copies of the first payload and are given its slot, so they
         write its values there once more.  One fetch (the first tokens) per
-        burst instead of one per request — the round-3
-        generate_path bench measured 9 device rounds to first token at
+        burst instead of one per request — before it, round 3
+        counted 9 device rounds to first token at
         concurrency 8, 8 of them serialized batch-1 admission prefills
         (VERDICT r3 #5).  Single-host only: the lockstep broadcast protocol
         keeps the proven per-admission form (serving/generation._loop).

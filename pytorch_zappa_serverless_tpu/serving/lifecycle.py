@@ -60,7 +60,7 @@ Mechanisms:
   with ``kind="activation"`` inject chaos into the build path.
 
 docs/LIFECYCLE.md is the operator story; ``GET/POST /admin/models/{name}``
-the admin surface; ``BENCH_LIFECYCLE=1`` the bench section.
+the admin surface.
 """
 
 from __future__ import annotations
@@ -125,8 +125,8 @@ class ModelResidency:
     activations: int = 0            # guarded-by: event-loop
     last_activation_ms: float | None = None  # guarded-by: event-loop
     cold_fast_fails: int = 0        # guarded-by: event-loop
-    # load_ms/compile_ms split of the last activation (the BENCH_LIFECYCLE
-    # attribution satellite); fake build_fns never set it.
+    # load_ms/compile_ms split of the last activation (on the model's row
+    # in /admin/models); fake build_fns never set it.
     last_activation_phases: dict | None = None  # guarded-by: event-loop
     # Requests currently inside a handler for this model (the server's
     # enter/exit guard): the in-flight floor the demotion path respects even
@@ -439,8 +439,8 @@ class LifecycleManager:
         A broken disk stream (torn chunks past the re-read, missing
         manifest) degrades to the legacy whole-file build — never a dead
         activation.  Fills ``_build_phases[name]`` with the
-        ``load_ms``/``compile_ms`` attribution the activation record and
-        BENCH_LIFECYCLE report.
+        ``load_ms``/``compile_ms`` attribution the activation record
+        reports.
         """
         server = self.server
         server.engine.runner.faults.on_activation(name)
@@ -554,7 +554,7 @@ class LifecycleManager:
                 cm.servable.params = payload
                 # The stream ran concurrently with build+compile above, so
                 # load_ms + compile_ms can exceed the activation wall
-                # clock; that overlap IS the win the bench attributes.
+                # clock; that overlap IS the win of streaming.
                 phases["load_ms"] = stream_ms
                 phases["streamed"] = True
             else:

@@ -16,17 +16,17 @@ changes judgeable — PAPERS.md):
   ``payload_read`` / ``json_decode`` / ``b64_decode`` / ``binary_decode`` /
   ``validate`` / ``batch_form`` / ``serialize`` / ``respond`` — beside the
   trace substages
-  the waterfall renders (tools/tracedump.py).  ``BENCH_SERVERPATH=1``
-  aggregates the same stages into the gap-decomposition bench table.
+  the waterfall renders (tools/tracedump.py).
 - **Continuous runtime profiler**: :class:`LoopLagSampler` (scheduled-vs-
   actual callback delta — the event-loop stall detector: a blocking call on
   the loop shows here before it shows as tail latency) and
   :class:`StackSampler` (a py-spy-style wall-clock sampler over
   ``sys._current_frames()``, aggregated by collapsed stack into a bounded
   top-K table — the "what is the host actually doing" answer without a
-  redeploy).  Both are injectable-clock testable and cheap enough to stay
-  on (<1% serving overhead, measured by the BENCH_SERVERPATH section's
-  on-vs-off phase).
+  redeploy).  Both are injectable-clock testable and meant to be cheap
+  enough to stay on: a timer callback an interval and a frame walk a stack
+  tick, off the serving path (what that costs a request has no measurement
+  in the tree: ROADMAP Design 4).
 - **Rolling per-model gauges**: tok/s, samples/s, step time and device
   utilization computed by differencing the counters the runner and the
   generation schedulers already keep (RunStats.device_seconds/samples,

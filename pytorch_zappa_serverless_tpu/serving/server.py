@@ -896,10 +896,9 @@ class Server:
 
     def _flops_hint(self, name: str) -> float | None:
         """Per-sample FLOP hint for the live MFU gauge (docs/OBSERVABILITY
-        §9): ``ModelConfig.extra.flops_per_sample``, typically copied from a
-        bench round's ``hlo_gflops``.  None (the default) omits the gauge —
-        an unhinted MFU would be a guess, and the bench sections stay the
-        MFU source of truth."""
+        §9): ``ModelConfig.extra.flops_per_sample``, typically the program's
+        FLOPs a sample by XLA's cost analysis.  None (the default) omits the
+        gauge — an unhinted MFU would be a guess."""
         try:
             v = self.cfg.model(name).extra.get("flops_per_sample")
         except KeyError:
@@ -1713,7 +1712,7 @@ class Server:
 
         The escalation path from a trace: a span tree says *which stage* is
         slow; this says *which device ops* (``ops``, classified through the
-        ``utils/xplane.py`` rules the bench's ``device_trace_ms`` uses;
+        ``utils/xplane.py`` rules ``tools/trace_ops.py`` uses;
         ``top`` bounds the list, default 15), *which program* runs them
         (``programs``: device runs named by the ``tpuserve.*.launch``
         annotation that launched them) and *what the host was doing while
@@ -2009,7 +2008,7 @@ class Server:
         # Admission stage span: anchored to the root's start so the stage
         # chain (admission → queue → device → respond) tiles the request
         # wall time with no gaps (the acceptance check tools/tracedump.py
-        # and BENCH_TRACE report as coverage).
+        # reports as coverage).
         adm = (ctx.span.child("admission", start=ctx.span.t0)
                if ctx is not None else None)
         cm = self._servable(name)
@@ -2521,8 +2520,8 @@ class Server:
             if gen.rounds_to_first_token is not None:
                 # Device round-trips before the first token (admission
                 # prefills + decode segments): lets a client separate queue
-                # effects from device time in its TTFT (benchmark.py
-                # generate_path reports the medians).
+                # effects from device time in its TTFT (the benchmark's
+                # ``rounds_to_first_token``: benchmark/readers/client.py).
                 # The *_ms keys tile the time to the first token by the
                 # server's own stamps (GenRequest.timing_stats).
                 out["stats"] = {

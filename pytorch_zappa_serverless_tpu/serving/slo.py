@@ -38,8 +38,8 @@ error-budget plane:
 Surfaces: ``GET /admin/slo`` (replica and router), burn state on both
 healthz bodies, ``tpuserve slo`` CLI table, and the manifest-pinned
 ``tpuserve_slo_*`` / ``tpuserve_usage_*`` Prometheus families
-(serving/metrics.py).  ``tools/replay.py`` + the ``BENCH_REPLAY=1`` bench
-section replay production-shaped traces against this plane.
+(serving/metrics.py).  ``tools/replay.py`` replays production-shaped
+traces against this plane.
 docs/OBSERVABILITY.md §6-§8 is the operator story.
 """
 

@@ -3,11 +3,11 @@
 The JSON+b64 lane pays three host costs per request that have nothing to do
 with inference: a JSON parse over a body that is ~99% base64 text, the b64
 decode itself (a 33% size tax paid twice), and — for PIL lanes — an image
-decode.  BENCH_SERVERPATH prices exactly those stages; this module removes
-them.  A tensor frame carries a compact dtype+shape header plus raw
-row-major bytes, and :func:`unpack` hands the server ``np.frombuffer`` views
-over the request body — no base64, no JSON parse, no per-instance copy
-(docs/SERVERPATH.md is the wire spec; ISSUE 16).
+decode.  The perf plane's ingest stages (``GET /admin/perf``) price exactly
+those; this module removes them.  A tensor frame carries a compact
+dtype+shape header plus raw row-major bytes, and :func:`unpack` hands the
+server ``np.frombuffer`` views over the request body — no base64, no JSON
+parse, no per-instance copy (docs/SERVERPATH.md is the wire spec; ISSUE 16).
 
 Frame layout (all integers little-endian)::
 

@@ -1,6 +1,6 @@
 """The device this process runs on: what JAX reports, the published peaks of
-the chips the repo knows, and the start-up check that keeps a serving or
-bench entry point from silently landing on the CPU.
+the chips the repo knows, and the start-up check that keeps a serving entry
+point from silently landing on the CPU.
 
 A host where libtpu fails to initialise still gives JAX a CPU backend; the
 Pallas entry points would then pick interpret mode and ``/healthz`` would
@@ -20,19 +20,6 @@ CHIP_PEAKS: dict[str, tuple[float, float]] = {
     "TPU v5": (459e12, 2765e9),
     "TPU v6 lite": (918e12, 1640e9),
 }
-
-
-def chip_peaks(device_kind: str) -> tuple[float, float]:
-    """(peak FLOP/s, peak HBM bytes/s) for a reported ``device_kind``; an
-    unknown kind is an error — a utilization against a guessed peak is not a
-    measurement."""
-    try:
-        return CHIP_PEAKS[device_kind]
-    except KeyError:
-        raise KeyError(
-            f"no published peaks for device kind {device_kind!r}; add it to "
-            f"utils/device.py CHIP_PEAKS with its source "
-            f"(known: {sorted(CHIP_PEAKS)})") from None
 
 
 def device_info() -> dict:
@@ -60,7 +47,7 @@ def device_memory() -> list[dict]:
 
 
 def require_tpu(platform: str | None, what: str) -> dict:
-    """Start-up gate for entry points that serve or measure: returns
+    """Start-up gate for entry points that serve: returns
     :func:`device_info`, or raises ``SystemExit`` naming ``--platform cpu``
     when the backend JAX selected is not a TPU and the CPU was not asked for.
     """
@@ -70,6 +57,5 @@ def require_tpu(platform: str | None, what: str) -> dict:
             f"{what} needs a TPU: JAX selected the {info['platform']!r} "
             f"backend ({info['kind']} x{info['count']}) — libtpu failed to "
             f"initialise or there is no chip here.  To serve from the host "
-            f"CPU on purpose pass --platform cpu (serve, warm, fleet); the "
-            f"bench has no CPU mode.")
+            f"CPU on purpose pass --platform cpu (serve, warm, fleet).")
     return info

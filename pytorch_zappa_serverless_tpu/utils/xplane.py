@@ -1,12 +1,11 @@
 """Xplane (profiler capture) op-time aggregation — THE single classifier.
 
-Both the benchmark's ``device_trace_ms`` column and ``tools/trace_ops.py``
-read device op times from ``.xplane.pb`` captures; the classification rules
-(which plane, which line, what counts as overlapped-async vs synchronous
-compute) are metric-load-bearing and must not drift between the two — a
-divergent copy once double-booked an SD-1.5 step at 862 ms against a 444 ms
-wall (async in-flight windows overlap compute; summing them with it is
-wrong).
+``POST /admin/profile`` and ``tools/trace_ops.py`` both read device op
+times from ``.xplane.pb`` captures; the classification rules (which plane,
+which line, what counts as overlapped-async vs synchronous compute) are
+metric-load-bearing and must not drift between the two — a divergent copy
+once double-booked an SD-1.5 step at 862 ms against a 444 ms wall (async
+in-flight windows overlap compute; summing them with it is wrong).
 
 Rules:
 - TPU planes: the ``XLA Ops`` line is synchronous compute; ``Async XLA
@@ -98,13 +97,6 @@ def op_time_breakdown(trace_dir, capture=None):
                     compute[fam] += ev.duration_ns
                     counts[fam] += 1
     return compute, counts, overlap, envelope
-
-
-def device_compute_ms(trace_dir, iters: int) -> float | None:
-    """Per-iteration synchronous device compute, or None on an empty capture."""
-    compute, _, _, _ = op_time_breakdown(trace_dir)
-    total = sum(compute.values())
-    return round(total / iters / 1e6, 3) if total else None
 
 
 # -- host and device together (POST /admin/profile) ----------------------------

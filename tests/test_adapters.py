@@ -659,7 +659,7 @@ async def test_adapter_metrics_families_and_manifest(aiohttp_client,
 
 
 # ---------------------------------------------------------------------------
-# CLI + bench wiring
+# CLI
 # ---------------------------------------------------------------------------
 
 def test_adapters_cli_table():
@@ -683,24 +683,3 @@ def test_adapters_cli_table():
                for l in lines)
     assert any("tenant-b" in l and "cold" in l for l in lines)
     assert ">1 adapter: 3" in lines[-1]
-
-
-def test_bench_adapters_section_wiring(monkeypatch):
-    from pytorch_zappa_serverless_tpu import benchmark as B
-
-    monkeypatch.setattr(B, "bench_adapters", lambda: {"stub": True})
-    assert B.run_section("adapters") == {"stub": True}
-
-
-def test_bench_adapters_tiny_smoke(monkeypatch):
-    """BENCH_ADAPTERS=1's section in its CPU smoke shape: the attach
-    ladder, the co-batch overhead pair, and the scale-to-zero cold hit."""
-    monkeypatch.setenv("BENCH_ADAPTERS_TINY", "1")
-    from pytorch_zappa_serverless_tpu.benchmark import bench_adapters
-
-    out = bench_adapters(n_requests=4)
-    for key in ("attach_p50_ms", "attach_p99_ms", "base_predict_p50_ms",
-                "mixed_adapter_predict_p50_ms",
-                "scale_to_zero_cold_hit_p50_ms"):
-        assert out[key] is not None and out[key] > 0, (key, out)
-    assert out["multi_adapter_batches"] >= 0

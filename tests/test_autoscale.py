@@ -9,7 +9,7 @@ integration, and the fleet-sizing core.  HTTP half: the real serving
 stack — /admin/autoscale, the ``tpuserve autoscale`` table, prometheus
 families, and the tier-1 chaos bar (phantom predictions must converge
 back to reactive with zero acked loss and no activation stampede).
-The ``BENCH_AUTOSCALE_TINY`` policy-sweep smoke is at the bottom.
+``tools/replay.py``'s ``policy_sweep`` over live servers is at the bottom.
 """
 
 import asyncio
@@ -26,6 +26,7 @@ from pytorch_zappa_serverless_tpu.faults import FaultInjector
 from pytorch_zappa_serverless_tpu.serving.autoscale import (
     AutoscalePlane, DemandModel, SingleFlight, desired_replicas,
     fleet_wait_ms)
+from tools import replay
 
 pytest_plugins = "aiohttp.pytest_plugin"
 
@@ -582,55 +583,52 @@ async def test_http_surface_chaos_and_cli(aiohttp_client, cache_dir):
     assert "KEEPWARM_S" in table
 
 
-# -- bench: the policy-sweep smoke (BENCH_AUTOSCALE_TINY) ---------------------
+# -- tools/replay.py policy_sweep ----------------------------------------------
 
-def test_bench_autoscale_section_wiring(monkeypatch):
-    import pytorch_zappa_serverless_tpu.benchmark as B
+@pytest.fixture(scope="module")
+def sweep():
+    """One bursty trace (6 s, 10 /s, seed 7) against the two ends of the
+    policy ladder, fixed idle timers and the predictive plane, at equal
+    ``hbm_budget_bytes``."""
+    return replay.policy_sweep(duration_s=6.0, rps=10.0, seed=7,
+                               policies=("fixed", "predictive"))
 
-    monkeypatch.setattr(B, "bench_autoscale", lambda: {"stub": True})
-    assert B.run_section("autoscale") == {"stub": True}
 
-
-def test_bench_autoscale_tiny_policy_sweep(monkeypatch):
-    """BENCH_AUTOSCALE_TINY acceptance (tier-1): one bursty trace replayed
-    against fixed vs histogram vs predictive at equal hbm_budget_bytes —
-    the fixed-timer baseline pays cold hits the predictive policy avoids,
-    and the verdict is embedded in the artifact."""
-    from pytorch_zappa_serverless_tpu.benchmark import bench_autoscale
-
-    monkeypatch.setenv("BENCH_AUTOSCALE_TINY", "1")
-    monkeypatch.setenv("BENCH_AUTOSCALE_SEED", "7")
-    out = bench_autoscale()
-    pols = out["policies"]
-    assert set(pols) == {"fixed", "predictive"}  # tiny: the ladder's ends
-    for name, rep in pols.items():
-        assert rep["offered"] > 0, name
-        assert rep["served"] > rep["offered"] * 0.5, (name, rep)
-    fixed, pred = pols["fixed"], pols["predictive"]
-    # Equal budget; the only delta is the policy.
-    assert out["hbm_budget_bytes"] > 0
-    # The fixed timer demoted between bursts and ate cold starts...
+@pytest.mark.parametrize("store", [False, True],
+                         ids=["fixed-vs-predictive", "fixed-with-store"])
+def test_policy_sweep(sweep, store, tmp_path):
+    fixed = sweep["policies"]["fixed"]
+    # The fixed timer demoted between bursts and ate cold starts.
     assert fixed["demotions_idle"] >= 1
-    assert fixed["cold_hits"] >= 1 and fixed["cold_hit_rate"] > 0
-    # ...which the learned keep-warm window avoided.
-    assert pred["keepwarm_window_s"] is not None
-    assert pred["cold_hit_rate"] < fixed["cold_hit_rate"]
-    # The acceptance verdict is embedded, with both halves present.
-    v = out["verdict"]
-    assert v["cold_hit_rate"]["predictive_better"] is True
-    assert isinstance(v["predictive_beats_fixed"], bool)
-    assert {"fixed", "predictive"} <= set(v["latency_p99_ms"])
-    # Streaming checkpoint store (docs/LIFECYCLE.md): same trace, fixed
-    # timers, disk-tier demotions — the learned streamed-restore estimate
-    # undercuts the full-rebuild one, and that lower estimated_warm_ms
-    # makes mid-trace activations deadline-feasible, cutting cold hits.
-    assert out["store_estimated_warm_ms"] is not None
-    assert out["fixed_estimated_warm_ms"] is not None
-    assert out["store_estimated_warm_ms"] < out["fixed_estimated_warm_ms"]
-    assert out["store_cold_hit_rate"] <= out["fixed_cold_hit_rate"]
-    assert out["store_cuts_cold_hits"] is True
-    # Compact keys the driver line carries.
-    for key in ("cold_hit_rate", "latency_p99_ms", "goodput_rps",
-                "fixed_cold_hit_rate", "fixed_latency_p99_ms",
-                "store_cold_hit_rate", "store_estimated_warm_ms"):
-        assert key in out
+    assert fixed["cold_hits"] >= 1
+    assert fixed["tier_end"] == "none"
+    if not store:
+        assert set(sweep["policies"]) == {"fixed", "predictive"}
+        assert sweep["hbm_budget_bytes"] > 0  # equal for both: one config
+        pred = sweep["policies"]["predictive"]
+        for name, rep in sweep["policies"].items():
+            assert rep["offered"] == fixed["offered"] > 0, name  # one trace
+            assert rep["served"] > rep["offered"] * 0.5, (name, rep)
+        # The plane learned a keep-warm window over the gaps between bursts
+        # and held the model through them.
+        assert pred["keepwarm_window_s"] is not None
+        assert fixed["keepwarm_window_s"] is None
+        assert pred["cold_hits"] <= fixed["cold_hits"]
+        # The verdict is in the report, both halves of it.
+        v = sweep["verdict"]
+        assert isinstance(v["predictive_beats_fixed"], bool)
+        for key in ("cold_hit_rate", "latency_p99_ms"):
+            assert {"fixed", "predictive", "predictive_better"} <= set(v[key])
+        return
+    # Same trace and timers with the streaming checkpoint store on
+    # (docs/LIFECYCLE.md): idle demotions land in the disk tier, and what
+    # the plane has learned a re-activation from there costs undercuts what
+    # it has learned a full rebuild costs.
+    with_store = replay.policy_sweep(
+        duration_s=6.0, rps=10.0, seed=7, policies=("fixed",),
+        ckpt_store_dir=str(tmp_path / "ckpt"))
+    assert with_store["ckpt_store"] is True
+    stored = with_store["policies"]["fixed"]
+    assert stored["demotions_idle"] >= 1
+    assert stored["tier_end"] == "disk"
+    assert stored["estimated_warm_ms"] < fixed["estimated_warm_ms"]

@@ -3,7 +3,7 @@
 The cache is the cold-start killer (and the thing the lifecycle manager's
 warm-activation estimate leans on), yet until this file nothing tier-1
 asserted its contract: idempotent setup, live reconfiguration to a new
-directory (the lifecycle bench switches dirs per cold trial), and an actual
+directory (a measurement switches dirs per cold trial), and an actual
 warm-vs-cold ``build_engine`` wall-time win on the CPU harness.
 """
 
@@ -34,7 +34,7 @@ def test_setup_compile_cache_idempotent(tmp_path):
 def test_setup_compile_cache_reconfigures_to_new_dir(tmp_path):
     a, b = tmp_path / "a", tmp_path / "b"
     setup_compile_cache(a)
-    # Live re-point (the lifecycle bench's fresh-dir-per-cold-trial path).
+    # Live re-point (a fresh directory for each cold trial).
     assert setup_compile_cache(b) == str(b)
     assert jax.config.jax_compilation_cache_dir == str(b)
     assert b.is_dir()

@@ -424,7 +424,7 @@ async def test_burst_admissions_coalesce_into_one_prefill(engine):
             assert got == want[: len(got)] and got
         # One admission round + one segment round to the first token —
         # pinned so a regression to per-request admission (2+N rounds)
-        # fails here, not in the bench artifact.
+        # fails here, not in a chip run.
         assert [r.rounds_to_first_token for r in reqs] == [2, 2]
     finally:
         await sched.stop()

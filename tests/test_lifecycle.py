@@ -5,8 +5,8 @@ single-flight activation, deadline-aware cold admission, idle scale-to-zero
 through the warm tiers, LRU-under-budget eviction, PIN semantics, busy
 protection, activation chaos.  HTTP half: the real serving stack with a lazy
 ResNet-18 — cold 503 fast-fail, unload/reactivate with zero acknowledged
-loss, the /admin/models surface, the residency metrics, the ``tpuserve
-models`` CLI, and the ``BENCH_LIFECYCLE=1`` bench section.
+loss, the /admin/models surface with each tier's activation record, the
+residency metrics, and the ``tpuserve models`` CLI.
 """
 
 import asyncio
@@ -24,7 +24,7 @@ from pytorch_zappa_serverless_tpu.engine.cache import CompileClock
 from pytorch_zappa_serverless_tpu.faults import FaultInjector, TransientFault
 from pytorch_zappa_serverless_tpu.serving.lifecycle import (
     ACTIVE, COLD, ColdStart, LifecycleManager)
-from pytorch_zappa_serverless_tpu.serving.server import create_app
+from pytorch_zappa_serverless_tpu.serving.server import Server, create_app
 
 pytest_plugins = "aiohttp.pytest_plugin"
 
@@ -507,6 +507,53 @@ async def test_unload_reactivate_zero_acked_loss(aiohttp_client, cache_dir):
     assert (await r.json())["model"]["state"] == "active"
 
 
+async def test_activation_from_each_tier_leaves_its_record(aiohttp_client,
+                                                           cache_dir):
+    """The ladder through the admin API on the real stack: an activation from
+    the compiled-cache-only tier and one from the host tier each leave their
+    ``load_ms``/``compile_ms`` split on the model's row and their wall time in
+    the history ``estimate_warm_ms`` reads; activating a model that is on the
+    device already is no activation, and the restored model serves the bytes
+    it served before."""
+    srv = Server(_http_cfg(cache_dir))
+    client = await aiohttp_client(srv.app)
+
+    async def action(act):
+        r = await client.post("/admin/models/resnet18", json={"action": act})
+        body = await r.json()
+        assert r.status == 200, (act, body)
+        return body["model"]
+
+    async def predict():
+        r = await client.post("/v1/models/resnet18:predict", data=_jpeg(),
+                              headers=_IMG_HEADERS)
+        assert r.status == 200, await r.text()
+        return (await r.json())["predictions"]
+
+    m = await action("activate")                    # none -> device
+    assert (m["state"], m["tier"], m["activations"]) == ("active", "device", 1)
+    built = m["last_activation_phases"]
+    assert built["tier"] == "none"
+    assert built["load_ms"] > 0 and built["compile_ms"] > 0
+    before = await predict()
+
+    again = await action("activate")                # device: nothing to do
+    assert again["activations"] == 1
+    assert again["last_activation_phases"] == built
+    m = await action("demote")                      # device -> host
+    assert (m["state"], m["tier"], m["hbm_bytes"]) == ("cold", "host", 0)
+    m = await action("activate")                    # host -> device
+    assert (m["state"], m["tier"], m["activations"]) == ("active", "device", 2)
+    restored = m["last_activation_phases"]
+    assert restored["tier"] == "host"
+    assert restored["load_ms"] > 0 and restored["compile_ms"] == 0.0
+    assert await predict() == before
+
+    history = srv.lifecycle.residency("resnet18").history
+    assert {tier: len(ms) for tier, ms in history.items()} \
+        == {"none": 1, "host": 1}
+
+
 async def test_pin_blocks_unload_and_budget(aiohttp_client, cache_dir):
     client = await aiohttp_client(create_app(_http_cfg(cache_dir)))
     r = await client.post("/admin/models/resnet18", json={"action": "pin"})
@@ -630,32 +677,3 @@ def test_models_cli_table(monkeypatch, capsys):
     assert "resnet18" in out and "MODEL" in out
     assert cli.main(["models", "--json"]) == 0
     assert json.loads(capsys.readouterr().out)["hbm_bytes_total"] == 1048576
-
-
-# -- bench --------------------------------------------------------------------
-
-def test_bench_lifecycle_section_wiring(monkeypatch):
-    from pytorch_zappa_serverless_tpu import benchmark as B
-
-    monkeypatch.setattr(B, "bench_lifecycle", lambda: {"stub": True})
-    assert B.run_section("lifecycle") == {"stub": True}
-
-
-def test_bench_lifecycle_emits_activation_ladder():
-    """BENCH_LIFECYCLE=1's section: cold / warm-cache / resident activation
-    p50+p99 plus the steady-vs-eager comparison under a generous budget."""
-    from pytorch_zappa_serverless_tpu.benchmark import bench_lifecycle
-
-    out = bench_lifecycle(trials=1, steady_requests=4)
-    for key in ("cold_activation_p50_ms", "cold_activation_p99_ms",
-                "warm_cache_activation_p50_ms",
-                "warm_cache_activation_p99_ms",
-                "resident_activation_p50_ms", "resident_activation_p99_ms",
-                "steady_p50_ms", "steady_p99_ms", "steady_eager_p50_ms"):
-        assert out[key] is not None and out[key] > 0, (key, out)
-    # The tier ladder's one robust ordering: a host-weights restore never
-    # costs as much as a cold build + real XLA compile.
-    assert out["resident_activation_p50_ms"] < out["cold_activation_p50_ms"]
-    # Steady-state serve-path latency is the same code path warm; allow wide
-    # CPU-harness noise but catch a structural regression.
-    assert out["steady_p50_ms"] < out["steady_eager_p50_ms"] * 3 + 50.0

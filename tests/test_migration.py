@@ -580,24 +580,3 @@ def test_cli_disagg_flags_exist():
         ["fleet", "--replicas", "http://a,http://b", "--disagg",
          "--prefill-replicas", "http://a"])
     assert args.disagg and args.prefill_replicas == "http://a"
-
-
-def test_bench_disagg_section_wiring(monkeypatch):
-    from pytorch_zappa_serverless_tpu import benchmark as B
-
-    monkeypatch.setattr(B, "bench_disagg", lambda: {"stub": True})
-    assert B.run_section("disagg") == {"stub": True}
-
-
-@pytest.mark.slow
-def test_bench_disagg_smoke(monkeypatch):
-    """BENCH_DISAGG acceptance: migrated output byte-identical, forced
-    migration/failover costs measured, dedup observed."""
-    from pytorch_zappa_serverless_tpu.benchmark import bench_disagg
-
-    monkeypatch.setenv("BENCH_DISAGG_TINY", "1")
-    out = bench_disagg()
-    assert out["migrated_parity_byte_identical"]
-    assert out["migration_added_ms"] >= 0.0
-    assert out["failover_recovery_ms"] > 0.0
-    assert out["pages_copied"] >= 1

@@ -1,19 +1,15 @@
-"""Perf plane (ISSUE 14): continuous profiling, ingest attribution, benchdiff.
+"""Perf plane (ISSUE 14): continuous profiling and ingest attribution.
 
-Four layers:
+Two layers:
 
 - Unit: the loop-lag sampler with an injectable clock (deterministic lag
   detection), the stack sampler's top-K bounding/eviction with injected
-  frames, the ingest histogram registry, and the rolling gauge windows.
-- Sentinel: tools/benchdiff.py verdicts (pass / regress / improved /
-  missing) over tiny fixture JSONs, the --check self-test, and a
-  driver-round pair against the checked-in tools/perf_budget.json.
+  frames, the ingest histogram registry, the rolling gauge windows, and the
+  peaks table a live MFU gauge is read against.
 - Integration: a real booted CPU server — GET /admin/perf carries loop
-  lag, ingest stages for a served request, and the split ttft/itl
-  histograms ride gen_snapshot + /metrics; the `tpuserve perf` table
-  renders the payload.
-- Bench: the BENCH_SERVERPATH_TINY smoke (stage table tiles >= 95% of the
-  measured http→device gap) and the section's run_flagship_bench wiring.
+  lag, ingest stages for a served request (which tile >= 95% of its trace
+  beside the stage chain), and the split ttft/itl histograms ride
+  gen_snapshot + /metrics; the `tpuserve perf` table renders the payload.
 """
 
 import asyncio
@@ -172,167 +168,35 @@ def test_hist_quantile_interpolates():
     assert 1.0 < hist_quantile(snap, 0.5) <= 2.0
 
 
-# -- sentinel: tools/benchdiff.py -------------------------------------------
+def test_peaks_table_is_keyed_by_reported_kind_and_unknown_is_an_error(
+        monkeypatch):
+    """A utilization against a guessed peak is not a measurement: a kind the
+    table does not know gets no MFU gauge, and no exception either."""
+    from pytorch_zappa_serverless_tpu.serving import perfplane
+    from pytorch_zappa_serverless_tpu.utils.device import CHIP_PEAKS
 
-def _benchdiff():
-    import importlib.util
+    assert CHIP_PEAKS["TPU v5 lite"] == (197e12, 819e9)   # what a v5e reports
+    assert "TPU v9 imaginary" not in CHIP_PEAKS
 
-    spec = importlib.util.spec_from_file_location(
-        "tpuserve_benchdiff", REPO / "tools" / "benchdiff.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    def gauges(kind):
+        monkeypatch.setattr(perfplane, "device_info", lambda: {
+            "platform": "tpu", "kind": kind, "count": 1})
+        perf = PerfPlane(ServeConfig(perf_window_s=30.0))
+        stats = {"m": SimpleNamespace(samples=0, batches=0,
+                                      device_seconds=0.0)}
+        perf.runner_stats = lambda: stats
+        perf.flops_hint = lambda m: 1.97e12
+        perf.observe_models(now=0.0)
+        stats["m"] = SimpleNamespace(samples=100, batches=100,
+                                     device_seconds=2.0)
+        perf.observe_models(now=10.0)
+        return perf.model_gauges()["m"]
 
-
-def test_benchdiff_verdicts_over_fixtures():
-    bd = _benchdiff()
-    budget = {"defaults": {"regress_pct": {"lower_better": 50.0,
-                                           "higher_better": 30.0}},
-              "keys": {"p50_ms": {"direction": "lower_better",
-                                  "regress_pct": 25.0},
-                       "tokens_per_s": {"required": True}}}
-    old = {"p50_ms": 10.0, "tokens_per_s": 1000.0, "mfu_pct": 40.0,
-           "nested": {"queue_ms": 5.0}}
-    new = {"p50_ms": 14.0, "mfu_pct": 41.0, "nested": {"queue_ms": 2.0},
-           "fresh_key_ms": 1.0}
-    rows = {r["key"]: r for r in bd.diff(old, new, budget)}
-    assert rows["p50_ms"]["verdict"] == "regress"        # +40% > 25%
-    assert rows["p50_ms"]["delta_pct"] == pytest.approx(40.0)
-    # required key vanished -> violation, not a shrug
-    assert rows["tokens_per_s"]["verdict"] == "regress"
-    assert rows["mfu_pct"]["verdict"] == "pass"
-    assert rows["nested.queue_ms"]["verdict"] == "improved"
-    assert rows["fresh_key_ms"]["verdict"] == "new"
-    assert len(bd.violations(bd.diff(old, new, budget))) == 2
-    # Non-required missing keys report but do not fail.
-    budget2 = {"defaults": {"regress_pct": 50.0}, "keys": {}}
-    rows2 = {r["key"]: r for r in bd.diff({"a_ms": 1.0, "b_ms": 2.0},
-                                          {"a_ms": 1.0}, budget2)}
-    assert rows2["b_ms"]["verdict"] == "missing"
-    assert not bd.violations(list(rows2.values()))
-
-
-def test_benchdiff_exit_codes_and_table(capsys, tmp_path):
-    bd = _benchdiff()
-    old = tmp_path / "old.json"
-    bad = tmp_path / "bad.json"
-    old.write_text(json.dumps(bd._FIXTURE_OLD))
-    bad.write_text(json.dumps(bd._FIXTURE_BAD))
-    # A fixture round that violates the CHECKED-IN budget exits nonzero
-    # (acceptance criterion) and names the regressed keys in the table.
-    assert bd.main([str(old), str(bad)]) == 1
-    out = capsys.readouterr().out
-    assert "regress" in out and "value" in out and "summary:" in out
-    ok = tmp_path / "ok.json"
-    ok.write_text(json.dumps(bd._FIXTURE_OK))
-    assert bd.main([str(old), str(ok)]) == 0
-
-
-def test_benchdiff_json_mode(capsys, tmp_path):
-    bd = _benchdiff()
-    old = tmp_path / "old.json"
-    bad = tmp_path / "bad.json"
-    old.write_text(json.dumps(bd._FIXTURE_OLD))
-    bad.write_text(json.dumps(bd._FIXTURE_BAD))
-    assert bd.main([str(old), str(bad), "--json"]) == 1
-    payload = json.loads(capsys.readouterr().out)
-    assert payload["violations"] >= 1
-    assert any(r["verdict"] == "regress" for r in payload["rows"])
-
-
-def test_benchdiff_check_mode_self_tests(capsys):
-    bd = _benchdiff()
-    assert bd.main(["--check"]) == 0
-    assert "sentinel bites" in capsys.readouterr().out
-    # The literal CI command works as a module (tier-1 wiring, no device).
-    import subprocess
-    import sys
-
-    proc = subprocess.run([sys.executable, "-m", "tools.benchdiff",
-                           "--check"], cwd=REPO, capture_output=True,
-                          text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr
-    # A budget that cannot bite fails --check: the self-test guards the
-    # guard (a 1e9% threshold passes everything).
-    lax = {"defaults": {"regress_pct": {"lower_better": 1e9,
-                                        "higher_better": 1e9}}, "keys": {}}
-    assert bd.self_check(lax)
-
-
-def _driver_round(p50_ms: float, rps: float) -> dict:
-    """A driver round envelope (``{"parsed": <compact bench line>}``)."""
-    return {"rc": 0, "parsed": {
-        "metric": "resnet50_b8_p50_latency", "value": p50_ms, "unit": "ms",
-        "vs_baseline": round(30.0 / p50_ms, 3),
-        "extra": {"req_s_chip": round(8000.0 / p50_ms, 1),
-                  "device_trace_ms": 0.773,
-                  "server_path": {"achieved_rps": rps,
-                                  "http_device_p50_ms": 120.0}}}}
-
-
-@pytest.mark.parametrize("new_p50,new_rps,flagged", [
-    (1.88, 52.3, []),                      # +59% / -10%: inside the budget
-    (2.60, 30.0, ["extra.req_s_chip", "extra.server_path.achieved_rps",
-                  "value", "vs_baseline"]),
-], ids=["inside-budget-passes", "outside-budget-flagged"])
-def test_benchdiff_round_pair_against_checked_in_budget(
-        tmp_path, new_p50, new_rps, flagged):
-    """The checked-in budget over two driver-round files: a pair inside it
-    passes, a pair outside it names the keys that broke it."""
-    bd = _benchdiff()
-    old, new = tmp_path / "old.json", tmp_path / "new.json"
-    old.write_text(json.dumps(_driver_round(1.18, 58.2)))
-    new.write_text(json.dumps(_driver_round(new_p50, new_rps)))
-    rows = bd.diff(bd.load_round(old), bd.load_round(new), bd.load_budget())
-    assert rows, "no comparable keys between the rounds"
-    assert sorted(r["key"] for r in bd.violations(rows)) == flagged, \
-        bd.render(rows)
-
-
-# -- bench: no fallback that hides the device ---------------------------------
-
-def _full(section_entry: dict) -> dict:
-    return {"metric": "resnet50_b8_p50_latency", "value": 1.2, "unit": "ms",
-            "vs_baseline": 25.0,
-            "extra": {"req_s_chip": 6666.7, "configs": {"gpt2": section_entry},
-                      "cold_start": None, "server_path": {"achieved_rps": 50.0},
-                      "generate_path": None, "mixed_path": None}}
-
-
-@pytest.mark.parametrize("entry,rc", [
-    ({"p50_ms": 11.0, "tokens_per_s": 15000.0}, 0),
-    ({"error": "RuntimeError: boom"}, 1),
-], ids=["healthy-exits-0", "failed-section-exits-1"])
-def test_bench_main_exit_code_follows_section_errors(monkeypatch, tmp_path,
-                                                     capsys, entry, rc):
-    """A section that errors still gets its line printed — and then the run
-    exits non-zero instead of passing for a result."""
-    import pytorch_zappa_serverless_tpu.benchmark as B
-
-    monkeypatch.setattr(B, "run_flagship_bench", lambda emit=None: _full(entry))
-    monkeypatch.setenv("BENCH_FULL_PATH", str(tmp_path / "full.json"))
-    assert B.main() == rc
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["value"] == 1.2 and "gpt2" in line["extra"]["configs"]
-    assert B.section_errors(_full(entry)) == (["gpt2"] if rc else [])
-
-
-def test_bench_refuses_a_non_tpu_backend():
-    """bench.py off-TPU ends at its device probe (a child: the parent stays
-    off JAX), before any section runs."""
-    import pytorch_zappa_serverless_tpu.benchmark as B
-
-    with pytest.raises(SystemExit) as e:
-        B._probe_device()
-    assert "needs a TPU" in str(e.value.code)
-
-
-def test_peaks_table_is_keyed_by_reported_kind_and_unknown_is_an_error():
-    from pytorch_zappa_serverless_tpu.utils.device import chip_peaks
-
-    assert chip_peaks("TPU v5 lite") == (197e12, 819e9)   # what a v5e reports
-    with pytest.raises(KeyError, match="no published peaks"):
-        chip_peaks("TPU v9 imaginary")
+    # 10 samples/s * 1.97 TF = 19.7 TF/s against the v5e's 197 TF = 10%.
+    assert gauges("TPU v5 lite")["mfu_pct"] == pytest.approx(10.0)
+    unknown = gauges("TPU v9 imaginary")
+    assert unknown["samples_per_s"] == pytest.approx(10.0)
+    assert "mfu_pct" not in unknown
 
 
 # -- integration: a real booted server ---------------------------------------
@@ -503,46 +367,3 @@ async def test_ttft_and_itl_split_histograms(aiohttp_client, tmp_path):
         assert "ttft_p50_ms" in perf["models"]["gpt2:generate"]
     finally:
         engine.shutdown()
-
-
-# -- bench: section wiring + tiny smoke --------------------------------------
-
-def test_bench_serverpath_section_wiring(monkeypatch):
-    import pytorch_zappa_serverless_tpu.benchmark as B
-
-    monkeypatch.setenv("BENCH_SERVERPATH", "1")
-    monkeypatch.setattr(B, "bench_serverpath", lambda: {"stub": True})
-    assert B.run_section("serverpath") == {"stub": True}
-    assert "serverpath" in B._COMPACT_KEYS
-
-
-def test_bench_serverpath_tiny_smoke(monkeypatch, tmp_path):
-    """BENCH_SERVERPATH_TINY acceptance (tier-1): the stage table tiles
-    >= 95% of the measured http→device gap on a real CPU-served load, the
-    substage table prices the JSON lane, and the on-vs-off overhead pair
-    reports."""
-    from pytorch_zappa_serverless_tpu.benchmark import bench_serverpath
-
-    monkeypatch.setenv("BENCH_SERVERPATH_TINY", "1")
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
-    out = bench_serverpath()
-    assert out["tiny"] is True
-    assert out["n_traces"] >= 1
-    assert out["gap_coverage_p50_pct"] >= 95.0, out
-    assert out["coverage_p50_pct"] >= 95.0
-    for stage in ("payload_read", "json_decode", "b64_decode", "validate",
-                  "serialize"):
-        assert stage in out["substage_p50_ms"], out
-    assert {"admission", "queue", "device", "respond"} \
-        <= set(out["stage_p50_ms"])
-    assert "overhead_pct" in out and out["perfplane_off_p50_ms"] > 0
-    assert "ingest_p50_ms" in out and "batch_form" in out["ingest_p50_ms"]
-    # Fast-lane telemetry phase (ISSUE 19): the ring-served requests hold
-    # the same >= 95% coverage bar with the worker substages priced, and
-    # the on-vs-off pair bounds the telemetry overhead.
-    assert out["fast_lane_gap_coverage_p50_pct"] >= 95.0, out
-    for sub in ("sock_read", "frame_validate", "ring_wait",
-                "binary_decode"):
-        assert sub in out["fast_lane_substage_p50_ms"], out
-    assert out["fast_lane_rps_on"] > 0 and out["fast_lane_rps_off"] > 0
-    assert "fast_lane_overhead_pct" in out

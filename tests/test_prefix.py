@@ -17,9 +17,7 @@ Covers, on the CPU backend with a tiny arch:
 - pool pressure: decayed prefix pages yield before any live stream is
   evicted;
 - HTTP surface: /admin/prefix, per-stream stats evidence, the
-  tpuserve_prefix_* families + manifest, the CLI table;
-- BENCH_PREFIX smoke (warm ttft strictly below cold, >=1 hit, ledger
-  within budget under forced LRU decay).
+  tpuserve_prefix_* families + manifest, the CLI table.
 """
 
 import asyncio
@@ -304,7 +302,7 @@ async def test_warm_prefix_parity_greedy_and_sampled(engine):
         assert snap["pages"] >= 2
         # Warm TTFT in device rounds: one small chunk instead of the full
         # prompt — device work strictly shrinks (wall clocks are too noisy
-        # for tier-1; the bench section measures them).
+        # for tier-1).
         assert snap["cached_tokens"]["count"] == 2
     finally:
         await sched.stop()
@@ -625,27 +623,3 @@ def test_cli_prefix_table_renders():
                                 "RECLAIMABLE", "SHARED_NOW"]
     assert lines[1].split() == ["gpt2", "3", "7", "5", "2", "0.714", "1",
                                 "2", "6", "3"]
-
-
-def test_bench_prefix_section_wiring(monkeypatch):
-    from pytorch_zappa_serverless_tpu import benchmark as B
-
-    monkeypatch.setattr(B, "bench_prefix", lambda: {"stub": True})
-    assert B.run_section("prefix") == {"stub": True}
-
-
-@pytest.mark.slow
-def test_bench_prefix_smoke(monkeypatch):
-    """BENCH_PREFIX acceptance: warm ttft strictly below cold with >=1 hit,
-    CoW + forced LRU decay observed, kv ledger within hbm_budget_bytes."""
-    from pytorch_zappa_serverless_tpu.benchmark import bench_prefix
-
-    monkeypatch.setenv("BENCH_PREFIX_TINY", "1")
-    monkeypatch.setenv("BENCH_PREFIX_REQS", "4")
-    out = bench_prefix()
-    assert out["warm_parity_byte_identical"]
-    assert out["hits"] >= 1
-    assert out["warm_ttft_p50_ms"] < out["cold_ttft_ms"]
-    assert out["cow_copies"] > 0
-    assert out["prefix_evictions"] > 0
-    assert out["kv_within_budget"] and out["kv_ledger_bytes"] > 0

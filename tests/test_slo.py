@@ -18,8 +18,8 @@ Four layers, all CPU-runnable:
   and shed responses under budget exhaustion still compute fleet-minimum
   Retry-After.
 
-tools/replay.py (trace shapes, the replayer, and the ``BENCH_REPLAY_TINY``
-smoke) is covered at the bottom.
+tools/replay.py (trace shapes, the replayer, and a replay against a live
+two-deploy server) is covered at the bottom.
 """
 
 import importlib.util
@@ -714,37 +714,52 @@ async def test_replay_async_is_open_loop():
     assert outcomes[0]["status"] == 599
 
 
-# -- bench section -------------------------------------------------------------
+# -- the replayer against a live server ----------------------------------------
 
-def test_bench_replay_section_wiring(monkeypatch):
-    from pytorch_zappa_serverless_tpu import benchmark as B
+async def test_replay_against_a_live_server_burns_the_cold_deploys_fast_window(
+        aiohttp_client, tmp_path):
+    """A bursty trace over two deploys of one builder, one built at boot and
+    one lazy, with deadlines under the activation estimate: the lazy deploy's
+    requests fast-fail 503 ``cold_start``, the replayer counts them as cold
+    hits, and the server's own /admin/slo tells the same story (sheds that
+    burned the fast window).  The replayer and the SLO plane agree."""
+    import asyncio
 
-    monkeypatch.setattr(B, "bench_replay", lambda: {"stub": True})
-    assert B.run_section("replay") == {"stub": True}
+    from pytorch_zappa_serverless_tpu.serving.server import create_app
 
+    rp = _load_tool("replay")
 
-def test_bench_replay_tiny_smoke(monkeypatch):
-    """BENCH_REPLAY_TINY acceptance (tier-1): a bursty trace replays
-    end-to-end against a live two-deploy server and reports SLO
-    attainment, goodput-vs-throughput, and a non-zero cold-hit rate, and
-    the server's own /admin/slo agrees a budget is burning."""
-    from pytorch_zappa_serverless_tpu.benchmark import bench_replay
+    def mk(name, lazy):
+        return ModelConfig(name=name, builder="resnet18", batch_buckets=(1, 4),
+                           dtype="float32", coalesce_ms=1.0, lazy_load=lazy,
+                           extra={"image_size": 48, "resize_to": 56})
 
-    monkeypatch.setenv("BENCH_REPLAY_TINY", "1")
-    monkeypatch.setenv("BENCH_REPLAY_DURATION_S", "3")
-    monkeypatch.setenv("BENCH_REPLAY_RPS", "8")
-    monkeypatch.setenv("BENCH_REPLAY_SEED", "7")
-    out = bench_replay()
-    assert out["shape"] == "bursty"
-    assert out["offered"] > 0
-    assert 0.0 <= out["slo_attainment"] <= 1.0
-    assert out["cold_hits"] >= 1, out  # the lazy deploy fast-failed cold
-    assert out["cold_hit_rate"] > 0.0
-    assert out["goodput_rps"] <= out["throughput_rps"] + 1e-9
-    assert out["goodput_vs_throughput"] is None \
-        or 0.0 <= out["goodput_vs_throughput"] <= 1.0
-    # The server's own SLO plane saw the same story: the cold deploy's
-    # sheds burned its fast window.
-    assert "rn_cold" in out["server_slo"]
-    assert out["server_slo"]["rn_cold"]["outcomes"]["shed"] >= 1
-    assert out["server_slo"]["rn_cold"]["fast_alarm"] is True
+    slo = {"latency_objective_ms": 1500.0, "availability_target": 0.99}
+    client = await aiohttp_client(create_app(ServeConfig(
+        compile_cache_dir=str(tmp_path / "xla"), warmup_at_boot=True,
+        # The cold deploy must fast-fail under the replay deadline, not
+        # absorb it into a blocked activation.
+        activation_estimate_ms=60000.0,
+        slo={"rn_hot": slo, "rn_cold": slo},
+        models=[mk("rn_hot", lazy=False), mk("rn_cold", lazy=True)])))
+    send = rp.http_sender(client.session, str(client.make_url("")), _png(),
+                          "image/png", deadline_ms=2000.0)
+    trace = rp.synth_trace("bursty", 3.0, 8.0, ["rn_hot", "rn_cold"], seed=7)
+    assert {t["model"] for t in trace} == {"rn_hot", "rn_cold"}
+    report = rp.summarize(await rp.replay_async(send, trace), 3.0,
+                          objective_ms=1500.0)
+    assert report["offered"] == len(trace)
+    assert report["cold_hits"] >= 1, report  # the lazy deploy fast-failed
+    assert report["goodput_rps"] <= report["throughput_rps"] + 1e-9
+
+    lanes = (await (await client.get("/admin/slo")).json())["models"]
+    cold = lanes["rn_cold"]["predict"]
+    assert cold["outcomes"]["shed"] >= 1
+    assert cold["windows"]["fast"]["alarm"] is True
+    # The fast-fails started rn_cold's activation in the background: let it
+    # land before the compile cache's directory goes.
+    for _ in range(600):
+        models = (await (await client.get("/admin/models")).json())["models"]
+        if models["rn_cold"]["state"] != "warming":
+            break
+        await asyncio.sleep(0.1)

@@ -110,8 +110,8 @@ async def test_metrics_surface_after_load(client):
 
 def test_cold_start_recorded_on_chip(tmp_path):
     """Engine boot on the chip records real compile timings (BASELINE
-    cold-start metric); the empty-vs-warm comparison is benchmark.py's
-    subprocess harness."""
+    cold-start metric); the empty-vs-warm comparison is
+    test_cache.py::test_warm_cache_build_is_faster_than_cold."""
     cfg = ServeConfig(compile_cache_dir=str(tmp_path / "xla"), models=[
         ModelConfig(name="resnet50", batch_buckets=(1,))])
     eng = build_engine(cfg, warmup=True)

@@ -18,8 +18,8 @@ per-thread held stack and record every (held -> acquired) pair:
 
 Wiring: the package honors the env knob at import (see
 ``pytorch_zappa_serverless_tpu/__init__``), the test conftest turns it on
-for the tier-1 suite, and ``bench.py``/``tools/crashtest.py`` set it for
-their subprocesses so chaos runs double as sanitizer runs.  With
+for the tier-1 suite, and ``tools/crashtest.py`` sets it for its
+subprocesses so chaos runs double as sanitizer runs.  With
 ``TPUSERVE_LOCKWATCH_OUT=<path>`` the process dumps a JSON report at exit
 (the crashtest reads it back and fails on violations).
 

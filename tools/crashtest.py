@@ -15,9 +15,8 @@ twice.  This script proves it end to end against the real CLI entrypoint:
 5. assert every acknowledged job id reaches ``done``, resubmits dedupe to
    the original ids, and the replay metrics moved.
 
-Usable three ways: CLI (``python tools/crashtest.py --workdir /tmp/ct``),
-the tier-1 pytest case (``tests/test_crash_recovery.py``), and the bench
-``recovery`` section hook (``benchmark.py``, ``BENCH_RECOVERY=1``).
+Usable two ways: CLI (``python tools/crashtest.py --workdir /tmp/ct``) and
+the tier-1 pytest case (``tests/test_crash_recovery.py``).
 """
 
 from __future__ import annotations
@@ -155,7 +154,7 @@ def run_crashtest(workdir: str | Path, n_jobs: int = 6,
     """Run the full kill-9 scenario; returns the evidence dict.
 
     Raises AssertionError on any acknowledged-job loss or double run —
-    callers (pytest / bench / CLI) treat a clean return as a pass.
+    callers (pytest / CLI) treat a clean return as a pass.
     """
     workdir = Path(workdir)
     workdir.mkdir(parents=True, exist_ok=True)

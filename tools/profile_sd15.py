@@ -1,9 +1,10 @@
 """SD-1.5 component-level on-chip profile (VERDICT r2 item 1).
 
 Decomposes the full txt2img step into CLIP encode, one UNet CFG step (b2),
-and VAE decode, each measured with the same pipelined-differencing method
-benchmark.py uses (see its module docstring), and each annotated with XLA's
-flops/bytes cost analysis so the roofline gap per component is visible.
+and VAE decode, each measured by pipelined differencing (``2K`` chained
+dispatches less ``K``, over ``K``: the fetch and the first dispatch's
+latency cancel), and each annotated with XLA's flops/bytes cost analysis so
+the roofline gap per component is visible.
 
 Usage:  python tools/profile_sd15.py [--steps 20]
 """

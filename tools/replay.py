@@ -32,9 +32,9 @@ Usage (CLI, against any running server/router)::
     python tools/replay.py --url http://localhost:8000 --model resnet18 \
         --shape bursty --duration 30 --rps 20
 
-Importable: ``synth_trace`` and ``replay_async`` are used by the
-``BENCH_REPLAY=1`` bench section and the tier-1 smoke
-(``BENCH_REPLAY_TINY``); ``summarize`` turns raw outcomes into the report.
+Importable: ``synth_trace`` and ``replay_async`` are used by the tier-1
+replay against a live server (``tests/test_slo.py``); ``summarize`` turns
+raw outcomes into the report.
 Traces are deterministic per seed so reruns are comparable.
 """
 
@@ -130,7 +130,7 @@ async def replay_async(send, trace: list[dict], speedup: float = 1.0,
 
     ``send(item) -> {"status": int, "latency_ms": float, "cold": bool,
     "degraded": bool, "retry_after_s": float | None}`` is the transport —
-    the CLI wraps aiohttp against a URL, the bench wraps a TestClient.
+    the CLI wraps aiohttp against a URL, a test wraps a TestClient.
     Arrivals are scheduled at ``t / speedup``; a request whose slot has
     already passed fires immediately (open-loop lag is part of the story,
     not hidden by back-pressure).
@@ -241,7 +241,7 @@ def retrying_sender(send, *, max_attempts: int = 12,
     return retry_send
 
 
-# -- policy sweep (docs/AUTOSCALE.md; the BENCH_AUTOSCALE section) ------------
+# -- policy sweep (docs/AUTOSCALE.md) -----------------------------------------
 
 POLICIES = ("fixed", "histogram", "predictive")
 

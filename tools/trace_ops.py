@@ -45,8 +45,8 @@ def analyze(trace_dir: Path, iters: int, top: int = 25):
     """Aggregate device-plane op durations from the xplane capture.
 
     Classification (sync compute vs overlapped-async windows, plane/line
-    scoping) lives in ``utils/xplane.py`` — shared with the benchmark's
-    ``device_trace_ms`` column so the two can't drift.
+    scoping) lives in ``utils/xplane.py`` — shared with ``POST
+    /admin/profile`` so the two can't drift.
     """
     from pytorch_zappa_serverless_tpu.utils.xplane import op_time_breakdown
 

@@ -10,8 +10,7 @@ inside it), from a file, stdin, or fetched live with ``--url``::
 
 Output: one row per span (indent = tree depth) with start offset, duration,
 status, and a proportional bar, then a stage-attribution summary over the
-root's direct children — the same per-stage numbers the ``BENCH_TRACE=1``
-bench section aggregates into p50/p99 (docs/OBSERVABILITY.md)::
+root's direct children (docs/OBSERVABILITY.md)::
 
     predict resnet18 trace 1f3c... (ok, 212.4 ms)
       0.0ms  +-  212.4ms  predict                [##############################]
@@ -19,7 +18,7 @@ bench section aggregates into p50/p99 (docs/OBSERVABILITY.md)::
       ...
 
 Importable: ``render(trace_dict)`` and ``stage_attribution(trace_dict)`` are
-used by the bench section and tier-1 tests.
+used by the tier-1 tests.
 """
 
 from __future__ import annotations
