@@ -62,6 +62,16 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
              through ``prefill_start`` into the pool and segments against the
              plain reference on the same device, by the logits and by the
              cell's own ``judge``; and the int8 control, which must fail.
+- *mellum*   Mellum 2 at the benchmark cell's widths (eight layers, two
+             kinds of K/V layer): ``decode_attention`` at a group of 8 of
+             128 over a ring and over full rows, ``flash_attention`` with
+             and without the band at the four buckets, the gated
+             ``expert_matmul`` at ``K`` 2304 and ``F`` 896, alone by the
+             profiler's clock; then the cell's 16 reference prompts (700 and
+             5,000 tokens) through ``prefill_start`` into both leaf pairs
+             and segments against the plain reference, by the logits and by
+             the cell's own ``judge``; and the three controls (int8, the
+             window layers read as full, no YaRN), each of which must fail.
 - *sd15*     Stable Diffusion 1.5 at 512x512, two steps, one ``:submit``
              polled to ``done`` (flash attention's only serving caller).
 
@@ -115,6 +125,10 @@ NEMOTRON_RMS_TOL = 0.05
 # difference is reported and not judged: it is one flipped expert's on either
 # side (0.905 and 1.056).
 LFM2_RMS_TOL = 0.105
+# Mellum 2's programs in bfloat16 against the float32 reference, in logits
+# of spread 1.0: the root mean square of all differences read 0.0137 sound
+# and 0.0400 under the int8 control (PERF.md section 6, PR 52).
+MELLUM_RMS_TOL = 0.025
 # --rehearse widths: d_model is one 128-lane tile, the int8 kernel's floor.
 TINY_GPT2 = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 256,
              "vocab_size": 512, "max_positions": 128}
@@ -1645,6 +1659,73 @@ def _nemotron_child(rehearse: bool) -> None:
     print(json.dumps(report))
 
 
+def _serve_prompts_alone(kernels, params, meta, buckets, prompts,
+                         new: int):
+    """Each of ``prompts`` prefilled alone into a slot of the pool (slot
+    ``j % S``) and decoded for ``new`` tokens in whole segments, the longest
+    for twice as many, by the programs ``build_gen_kernels`` jitted, as the
+    scheduler runs them → ``(runs, got)``: ``{"ids", "tokens"}`` a prompt and
+    the logits each served token was chosen from ``[tokens, V]``.
+    ``decoder.choose`` is watched, not replaced: it reports the logits it
+    was given."""
+    import jax
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.models import decoder
+
+    seen = []
+    choose = decoder.choose
+
+    def watched(logits, temperature, seeds, t, top_k=None, top_p=None):
+        jax.debug.callback(lambda lg: seen.append(np.asarray(lg)), logits)
+        return choose(logits, temperature, seeds, t, top_k, top_p)
+
+    decoder.choose = watched
+    S, seg = meta["slots"], meta["segment_tokens"]
+    long_one = max(range(len(prompts)), key=lambda j: len(prompts[j]))
+    cache = kernels["alloc_cache"]()
+    zf, zi = np.zeros(S, np.float32), np.zeros(S, np.int32)
+    runs, got = [], []
+    try:
+        for j, ids in enumerate(prompts):
+            bucket = next(b for b in buckets if b >= len(ids))
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :len(ids)] = ids
+            payload = {"input_ids": toks,
+                       "length": np.asarray([len(ids)], np.int32),
+                       "temperature": np.zeros(1, np.float32),
+                       "seed": np.zeros(1, np.int32),
+                       "top_k": np.zeros(1, np.int32),
+                       "top_p": np.ones(1, np.float32)}
+            seen.clear()
+            slot = j % S
+            first, *cache = kernels["prefill"](params, tuple(cache),
+                                               np.asarray([slot], np.int32),
+                                               payload)
+            tok, pos, fin = zi.copy(), zi.copy(), np.ones(S, bool)
+            tok[slot], pos[slot], fin[slot] = int(np.asarray(first)[0]), \
+                len(ids), False
+            st, emits = zi.copy(), []
+            steps = 2 * new if j == long_one else new  # four segments for one
+            for _ in range(steps // seg):
+                packed, *cache = kernels["segment"](params, tuple(cache), tok,
+                                                    pos, st, fin, zf, zi, zi,
+                                                    zf + 1)
+                packed = np.asarray(packed)
+                emits.append(packed[:, :seg])
+                tok, pos, st = (packed[:, seg + k].copy() for k in range(3))
+            jax.effects_barrier()
+            served = np.concatenate(emits, axis=1)[slot].tolist()
+            # The logits each served token was chosen from: the prefill's,
+            # then every step's but the last.
+            got.append(np.stack([seen[0][0]]
+                                + [lg[slot] for lg in seen[1:steps]]))
+            runs.append({"ids": ids, "tokens": served})
+    finally:
+        decoder.choose = choose
+    return runs, got
+
+
 def _busy_us(run) -> float:
     """Device microseconds of one of the ``_TIMED_CALLS`` calls a profiled
     ``run()`` chains: the union of every device interval, so a form that is
@@ -1953,7 +2034,7 @@ def _lfm2_child(rehearse: bool) -> None:
     del tree
     params, meta = sv.params, sv.meta["continuous"]
     kernels = build_gen_kernels(types.SimpleNamespace(servable=sv))
-    S, seg, V = meta["slots"], meta["segment_tokens"], cfg.vocab_size
+    V = cfg.vocab_size
     print("lfm2: cache leaves " + json.dumps(
         [[list(shape), str(np.dtype(dt))]
          for shape, dt in meta["cache_leaves"]])
@@ -1961,60 +2042,14 @@ def _lfm2_child(rehearse: bool) -> None:
         + json.dumps({b: meta["prompt_form"](1, b) for b in buckets}),
         flush=True)
 
-    seen = []
-    choose = decoder.choose
-
-    def watched(logits, temperature, seeds, t, top_k=None, top_p=None):
-        jax.debug.callback(lambda lg: seen.append(np.asarray(lg)), logits)
-        return choose(logits, temperature, seeds, t, top_k, top_p)
-
-    decoder.choose = watched
     # The cell's reference prompts, as benchmark/run.py draws them.
     rng = np.random.default_rng(config["weights"]["seed"] + 1)
     prompts = [traffic.token_ids(rng, max(2, round(n * scale)), V)
                for n in config["reference_prompts"]]
     new = min(16, extra["max_new_tokens"])
-    long_one = max(range(len(prompts)), key=lambda j: len(prompts[j]))
-    cache = kernels["alloc_cache"]()
-    zf, zi = np.zeros(S, np.float32), np.zeros(S, np.int32)
-    runs, got = [], []
     t0 = time.monotonic()
-    for j, ids in enumerate(prompts):
-        bucket = next(b for b in buckets if b >= len(ids))
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :len(ids)] = ids
-        payload = {"input_ids": toks,
-                   "length": np.asarray([len(ids)], np.int32),
-                   "temperature": np.zeros(1, np.float32),
-                   "seed": np.zeros(1, np.int32),
-                   "top_k": np.zeros(1, np.int32),
-                   "top_p": np.ones(1, np.float32)}
-        seen.clear()
-        slot = j % S
-        first, *cache = kernels["prefill"](params, tuple(cache),
-                                           np.asarray([slot], np.int32),
-                                           payload)
-        tok, pos, fin = zi.copy(), zi.copy(), np.ones(S, bool)
-        tok[slot], pos[slot], fin[slot] = int(np.asarray(first)[0]), \
-            len(ids), False
-        st, emits = zi.copy(), []
-        steps = 2 * new if j == long_one else new  # four segments for one
-        for _ in range(steps // seg):
-            packed, *cache = kernels["segment"](params, tuple(cache), tok,
-                                                pos, st, fin, zf, zi, zi,
-                                                zf + 1)
-            packed = np.asarray(packed)
-            emits.append(packed[:, :seg])
-            tok, pos, st = (packed[:, seg + k].copy() for k in range(3))
-        jax.effects_barrier()
-        served = np.concatenate(emits, axis=1)[slot].tolist()
-        # The logits each served token was chosen from: the prefill's, then
-        # every step's but the last.
-        got.append(np.stack([seen[0][0]]
-                            + [lg[slot] for lg in seen[1:steps]]))
-        runs.append({"ids": ids, "tokens": served})
-    decoder.choose = choose
-    del cache
+    runs, got = _serve_prompts_alone(kernels, params, meta, buckets, prompts,
+                                     new)
     print(f"lfm2: {len(prompts)} prompts of {sorted({len(p) for p in prompts})}"
           f" tokens prefilled alone into a slot and decoded in "
           f"{time.monotonic() - t0:.0f} s (compiles included)", flush=True)
@@ -2042,6 +2077,217 @@ def _lfm2_child(rehearse: bool) -> None:
     # comparison of the served tokens.
     assert rehearse or (report["int8"]["rms_logit_diff"] > LFM2_RMS_TOL
                         and not report["int8"]["ok"]), report["int8"]
+    print(json.dumps(report))
+
+
+def _mellum_child(rehearse: bool) -> None:
+    """Mellum 2's kernels alone, then its programs against its plain
+    reference, on one device, at the benchmark cell's widths
+    (``benchmark/configs/mellum2-12b-8l.json``; its ``rehearse`` widths on
+    the CPU).
+
+    Alone, by the profiler's clock: ``decode_attention`` at 32 slots and a
+    group of 8 queries a K/V head of 128, over a ring (1,024 rows, all live)
+    and over the full layers' rows at 4k, 8k and 16k live; ``flash_attention``
+    with the K/V heads read through the tile map, with and without the band,
+    at the four buckets (the share of 197 TFLOP/s is of the work the grid
+    visits); the gated ``expert_matmul`` at ``K`` 2304, ``F`` 896 at 4, 512,
+    1,024 and 2,048 rows an expert, each by the kernel's own plan.
+
+    Then the servable over the tree the benchmark stages and the programs
+    ``build_gen_kernels`` jits, as the scheduler runs them: the cell's own 16
+    reference prompts (700 and 5,000 tokens, drawn as ``benchmark/run.py``
+    draws them), each prefilled alone into a slot of a pool of 32 and decoded
+    for two segments, the first 5,000-token one for four.  ``choose`` is
+    watched, not replaced.  The reference's full forward pass over prompt +
+    served tokens gives the largest and the root-mean-square logit
+    difference and, through the cell's own ``check`` rule (``judge``), how
+    many served tokens lie far; the same against the reference's three
+    controls (int8; the window layers read as full; the full layers turned
+    as window layers), each of which must fail."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import traffic
+    from benchmark.families import mellum as bench_family
+    from benchmark.reference import mellum as reference
+    from pytorch_zappa_serverless_tpu.config import ModelConfig
+    from pytorch_zappa_serverless_tpu.models import decoder, mellum
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul as em
+    from pytorch_zappa_serverless_tpu.ops.flash_attention import (
+        band_blocks, flash_attention, masked_attention)
+    from pytorch_zappa_serverless_tpu.serving.generation import (
+        build_gen_kernels)
+
+    on_device = not rehearse
+    config = json.loads((ROOT / "benchmark" / "configs"
+                         / "mellum2-12b-8l.json").read_text())
+    serve = config["serve"]
+    buckets, extra = serve["seq_buckets"], dict(serve["extra"])
+    dtype, scale = "bfloat16", 1.0
+    if rehearse:
+        buckets = config["rehearse"]["seq_buckets"]
+        extra.update(config["rehearse"]["extra"])
+        dtype, scale = "float32", config["rehearse"]["scale"]
+        config["weights"]["dtype"] = "float32"
+    cfg = mellum.config_from_arch(extra["arch"])
+    fam = mellum.family(cfg, jnp.dtype(dtype))
+    report = {}
+
+    # -- the kernels alone ------------------------------------------------
+    S, W = extra["gen_slots"], cfg.sliding_window
+    kv, heads, dh = cfg.kv_heads, cfg.heads, cfg.head_dim
+    T = fam.rows.count(buckets[-1] + extra["max_new_tokens"])
+    shapes = [(S, W, kv, dh, heads, W)] + [
+        (S, T, kv, dh, heads, max(T * live // 17408, 1))
+        for live in (4096, 8192, 16384)]
+    report["decode_attention a kind"] = time_grouped_attention(
+        shapes, on_device, interpret=rehearse)
+    for row in report["decode_attention a kind"]:
+        print("mellum decode_attention " + json.dumps(row), flush=True)
+    report["expert_matmul gated"] = time_gated_experts(
+        (2, 4) if rehearse else (4, 512, 1024, 2048), on_device,
+        interpret=rehearse, experts=cfg.experts_held, width=cfg.hidden_size,
+        inner=cfg.expert_width, kinds=("routed",))
+    for row in report["expert_matmul gated"]:
+        print("mellum expert_matmul " + json.dumps(row), flush=True)
+    report["expert_plans"] = {
+        rows: em.plan_summary(rows // cfg.top_k, cfg.top_k, cfg.hidden_size,
+                              cfg.expert_width, cfg.experts_held, True, 2)
+        for rows in (S * cfg.top_k, buckets[-1] * cfg.top_k)}
+    print("mellum expert_plans " + json.dumps(report["expert_plans"]),
+          flush=True)
+    rng = np.random.default_rng(SEED)
+    report["prompt attention"] = []
+    for P in buckets:
+        q = jnp.asarray(rng.standard_normal((1, P, heads, dh)) * 0.5,
+                        jnp.bfloat16)
+        k, v = (jnp.asarray(rng.standard_normal((1, P, kv, dh)) * 0.5,
+                            jnp.bfloat16) for _ in range(2))
+        for form, window in (("flash", None), ("flash_band", W)):
+            def attend(q, k, v):
+                return flash_attention(q, k, v, causal=True, window=window,
+                                       interpret=rehearse)
+
+            @jax.jit
+            def chain(q, k, v):
+                for _ in range(_TIMED_CALLS):
+                    q = q + attend(q, k, v) * 0.01
+                return q
+
+            got = attend(q, k, v)
+            chain(q, k, v).block_until_ready()
+            blk = 1024 if P >= 1024 else 512
+            nq = -(-P // blk)
+            visited = (nq * (nq + 1) // 2 if window is None else sum(
+                min(i + 1, band_blocks(nq, blk, blk, W)) for i in range(nq)))
+            row = {"shape": [1, P, heads * dh], "kv_heads": kv, "form": form,
+                   "blocks_visited": visited}
+            if on_device:
+                row["us_a_layer"] = _busy_us(lambda: chain(q, k, v))
+                row["share_of_197"] = round(
+                    2 * 2 * heads * dh * visited * blk * blk / 197e12 * 1e6
+                    / row["us_a_layer"], 3)
+            if P <= 4096:  # the form that writes its scores, where they fit
+                at = np.arange(P)
+                behind = at[:, None] - at[None, :]
+                keep = (behind >= 0) & (behind < (window or P))
+                want = masked_attention(
+                    q.reshape(1, P, heads * dh),
+                    *(jnp.repeat(a, heads // kv, axis=2).reshape(
+                        1, P, heads * dh) for a in (k, v)),
+                    jnp.asarray(np.where(keep, 0.0, -1e9)[None, None],
+                                jnp.float32), heads)
+                off = float(jnp.max(jnp.abs(
+                    got.reshape(1, P, heads * dh).astype(jnp.float32)
+                    - want.astype(jnp.float32))))
+                assert off < 0.05, (P, form, off)
+                row["max_diff_from_masked"] = round(off, 4)
+            report["prompt attention"].append(row)
+            print("mellum prompt_attention " + json.dumps(row), flush=True)
+        del q, k, v
+
+    # -- the programs against the reference -----------------------------------
+    t0 = time.monotonic()
+    tree = bench_family.init_tree(config["weights"]["seed"], config,
+                                  {"extra": extra})
+    print(f"mellum: {len(cfg.layer_types)} layers drawn in "
+          f"{time.monotonic() - t0:.0f} s", flush=True)
+    sv = decoder.make_servable(
+        "mellum", ModelConfig(name="mellum", dtype=dtype, batch_buckets=(1,),
+                              seq_buckets=buckets, extra=extra), fam, tree)
+    del tree
+    params, meta = sv.params, sv.meta["continuous"]
+    kernels = build_gen_kernels(types.SimpleNamespace(servable=sv))
+    V = cfg.vocab_size
+    print("mellum: cache leaves " + json.dumps(
+        [[list(shape), str(np.dtype(dt))]
+         for shape, dt in meta["cache_leaves"]])
+        + ", read_block " + json.dumps(
+            {k["name"]: k["read_block"] for k in meta["kinds"]})
+        + ", prompt forms "
+        + json.dumps({b: meta["prompt_form"](1, b) for b in buckets}),
+        flush=True)
+
+    # The cell's reference prompts, as benchmark/run.py draws them.
+    rng = np.random.default_rng(config["weights"]["seed"] + 1)
+    prompts = [traffic.token_ids(rng, max(2, round(n * scale)), V)
+               for n in config["reference_prompts"]]
+    new = min(16, extra["max_new_tokens"])
+    t0 = time.monotonic()
+    runs, got = _serve_prompts_alone(kernels, params, meta, buckets, prompts,
+                                     new)
+    print(f"mellum: {len(prompts)} prompts of "
+          f"{sorted({len(p) for p in prompts})} tokens prefilled alone into "
+          f"a slot and decoded in {time.monotonic() - t0:.0f} s (compiles "
+          f"included)", flush=True)
+
+    keys = bench_family.published({"extra": extra})
+    report["logit_std"] = float(np.std(got[0][0]))
+
+    refs = {}
+
+    def forward(run, control):
+        at = (id(run), control)  # a pass a run a control, however often read
+        if at not in refs:
+            refs[at] = reference.forward(
+                params, run["ids"] + run["tokens"][:-1], keys, control,
+                len(run["tokens"]))
+        return refs[at]
+
+    controls = ("int8", "window_as_full", "no_yarn")
+    for control in (None,) + controls:
+        # Judged over the 16 tokens a prompt the cell asks for.
+        report[control or "float32"] = _against_reference(
+            forward, bench_family.judge, config, runs, got, control, new)
+        print(f"mellum: reference {control or 'float32'}: "
+              + json.dumps(report[control or "float32"]), flush=True)
+    # What the cell's limits are set between: the share of served tokens
+    # that lie far under the reference's best, by how far "far" is.
+    report["far_share_by_tolerance"] = {
+        str(tol): {control or "float32": _against_reference(
+            forward, bench_family.judge, {**config,
+                                          "reference_tolerance": tol},
+            runs, got, control, new)["far_share"]
+            for control in (None,) + controls}
+        for tol in (0.01, 0.02, 0.03, 0.05)}
+    print("mellum: far share by tolerance "
+          + json.dumps(report["far_share_by_tolerance"]), flush=True)
+    stats = jax.local_devices()[0].memory_stats() or {}
+    report["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+    print("mellum " + json.dumps(report))
+    limit = 1e-3 if rehearse else MELLUM_RMS_TOL
+    assert report["float32"]["rms_logit_diff"] <= limit, report["float32"]
+    assert report["float32"]["ok"], report["float32"]
+    # Each control must fail, by the logits and (on the chip, where a
+    # window's worth of positions is read) by the cell's own comparison of
+    # the served tokens.
+    for control in controls:
+        assert report[control]["rms_logit_diff"] > limit, report[control]
+        assert rehearse or not report[control]["ok"], report[control]
     print(json.dumps(report))
 
 
@@ -2667,6 +2913,15 @@ def main(argv=None) -> int:
                 "cell's reference prompts through prefill into the pool and "
                 "segments agree with the plain reference; the reference in "
                 "the precision below does not")
+            run_child(f"import chip_smoke; "
+                      f"chip_smoke._mellum_child({args.rehearse})",
+                      args.rehearse, "mellum.log", timeout=3000.0)
+            say("mellum: the decode kernel over a ring and over full rows, "
+                "the band form of the prompt attention and the gated expert "
+                "matmul at its widths match their jax.numpy forms; the "
+                "cell's reference prompts through prefill into both leaf "
+                "pairs and segments agree with the plain reference; its "
+                "three controls do not")
             phase_sd15(sd15_cfg, probe, args.rehearse)
     except SmokeFailure as e:
         print(f"[smoke] FAIL after {time.monotonic() - t0:.0f}s: {e}",

@@ -24,6 +24,7 @@ TPU-first structure, one jitted program per (batch, prompt-bucket):
 
 from __future__ import annotations
 
+import functools
 import hashlib
 from dataclasses import dataclass
 from typing import Callable, NamedTuple
@@ -118,6 +119,18 @@ class Rows:
 ROWS = Rows()  # a row a position
 
 
+class Kind(NamedTuple):
+    """One kind of K/V layer, of a family whose layers do not all keep their
+    rows alike (models/mellum.py: a ring of a window's rows in three layers
+    of four, a row a position in the fourth): its ``name`` (what the
+    family's layer is told and the scheduler counts by), how many ``layers``
+    hold it, and its ``rows``.  A kind has a K and a V leaf of its own
+    (:func:`cache_leaves`), its own span and its own work list a step."""
+    name: str
+    layers: int
+    rows: Rows
+
+
 @dataclass(frozen=True)
 class Family:
     """A family's block, and the numbers the programs read of it.
@@ -150,6 +163,15 @@ class Family:
     (:func:`segment_scan`).  ``expert_plan(rows)`` is what the family's
     routed experts run for ``rows`` rows of a program
     (ops/expert_matmul.plan_summary), for the lane's boot log.
+
+    A family whose K/V layers keep their rows in more than one way declares
+    them as ``kinds`` (:class:`Kind` each; none: one kind, ``kv_layers``
+    layers of ``rows``).  The pool then holds a K and a V leaf a kind, in
+    that order and before the state; ``cache_index(i)`` is ``(kind, index)``
+    with ``kind`` an index into ``kinds``; the layer is called with one more
+    keyword, ``kind=`` (the kind's name), and traced once a kind; ``rows``
+    stays what the scheduler asks about a prompt (``prefill_batch``,
+    ``windows``).  Only the slot lane serves such a family.
     """
     embed: Callable
     positions: Callable | None
@@ -171,14 +193,19 @@ class Family:
     cache_index: Callable = lambda i: i
     counters: tuple = ()
     expert_plan: Callable | None = None
+    kinds: tuple = ()
 
 
 def cache_leaves(fam: Family, slots: int, T: int, dtype) -> tuple:
     """``(shape, dtype)`` of every leaf of a cache of ``slots`` slots of
-    ``T`` rows, the slot axis second: K, V, then the family's state."""
-    kv = ((fam.kv_layers or fam.layers, slots, T, fam.width), dtype)
-    return (kv, kv) + tuple(((n, slots, *shape), dt)
-                            for n, shape, dt in fam.state)
+    ``T`` rows, the slot axis second: K, V (a pair a kind, each with the
+    rows its kind needs for ``T`` positions), then the family's state."""
+    if fam.kinds:
+        kv = tuple(((k.layers, slots, k.rows.count(T), fam.width), dtype)
+                   for k in fam.kinds for _ in "kv")
+    else:
+        kv = (((fam.kv_layers or fam.layers, slots, T, fam.width), dtype),) * 2
+    return kv + tuple(((n, slots, *shape), dt) for n, shape, dt in fam.state)
 
 
 # ---------------------------------------------------------------------------
@@ -226,6 +253,18 @@ class SlotPool(NamedTuple):
 
 def slot_pool(k, v, rows: Rows = ROWS) -> SlotPool:
     return SlotPool(k, v, jnp.arange(k.shape[1]), rows)
+
+
+def slot_pools(fam: Family, cache):
+    """``(pool, state)`` of the slot lane's ``cache`` (its leaves): the one
+    :class:`SlotPool`, or for a family of several kinds a list of them, a
+    kind each in ``fam.kinds``' order, and the leaves after K and V."""
+    if not fam.kinds:
+        return slot_pool(*cache[:2], fam.rows), tuple(cache[2:])
+    slots = jnp.arange(cache[0].shape[1])
+    n = 2 * len(fam.kinds)
+    return ([SlotPool(cache[2 * j], cache[2 * j + 1], slots, k.rows)
+             for j, k in enumerate(fam.kinds)], tuple(cache[n:]))
 
 
 def slot_put(slots):
@@ -330,7 +369,8 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
     however the program caches and returns the attention output; ``p`` is
     the layer's parameters, ``cache`` the tuple of leaves (K, V, then the
     family's state: :func:`cache_leaves`) and ``i`` the layer's index into
-    them (``fam.cache_index``).
+    them (``fam.cache_index``).  For a family of several kinds ``attend`` is
+    a tuple, a kind each, and each is handed its own kind's K and V alone.
     ``adapter_idx`` [B] routes each row through its tenant's LoRA slot of
     ``params["__adapters__"]`` (docs/ADAPTERS.md; 0 = base passthrough).
 
@@ -364,6 +404,7 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
     kinds of layer (models/nemotron_h.py) is three traces."""
     stacks = None if adapter_idx is None else params.get("__adapters__")
     hooked = bool(fam.state or fam.counters)
+    n_kv = 2 * max(len(fam.kinds), 1)  # the leaves before the state
     if put is None:  # a decode step: the slots' own state, set in place
         def held(leaf, i):
             return leaf[i]
@@ -376,22 +417,27 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
 
         keep = put
 
-    @jax.jit
-    def layer(p, x, cache, i, lora):
+    @functools.partial(jax.jit, static_argnames="kind")
+    def layer(p, x, cache, i, lora, kind=None):
         layer_traced()
         counts = None
 
         def layer_attend(q, k, v):
             nonlocal cache
-            cache, out = attend(p, cache, i, q, k, v)
+            if kind is None:
+                cache, out = attend(p, cache, i, q, k, v)
+            else:  # this kind's K and V, by this kind's ``attend``
+                at = 2 * kind
+                mine, out = attend[kind](p, cache[at:at + 2], i, q, k, v)
+                cache = cache[:at] + tuple(mine) + cache[at + 2:]
             return out
 
         def layer_state(update):
             nonlocal cache
-            mine, out = update(tuple(held(leaf, i) for leaf in cache[2:]),
+            mine, out = update(tuple(held(leaf, i) for leaf in cache[n_kv:]),
                                lengths)
-            cache = cache[:2] + tuple(
-                keep(leaf, i, m) for leaf, m in zip(cache[2:], mine))
+            cache = cache[:n_kv] + tuple(
+                keep(leaf, i, m) for leaf, m in zip(cache[n_kv:], mine))
             return out
 
         def layer_count(c):
@@ -399,15 +445,20 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
             counts = c
 
         hooks = {"state": layer_state, "count": layer_count} if hooked else {}
+        if kind is not None:
+            hooks["kind"] = fam.kinds[kind].name
         x = fam.layer(p, x, layer_attend, pos, lora=lora,
                       lora_idx=adapter_idx, **hooks)
         return x, cache, counts
 
     counts = None
     for i in range(fam.layers):
+        at, kind = fam.cache_index(i), {}
+        if fam.kinds:
+            at, kind = at[1], {"kind": at[0]}
         x, cache, c = layer(
-            params[f"layer{i}"], x, cache, jnp.int32(fam.cache_index(i)),
-            None if stacks is None else stacks.get(f"layer{i}"))
+            params[f"layer{i}"], x, cache, jnp.int32(at),
+            None if stacks is None else stacks.get(f"layer{i}"), **kind)
         if c is not None:
             counts = c if counts is None else counts + c
     return fam.norm(params, x), cache, counts
@@ -430,8 +481,12 @@ def _decode_logits(fam, params, pool, cache, tok, wpos, span, work, dtype,
     """One token a slot through the trunk, over ``pool``'s layout holding
     ``cache`` (its leaves) → (logits [S, V], cache, counts)."""
     x = _embed(fam, params, tok, wpos, dtype)[:, None, :]
-    x, cache, counts = _trunk(fam, params, x, wpos[:, None], cache,
-                              _write_then_attend(fam, pool, wpos, span, work),
+    if isinstance(pool, list):  # a pool, a span and a work list a kind
+        attend = tuple(_write_then_attend(fam, p, wpos, s, w)
+                       for p, s, w in zip(pool, span, work))
+    else:
+        attend = _write_then_attend(fam, pool, wpos, span, work)
+    x, cache, counts = _trunk(fam, params, x, wpos[:, None], cache, attend,
                               adapter_idx)
     return fam.head(params, x[:, 0]), cache, counts
 
@@ -500,7 +555,11 @@ def prefill(fam: Family, params: dict, tokens: jax.Array, lengths: jax.Array,
     pos = jnp.arange(P)
     x = _embed(fam, params, tokens, pos, dtype, clamp=False)
     put = slot_put(slots)
-    prompt = fam.rows.prompt(fam.heads, lengths, P, put)
+    if fam.kinds:
+        prompt = tuple(k.rows.prompt(fam.heads, lengths, P, put)
+                       for k in fam.kinds)
+    else:
+        prompt = fam.rows.prompt(fam.heads, lengths, P, put)
 
     x, cache, _ = _trunk(fam, params, x, pos, tuple(cache), prompt,
                          adapter_idx, lengths, put)
@@ -565,7 +624,8 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
     [S, seg], *the cache's leaves, tok, pos, step, finished) and the
     family's counts, as :func:`segment_scan`.
     """
-    total, T = pool.positions, pool.k.shape[2]
+    pools = pool if isinstance(pool, list) else [pool]
+    total = min(p.positions for p in pools)
     # Repetition penalty (fixed-batch lane only, which is the slot pool —
     # the streaming lane would need a [S, V] presence buffer donated across
     # segments; declined there, loudly, in serving/server.py): the presence
@@ -580,20 +640,27 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
         wpos = jnp.minimum(pos, total - 1)
         # A finished slot's token is pinned to EOS whatever it attends to:
         # it is dead to attention, which reads nothing of its row.
-        first, last = pool.span(wpos)
-        last = jnp.where(finished, -1, last)
-        work = decode_attention.step_work(last, T, fam.width, cache[0].dtype,
-                                          first)
+        spans, works = [], []
+        for each in pools:  # a span and a list of live blocks a kind
+            first, last = each.span(wpos)
+            last = jnp.where(finished, -1, last)
+            spans.append((first, last))
+            works.append(decode_attention.step_work(
+                last, each.k.shape[2], fam.width, each.k.dtype, first))
+        if not isinstance(pool, list):
+            spans, works = spans[0], works[0]
         logits, cache, counts = _decode_logits(
-            fam, params, pool, cache, tok, wpos, (first, last), work, dtype,
+            fam, params, pool, cache, tok, wpos, spans, works, dtype,
             adapter_idx)
         if seen is not None:
-            seen = seen.at[pool.slots, tok].set(True)
+            seen = seen.at[pools[0].slots, tok].set(True)
             logits = _penalized(logits, seen, repetition_penalty, rep_on)
         nxt = choose(logits, temperature, seeds, t + 1, top_k, top_p)
         return (cache, nxt, seen, *([counts] if fam.counters else []))
 
-    return segment_scan(one, (pool.k, pool.v, *state), tok, pos, step,
+    return segment_scan(one, (*(leaf for each in pools
+                                for leaf in (each.k, each.v)), *state),
+                        tok, pos, step,
                         finished, seg, fam.eos_id, presence,
                         len(fam.counters))
 
@@ -623,18 +690,18 @@ def generate(fam: Family, params: dict, tokens: jax.Array,
         valid = jnp.arange(P)[None, :] < lengths[:, None]
         presence = jnp.zeros((B, fam.vocab_size), bool).at[
             jnp.arange(B)[:, None], tokens].max(valid)
-    first, cache_k, cache_v, *state = prefill_start(
+    first, *cache = prefill_start(
         fam, fam.pre_tree(params), tokens, lengths, temperature, seeds,
         zero_cache(fam, B, P + max_new, dtype), jnp.arange(B), dtype,
         top_k=top_k, top_p=top_p, repetition_penalty=repetition_penalty,
         presence=presence, adapter_idx=adapter_idx)
     step, finished = jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool)
+    pool, state = slot_pools(fam, cache)
     emits, *_ = decode_segment(
-        fam, fam.dec_tree(params, B), slot_pool(cache_k, cache_v, fam.rows),
-        first, lengths, step, finished,
+        fam, fam.dec_tree(params, B), pool, first, lengths, step, finished,
         temperature, seeds, max_new, dtype, top_k=top_k, top_p=top_p,
         repetition_penalty=repetition_penalty, presence=presence,
-        adapter_idx=adapter_idx, state=tuple(state))
+        adapter_idx=adapter_idx, state=state)
     return emits
 
 
@@ -802,15 +869,16 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
             f"{max_new} exceeds the model's max_positions "
             f"({fam.max_positions}); shrink seq_buckets or max_new_tokens")
 
-    paged_ok = type(fam.rows) is Rows and not fam.state
+    paged_ok = type(fam.rows) is Rows and not fam.state and not fam.kinds
     if not paged_ok and getattr(cfg_model, "kv_cache", "slot") == "paged":
         # A page table holds a row a position and the chunked prefill reads
-        # it back as one: a family whose rows are laid out otherwise, or
-        # that keeps state which is no row, has no paged lane, and says so
-        # here rather than serve something else.
+        # it back as one: a family whose rows are laid out otherwise (or in
+        # more than one way), or that keeps state which is no row, has no
+        # paged lane, and says so here rather than serve something else.
         raise ValueError(
             f"{name}: kv_cache='paged' cannot serve this family: its cache "
             f"is not a row a position ({type(fam.rows).__name__}, "
+            f"{len(fam.kinds) or 1} kinds of K/V layer, "
             f"{len(fam.state)} leaves of state); use kv_cache='slot'")
 
     adapters_on = int(getattr(cfg_model, "adapter_slots", 0)) > 0
@@ -946,6 +1014,14 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
     segment_tokens = int(cfg_model.extra.get("segment_tokens", 8))
     total = max_seq + max_new
     T = fam.rows.count(total)  # rows a slot holds for ``total`` positions
+    head_dim = fam.width // (fam.kv_heads or fam.heads)
+
+    def kind_meta(k: Kind) -> dict:
+        rows = k.rows.count(total)
+        return {"name": k.name, "layers": k.layers, "rows": k.rows,
+                "count": rows,
+                "read_block": decode_attention.read_block(rows, fam.width,
+                                                          dtype)}
 
     def collate_admit(sample, bucket):
         ids = np.asarray(sample["input_ids"], np.int32)
@@ -959,6 +1035,12 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         return {"input_ids": jax.ShapeDtypeStruct((1, bucket), jnp.int32),
                 "length": jax.ShapeDtypeStruct((1,), jnp.int32),
                 **knob_spec(1)}
+
+    def _segment(p, cache, tok, pos, st, fin, temp, seeds, topk, topp):
+        pool, state = slot_pools(fam, cache)
+        return decode_segment(fam, fam.dec_tree(p, gen_slots), pool, tok, pos,
+                              st, fin, temp, seeds, segment_tokens, dtype,
+                              top_k=topk, top_p=topp, state=state)
 
     continuous = {
         "slots": gen_slots,
@@ -982,10 +1064,15 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         # What the scheduler counts with, in numpy (spans, summaries, the
         # passes of a prompt's attention, prompts a prefill dispatch).
         "rows": fam.rows,
-        # The form the prompt attention of a (batch, bucket) prefill takes.
-        "prompt_form": lambda batch, bucket: fam.rows.prompt_form(
-            batch, fam.heads, bucket,
-            fam.width // (fam.kv_heads or fam.heads)),
+        # A family of several kinds of K/V layer: each kind's name, layers,
+        # ``Rows``, rows a slot and read block, in the order of the pool's
+        # K/V pairs (none: the one kind the four entries above describe).
+        "kinds": tuple(kind_meta(k) for k in fam.kinds),
+        # The form the prompt attention of a (batch, bucket) prefill takes
+        # (of several kinds: each kind's, in their order).
+        "prompt_form": lambda batch, bucket: "+".join(
+            rows.prompt_form(batch, fam.heads, bucket, head_dim)
+            for rows in ([k.rows for k in fam.kinds] or [fam.rows])),
         # Routed lane: admission prefills run on the prefill tree, the
         # slot-pool segment routes on the POOL size (the decode-row count of
         # its program) — consistent with the fixed-batch path at the same
@@ -997,14 +1084,7 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
                                   payload["seed"], cache, slots, dtype,
                                   top_k=payload["top_k"],
                                   top_p=payload["top_p"])),
-        "segment": (lambda p, cache, tok, pos, st, fin, temp, seeds,
-                    topk, topp:
-                    decode_segment(fam, fam.dec_tree(p, gen_slots),
-                                   slot_pool(*cache[:2], fam.rows), tok, pos,
-                                   st, fin,
-                                   temp, seeds, segment_tokens, dtype,
-                                   top_k=topk, top_p=topp,
-                                   state=cache[2:])),
+        "segment": _segment,
         "detokenize": ((lambda toks: tokenizer.decode(toks))
                        if tokenizer is not None else None),
     }

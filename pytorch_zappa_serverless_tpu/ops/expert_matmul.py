@@ -72,18 +72,24 @@ _TILES_BLOCK_BYTES = 13 << 20
 _ROW_ALIGN = 16  # rows: a bfloat16 tile of HBM, where a block may begin
 
 
-def route(x, gate, bias, top_k: int, scale: float, offset: int, held: int):
+def route(x, gate, bias, top_k: int, scale: float, offset: int, held: int,
+          scoring: str = "sigmoid"):
     """The router, in float32: x [N, D] (the rows at full width), ``gate``
     [D, E], ``bias`` [E] → ``(weights [N, top_k] float32, group [N, top_k]
     int32)``.  ``s = sigmoid(x @ gate)``; the ``top_k`` largest of ``s +
     bias`` are chosen; their weights are ``s`` there, divided by their sum,
-    times ``scale``.  ``group`` is the chosen expert less ``offset`` where
-    it is held here, and ``held`` (the group that is not multiplied)
-    elsewhere."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                               gate.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    times ``scale``.  With ``scoring="softmax"`` ``s`` is the softmax over
+    all ``E`` and there is no bias in the choice (``bias`` None).  ``group``
+    is the chosen expert less ``offset`` where it is held here, and ``held``
+    (the group that is not multiplied) elsewhere."""
+    logits = jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(s, top_k)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, chosen, axis=-1)
     w = w / w.sum(-1, keepdims=True) * scale
     local = chosen - offset

@@ -62,9 +62,25 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def band_first(iq, block_q: int, block_k: int, window: int):
+    """The first block of keys a block ``iq`` of queries (traced) reads
+    under a band of ``window`` (query ``p`` sees keys ``j`` with ``0 <= p -
+    j < window``); the last is the diagonal's."""
+    return jnp.maximum(iq * block_q - (window - 1), 0) // block_k
+
+
+def band_blocks(blocks_q: int, block_q: int, block_k: int, window: int) -> int:
+    """Blocks of keys the widest band of any block of queries spans: the
+    grid's bound on its innermost axis."""
+    return max((iq * block_q + block_q - 1) // block_k + 1
+               - max(iq * block_q - (window - 1), 0) // block_k
+               for iq in range(blocks_q))
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             sm_scale: float, causal: bool, block_q: int, block_k: int,
-            tk_valid: int, tk_padded: int, bias_ref=None):
+            tk_valid: int, tk_padded: int, bias_ref=None,
+            window: int | None = None):
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -76,6 +92,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     q_start = pl.program_id(2) * block_q
     k_start = ik * block_k
+    if window is not None:
+        # The axis counts from the band's first block (the index map's
+        # rule); a step past the diagonal's block is skipped below.
+        k_start = (band_first(pl.program_id(2), block_q, block_k, window)
+                   + ik) * block_k
 
     def _block():
         q = q_ref[0, 0]                                   # (bq, D)
@@ -92,6 +113,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(k_start + cols <= q_start + rows, s, _NEG_INF)
+            if window is not None:
+                s = jnp.where(q_start + rows - (k_start + cols) < window, s,
+                              _NEG_INF)
 
         m_prev = m_ref[:, :1]                             # (bq, 1)
         l_prev = l_ref[:, :1]
@@ -121,16 +145,32 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
                     sm_scale: float | None = None, block_q: int | None = None,
-                    block_k: int = 1024, interpret: bool | None = None):
+                    block_k: int = 1024, interpret: bool | None = None,
+                    window: int | None = None):
     """Blocked online-softmax attention.
 
     q: [B, Tq, H, D]; k, v: [B, Tk, H, D]; kv_mask: optional [B, Tk] bool
     (True = attend).  Returns [B, Tq, H, D] in q.dtype.
+
+    ``k`` and ``v`` may hold fewer heads than ``q``, ``[B, Tk, KV, D]`` with
+    ``H`` a multiple of ``KV``: query head ``h`` reads K/V head ``h // (H /
+    KV)`` through the tile map, and nothing is repeated in HBM.
+
+    ``window`` (with ``causal``) is a band: query ``p`` sees keys ``j`` with
+    ``0 <= p - j < window``.  The grid's innermost axis is then as long as
+    the widest band in blocks (:func:`band_blocks`) and counts from the
+    band's first block (:func:`band_first`): a block of keys wholly outside
+    the band costs no grid step, no DMA and no arithmetic, and the mask
+    inside a visited block is exact.  Near the start of the prompt, where
+    the band is cut short, the spare steps name the diagonal's block again
+    (no new copy) and are skipped.
     """
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, group = k.shape[1], H // k.shape[2]
     if causal and Tq != Tk:
         raise ValueError(f"causal needs Tq == Tk, got {Tq} != {Tk}")
+    if window is not None and (not causal or kv_mask is not None):
+        raise ValueError("a band (window=) is causal and takes no kv_mask")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     if interpret is None:
@@ -156,13 +196,27 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
     qt, kt, vt = _prep(q, tq_p), _prep(k, tk_p), _prep(v, tk_p)
     nq, nk = tq_p // block_q, tk_p // block_k
 
+    if window is None and group == 1:
+        keys = lambda b, h, iq, ik: (b, h, ik, 0)  # noqa: E731
+    else:
+        blocks_k = nk
+
+        def keys(b, h, iq, ik):
+            if window is not None:
+                last = jnp.minimum((iq * block_q + block_q - 1) // block_k,
+                                   blocks_k - 1)
+                ik = jnp.minimum(
+                    band_first(iq, block_q, block_k, window) + ik, last)
+            return (b, h // group, ik, 0)
+
+        if window is not None:
+            nk = min(nk, band_blocks(nq, block_q, block_k, window))
+
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d_p), lambda b, h, iq, ik: (b, h, iq, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_k, d_p), lambda b, h, iq, ik: (b, h, ik, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_k, d_p), lambda b, h, iq, ik: (b, h, ik, 0),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, block_k, d_p), keys, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, block_k, d_p), keys, memory_space=pltpu.VMEM),
     ]
     operands = [qt, kt, vt]
     bias_kw = {}
@@ -176,7 +230,8 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
 
     kernel = functools.partial(
         _kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, tk_valid=Tk, tk_padded=tk_p)
+        block_k=block_k, tk_valid=Tk, tk_padded=tk_p,
+        **({} if window is None else {"window": window}))
     if bias_kw:
         # bias ref arrives positionally after v_ref; rebind so the kernel body
         # sees it as bias_ref (scratch refs always trail the operand refs).

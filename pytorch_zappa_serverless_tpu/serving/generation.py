@@ -456,6 +456,16 @@ class GenerationScheduler:
         # Rows a live slot's span is read in (a model that does not say
         # reads whole rows).
         self.read_block: int = meta.get("read_block", self.rows)
+        # The kinds of K/V layer the pool holds a leaf pair of, each with
+        # its own rows a slot, span and read block (a model that names none
+        # has the one the three lines above describe).  What a round counts
+        # of rows is summed over them, a kind's layers weighing its rows
+        # where a share of the pool is taken.
+        self._kinds: tuple = meta.get("kinds") or ({
+            "name": None, "layers": 1, "rows": self._rows,
+            "count": self.rows, "read_block": self.read_block},)
+        self._pool_rows = self.slots * sum(
+            k["layers"] * k["count"] for k in self._kinds)
         self.eos_id: int = meta["eos_id"]
         self.max_new: int = meta["max_new"]
         self.seg: int = meta["segment_tokens"]
@@ -484,6 +494,13 @@ class GenerationScheduler:
         # model says of the dispatch's (padded batch, bucket); a model that
         # does not say has a prompt path of its own.
         self.prefill_kernel_dispatches = 0  # guarded-by: dispatch-serialized
+        # Prompts prefilled (a batch's padding with them), by the bucket of
+        # their dispatch: what the prompt passes of a stretch of time cost
+        # is a function of these.
+        # (Every bucket from the start: a scrape iterates it from the loop's
+        # thread, and an entry that only changes its value is safe there.)
+        self.prefill_buckets: dict[int, int] = {  # guarded-by: dispatch-serialized
+            int(b): 0 for b in meta["prompt_buckets"]}
         self._prompt_form = meta.get("prompt_form",
                                      lambda batch, bucket: "own")
         # The pool: the model's cache leaves, K and V first.
@@ -556,6 +573,9 @@ class GenerationScheduler:
         self.kv_live_sum = 0.0   # guarded-by: dispatch-serialized
         self.kv_read_sum = 0.0   # guarded-by: dispatch-serialized
         self.span_rows_sum = 0      # guarded-by: dispatch-serialized
+        # The same a kind, for a model of more than one.
+        self.span_rows_by_kind = {  # guarded-by: dispatch-serialized
+            k["name"]: 0 for k in self._kinds if len(self._kinds) > 1}
         self.summary_rows_sum = 0   # guarded-by: dispatch-serialized
         self.live_positions_sum = 0  # guarded-by: dispatch-serialized
         self.window_rolls = 0       # guarded-by: dispatch-serialized
@@ -572,7 +592,8 @@ class GenerationScheduler:
                                       program_of=slot_program)
         log_event(log, "generation lane ready", model=self.name, mode="slot",
                   slots=self.slots, positions=self.total, rows=self.rows,
-                  read_block=self.read_block,
+                  read_block=(self.read_block if len(self._kinds) == 1 else {
+                      k["name"]: k["read_block"] for k in self._kinds}),
                   prompt_buckets=list(self.prompt_buckets),
                   prompt_forms=self._prompt_forms(),
                   expert_plans=self._expert_plans(),
@@ -634,13 +655,14 @@ class GenerationScheduler:
             # post-payload (deadlocked before this ordering: leader in the
             # alloc allgather, follower in the header broadcast).
             self._ensure_cache()
-            first = self._launch_prefill([slot], payload, form)
+            first = self._launch_prefill([slot], payload, form, bucket)
         with tl.phase("prefill.fetch"):
             first_tok = int(np.asarray(first)[0])
             self._set_slot(slot, first_tok, payload, 0, req.max_new)
             self.device_rounds += 1
 
-    def _launch_prefill(self, slots: list[int], payload: dict, form: str):
+    def _launch_prefill(self, slots: list[int], payload: dict, form: str,
+                        bucket: int):
         """One prefill dispatch over the pool, which it donates: the
         payload's prompts into ``slots`` (one a row of the payload)."""
         first, *cache = self._prefill(self.params, self._cache,
@@ -648,6 +670,7 @@ class GenerationScheduler:
         self._cache = tuple(cache)
         self.prefill_dispatches += 1
         self.prefill_kernel_dispatches += form == "kernel"
+        self.prefill_buckets[bucket] += len(slots)
         return first
 
     def _set_slot(self, slot: int, first_tok: int, payload: dict, j: int,
@@ -733,7 +756,7 @@ class GenerationScheduler:
             }
             self._ensure_cache()
             return self._launch_prefill(slots + slots[:1] * (Bp - B),
-                                        batched, form), batched
+                                        batched, form, bucket), batched
 
     def _launch_segment(self):
         """Launch one decode segment over the whole pool (dispatch thread).
@@ -781,11 +804,16 @@ class GenerationScheduler:
             # count (a capture of 8 rounds read a share 12% high by it).
             live = ~self._finished
             at = np.minimum(self._pos[live], self.total - 1)
-            first, last = self._rows.span(at, self.rows)
-            held = int((last - first + 1).sum())
-            summaries = int(self._rows.summaries(at, self.rows).sum())
-            rb = self.read_block
-            read = float(((last // rb - first // rb + 1) * rb).sum())
+            held, firsts, summaries, live_rows, read = [], [], 0, 0, 0.0
+            for k in self._kinds:
+                first, last = k["rows"].span(at, k["count"])
+                held.append(int((last - first + 1).sum()))
+                firsts.append(first)
+                summaries += int(k["rows"].summaries(at, k["count"]).sum())
+                rb = k["read_block"]
+                live_rows += k["layers"] * held[-1]
+                read += k["layers"] * float(
+                    ((last // rb - first // rb + 1) * rb).sum())
             inflight, self._inflight = self._inflight, None
             # The round's one blocking wait: [S, seg + 4 + C], emits, the
             # carries, then the model's counts (``build_gen_kernels``);
@@ -799,18 +827,22 @@ class GenerationScheduler:
             self._finished = packed[:, seg + 3] != 0
             for k, name in enumerate(self.counter_sums):
                 self.counter_sums[name] += int(packed[0, seg + 4 + k])
-            self.window_rolls += int((self._rows.span(np.minimum(
-                self._pos[live], self.total - 1), self.rows)[0]
-                != first).sum())
+            after = np.minimum(self._pos[live], self.total - 1)
+            self.window_rolls += sum(
+                int((k["rows"].span(after, k["count"])[0] != first).sum())
+                for k, first in zip(self._kinds, firsts))
             n, done = _retire(emits, live, self._budget, self.eos_id)
             self._budget -= n
             self._finished[done] = True
             self._tok[done] = self.eos_id
-            self.span_rows_sum += held
+            self.span_rows_sum += sum(held)
+            for k, rows in zip(self._kinds, held):
+                if k["name"] in self.span_rows_by_kind:
+                    self.span_rows_by_kind[k["name"]] += rows
             self.summary_rows_sum += summaries
-            self.live_positions_sum += int(at.sum()) + len(at)
-            self.kv_live_sum += held / (self.slots * self.rows)
-            self.kv_read_sum += read / (self.slots * self.rows)
+            self.live_positions_sum += (int(at.sum()) + len(at)) * len(held)
+            self.kv_live_sum += live_rows / self._pool_rows
+            self.kv_read_sum += read / self._pool_rows
             self.device_rounds += 1
             self.segment_rounds += 1
             free = len(self._free) + int(done.sum())
@@ -892,6 +924,8 @@ class GenerationScheduler:
                 "chained_rounds": self.chained_rounds,
                 "prefill_dispatches": self.prefill_dispatches,
                 "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
+                "prefill_buckets": {str(b): n for b, n
+                                    in self.prefill_buckets.items()},
                 "tokens_emitted": self.tokens_emitted,
                 "kv_live_share": {"sum": round(self.kv_live_sum, 6),
                                   "count": self.segment_rounds},
@@ -904,6 +938,10 @@ class GenerationScheduler:
                 "live_positions": {"sum": self.live_positions_sum,
                                    "count": self.segment_rounds},
                 "window_rolls": self.window_rolls,
+                **({"span_rows_by_kind": {
+                    name: {"sum": total, "count": self.segment_rounds}
+                    for name, total in self.span_rows_by_kind.items()}}
+                   if self.span_rows_by_kind else {}),
                 **{name: {"sum": total, "count": self.segment_rounds}
                    for name, total in self.counter_sums.items()},
                 **({"step_counters": self._counters}

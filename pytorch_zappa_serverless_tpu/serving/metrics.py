@@ -787,6 +787,11 @@ class MetricsHub:
                 metric(f"tpuserve_{key}_total", "counter", what,
                        [({"model": m}, s[key]) for m, s in gsnap.items()
                         if s.get(key) is not None])
+            metric("tpuserve_prefill_bucket_prompts_total", "counter",
+                   "Prompts prefilled (a batch's padding with them) by the "
+                   "bucket of their dispatch (slot lanes)",
+                   [({"model": m, "bucket": b}, n) for m, s in gsnap.items()
+                    for b, n in s.get("prefill_buckets", {}).items()])
             # How much of the slot pool decode attention has to read
             # (live), and how much its copies cover (read), per segment round
             # (slot lanes): _sum / _count is the mean share.
@@ -819,6 +824,23 @@ class MetricsHub:
                     label = f'{{model="{_prom_label(m)}"}}'
                     lines.append(f"tpuserve_{key}_sum{label} {v['sum']}")
                     lines.append(f"tpuserve_{key}_count{label} {v['count']}")
+            # A lane whose model keeps more than one kind of K/V layer: the
+            # rows its spans hold, a kind.
+            by_kind = [(m, kind, v) for m, s in gsnap.items()
+                       for kind, v in s.get("span_rows_by_kind", {}).items()]
+            if by_kind:
+                lines.append("# HELP tpuserve_span_rows_by_kind Cache rows "
+                             "the generating slots' spans hold in one layer "
+                             "of a kind, per segment round")
+                lines.append("# TYPE tpuserve_span_rows_by_kind summary")
+                for m, kind, v in by_kind:
+                    label = (f'{{model="{_prom_label(m)}",'
+                             f'kind="{_prom_label(kind)}"}}')
+                    lines.append(
+                        f"tpuserve_span_rows_by_kind_sum{label} {v['sum']}")
+                    lines.append(
+                        f"tpuserve_span_rows_by_kind_count{label} "
+                        f"{v['count']}")
         if self.adapters is not None and self.adapters.enabled:
             # Multi-tenant adapters (serving/adapters.py; docs/ADAPTERS.md):
             # per-tenant residency gauge, attach-latency histograms, and the

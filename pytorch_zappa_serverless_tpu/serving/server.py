@@ -1756,7 +1756,9 @@ class Server:
             rows of the very rounds whose device time the capture holds."""
             keys = ("span_rows", "summary_rows", "live_positions")
             return {name: {k: snap[k] for k in keys
-                           + tuple(sched.counter_sums)}
+                           + tuple(sched.counter_sums)
+                           + tuple({"span_rows_by_kind", "prefill_buckets"}
+                                   & set(snap))}
                     for name, sched in self.schedulers.items()
                     for snap in [sched.gen_snapshot()] if keys[0] in snap}
 

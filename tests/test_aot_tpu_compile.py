@@ -14,6 +14,7 @@ reaches it.  The persistent compile cache is switched off around the cases:
 a TPU executable written here cannot be read back without a chip.
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
@@ -725,3 +726,114 @@ def test_lfm2_programs_compile_for_v5e_and_hold_no_score_array(
         shape = [int(d) for d in dims.split(",")]
         assert not (32 in shape and shape.count(P) >= 2), shape
     assert done.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("p,window", [(4096, 1024), (16384, 1024),
+                                      (16384, None)])
+def test_flash_attention_with_a_group_and_a_band_compiles_for_v5e(
+        one_chip, p, window):
+    """Mellum 2's prompt attention: 32 queries over 4 K/V heads of 128 read
+    through the tile map (K and V are not repeated), with the band of a
+    window layer and without."""
+    text = _compile(
+        functools.partial(flash_attention, causal=True, window=window,
+                          interpret=False),
+        one_chip, ((1, p, 32, 128), jnp.bfloat16),
+        ((1, p, 4, 128), jnp.bfloat16), ((1, p, 4, 128), jnp.bfloat16))
+    assert "flash_attention" in text and text.count("tpu_custom_call") == 1
+
+
+def _mellum_shapes(cfg, sd):
+    """Mellum 2's parameter tree as shapes (``sd(*shape, dtype=)``)."""
+    D = cfg.hidden_size
+
+    def vec(n=D):
+        return sd(n, dtype=jnp.float32)
+
+    q, kv = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    E, F = cfg.experts_held, cfg.expert_width
+    params = {"embed": sd(cfg.vocab_size, D), "head": sd(D, cfg.vocab_size),
+              "norm": vec()}
+    for i in range(len(cfg.layer_types)):
+        params[f"layer{i}"] = {
+            "input_norm": vec(), "post_attention_norm": vec(),
+            "q": sd(D, q), "k": sd(D, kv), "v": sd(D, kv), "o": sd(q, D),
+            "q_norm": vec(cfg.head_dim), "k_norm": vec(cfg.head_dim),
+            "router": sd(D, cfg.experts_published),
+            "w1": sd(E, D, F), "w3": sd(E, D, F), "w2": sd(E, F, D)}
+    return params
+
+
+@pytest.mark.parametrize("program", ["segment", "prefill"])
+def test_mellum_programs_compile_for_v5e_and_hold_no_score_array(
+        one_chip, monkeypatch, program):
+    """The benchmark's eight layers of Mellum 2 at the published widths,
+    with the kernels a chip takes (the pickers ask the backend, which is the
+    CPU here, so the test steers them).  The 32-slot segment over both leaf
+    pairs (17,408 rows a full layer, a ring of 1,024 a window layer):
+    ``decode_attention`` once a layer, each kind over its own work list, and
+    ``expert_matmul`` twice a layer; temporaries under 0.3 GB (10.3 GB of
+    weights and pool are arguments).  The prefill of one prompt of 16,384
+    positions: ``flash_attention`` once a layer, so no float32 array with
+    the 32 heads and two dimensions of 16,384 (``[1, 32, P, P]`` is 34 GB),
+    and temporaries under 3.5 GB: what is left of 16 GB beside 10.3 and the
+    runtime's own."""
+    import re
+
+    from pytorch_zappa_serverless_tpu.models import mellum
+    from pytorch_zappa_serverless_tpu.ops import (
+        expert_matmul as expert_matmul_module)
+
+    cfg = mellum.config_from_arch(
+        {"layer_types": mellum.PUBLISHED.layer_types[:8], "eos_id": 98304})
+    slots, P, total = 32, 16384, 16384 + 768
+    monkeypatch.setattr(
+        decode_attention_module, "_kernel_block",
+        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
+                                     if Tq == 1 else None))
+    monkeypatch.setattr(expert_matmul_module, "_use_kernel", lambda: True)
+    monkeypatch.setattr(mellum, "_on_chip", lambda: True)
+    monkeypatch.setattr(mellum, "flash_attention", functools.partial(
+        flash_attention, interpret=False))
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = _mellum_shapes(cfg, sd)
+    fam = mellum.family(cfg)
+    leaves = [sd(*shape, dtype=dt) for shape, dt in decoder.cache_leaves(
+        fam, slots, fam.rows.count(total), jnp.bfloat16)]
+    assert [leaf.shape for leaf in leaves] == [
+        (2, 32, 17408, 512)] * 2 + [(6, 32, 1024, 512)] * 2
+    if program == "segment":
+        i32, f32 = sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.float32)
+
+        def segment(p, *rest):
+            pool, state = decoder.slot_pools(fam, rest[:4])
+            tok, pos, st, fin, temp, seeds, topk, topp = rest[4:]
+            return decoder.decode_segment(
+                fam, p, pool, tok, pos, st, fin, temp, seeds, 8,
+                jnp.bfloat16, top_k=topk, top_p=topp, state=state)
+
+        done = jax.jit(segment, donate_argnums=(1, 2, 3, 4)).lower(
+            params, *leaves, i32, i32, i32, sd(slots, dtype=jnp.bool_),
+            f32, i32, i32, f32).compile()
+        text = done.as_text()
+        assert text.count("decode_attention") >= 2
+        assert text.count("tpu_custom_call") == 3 * 8
+        assert done.memory_analysis().temp_size_in_bytes < 0.3e9
+        return
+    done = jax.jit(
+        lambda p, fk, fv, rk, rv, at, tokens, lengths: decoder.prefill(
+            fam, p, tokens, lengths, (fk, fv, rk, rv), at, jnp.bfloat16),
+        donate_argnums=(1, 2, 3, 4)).lower(
+            params, *leaves, sd(1, dtype=jnp.int32),
+            sd(1, P, dtype=jnp.int32), sd(1, dtype=jnp.int32)).compile()
+    text = done.as_text()
+    assert text.count("tpu_custom_call") == 3 * 8
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        shape = [int(d) for d in dims.split(",")]
+        assert not (32 in shape and shape.count(P) >= 2), shape
+    temp = done.memory_analysis().temp_size_in_bytes
+    print(f"mellum 16,384 prefill temporaries: {temp / 1e9:.2f} GB")
+    assert temp < 3.5e9

@@ -425,3 +425,37 @@ def test_lfm2_declares_its_leaves_and_its_counters():
     assert D.cache_leaves(fam, 32, T, jnp.bfloat16) == (
         ((2, 32, 8704, 512), jnp.bfloat16), ((2, 32, 8704, 512), jnp.bfloat16),
         ((8, 32, 2, 2048), jnp.bfloat16))
+
+
+def test_mellum_declares_two_kinds_and_four_leaves():
+    """The first family whose K/V layers keep their rows in two ways: a K
+    and a V leaf a kind, the full layers' first, each with its own rows a
+    slot, and a layer finds its pair by ``(kind, index)``."""
+    from pytorch_zappa_serverless_tpu.models import mellum
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul
+
+    cfg = mellum.config_from_arch(
+        {"layer_types": mellum.PUBLISHED.layer_types[:8]})
+    fam = mellum.family(cfg)
+    assert (fam.layers, fam.kv_heads, fam.heads, fam.width) == (8, 4, 32, 512)
+    assert [(k.name, k.layers, type(k.rows).__name__) for k in fam.kinds] == [
+        ("full_attention", 2, "FullRows"),
+        ("sliding_attention", 6, "RingRows")]
+    assert fam.rows is fam.kinds[0].rows and not fam.state
+    assert fam.positions is None and fam.counters == expert_matmul.COUNTERS
+    assert [fam.cache_index(i) for i in range(8)] == [
+        (1, 0), (1, 1), (1, 2), (0, 0), (1, 3), (1, 4), (1, 5), (0, 1)]
+    full = ((2, 32, 17408, 512), jnp.bfloat16)
+    ring = ((6, 32, 1024, 512), jnp.bfloat16)
+    # Whether it is handed the positions or the full kind's rows.
+    for T in (16384 + 768, fam.rows.count(16384 + 768)):
+        assert D.cache_leaves(fam, 32, T, jnp.bfloat16) == (
+            full, full, ring, ring)
+    # A family that declares no kinds has the one pair it always had.
+    assert D.cache_leaves(TOY, 2, 28, jnp.float32) == (
+        ((LAYERS, 2, 28, WIDTH), jnp.float32),) * 2
+    pool, state = D.slot_pools(fam, tuple(
+        jnp.zeros((n, 2, t, 8)) for n, t in ((2, 40), (2, 40), (6, 8),
+                                             (6, 8))))
+    assert [p.k.shape for p in pool] == [(2, 2, 40, 8), (6, 2, 8, 8)]
+    assert state == () and pool[1].positions > pool[0].positions == 40

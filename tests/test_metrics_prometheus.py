@@ -124,6 +124,10 @@ def _loaded_hub():
                  "kv_live_share": {"sum": 0.93, "count": 5},
                  "kv_read_share": {"sum": 1.25, "count": 5},
                  "span_rows": {"sum": 3561, "count": 5},
+                 "span_rows_by_kind": {
+                     "full_attention": {"sum": 2537, "count": 5},
+                     "sliding_attention": {"sum": 1024, "count": 5}},
+                 "prefill_buckets": {"512": 3, "768": 1},
                  "summary_rows": {"sum": 768, "count": 5},
                  "live_positions": {"sum": 14900, "count": 5},
                  "expert_assignments_held": {"sum": 7040, "count": 5},
