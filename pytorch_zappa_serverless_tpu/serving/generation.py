@@ -17,7 +17,14 @@ answer to both, built so every device program keeps static shapes:
   **admit** into free slots: a per-prompt-bucket ``prefill`` takes the pool
   and the slots its prompts were given and writes their K, V and state
   where a decode step will read them, while other slots ride along
-  untouched.  When
+  untouched.  A round's admissions are planned as a whole, by the rows
+  the plan pads (``plan_prefills``): a dispatch multiplies its batch, padded
+  to a power of two, times its bucket, and is ragged by its lengths, so a
+  prompt may ride in a longer bucket's dispatch where that makes the sum
+  less, (1) only in a bucket that holds a prompt of its own this round, (2)
+  with no more dispatches than one a bucket (split by the family's
+  ``prefill_batch``) would make, (3) with no padded batch larger than that
+  rule's largest, (4) ties keeping a dispatch a bucket.  When
   nothing could be admitted anyway, the call that fetched a segment launches
   the next one before it returns, and the tokens are fanned out while the
   device works (``GenerationScheduler._segment_sync``; docs/GENERATION.md).
@@ -63,6 +70,98 @@ log = get_logger("serving.generation")
 
 def _pow2(n: int) -> int:
     return 1 << (int(n) - 1).bit_length()
+
+
+def _bucket_of(n: int, buckets) -> int:
+    """The shortest of ``buckets`` (ascending) that holds ``n`` positions."""
+    for b in buckets:
+        if b >= n:
+            return b
+    raise ValueError(f"prompt of {n} tokens exceeds the largest bucket "
+                     f"{buckets[-1]}")
+
+
+def plan_prefills(lengths, buckets,
+                  prefill_batch) -> list[tuple[int, list[int]]]:
+    """A round's prefill dispatches, ``[(bucket, indices into lengths)]``:
+    the assignment of the admitted prompts that pads the fewest rows, the
+    sum over dispatches of ``_pow2(prompts) * bucket``.
+
+    ``lengths`` are the admitted prompts' in admission order, ``buckets`` the
+    lane's (ascending), ``prefill_batch(bucket)`` the prompts one dispatch of
+    that bucket may hold (None: all of them).  The per-bucket plan puts each
+    prompt in its own bucket, one dispatch a bucket split by
+    ``prefill_batch``; a prefill is ragged by its lengths, so a prompt may
+    as well ride in a longer bucket's dispatch, under four rules:
+
+    1. only a bucket that holds a prompt of its own this round is used;
+    2. a bucket's prompts are split by ``prefill_batch`` as ever, and the
+       plan makes no more dispatches than the per-bucket plan;
+    3. no padded batch is larger than the per-bucket plan's largest;
+    4. ties go to the per-bucket plan, then to fewer dispatches, then to
+       fewer prompts moved; a bucket's longest prompts are the ones to move.
+
+    Groups that are powers of two pad nothing and keep the per-bucket plan
+    (a prompt costs its own bucket at least), as does a single bucket.  The
+    search is over how many prompts stay in each bucket, shortest bucket
+    first: those that do not stay ride on to the next bucket in use.
+    """
+    members: dict[int, list[int]] = {}
+    for i, n in enumerate(lengths):
+        members.setdefault(_bucket_of(n, buckets), []).append(i)
+
+    def sizes(bucket: int, n: int) -> list[int]:
+        """The padded batches of the dispatches ``n`` prompts make in
+        ``bucket``."""
+        cap = prefill_batch(bucket) or max(n, 1)
+        return [_pow2(cap)] * (n // cap) + [_pow2(n % cap)] * (n % cap > 0)
+
+    used = sorted(members)
+    counts = [len(members[b]) for b in used]
+    today = [sizes(b, n) for b, n in zip(used, counts)]
+    stay = counts
+    if len(used) > 1 and sum(map(sum, today)) > len(lengths):  # pads a row
+        widest = max(map(max, today))
+        most = sum(map(len, today))
+        # (rows, moved at all, dispatches, prompts moved), and who stays.
+        best = [(sum(b * sum(ss) for b, ss in zip(used, today)), False, most,
+                 0), counts]
+
+        def search(i: int, carry: int, rows: int, made: int, moved: int,
+                   stays: list[int]) -> None:
+            have = counts[i] + carry
+            last = i == len(used) - 1
+            for s in [have] if last else range(have, -1, -1):
+                ss = sizes(used[i], s)
+                at = (rows + used[i] * sum(ss), made + len(ss),
+                      moved + max(counts[i] - s, 0))
+                if (at[0] > best[0][0] or at[1] > most
+                        or max(ss, default=0) > widest):
+                    continue
+                if not last:
+                    search(i + 1, have - s, *at, stays + [s])
+                elif (key := (at[0], at[2] > 0, at[1], at[2])) < best[0]:
+                    best[:] = key, stays + [s]
+
+        search(0, 0, 0, 0, 0, [])
+        stay = best[1]
+    # Who rides: of a bucket's own prompts the longest, and of those riding
+    # past a bucket the shortest stop there.
+    planned: dict[int, list[int]] = {}
+    riding: list[int] = []
+    for b, s in zip(used, stay):
+        own = sorted(members[b], key=lambda i: (lengths[i], i))
+        if s <= len(own):
+            planned[b] = own[:s]
+            riding = sorted(riding + own[s:], key=lambda i: (lengths[i], i))
+        else:
+            planned[b] = own + riding[:s - len(own)]
+            riding = riding[s - len(own):]
+    return [(b, group[i:i + n])
+            for b in members  # in the order the round met its buckets
+            for group in [sorted(planned[b])] if group
+            for n in [prefill_batch(b) or len(group)]
+            for i in range(0, len(group), n)]
 
 
 def slot_program(phase: str, attrs: dict) -> tuple[str, dict] | None:
@@ -501,6 +600,12 @@ class GenerationScheduler:
         # thread, and an entry that only changes its value is safe there.)
         self.prefill_buckets: dict[int, int] = {  # guarded-by: dispatch-serialized
             int(b): 0 for b in meta["prompt_buckets"]}
+        # What the dispatches multiplied (padded batch x bucket) beside the
+        # positions their prompts hold, and the prompts that rode in a
+        # longer bucket's dispatch than their own (``plan_prefills``).
+        self.prefill_rows_padded = 0  # guarded-by: dispatch-serialized
+        self.prefill_rows_prompt = 0  # guarded-by: dispatch-serialized
+        self.prompts_moved_up = 0     # guarded-by: dispatch-serialized
         self._prompt_form = meta.get("prompt_form",
                                      lambda batch, bucket: "own")
         # The pool: the model's cache leaves, K and V first.
@@ -630,11 +735,7 @@ class GenerationScheduler:
             self._cache = self._alloc_cache()
 
     def _bucket_for(self, n: int) -> int:
-        for b in self.prompt_buckets:
-            if b >= n:
-                return b
-        raise ValueError(f"prompt of {n} tokens exceeds the largest bucket "
-                         f"{self.prompt_buckets[-1]}")
+        return _bucket_of(n, self.prompt_buckets)
 
     def _admit_sync(self, req: GenRequest, slot: int):
         """Prefill one request into its slot of the pool (dispatch thread)."""
@@ -644,7 +745,8 @@ class GenerationScheduler:
         bucket = self._bucket_for(n)
         form = self._prompt_form(1, bucket)
         with tl.phase("prefill.launch", programs=1, batch=1, bucket=bucket,
-                      windows=req.prefill_windows, form=form, slots=str(slot)):
+                      windows=req.prefill_windows, form=form, moved=0,
+                      slots=str(slot)):
             payload = self._collate_admit(req.sample, bucket)
             if self.lockstep is not None:
                 self.lockstep.lead_gen_admit(self.name, slot, bucket, payload)
@@ -655,22 +757,27 @@ class GenerationScheduler:
             # post-payload (deadlocked before this ordering: leader in the
             # alloc allgather, follower in the header broadcast).
             self._ensure_cache()
-            first = self._launch_prefill([slot], payload, form, bucket)
+            first = self._launch_prefill([slot], payload, form, bucket, n)
         with tl.phase("prefill.fetch"):
             first_tok = int(np.asarray(first)[0])
             self._set_slot(slot, first_tok, payload, 0, req.max_new)
             self.device_rounds += 1
 
     def _launch_prefill(self, slots: list[int], payload: dict, form: str,
-                        bucket: int):
+                        bucket: int, prompt_rows: int, moved: int = 0):
         """One prefill dispatch over the pool, which it donates: the
-        payload's prompts into ``slots`` (one a row of the payload)."""
+        payload's prompts into ``slots`` (one a row of the payload), of
+        ``prompt_rows`` positions between them, ``moved`` of them from a
+        shorter bucket."""
         first, *cache = self._prefill(self.params, self._cache,
                                       np.asarray(slots, np.int32), payload)
         self._cache = tuple(cache)
         self.prefill_dispatches += 1
         self.prefill_kernel_dispatches += form == "kernel"
         self.prefill_buckets[bucket] += len(slots)
+        self.prefill_rows_padded += len(slots) * bucket
+        self.prefill_rows_prompt += prompt_rows
+        self.prompts_moved_up += moved
         return first
 
     def _set_slot(self, slot: int, first_tok: int, payload: dict, j: int,
@@ -745,8 +852,10 @@ class GenerationScheduler:
         Bp = _pow2(B)
         form = self._prompt_form(Bp, bucket)
         slots = [slot for _, slot, _ in group]
+        lens = [self._admit_len_of(req.sample) for req, _, _ in group]
+        moved = sum(self._bucket_for(n) < bucket for n in lens)
         with tl.phase("prefill.launch", programs=1, batch=B, bucket=bucket,
-                      windows=max(windows), form=form,
+                      windows=max(windows), form=form, moved=moved,
                       slots=" ".join(map(str, slots))):
             payloads = [p for _, _, p in group]
             batched = {
@@ -756,7 +865,8 @@ class GenerationScheduler:
             }
             self._ensure_cache()
             return self._launch_prefill(slots + slots[:1] * (Bp - B),
-                                        batched, form, bucket), batched
+                                        batched, form, bucket, sum(lens),
+                                        moved), batched
 
     def _launch_segment(self):
         """Launch one decode segment over the whole pool (dispatch thread).
@@ -926,6 +1036,9 @@ class GenerationScheduler:
                 "prefill_kernel_dispatches": self.prefill_kernel_dispatches,
                 "prefill_buckets": {str(b): n for b, n
                                     in self.prefill_buckets.items()},
+                "prefill_rows_padded": self.prefill_rows_padded,
+                "prefill_rows_prompt": self.prefill_rows_prompt,
+                "prompts_moved_up": self.prompts_moved_up,
                 "tokens_emitted": self.tokens_emitted,
                 "kv_live_share": {"sum": round(self.kv_live_sum, 6),
                                   "count": self.segment_rounds},
@@ -972,6 +1085,40 @@ class GenerationScheduler:
         self._active.clear()
         self._pending.clear()
 
+    def _plan_round(self, admits: list) -> list[tuple[int, list]]:
+        """The round's prefill dispatches ``[(bucket, [(req, slot,
+        payload)])]`` for the requests just given slots: planned by the rows
+        they pad (:func:`plan_prefills`) before any payload is collated, each
+        payload then collated to the bucket of its dispatch.  A bad sample
+        fails only itself and gives its slot back."""
+        def fail(req, slot, e):
+            self._free.append(slot)
+            req.finish(error=f"{type(e).__name__}: {e}")
+
+        sized = []
+        for req, slot in admits:
+            try:
+                n = self._admit_len_of(req.sample)
+                self._bucket_for(n)
+            except Exception as e:
+                fail(req, slot, e)
+                continue
+            sized.append((req, slot, n))
+        group_list = []
+        for bucket, members in plan_prefills(
+                [n for _, _, n in sized], self.prompt_buckets,
+                self._rows.prefill_batch):
+            group = []
+            for req, slot, _ in map(sized.__getitem__, members):
+                try:
+                    group.append((req, slot,
+                                  self._collate_admit(req.sample, bucket)))
+                except Exception as e:
+                    fail(req, slot, e)
+            if group:
+                group_list.append((bucket, group))
+        return group_list
+
     # -- the loop -----------------------------------------------------------
     async def _loop(self):
         tl = self.timeline
@@ -993,9 +1140,10 @@ class GenerationScheduler:
             round_no = tl.begin_round(active=len(self._active))
             # Admit into free slots (prefill runs on the dispatch thread, so
             # it serializes with segments and other models' traffic).
-            # Single-host, >1 admissible: same-bucket admissions coalesce
-            # into ONE batched prefill dispatch (_admit_batch_sync); the
-            # lockstep leader keeps the proven per-admission broadcast.
+            # Single-host, >1 admissible: the round's admissions coalesce
+            # into one batched prefill dispatch a bucket in use, or fewer
+            # (_plan_round, _admit_batch_sync); the lockstep leader keeps the
+            # proven per-admission broadcast.
             with tl.phase("round.admit_host"):
                 admits: list[tuple[GenRequest, int]] = []
                 if not inflight or not self._free:
@@ -1005,30 +1153,11 @@ class GenerationScheduler:
                         req = self._pending.popleft()
                         req.note_slotted(t_top, round_no)
                         admits.append((req, self._free.pop()))
-                groups: dict[int, list] = {}
-                for req, slot in admits:
-                    if self.lockstep is None:
-                        try:
-                            bucket = self._bucket_for(
-                                self._admit_len_of(req.sample))
-                            payload = self._collate_admit(req.sample, bucket)
-                        except Exception as e:  # bad sample fails only itself
-                            self._free.append(slot)
-                            req.finish(error=f"{type(e).__name__}: {e}")
-                            continue
-                        groups.setdefault(bucket, []).append(
-                            (req, slot, payload))
-                    else:
-                        groups.setdefault(-1 - slot, []).append(
-                            (req, slot, None))
-                # A dispatch holds as many prompts of a bucket as the model
-                # says one may (all of them, where it says nothing).
-                group_list = [
-                    (bucket, group[i:i + n])
-                    for bucket, group in groups.items()
-                    for n in [(self._rows.prefill_batch(bucket) if bucket >= 0
-                               else None) or len(group)]
-                    for i in range(0, len(group), n)]
+                # Single host: the round's dispatches, planned as a whole.
+                # The leader's stay one a request, keyed below zero.
+                group_list = (
+                    self._plan_round(admits) if self.lockstep is None else
+                    [(-1 - slot, [(req, slot, None)]) for req, slot in admits])
             for gi, (bucket, group) in enumerate(group_list):
                 # The next group's prefill is launched behind this one's
                 # (single host; the leader's admissions stay one by one).

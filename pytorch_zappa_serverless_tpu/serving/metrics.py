@@ -783,7 +783,14 @@ class MetricsHub:
                                "launched per model (slot lanes)"),
                               ("prefill_kernel_dispatches", "Those of them "
                                "whose prompt attention took the Pallas "
-                               "kernel (ops/flash_attention.prompt_form)")):
+                               "kernel (ops/flash_attention.prompt_form)"),
+                              ("prefill_rows_padded", "Rows those programs "
+                               "multiplied: padded batch x bucket, summed"),
+                              ("prefill_rows_prompt", "Positions the prompts "
+                               "in them hold"),
+                              ("prompts_moved_up", "Prompts prefilled in a "
+                               "longer bucket's dispatch than their own "
+                               "(serving/generation.plan_prefills)")):
                 metric(f"tpuserve_{key}_total", "counter", what,
                        [({"model": m}, s[key]) for m, s in gsnap.items()
                         if s.get(key) is not None])

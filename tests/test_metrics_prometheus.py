@@ -16,6 +16,8 @@ import re
 from pathlib import Path
 from types import SimpleNamespace
 
+import pytest
+
 from pytorch_zappa_serverless_tpu.config import ModelConfig, ServeConfig
 from pytorch_zappa_serverless_tpu.engine.runner import DeviceRunner
 from pytorch_zappa_serverless_tpu.faults import FaultInjector
@@ -128,6 +130,8 @@ def _loaded_hub():
                      "full_attention": {"sum": 2537, "count": 5},
                      "sliding_attention": {"sum": 1024, "count": 5}},
                  "prefill_buckets": {"512": 3, "768": 1},
+                 "prefill_rows_padded": 3584, "prefill_rows_prompt": 1700,
+                 "prompts_moved_up": 1,
                  "summary_rows": {"sum": 768, "count": 5},
                  "live_positions": {"sum": 14900, "count": 5},
                  "expert_assignments_held": {"sum": 7040, "count": 5},
@@ -491,6 +495,23 @@ def test_a_lane_s_own_step_counters_render_under_the_names_it_gives():
             "round") in text
     assert 'tpuserve_rows_skipped_sum{model="gpt2"} 9' in text
     assert 'tpuserve_experts_touched_count{model="gpt2"} 5' in text
+
+
+@pytest.mark.parametrize("family, value", [
+    ("tpuserve_prefill_rows_padded_total", 3584),
+    ("tpuserve_prefill_rows_prompt_total", 1700),
+    ("tpuserve_prompts_moved_up_total", 1)])
+def test_a_round_s_prefill_plan_renders_beside_the_generation_counters(
+        family, value):
+    """What a slot lane's prefill dispatches multiplied, what their prompts
+    hold and how many rode in a longer bucket (ISSUE 53), as counters a
+    model beside ``tpuserve_prefill_dispatches_total``."""
+    text = _loaded_hub().render_prometheus()
+    assert f"# TYPE {family} counter" in text
+    assert f'{family}{{model="gpt2"}} {value}' in text
+    assert text.index("# TYPE tpuserve_prefill_dispatches_total") \
+        < text.index(f"# TYPE {family}") \
+        < text.index("# TYPE tpuserve_prefill_bucket_prompts_total")
 
 
 def test_device_memory_gauge_renders_where_the_backend_counts(monkeypatch):
