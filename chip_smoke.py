@@ -1896,6 +1896,129 @@ def time_gated_experts(rows_an_expert, on_device: bool, interpret: bool,
     return out
 
 
+def time_expert_unsort(tokens: int, top_k: int, width: int, experts: int,
+                       on_device: bool, interpret: bool):
+    """The ``tiles`` regime's way round its two grouped calls, alone, for
+    ``tokens`` rows routed ``top_k`` ways over ``experts`` experts of
+    ``width`` (a router's draw: ``top_k`` distinct experts a row):
+
+    - ``expert_combine`` by its name, device microseconds a call beside the
+      share of 819 GB/s (every gathered row in, the result out; it reads
+      above 1 where the compiler keeps the chained calls' result in VMEM,
+      75 MB at 8,192 rows of 2,304), and its largest difference from the
+      form it replaced;
+    - the whole un-sort, the gather and what follows it, in both forms:
+      ``combine``, and ``einsum`` (a cast of every gathered row to float32,
+      ``einsum("knd,kn->nd")`` and a select, as until PR 54);
+    - the two gathers (``in``: the rows laid out for the first call;
+      ``out``: the second call's rows read back), each in the forms XLA
+      offers for the same rows: as :func:`experts` writes it, with
+      ``promise_in_bounds``, with the indices sorted (and declared so: what
+      the order itself costs, no form the layer could take), and the rows
+      viewed as 32-bit words; nanoseconds a gathered row.
+
+    Every array is an argument of the timed program, and each call's
+    indices or weights hang on the call before it, so that nothing is folded
+    at compile time or lifted out of the chain.  Off the device no time."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul as em
+
+    rng = np.random.default_rng(SEED)
+    A = tokens * top_k
+    tile = em.plan(A, width, width, experts).tile
+    group = jnp.asarray(np.argsort(rng.random((tokens, experts)),
+                                   axis=1)[:, :top_k], jnp.int32)
+    sizes = em.group_sizes(group, experts)
+    src, back = em.sorted_places(jnp.argsort(group.reshape(-1), stable=True),
+                                 sizes, top_k, tile)
+    back = back.T.reshape(-1)
+    u = jnp.asarray(rng.standard_normal((tokens, width)), jnp.bfloat16)
+    y = jnp.asarray(rng.standard_normal(
+        (em.laid_rows(src.shape[0] - tile, experts, tile), width)),
+        jnp.bfloat16)
+    w = jnp.asarray(rng.random((tokens, top_k)), jnp.float32)
+    shape = {"tokens": tokens, "top_k": top_k, "width": width}
+
+    def einsum_form(rows, w):
+        out = jnp.einsum("knd,kn->nd", rows.astype(jnp.float32), w.T)
+        return jnp.where(sizes.sum() > 0, out, 0)
+
+    def combine(rows, w):
+        return em.expert_combine(rows, w, interpret=interpret)
+
+    def unsort(then):
+        return lambda y, at, w: then(
+            y[at + (w[0, 0] < 0).astype(at.dtype)].reshape(
+                top_k, tokens, width), w)
+
+    def chained(step, *arrays):
+        """``step(*arrays, w) -> [tokens, width]`` sixteen times, each
+        call's weights from the call before."""
+        @jax.jit
+        def chain(w, *arrays):
+            for _ in range(_TIMED_CALLS):
+                w = w + step(*arrays, w)[:, :top_k] * 1e-9
+            return w
+        chain(w, *arrays).block_until_ready()
+        return lambda: chain(w, *arrays)
+
+    rows = y[back].reshape(top_k, tokens, width)
+    off = float(jnp.max(jnp.abs(combine(rows, w) - einsum_form(rows, w))))
+    assert off < 1e-4, off
+    moved = A * width * 2 + tokens * width * 4 + A * 4
+    out = [{"what": "expert_combine", **shape,
+            "rows_a_block": em.pick_combine_rows(top_k, width, 2),
+            "max_diff_from_einsum": round(off, 7)}]
+    if on_device:
+        us = _device_us(chained(combine, rows), "expert_combine")
+        out[0].update(us_a_call=us,
+                      share_of_819=round(moved / 819e9 * 1e6 / us, 3))
+    del rows
+    for form, then in (("combine", combine), ("einsum", einsum_form)):
+        row = {"what": "unsort", **shape, "form": form}
+        if on_device:
+            row["us_a_call"] = _busy_us(chained(unsort(then), y, back))
+        out.append(row)
+
+    def words(x):
+        return jax.lax.bitcast_convert_type(
+            x.reshape(x.shape[0], -1, 2), jnp.uint32)
+
+    forms = {
+        "as written": lambda x, i: x[i],
+        "promise_in_bounds": lambda x, i: x.at[i].get(
+            mode="promise_in_bounds"),
+        "sorted indices": lambda x, i: x.at[i].get(
+            mode="promise_in_bounds", indices_are_sorted=True),
+        "32-bit words": lambda x, i: jax.lax.bitcast_convert_type(
+            words(x).at[i].get(mode="promise_in_bounds"),
+            x.dtype).reshape(i.shape[0], -1)}
+    for name, x, at in (("in", u, src), ("out", y, back)):
+        for form, gather in forms.items():
+            idx = jnp.sort(at) if form == "sorted indices" else at
+            assert bool(jnp.all(gather(x, idx) == x[idx])), (name, form)
+
+            @jax.jit
+            def chain(x, idx):
+                for _ in range(_TIMED_CALLS):  # each waits for the last
+                    got = gather(x, idx)
+                    idx = idx + (got[0, 0] != got[0, 0]).astype(idx.dtype)
+                return idx
+
+            chain(x, idx).block_until_ready()
+            row = {"what": f"gather {name}", **shape, "form": form,
+                   "rows": [int(at.shape[0]), int(x.shape[0])]}
+            if on_device:
+                us = _busy_us(lambda: chain(x, idx))
+                row.update(us_a_call=us,
+                           ns_a_row=round(us * 1e3 / at.shape[0], 1))
+            out.append(row)
+    return out
+
+
 def _lfm2_child(rehearse: bool) -> None:
     """LFM2's kernels alone, then its programs against its plain reference,
     on one device, at the benchmark cell's widths (``benchmark/configs/
@@ -1966,6 +2089,11 @@ def _lfm2_child(rehearse: bool) -> None:
         inner=cfg.expert_width)
     for row in report["expert_matmul gated"]:
         print("lfm2 expert_matmul " + json.dumps(row), flush=True)
+    report["expert unsort"] = time_expert_unsort(
+        buckets[-1], cfg.top_k, cfg.hidden_size, cfg.experts_held, on_device,
+        interpret=rehearse)
+    for row in report["expert unsort"]:
+        print("lfm2 expert_unsort " + json.dumps(row), flush=True)
     kv, heads, dh = cfg.kv_heads, cfg.heads, cfg.head_dim
 
     def prompt_in(form):
@@ -2154,6 +2282,11 @@ def _mellum_child(rehearse: bool) -> None:
         inner=cfg.expert_width, kinds=("routed",))
     for row in report["expert_matmul gated"]:
         print("mellum expert_matmul " + json.dumps(row), flush=True)
+    report["expert unsort"] = time_expert_unsort(
+        buckets[1], cfg.top_k, cfg.hidden_size, cfg.experts_held, on_device,
+        interpret=rehearse)
+    for row in report["expert unsort"]:
+        print("mellum expert_unsort " + json.dumps(row), flush=True)
     report["expert_plans"] = {
         rows: em.plan_summary(rows // cfg.top_k, cfg.top_k, cfg.hidden_size,
                               cfg.expert_width, cfg.experts_held, True, 2)

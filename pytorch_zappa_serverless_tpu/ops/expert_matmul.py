@@ -44,6 +44,14 @@ they take (``Plan.vmem``: two buffers a matrix, the row tile and the output
 twice, the float32 products, 4 MiB besides; 16 MiB is what a kernel gets
 unasked, a v5e core has 128).
 
+The ``tiles`` regime's un-sort is a gather and one pass
+(:func:`expert_combine`, a kernel bound by its bytes): a row's ``top_k``
+rows are read where the gather left them, in bfloat16, weighed and summed in
+float32 in VMEM, and ``[N, K]`` float32 is all that is written.  The cast of
+every assignment row to float32 that ``einsum`` makes of the same sum, the
+largest array a prefill wrote, does not exist; a weight of 0 selects 0
+whatever lies in its row.
+
 ``interpret=True`` runs the kernel itself on the CPU for the tests.
 """
 
@@ -70,6 +78,10 @@ _MAX_TILE = 128
 _TILES_FROM = 128
 _TILES_BLOCK_BYTES = 13 << 20
 _ROW_ALIGN = 16  # rows: a bfloat16 tile of HBM, where a block may begin
+# Bytes a block of the gathered rows aims at in the un-sort's pass
+# (:func:`expert_combine`): two buffers of it, the output's two and the
+# float32 sum fit the 16 MiB a kernel is given unasked.
+_COMBINE_BLOCK_BYTES = 3 << 20
 
 
 def route(x, gate, bias, top_k: int, scale: float, offset: int, held: int,
@@ -213,13 +225,16 @@ def plan_summary(rows: int, top_k: int, K: int, F: int, held: int,
                  gated: bool, itemsize: int = 2) -> dict:
     """What :func:`experts` runs for ``rows`` rows routed ``top_k`` ways over
     ``held`` experts of [K, F] (and [F, K] back), for a log line: the regime,
-    the row tile, the blocks' columns of both calls, the grid's order, and
-    the most VMEM a call asks for (None: the default)."""
+    the row tile, the blocks' columns of both calls, the grid's order, the
+    most VMEM a call asks for (None: the default), and the un-sort's form:
+    ``combine`` (:func:`expert_combine`, the ``tiles`` regime's) or
+    ``einsum``."""
     up = plan(rows * top_k, K, F, held, 2 if gated else 1, itemsize)
     down = plan(rows * top_k, F, K, held, 1, itemsize)
     return {"regime": up.regime, "tile": up.tile,
             "blocks": [up.block_n, down.block_n], "grid": up.grid,
-            "vmem": max(up.vmem or 0, down.vmem or 0) or None}
+            "vmem": max(up.vmem or 0, down.vmem or 0) or None,
+            "unsort": "combine" if up.regime == "tiles" else "einsum"}
 
 
 def laid_rows(rows: int, groups: int, tile: int, align: int | None = None):
@@ -408,6 +423,50 @@ def expert_matmul_kernel(x, w, sizes, up=None, *, relu2: bool = False,
     return out[:M]
 
 
+def _combine_kernel(w_ref, y_ref, o_ref):
+    """A block of rows' weighted sum over their ``top_k`` gathered rows, in
+    float32.  A weight of 0 gives 0 whatever lies in its row: an assignment
+    held elsewhere reads a row that is not its own, and with no row held
+    anywhere one that was never written."""
+    acc = None
+    for k in range(y_ref.shape[0]):
+        w = w_ref[:, k:k + 1]
+        term = jnp.where(w != 0, w * y_ref[k].astype(jnp.float32), 0.0)
+        acc = term if acc is None else acc + term
+    o_ref[...] = acc
+
+
+def pick_combine_rows(top_k: int, K: int, itemsize: int) -> int:
+    """Rows of the result a block of :func:`expert_combine` holds: a power
+    of two from the bfloat16 sublane tile to 256 whose ``top_k`` gathered
+    rows each stay within ``_COMBINE_BLOCK_BYTES``."""
+    fits = max(_COMBINE_BLOCK_BYTES // (top_k * K * itemsize), 16)
+    return min(256, 1 << (fits.bit_length() - 1))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def expert_combine(y, weights, *, interpret: bool = False):
+    """The un-sort's one pass: y [top_k, N, K], a row's ``top_k`` gathered
+    rows as they lie, ``weights`` [N, top_k] float32 → ``sum_k weights[n, k]
+    y[k, n]`` [N, K] float32, each row of ``y`` read once and turned to
+    float32 where it is multiplied; nothing of ``top_k x N x K`` float32
+    elements is written.  Bound by its bytes."""
+    top_k, N, K = y.shape
+    tn = pick_combine_rows(top_k, K, y.dtype.itemsize)
+    return pl.pallas_call(
+        _combine_kernel,
+        out_shape=jax.ShapeDtypeStruct((N, K), jnp.float32),
+        grid=(pl.cdiv(N, tn),),
+        in_specs=[pl.BlockSpec((tn, top_k), lambda i: (i, 0)),
+                  pl.BlockSpec((top_k, tn, K), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((tn, K), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="expert_combine",
+    )(weights, y)
+
+
 def _use_kernel() -> bool:
     """One TPU device: a Mosaic kernel is not partitioned automatically, so
     a process that addresses several devices (a mesh) and the CPU take
@@ -465,28 +524,39 @@ def experts(u, w1, w2, weights, group, w3=None):
     return jnp.einsum("nkd,nk->nd", y, weights), sizes
 
 
+def sorted_places(order, sizes, top_k: int, tile: int):
+    """The two gathers of the ``tiles`` regime, as indices: ``order`` [A]
+    the assignment rows by group, ``sizes`` [held] → ``(src, back)``.  Row
+    ``j`` of the first call's input is row ``src[j]`` of ``u``, each group
+    begun on a multiple of ``_ROW_ALIGN`` rows (what a block's offset into
+    an array in HBM must be); assignment ``k`` of row ``n`` lies at row
+    ``back[n, k]`` of the second call's result, each group on a multiple of
+    the tile, or ``_NOWHERE`` where its expert is not held."""
+    A = order.shape[0]
+    src = jnp.zeros((laid_rows(A, sizes.shape[0], tile, _ROW_ALIGN),),
+                    order.dtype).at[lay_out(sizes, A, _ROW_ALIGN)].set(
+                        order // top_k, mode="drop")
+    back = jnp.zeros_like(order).at[order].set(lay_out(sizes, A, tile))
+    return src, back.reshape(-1, top_k)
+
+
 def _experts_laid_out(u, w1, w2, w3, weights, order, sizes, tile: int):
     """:func:`experts`' sum in the ``tiles`` regime.  The gather that sorts
-    the rows begins each group on a multiple of ``_ROW_ALIGN`` rows (what a
-    block's offset into an array in HBM must be), the first call writes each
-    on a multiple of the tile, where the second reads them, and the un-sort
+    the rows lays them out for the first call, which writes each group on a
+    multiple of the tile, where the second reads them
+    (:func:`sorted_places`), and the un-sort is a gather and one pass: it
     reads those places back, a row's first assignments before its second
     ones (``[top_k, N, K]`` is the gathered rows as they lie; ``[N, top_k,
-    K]`` would be a copy of them all), at weight 0 where the expert is not
-    held."""
-    (N, top_k), held = weights.shape, sizes.shape[0]
-    A = N * top_k
-    src = jnp.zeros((laid_rows(A, held, tile, _ROW_ALIGN),), order.dtype).at[
-        lay_out(sizes, A, _ROW_ALIGN)].set(order // top_k, mode="drop")
+    K]`` would be a copy of them all), and :func:`expert_combine` weighs and
+    sums them as it reads them, at weight 0 where the expert is not held."""
+    N, top_k = weights.shape
+    src, back = sorted_places(order, sizes, top_k, tile)
     y = expert_matmul_kernel(u[src], w1, sizes, w3, relu2=w3 is None,
                              tile=tile, laid_out=_ROW_ALIGN)
     y = expert_matmul_kernel(y, w2, sizes, tile=tile, laid_out=tile)
-    back = jnp.zeros_like(order).at[order].set(
-        lay_out(sizes, A, tile)).reshape(N, top_k).T
     here = back != _NOWHERE
-    # An assignment held elsewhere reads row 0 (a row of the first group
-    # that has one) at weight 0; with no row held anywhere, nothing is read.
-    y = y[jnp.where(here, back, 0).reshape(-1)].reshape(top_k, N, -1)
-    out = jnp.einsum("knd,kn->nd", y.astype(jnp.float32),
-                     jnp.where(here, weights.T, 0))
-    return jnp.where(sizes.sum() > 0, out, 0)
+    # An assignment held elsewhere reads row 0 at weight 0: a row of the
+    # first group that has one, or, with no row held anywhere, a row that
+    # nothing wrote.
+    y = y[jnp.where(here, back, 0).T.reshape(-1)].reshape(top_k, N, -1)
+    return expert_combine(y, jnp.where(here, weights, 0))

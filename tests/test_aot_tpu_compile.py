@@ -566,6 +566,38 @@ def test_gated_expert_matmul_compiles_for_v5e(one_chip, monkeypatch, call):
                     ((tokens, top_k), jnp.float32),
                     ((tokens, top_k), jnp.int32))
     assert text.count("tpu_custom_call") >= 2 and "expert_matmul" in text
+    # The un-sort's pass runs where the plan says ``tiles`` and nowhere else.
+    assert ("expert_combine" in text) == (chosen.regime == "tiles")
+    if chosen.regime == "tiles":
+        assert not _float32_arrays(text, tokens * top_k, K)
+
+
+def _float32_arrays(text: str, rows: int, width: int) -> list:
+    """The float32 shapes of ``rows x width`` elements, ``width`` last, in a
+    compiled program's text: what the un-sort wrote before it weighed and
+    summed the routed rows in the pass that reads them (``[A, K]`` or
+    ``[top_k, N, K]`` float32, a cast of every assignment row)."""
+    import re
+
+    found = {tuple(int(d) for d in dims.split(","))
+             for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+    return sorted(shape for shape in found
+                  if shape[-1] == width and np.prod(shape) == rows * width)
+
+
+@pytest.mark.parametrize("family, shape", [
+    ("lfm2", (4, 8192, 2048)), ("mellum", (8, 16384, 2304)),
+    ("nemotron-h", (22, 4096, 1024))])
+def test_expert_combine_compiles_for_v5e(one_chip, family, shape):
+    """The un-sort's pass at the three families' ``top_k`` and widths, at a
+    prefill's rows, inside the VMEM a kernel is given unasked."""
+    from pytorch_zappa_serverless_tpu.ops.expert_matmul import expert_combine
+
+    top_k, N, K = shape
+    text = _compile(expert_combine, one_chip, (shape, jnp.bfloat16),
+                    ((N, top_k), jnp.float32))
+    assert "expert_combine" in text and text.count("tpu_custom_call") == 1
+    assert not _float32_arrays(text, top_k * N, K)
 
 
 # sha256 of a decode step's calls as they lower for the described chip, the
@@ -721,10 +753,15 @@ def test_lfm2_programs_compile_for_v5e_and_hold_no_score_array(
             params, *leaves, sd(1, dtype=jnp.int32),
             sd(1, P, dtype=jnp.int32), sd(1, dtype=jnp.int32)).compile()
     text = done.as_text()
-    assert text.count("tpu_custom_call") == 2 + 2 * 8
+    # ``flash_attention`` an attention layer; ``expert_matmul`` twice and
+    # ``expert_combine`` once an expert layer.
+    assert text.count("tpu_custom_call") == 2 + 3 * 8
+    assert text.count("expert_combine") >= 8
     for dims in re.findall(r"f32\[([\d,]+)\]", text):
         shape = [int(d) for d in dims.split(",")]
         assert not (32 in shape and shape.count(P) >= 2), shape
+    # No cast of the gathered rows: no float32 array of ``[A, K]``.
+    assert not _float32_arrays(text, P * cfg.top_k, cfg.hidden_size)
     assert done.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
@@ -764,7 +801,7 @@ def _mellum_shapes(cfg, sd):
     return params
 
 
-@pytest.mark.parametrize("program", ["segment", "prefill"])
+@pytest.mark.parametrize("program", ["segment", "prefill", "prefill 8192"])
 def test_mellum_programs_compile_for_v5e_and_hold_no_score_array(
         one_chip, monkeypatch, program):
     """The benchmark's eight layers of Mellum 2 at the published widths,
@@ -777,7 +814,11 @@ def test_mellum_programs_compile_for_v5e_and_hold_no_score_array(
     positions: ``flash_attention`` once a layer, so no float32 array with
     the 32 heads and two dimensions of 16,384 (``[1, 32, P, P]`` is 34 GB),
     and temporaries under 3.5 GB: what is left of 16 GB beside 10.3 and the
-    runtime's own."""
+    runtime's own.  Either prefill: ``expert_combine`` once a layer behind
+    the two ``expert_matmul`` calls, so no float32 array of ``A x K``
+    elements (a cast of every assignment row, 4.8 GB written over the eight
+    layers at 8,192 tokens until PR 54); at 8,192 the temporaries are under
+    0.8 GB (1.015 with the cast)."""
     import re
 
     from pytorch_zappa_serverless_tpu.models import mellum
@@ -786,7 +827,8 @@ def test_mellum_programs_compile_for_v5e_and_hold_no_score_array(
 
     cfg = mellum.config_from_arch(
         {"layer_types": mellum.PUBLISHED.layer_types[:8], "eos_id": 98304})
-    slots, P, total = 32, 16384, 16384 + 768
+    slots, total = 32, 16384 + 768
+    P = 8192 if program.endswith("8192") else 16384
     monkeypatch.setattr(
         decode_attention_module, "_kernel_block",
         lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
@@ -830,10 +872,12 @@ def test_mellum_programs_compile_for_v5e_and_hold_no_score_array(
             params, *leaves, sd(1, dtype=jnp.int32),
             sd(1, P, dtype=jnp.int32), sd(1, dtype=jnp.int32)).compile()
     text = done.as_text()
-    assert text.count("tpu_custom_call") == 3 * 8
+    assert text.count("tpu_custom_call") == 4 * 8
+    assert text.count("expert_combine") >= 8
     for dims in re.findall(r"f32\[([\d,]+)\]", text):
         shape = [int(d) for d in dims.split(",")]
         assert not (32 in shape and shape.count(P) >= 2), shape
+    assert not _float32_arrays(text, P * cfg.top_k, cfg.hidden_size)
     temp = done.memory_analysis().temp_size_in_bytes
-    print(f"mellum 16,384 prefill temporaries: {temp / 1e9:.2f} GB")
-    assert temp < 3.5e9
+    print(f"mellum {P:,} prefill temporaries: {temp / 1e9:.2f} GB")
+    assert temp < (0.8e9 if P == 8192 else 3.5e9)

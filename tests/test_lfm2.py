@@ -526,6 +526,8 @@ def test_experts_is_the_same_sum_in_both_regimes(monkeypatch, kind, tokens,
         monkeypatch.setattr(E, "_use_kernel", lambda: True)
         monkeypatch.setattr(E, "expert_matmul_kernel", functools.partial(
             E.expert_matmul_kernel, interpret=True))
+        monkeypatch.setattr(E, "expert_combine", functools.partial(
+            E.expert_combine, interpret=True))
         got, sizes_ = E.experts(u, w1, w2, weights, group, w3=w3)
     assert sizes.tolist() == sizes_.tolist()
     assert (np.abs(np.asarray(want)).max() > 0.1) == (unheld < 1)
@@ -621,6 +623,7 @@ def test_the_servable_declares_its_pool_and_one_prompt_a_dispatch(servable):
         16, CFG.top_k, CFG.hidden_size, CFG.expert_width, CFG.experts_held,
         True, 4)
     assert meta["expert_plan"](16)["regime"] == "stream"
+    assert meta["expert_plan"](16)["unsort"] == "einsum"
 
 
 def test_paged_lane_is_refused_at_build(servable):
