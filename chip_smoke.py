@@ -72,6 +72,19 @@ fixed seed; inputs generated from ``SEED`` into ``smoke_out/``):
              and segments against the plain reference, by the logits and by
              the cell's own ``judge``; and the three controls (int8, the
              window layers read as full, no YaRN), each of which must fail.
+- *joyai*    JoyAI-LLM-Flash at the benchmark cell's widths (ten layers, 32
+             of 256 experts, a pool of one leaf): ``latent_attention`` at 64
+             slots over spans of 2k-9k rows with one pool operand and, for
+             the record, with the leaf handed twice to ``decode_attention``;
+             ``flash_attention`` at keys of 192 and values of 128 at the
+             four buckets; the gated ``expert_matmul`` at 2 and 256 rows an
+             expert, alone by the profiler's clock; then an 8,192 and a
+             2,300 prompt and the cell's 16 reference prompts (3,000 and
+             5,000 tokens) through ``prefill_start`` into the leaf and
+             segments against the plain reference, by the logits and by the
+             cell's own ``judge``; and the three controls (int8, the rope
+             part of the score left out, the latent kept without its norm),
+             each of which must fail.
 - *sd15*     Stable Diffusion 1.5 at 512x512, two steps, one ``:submit``
              polled to ``done`` (flash attention's only serving caller).
 
@@ -129,6 +142,11 @@ LFM2_RMS_TOL = 0.105
 # of spread 1.0: the root mean square of all differences read 0.0137 sound
 # and 0.0400 under the int8 control (PERF.md section 6, PR 52).
 MELLUM_RMS_TOL = 0.025
+# JoyAI-LLM-Flash's programs in bfloat16 against the float32 reference, in
+# logits of spread 0.91: the root mean square of all differences read 0.0334
+# (the cell's 16 prompts) and 0.0397 (an 8,192 and a 2,300 prompt) sound and
+# 0.0724 and 0.0733 under the int8 control (PERF.md section 6, PR 55).
+JOYAI_RMS_TOL = 0.053
 # --rehearse widths: d_model is one 128-lane tile, the int8 kernel's floor.
 TINY_GPT2 = {"d_model": 128, "layers": 2, "heads": 2, "ffn_dim": 256,
              "vocab_size": 512, "max_positions": 128}
@@ -2424,6 +2442,294 @@ def _mellum_child(rehearse: bool) -> None:
     print(json.dumps(report))
 
 
+def time_latent_attention(slots: int, total: int, width: int, values: int,
+                          heads: int, lives, on_device: bool,
+                          interpret: bool):
+    """``latent_attention`` alone over a leaf ``[2, slots, total, width]``
+    whose every slot holds ``live`` rows: with one pool operand, with the
+    leaf handed twice to the grouped ``decode_attention`` (for the record: a
+    block is then fetched as K and as V), each beside the least the chip
+    could take over the live rows' bytes as stored (bfloat16, 819 GB/s);
+    off the device, where the rows carry no time, the ``jax.numpy`` form is
+    held against both."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_zappa_serverless_tpu.ops import decode_attention as da
+
+    rng = np.random.default_rng(SEED)
+    leaf = jnp.asarray(rng.standard_normal((2, slots, total, width),
+                                           dtype=np.float32), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((slots, heads * width)) * 0.02,
+                    jnp.bfloat16)
+    bt = da.pick_block_t(total, width, jnp.bfloat16)
+    rows = []
+
+    def one_operand(q, leaf, wpos, work, layer):
+        return da.latent_attention(q, leaf, wpos, work, layer=layer,
+                                   heads=heads, values=values, block_t=bt,
+                                   interpret=interpret)
+
+    def leaf_twice(q, leaf, wpos, work, layer):
+        out = da.decode_attention(q, leaf, leaf, wpos, work, layer=layer,
+                                  heads=heads, block_t=bt,
+                                  interpret=interpret)
+        return out.reshape(slots, heads, width)[..., :values].reshape(
+            slots, heads * values)
+
+    def numpy_form(q, leaf, wpos, work, layer):
+        return da.attend_latent(q[:, None], leaf, layer, wpos[:, None],
+                                heads, values)[:, 0]
+
+    forms = [("one pool operand", one_operand),
+             ("the leaf handed twice", leaf_twice)]
+    for live in lives:
+        wpos = jnp.full((slots,), live - 1, jnp.int32)
+        floor = slots * live * width * 2 / 819e9 * 1e6
+        got = {}
+        for form, attend in forms + [("jax.numpy", numpy_form)]:
+            @jax.jit
+            def chain(q, leaf, wpos):
+                work = da.work_list(wpos, total, bt)
+                for j in range(_TIMED_CALLS):
+                    # Each call's queries hang on the one before it: calls
+                    # alike in every operand would be folded into one.
+                    out = attend(q, leaf, wpos, work, j % 2)
+                    q = q + jnp.pad(
+                        out.reshape(slots, heads, values),
+                        ((0, 0), (0, 0), (0, width - values))).reshape(
+                            q.shape) * 0.01
+                return q
+
+            if form == "jax.numpy" and on_device:
+                continue  # on the chip ``attend_latent`` takes the kernel
+            chain(q, leaf, wpos).block_until_ready()
+            got[form] = attend(q, leaf, wpos,
+                               da.work_list(wpos, total, bt), 0)
+            row = {"leaf": [slots, total, width], "heads": heads,
+                   "values": values, "live": live, "form": form,
+                   "block_t": bt, "floor_us": round(floor, 2)}
+            if on_device:
+                row["us_a_layer"] = _busy_us(lambda: chain(q, leaf, wpos))
+                row["gb_per_s"] = round(floor * 819 / row["us_a_layer"], 1)
+                row["share_of_819"] = round(floor / row["us_a_layer"], 3)
+            rows.append(row)
+        for form in got:
+            off = float(jnp.max(jnp.abs(
+                got[form].astype(jnp.float32)
+                - got["one pool operand"].astype(jnp.float32))))
+            assert off < 0.05, (live, form, off)
+    return rows
+
+
+def _joyai_child(rehearse: bool) -> None:
+    """JoyAI-LLM-Flash's kernels alone, then its programs against its plain
+    reference, on one device, at the benchmark cell's widths
+    (``benchmark/configs/joyai-flash-10l.json``; its ``rehearse`` widths on
+    the CPU).
+
+    Alone, by the profiler's clock: ``latent_attention`` at 64 slots over
+    the leaf at 2k, 4k, 6k and all 9,216 rows live, beside the same read
+    with the leaf handed twice; ``flash_attention`` at keys of 192 and
+    values of 128 at the four buckets (the share of 197 TFLOP/s is of the
+    work the grid visits, keys padded to 256 lanes); the gated
+    ``expert_matmul`` at ``K`` 2048, ``F`` 768 at 2 and 256 rows an expert,
+    each by the kernel's own plan.
+
+    Then the servable over the tree the benchmark stages and the programs
+    ``build_gen_kernels`` jits, as the scheduler runs them: an 8,192 and a
+    2,300 prompt, then the cell's own 16 reference prompts (drawn as
+    ``benchmark/run.py`` draws them), each prefilled alone into a slot of
+    the pool and decoded for two segments.  ``choose`` is watched, not
+    replaced.  The reference's full forward pass (non-absorbed, in blocks
+    of queries) over prompt + served tokens gives the largest and the
+    root-mean-square logit difference and, through the cell's own ``check``
+    rule (``judge``, over the cell's 16), how many served tokens lie far;
+    the same against the reference's three controls, each of which must
+    fail."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import traffic
+    from benchmark.families import joyai as bench_family
+    from benchmark.reference import joyai as reference
+    from pytorch_zappa_serverless_tpu.config import ModelConfig
+    from pytorch_zappa_serverless_tpu.models import decoder, joyai
+    from pytorch_zappa_serverless_tpu.ops import expert_matmul as em
+    from pytorch_zappa_serverless_tpu.ops.flash_attention import (
+        flash_attention)
+    from pytorch_zappa_serverless_tpu.serving.generation import (
+        build_gen_kernels)
+
+    on_device = not rehearse
+    config = json.loads((ROOT / "benchmark" / "configs"
+                         / "joyai-flash-10l.json").read_text())
+    serve = config["serve"]
+    buckets, extra = serve["seq_buckets"], dict(serve["extra"])
+    dtype, scale = "bfloat16", 1.0
+    if rehearse:
+        buckets = config["rehearse"]["seq_buckets"]
+        extra.update(config["rehearse"]["extra"])
+        dtype, scale = "float32", config["rehearse"]["scale"]
+        config["weights"]["dtype"] = "float32"
+    cfg = joyai.config_from_arch(extra["arch"])
+    fam = joyai.family(cfg, jnp.dtype(dtype))
+    report = {}
+
+    # -- the kernels alone ------------------------------------------------
+    S, heads = extra["gen_slots"], cfg.heads
+    T = fam.rows.count(buckets[-1] + extra["max_new_tokens"])
+    report["latent_attention"] = time_latent_attention(
+        S, T, fam.width, fam.rows.values, heads,
+        [max(T * live // 9216, 1) for live in (2048, 4096, 6144, 9216)],
+        on_device, interpret=rehearse)
+    for row in report["latent_attention"]:
+        print("joyai latent_attention " + json.dumps(row), flush=True)
+    report["expert_matmul gated"] = time_gated_experts(
+        (2, 4) if rehearse else (2, 256), on_device, interpret=rehearse,
+        experts=cfg.experts_held, width=cfg.hidden_size,
+        inner=cfg.expert_width, kinds=("routed",))
+    for row in report["expert_matmul gated"]:
+        print("joyai expert_matmul " + json.dumps(row), flush=True)
+    report["expert_plans"] = {
+        rows * cfg.top_k: em.plan_summary(
+            rows, cfg.top_k, cfg.hidden_size, cfg.expert_width,
+            cfg.experts_held, True, 2)
+        for rows in (S, buckets[-1])}
+    print("joyai expert_plans " + json.dumps(report["expert_plans"]),
+          flush=True)
+    rng = np.random.default_rng(SEED)
+    report["prompt attention"] = []
+    qk, dv = cfg.qk_dim, cfg.v_dim
+    for P in buckets:
+        q, k = (jnp.asarray(rng.standard_normal((1, P, heads, qk)) * 0.5,
+                            jnp.bfloat16) for _ in range(2))
+        v = jnp.asarray(rng.standard_normal((1, P, heads, dv)) * 0.5,
+                        jnp.bfloat16)
+
+        def attend(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=rehearse)
+
+        @jax.jit
+        def chain(q, k, v):
+            for _ in range(_TIMED_CALLS):
+                q = q.at[..., :dv].add(attend(q, k, v) * 0.01)
+            return q
+
+        chain(q, k, v).block_until_ready()
+        row = {"shape": [1, P, heads, qk], "values": dv, "form": "flash_mla",
+               "visited_flops": bench_family.attend_flops(
+                   {"extra": extra}, P, visited=True) / cfg.layers,
+               "needed_flops": bench_family.attend_flops(
+                   {"extra": extra}, P) / cfg.layers}
+        if on_device:
+            row["us_a_layer"] = _busy_us(lambda: chain(q, k, v))
+            row["share_of_197"] = round(
+                row["visited_flops"] / 197e12 * 1e6 / row["us_a_layer"], 3)
+        report["prompt attention"].append(row)
+        print("joyai prompt_attention " + json.dumps(row), flush=True)
+        del q, k, v
+
+    # -- the programs against the reference -----------------------------------
+    t0 = time.monotonic()
+    tree = bench_family.init_tree(config["weights"]["seed"], config,
+                                  {"extra": extra})
+    print(f"joyai: {cfg.layers} layers drawn in "
+          f"{time.monotonic() - t0:.0f} s", flush=True)
+    sv = decoder.make_servable(
+        "joyai", ModelConfig(name="joyai", dtype=dtype, batch_buckets=(1,),
+                             seq_buckets=buckets, extra=extra), fam, tree)
+    del tree
+    params, meta = sv.params, sv.meta["continuous"]
+    kernels = build_gen_kernels(types.SimpleNamespace(servable=sv))
+    V = cfg.vocab_size
+    stats = jax.local_devices()[0].memory_stats() or {}
+    print("joyai: cache leaves " + json.dumps(
+        [[list(shape), str(np.dtype(dt))]
+         for shape, dt in meta["cache_leaves"]])
+        + f", read_block {meta['read_block']}, prompt forms "
+        + json.dumps({b: meta["prompt_form"](1, b) for b in buckets})
+        + f", bytes in use with the weights alone "
+        f"{int(stats.get('bytes_in_use', 0))}", flush=True)
+
+    # An 8,192 and a 2,300 prompt, then the cell's reference prompts, as
+    # benchmark/run.py draws them.
+    rng = np.random.default_rng(config["weights"]["seed"] + 1)
+    cell = [traffic.token_ids(rng, max(2, round(n * scale)), V)
+            for n in config["reference_prompts"]]
+    new = min(16, extra["max_new_tokens"])
+    # (The longest is decoded for twice ``new``: at the rehearsal's widths a
+    # slot has room for ``new`` alone past its longest bucket.)
+    own = [traffic.token_ids(rng, n, V) for n in (
+        buckets[-1] - (new if rehearse else 0), max(2, round(2300 * scale)))]
+    t0 = time.monotonic()
+    runs, got = _serve_prompts_alone(kernels, params, meta, buckets,
+                                     own + cell, new)
+    stats = jax.local_devices()[0].memory_stats() or {}
+    report["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+    print(f"joyai: {len(runs)} prompts of "
+          f"{sorted({len(r['ids']) for r in runs})} tokens prefilled alone "
+          f"into a slot and decoded in {time.monotonic() - t0:.0f} s "
+          f"(compiles included); peak {report['memory_peak_bytes']} bytes",
+          flush=True)
+
+    keys = bench_family.published({"extra": extra})
+    report["logit_std"] = float(np.std(got[0][0]))
+    refs = {}
+
+    def forward(run, control):
+        at = (id(run), control)  # a pass a run a control, however often read
+        if at not in refs:
+            refs[at] = reference.forward(
+                params, run["ids"] + run["tokens"][:-1], keys, control,
+                len(run["tokens"]))
+        return refs[at]
+
+    # The two long prompts by the logits alone, against the sound reference
+    # and the int8 control.
+    for control in (None, "int8"):
+        report[f"own prompts {control or 'float32'}"] = _against_reference(
+            forward, bench_family.judge, config, runs[:2], got[:2], control,
+            new)
+        print(f"joyai: 8,192 and 2,300 prompts, reference "
+              f"{control or 'float32'}: "
+              + json.dumps(report[f"own prompts {control or 'float32'}"]),
+              flush=True)
+    runs, got = runs[2:], got[2:]
+    for control in (None,) + reference.CONTROLS:
+        # Judged over the 16 tokens a prompt the cell asks for.
+        report[control or "float32"] = _against_reference(
+            forward, bench_family.judge, config, runs, got, control, new)
+        print(f"joyai: reference {control or 'float32'}: "
+              + json.dumps(report[control or "float32"]), flush=True)
+    # What the cell's limits are set between: the share of served tokens
+    # that lie far under the reference's best, by how far "far" is.
+    report["far_share_by_tolerance"] = {
+        str(tol): {control or "float32": _against_reference(
+            forward, bench_family.judge, {**config,
+                                          "reference_tolerance": tol},
+            runs, got, control, new)["far_share"]
+            for control in (None,) + reference.CONTROLS}
+        for tol in (0.01, 0.02, 0.03, 0.05)}
+    print("joyai: far share by tolerance "
+          + json.dumps(report["far_share_by_tolerance"]), flush=True)
+    print("joyai " + json.dumps(report))
+    limit = 1e-3 if rehearse else JOYAI_RMS_TOL
+    assert report["float32"]["rms_logit_diff"] <= limit, report["float32"]
+    assert report["own prompts float32"]["rms_logit_diff"] <= limit, report
+    assert report["float32"]["ok"], report["float32"]
+    # Each control must fail, by the logits and (on the chip) by the cell's
+    # own comparison of the served tokens.
+    for control in reference.CONTROLS:
+        assert report[control]["rms_logit_diff"] > limit, report[control]
+        assert rehearse or not report[control]["ok"], report[control]
+    print(json.dumps(report))
+
+
 # What a burst admits on the int8 lane (16 slots, buckets 512 and 768), and
 # two of GPT-2 XL's admission batches that take the kernel: (batch, bucket).
 BURST_INT8 = [(8, 512), (16, 512), (4, 768), (8, 768), (16, 768)]
@@ -3054,6 +3360,16 @@ def main(argv=None) -> int:
                 "matmul at its widths match their jax.numpy forms; the "
                 "cell's reference prompts through prefill into both leaf "
                 "pairs and segments agree with the plain reference; its "
+                "three controls do not")
+            run_child(f"import chip_smoke; "
+                      f"chip_smoke._joyai_child({args.rehearse})",
+                      args.rehearse, "joyai.log", timeout=3000.0)
+            say("joyai: the latent kernel over one pool operand, the prompt "
+                "attention at keys of 192 and values of 128 and the gated "
+                "expert matmul at its widths match their jax.numpy forms; "
+                "an 8,192 and a 2,300 prompt and the cell's reference "
+                "prompts through prefill into the one leaf and absorbed "
+                "segments agree with the plain non-absorbed reference; its "
                 "three controls do not")
             phase_sd15(sd15_cfg, probe, args.rehearse)
     except SmokeFailure as e:

@@ -52,7 +52,17 @@ class Rows:
     operators alone, so that the scheduler counts with them in numpy what
     the programs compute with them traced.  What a query reads is always one
     contiguous span of rows: ops/decode_attention.py takes a first and a
-    last row and nothing more."""
+    last row and nothing more.
+
+    A row is a K and a V, a leaf each, unless ``values`` says that it is one
+    leaf whose first ``values`` columns are what a query sums (latent
+    attention: the row is scored whole).  The layer then hands ``attend``
+    its queries and that one row; a prefill's ``prompt`` writes the rows and
+    attends however the family does, and a decode step turns the queries to
+    the row's columns (:meth:`absorb`), attends over the leaf and turns the
+    result back (:meth:`expand`)."""
+
+    values: int | None = None
 
     def count(self, total: int) -> int:
         """Rows a slot needs for ``total`` positions."""
@@ -115,6 +125,16 @@ class Rows:
         of ``P`` positions: what the scheduler logs and counts by."""
         return prompt_form(batch, heads, P, head_dim)
 
+    def absorb(self, p, q):
+        """A decode step's queries [S, Tq, .] as the rows are scored
+        against them (``p``: the layer's parameters).  Here as they come."""
+        return q
+
+    def expand(self, p, out):
+        """What a decode step's attention gave, [S, Tq, .], as the layer
+        takes it.  Here as it comes."""
+        return out
+
 
 ROWS = Rows()  # a row a position
 
@@ -150,7 +170,8 @@ class Family:
     ``rows`` says how a slot's cache rows hold its positions (:class:`Rows`:
     a row a position unless the family brings its own).
 
-    What a slot keeps (:func:`cache_leaves`): K and V rows ``width`` wide in
+    What a slot keeps (:func:`cache_leaves`): K and V rows ``width`` wide
+    (or, where ``rows.values`` is set, one leaf of rows ``width`` wide) in
     ``kv_layers`` of the layers (None: in all), and whatever ``state``
     declares after them, ``(layers that hold it, shape a slot, dtype)`` a
     leaf.  ``cache_index(i)`` is where layer ``i`` finds its own part of
@@ -199,13 +220,21 @@ class Family:
 def cache_leaves(fam: Family, slots: int, T: int, dtype) -> tuple:
     """``(shape, dtype)`` of every leaf of a cache of ``slots`` slots of
     ``T`` rows, the slot axis second: K, V (a pair a kind, each with the
-    rows its kind needs for ``T`` positions), then the family's state."""
+    rows its kind needs for ``T`` positions; one leaf where a row is one:
+    :func:`row_leaves`), then the family's state."""
+    n = row_leaves(fam)
     if fam.kinds:
         kv = tuple(((k.layers, slots, k.rows.count(T), fam.width), dtype)
-                   for k in fam.kinds for _ in "kv")
+                   for k in fam.kinds for _ in range(n))
     else:
-        kv = (((fam.kv_layers or fam.layers, slots, T, fam.width), dtype),) * 2
+        kv = (((fam.kv_layers or fam.layers, slots, T, fam.width), dtype),) * n
     return kv + tuple(((n, slots, *shape), dt) for n, shape, dt in fam.state)
+
+
+def row_leaves(fam: Family) -> int:
+    """Leaves that hold a kind's rows: K and V, or the one leaf of a family
+    whose ``rows.values`` says a row is scored and summed out of itself."""
+    return 1 if fam.rows.values else 2
 
 
 # ---------------------------------------------------------------------------
@@ -213,7 +242,9 @@ def cache_leaves(fam: Family, slots: int, T: int, dtype) -> tuple:
 # ---------------------------------------------------------------------------
 
 class SlotPool(NamedTuple):
-    """A row a slot: ``k``, ``v`` [L, S, T, D].  ``slots`` is ``arange(S)``,
+    """A row a slot: ``k``, ``v`` [L, S, T, D] (``v`` None: the rows are one
+    leaf, ``k``, whose first ``rows.values`` columns are the values).
+    ``slots`` is ``arange(S)``,
     made where the pool is (:func:`slot_pool`), outside any scan, and
     ``rows`` how the ``T`` rows hold a slot's positions.  One query a slot:
     nothing feeds it several.  ``layer`` is an index as data wherever a
@@ -237,6 +268,8 @@ class SlotPool(NamedTuple):
         (``p``: the layer's parameters, for what the rows keep beside)."""
         row = self.rows.row(pos, self.k.shape[2])
         ck = self.k.at[layer, self.slots, row].set(k[:, 0])
+        if self.v is None:  # one leaf: the row is all there is
+            return self._replace(k=ck)
         cv = self.v.at[layer, self.slots, row].set(v[:, 0])
         ck, cv = self.rows.settle(p, ck, cv, layer, self.slots, pos)
         return self._replace(k=ck, v=cv)
@@ -246,6 +279,10 @@ class SlotPool(NamedTuple):
         rows ``(first, last)`` [S] (``last`` negative: dead, reads
         nothing)."""
         first, last = span
+        if self.v is None:
+            return decode_attention.attend_latent(
+                q, self.k, layer, last[:, None], heads, self.rows.values,
+                work, first[:, None])
         return decode_attention.attend(q, self.k, self.v, layer,
                                        last[:, None], heads, work,
                                        first[:, None])
@@ -255,16 +292,26 @@ def slot_pool(k, v, rows: Rows = ROWS) -> SlotPool:
     return SlotPool(k, v, jnp.arange(k.shape[1]), rows)
 
 
+def _leaves(pool) -> tuple:
+    """A pool's own leaves: K and V, or the one."""
+    return (pool.k,) if pool.v is None else (pool.k, pool.v)
+
+
 def slot_pools(fam: Family, cache):
     """``(pool, state)`` of the slot lane's ``cache`` (its leaves): the one
     :class:`SlotPool`, or for a family of several kinds a list of them, a
-    kind each in ``fam.kinds``' order, and the leaves after K and V."""
-    if not fam.kinds:
-        return slot_pool(*cache[:2], fam.rows), tuple(cache[2:])
+    kind each in ``fam.kinds``' order, and the leaves after the rows'."""
+    n = row_leaves(fam)
     slots = jnp.arange(cache[0].shape[1])
-    n = 2 * len(fam.kinds)
-    return ([SlotPool(cache[2 * j], cache[2 * j + 1], slots, k.rows)
-             for j, k in enumerate(fam.kinds)], tuple(cache[n:]))
+
+    def pool(mine, rows):
+        return SlotPool(mine[0], mine[1] if n == 2 else None, slots, rows)
+
+    if not fam.kinds:
+        return pool(cache[:n], fam.rows), tuple(cache[n:])
+    return ([pool(cache[n * j:n * j + n], k.rows)
+             for j, k in enumerate(fam.kinds)],
+            tuple(cache[n * len(fam.kinds):]))
 
 
 def slot_put(slots):
@@ -404,7 +451,8 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
     kinds of layer (models/nemotron_h.py) is three traces."""
     stacks = None if adapter_idx is None else params.get("__adapters__")
     hooked = bool(fam.state or fam.counters)
-    n_kv = 2 * max(len(fam.kinds), 1)  # the leaves before the state
+    per_kind = row_leaves(fam)
+    n_kv = per_kind * max(len(fam.kinds), 1)  # the leaves before the state
     if put is None:  # a decode step: the slots' own state, set in place
         def held(leaf, i):
             return leaf[i]
@@ -422,14 +470,14 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
         layer_traced()
         counts = None
 
-        def layer_attend(q, k, v):
+        def layer_attend(q, k, v=None):  # ``v`` None: the row is one leaf
             nonlocal cache
             if kind is None:
                 cache, out = attend(p, cache, i, q, k, v)
             else:  # this kind's K and V, by this kind's ``attend``
-                at = 2 * kind
-                mine, out = attend[kind](p, cache[at:at + 2], i, q, k, v)
-                cache = cache[:at] + tuple(mine) + cache[at + 2:]
+                at, upto = per_kind * kind, per_kind * (kind + 1)
+                mine, out = attend[kind](p, cache[at:upto], i, q, k, v)
+                cache = cache[:at] + tuple(mine) + cache[upto:]
             return out
 
         def layer_state(update):
@@ -467,11 +515,16 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
 def _write_then_attend(fam, pool, wpos, span, work=None):
     """The decode programs' ``attend`` over ``pool``'s layout: this layer's
     K/V in for position ``wpos``, then each query over its ``span`` of the
-    layer's rows."""
+    layer's rows.  Where a row is one leaf, the queries are turned to its
+    columns before and the result turned back after, by the family's own
+    ``rows`` (the seam names no matrix)."""
+    n = len(_leaves(pool))
+
     def attend(p, cache, i, q, k, v):
-        here = pool._replace(k=cache[0], v=cache[1]).write(i, wpos, k, v, p)
-        return ((here.k, here.v) + cache[2:],
-                here.attend(i, q, span, fam.heads, work))
+        here = pool._replace(**dict(zip("kv", cache[:n]))).write(
+            i, wpos, k, v, p)
+        out = here.attend(i, fam.rows.absorb(p, q), span, fam.heads, work)
+        return _leaves(here) + cache[n:], fam.rows.expand(p, out)
 
     return attend
 
@@ -659,7 +712,7 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
         return (cache, nxt, seen, *([counts] if fam.counters else []))
 
     return segment_scan(one, (*(leaf for each in pools
-                                for leaf in (each.k, each.v)), *state),
+                                for leaf in _leaves(each)), *state),
                         tok, pos, step,
                         finished, seg, fam.eos_id, presence,
                         len(fam.counters))
@@ -877,7 +930,8 @@ def make_servable(name: str, cfg_model, fam: Family, params: dict, *,
         # paged lane, and says so here rather than serve something else.
         raise ValueError(
             f"{name}: kv_cache='paged' cannot serve this family: its cache "
-            f"is not a row a position ({type(fam.rows).__name__}, "
+            f"is not a K and a V row a position "
+            f"({type(fam.rows).__name__}, {row_leaves(fam)} leaves a row, "
             f"{len(fam.kinds) or 1} kinds of K/V layer, "
             f"{len(fam.state)} leaves of state); use kv_cache='slot'")
 

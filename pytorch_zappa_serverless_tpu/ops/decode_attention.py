@@ -44,6 +44,13 @@ elsewhere, and row ``h`` of the ``[heads, D]`` result carries head ``h``'s
 output in those same columns, picked out afterwards.  ``D`` is the pool's
 width wherever a block is sized.
 
+A pool whose rows are one leaf (latent attention: a position's row is
+scored whole and its first ``values`` columns are what is summed) is the
+grouped case with one K/V head and **one pool operand**
+(:func:`latent_attention`, under its own name in a capture): the values are
+sliced out of the block of rows the kernel already holds, so a block is
+fetched once.  Its ``jax.numpy`` form and its entry are :func:`attend_latent`.
+
 A model calls :func:`attend` (with :func:`step_work` once a step, and
 :func:`read_block` for what it reports), which takes the kernel or the
 ``jax.numpy`` form by what it can observe (:func:`_kernel_block`).
@@ -134,7 +141,7 @@ def work_list(wpos, total: int, block_t: int, first=None):
 
 def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
             v_ref, o_ref, m_ref, l_ref, acc_ref, *, block_t: int,
-            head_dim: int, grouped: bool = False):
+            head_dim: int, grouped: bool = False, values: int | None = None):
     i = pl.program_id(0)
     b = block_ref[i]
     last = wpos_ref[slot_ref[i]]
@@ -172,7 +179,8 @@ def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(scores - m_new)
     l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-    v = v_ref[...]
+    # ``values``: the rows' own leading columns (``v_ref`` is ``k_ref``).
+    v = v_ref[...] if values is None else v_ref[:, :values]
     acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -187,6 +195,20 @@ def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
             # Each head keeps its own columns: one non-zero term a column.
             o_ref[...] = jnp.where(own(), out, 0.0).sum(
                 axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def _step_scalars(layer, wpos, first, work, T: int, bt: int):
+    """What either kernel prefetches: ``(layer [1], slot, block, count, wpos,
+    first)`` int32, the work list built here where the caller brings none."""
+    if T % bt:
+        raise ValueError(f"block_t {bt} does not divide the pool's {T} "
+                         "positions")
+    wpos = wpos.astype(jnp.int32)
+    slot, block, count = (work_list(wpos, T, bt, first) if work is None
+                          else work)
+    first = _first_row(first, wpos)
+    return (jnp.asarray(layer, jnp.int32).reshape(1), slot, block, count,
+            wpos, first)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "block_t", "interpret"))
@@ -207,14 +229,8 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
     dh = Dq // heads
     grouped = D != Dq
     bt = block_t or pick_block_t(T, D, cache_k.dtype)
-    if T % bt:
-        raise ValueError(f"block_t {bt} does not divide the pool's {T} "
-                         "positions")
-    wpos = wpos.astype(jnp.int32)
-    slot, block, count = (work_list(wpos, T, bt, first) if work is None
-                          else work)
-    first = _first_row(first, wpos)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    layer, slot, block, count, wpos, first = _step_scalars(
+        layer, wpos, first, work, T, bt)
     rows = -(-heads // 16) * 16  # the bf16 sublane tile
     if grouped:
         # Head h's query in the columns of K/V head h // group, [rows, D] a
@@ -259,6 +275,64 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
     return jnp.where(live, out.reshape(S, Dq), 0)
 
 
+def _latent_kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref,
+                   rows_ref, o_ref, m_ref, l_ref, acc_ref, *, block_t: int,
+                   values: int):
+    """:func:`_kernel`'s grouped form over one operand: the block of rows is
+    the keys, and its first ``values`` columns the values."""
+    _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref,
+            rows_ref, rows_ref, o_ref, m_ref, l_ref, acc_ref, block_t=block_t,
+            head_dim=0, grouped=True, values=values)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "values", "block_t",
+                                             "interpret"))
+def latent_attention(q, pool, wpos, work=None, first=None, *, layer,
+                     heads: int, values: int, block_t: int | None = None,
+                     interpret: bool = False):
+    """q [S, heads * D] (already scaled, each head's query over the row's
+    own ``D`` columns), pool [L, S, T, D] the one leaf, ``layer``, ``wpos``,
+    ``first`` and ``work`` as :func:`decode_attention` takes them → [S,
+    heads * values]: each head's probabilities over its slot's span against
+    the rows' first ``values`` columns.  The ``[heads, D]`` query block of a
+    slot meets each block of rows once, fetched once."""
+    S = q.shape[0]
+    T, D = pool.shape[2:]
+    bt = block_t or pick_block_t(T, D, pool.dtype)
+    layer, slot, block, count, wpos, first = _step_scalars(
+        layer, wpos, first, work, T, bt)
+    rows = -(-heads // 16) * 16  # the bf16 sublane tile
+    q = jnp.pad(q.reshape(S, heads, D), ((0, 0), (0, rows - heads), (0, 0)))
+
+    def at_slot(width):
+        return pl.BlockSpec(
+            (None, rows, width),
+            lambda i, layer, slot, block, wpos, first: (slot[i], 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, block_t=bt, values=values),
+        out_shape=jax.ShapeDtypeStruct((S, rows, values), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(count,),  # a dynamic bound: the live blocks and no more
+            in_specs=[at_slot(D), pl.BlockSpec(
+                (None, None, bt, D),
+                lambda i, layer, slot, block, wpos, first:
+                (layer[0], slot[i], block[i], 0))],
+            out_specs=at_slot(values),
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, values), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_attention",
+    )(layer, slot, block, wpos, first, q, pool)
+    # No grid step visits a dead slot, so nothing wrote its row.
+    return jnp.where((wpos >= 0)[:, None],
+                     out[:, :heads].reshape(S, heads * values), 0)
+
+
 def _kernel_block(Tq, total, d, dtype):
     """The block length at which the kernel serves a call, or None where the
     ``jax.numpy`` form of :func:`attend` does: the CPU, several queries a
@@ -286,6 +360,15 @@ def step_work(last, total, d, dtype, first=None):
     runs, which needs none."""
     bt = _kernel_block(1, total, d, dtype)
     return None if bt is None else work_list(last, total, bt, first)
+
+
+def _in_span(T: int, wpos, first):
+    """[S, Tq, T] bool: the rows ``[first, wpos]`` of each query's slot
+    (``first`` None: from row 0)."""
+    keep = jnp.arange(T)[None, None, :] <= wpos[:, :, None]
+    if first is not None:
+        keep &= jnp.arange(T)[None, None, :] >= first[:, :, None]
+    return keep
 
 
 def attend(q, cache_k, cache_v, layer, wpos, heads, work=None, first=None):
@@ -343,10 +426,7 @@ def attend(q, cache_k, cache_v, layer, wpos, heads, work=None, first=None):
     qh = jnp.where(own, q[:, :, None, :], 0)                   # [S,Tq,H,D]
     scores = jnp.einsum("smd,std->smt", qh.reshape(S, Tq * heads, D),
                         cache_k, preferred_element_type=jnp.float32)
-    keep = jnp.arange(T)[None, None, :] <= wpos[:, :, None]     # [S,Tq,T]
-    if first is not None:
-        keep &= jnp.arange(T)[None, None, :] >= first[:, :, None]
-    scores = jnp.where(keep[:, :, None, :],
+    scores = jnp.where(_in_span(T, wpos, first)[:, :, None, :],
                        scores.reshape(S, Tq, heads, T), -1e9)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("smt,std->smd", probs.reshape(S, Tq * heads, T),
@@ -367,11 +447,40 @@ def _attend_grouped(q, k, v, wpos, first, heads):
     qg = q.reshape(S, Tq, kv, heads // kv, dh)
     scores = jnp.einsum("sqhgd,sthd->sqhgt", qg, k.reshape(S, T, kv, dh),
                         preferred_element_type=jnp.float32)
-    keep = jnp.arange(T)[None, None, :] <= wpos[:, :, None]     # [S,Tq,T]
-    if first is not None:
-        keep &= jnp.arange(T)[None, None, :] >= first[:, :, None]
-    scores = jnp.where(keep[:, :, None, None, :], scores, -1e9)
+    scores = jnp.where(_in_span(T, wpos, first)[:, :, None, None, :], scores,
+                       -1e9)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("sqhgt,sthd->sqhgd", probs, v.reshape(S, T, kv, dh))
     return jnp.where((wpos >= 0)[:, :, None], out.reshape(S, Tq, D),
                      0).astype(q.dtype)
+
+
+def attend_latent(q, pool, layer, wpos, heads, values, work=None, first=None):
+    """Decode attention over one layer of a pool whose rows are one leaf.
+
+    q [S, Tq, heads * D] (scaled by the family, each head's query over the
+    row's own ``D`` columns: a latent family folds its key up-projection
+    into it), pool [L, S, T, D], ``layer``, ``wpos`` [S, Tq], ``first`` and
+    ``work`` as :func:`attend` takes them → [S, Tq, heads * values]: every
+    head scores each row of its span whole and sums the rows' first
+    ``values`` columns.  A dead query (``wpos < 0``) reads nothing and its
+    row is zeros.  The kernel (:func:`latent_attention`) where
+    :func:`_kernel_block` gives a block, else the ``jax.numpy`` form below
+    over all ``T`` positions, which is what the tests compare the kernel
+    with."""
+    S, Tq, _ = q.shape
+    T, D = pool.shape[2:]
+    bt = _kernel_block(Tq, T, D, pool.dtype)
+    if bt is not None:
+        return latent_attention(q[:, 0], pool, wpos[:, 0], work,
+                                None if first is None else first[:, 0],
+                                layer=layer, heads=heads, values=values,
+                                block_t=bt)[:, None]
+    rows = pool[layer]
+    scores = jnp.einsum("sqhd,std->sqht", q.reshape(S, Tq, heads, D), rows,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(_in_span(T, wpos, first)[:, :, None, :], scores, -1e9)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("sqht,stv->sqhv", probs, rows[..., :values])
+    return jnp.where((wpos >= 0)[:, :, None],
+                     out.reshape(S, Tq, heads * values), 0).astype(q.dtype)

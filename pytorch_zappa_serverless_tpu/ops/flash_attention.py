@@ -156,6 +156,12 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
     ``H`` a multiple of ``KV``: query head ``h`` reads K/V head ``h // (H /
     KV)`` through the tile map, and nothing is repeated in HBM.
 
+    ``v`` may be narrower than ``q`` and ``k``, ``[B, Tk, KV, Dv]`` (latent
+    attention's prompt form: keys of 192, values of 128): the ``P V``
+    product, the accumulator and the result are ``Dv`` wide, each width
+    padded to its own lane tiles (256 and 128, where one width for both
+    would make 256 and 256).  The scale is ``D``'s.
+
     ``window`` (with ``causal``) is a band: query ``p`` sees keys ``j`` with
     ``0 <= p - j < window``.  The grid's innermost axis is then as long as
     the widest band in blocks (:func:`band_blocks`) and counts from the
@@ -166,7 +172,7 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
     (no new copy) and are skipped.
     """
     B, Tq, H, D = q.shape
-    Tk, group = k.shape[1], H // k.shape[2]
+    Tk, group, Dv = k.shape[1], H // k.shape[2], v.shape[3]
     if causal and Tq != Tk:
         raise ValueError(f"causal needs Tq == Tk, got {Tq} != {Tk}")
     if window is not None and (not causal or kv_mask is not None):
@@ -186,12 +192,12 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
     block_q = min(block_q, _round_up(Tq, _LANES))
     block_k = min(block_k, _round_up(Tk, _LANES))
     tq_p, tk_p = _round_up(Tq, block_q), _round_up(Tk, block_k)
-    d_p = _round_up(D, _LANES)
+    d_p, dv_p = _round_up(D, _LANES), _round_up(Dv, _LANES)
 
     def _prep(x, t_pad):  # [B,T,H,D] -> [B,H,T_pad,D_pad]
         x = jnp.transpose(x, (0, 2, 1, 3))
         return jnp.pad(x, ((0, 0), (0, 0), (0, t_pad - x.shape[2]),
-                           (0, d_p - D)))
+                           (0, _round_up(x.shape[3], _LANES) - x.shape[3])))
 
     qt, kt, vt = _prep(q, tq_p), _prep(k, tk_p), _prep(v, tk_p)
     nq, nk = tq_p // block_q, tk_p // block_k
@@ -216,7 +222,7 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
         pl.BlockSpec((1, 1, block_q, d_p), lambda b, h, iq, ik: (b, h, iq, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, 1, block_k, d_p), keys, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, block_k, d_p), keys, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 1, block_k, dv_p), keys, memory_space=pltpu.VMEM),
     ]
     operands = [qt, kt, vt]
     bias_kw = {}
@@ -244,19 +250,19 @@ def flash_attention(q, k, v, *, causal: bool = False, kv_mask=None,
         kernel,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, d_p),
+        out_specs=pl.BlockSpec((1, 1, block_q, dv_p),
                                lambda b, h, iq, ik: (b, h, iq, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, H, tq_p, d_p), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, tq_p, dv_p), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max m
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # running denom l
-            pltpu.VMEM((block_q, d_p), jnp.float32),      # fp32 accumulator
+            pltpu.VMEM((block_q, dv_p), jnp.float32),     # fp32 accumulator
         ],
         interpret=interpret,
         name="flash_attention",
     )(*operands)
-    return jnp.transpose(out[:, :, :Tq, :D], (0, 2, 1, 3))
+    return jnp.transpose(out[:, :, :Tq, :Dv], (0, 2, 1, 3))
 
 
 # ---------------------------------------------------------------------------

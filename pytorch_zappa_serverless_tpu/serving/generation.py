@@ -24,7 +24,9 @@ answer to both, built so every device program keeps static shapes:
   less, (1) only in a bucket that holds a prompt of its own this round, (2)
   with no more dispatches than one a bucket (split by the family's
   ``prefill_batch``) would make, (3) with no padded batch larger than that
-  rule's largest, (4) ties keeping a dispatch a bucket.  When
+  rule's largest, (4) ties keeping a dispatch a bucket.  A round admits at
+  most ``ROUND_ADMITS`` requests, so a burst larger than that is admitted
+  over several rounds and live streams get a segment between them.  When
   nothing could be admitted anyway, the call that fetched a segment launches
   the next one before it returns, and the tokens are fanned out while the
   device works (``GenerationScheduler._segment_sync``; docs/GENERATION.md).
@@ -66,6 +68,17 @@ from .prefixcache import PrefixCache
 from .tracing import RoundTimeline
 
 log = get_logger("serving.generation")
+
+# The requests one round admits at most.  A round's prefills all run before
+# its segment, so every stream already live gets no token until the last of
+# them is done: a burst that fills a pool of 64 slots a prompt a dispatch
+# held the streams admitted a round earlier for 63 prefills, and which
+# round a request of the burst fell in (the arrivals race the first round)
+# decided what its stream waited.  Past this many the rest stay pending,
+# their slots free, and the rounds that follow admit them, each after a
+# segment (``_segment_sync`` chains none while a request is pending and a slot
+# free).  No lane of at most this many slots ever meets the bound.
+ROUND_ADMITS = 32
 
 
 def _pow2(n: int) -> int:
@@ -1149,7 +1162,8 @@ class GenerationScheduler:
                 if not inflight or not self._free:
                     _note_seen(self._pending, t_top)
                 if not inflight:
-                    while self._free and self._pending:
+                    while (self._free and self._pending
+                           and len(admits) < ROUND_ADMITS):
                         req = self._pending.popleft()
                         req.note_slotted(t_top, round_no)
                         admits.append((req, self._free.pop()))

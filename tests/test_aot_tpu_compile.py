@@ -881,3 +881,163 @@ def test_mellum_programs_compile_for_v5e_and_hold_no_score_array(
     temp = done.memory_analysis().temp_size_in_bytes
     print(f"mellum {P:,} prefill temporaries: {temp / 1e9:.2f} GB")
     assert temp < (0.8e9 if P == 8192 else 3.5e9)
+
+
+@pytest.mark.parametrize("p", [2048, 8192])
+def test_flash_attention_with_values_narrower_than_keys_compiles_for_v5e(
+        one_chip, p):
+    """JoyAI-LLM-Flash's prompt attention: 32 heads, keys of 192 (padded to
+    256 lanes), values of 128: the output is 128 wide."""
+    args = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+            for shape in ((1, p, 32, 192), (1, p, 32, 192), (1, p, 32, 128))]
+    done = jax.jit(functools.partial(flash_attention, causal=True,
+                                     interpret=False)).lower(*args).compile()
+    text = done.as_text()
+    assert "flash_attention" in text and text.count("tpu_custom_call") == 1
+    assert f"bf16[1,32,{p},128]" in text  # what the kernel writes
+
+
+def test_latent_attention_compiles_for_v5e_with_one_pool_operand(one_chip):
+    """The benchmark's leaf, [10, 64, 9216, 640] bfloat16 (576 values a row
+    in five whole lane tiles), read in blocks of 384 rows: one custom call,
+    whose operands hold the leaf once."""
+    import re
+
+    from pytorch_zappa_serverless_tpu.ops.decode_attention import (
+        latent_attention)
+
+    S, T, D, values = 64, 9216, 640, 512
+    bt = pick_block_t(T, D, jnp.bfloat16)
+    assert bt == 384
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(lambda q, pool, last, layer: latent_attention(
+        q, pool, last, layer=layer, heads=32, values=values, block_t=bt)
+    ).lower(sd(S, 32 * D), sd(10, S, T, D), sd(S, dtype=jnp.int32),
+            sd(dtype=jnp.int32))
+    (call,) = [line for line in lowered.as_text().splitlines()
+               if "tpu_custom_call" in line]
+    assert len(re.findall(r"tensor<10x64x9216x640xbf16>",
+                          call.rsplit(" : ", 1)[1].split(" -> ")[0])) == 1
+    text = lowered.compile().as_text()
+    assert "latent_attention" in text and text.count("tpu_custom_call") == 1
+
+
+def _joyai_shapes(cfg, sd):
+    """JoyAI-LLM-Flash's parameter tree as shapes (``sd(*shape, dtype=)``)."""
+    D, H = cfg.hidden_size, cfg.heads
+
+    def vec(n=D):
+        return sd(n, dtype=jnp.float32)
+
+    params = {"embed": sd(cfg.vocab_size, D), "head": sd(D, cfg.vocab_size),
+              "norm": vec()}
+    for i in range(cfg.layers):
+        p = {"input_norm": vec(), "post_attention_norm": vec(),
+             "q_down": sd(D, cfg.q_lora_rank), "q_norm": vec(cfg.q_lora_rank),
+             "q_up": sd(cfg.q_lora_rank, H * cfg.qk_dim),
+             "kv_down": sd(D, cfg.row_width),
+             "kv_norm": vec(cfg.kv_lora_rank),
+             "k_up": sd(cfg.kv_lora_rank, H * cfg.nope_dim),
+             "v_up": sd(cfg.kv_lora_rank, H * cfg.v_dim),
+             "o": sd(H * cfg.v_dim, D)}
+        if i < cfg.dense_layers:
+            F = cfg.dense_width
+            p.update(w1=sd(D, F), w3=sd(D, F), w2=sd(F, D))
+        else:
+            E, F = cfg.experts_held, cfg.expert_width
+            p.update(router=sd(D, cfg.experts_published),
+                     expert_bias=vec(cfg.experts_published),
+                     w1=sd(E, D, F), w3=sd(E, D, F), w2=sd(E, F, D),
+                     shared_w1=sd(D, F), shared_w3=sd(D, F),
+                     shared_w2=sd(F, D))
+        params[f"layer{i}"] = p
+    return params
+
+
+@pytest.mark.parametrize("program", ["segment", "prefill 8192"])
+def test_joyai_programs_compile_for_v5e_and_move_none_of_the_pool(
+        one_chip, monkeypatch, program):
+    """The benchmark's ten layers of JoyAI-LLM-Flash at the published widths
+    with 32 of 256 experts, with the kernels a chip takes (the pickers ask
+    the backend, which is the CPU here, so the test steers them).  The
+    64-slot segment over the one leaf (9,216 rows of 640): ``latent_attention``
+    once a layer and ``expert_matmul`` twice an expert layer, no other
+    kernel; the leaf goes in and comes out in one buffer, and
+    the temporaries are under 0.4 GB (12.1 GB of weights and pool are
+    arguments).  The prefill of one prompt of 8,192 positions:
+    ``flash_attention`` once a layer, so no float32 array with the 32 heads
+    and two dimensions of 8,192, the pool aliased, nothing as large as a
+    slot's rows of a layer moved, and the temporaries under 1.5 GB."""
+    import re
+
+    import chip_smoke
+    from pytorch_zappa_serverless_tpu.models import joyai
+    from pytorch_zappa_serverless_tpu.ops import (
+        expert_matmul as expert_matmul_module)
+
+    cfg = joyai.config_from_arch(
+        {"layers": 10, "experts_held": 32, "eos_id": 129280})
+    slots, total, P = 64, 8192 + 1024, 8192
+    monkeypatch.setattr(
+        decode_attention_module, "_kernel_block",
+        lambda Tq, total, d, dtype: (pick_block_t(total, d, dtype)
+                                     if Tq == 1 else None))
+    monkeypatch.setattr(expert_matmul_module, "_use_kernel", lambda: True)
+    monkeypatch.setattr(joyai.LatentRows, "prompt_form",
+                        lambda self, *shape: "flash_mla")
+    monkeypatch.setattr(joyai, "flash_attention", functools.partial(
+        flash_attention, interpret=False))
+
+    def sd(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = _joyai_shapes(cfg, sd)
+    fam = joyai.family(cfg)
+    (shape, dt), = decoder.cache_leaves(fam, slots, fam.rows.count(total),
+                                        jnp.bfloat16)
+    assert shape == (10, 64, 9216, 640)
+    leaf = sd(*shape, dtype=dt)
+    pool_bytes = int(np.prod(shape)) * 2
+    if program == "segment":
+        i32, f32 = sd(slots, dtype=jnp.int32), sd(slots, dtype=jnp.float32)
+
+        def segment(p, leaf, tok, pos, st, fin, temp, seeds, topk, topp):
+            pool, state = decoder.slot_pools(fam, (leaf,))
+            return decoder.decode_segment(
+                fam, p, pool, tok, pos, st, fin, temp, seeds, 8,
+                jnp.bfloat16, top_k=topk, top_p=topp, state=state)
+
+        done = jax.jit(segment, donate_argnums=(1,)).lower(
+            params, leaf, i32, i32, i32, sd(slots, dtype=jnp.bool_),
+            f32, i32, i32, f32).compile()
+        text = done.as_text()
+        # A kernel a layer for the rows, two an expert layer, and no other.
+        assert text.count("latent_attention") >= 10
+        assert text.count("tpu_custom_call") == 10 + 2 * 9
+        mem = done.memory_analysis()
+        assert mem.alias_size_in_bytes >= pool_bytes
+        print(f"joyai segment temporaries: {mem.temp_size_in_bytes / 1e9:.3f}"
+              f" GB, arguments {mem.argument_size_in_bytes / 1e9:.2f} GB")
+        assert mem.temp_size_in_bytes < 0.4e9
+        return
+    done = jax.jit(
+        lambda p, leaf, at, tokens, lengths: decoder.prefill(
+            fam, p, tokens, lengths, (leaf,), at, jnp.bfloat16),
+        donate_argnums=(1,)).lower(
+            params, leaf, sd(1, dtype=jnp.int32), sd(1, P, dtype=jnp.int32),
+            sd(1, dtype=jnp.int32)).compile()
+    text = done.as_text()
+    assert text.count("flash_attention") >= 10
+    assert text.count("expert_combine") >= 9
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        dims = [int(d) for d in dims.split(",")]
+        assert not (32 in dims and dims.count(P) >= 2), dims
+    mem = done.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert chip_smoke.prefill_pool_moves(text, 9216, 640) == []
+    print(f"joyai {P:,} prefill temporaries: "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert mem.temp_size_in_bytes < 1.5e9

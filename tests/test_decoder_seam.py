@@ -281,6 +281,27 @@ _NEMOTRON_ARCH = {
     "chunk_size": 8, "experts_published": 16, "experts_held": 4,
     "expert_offset": 4, "top_k": 3, "latent_size": 32, "expert_width": 48,
     "shared_width": 80, "max_positions": 512, "init_std": 0.1, "eos_id": 96}
+_LFM2_ARCH = {
+    "vocab_size": 96, "hidden_size": 64,
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv"],
+    "dense_layers": 2, "dense_width": 96, "heads": 8, "kv_heads": 2,
+    "head_dim": 8, "experts_published": 8, "experts_held": 8, "top_k": 2,
+    "expert_width": 48, "rope_theta": 100.0, "max_positions": 512,
+    "init_std": 0.1, "eos_id": 96}
+_MELLUM_ARCH = {
+    "vocab_size": 96, "hidden_size": 64,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+    "heads": 8, "kv_heads": 2, "head_dim": 8, "sliding_window": 8,
+    "experts_published": 8, "experts_held": 8, "top_k": 2,
+    "expert_width": 48, "rope_theta": 100.0, "yarn_original_positions": 16,
+    "max_positions": 512, "init_std": 0.1, "eos_id": 96}
+_JOYAI_ARCH = {
+    "vocab_size": 96, "hidden_size": 64, "layers": 3, "heads": 4,
+    "q_lora_rank": 48, "kv_lora_rank": 32, "nope_dim": 16, "rope_dim": 8,
+    "v_dim": 16, "dense_layers": 1, "dense_width": 96,
+    "experts_published": 16, "experts_held": 4, "expert_offset": 4,
+    "top_k": 4, "expert_width": 48, "rope_theta": 100.0,
+    "max_positions": 512, "init_std": 0.1, "eos_id": 96}
 _SLOT = {"max_new_tokens": 8, "gen_slots": 3, "segment_tokens": 4}
 LOWERED = {
     "gpt2": ("gpt2", "bfloat16", (16,), {
@@ -292,6 +313,9 @@ LOWERED = {
         **_SLOT, "max_new_tokens": 16, "arch": _EVA_ARCH}),
     "nemotron": ("nemotron_h", "bfloat16", (16,), {
         **_SLOT, "arch": _NEMOTRON_ARCH}),
+    "lfm2": ("lfm2", "bfloat16", (16,), {**_SLOT, "arch": _LFM2_ARCH}),
+    "mellum": ("mellum", "bfloat16", (16,), {**_SLOT, "arch": _MELLUM_ARCH}),
+    "joyai": ("joyai", "bfloat16", (16,), {**_SLOT, "arch": _JOYAI_ARCH}),
 }
 # sha256 of ``jit(...).lower(...).as_text()`` (no source locations in it) of
 # the slot lane's programs for the CPU, with the JAX this repository is
@@ -340,7 +364,7 @@ def lowered_programs():
                    for key, v in meta["admit_spec"](buckets[0]).items()}
         cache = tuple(jax.ShapeDtypeStruct(shape, dt)
                       for shape, dt in meta["cache_leaves"])
-        assert name == "nemotron" or (
+        assert name in ("nemotron", "lfm2", "mellum", "joyai") or (
             len(cache) == 2 and not meta["counters"])
 
         def per_slot(dt):
@@ -403,6 +427,74 @@ def test_nemotron_lowers_to_the_text_it_had_before_the_grouped_kernel(
         f"{case} lowers to other text than is pinned (jax {jax.__version__}, "
         f"pinned under 0.9.0).  If this PR meant to change that program, or "
         f"JAX moved, pin {digest!r} in PR46_NEMOTRON_TEXT")
+
+
+# LFM2's and Mellum 2's programs for the CPU as the parent of PR 55 (commit
+# 3ef190a) lowered them, pinned by the PR that let a row be one leaf
+# (``Rows.values``, ``absorb``, ``expand``) and gave ``flash_attention`` a
+# value width: a family that declares none of it gets the text it had.  And
+# JoyAI-LLM-Flash's own, as that PR made them: the one leaf donated to both
+# programs.
+PR55_TEXT = {
+    "lfm2-prefill":
+        "df6d87eef00f1f8ede148678360d84c90a489c9b42655294797be364299a2fe0",
+    "lfm2-segment":
+        "112417fc10d896c95914f6c564f236a474424f166abae89e74d98c6a8160e5ad",
+    "mellum-prefill":
+        "b53a3a02a1ac6ce058a6bbda64c1d3614f7015dab8b6feffc287769065de4e03",
+    "mellum-segment":
+        "0eb1a7579f45992a36470051693147776e211a4a3d7a1a64f8c18265b143bc5f",
+    "joyai-prefill":
+        "111699fd6e7ef6eb3c36e73d5ca6633d007cec986fe8510688c650c9a60e19ac",
+    "joyai-segment":
+        "6466b9714bdb97a5cba01b64897e9d0992ddc0121586afc66f4e540c0031f9a2",
+}
+
+
+@pytest.mark.parametrize("case", list(PR55_TEXT))
+def test_families_lower_to_the_text_they_had_before_the_one_leaf_row(
+        case, lowered_programs):
+    import hashlib
+
+    family, program = case.split("-")
+    text = lowered_programs(family)[program].as_text()
+    digest = hashlib.sha256(text.encode()).hexdigest()
+    assert digest == PR55_TEXT[case], (
+        f"{case} lowers to other text than is pinned (jax {jax.__version__}, "
+        f"pinned under 0.9.0).  If this PR meant to change that program, or "
+        f"JAX moved, pin {digest!r} in PR55_TEXT")
+
+
+def test_joyai_declares_one_leaf_and_the_seam_follows_it():
+    """The first family whose row is one leaf: ``cache_leaves`` gives one
+    where it gives a K and a V, ``slot_pools`` a pool with no V,
+    ``SlotPool.write`` writes the one row, and the leaves after it are the
+    state's as ever."""
+    from pytorch_zappa_serverless_tpu.models import joyai
+
+    cfg = joyai.config_from_arch({"layers": 10, "experts_held": 32})
+    fam = joyai.family(cfg)
+    assert (fam.layers, fam.width, fam.rows.values, fam.heads) == (
+        10, 640, 512, 32)
+    assert not fam.kinds and not fam.state and D.row_leaves(fam) == 1
+    assert D.row_leaves(TOY) == 2 and D.ROWS.values is None
+    assert D.cache_leaves(fam, 64, 9216, jnp.bfloat16) == (
+        ((10, 64, 9216, 640), jnp.bfloat16),)
+    assert [z.shape for z in D.zero_cache(fam, 2, 24, jnp.float32)] == [
+        (10, 2, 24, 640)]
+    pool, state = D.slot_pools(fam, (jnp.zeros((2, 3, 8, 640)),))
+    assert pool.v is None and state == () and D._leaves(pool) == (pool.k,)
+    wrote = pool.write(jnp.int32(1), jnp.asarray([0, 5, 7]),
+                       jnp.ones((3, 1, 640)), None)
+    assert wrote.v is None and float(wrote.k.sum()) == 3 * 640
+    assert np.asarray(wrote.k[1, 1, 5] == 1).all()
+    # A family of two leaves a row: the pair, as ever.
+    both, _ = D.slot_pools(TOY, (jnp.zeros((2, 3, 8, 4)),) * 2)
+    assert both.v is not None and len(D._leaves(both)) == 2
+    # The two methods a decode step calls are the identity unless a family
+    # brings its own.
+    q = jnp.ones((1, 1, 4))
+    assert D.ROWS.absorb(None, q) is q and D.ROWS.expand(None, q) is q
 
 
 def test_lfm2_declares_its_leaves_and_its_counters():

@@ -192,7 +192,7 @@ def test_a_prompt_past_the_largest_bucket_is_refused():
 # window's worth of positions, window 2,048).
 CAPS = {"gpt2": lambda b: None, "evabyte": lambda b: max(1, 2048 // b),
         "nemotron_h": lambda b: PREFILL_BATCH, "lfm2": lambda b: 1,
-        "mellum": lambda b: 1}
+        "mellum": lambda b: 1, "joyai": lambda b: 1}
 CONFIGS = sorted({(w["config"], w["traffic"]) for w in json.loads(
     (ROOT / "BENCHMARK.json").read_text())["workloads"]})
 
@@ -292,6 +292,66 @@ async def test_a_prompt_that_rides_up_is_served_what_it_is_served_alone(engine):
             snap["prompts_moved_up"]) == (64, 42, 1)
     assert [(p["batch"], p["bucket"], p["moved"]) for p in launches] \
         == [(4, 16, 1)]
+
+
+@pytest.mark.parametrize("engine", ["gpt2"], indirect=True)
+async def test_a_burst_past_the_round_s_bound_is_admitted_over_rounds(
+        engine, monkeypatch):
+    """Five prompts arrive together at a bound of three a round: three are
+    prefilled, a segment runs for them, then the other two are prefilled.
+    Every stream's greedy tokens are what it is served alone."""
+    from pytorch_zappa_serverless_tpu.serving import generation
+
+    eng, name = engine
+    cm = eng.model("m")
+    rng = np.random.default_rng(11)
+    samples = [cm.servable.preprocess(
+        {"input_ids": [int(t) for t in rng.integers(1, SERVED[name][2], n)]})
+        for n in (5, 7, 3, 8, 6)]
+
+    async def serve(together: bool):
+        sched = GenerationScheduler(cm, eng.runner, cm.cfg).start()
+        try:
+            if not together:
+                return [await asyncio.wait_for(sched.submit(s).done, 120)
+                        for s in samples], None
+            reqs = [sched.submit(s) for s in samples]
+            out = await asyncio.wait_for(
+                asyncio.gather(*[r.done for r in reqs]), 120)
+            return out, [[p["phase"] for p in r["phases"]
+                          if p["phase"] in ("prefill.launch",
+                                            "segment.launch")]
+                         + [p["batch"] for p in r["phases"]
+                            if p["phase"] == "prefill.launch"]
+                         for r in sched.timeline.recent(64)]
+        finally:
+            await sched.stop()
+
+    alone, _ = await serve(False)
+    whole, rounds = await serve(True)  # the bound unmet: one admission
+    assert whole == alone
+    admitting = [r for r in rounds if "prefill.launch" in r]
+    assert [r[-1] for r in admitting] == [5]
+
+    monkeypatch.setattr(generation, "ROUND_ADMITS", 3)
+    split, rounds = await serve(True)
+    assert split == alone and all(len(t) == 6 for t in split)
+    admitting = [r for r in rounds if "prefill.launch" in r]
+    assert [r[-1] for r in admitting] == [3, 2]
+    # The first three streams got a segment before the other two's prefill.
+    assert all("segment.launch" in r for r in admitting)
+
+
+def test_the_round_s_bound_is_met_by_no_lane_of_at_most_its_slots():
+    """The bound leaves every accepted cell's rounds as they were: only a
+    configuration of more slots than it can ever meet it."""
+    from pytorch_zappa_serverless_tpu.serving.generation import ROUND_ADMITS
+
+    slots = {c: int(json.loads((ROOT / "benchmark" / "configs" / f"{c}.json")
+                               .read_text())["serve"]["extra"]["gen_slots"])
+             for c, _ in CONFIGS}
+    assert {c for c, n in slots.items() if n > ROUND_ADMITS} \
+        == {"joyai-flash-10l"}
 
 
 # -- a prefill at a longer bucket, every family -------------------------------------

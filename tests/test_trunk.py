@@ -10,8 +10,8 @@ bit for bit, on every pool and family the programs serve; and to the ledger's
 
 Tiny sizes: GPT-2 in bfloat16 (3 layers, 2 heads of 16), the W8A16 lane as
 its builder quantizes it (2 layers of 128, the Mosaic kernel interpreted),
-EvaByte (3 layers, window 32, chunk 4) and GPT-2 with two tenants' LoRA
-stacks.
+EvaByte (3 layers, window 32, chunk 4), GPT-2 with two tenants' LoRA
+stacks, and JoyAI-LLM-Flash (3 layers, a pool of one leaf, counters).
 """
 
 import dataclasses
@@ -29,6 +29,7 @@ from pytorch_zappa_serverless_tpu.engine.cache import CompileClock
 from pytorch_zappa_serverless_tpu.models import decoder as D
 from pytorch_zappa_serverless_tpu.models import evabyte as E
 from pytorch_zappa_serverless_tpu.models import gpt2 as G
+from pytorch_zappa_serverless_tpu.models import joyai as J
 from pytorch_zappa_serverless_tpu.ops import lora as L
 from pytorch_zappa_serverless_tpu.utils.registry import get_model_builder
 
@@ -37,20 +38,28 @@ def loop_trunk(fam, params, x, pos, cache, attend, adapter_idx=None,
                lengths=None, put=None):
     """The trunk as it was before the shared body: a Python loop that calls
     the family's block afresh a layer, with the layer's index a Python int
-    (for the families whose cache is K and V rows and nothing else)."""
+    (for the families whose cache is rows, a K and a V or one leaf, and
+    nothing else; a family that counts is handed ``count=``)."""
     stacks = None if adapter_idx is None else params.get("__adapters__")
+    counts = None
+
+    def count(c):
+        nonlocal counts
+        counts = c if counts is None else counts + c
+
+    hooks = {"state": None, "count": count} if fam.counters else {}
     for i in range(fam.layers):
         p = params[f"layer{i}"]
 
-        def layer_attend(q, k, v, i=i, p=p):
+        def layer_attend(q, k, v=None, i=i, p=p):
             nonlocal cache
             cache, out = attend(p, cache, i, q, k, v)
             return out
 
         x = fam.layer(p, x, layer_attend, pos,
                       lora=None if stacks is None else stacks.get(f"layer{i}"),
-                      lora_idx=adapter_idx)
-    return fam.norm(params, x), cache, None
+                      lora_idx=adapter_idx, **hooks)
+    return fam.norm(params, x), cache, counts
 
 
 # -- the families -------------------------------------------------------------
@@ -102,8 +111,20 @@ def _lora():
         dtype
 
 
+def _joyai():
+    cfg = J.config_from_arch({
+        "vocab_size": 96, "hidden_size": 64, "layers": 3, "heads": 4,
+        "q_lora_rank": 48, "kv_lora_rank": 32, "nope_dim": 16, "rope_dim": 8,
+        "v_dim": 16, "dense_layers": 1, "dense_width": 96,
+        "experts_published": 16, "experts_held": 4, "expert_offset": 4,
+        "top_k": 4, "expert_width": 48, "rope_theta": 100.0,
+        "max_positions": 512, "init_std": 0.1, "eos_id": 96})
+    tree = jax.tree.map(jnp.asarray, J.init_joyai_params(0, cfg))
+    return J.family(cfg, jnp.float32), tree, jnp.float32
+
+
 FAMILIES = {"gpt2": _gpt2, "w8a16": _w8a16, "evabyte": _evabyte,
-            "lora": _lora}
+            "lora": _lora, "joyai": _joyai}
 
 
 @pytest.fixture(scope="module")
@@ -139,13 +160,17 @@ def _program(name, fam, dtype, adapters):
                                                    dtype, aidx),
                 (toks, lens))
     if name == "segment":
-        T = fam.rows.count(total)
-        shape = (fam.layers, S, T, fam.width)
+        leaves = D.cache_leaves(fam, S, fam.rows.count(total), dtype)
         pos = jnp.asarray([P, total - 2, 9], jnp.int32)
-        return (lambda p, ck, cv, tok, pos: D.decode_segment(
-            fam, p, D.slot_pool(ck, cv, fam.rows), tok, pos, zi, fin, zf, zi,
-            3, dtype, adapter_idx=aidx)[:3],
-            (pool(*shape), pool(*shape), toks[:, 0], pos))
+
+        def segment(p, *rest):  # the pool's leaves (a K and a V, or one)
+            mine, state = D.slot_pools(fam, rest[:-2])
+            return D.decode_segment(
+                fam, p, mine, *rest[-2:], zi, fin, zf, zi, 3, dtype,
+                adapter_idx=aidx, state=state)[:3]
+
+        return segment, (*(pool(*shape) for shape, _ in leaves), toks[:, 0],
+                         pos)
     # The paged programs: MB pages a slot of a pool of 2 + S * MB.
     MB = total // BS
     shape = (fam.layers, 2 + S * MB, BS, fam.width)
@@ -202,19 +227,20 @@ def test_a_program_traces_its_layer_once_and_lowers_it_once(built, family,
     ("gpt2", "paged_segment"), ("gpt2", "verify"), ("gpt2", "propose"),
     ("w8a16", "prefill"), ("w8a16", "segment"), ("evabyte", "prefill"),
     ("evabyte", "segment"), ("lora", "prefill"), ("lora", "segment"),
-    ("lora", "prefill_chunk"), ("lora", "paged_segment")])
+    ("lora", "prefill_chunk"), ("lora", "paged_segment"),
+    ("joyai", "prefill"), ("joyai", "segment")])
 def test_the_shared_body_is_the_loop_bit_for_bit(built, monkeypatch, family,
                                                  program):
     """Everything a program returns (logits or tokens, then both cache
-    arrays) with the shared body against the loop over the same inputs: on
-    the slot pool, the paged pool, with adapter indices, on ``TwoTier``
-    rows."""
+    arrays, or the one leaf and what follows it) with the shared body
+    against the loop over the same inputs: on the slot pool, the paged pool,
+    with adapter indices, on ``TwoTier`` rows, on rows of one leaf."""
     fam, params, dtype = built(family)
     fn, args = _program(program, fam, dtype, family == "lora")
     shared = jax.jit(fn)(params, *args)
     monkeypatch.setattr(D, "_trunk", loop_trunk)
     loop = jax.jit(fn)(params, *args)
-    assert len(shared) >= 3
+    assert len(shared) >= 3 - (family == "joyai")  # one leaf, not two
     for got, want in zip(shared, loop, strict=True):
         assert got.dtype == want.dtype
         assert np.array_equal(np.asarray(got), np.asarray(want),
