@@ -2444,14 +2444,23 @@ def _mellum_child(rehearse: bool) -> None:
 
 def time_latent_attention(slots: int, total: int, width: int, values: int,
                           heads: int, lives, on_device: bool,
-                          interpret: bool):
+                          interpret: bool, forms=None):
     """``latent_attention`` alone over a leaf ``[2, slots, total, width]``
-    whose every slot holds ``live`` rows: with one pool operand, with the
-    leaf handed twice to the grouped ``decode_attention`` (for the record: a
-    block is then fetched as K and as V), each beside the least the chip
-    could take over the live rows' bytes as stored (bfloat16, 819 GB/s);
-    off the device, where the rows carry no time, the ``jax.numpy`` form is
-    held against both."""
+    whose every slot holds ``live`` rows, a row a form a ``live``: device
+    microseconds a layer and a grid step, beside the least the chip could
+    take to copy a step's block and a layer's live rows as stored (bfloat16,
+    819 GB/s).
+
+    ``forms`` are ``(name, block_t, buffers)``; ``block_t`` None is the
+    block the served path picks (``pick_block_t``) and ``buffers`` None
+    hands the leaf twice to the grouped ``decode_attention`` instead (a block
+    is then fetched as K and as V by the pipeline's own copies).  The default
+    is PERF.md's table of PR 56: the kernel as served; the same with two
+    buffers (a block's copy started in the step before the one that reads
+    it, the order of the pipeline's own copies, which is what the kernel ran
+    until PR 56) and with one more than served; a block twice as long; the
+    leaf handed twice.  Off the device, where the rows carry no time, the
+    ``jax.numpy`` form is held against each."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -2463,63 +2472,68 @@ def time_latent_attention(slots: int, total: int, width: int, values: int,
                                            dtype=np.float32), jnp.bfloat16)
     q = jnp.asarray(rng.standard_normal((slots, heads * width)) * 0.02,
                     jnp.bfloat16)
-    bt = da.pick_block_t(total, width, jnp.bfloat16)
+    served = da.pick_block_t(total, width, jnp.bfloat16)
+    if forms is None:
+        forms = [("one pool operand", None, da._LATENT_BUFFERS),
+                 ("two buffers", None, 2),
+                 ("a buffer more", None, da._LATENT_BUFFERS + 1),
+                 ("a block twice as long", 2 * served, da._LATENT_BUFFERS),
+                 ("the leaf handed twice", None, None)]
+    forms = [(name, bt or served, buffers) for name, bt, buffers in forms
+             if total % (bt or served) == 0]
     rows = []
 
-    def one_operand(q, leaf, wpos, work, layer):
-        return da.latent_attention(q, leaf, wpos, work, layer=layer,
-                                   heads=heads, values=values, block_t=bt,
-                                   interpret=interpret)
-
-    def leaf_twice(q, leaf, wpos, work, layer):
+    def attend(bt, buffers, q, leaf, wpos, work, layer):
+        if buffers is not None:
+            return da.latent_attention(q, leaf, wpos, work, layer=layer,
+                                       heads=heads, values=values, block_t=bt,
+                                       buffers=buffers, interpret=interpret)
         out = da.decode_attention(q, leaf, leaf, wpos, work, layer=layer,
                                   heads=heads, block_t=bt,
                                   interpret=interpret)
         return out.reshape(slots, heads, width)[..., :values].reshape(
             slots, heads * values)
 
-    def numpy_form(q, leaf, wpos, work, layer):
-        return da.attend_latent(q[:, None], leaf, layer, wpos[:, None],
-                                heads, values)[:, 0]
-
-    forms = [("one pool operand", one_operand),
-             ("the leaf handed twice", leaf_twice)]
     for live in lives:
         wpos = jnp.full((slots,), live - 1, jnp.int32)
         floor = slots * live * width * 2 / 819e9 * 1e6
-        got = {}
-        for form, attend in forms + [("jax.numpy", numpy_form)]:
+        want = da.attend_latent(q[:, None], leaf, 0, wpos[:, None], heads,
+                                values)[:, 0]
+        for form, bt, buffers in forms:
             @jax.jit
             def chain(q, leaf, wpos):
                 work = da.work_list(wpos, total, bt)
                 for j in range(_TIMED_CALLS):
                     # Each call's queries hang on the one before it: calls
                     # alike in every operand would be folded into one.
-                    out = attend(q, leaf, wpos, work, j % 2)
+                    out = attend(bt, buffers, q, leaf, wpos, work, j % 2)
                     q = q + jnp.pad(
                         out.reshape(slots, heads, values),
                         ((0, 0), (0, 0), (0, width - values))).reshape(
                             q.shape) * 0.01
                 return q
 
-            if form == "jax.numpy" and on_device:
-                continue  # on the chip ``attend_latent`` takes the kernel
             chain(q, leaf, wpos).block_until_ready()
-            got[form] = attend(q, leaf, wpos,
-                               da.work_list(wpos, total, bt), 0)
+            got = attend(bt, buffers, q, leaf, wpos,
+                         da.work_list(wpos, total, bt), 0)
+            # (On the chip ``attend_latent`` is the kernel as served.)
+            off = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                        - want.astype(jnp.float32))))
+            assert off < 0.05, (live, form, off)
+            steps = slots * -(-live // bt)
             row = {"leaf": [slots, total, width], "heads": heads,
                    "values": values, "live": live, "form": form,
-                   "block_t": bt, "floor_us": round(floor, 2)}
+                   "block_t": bt, "buffers": buffers, "grid_steps": steps,
+                   "floor_us": round(floor, 2),
+                   "copy_floor_us_a_step": round(
+                       bt * width * 2 * (1 if buffers else 2) / 819e9 * 1e6,
+                       4)}
             if on_device:
                 row["us_a_layer"] = _busy_us(lambda: chain(q, leaf, wpos))
+                row["us_a_step"] = round(row["us_a_layer"] / steps, 4)
                 row["gb_per_s"] = round(floor * 819 / row["us_a_layer"], 1)
                 row["share_of_819"] = round(floor / row["us_a_layer"], 3)
             rows.append(row)
-        for form in got:
-            off = float(jnp.max(jnp.abs(
-                got[form].astype(jnp.float32)
-                - got["one pool operand"].astype(jnp.float32))))
-            assert off < 0.05, (live, form, off)
     return rows
 
 
@@ -2530,8 +2544,10 @@ def _joyai_child(rehearse: bool) -> None:
     the CPU).
 
     Alone, by the profiler's clock: ``latent_attention`` at 64 slots over
-    the leaf at 2k, 4k, 6k and all 9,216 rows live, beside the same read
-    with the leaf handed twice; ``flash_attention`` at keys of 192 and
+    the leaf at 2k, 4k, 6k and all 9,216 rows live, a layer and a grid
+    step, as served and in the forms ``time_latent_attention`` lists (fewer
+    and more buffers, a longer block, the leaf handed twice);
+    ``flash_attention`` at keys of 192 and
     values of 128 at the four buckets (the share of 197 TFLOP/s is of the
     work the grid visits, keys padded to 256 lanes); the gated
     ``expert_matmul`` at ``K`` 2048, ``F`` 768 at 2 and 256 rows an expert,

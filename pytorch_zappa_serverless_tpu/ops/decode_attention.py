@@ -45,11 +45,14 @@ output in those same columns, picked out afterwards.  ``D`` is the pool's
 width wherever a block is sized.
 
 A pool whose rows are one leaf (latent attention: a position's row is
-scored whole and its first ``values`` columns are what is summed) is the
-grouped case with one K/V head and **one pool operand**
-(:func:`latent_attention`, under its own name in a capture): the values are
-sliced out of the block of rows the kernel already holds, so a block is
-fetched once.  Its ``jax.numpy`` form and its entry are :func:`attend_latent`.
+scored whole and its first ``values`` columns are what is summed) has a
+kernel of its own over **one pool operand** (:func:`latent_attention`,
+under its own name in a capture): the same work list, the same blocks, the
+same online softmax, but the values are sliced out of the block of rows the
+kernel already holds, so a block is fetched once, and the kernel copies its
+blocks itself, ``_LATENT_BUFFERS - 1`` grid steps ahead of the step that
+reads them (:func:`_latent_kernel` says why).  Its ``jax.numpy`` form and
+its entry are :func:`attend_latent`.
 
 A model calls :func:`attend` (with :func:`step_work` once a step, and
 :func:`read_block` for what it reports), which takes the kernel or the
@@ -76,6 +79,13 @@ _SLAB_BYTES = 512 << 10
 # What K's and V's blocks, two buffers each, may take of the 16 MiB of VMEM
 # a kernel is given by default.
 _VMEM_BYTES = 8 << 20
+# Blocks of rows the latent kernel keeps in VMEM: the one a grid step reads
+# and the copies in flight behind it.  From the kernel alone on the v5e
+# (chip_smoke.py *joyai*; PERF.md section 6, PR 56): a copy of one block is
+# done 0.45 us + its bytes after it is started, longer than the grid step it
+# is started in, so with two buffers (the pipeline's own) a step waits for
+# the mean of that and its body; started two steps ahead it is there.
+_LATENT_BUFFERS = 3
 
 
 def _slab(d: int, dtype) -> tuple[int, int]:
@@ -141,7 +151,7 @@ def work_list(wpos, total: int, block_t: int, first=None):
 
 def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
             v_ref, o_ref, m_ref, l_ref, acc_ref, *, block_t: int,
-            head_dim: int, grouped: bool = False, values: int | None = None):
+            head_dim: int, grouped: bool = False):
     i = pl.program_id(0)
     b = block_ref[i]
     last = wpos_ref[slot_ref[i]]
@@ -179,8 +189,7 @@ def _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref, k_ref,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(scores - m_new)
     l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-    # ``values``: the rows' own leading columns (``v_ref`` is ``k_ref``).
-    v = v_ref[...] if values is None else v_ref[:, :values]
+    v = v_ref[...]
     acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -276,32 +285,119 @@ def decode_attention(q, cache_k, cache_v, wpos, work=None, first=None, *,
 
 
 def _latent_kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref,
-                   rows_ref, o_ref, m_ref, l_ref, acc_ref, *, block_t: int,
-                   values: int):
-    """:func:`_kernel`'s grouped form over one operand: the block of rows is
-    the keys, and its first ``values`` columns the values."""
-    _kernel(layer_ref, slot_ref, block_ref, wpos_ref, first_ref, q_ref,
-            rows_ref, rows_ref, o_ref, m_ref, l_ref, acc_ref, block_t=block_t,
-            head_dim=0, grouped=True, values=values)
+                   pool_ref, o_ref, m_ref, l_ref, acc_ref, rows_ref, sem_ref, *,
+                   block_t: int, values: int):
+    """A grid step of :func:`latent_attention`: block ``i`` of the work list,
+    the keys its rows and the values their first ``values`` columns.
+
+    The mathematics is :func:`_kernel`'s grouped form (scores, the block's
+    max, ``exp``, the sums, ``probs @ values``, float32 throughout but the
+    two products' operands), laid out for what a grid step waits on
+    (PERF.md section 6, PR 56):
+
+    *Who copies the blocks.*  ``pool_ref`` is the whole leaf where it lies
+    in HBM, and ``rows_ref`` ``[buffers, block_t, D]`` holds the blocks in
+    flight: step ``i`` starts the copy of block ``i + buffers - 1`` into the
+    buffer that block ``i - 1`` has left, waits for its own and reads it.
+    (The pipeline's own two buffers start a block's copy in the step before
+    the one that reads it, and that copy outlasts the step:
+    ``_LATENT_BUFFERS``.  Every copy started is waited for by the step that
+    reads it, so none outlives the call.)
+
+    *The running max and sum a lane tile wide.*  ``m_ref`` and ``l_ref`` are
+    ``[rows, 128]`` (``[rows, block_t]`` where no whole tiles make up a
+    block): the max the same in every column, the sum a partial sum a
+    column, added up across columns once, where the span ends.  A ``[rows,
+    1]`` max has to be spread over the lanes again before ``exp`` can take
+    it, in the middle of the one chain a step is, and the sum's cross-lane
+    add a step is spared too: a float32 sum reordered, nothing left out."""
+    i = pl.program_id(0)
+    count = pl.num_programs(0)
+    buffers = rows_ref.shape[0]
+    lanes = m_ref.shape[1]
+
+    def copy(j):
+        at = j % buffers
+        row = pl.multiple_of(block_ref[j] * block_t, block_t)
+        return pltpu.make_async_copy(
+            pool_ref.at[layer_ref[0], slot_ref[j], pl.ds(row, block_t)],
+            rows_ref.at[at], sem_ref.at[at])
+
+    @pl.when(i == 0)
+    def _():
+        for j in range(buffers - 1):
+            pl.when(j < count)(lambda j=j: copy(j).start())
+
+    @pl.when(i + buffers - 1 < count)
+    def _():
+        copy(i + buffers - 1).start()
+
+    copy(i).wait()
+    at = i % buffers
+    b = block_ref[i]
+    last = wpos_ref[slot_ref[i]]
+    first = first_ref[slot_ref[i]]
+
+    @pl.when(b == first // block_t)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[...]                                  # [rows, D], a head a row
+    scores = jax.lax.dot_general(
+        q, rows_ref[at], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                   # [rows, bt]
+    # Every block visited is live; the select in float32, as in ``_kernel``.
+    kpos = b * block_t + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    scores = jnp.where((kpos >= first) & (kpos <= last), scores, _MASKED)
+    tiles = [scores[:, k:k + lanes] for k in range(0, block_t, lanes)]
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, functools.reduce(jnp.maximum, tiles).max(
+        axis=-1, keepdims=True))                              # [rows, lanes]
+    alpha = jnp.exp(m_prev - m_new)
+    probs = [jnp.exp(tile - m_new) for tile in tiles]
+    l_ref[...] = alpha * l_ref[...] + functools.reduce(jnp.add, probs)
+    p = probs[0] if len(probs) == 1 else jnp.concatenate(probs, axis=1)
+    # ``alpha`` over the accumulator's columns: its lane tile side by side
+    # where whole tiles make them up (one column of it spread over the lanes
+    # costs 0.02 us a step on the v5e), else that column.
+    scale = (jnp.concatenate([alpha] * (values // lanes), axis=1)
+             if values % lanes == 0 else alpha[:, :1])
+    acc_ref[...] = scale * acc_ref[...] + jnp.dot(
+        p.astype(q.dtype), rows_ref[at, :, :values],
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+    @pl.when(b == last // block_t)
+    def _():
+        # Row h whole: a head's ``values`` columns.
+        o_ref[...] = (acc_ref[...] / l_ref[...].sum(
+            axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "values", "block_t",
-                                             "interpret"))
+                                             "buffers", "interpret"))
 def latent_attention(q, pool, wpos, work=None, first=None, *, layer,
                      heads: int, values: int, block_t: int | None = None,
-                     interpret: bool = False):
+                     buffers: int = _LATENT_BUFFERS, interpret: bool = False):
     """q [S, heads * D] (already scaled, each head's query over the row's
     own ``D`` columns), pool [L, S, T, D] the one leaf, ``layer``, ``wpos``,
     ``first`` and ``work`` as :func:`decode_attention` takes them → [S,
     heads * values]: each head's probabilities over its slot's span against
     the rows' first ``values`` columns.  The ``[heads, D]`` query block of a
-    slot meets each block of rows once, fetched once."""
+    slot meets each block of rows once, fetched once, by the kernel's own
+    copies into ``buffers`` blocks of VMEM (two is the order of the
+    pipeline's own copies: chip_smoke.py times it for the record)."""
     S = q.shape[0]
     T, D = pool.shape[2:]
     bt = block_t or pick_block_t(T, D, pool.dtype)
     layer, slot, block, count, wpos, first = _step_scalars(
         layer, wpos, first, work, T, bt)
     rows = -(-heads // 16) * 16  # the bf16 sublane tile
+    # Columns of the running max and sum: a lane tile where whole tiles make
+    # up a block of scores, else the block.
+    lanes = 128 if bt % 128 == 0 else bt
     q = jnp.pad(q.reshape(S, heads, D), ((0, 0), (0, rows - heads), (0, 0)))
 
     def at_slot(width):
@@ -315,14 +411,13 @@ def latent_attention(q, pool, wpos, work=None, first=None, *, layer,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(count,),  # a dynamic bound: the live blocks and no more
-            in_specs=[at_slot(D), pl.BlockSpec(
-                (None, None, bt, D),
-                lambda i, layer, slot, block, wpos, first:
-                (layer[0], slot[i], block[i], 0))],
+            in_specs=[at_slot(D), pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=at_slot(values),
-            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, values), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((rows, lanes), jnp.float32),
+                            pltpu.VMEM((rows, lanes), jnp.float32),
+                            pltpu.VMEM((rows, values), jnp.float32),
+                            pltpu.VMEM((buffers, bt, D), pool.dtype),
+                            pltpu.SemaphoreType.DMA((buffers,))]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

@@ -900,11 +900,20 @@ def test_flash_attention_with_values_narrower_than_keys_compiles_for_v5e(
 def test_latent_attention_compiles_for_v5e_with_one_pool_operand(one_chip):
     """The benchmark's leaf, [10, 64, 9216, 640] bfloat16 (576 values a row
     in five whole lane tiles), read in blocks of 384 rows: one custom call,
-    whose operands hold the leaf once."""
+    whose operands hold the leaf once (where it lies: the kernel copies its
+    blocks itself).  The kernel has a body of its own: ``decode_attention``'s
+    takes no ``values``, and the other families' calls cannot reach it."""
+    import inspect
     import re
 
     from pytorch_zappa_serverless_tpu.ops.decode_attention import (
         latent_attention)
+
+    for call in (decode_attention_module.decode_attention,
+                 decode_attention_module._kernel):
+        assert "values" not in inspect.signature(call).parameters
+    assert "_kernel" not in (
+        decode_attention_module._latent_kernel.__code__.co_names)
 
     S, T, D, values = 64, 9216, 640, 512
     bt = pick_block_t(T, D, jnp.bfloat16)

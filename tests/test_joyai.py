@@ -239,19 +239,39 @@ def test_the_absorbed_step_is_the_expanded_attention(tree):
 # heads, row width, value columns, rows a slot, rows a block.
 SHAPES = {"32 queries over 640, values 512": (32, 640, 512, 256, 64),
           "4 queries over 40, values 32 (padded to 16 rows)": (4, 40, 32, 64,
-                                                              16)}
-# The last row each of 5 slots reads (negative: dead) and the first.
-SPANS = {"ragged, one dead": ([-1, 255, 5, 17, 0], None),
-         "all dead": ([-1, -1, -1, -1, -1], None),
-         "spans with a start": ([63, -1, 40, 17, 9], [0, 0, 33, 16, 9])}
+                                                              16),
+          "blocks of 48 rows, which no 32 values make up": (4, 40, 32, 192,
+                                                            48)}
+# The last row each of 5 slots reads (negative: dead) and the first, by the
+# rows a slot holds and the rows a block.  The kernel copies its blocks itself,
+# some grid steps ahead, so the spans end and start on every side of a block's
+# edge, leave dead slots between live ones, and make lists shorter than the
+# blocks in flight.
+SPANS = {
+    "ragged, one dead": lambda T, bt: ([-1, 255, 5, 17, 0], None),
+    "all dead": lambda T, bt: ([-1, -1, -1, -1, -1], None),
+    "spans with a start": lambda T, bt: ([63, -1, 40, 17, 9],
+                                         [0, 0, 33, 16, 9]),
+    "ends inside the first block, on its edge and a row past it":
+        lambda T, bt: ([bt // 2, bt - 1, bt, bt - 2, bt + 1], None),
+    "a span of one row": lambda T, bt: ([0, -1, -1, -1, -1], None),
+    "a span of one row in a later block":
+        lambda T, bt: ([-1, -1, 2 * bt + 3, -1, -1], [0, 0, 2 * bt + 3, 0, 0]),
+    "a first inside a later block":
+        lambda T, bt: ([T - 1, 3 * bt, 2 * bt + 1, T - 2, bt],
+                       [2 * bt + 1, bt + bt // 2, 2 * bt, 3 * bt + 1, bt]),
+    "dead slots between live ones":
+        lambda T, bt: ([T - 1, -1, bt, -1, 2 * bt - 1], None),
+    "two blocks in all": lambda T, bt: ([-1, -1, -1, bt + 1, -1], None),
+    "every row of every slot": lambda T, bt: ([T - 1] * 5, None),
+}
 
 
-@pytest.mark.parametrize("spans", list(SPANS))
-@pytest.mark.parametrize("shape", list(SHAPES))
-def test_the_latent_kernel_reads_its_span_and_is_the_jnp_form(shape, spans):
+def _latent_case(shape, spans, seed=0):
+    """(q, pool with the rows no span holds spoiled, last, first, want)."""
     heads, width, values, T, bt = SHAPES[shape]
-    last, first = SPANS[spans]
-    rng = np.random.default_rng(0)
+    last, first = SPANS[spans](T, bt)
+    rng = np.random.default_rng(seed)
     S, L = 5, 3
     last = jnp.minimum(jnp.asarray(last, jnp.int32), T - 1)
     first = None if first is None else jnp.asarray(first, jnp.int32)
@@ -266,14 +286,50 @@ def test_the_latent_kernel_reads_its_span_and_is_the_jnp_form(shape, spans):
     # Rows no live span holds reach nothing, whatever they hold (finite: a
     # masked row of a visited block weighs an exact zero).
     pool = jnp.where(held[None, :, :, None], pool, 1e3)
+    return q, pool, last, first, want
+
+
+@pytest.mark.parametrize("spans", list(SPANS))
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_the_latent_kernel_reads_its_span_and_is_the_jnp_form(shape, spans):
+    heads, width, values, T, bt = SHAPES[shape]
+    q, pool, last, first, want = _latent_case(shape, spans)
     got = DA.latent_attention(q[:, 0], pool, last, None, first, layer=1,
                               heads=heads, values=values, block_t=bt,
                               interpret=True)
-    assert got.shape == (S, heads * values)
+    assert got.shape == (5, heads * values)
     assert np.abs(np.asarray(got) - np.asarray(want[:, 0])).max() < 2e-6
     assert not np.asarray(got)[np.asarray(last) < 0].any()
     if spans != "all dead":
         assert np.abs(np.asarray(got)).max() > 0.01
+
+
+@pytest.mark.parametrize("buffers", [1, 2, 4, 7])
+@pytest.mark.parametrize("spans", ["ragged, one dead",
+                                   "a first inside a later block",
+                                   "two blocks in all"])
+def test_the_latent_kernel_reads_the_same_whatever_blocks_it_keeps_in_flight(
+        spans, buffers):
+    """One buffer copies and reads in turn, two is the order of the
+    pipeline's own copies, seven is more than the blocks of most lists here."""
+    shape = "4 queries over 40, values 32 (padded to 16 rows)"
+    heads, width, values, T, bt = SHAPES[shape]
+    q, pool, last, first, want = _latent_case(shape, spans, seed=1)
+    got = DA.latent_attention(q[:, 0], pool, last, None, first, layer=1,
+                              heads=heads, values=values, block_t=bt,
+                              buffers=buffers, interpret=True)
+    assert np.abs(np.asarray(got) - np.asarray(want[:, 0])).max() < 2e-6
+    assert not np.asarray(got)[np.asarray(last) < 0].any()
+
+
+def test_the_latent_kernel_refuses_a_block_that_does_not_divide_the_rows():
+    shape = "4 queries over 40, values 32 (padded to 16 rows)"
+    heads, width, values, T, bt = SHAPES[shape]
+    q, pool, last, first, _ = _latent_case(shape, "ragged, one dead")
+    with pytest.raises(ValueError, match="block_t 48 does not divide"):
+        DA.latent_attention(q[:, 0], pool, last, None, first, layer=1,
+                            heads=heads, values=values, block_t=48,
+                            interpret=True)
 
 
 def test_the_latent_kernel_s_call_has_one_pool_operand():
