@@ -891,7 +891,7 @@ def _device_ns(run):
         compute, counts, _, _ = op_time_breakdown(trace_dir, capture)
     busy = until = 0
     for plane in capture[0]:
-        for start, end, _, is_op in plane["ops"]:
+        for start, end, _, is_op, _ in plane["ops"]:
             if is_op and end > until:
                 busy += end - max(start, until)
                 until = end

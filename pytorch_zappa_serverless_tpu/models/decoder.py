@@ -42,6 +42,31 @@ from ..ops.sampling import (DRAFT_SEED_SALT, apply_repetition_penalty,
                             choose)
 
 
+# The named parts of a model's step, one vocabulary for every family: what a
+# profiler capture's device time is booked to (utils/xplane.py reads the
+# scope back out of each operation's ``op_name``; docs/OBSERVABILITY.md says
+# what each part holds).  A dot makes a sub-part.
+PARTS = ("embed", "norm", "qkv", "attend", "cache_write", "attend_out", "mlp",
+         "shared", "route", "experts.sort", "experts.matmul",
+         "experts.unsort", "ssm", "conv", "summary", "head", "sample")
+# What a part's scope begins with, so that no primitive's or function's name
+# in an ``op_name`` path (``jit(norm)``, ``conv_general_dilated``) can be
+# taken for a part.
+PART_MARK = "part."
+
+
+def part(name: str):
+    """The scope of one of :data:`PARTS`: what is traced inside it is that
+    part's work (the innermost one, where they nest).  Metadata on the
+    traced operations and nothing else: the lowered text, the compile
+    cache's key and the compiled program are the same with and without (a
+    scope's name is in none of them; the source *lines* of the frames above
+    a Mosaic kernel are in its payload, whoever edits them)."""
+    if name not in PARTS:
+        raise ValueError(f"{name!r} is no part of a model's step: {PARTS}")
+    return jax.named_scope(PART_MARK + name)
+
+
 class Rows:
     """How the ``T`` rows of a slot hold its positions, and what a query
     reads of them.  This one is a row a position: position ``p`` lies at row
@@ -267,10 +292,11 @@ class SlotPool(NamedTuple):
         """This layer's ``k``, ``v`` [S, 1, D] for position ``pos`` [S]
         (``p``: the layer's parameters, for what the rows keep beside)."""
         row = self.rows.row(pos, self.k.shape[2])
-        ck = self.k.at[layer, self.slots, row].set(k[:, 0])
-        if self.v is None:  # one leaf: the row is all there is
-            return self._replace(k=ck)
-        cv = self.v.at[layer, self.slots, row].set(v[:, 0])
+        with part("cache_write"):
+            ck = self.k.at[layer, self.slots, row].set(k[:, 0])
+            if self.v is None:  # one leaf: the row is all there is
+                return self._replace(k=ck)
+            cv = self.v.at[layer, self.slots, row].set(v[:, 0])
         ck, cv = self.rows.settle(p, ck, cv, layer, self.slots, pos)
         return self._replace(k=ck, v=cv)
 
@@ -326,10 +352,11 @@ def slot_put(slots):
     first prompt's slot and writes that prompt's values again)."""
     def put(leaf, layer, values, row=0):
         at = (jnp.int32(row),) + (jnp.int32(0),) * (leaf.ndim - 3)
-        for b in range(values.shape[0]):
-            leaf = jax.lax.dynamic_update_slice(
-                leaf, values[b][None, None].astype(leaf.dtype),
-                (layer, slots[b]) + at)
+        with part("cache_write"):
+            for b in range(values.shape[0]):
+                leaf = jax.lax.dynamic_update_slice(
+                    leaf, values[b][None, None].astype(leaf.dtype),
+                    (layer, slots[b]) + at)
         return leaf
 
     return put
@@ -372,8 +399,9 @@ class PagedPool(NamedTuple):
                 self.block_size)
             return pages.at[layer, bidx, off].set(values)
 
-        ck = put(self.k, k)
-        return self._replace(k=ck, v=put(self.v, v))
+        with part("cache_write"):
+            ck = put(self.k, k)
+            return self._replace(k=ck, v=put(self.v, v))
 
     def _virtual(self, pages, layer):
         return gather_kv(pages[layer], self.table)
@@ -399,12 +427,13 @@ class PagedPool(NamedTuple):
 def _embed(fam: Family, params, tokens, pos, dtype, clamp=True):
     """``tokens`` at ``pos`` as rows.  A learned position is clamped to its
     table (defensive: the servable's guard keeps every stream inside it)."""
-    x = fam.embed(params, tokens, dtype)
-    if fam.positions is None:
-        return x
-    table = fam.positions(params, dtype)
-    return x + table[jnp.minimum(pos, fam.max_positions - 1) if clamp
-                     else pos]
+    with part("embed"):
+        x = fam.embed(params, tokens, dtype)
+        if fam.positions is None:
+            return x
+        table = fam.positions(params, dtype)
+        return x + table[jnp.minimum(pos, fam.max_positions - 1) if clamp
+                         else pos]
 
 
 def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
@@ -509,7 +538,8 @@ def _trunk(fam: Family, params, x, pos, cache, attend, adapter_idx=None,
             None if stacks is None else stacks.get(f"layer{i}"), **kind)
         if c is not None:
             counts = c if counts is None else counts + c
-    return fam.norm(params, x), cache, counts
+    with part("head"):  # the final norm is the head's
+        return fam.norm(params, x), cache, counts
 
 
 def _write_then_attend(fam, pool, wpos, span, work=None):
@@ -541,7 +571,8 @@ def _decode_logits(fam, params, pool, cache, tok, wpos, span, work, dtype,
         attend = _write_then_attend(fam, pool, wpos, span, work)
     x, cache, counts = _trunk(fam, params, x, wpos[:, None], cache, attend,
                               adapter_idx)
-    return fam.head(params, x[:, 0]), cache, counts
+    with part("head"):
+        return fam.head(params, x[:, 0]), cache, counts
 
 
 def segment_scan(step, cache, tok, pos, t, finished, seg: int, eos_id: int,
@@ -568,18 +599,21 @@ def segment_scan(step, cache, tok, pos, t, finished, seg: int, eos_id: int,
     def body(carry, _):
         cache, tok, pos, t, finished, seen, tally = carry
         cache, nxt, seen, *counts = step(cache, tok, pos, t, finished, seen)
-        if counters:
-            tally = tally + counts[0]
-        emit = jnp.where(finished, eos_id, tok)
-        fin = finished | (tok == eos_id)
-        tok_next = jnp.where(fin, eos_id, nxt)
-        pos_next = jnp.where(fin, pos, pos + 1)
+        with part("sample"):  # what the step decided, packed for the host
+            if counters:
+                tally = tally + counts[0]
+            emit = jnp.where(finished, eos_id, tok)
+            fin = finished | (tok == eos_id)
+            tok_next = jnp.where(fin, eos_id, nxt)
+            pos_next = jnp.where(fin, pos, pos + 1)
         return (cache, tok_next, pos_next, t + 1, fin, seen, tally), emit
 
     tally = jnp.zeros((counters,), jnp.int32) if counters else None
     carry, emits = jax.lax.scan(
         body, (cache, tok, pos, t, finished, seen, tally), None, length=seg)
-    return (jnp.transpose(emits, (1, 0)), *jax.tree.leaves(carry[0]),
+    with part("sample"):
+        emits = jnp.transpose(emits, (1, 0))
+    return (emits, *jax.tree.leaves(carry[0]),
             *carry[1:5], *([carry[6]] if counters else []))
 
 
@@ -616,8 +650,10 @@ def prefill(fam: Family, params: dict, tokens: jax.Array, lengths: jax.Array,
 
     x, cache, _ = _trunk(fam, params, x, pos, tuple(cache), prompt,
                          adapter_idx, lengths, put)
-    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return (fam.head(params, last),) + cache
+    with part("head"):
+        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None],
+                                   axis=1)[:, 0]
+        return (fam.head(params, last),) + cache
 
 
 def _penalized(logits, seen, repetition_penalty, on):
@@ -644,11 +680,12 @@ def prefill_start(fam: Family, params: dict, tokens: jax.Array,
     """
     logits, *cache = prefill(fam, params, tokens, lengths, cache, slots,
                              dtype, adapter_idx=adapter_idx)
-    if repetition_penalty is not None:
-        logits = _penalized(logits, presence, repetition_penalty,
-                            jnp.any(repetition_penalty != 1.0))
-    first = choose(logits, temperature, seeds,
-                   jnp.zeros(tokens.shape[:1], jnp.int32), top_k, top_p)
+    with part("sample"):
+        if repetition_penalty is not None:
+            logits = _penalized(logits, presence, repetition_penalty,
+                                jnp.any(repetition_penalty != 1.0))
+        first = choose(logits, temperature, seeds,
+                       jnp.zeros(tokens.shape[:1], jnp.int32), top_k, top_p)
     return (first, *cache)
 
 
@@ -695,20 +732,22 @@ def decode_segment(fam: Family, params: dict, pool, tok: jax.Array,
         # it is dead to attention, which reads nothing of its row.
         spans, works = [], []
         for each in pools:  # a span and a list of live blocks a kind
-            first, last = each.span(wpos)
-            last = jnp.where(finished, -1, last)
-            spans.append((first, last))
-            works.append(decode_attention.step_work(
-                last, each.k.shape[2], fam.width, each.k.dtype, first))
+            with part("attend"):  # the step's share of it, once for all layers
+                first, last = each.span(wpos)
+                last = jnp.where(finished, -1, last)
+                spans.append((first, last))
+                works.append(decode_attention.step_work(
+                    last, each.k.shape[2], fam.width, each.k.dtype, first))
         if not isinstance(pool, list):
             spans, works = spans[0], works[0]
         logits, cache, counts = _decode_logits(
             fam, params, pool, cache, tok, wpos, spans, works, dtype,
             adapter_idx)
-        if seen is not None:
-            seen = seen.at[pools[0].slots, tok].set(True)
-            logits = _penalized(logits, seen, repetition_penalty, rep_on)
-        nxt = choose(logits, temperature, seeds, t + 1, top_k, top_p)
+        with part("sample"):
+            if seen is not None:
+                seen = seen.at[pools[0].slots, tok].set(True)
+                logits = _penalized(logits, seen, repetition_penalty, rep_on)
+            nxt = choose(logits, temperature, seeds, t + 1, top_k, top_p)
         return (cache, nxt, seen, *([counts] if fam.counters else []))
 
     return segment_scan(one, (*(leaf for each in pools
@@ -796,10 +835,13 @@ def prefill_chunk(fam: Family, params: dict, tokens: jax.Array,
 
     x, cache, _ = _trunk(fam, params, x, pos, (pool.k, pool.v), attend,
                          adapter_idx)
-    idx = jnp.clip(lengths - 1 - start, 0, C - 1)
-    last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    first = choose(fam.head(params, last), temperature, seeds,
-                   jnp.zeros((G,), jnp.int32), top_k, top_p)
+    with part("head"):
+        idx = jnp.clip(lengths - 1 - start, 0, C - 1)
+        last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+        logits = fam.head(params, last)
+    with part("sample"):
+        first = choose(logits, temperature, seeds,
+                       jnp.zeros((G,), jnp.int32), top_k, top_p)
     return (first,) + cache
 
 
@@ -834,7 +876,9 @@ def propose(fam: Family, params: dict, pool, prev: jax.Array, tok: jax.Array,
         logits, (cache_k, cache_v), _ = _decode_logits(
             fam, params, pool, (cache_k, cache_v), cur, wpos,
             pool.span(wpos), None, dtype)
-        nxt = choose(logits, temperature, draft_seeds, t + 1, top_k, top_p)
+        with part("sample"):
+            nxt = choose(logits, temperature, draft_seeds, t + 1, top_k,
+                         top_p)
         # Backfill step feeds the pending token next; proposal steps feed
         # the model's own choice.
         prop = jnp.where(finished, fam.eos_id, jnp.where(first, tok, nxt))
@@ -872,7 +916,8 @@ def verify(fam: Family, params: dict, pool, toks: jax.Array, pos: jax.Array,
     x = _embed(fam, params, toks, wp, dtype)
     x, cache, _ = _trunk(fam, params, x, wp, (pool.k, pool.v),
                          _write_then_attend(fam, pool, wp, pool.span(wp)))
-    logits = fam.head(params, x.reshape(S * K1, -1)).reshape(S, K1, -1)
+    with part("head"):
+        logits = fam.head(params, x.reshape(S * K1, -1)).reshape(S, K1, -1)
     return (logits,) + cache
 
 

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import decode_attention, flash_attention
-from .decoder import Family, Rows, make_servable
+from .decoder import Family, Rows, make_servable, part
 
 
 @dataclass(frozen=True)
@@ -102,9 +102,25 @@ def _layer(p, x, cfg: EvaByteConfig, attend, pos):
         # temporaries for 16 layers over 12,288 positions by its own
         # analysis, 1.1 GB so (PERF.md section 6, PR 35).
         p, x = jax.lax.optimization_barrier((p, x))
-    h = _norm(p["n1"], x, cfg.rms_norm_eps, dt)
+    with part("norm"):
+        h = _norm(p["n1"], x, cfg.rms_norm_eps, dt)
+    with part("qkv"):
+        q, k, v = _qkv(p, h, cfg, pos, x.shape[1] == 1)
+    with part("attend"):
+        a = attend(q, k, v).astype(dt)
+    with part("attend_out"):
+        x = x + (a @ p["o"]).astype(jnp.float32)
+    with part("norm"):
+        n = _norm(p["n2"], x, cfg.rms_norm_eps, dt)
+    with part("mlp"):
+        y = (jax.nn.silu(n @ p["gate"]) * (n @ p["up"])) @ p["down"]
+        return x + y.astype(jnp.float32)
+
+
+def _qkv(p, h, cfg: EvaByteConfig, pos, decode: bool):
+    """The three projections of ``h``, the queries and keys turned."""
     q, k = h @ p["q"], h @ p["k"]
-    if x.shape[1] == 1:
+    if decode:
         # A decode step: the projections are whole before they are split
         # into heads.  The trunk calls this block as one function of its
         # weights, and XLA simplifies a function called from several sites
@@ -114,13 +130,8 @@ def _layer(p, x, cfg: EvaByteConfig, attend, pos):
         # launch and 1.1 GB of temporaries (compiled for a described v5e:
         # PERF.md section 6, PR 43).
         q, k = jax.lax.optimization_barrier((q, k))
-    q = _rope(q, pos, cfg.heads, cfg.rope_theta)
-    k = _rope(k, pos, cfg.heads, cfg.rope_theta)
-    a = attend(q, k, h @ p["v"]).astype(dt)
-    x = x + (a @ p["o"]).astype(jnp.float32)
-    n = _norm(p["n2"], x, cfg.rms_norm_eps, dt)
-    y = (jax.nn.silu(n @ p["gate"]) * (n @ p["up"])) @ p["down"]
-    return x + y.astype(jnp.float32)
+    return (_rope(q, pos, cfg.heads, cfg.rope_theta),
+            _rope(k, pos, cfg.heads, cfg.rope_theta), h @ p["v"])
 
 
 def summarize(p, k, v, real, heads: int):
@@ -128,7 +139,7 @@ def summarize(p, k, v, real, heads: int):
     its values, ``real`` [..., c] which of its positions are written →
     ``(kbar, vbar)`` [..., D] float32.  A chunk with no real position gives
     a finite row that nothing reads."""
-    with jax.named_scope("eva_summary"):
+    with part("summary"):
         *lead, c, D = k.shape
         dh = D // heads
         kh = k.astype(jnp.float32).reshape(*lead, c, heads, dh)
@@ -229,7 +240,7 @@ class TwoTier(Rows):
         → [B, Pw, D].  A query reads its own window's keys at or below it
         and the summaries of the windows before, in one softmax; rows past
         a length hold finite values that mean nothing."""
-        with jax.named_scope("eva_prefill_attend"):
+        with part("attend"):
             if form == "kernel":
                 return self._kernel_windows(heads, q, k, v, kbar, vbar,
                                             lengths)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.logging import get_logger, log_event
-from .decoder import Family, make_servable
+from .decoder import Family, make_servable, part
 
 log = get_logger("models.gpt2")
 
@@ -109,20 +109,28 @@ def _layer(p, x, cfg, attend, lora=None, lora_idx=None):
 
         return lora_apply(y, inp, lora[name], lora_idx)
 
-    h = _ln(p["ln1"], x, cfg.ln_eps)
-    if "qkv" in p:
-        # Fused projection (int8 lane): one [D, 3D] matmul instead of three —
-        # 2 fewer kernel launches per layer per decode step, and the W8A16
-        # Pallas kernel amortizes its grid setup over 3x the weight block.
-        q_, k_, v_ = jnp.split(_dense(p["qkv"], h), 3, axis=-1)
-    else:
-        k_, v_ = ad("k", _dense(p["k"], h), h), ad("v", _dense(p["v"], h), h)
-        q_ = ad("q", _dense(p["q"], h), h)
-    ao = attend(q_, k_, v_)
-    x = x + ad("out", _dense(p["out"], ao), ao)
-    h = _ln(p["ln2"], x, cfg.ln_eps)
-    h2 = jax.nn.gelu(ad("fc1", _dense(p["fc1"], h), h), approximate=True)
-    return x + ad("fc2", _dense(p["fc2"], h2), h2)
+    with part("norm"):
+        h = _ln(p["ln1"], x, cfg.ln_eps)
+    with part("qkv"):
+        if "qkv" in p:
+            # Fused projection (int8 lane): one [D, 3D] matmul instead of
+            # three — 2 fewer kernel launches per layer per decode step, and
+            # the W8A16 Pallas kernel amortizes its grid setup over 3x the
+            # weight block.
+            q_, k_, v_ = jnp.split(_dense(p["qkv"], h), 3, axis=-1)
+        else:
+            k_ = ad("k", _dense(p["k"], h), h)
+            v_ = ad("v", _dense(p["v"], h), h)
+            q_ = ad("q", _dense(p["q"], h), h)
+    with part("attend"):
+        ao = attend(q_, k_, v_)
+    with part("attend_out"):
+        x = x + ad("out", _dense(p["out"], ao), ao)
+    with part("norm"):
+        h = _ln(p["ln2"], x, cfg.ln_eps)
+    with part("mlp"):
+        h2 = jax.nn.gelu(ad("fc1", _dense(p["fc1"], h), h), approximate=True)
+        return x + ad("fc2", _dense(p["fc2"], h2), h2)
 
 
 def _logits(params, x):

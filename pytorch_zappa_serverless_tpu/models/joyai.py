@@ -64,7 +64,7 @@ import numpy as np
 
 from ..ops import expert_matmul
 from ..ops.flash_attention import flash_attention
-from .decoder import Family, Rows, make_servable
+from .decoder import Family, Rows, make_servable, part
 
 
 @dataclass(frozen=True)
@@ -164,11 +164,12 @@ def _latent(cfg: JoyAIConfig, p, h, pos):
 
 
 def _attention(cfg: JoyAIConfig, p, h, attend, pos):
-    with jax.named_scope("joyai_latent"):
+    with part("qkv"):  # the down-projections, their norms, the rotation
         q, row = _latent(cfg, p, h, pos)
-    with jax.named_scope("joyai_attend"):
-        out = attend(q, row)
-    return out.astype(h.dtype) @ p["o"]
+    with part("attend"):
+        out = attend(q, row).astype(h.dtype)
+    with part("attend_out"):
+        return out @ p["o"]
 
 
 def _gated(h, w1, w3, w2):
@@ -181,17 +182,16 @@ def _gated(h, w1, w3, w2):
 def _experts(cfg: JoyAIConfig, p, h, count):
     B_, T, D = h.shape
     rows = h.reshape(B_ * T, D)
-    with jax.named_scope("joyai_route"):
+    with part("route"):
         weights, group = expert_matmul.route(
             rows, p["router"], p["expert_bias"], cfg.top_k, cfg.routed_scale,
             cfg.expert_offset, cfg.experts_held)
-    with jax.named_scope("joyai_experts"):
-        out, sizes = expert_matmul.experts(rows, p["w1"], p["w2"], weights,
-                                           group, w3=p["w3"])
+    out, sizes = expert_matmul.experts(rows, p["w1"], p["w2"], weights,
+                                       group, w3=p["w3"])
     count(expert_matmul.counters(sizes))
-    with jax.named_scope("joyai_shared"):
+    with part("shared"):
         shared = _gated(h, p["shared_w1"], p["shared_w3"], p["shared_w2"])
-    return out.astype(h.dtype).reshape(B_, T, D) + shared
+        return out.astype(h.dtype).reshape(B_, T, D) + shared
 
 
 def _layer(cfg: JoyAIConfig, p, x, attend, pos, count):
@@ -201,12 +201,19 @@ def _layer(cfg: JoyAIConfig, p, x, attend, pos, count):
         # A prompt pass: this layer's weights are touched when its input is
         # there and no sooner (models/evabyte.py has the reason).
         p, x = jax.lax.optimization_barrier((p, x))
-    h = _norm(p["input_norm"], x, cfg.norm_eps)
-    x = x + _attention(cfg, p, h, attend, pos)
-    h = _norm(p["post_attention_norm"], x, cfg.norm_eps)
+    with part("norm"):
+        h = _norm(p["input_norm"], x, cfg.norm_eps)
+    y = _attention(cfg, p, h, attend, pos)
+    with part("attend_out"):
+        x = x + y
+    with part("norm"):
+        h = _norm(p["post_attention_norm"], x, cfg.norm_eps)
     if "router" in p:
-        return x + _experts(cfg, p, h, count)
-    return x + _gated(h, p["w1"], p["w3"], p["w2"])
+        y = _experts(cfg, p, h, count)
+        with part("shared"):
+            return x + y
+    with part("mlp"):
+        return x + _gated(h, p["w1"], p["w3"], p["w2"])
 
 
 # ---------------------------------------------------------------------------
@@ -247,13 +254,15 @@ class LatentRows(Rows):
 
         def attend(p, cache, i, q, row, _=None):
             B = q.shape[0]
-            c = row[..., :self.values]
-            k_rope = row[..., self.values:cfg.row_width]
-            k_nope = (c @ p["k_up"]).reshape(B, P, heads, cfg.nope_dim)
-            v = (c @ p["v_up"]).reshape(B, P, heads, cfg.v_dim)
-            k = jnp.concatenate([k_nope, jnp.broadcast_to(
-                k_rope[:, :, None, :], (B, P, heads, cfg.rope_dim))], axis=-1)
-            qh = q.reshape(B, P, heads, cfg.qk_dim)
+            with part("qkv"):  # the up-projections, and the keys built
+                c = row[..., :self.values]
+                k_rope = row[..., self.values:cfg.row_width]
+                k_nope = (c @ p["k_up"]).reshape(B, P, heads, cfg.nope_dim)
+                v = (c @ p["v_up"]).reshape(B, P, heads, cfg.v_dim)
+                k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                    k_rope[:, :, None, :], (B, P, heads, cfg.rope_dim))],
+                    axis=-1)
+                qh = q.reshape(B, P, heads, cfg.qk_dim)
             if kernel:
                 # Causal alone: a real query reads no key past its length.
                 out = flash_attention(qh, k, v, causal=True)
@@ -274,7 +283,7 @@ class LatentRows(Rows):
         columns."""
         cfg = self.cfg
         S, Tq, _ = q.shape
-        with jax.named_scope("joyai_absorb"):
+        with part("qkv"):
             qh = q.reshape(S, Tq, cfg.heads, cfg.qk_dim)
             q_lat = jnp.einsum(
                 "sqhd,chd->sqhc", qh[..., :cfg.nope_dim],
@@ -291,7 +300,7 @@ class LatentRows(Rows):
         """``o_lat_h W_UV_h`` a head."""
         cfg = self.cfg
         S, Tq, _ = out.shape
-        with jax.named_scope("joyai_absorb"):
+        with part("qkv"):
             return jnp.einsum(
                 "sqhc,chd->sqhd", out.reshape(S, Tq, cfg.heads, self.values),
                 p["v_up"].reshape(self.values, cfg.heads, cfg.v_dim),

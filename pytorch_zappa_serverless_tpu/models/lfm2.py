@@ -56,7 +56,7 @@ import numpy as np
 
 from ..ops import decode_attention, expert_matmul
 from ..ops.flash_attention import flash_attention
-from .decoder import Family, make_servable
+from .decoder import Family, make_servable, part
 from .nemotron_h import GroupedRows
 
 _PERIOD = ("full_attention", "conv", "conv", "conv")
@@ -146,16 +146,19 @@ def _conv(cfg: LFM2Config, p, h, state):
             jnp.take_along_axis(u, jnp.maximum(at, 0)[..., None], 1), 0)
         return (new_tail.astype(tail.dtype),), conv
 
-    with jax.named_scope("lfm2_conv"):
-        c = state(update)
+    c = state(update)
     return (gate_out * c.astype(h.dtype)) @ p["out_proj"]
 
 
 def _attention(cfg: LFM2Config, p, h, attend, pos):
-    with jax.named_scope("lfm2_attend"):
+    with part("qkv"):  # the norms a head and the rotation with them
         q = _normed_and_turned(p["q_norm"], h @ p["q"], pos, cfg)
         k = _normed_and_turned(p["k_norm"], h @ p["k"], pos, cfg)
-        return attend(q, k, h @ p["v"]).astype(h.dtype) @ p["o"]
+        v = h @ p["v"]
+    with part("attend"):
+        a = attend(q, k, v).astype(h.dtype)
+    with part("attend_out"):
+        return a @ p["o"]
 
 
 def _gated(h, w1, w3, w2):
@@ -168,15 +171,15 @@ def _gated(h, w1, w3, w2):
 def _experts(cfg: LFM2Config, p, h, count):
     B_, T, D = h.shape
     rows = h.reshape(B_ * T, D)
-    with jax.named_scope("lfm2_route"):
+    with part("route"):
         weights, group = expert_matmul.route(
             rows, p["router"], p["expert_bias"], cfg.top_k, cfg.routed_scale,
             cfg.expert_offset, cfg.experts_held)
-    with jax.named_scope("lfm2_experts"):
-        out, sizes = expert_matmul.experts(rows, p["w1"], p["w2"], weights,
-                                           group, w3=p["w3"])
+    out, sizes = expert_matmul.experts(rows, p["w1"], p["w2"], weights,
+                                       group, w3=p["w3"])
     count(expert_matmul.counters(sizes))
-    return out.astype(h.dtype).reshape(B_, T, D)
+    with part("experts.unsort"):  # the sum, as the residual stream takes it
+        return out.astype(h.dtype).reshape(B_, T, D)
 
 
 def _layer(cfg: LFM2Config, p, x, attend, pos, state, count):
@@ -186,15 +189,23 @@ def _layer(cfg: LFM2Config, p, x, attend, pos, state, count):
         # A prompt pass: this layer's weights are touched when its input is
         # there and no sooner (models/evabyte.py has the reason).
         p, x = jax.lax.optimization_barrier((p, x))
-    h = _norm(p["operator_norm"], x, cfg.norm_eps)
+    with part("norm"):
+        h = _norm(p["operator_norm"], x, cfg.norm_eps)
     if "in_proj" in p:
-        x = x + _conv(cfg, p, h, state)
+        with part("conv"):  # the operator whole: projections, taps, state
+            x = x + _conv(cfg, p, h, state)
     else:
-        x = x + _attention(cfg, p, h, attend, pos)
-    h = _norm(p["ffn_norm"], x, cfg.norm_eps)
+        y = _attention(cfg, p, h, attend, pos)
+        with part("attend_out"):
+            x = x + y
+    with part("norm"):
+        h = _norm(p["ffn_norm"], x, cfg.norm_eps)
     if "router" in p:
-        return x + _experts(cfg, p, h, count)
-    return x + _gated(h, p["w1"], p["w3"], p["w2"])
+        y = _experts(cfg, p, h, count)
+        with part("experts.unsort"):  # the sum's last term
+            return x + y
+    with part("mlp"):
+        return x + _gated(h, p["w1"], p["w3"], p["w2"])
 
 
 # ---------------------------------------------------------------------------

@@ -56,7 +56,7 @@ import numpy as np
 
 from ..ops import decode_attention, expert_matmul
 from ..ops.flash_attention import flash_attention
-from .decoder import Family, Kind, make_servable
+from .decoder import Family, Kind, make_servable, part
 from .lfm2 import GroupedFlashRows
 
 WINDOW, FULL = "sliding_attention", "full_attention"
@@ -156,26 +156,28 @@ def _normed_and_turned(w, x, pos, cfg: MellumConfig, kind: str):
 # ---------------------------------------------------------------------------
 
 def _attention(cfg: MellumConfig, p, h, attend, pos, kind: str):
-    scope = ("mellum_attend_window" if kind == WINDOW
-             else "mellum_attend_full")
-    with jax.named_scope(scope):
+    with part("qkv"):  # the norms a head and the kind's rotation with them
         q = _normed_and_turned(p["q_norm"], h @ p["q"], pos, cfg, kind)
         k = _normed_and_turned(p["k_norm"], h @ p["k"], pos, cfg, kind)
-        return attend(q, k, h @ p["v"]).astype(h.dtype) @ p["o"]
+        v = h @ p["v"]
+    with part("attend"):
+        a = attend(q, k, v).astype(h.dtype)
+    with part("attend_out"):
+        return a @ p["o"]
 
 
 def _experts(cfg: MellumConfig, p, h, count):
     B_, T, D = h.shape
     rows = h.reshape(B_ * T, D)
-    with jax.named_scope("mellum_route"):
+    with part("route"):
         weights, group = expert_matmul.route(
             rows, p["router"], None, cfg.top_k, 1.0, cfg.expert_offset,
             cfg.experts_held, scoring="softmax")
-    with jax.named_scope("mellum_experts"):
-        out, sizes = expert_matmul.experts(rows, p["w1"], p["w2"], weights,
-                                           group, w3=p["w3"])
+    out, sizes = expert_matmul.experts(rows, p["w1"], p["w2"], weights,
+                                       group, w3=p["w3"])
     count(expert_matmul.counters(sizes))
-    return out.astype(h.dtype).reshape(B_, T, D)
+    with part("experts.unsort"):  # the sum, as the residual stream takes it
+        return out.astype(h.dtype).reshape(B_, T, D)
 
 
 def _layer(cfg: MellumConfig, p, x, attend, pos, count, kind: str):
@@ -184,10 +186,16 @@ def _layer(cfg: MellumConfig, p, x, attend, pos, count, kind: str):
         # A prompt pass: this layer's weights are touched when its input is
         # there and no sooner (models/evabyte.py has the reason).
         p, x = jax.lax.optimization_barrier((p, x))
-    h = _norm(p["input_norm"], x, cfg.norm_eps)
-    x = x + _attention(cfg, p, h, attend, pos, kind)
-    h = _norm(p["post_attention_norm"], x, cfg.norm_eps)
-    return x + _experts(cfg, p, h, count)
+    with part("norm"):
+        h = _norm(p["input_norm"], x, cfg.norm_eps)
+    y = _attention(cfg, p, h, attend, pos, kind)
+    with part("attend_out"):
+        x = x + y
+    with part("norm"):
+        h = _norm(p["post_attention_norm"], x, cfg.norm_eps)
+    y = _experts(cfg, p, h, count)
+    with part("experts.unsort"):
+        return x + y
 
 
 # ---------------------------------------------------------------------------

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import expert_matmul
-from .decoder import Family, Rows, make_servable
+from .decoder import Family, Rows, make_servable, part
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # Prompts one prefill dispatch may hold: 8 x 512 positions make 90,112
@@ -212,29 +212,36 @@ def _mamba(cfg: NemotronHConfig, p, x, state):
         y = y + p["D"].astype(f32).reshape(G, Hg)[..., None] * xs
         return (h.reshape(B_, H, P, N), new_tail), y.reshape(B_, T, cfg.inner)
 
-    with jax.named_scope("nemotron_ssm"):
-        y = state(update)
+    y = state(update)
     y = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype)
     return _norm(p["gnorm"], y, cfg.norm_eps, G) @ p["out_proj"]
 
 
 def _attention(p, x, attend):
-    return attend(x @ p["q"], x @ p["k"], x @ p["v"]).astype(x.dtype) @ p["o"]
+    with part("qkv"):
+        q, k, v = x @ p["q"], x @ p["k"], x @ p["v"]
+    with part("attend"):
+        a = attend(q, k, v).astype(x.dtype)
+    with part("attend_out"):
+        return a @ p["o"]
 
 
 def _experts(cfg: NemotronHConfig, p, x, count):
     B_, T, D = x.shape
     rows = x.reshape(B_ * T, D)
-    with jax.named_scope("nemotron_route"):
+    with part("route"):
         weights, group = expert_matmul.route(
             rows, p["router"], p["router_bias"], cfg.top_k, cfg.routed_scale,
             cfg.expert_offset, cfg.experts_held)
-    with jax.named_scope("nemotron_experts"):
-        out, sizes = expert_matmul.experts(rows @ p["down"], p["w1"], p["w2"],
-                                           weights, group)
+    # Into the experts' latent width and out of it: the experts' matmuls too.
+    with part("experts.matmul"):
+        u = rows @ p["down"]
+    out, sizes = expert_matmul.experts(u, p["w1"], p["w2"], weights, group)
+    with part("experts.matmul"):
         y = out.astype(x.dtype) @ p["up"]
     count(expert_matmul.counters(sizes))
-    return (y + _relu2(rows, p["s1"], p["s2"])).reshape(B_, T, D)
+    with part("shared"):
+        return (y + _relu2(rows, p["s1"], p["s2"])).reshape(B_, T, D)
 
 
 def _layer(cfg: NemotronHConfig, p, x, attend, state, count):
@@ -243,14 +250,18 @@ def _layer(cfg: NemotronHConfig, p, x, attend, state, count):
         # A prompt pass: this layer's weights are touched when its input is
         # there and no sooner (models/evabyte.py has the reason).
         p, x = jax.lax.optimization_barrier((p, x))
-    h = _norm(p["norm"], x, cfg.norm_eps)
+    with part("norm"):
+        h = _norm(p["norm"], x, cfg.norm_eps)
     if "in_proj" in p:
-        y = _mamba(cfg, p, h, state)
-    elif "router" in p:
+        with part("ssm"):  # the mixer whole: projections, convolution, state
+            return x + _mamba(cfg, p, h, state).astype(x.dtype)
+    if "router" in p:
         y = _experts(cfg, p, h, count)
-    else:
-        y = _attention(p, h, attend)
-    return x + y.astype(x.dtype)
+        with part("shared"):
+            return x + y.astype(x.dtype)
+    y = _attention(p, h, attend)
+    with part("attend_out"):
+        return x + y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -498,6 +498,15 @@ def expert_matmul(x, w, sizes, relu2: bool = False, up=None):
     return out.astype(x.dtype)
 
 
+def part(name: str):
+    """models/decoder.py's scope of a named part of the model's step (what a
+    capture's device time is booked to), imported where it is used: the
+    models import this module."""
+    from ..models.decoder import part as scope
+
+    return scope(name)
+
+
 def experts(u, w1, w2, weights, group, w3=None):
     """The held experts' part of the layer: u [N, K] the rows in the width
     the experts read, w1 [held, K, F] and w2 [held, F, K] the experts'
@@ -507,21 +516,26 @@ def experts(u, w1, w2, weights, group, w3=None):
     [held, K, F], the gated expert ``W2_e (silu(W1_e u) * (W3_e u))``."""
     N, top_k = group.shape
     held = w1.shape[0]
-    flat = group.reshape(-1)
-    order = jnp.argsort(flat, stable=True)       # assignment rows by group
-    sizes = group_sizes(group, held)
+    with part("experts.sort"):
+        flat = group.reshape(-1)
+        order = jnp.argsort(flat, stable=True)   # assignment rows by group
+        sizes = group_sizes(group, held)
     chosen = plan(N * top_k, *w1.shape[1:], held, 1 if w3 is None else 2,
                   w1.dtype.itemsize)
     if _use_kernel() and chosen.regime == "tiles":
         return _experts_laid_out(u, w1, w2, w3, weights, order, sizes,
                                  chosen.tile), sizes
-    rows = u[order // top_k]                     # [N * top_k, K]
-    y = expert_matmul(expert_matmul(rows, w1, sizes, relu2=w3 is None, up=w3),
-                      w2, sizes)
-    y = jnp.where((jnp.arange(N * top_k) < sizes.sum())[:, None], y, 0)
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(N * top_k))
-    y = y[back].reshape(N, top_k, -1).astype(jnp.float32)
-    return jnp.einsum("nkd,nk->nd", y, weights), sizes
+    with part("experts.sort"):
+        rows = u[order // top_k]                 # [N * top_k, K]
+    with part("experts.matmul"):
+        y = expert_matmul(
+            expert_matmul(rows, w1, sizes, relu2=w3 is None, up=w3), w2,
+            sizes)
+    with part("experts.unsort"):
+        y = jnp.where((jnp.arange(N * top_k) < sizes.sum())[:, None], y, 0)
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(N * top_k))
+        y = y[back].reshape(N, top_k, -1).astype(jnp.float32)
+        return jnp.einsum("nkd,nk->nd", y, weights), sizes
 
 
 def sorted_places(order, sizes, top_k: int, tile: int):
@@ -550,13 +564,17 @@ def _experts_laid_out(u, w1, w2, w3, weights, order, sizes, tile: int):
     K]`` would be a copy of them all), and :func:`expert_combine` weighs and
     sums them as it reads them, at weight 0 where the expert is not held."""
     N, top_k = weights.shape
-    src, back = sorted_places(order, sizes, top_k, tile)
-    y = expert_matmul_kernel(u[src], w1, sizes, w3, relu2=w3 is None,
-                             tile=tile, laid_out=_ROW_ALIGN)
-    y = expert_matmul_kernel(y, w2, sizes, tile=tile, laid_out=tile)
-    here = back != _NOWHERE
-    # An assignment held elsewhere reads row 0 at weight 0: a row of the
-    # first group that has one, or, with no row held anywhere, a row that
-    # nothing wrote.
-    y = y[jnp.where(here, back, 0).T.reshape(-1)].reshape(top_k, N, -1)
-    return expert_combine(y, jnp.where(here, weights, 0))
+    with part("experts.sort"):
+        src, back = sorted_places(order, sizes, top_k, tile)
+        rows = u[src]
+    with part("experts.matmul"):
+        y = expert_matmul_kernel(rows, w1, sizes, w3, relu2=w3 is None,
+                                 tile=tile, laid_out=_ROW_ALIGN)
+        y = expert_matmul_kernel(y, w2, sizes, tile=tile, laid_out=tile)
+    with part("experts.unsort"):
+        here = back != _NOWHERE
+        # An assignment held elsewhere reads row 0 at weight 0: a row of the
+        # first group that has one, or, with no row held anywhere, a row
+        # that nothing wrote.
+        y = y[jnp.where(here, back, 0).T.reshape(-1)].reshape(top_k, N, -1)
+        return expert_combine(y, jnp.where(here, weights, 0))

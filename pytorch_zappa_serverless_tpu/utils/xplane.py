@@ -28,14 +28,23 @@ intervals (envelopes included), a program run is an ``XLA Modules`` event,
 the window runs from the first device event to the last.  On top of them it
 reads the host plane's ``tpuserve.*`` annotations (serving/tracing.py
 ``RoundTimeline``): they name each run and say what the host was doing in
-every gap between runs.
+every gap between runs.  And it books the device time of every run to the
+named parts of the model (models/decoder.py ``PARTS``): the scope an
+operation was traced in is in the capture, as the ``tf_op`` stat of the
+operation's event metadata, beside the ``program_id`` of the program it
+belongs to.  ``jax.profiler.ProfileData`` shows an event's own stats and not
+its metadata's, so :func:`_scopes` reads those two out of the file's
+protobuf wire format itself, the event metadata alone (a few thousand
+entries: the million events are behind one length each and are skipped).
 """
 
 from __future__ import annotations
 
 import bisect
 import collections
+import functools
 import heapq
+import operator
 import re
 from pathlib import Path
 
@@ -63,7 +72,7 @@ def op_time_breakdown(trace_dir, capture=None):
         for plane in capture[0]:
             for fam, ns in plane["async"]:
                 overlap[fam] += ns
-            for start, end, fam, is_op in plane["ops"]:
+            for start, end, fam, is_op, _ in plane["ops"]:
                 if not is_op:
                     continue
                 if _ASYNC_NAME.search(fam):
@@ -109,54 +118,211 @@ _DISPATCH = ("prefill.", "segment.")
 IN_PROGRAM = "in_program"  # idle between the operations of one program run
 
 
+def _varint(buf: bytes, at: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[at]
+        at += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, at
+        shift += 7
+
+
+def _fields(buf: bytes, at: int, end: int):
+    """The fields of the protobuf message ``buf[at:end]``, ``(number,
+    value)`` each: a varint's integer, the ``(start, end)`` of a
+    length-delimited field's bytes, None for a fixed-width one."""
+    while at < end:
+        key, at = _varint(buf, at)
+        kind = key & 7
+        if kind == 0:
+            value, at = _varint(buf, at)
+        elif kind == 2:
+            size, at = _varint(buf, at)
+            value, at = (at, at + size), at + size
+        elif kind in (1, 5):
+            value, at = None, at + (8 if kind == 1 else 4)
+        else:
+            raise ValueError(f"wire type {kind} in an xplane file")
+        yield key >> 3, value
+
+
+def _entry(buf: bytes, span) -> tuple:
+    """The ``(start, end)`` of the message a map field's entry holds."""
+    return next(v for f, v in _fields(buf, *span) if f == 2)
+
+
+@functools.lru_cache(maxsize=None)  # a capture has a few hundred scopes
+def _part(scope: str) -> str | None:
+    """The part an operation traced under ``scope`` (its ``op_name``, a
+    path) belongs to: the innermost component that models/decoder.py's
+    ``part`` made.  A primitive or a function of a part's name
+    (``jit(norm)``, ``norm``) carries no mark and is none."""
+    from ..models.decoder import PART_MARK, PARTS
+
+    for piece in reversed(scope.split("/")):
+        if piece.startswith(PART_MARK) and piece[len(PART_MARK):] in PARTS:
+            return piece[len(PART_MARK):]
+    return None
+
+
+_INSTRUCTION = re.compile(r"%([\w.\-]+)")  # a name in an instruction's text
+_READERS_DEEP = 4  # a prefetch, its wait, a layout change, then the reader
+
+
+def _by_reader(program: dict[str, str | None]) -> dict[str, str]:
+    """The part of every operation of one program that has none of its own
+    and whose result an operation with one reads: the compiler's copies,
+    prefetches and their waits carry no scope, and their time is the time
+    of what they fetch for.  ``program`` is ``{instruction text: part}``;
+    a text is ``%name = shape opcode(shape %operand, ...)``.  The nearest
+    reader decides, the first in the program's order among equals; an
+    operation nothing named reads within ``_READERS_DEEP`` steps stays in
+    no part."""
+    names = [_INSTRUCTION.findall(text) for text in program]  # its own first
+    part_of = {own[0]: part for own, part in zip(names, program.values())}
+    bare = {own[0]: text for own, (text, part) in zip(names, program.items())
+            if not part}
+    readers: dict[str, list[str]] = {}  # of the operations with no part
+    for reader, *read in names:
+        for operand in read:
+            if operand in bare:
+                readers.setdefault(operand, []).append(reader)
+    found = {}
+    for fetched, text in bare.items():
+        front = [fetched]
+        for _ in range(_READERS_DEEP):
+            front = [r for n in front for r in readers.get(n, ())]
+            named = next((part_of[r] for r in front if part_of.get(r)), None)
+            if named or not front:
+                break
+        if named:
+            found[text] = named
+    return found
+
+
+def _scopes(buf: bytes) -> dict[tuple[int, str], str]:
+    """``{(program id, event name): part}`` of a capture file's bytes, for
+    every operation whose scope names one: the ``tf_op`` and ``program_id``
+    stats of the planes' event metadata (tsl ``xplane.proto``: an XSpace's
+    planes are field 1; a plane's lines 3, event metadata 4, stat metadata
+    5; an event metadata's name 2, stats 5; a stat's metadata id 1, its
+    value 3, 4 (integers), 5 (string) or 7 (a stat metadata's name)).  The
+    key holds the program: ``%fusion.12`` is other work in a prefill than
+    in a segment.  An operation traced in no part takes its reader's
+    (:func:`_by_reader`)."""
+    found: dict[tuple[int, str], str] = {}
+    for number, plane in _fields(buf, 0, len(buf)):
+        if number != 1:
+            continue
+        events, stat_names = [], {}
+        for f, span in _fields(buf, *plane):
+            if f == 4:
+                events.append(_entry(buf, span))
+            elif f == 5:
+                at = dict(_fields(buf, *_entry(buf, span)))
+                if 1 in at and 2 in at:
+                    stat_names[at[1]] = buf[slice(*at[2])].decode()
+        ids = {name: i for i, name in stat_names.items()}
+        if "tf_op" not in ids or "program_id" not in ids:
+            continue
+        wanted = (ids["tf_op"], ids["program_id"])
+        programs: dict[int, dict[str, str | None]] = {}
+        for span in events:
+            name = program = scope = None
+            for f, v in _fields(buf, *span):
+                if f == 2:
+                    name = buf[slice(*v)].decode()
+                elif f == 5 and buf[v[0]] == 0x08:  # a stat: its id first
+                    which, at = _varint(buf, v[0] + 1)
+                    if which not in wanted:  # a dozen stats an event, two read
+                        continue
+                    stat = dict(_fields(buf, at, v[1]))
+                    if which == ids["program_id"]:
+                        program = stat.get(3, stat.get(4))
+                    else:
+                        scope = (buf[slice(*stat[5])].decode() if 5 in stat
+                                 else stat_names.get(stat.get(7), ""))
+            if program is None or not name or not name.startswith("%"):
+                continue
+            programs.setdefault(program & 0xFFFFFFFFFFFFFFFF, {})[name] = \
+                _part(scope) if scope else None
+        for program, texts in programs.items():
+            for text, part in {**texts, **_by_reader(texts)}.items():
+                if part:
+                    found[program, text] = part
+    return found
+
+
+def _module_run(ev) -> tuple[int, int, str, int | None]:
+    """``(start_ns, end_ns, module name, program id)`` of an ``XLA Modules``
+    event, whose name is ``jit_name(program id)``."""
+    module, _, program = ev.name.partition("(")
+    program = program.rstrip(")")
+    start = int(ev.start_ns)
+    return (start, start + int(ev.duration_ns), module,
+            int(program) if program.isdigit() else None)
+
+
 def read_capture(trace_dir):
     """One pass over a capture -> (device planes, host annotations).
 
     A device plane (one that has an ``XLA Ops`` line) becomes ``{"ops":
-    [(start_ns, end_ns, family, is_op)] sorted by start, "async": [(family,
-    ns)] of its ``Async XLA Ops`` line, "mods": [(start_ns, end_ns, module
-    name)] sorted}``; ``is_op`` is false for the module and step envelopes
-    on the line (``jit_*``, no `` = ``), which count as busy time and not as
-    operations.  Host annotations are ``(start_ns, end_ns, phase,
-    programs)`` sorted by start.  A capture holds a million device events
-    with a few hundred names: each event is touched once, here, for both
-    reductions of ``POST /admin/profile``."""
+    [(start_ns, end_ns, family, is_op, part)] sorted by start, "async":
+    [(family, ns)] of its ``Async XLA Ops`` line, "mods": [(start_ns,
+    end_ns, module name)] sorted}``; ``is_op`` is false for the module and
+    step envelopes on the line (``jit_*``, no `` = ``), which count as busy
+    time and not as operations, and ``part`` is the named part of the model
+    the operation was traced in (models/decoder.py ``PARTS``; None: in
+    none), looked up by the program whose run holds the operation and the
+    operation's name (:func:`_scopes`).  Host annotations are ``(start_ns,
+    end_ns, phase, programs)`` sorted by start.  A capture holds a million
+    device events with a few hundred names: each event is touched once,
+    here, for both reductions of ``POST /admin/profile``."""
     from jax.profiler import ProfileData
 
     device, host = [], []
-    families: dict[str, tuple[str, bool]] = {}
-
-    def family(name: str) -> tuple[str, bool]:
-        known = families.get(name)
-        if known is None:
-            known = families[name] = (
-                _family(name),
-                not name.startswith("jit_") and " = " in name)
-        return known
-
     for pb in sorted(Path(trace_dir).rglob("*.xplane.pb")):
-        for plane in ProfileData.from_file(str(pb)).planes:
+        data = pb.read_bytes()
+        scopes = _scopes(data)
+        # {program: {event name: (family, is_op, part)}}
+        known: dict[int | None, dict[str, tuple]] = {}
+
+        def family(name: str, program=None) -> tuple[str, bool, str | None]:
+            return (_family(name),
+                    not name.startswith("jit_") and " = " in name,
+                    scopes.get((program, name)))
+
+        for plane in ProfileData.from_serialized_xspace(data).planes:
             lines = {line.name: line for line in plane.lines}
             if "XLA Ops" in lines:
+                runs = sorted(map(_module_run, lines["XLA Modules"].events)) \
+                    if "XLA Modules" in lines else []
+                begins = [r[0] for r in runs]
                 ops = []
+                begin = end = -1  # of the run that holds the event
                 for ev in lines["XLA Ops"].events:
                     start = int(ev.start_ns)
-                    ops.append((start, start + int(ev.duration_ns),
-                                *family(ev.name)))
-                ops.sort()
+                    if not begin <= start < end:  # another run's: its program
+                        k = bisect.bisect_right(begins, start) - 1
+                        begin, end, _, program = runs[k] if k >= 0 \
+                            and start < runs[k][1] else (-1, -1, None, None)
+                        seen = known.setdefault(program, {})
+                    name = ev.name
+                    got = seen.get(name)
+                    if got is None:
+                        got = seen[name] = family(name, program)
+                    ops.append((start, start + int(ev.duration_ns), *got))
+                ops.sort(key=operator.itemgetter(0, 1))
                 overlapped = []
                 if "Async XLA Ops" in lines:
                     for ev in lines["Async XLA Ops"].events:
-                        fam, is_op = family(ev.name)
+                        fam, is_op, _ = family(ev.name)
                         if is_op:
                             overlapped.append((fam, int(ev.duration_ns)))
-                mods = sorted(
-                    (int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
-                     ev.name.split("(")[0])
-                    for ev in lines["XLA Modules"].events) \
-                    if "XLA Modules" in lines else []
                 device.append({"ops": ops, "async": overlapped,
-                               "mods": mods})
+                               "mods": [r[:3] for r in runs]})
                 continue
             for line in plane.lines:
                 for ev in line.events:
@@ -283,11 +449,11 @@ def attribute_idle(trace_dir, capture=None) -> dict:
         if not ops:
             continue
         first = min(ops[0][0], mods[0][0] if mods else ops[0][0])
-        last = max(max(e for _, e, _, _ in ops),
+        last = max(max(op[1] for op in ops),
                    max((e for _, e, _ in mods), default=0))
         window += last - first
         end = -1
-        for s, e, _, _ in ops:  # the union of the operation intervals
+        for s, e, *_ in ops:  # the union of the operation intervals
             if s > end:
                 busy += e - s
                 end = e
@@ -304,10 +470,11 @@ def attribute_idle(trace_dir, capture=None) -> dict:
                                                 "ops": {}})
             p["runs"] += 1
             p["device_ns"] += r["end"] - r["start"]
+            booked = p["ops"]  # {(part, family): ns}
             while k < len(ops) and ops[k][0] < r["end"]:
-                s, e, fam, _ = ops[k]
+                s, e, fam, _, part = ops[k]
                 if s >= r["start"] and fam not in _ENVELOPE:
-                    p["ops"][fam] = p["ops"].get(fam, 0) + e - s
+                    booked[part, fam] = booked.get((part, fam), 0) + e - s
                 k += 1
             if prev is not None and r["start"] > prev[0]:
                 gap = gaps.setdefault((prev[1], r["kind"]),
@@ -333,6 +500,28 @@ def attribute_idle(trace_dir, capture=None) -> dict:
         return {(k or "unattributed"): ms(v)
                 for k, v in sorted(d.items(), key=lambda kv: -kv[1])}
 
+    def largest(fams: dict) -> dict:
+        return dict(list(phases_ms(fams).items())[:3])
+
+    def ops_ms(booked: dict) -> dict:
+        """A kind's operations by family, and the same operations by the
+        part of the model each was traced in, each part with its three
+        largest families; with no part, ``unnamed_ms`` and ``unnamed_ops``."""
+        by_fam: dict = {}
+        by_part: dict = {}
+        for (part, fam), ns in booked.items():
+            by_fam[fam] = by_fam.get(fam, 0) + ns
+            by_part.setdefault(part, {})[fam] = ns
+        named = {part: fams for part, fams in by_part.items() if part}
+        return {
+            "ops": phases_ms(by_fam),
+            "parts": phases_ms({part: sum(fams.values())
+                                for part, fams in named.items()}),
+            "unnamed_ms": ms(sum(by_part.get(None, {}).values())),
+            "unnamed_ops": largest(by_part.get(None, {})),
+            "part_ops": {part: largest(fams) for part, fams in sorted(
+                named.items(), key=lambda kv: -sum(kv[1].values()))}}
+
     longest = sorted(gaps.items(), key=lambda kv: -kv[1]["ns"])[:10]
     return {
         "idle": {
@@ -347,6 +536,6 @@ def attribute_idle(trace_dir, capture=None) -> dict:
         },
         "programs": {
             kind: {"runs": p["runs"] // n, "device_ms": ms(p["device_ns"]),
-                   "ops": phases_ms(p["ops"])}
+                   **ops_ms(p["ops"])}
             for kind, p in programs.items()},
     }

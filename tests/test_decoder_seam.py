@@ -465,6 +465,76 @@ def test_families_lower_to_the_text_they_had_before_the_one_leaf_row(
         f"JAX moved, pin {digest!r} in PR55_TEXT")
 
 
+# -- the named parts of a step (ISSUE 57) ----------------------------------------
+
+def _scoped_ops(text: str):
+    """Every matmul, convolution and custom call of a module lowered with
+    ``debug_info``, as ``(operation, local path, function)``, and ``{function:
+    [(calling function, the call's path)]}``: an operation inside a private
+    function (the trunk's one ``layer``, a jitted kernel wrapper) carries
+    the path below its function, and the rest of it is its call site's."""
+    import re
+
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"\(', text, re.M))
+    ops, calls, fn = [], {}, None
+    for line in text.splitlines():
+        began = re.match(r"\s*func\.func \w+ @([\w.]+)\(", line)
+        if began:
+            fn = began.group(1)
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        path = names.get(at.group(1), "") if at else ""
+        called = re.search(r"\bcall @([\w.]+)\(", line)
+        if called:
+            calls.setdefault(called.group(1), []).append((fn, path))
+        op = re.search(r"(stablehlo\.(?:dot_general|convolution|custom_call)"
+                       r"|\w+\.ragged_dot)\b", line)
+        if op:
+            ops.append((op.group(1), path, fn))
+    return ops, calls, set(names.values())
+
+
+@pytest.mark.parametrize("case", [f"{family}-{program}" for family in LOWERED
+                                  for program in ("prefill", "segment")])
+def test_every_matmul_lies_in_a_named_part(case, lowered_programs):
+    """One vocabulary in every family and in GPT-2 (``decoder.PARTS``): what
+    a capture's device time is booked to (utils/xplane.py).  Every matmul,
+    convolution and kernel call of both programs is traced inside a part,
+    its own scope's or that of every site its function is called from, and
+    the scopes the families had under their own names are gone."""
+    import re
+
+    from pytorch_zappa_serverless_tpu.utils.xplane import _part
+
+    family, program = case.split("-")
+    text = lowered_programs(family)[program].as_text(debug_info=True)
+    ops, calls, paths = _scoped_ops(text)
+    assert len(ops) >= 4, case
+
+    def outer(fn, seen=()):
+        """The parts ``fn``'s call sites lie in (None: one lies in none)."""
+        if fn == "main" or fn in seen or fn not in calls:
+            return {None}
+        return {p for caller, path in calls[fn]
+                for p in ([_part(path)] if _part(path)
+                          else outer(caller, seen + (fn,)))}
+
+    bare = [(op, path, fn) for op, path, fn in ops
+            if not _part(path) and None in outer(fn)]
+    assert not bare, f"{case}: in no part of {D.PARTS}: {bare[:5]}"
+    parts = {_part(path) for _, path, _ in ops} - {None}
+    assert parts <= set(D.PARTS) and {"qkv", "attend_out", "head"} <= parts
+    old = [p for p in paths for piece in p.split("/")
+           if re.match(r"(eva|nemotron|lfm2|mellum|joyai)_", piece)]
+    assert not old, f"{case}: family-named scopes are left: {old[:5]}"
+
+
+def test_part_refuses_a_name_outside_the_vocabulary():
+    with pytest.raises(ValueError, match="no part"):
+        D.part("mellum_attend_window")
+    with D.part("experts.unsort"):
+        pass
+
+
 def test_joyai_declares_one_leaf_and_the_seam_follows_it():
     """The first family whose row is one leaf: ``cache_leaves`` gives one
     where it gives a K and a V, ``slot_pools`` a pool with no V,

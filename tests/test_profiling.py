@@ -120,8 +120,18 @@ def _line(line_id, name, events) -> str:
 
 
 def _plane(plane_id, name, lines, event_names, stat_names=()) -> str:
-    meta = "".join(f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}\n'
-                   for i, n in enumerate(event_names, 1))
+    """``event_names``: a name, or ``(name, {stat's index: value})`` for an
+    event whose metadata carries stats (an int or a string each)."""
+    def stats_of(stats: dict) -> str:
+        return "".join(
+            f" stats {{ metadata_id: {k} "
+            + (f'str_value: "{v}"' if isinstance(v, str)
+               else f"uint64_value: {v}") + " }" for k, v in stats.items())
+
+    named = [(n, {}) if isinstance(n, str) else n for n in event_names]
+    meta = "".join(f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}"'
+                   f'{stats_of(stats)} }} }}\n'
+                   for i, (n, stats) in enumerate(named, 1))
     stats = "".join(f'stat_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}\n'
                     for i, n in enumerate(stat_names, 1))
     return (f'planes {{ id: {plane_id} name: "{name}"\n' + "".join(lines)
@@ -188,12 +198,118 @@ def test_attribute_idle_books_a_known_gap(synthetic_capture):
                              "device_early_ms": 0.0}
     # Both runs are jit__lambda_ to the profiler; the launches name them, and
     # the envelope (while) is not an operation of its own.
+    # No scope in this capture: every operation is in no part.
     assert got["programs"] == {
         "prefill": {"runs": 1, "device_ms": pytest.approx(2.0),
-                    "ops": {"fusion": pytest.approx(1.8)}},
+                    "ops": {"fusion": pytest.approx(1.8)},
+                    "parts": {}, "unnamed_ms": pytest.approx(1.8),
+                    "unnamed_ops": {"fusion": pytest.approx(1.8)},
+                    "part_ops": {}},
         "segment": {"runs": 1, "device_ms": pytest.approx(5.0),
                     "ops": {"copy": pytest.approx(3.0),
-                            "fusion": pytest.approx(2.0)}}}
+                            "fusion": pytest.approx(2.0)},
+                    "parts": {}, "unnamed_ms": pytest.approx(5.0),
+                    "unnamed_ops": {"copy": pytest.approx(3.0),
+                                    "fusion": pytest.approx(2.0)},
+                    "part_ops": {}}}
+
+
+# -- device time by named part of the model (ISSUE 57) ---------------------------
+
+@pytest.fixture
+def parts_capture(tmp_path):
+    """A prefill (program 7) and a segment (program 8), both
+    ``jit__lambda`` to the profiler, whose operations carry the scope they
+    were traced in as their event metadata's ``tf_op``, as a v5e's capture
+    does (stat 1 ``program_id``, stat 2 ``tf_op``), in us:
+
+        prefill [1000, 3000): fusion.3 400 (mlp), expert_matmul.9 600
+            (experts.matmul inside mlp), convolution.4 300 (under a function
+            and a primitive of a part's name: in none), reduce.1 100 (a mark
+            on a name that is no part), copy-done.5 50 (no scope: the
+            compiler's prefetch, which fusion.3 reads through a bitcast)
+        segment [4000, 9000): a while's envelope, fusion.3 2000 (cache_write
+            inside attend: the same name, another program), copy.11 500 (no
+            part), fusion.3 again 1000
+    """
+    def op(name, program, scope=None):
+        return (name, {1: program, **({2: scope + ":"} if scope else {})})
+
+    here = "jit(_lambda)/jit(layer)/"
+    device = _plane(1, "/device:TPU:0", [
+        _line(1, "XLA Modules", [(1, 1000, 2000, {}), (2, 4000, 5000, {})]),
+        _line(2, "XLA Ops", [
+            (3, 1000, 400, {}), (7, 1400, 600, {}), (6, 2000, 300, {}),
+            (9, 2300, 100, {}), (10, 2400, 50, {}),
+            (8, 4000, 5000, {}), (4, 4000, 2000, {}), (5, 6000, 500, {}),
+            (4, 6500, 1000, {})]),
+    ], ["jit__lambda(7)", "jit__lambda(8)",
+        op("%fusion.3 = f32[8] fusion(f32[8] %bitcast.6)", 7,
+           here + "part.mlp/dot_general"),
+        op("%fusion.3 = f32[8] fusion(f32[8] %bitcast.6)", 8,
+           "jit(_lambda)/while/body/" + "jit(layer)/part.attend/"
+           "part.cache_write/scatter"),
+        op("%copy.11 = f32[8] copy(%q)", 8, "jit(_lambda)/transpose"),
+        op("%convolution.4 = f32[8] convolution(%a, %b)", 7,
+           "jit(_lambda)/jit(norm)/norm/conv_general_dilated"),
+        op("%expert_matmul.9 = bf16[8] custom-call(%x)", 7,
+           here + "part.mlp/part.experts.matmul/expert_matmul/pallas_call"),
+        op("%while.2 = (s32[]) while(%t)", 8),
+        op("%reduce.1 = f32[] reduce(%x)", 7,
+           "jit(_lambda)/part.bogus/reduce_sum"),
+        op("%copy-done.5 = f32[8] copy-done((f32[8], u32[]) %copy-start.5)",
+           7),
+        op("%bitcast.6 = f32[8] bitcast(f32[8] %copy-done.5)", 7)],
+        ["program_id", "tf_op"])
+    host = _plane(2, "/host:CPU", [
+        _line(7, "python", [(1, 500, 700, {1: 1}), (2, 3700, 400, {1: 1})]),
+    ], ["tpuserve.prefill.launch", "tpuserve.segment.launch"], ["programs"])
+    out = tmp_path / "capture"
+    out.mkdir()
+    (out / "host.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(device + host))
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    "inside a part", "innermost wins", "no part",
+    "one name in two programs", "a primitive's name is no part",
+    "a prefetch takes its reader's part", "parts and unnamed sum to ops"])
+def test_device_time_by_part(parts_capture, case):
+    programs = attribute_idle(parts_capture)["programs"]
+    pre, seg = programs["prefill"], programs["segment"]
+    if case == "inside a part":
+        assert pre["parts"]["mlp"] == pytest.approx(0.45)
+        assert pre["part_ops"]["mlp"]["fusion"] == pytest.approx(0.4)
+    elif case == "innermost wins":
+        assert pre["parts"]["experts.matmul"] == pytest.approx(0.6)
+        assert pre["part_ops"]["experts.matmul"] == {
+            "expert_matmul": pytest.approx(0.6)}
+        assert seg["parts"] == {"cache_write": pytest.approx(3.0)}
+    elif case == "no part":
+        # The copy; the while is an envelope and no operation of either sum.
+        assert seg["unnamed_ms"] == pytest.approx(0.5)
+        assert seg["unnamed_ops"] == {"copy": pytest.approx(0.5)}
+        assert "while" not in seg["ops"]
+    elif case == "one name in two programs":
+        assert pre["part_ops"]["mlp"]["fusion"] == pytest.approx(0.4)
+        assert seg["part_ops"]["cache_write"] == {
+            "fusion": pytest.approx(3.0)}
+    elif case == "a primitive's name is no part":
+        assert set(pre["parts"]) == {"mlp", "experts.matmul"}
+        assert pre["unnamed_ms"] == pytest.approx(0.4)  # convolution, reduce
+    elif case == "a prefetch takes its reader's part":
+        # Two steps away, through an operation that never ran; in the
+        # segment the same fusion reads nothing that was fetched.
+        assert pre["part_ops"]["mlp"] == {
+            "fusion": pytest.approx(0.4), "copy-done": pytest.approx(0.05)}
+        assert "copy-done" not in seg["ops"]
+    else:
+        for p in programs.values():
+            assert sum(p["parts"].values()) + p["unnamed_ms"] \
+                == pytest.approx(sum(p["ops"].values()))
+            # The largest part first, and its largest families.
+            assert list(p["parts"]) == list(p["part_ops"])
 
 
 def test_one_read_of_a_capture_serves_both_reductions(synthetic_capture):
@@ -206,7 +322,7 @@ def test_one_read_of_a_capture_serves_both_reductions(synthetic_capture):
     device, host = capture
     assert [len(p["ops"]) for p in device] == [4] and len(host) == 6
     # The while is an envelope, the jit_ event no operation at all.
-    assert [(fam, is_op) for _, _, fam, is_op in device[0]["ops"]] == [
+    assert [(fam, is_op) for _, _, fam, is_op, _ in device[0]["ops"]] == [
         ("fusion", True), ("copy", True), ("while", True), ("fusion", True)]
     alone = op_time_breakdown(synthetic_capture)
     assert op_time_breakdown(synthetic_capture, capture) == alone
