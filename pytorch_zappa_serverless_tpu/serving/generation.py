@@ -50,7 +50,9 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import dataclasses
 import functools
+import os
 import time
 from dataclasses import dataclass, field
 
@@ -226,6 +228,32 @@ def _prefill_compiler_options() -> dict:
     return _NO_REMAT
 
 
+def _lane_programs(cm, mesh=None, **options):
+    """``program(name, fn, **jit options) -> StoredProgram`` for the programs
+    of one lane over ``cm``: jitted as they were, their executables kept by
+    the program store (engine/cache.py) under what they were built from, the
+    servable's whole configuration and the lane's ``options``.  A lane under
+    a mesh, and a ``cm`` that carries no configuration (a bare servable in a
+    measurement), get the jitted functions and no store."""
+    from ..engine.cache import StoredProgram, lane_basis
+
+    cfg = getattr(cm, "cfg", None)
+    basis = None
+    if cfg is not None and mesh is None:
+        config = dataclasses.asdict(cfg)
+        if cfg.checkpoint:  # which weights' builder read which file
+            try:
+                at = os.stat(cfg.checkpoint)
+                config["checkpoint"] = [cfg.checkpoint, at.st_size,
+                                        at.st_mtime_ns]
+            except OSError:
+                pass
+        basis = lane_basis(config, options)
+    return functools.partial(StoredProgram, basis=basis,
+                             model=getattr(cfg, "name", ""),
+                             clock=getattr(cm, "clock", None))
+
+
 def build_gen_kernels(cm, mesh=None):
     """The jitted prefill and segment + cache allocator for one model.
 
@@ -264,6 +292,7 @@ def build_gen_kernels(cm, mesh=None):
                 *rest[:n_leaves])
 
     kw = {"out_shardings": replicated} if mesh is not None else {}
+    program = _lane_programs(cm, mesh, lane="slot")
 
     def alloc_cache():
         """One allocation a leaf: a shared buffer would double-donate on the
@@ -284,10 +313,11 @@ def build_gen_kernels(cm, mesh=None):
     return {
         # (params, the pool's leaves, slots [B], payload) -> (first_tok [B],
         # *the pool's leaves): the pool donated, as to the segment.
-        "prefill": jax.jit(meta["prefill"], donate_argnums=(1,),
+        "prefill": program("prefill", meta["prefill"], donate_argnums=(1,),
                            compiler_options=_prefill_compiler_options(),
                            **kw),
-        "segment": jax.jit(lambda *a: _pack(*meta["segment"](*a)),
+        "segment": program("segment",
+                           lambda *a: _pack(*meta["segment"](*a)),
                            donate_argnums=(1,), **kw),
         "alloc_cache": alloc_cache,
         "meta": meta,
@@ -314,6 +344,8 @@ def build_paged_kernels(cm, block_size: int, num_blocks: int, spec_k: int):
     fns = pg["make"](block_size, spec_k)
     shape = pg["cache_shape"](num_blocks, block_size)
     cache_dtype = meta["cache_dtype"]
+    program = _lane_programs(cm, lane="paged", block_size=block_size,
+                             num_blocks=num_blocks, spec_k=spec_k)
 
     def alloc_cache():
         # Device-native zeros, NOT jnp.asarray(np.zeros(...)): the CPU
@@ -342,11 +374,11 @@ def build_paged_kernels(cm, block_size: int, num_blocks: int, spec_k: int):
         return ck.at[:, idx].set(kv), cv.at[:, idx].set(vv)
 
     return {
-        "prefill_chunk": jax.jit(fns["prefill_chunk"],
+        "prefill_chunk": program("prefill_chunk", fns["prefill_chunk"],
                                  donate_argnums=(4, 5)),
-        "segment": jax.jit(fns["segment"], donate_argnums=(1, 2)),
-        "propose": jax.jit(fns["propose"], donate_argnums=(1, 2)),
-        "verify": jax.jit(fns["verify"], donate_argnums=(1, 2)),
+        "segment": program("segment", fns["segment"], donate_argnums=(1, 2)),
+        "propose": program("propose", fns["propose"], donate_argnums=(1, 2)),
+        "verify": program("verify", fns["verify"], donate_argnums=(1, 2)),
         "spec_verify": jax.jit(speculative_verify),
         "copy_page": jax.jit(_copy_page, donate_argnums=(0, 1)),
         "read_page": jax.jit(_read_page),

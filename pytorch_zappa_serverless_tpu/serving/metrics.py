@@ -470,6 +470,13 @@ class MetricsHub:
                        "persistent cache's answer (hit|miss|uncached)",
                        [({"model": m, "program": p, "outcome": o}, n)
                         for (m, p, o), n in engine.clock.first_uses().items()])
+                metric("tpuserve_program_store_total", "counter",
+                       "Generation programs the program store restored "
+                       "(hits), compiled and stored (misses), failed to "
+                       "load, or left for the jitted function at a launch",
+                       [({"model": m, "outcome": o}, n)
+                        for m, counts in engine.clock.store_snapshot().items()
+                        for o, n in counts.items()])
             resident = getattr(engine.runner, "resident_bytes", None)
             if resident is not None:
                 by_model = resident()

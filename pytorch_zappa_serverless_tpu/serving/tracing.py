@@ -467,6 +467,13 @@ class _Phase:
         is open; None where the phase launches no program."""
         return self.tl.first_use(self)
 
+    def program(self) -> tuple[str, dict] | None:
+        """``(program, key)`` of the launch this phase is, as the lane names
+        it for the ledger (and the program store keys its executables by);
+        None where the phase launches no program."""
+        of = self.tl.program_of
+        return of(self.name, self.attrs) if of else None
+
 
 class _Trip:
     """One awaited round-trip to the dispatch thread (``runner.run_fn``).
@@ -578,8 +585,7 @@ class RoundTimeline:
         for held, use in self.open_uses:
             if held is phase:
                 return use
-        named = (self.program_of(phase.name, phase.attrs)
-                 if self.program_of and self.clock is not None else None)
+        named = phase.program() if self.clock is not None else None
         if named is None:
             return None
         use = self.clock.open(self.model, *named, seen=self._seen,

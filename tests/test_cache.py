@@ -122,7 +122,8 @@ def test_compile_clock_per_model_totals():
 
 # -- the ledger: a first use's stages, heard from inside jax -------------------
 
-ENTRY_KEYS = {"model", "program", "key", "outcome", "cause", "compiles",
+ENTRY_KEYS = {"model", "program", "key", "outcome", "restored", "cause",
+              "compiles",
               "layer_traces", "trace_s", "lower_s", "cache_read_s",
               "backend_s", "launch_s", "first_run_s", "round"}
 
